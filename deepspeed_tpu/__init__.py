@@ -15,52 +15,6 @@ from typing import Any, Dict, Optional, Tuple, Union
 __version__ = "0.1.0"
 __git_branch__ = "main"
 
-# jax < 0.6 keeps shard_map under jax.experimental; alias it onto the jax
-# namespace so every `from jax import shard_map` / `jax.shard_map` site in
-# the package works on both sides of the move.  Old jax's replication
-# checker also predates lax.scan-under-shard_map carry tracking (it reports
-# spurious carry replication mismatches), so default check_rep off there —
-# newer jax dropped the argument entirely.
-import jax as _jax
-if not hasattr(_jax, "shard_map"):
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _shard_map_compat(*args, **kwargs):
-        # new-API spelling -> old: check_vma==check_rep; axis_names (manual
-        # axes) is the complement of old `auto`
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        axis_names = kwargs.pop("axis_names", None)
-        if axis_names is not None:
-            mesh = kwargs.get("mesh", args[1] if len(args) > 1 else None)
-            if mesh is not None:
-                kwargs["auto"] = (frozenset(mesh.axis_names)
-                                  - frozenset(axis_names))
-        kwargs.setdefault("check_rep", False)
-        return _shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-# lax.axis_size is also newer than this jax; inside shard_map the old
-# spelling is jax.core.axis_frame(name) (a static int on 0.4.x, a frame
-# object with .size on some later versions)
-if not hasattr(_jax.lax, "axis_size"):
-    from jax import core as _core
-
-    def _axis_size_compat(axis_name):
-        if isinstance(axis_name, (tuple, list)):
-            n = 1
-            for a in axis_name:
-                n *= _axis_size_compat(a)
-            return n
-        frame = _core.axis_frame(axis_name)
-        return frame if isinstance(frame, int) else frame.size
-
-    _jax.lax.axis_size = _axis_size_compat
-
 from . import comm as _comm_pkg  # noqa: F401
 from .accelerator import get_accelerator  # noqa: F401 — reference parity
 from .comm.comm import init_distributed

@@ -61,10 +61,7 @@ def get_accelerator() -> DeepSpeedAccelerator:
     if not name:
         import jax
 
-        try:
-            name = jax.devices()[0].platform
-        except Exception:
-            name = "cpu"
+        name = jax.devices()[0].platform
     _accelerator = TpuAccelerator() if name == "tpu" else CpuAccelerator()
     return _accelerator
 

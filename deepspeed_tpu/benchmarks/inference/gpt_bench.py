@@ -155,6 +155,8 @@ def main() -> None:
                     "decode with dead-block DMA skip)")
     ap.add_argument("--warmup", type=int, default=3)
     args = ap.parse_args()
+    from ...utils.platform import enable_compile_cache
+    enable_compile_cache()
     result = run_bench(model=args.model, batch=args.batch,
                        prompt=args.prompt, new_tokens=args.new_tokens,
                        dtype=args.dtype, warmup=args.warmup,

@@ -2,9 +2,11 @@
 
 Counterpart of the reference's ``launcher/launch.py`` (per-local-rank Popen
 with RANK/LOCAL_RANK/WORLD_SIZE env, signal handling + process-tree kill
-:115).  On TPU each host usually runs ONE process that owns all local chips;
-``slots=N`` in the hostfile spawns N (for CPU simulation or megacore
-splits).  Rendezvous env is JAX's: DS_COORDINATOR/NUM_PROCESSES/PROCESS_ID,
+:115).  A TPU host runs ONE process that owns all its chips: a chip belongs
+to one process at a time and nothing here binds a process to a chip, so
+``slots=N > 1`` in the hostfile is for CPU simulation.  This parent only
+spawns; it never asks jax for a device, so it holds no chip while its
+children run.  Rendezvous env is JAX's: DS_COORDINATOR/NUM_PROCESSES/PROCESS_ID,
 consumed by ``deepspeed_tpu.comm.init_distributed`` →
 ``jax.distributed.initialize``.
 """

@@ -631,9 +631,44 @@ def flash_attention(q, k, v, causal: bool = True,
                              kv_lens=kv_lens)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
 
-    def to3(x):  # [B,S,H,D] → [B*H, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
+    def kernel(q, k, v, kv_lens, window):
+        b, _, h, _ = q.shape    # this shard's rows and heads under a mesh
 
-    o3 = _flash(to3(q), to3(k), to3(v), kv_lens, window, causal, scale,
-                bq, bk, H)
-    return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+        def to3(x):  # [B,S,H,D] → [B*H, S, D]
+            return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], D)
+
+        o3 = _flash(to3(q), to3(k), to3(v), kv_lens, window, causal, scale,
+                    bq, bk, h)
+        return o3.reshape(b, h, Sq, D).transpose(0, 2, 1, 3)
+
+    mesh = _partition_mesh()
+    if mesh is None:
+        return kernel(q, k, v, kv_lens, window)
+    # Mosaic kernels cannot be partitioned by the compiler: under a
+    # multi-device mesh the call sits in a shard_map, rows over the
+    # data-parallel axes and heads over the model axis (attention is
+    # independent across both).  A dim its axes do not divide stays whole
+    # and that work is repeated on each of their devices.
+    from jax.sharding import PartitionSpec as P
+    from ...parallel.mesh import DP_GROUP, MODEL_AXIS
+    rows = tuple(a for a in DP_GROUP if mesh.shape[a] > 1)
+    if not rows or B % math.prod(mesh.shape[a] for a in rows):
+        rows = None
+    heads = MODEL_AXIS if H % mesh.shape[MODEL_AXIS] == 0 else None
+    qkv = P(rows, None, heads, None)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(qkv, qkv, qkv, P(rows), P()),
+        out_specs=qkv, check_vma=False)(q, k, v, kv_lens, window)
+
+
+def _partition_mesh():
+    """The active multi-device mesh the kernel call must be mapped over,
+    or None: one device, no mesh, or a caller that is already inside a
+    manual region (ring/Ulysses attention, the pipeline stages, the
+    per-worker gradient collapse), where the operands are per-shard."""
+    from ...parallel.mesh import get_mesh_manager
+    mm = get_mesh_manager(optional=True)
+    if mm is None or mm.mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return mm.mesh

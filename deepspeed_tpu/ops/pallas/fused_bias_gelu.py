@@ -22,12 +22,15 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .utils import cdiv, interpret_mode, use_pallas
 
 _BLOCK_ROWS = 256
+_BLOCK_BYTES = 1 << 20   # cap on one fp32 (rows, C) tile, see _specs
+_DB_ROWS = 8    # sublanes of one bias-gradient output block
 _SQRT_2_OVER_PI = math.sqrt(2.0 / math.pi)
 
 
@@ -63,8 +66,13 @@ def _keep_mask(shape, rate: float, seed, block_id, block_rows):
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    u = (h >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
-    return (u >= rate).astype(jnp.float32)
+    # keep iff u >= rate for u = (h >> 8) / 2^24, decided on the integers:
+    # Mosaic has no uint32 -> float32 cast, and both u (a 24-bit integer
+    # times a power of two) and the float32 rate are exact, so comparing
+    # h >> 8 with ceil(rate * 2^24) gives the same mask bit for bit
+    threshold = math.ceil(float(np.float32(rate)) * (1 << 24))
+    keep = jax.lax.bitcast_convert_type(h >> 8, jnp.int32) >= threshold
+    return keep.astype(jnp.float32)
 
 
 def _fwd_kernel(seed_ref, x_ref, b_ref, o_ref, *, rate, block_rows):
@@ -91,12 +99,18 @@ def _bwd_kernel(seed_ref, x_ref, b_ref, g_ref, dx_ref, db_ref, *, rate,
     # dx writes are discarded, but a row-sum would carry undefined padding
     # contents into db on hardware
     row = i * block_rows + jax.lax.broadcasted_iota(jnp.int32, dx.shape, 0)
-    db_ref[...] = jnp.sum(jnp.where(row < total_rows, dx, 0.0),
-                          axis=0, keepdims=True)
+    db = jnp.sum(jnp.where(row < total_rows, dx, 0.0), axis=0, keepdims=True)
+    # the per-block row-sum is written to all _DB_ROWS sublanes of its
+    # output block: a (1, C) block would break Mosaic's (8, 128) tiling
+    db_ref[...] = jnp.broadcast_to(db, db_ref.shape)
 
 
 def _specs(rows, C):
-    block = min(_BLOCK_ROWS, rows)
+    # the kernels hold several fp32 (block, C) temporaries next to the
+    # double-buffered operands, all inside the compiler's scoped VMEM
+    # limit: narrow the row block as C grows (C=4096 gives 64 rows)
+    fit = max(8, _BLOCK_BYTES // (4 * C) // 8 * 8)
+    block = min(_BLOCK_ROWS, fit, rows)
     grid = (cdiv(rows, block),)
     row_blk = pl.BlockSpec((block, C), lambda i: (i, 0))
     bias_blk = pl.BlockSpec((1, C), lambda i: (0, 0))
@@ -131,14 +145,14 @@ def _bias_gelu_bwd(rate, res, g):
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), row_blk, bias_blk,
                   row_blk],
-        out_specs=[row_blk, pl.BlockSpec((1, C), lambda i: (i, 0))],
+        out_specs=[row_blk, pl.BlockSpec((_DB_ROWS, C), lambda i: (i, 0))],
         out_shape=[
             jax.ShapeDtypeStruct(x2.shape, x2.dtype),
-            jax.ShapeDtypeStruct((grid[0], C), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0] * _DB_ROWS, C), jnp.float32),
         ],
         interpret=interpret_mode(),
     )(seed, x2, b.reshape(1, -1), g)
-    return dx, jnp.sum(db_part, axis=0).astype(b.dtype), None
+    return dx, jnp.sum(db_part[::_DB_ROWS], axis=0).astype(b.dtype), None
 
 
 _bias_gelu.defvjp(_bias_gelu_fwd, _bias_gelu_bwd)

@@ -102,9 +102,14 @@ def _quant_kernel(seed_ref, x_ref, q_ref, scale_ref, offset_ref, *,
     scaled = (x - offset) / scale
     if stochastic:
         pltpu.prng_seed(seed_ref[0] + pl.program_id(0))
-        bits_u32 = pltpu.bitcast(pltpu.prng_random_bits(scaled.shape),
-                                 jnp.uint32)
-        noise = bits_u32.astype(jnp.float32) * (1.0 / 4294967296.0) - 0.5
+        bits = pltpu.bitcast(pltpu.prng_random_bits(scaled.shape), jnp.int32)
+        # the 32 random bits as an unsigned value in float32, built from
+        # two 16-bit halves: Mosaic has no uint32 -> float32 cast, each
+        # half converts exactly, and the one rounding left (in the add)
+        # is the rounding that cast would have made
+        hi = jax.lax.shift_right_logical(bits, 16).astype(jnp.float32)
+        lo = (bits & 0xFFFF).astype(jnp.float32)
+        noise = (hi * 65536.0 + lo) * (1.0 / 4294967296.0) - 0.5
         q = jnp.round(scaled + noise)
     else:
         q = jnp.round(scaled)

@@ -76,15 +76,10 @@ def _ring_attention_local(q, k, v, *, axis_name: str, sp: int, causal: bool):
     qf = q.astype(jnp.float32) * scale
 
     # mark initial accumulators as device-varying so the scan carry type is
-    # stable under shard_map's varying-manual-axes tracking (jax>=0.8)
-    try:
-        vma = tuple(jax.typeof(q).vma)
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        vma = ()
-    if vma and hasattr(lax, "pcast"):
+    # stable under shard_map's varying-manual-axes tracking
+    vma = tuple(jax.typeof(q).vma)
+    if vma:
         pvary = lambda x: lax.pcast(x, vma, to="varying")
-    elif vma:  # pragma: no cover - pre-pcast jax
-        pvary = lambda x: lax.pvary(x, vma)
     else:
         pvary = lambda x: x
     m0 = pvary(jnp.full((B, H, S), NEG_INF, jnp.float32))

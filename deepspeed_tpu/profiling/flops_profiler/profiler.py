@@ -35,10 +35,7 @@ def _num(x) -> float:
 
 
 def _cost_analysis(compiled) -> Dict[str, float]:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returned [dict]
-        ca = ca[0] if ca else {}
-    return {k: _num(v) for k, v in dict(ca).items()}
+    return {k: _num(v) for k, v in compiled.cost_analysis().items()}
 
 
 def count_params(params: PyTree) -> int:

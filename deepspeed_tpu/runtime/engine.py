@@ -1296,15 +1296,17 @@ class DeepSpeedEngine:
             self._micro_jit = self.compile_registry.register(
                 "micro", jax.jit(micro, donate_argnums=(1,)))
 
-        # offload_param (ZeRO-3 parameter offload): the stored-param
-        # placement is host memory — the step outputs must land back there
-        # or the offload is silently lost at the first optimizer step.
-        # None leaves mean "infer" (everything else keeps its placement).
-        pkind = self.zero_partitioner.param_memory_kind()
-        out_sh = None
-        if pkind is not None:
-            psh = self.shardings.params
-            out_sh = (psh, None, None, None, None, None, None)
+        # Every piece of state leaves the step with the sharding it was
+        # created with (norm and overflow are inferred).  Left to the
+        # compiler, the outputs come back under other, if equivalent,
+        # specs; the next call then misses the jit cache and the whole
+        # step compiles a second time.  It is also what returns
+        # offload_param's host-resident params (ZeRO-3 parameter offload)
+        # to host memory after the update.
+        so = self._out_shardings
+        master_sh = so["master"] if separate_master else so["params"]
+        out_sh = (so["params"], master_sh, so["opt_state"],
+                  self.shardings.grads, so["scale"], None, None)
 
         if separate_master:
             self._apply_jit = self.compile_registry.register(
@@ -1321,16 +1323,14 @@ class DeepSpeedEngine:
 
             self._fused_jit = self.compile_registry.register(
                 "fused", jax.jit(fused, donate_argnums=(0, 1, 2, 3, 4),
-                                 out_shardings=None if out_sh is None
-                                 else out_sh + (None,)))
+                                 out_shardings=out_sh + (None,)))
         else:
-            # offload_param implies stage >= 3 implies separate_master, so
-            # this branch never carries a host placement (out_sh is None)
             def apply_single(params, opt_state, grad_acc, scale_state, hyper):
                 return apply_core(params, params, opt_state, grad_acc, scale_state, hyper)
 
             self._apply_jit_single = self.compile_registry.register(
-                "apply", jax.jit(apply_single, donate_argnums=(0, 1, 2, 3)))
+                "apply", jax.jit(apply_single, donate_argnums=(0, 1, 2, 3),
+                                 out_shardings=out_sh))
 
             def fused_single(params, opt_state, grad_acc, scale_state, batches, hyper):
                 def body(acc, batch):
@@ -1341,7 +1341,8 @@ class DeepSpeedEngine:
                 return out + (jnp.mean(losses),)
 
             self._fused_jit_single = self.compile_registry.register(
-                "fused", jax.jit(fused_single, donate_argnums=(0, 1, 2, 3)))
+                "fused", jax.jit(fused_single, donate_argnums=(0, 1, 2, 3),
+                                 out_shardings=out_sh + (None,)))
 
     # ------------------------------------------------------------------ data
     def deepspeed_io(self, dataset, batch_size=None, route=None, pin_memory=False,
@@ -1936,6 +1937,31 @@ class DeepSpeedEngine:
             return jnp.mean(jnp.stack(losses))
         self._ensure_params_resident()
         self._count_batch_tokens(batches)
+        fused, args = self._fused_program(batches)
+        (new_params, new_master, new_opt, zero_acc, new_scale, norm, overflow,
+         mean_loss) = fused(*args)
+        s = self.state
+        s["params"] = new_params
+        s["master"] = new_master if self._separate_master else new_params
+        s["opt_state"] = new_opt
+        s["grad_acc"] = zero_acc
+        s["scale"] = new_scale
+        self._last_global_norm = norm
+        self._spill_params()
+        self.micro_steps += self.gradient_accumulation_steps()
+        self.global_samples += self.train_batch_size()
+        self.compile_registry.note_host_sync("step.overflow")
+        with self.tracer.span(SpanName.TRAIN_HOST_SYNC,
+                              label="step.overflow"):
+            overflow_host = bool(overflow)
+        self._finish_model_step(overflow_host)
+        return mean_loss
+
+    def _fused_program(self, batches):
+        """``(program, args)`` of the fused whole-batch step for
+        ``batches``: the registered jit and the sharded operands
+        ``train_batch_fused`` calls it with (``program.lower(*args)`` is
+        the step's AOT handle — HLO text, memory analysis)."""
         s = self.state
         batches = self._apply_curriculum(batches)
         batches = jax.tree_util.tree_map(
@@ -1957,30 +1983,12 @@ class DeepSpeedEngine:
         batches = self._inject_pld(
             batches, n=self.gradient_accumulation_steps())
         if self._separate_master:
-            (new_params, new_master, new_opt, zero_acc, new_scale, norm, overflow,
-             mean_loss) = self._fused_jit(
-                s["params"], s["master"], s["opt_state"], s["grad_acc"], s["scale"],
-                batches, self._hyper())
-        else:
-            (new_params, new_master, new_opt, zero_acc, new_scale, norm, overflow,
-             mean_loss) = self._fused_jit_single(
-                s["params"], s["opt_state"], s["grad_acc"], s["scale"],
-                batches, self._hyper())
-        s["params"] = new_params
-        s["master"] = new_master if self._separate_master else new_params
-        s["opt_state"] = new_opt
-        s["grad_acc"] = zero_acc
-        s["scale"] = new_scale
-        self._last_global_norm = norm
-        self._spill_params()
-        self.micro_steps += self.gradient_accumulation_steps()
-        self.global_samples += self.train_batch_size()
-        self.compile_registry.note_host_sync("step.overflow")
-        with self.tracer.span(SpanName.TRAIN_HOST_SYNC,
-                              label="step.overflow"):
-            overflow_host = bool(overflow)
-        self._finish_model_step(overflow_host)
-        return mean_loss
+            return self._fused_jit, (
+                s["params"], s["master"], s["opt_state"], s["grad_acc"],
+                s["scale"], batches, self._hyper())
+        return self._fused_jit_single, (
+            s["params"], s["opt_state"], s["grad_acc"], s["scale"],
+            batches, self._hyper())
 
     # ------------------------------------------------------------------ eval
     def eval_loss(self, batch):

@@ -410,14 +410,8 @@ class StagePrograms:
     def compile_counts(self) -> Dict[str, int]:
         """Jit-cache entry counts per program — the zero-steady-state-
         recompile gate asserts these stop growing after warmup."""
-        out: Dict[str, int] = {}
-        for name in ("stage_fwd", "stage_bwd", "finalize", "adam"):
-            fn = getattr(self, name)
-            try:
-                out[name] = int(fn._cache_size())
-            except Exception:  # dslint: disable=swallowed-exception — cache introspection is best-effort across jax versions
-                out[name] = -1
-        return out
+        return {name: int(getattr(self, name)._cache_size())
+                for name in ("stage_fwd", "stage_bwd", "finalize", "adam")}
 
 
 def _adam_leaf(p, m, v, g, t, lr, b1, b2, eps):

@@ -56,8 +56,8 @@ class DeepSpeedZeroOffloadOptimizerConfig(DeepSpeedConfigModel):
     #: carried in a device-resident residual and re-injected next step
     #: (error feedback), preserving convergence.  The reference streams
     #: uncompressed fp16 over PCIe (ZeRO-Infinity); over slower host
-    #: links (DCN-attached hosts, tunneled devices) compression is what
-    #: keeps the optimizer step off the critical path.
+    #: links (DCN-attached hosts) compression is what keeps the
+    #: optimizer step off the critical path.
     grad_compression: str = "none"
     #: scale-block granularity for grad_compression (elements per scale)
     compression_block: int = 2048

@@ -11,7 +11,7 @@ serving throughput: a program showing 2 compiles where the baseline shows
 1 means a shape/dtype leak into a supposedly stable program.
 
 Usage:
-    python scripts/compile_report.py [--train-steps 3] [--warmup 2]
+    python scripts/compile_report.py [--train-steps 3] [--warmup 1]
                                      [--requests 8] [--slots 3]
                                      [--out BENCH_COMPILE.json]
 
@@ -137,7 +137,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--train-steps", type=int, default=3,
                     help="steady-state steps after warmup")
-    ap.add_argument("--warmup", type=int, default=2)
+    ap.add_argument("--warmup", type=int, default=1,
+                    help="each step program compiles once, in the first step")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=3)
     ap.add_argument("--out", default="BENCH_COMPILE.json")

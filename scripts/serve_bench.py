@@ -4,8 +4,8 @@
 Drives a real ``ServingGateway`` (tiny random-init GPT by default) with a
 seeded Poisson arrival process and mixed prompt/reply lengths, then writes
 ``BENCH_SERVE.json`` — throughput tokens/s, TTFT p50/p99, slot occupancy,
-reject/timeout counts — so serving perf is a tracked per-PR trajectory
-like ``bench_artifacts/`` (schema: ``docs/serving.md``).
+reject/timeout counts — so serving behaviour is a tracked per-PR
+trajectory (schema: ``docs/serving.md``).
 
 A second phase benchmarks paged KV + session tiering on a **long-tail**
 conversation-length mix with **multi-turn** traffic (follow-up after
@@ -32,7 +32,7 @@ block GATES tokens/s uplift ≥ ``--spec-uplift``
 journaled per-round acceptance rate.  ``--config gemma_tpu_baseline``
 additionally appends an informational external-baseline reference row
 (the paper's Gemma-on-TPU serving baseline vs the local CPU fixture) to
-``bench_artifacts/bench_log.jsonl``.
+``bench_log.jsonl`` in the directory of ``--out``.
 
 Usage:
     python scripts/serve_bench.py [--slots 4] [--requests 32] [--rate 20]
@@ -458,8 +458,8 @@ EXTERNAL_BASELINES = {
 
 def emit_external_baseline(args, result: dict) -> str:
     """Append one informational external-baseline row to
-    ``bench_artifacts/bench_log.jsonl`` (the mfu_sweep trajectory log):
-    the named paper baseline next to the local fixture numbers."""
+    ``bench_log.jsonl`` beside ``--out``: the named paper baseline next to
+    the local fixture numbers."""
     base = EXTERNAL_BASELINES[args.config]
     row = {
         "label": f"serve-{args.config.replace('_', '-')}",
@@ -480,9 +480,8 @@ def emit_external_baseline(args, result: dict) -> str:
         row["local_fixture"]["spec_uplift"] = result["spec"]["uplift"]
         row["local_fixture"]["spec_accept_rate"] = \
             result["spec"]["accept_rate_mean"]
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(repo, "bench_artifacts", "bench_log.jsonl")
-    os.makedirs(os.path.dirname(path), exist_ok=True)
+    path = os.path.join(os.path.dirname(os.path.abspath(args.out)),
+                        "bench_log.jsonl")
     with open(path, "a") as f:
         f.write(json.dumps(row) + "\n")
     return path
@@ -635,8 +634,8 @@ def main(argv=None) -> int:
     ap.add_argument("--config", default=None,
                     choices=sorted(EXTERNAL_BASELINES),
                     help="also append this named external-baseline "
-                         "reference row to bench_artifacts/"
-                         "bench_log.jsonl (informational, gates nothing)")
+                         "reference row to bench_log.jsonl beside --out "
+                         "(informational, gates nothing)")
     ap.add_argument("--print-json", action="store_true",
                     help="print the result as one JSON line on stdout "
                          "(mfu_sweep row protocol)")
@@ -699,4 +698,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from deepspeed_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -1,0 +1,151 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for a
+described (not attached) v5e at the widths GPT-2 350M training and serving
+use.  Interpret mode cannot see what Mosaic refuses (a cast it lacks, a
+block that breaks the (8, 128) tiling, a kernel over its VMEM limit); these
+compiles can, and cost no chip.  Nothing runs: a pass here is not a chip
+run.
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+_KERNEL_MODULES = [
+    importlib.import_module(f"deepspeed_tpu.ops.pallas.{name}")
+    for name in ("flash_attention", "decode_attention", "fused_bias_gelu",
+                 "quantizer")]
+flash, decode, gelu, quantizer = _KERNEL_MODULES
+
+BF16 = jnp.bfloat16
+# GPT-2 350M: 16 heads of 64; training micro-batch rows x 1024 tokens,
+# serving 4 slots over a 1024-token cache in 128-token prefill chunks
+B, S, H, D = 8, 1024, 16, 64
+SLOTS, SMAX, CHUNK = 4, 1024, 128
+D_FF = 4096
+
+
+@pytest.fixture(scope="module")
+def v5e_host():
+    """The four devices of a described v5e 2x2 host."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile with
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_host):
+    """Sharding on one chip of that host."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_host[0])
+
+
+@pytest.fixture(autouse=True)
+def mosaic(monkeypatch):
+    """Steer the kernel entries onto the real lowering (they ask
+    ``jax.default_backend()``, which is the CPU here), with the persistent
+    compile cache off: a described-device executable can be written to it
+    but never read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    for mod in _KERNEL_MODULES:
+        monkeypatch.setattr(mod, "use_pallas", lambda: True)
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiles_with_kernel(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+def _flash_loss(q, k, v):
+    return jnp.sum(flash.flash_attention(q, k, v, causal=True)
+                   .astype(jnp.float32))
+
+
+def _qkv(sh, b, s, h, d):
+    return [jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=sh)] * 3
+
+
+def test_flash_forward(v5e):
+    _compiles_with_kernel(
+        lambda q, k, v: flash.flash_attention(q, k, v, causal=True),
+        *_qkv(v5e, B, S, H, D))
+
+
+@pytest.mark.parametrize("heads,head_dim", [(H, D), (8, 128)])
+def test_flash_forward_backward(v5e, heads, head_dim):
+    _compiles_with_kernel(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                          *_qkv(v5e, B, S, heads, head_dim))
+
+
+def test_flash_forward_backward_on_dp2_tp2_mesh(v5e_host):
+    """The compiler cannot partition a Mosaic kernel; under a mesh the
+    kernel entry maps it over rows and heads itself."""
+    from deepspeed_tpu.parallel.mesh import (DP_GROUP, MODEL_AXIS,
+                                             ParallelDims, initialize_mesh)
+    mm = initialize_mesh(ParallelDims(dp=2, tp=2), devices=v5e_host)
+    sh = mm.sharding(DP_GROUP, None, MODEL_AXIS, None)
+    _compiles_with_kernel(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                          *_qkv(sh, B, S, H, D))
+
+
+def _cache(sh, int8):
+    shape = (SLOTS, SMAX, H, D)
+    if not int8:
+        kv = jax.ShapeDtypeStruct(shape, BF16, sharding=sh)
+        return kv, kv, None, None
+    codes = jax.ShapeDtypeStruct(shape, jnp.int8, sharding=sh)
+    scale = jax.ShapeDtypeStruct(shape[:3] + (1,), jnp.float32, sharding=sh)
+    return codes, codes, scale, scale
+
+
+@pytest.mark.parametrize("sq", [1, CHUNK], ids=["decode", "chunk"])
+@pytest.mark.parametrize("int8,per_row_pos",
+                         [(False, True), (True, False), (True, True)],
+                         ids=["bf16-rowpos", "int8-scalarpos", "int8-rowpos"])
+def test_cached_attention(v5e, sq, int8, per_row_pos):
+    q = jax.ShapeDtypeStruct((SLOTS, sq, H, D), BF16, sharding=v5e)
+    pos = jax.ShapeDtypeStruct((SLOTS,) if per_row_pos else (), jnp.int32,
+                               sharding=v5e)
+    k, v, ks, vs = _cache(v5e, int8)
+    if int8:
+        _compiles_with_kernel(
+            lambda q, k, v, pos, ks, vs: decode.cached_attention(
+                q, k, v, pos, k_scale=ks, v_scale=vs), q, k, v, pos, ks, vs)
+    else:
+        _compiles_with_kernel(decode.cached_attention, q, k, v, pos)
+
+
+@pytest.mark.parametrize("stochastic", [False, True],
+                         ids=["nearest", "stochastic"])
+def test_symmetric_quantizer(v5e, stochastic):
+    w = jax.ShapeDtypeStruct((1024, D_FF), jnp.float32, sharding=v5e)
+    _compiles_with_kernel(
+        lambda w: quantizer.quantize(w, groups=1024, stochastic=stochastic),
+        w)
+
+
+def test_bias_gelu_dropout_forward_backward(v5e):
+    x = jax.ShapeDtypeStruct((B * S, D_FF), BF16, sharding=v5e)
+    b = jax.ShapeDtypeStruct((D_FF,), BF16, sharding=v5e)
+
+    def loss(x, b):
+        return jnp.sum(gelu.bias_gelu_dropout(x, b, dropout_rate=0.1, seed=3)
+                       .astype(jnp.float32))
+
+    _compiles_with_kernel(jax.grad(loss, argnums=(0, 1)), x, b)
