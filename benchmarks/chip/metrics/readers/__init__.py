@@ -1,0 +1,1 @@
+"""Part of the chip benchmark (see ``run.py``)."""
