@@ -372,8 +372,9 @@ def _attention(q, k, v, config: GPTConfig, window=None):
     """
     from jax.ad_checkpoint import checkpoint_name
 
-    return checkpoint_name(_attention_impl(q, k, v, config, window),
-                           "ds_attn_out")
+    with jax.named_scope("attention"):
+        return checkpoint_name(_attention_impl(q, k, v, config, window),
+                               "ds_attn_out")
 
 
 def _attention_impl(q, k, v, config: GPTConfig, window=None):
@@ -475,6 +476,7 @@ def attn_out_residual(x, attn, p, config: GPTConfig, dropout_key=None):
                         dropout_key)
 
 
+@jax.named_scope("mlp")
 def mlp_out(x, p, config: GPTConfig, dropout_key=None):
     """LN2 + MLP (no residual add — parallel-residual models sum it with
     the attention branch instead of chaining)."""
@@ -532,6 +534,7 @@ def _block(x, layer_params, config: GPTConfig, positions=None,
     return mlp_residual(h, layer_params, config, dropout_key=k_mlp)
 
 
+@jax.named_scope("embed")
 def embed(params: PyTree, tokens: jnp.ndarray, config: GPTConfig,
           positions=None) -> jnp.ndarray:
     """Token (+ learned position) embedding with the family's variants.
@@ -548,6 +551,7 @@ def embed(params: PyTree, tokens: jnp.ndarray, config: GPTConfig,
     return x
 
 
+@jax.named_scope("head")
 def _head_logits(params: PyTree, h, config: GPTConfig) -> jnp.ndarray:
     """(Tied or separate) head on final-layernormed hiddens ``h``.
 
@@ -564,6 +568,7 @@ def _head_logits(params: PyTree, h, config: GPTConfig) -> jnp.ndarray:
     return logits
 
 
+@jax.named_scope("loss")
 def _token_nll(logits, targets):
     """Per-token masked NLL sums: (sum nll, count). targets < 0 are masked
     (the -100 convention)."""

@@ -212,7 +212,8 @@ def _decode(q3, k3, v3, pos, sm_scale, block_k, H, ks3=None, vs3=None,
     return pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=jax.ShapeDtypeStruct((BH, 1, D),
                                                          q3.dtype),
-                          interpret=interpret_mode())(*args)
+                          interpret=interpret_mode(),
+                          name="decode_attention")(*args)
 
 
 def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
@@ -337,7 +338,8 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
     return pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=jax.ShapeDtypeStruct((BH, Sq, D),
                                                          q3.dtype),
-                          interpret=interpret_mode())(*args)
+                          interpret=interpret_mode(),
+                          name="chunk_attention")(*args)
 
 
 def cached_attention(q, cache_k, cache_v, pos,

@@ -237,6 +237,7 @@ def _fwd(q3, k3, v3, lens, win, causal, sm_scale, block_q, block_k, H):
         interpret=interpret_mode(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_fwd",
     )(lens_arr, win_arr, q3, k3, v3)
     return o, lse
 
@@ -446,6 +447,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, lens, win, causal, sm_scale, block_q,
             interpret=interpret_mode(),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="flash_bwd",
         )(lens_arr, win_arr, q3, k3, v3, do3, lse, delta)
         dq = jnp.sum(dqp, axis=1).astype(q3.dtype)
         return dq, dk, dv
@@ -470,6 +472,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, lens, win, causal, sm_scale, block_q,
         interpret=interpret_mode(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dq",
     )(lens_arr, win_arr, q3, k3, v3, do3, lse, delta)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, emit_dq=False, **common)
@@ -501,6 +504,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, lens, win, causal, sm_scale, block_q,
         interpret=interpret_mode(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dkv",
     )(lens_arr, win_arr, q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 

@@ -1215,7 +1215,9 @@ class DeepSpeedEngine:
                 norm = global_grad_norm(grads)
             # compute the update on master shards (ZeRO weight-update sharding)
             grads = constrain(grads, master_specs)
-            new_master, new_opt = optimizer.update(grads, opt_state, master, hyper)
+            with jax.named_scope("optimizer"):
+                new_master, new_opt = optimizer.update(
+                    grads, opt_state, master, hyper)
             new_master = constrain(new_master, master_specs)
             # overflow → keep previous state (the reference's skipped step)
             keep = lambda new, old: jax.tree_util.tree_map(
@@ -1938,8 +1940,9 @@ class DeepSpeedEngine:
         self._ensure_params_resident()
         self._count_batch_tokens(batches)
         fused, args = self._fused_program(batches)
-        (new_params, new_master, new_opt, zero_acc, new_scale, norm, overflow,
-         mean_loss) = fused(*args)
+        with self.tracer.span(SpanName.TRAIN_DISPATCH):
+            (new_params, new_master, new_opt, zero_acc, new_scale, norm,
+             overflow, mean_loss) = fused(*args)
         s = self.state
         s["params"] = new_params
         s["master"] = new_master if self._separate_master else new_params
@@ -1964,13 +1967,15 @@ class DeepSpeedEngine:
         the step's AOT handle — HLO text, memory analysis)."""
         s = self.state
         batches = self._apply_curriculum(batches)
-        batches = jax.tree_util.tree_map(
-            lambda x: jnp.asarray(x).reshape(
-                (self.gradient_accumulation_steps(), -1) + np.shape(x)[1:]), batches)
-        batches = jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, NamedSharding(
-                self.mesh, P(None, (DCN_AXIS, DATA_AXIS, EXPERT_AXIS)))),
-            batches)
+        with self.tracer.span(SpanName.TRAIN_BATCH_PUT):
+            batches = jax.tree_util.tree_map(
+                lambda x: jnp.asarray(x).reshape(
+                    (self.gradient_accumulation_steps(), -1)
+                    + np.shape(x)[1:]), batches)
+            batches = jax.tree_util.tree_map(
+                lambda x: jax.device_put(x, NamedSharding(
+                    self.mesh, P(None, (DCN_AXIS, DATA_AXIS, EXPERT_AXIS)))),
+                batches)
         if self._compression_scheduler is not None and isinstance(batches, dict):
             from ..compression.compress import STEP_KEY
             # one step scalar per gas micro-step (same global step for all)

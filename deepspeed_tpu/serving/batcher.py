@@ -56,6 +56,7 @@ from ..inference.speculative import (spec_accept_batch, spec_accept_keys,
 from ..telemetry.spans import SpanName, Tracer
 from ..utils.compile_watch import CompiledProgramRegistry, hot_path
 from .config import ServingConfig
+from .paging import cache_bank_bytes
 
 
 @dataclasses.dataclass
@@ -73,8 +74,8 @@ class SlotBatcher:
 
     def __init__(self, engine, config: ServingConfig,
                  tracer: Optional[Tracer] = None, draft=None):
-        #: telemetry tracer shared with the owning gateway (disabled
-        #: no-op when serving runs without telemetry)
+        #: telemetry tracer shared with the owning gateway (keeps no
+        #: records when serving runs without telemetry)
         self.tracer = tracer if tracer is not None else Tracer(
             enabled=False, name="serving")
         self._engine = engine
@@ -98,6 +99,8 @@ class SlotBatcher:
         B = self.slots
         self.cache = fam.init_cache(cfg, B, self.max_len,
                                     kv_dtype=self._kv_dtype)
+        #: bytes of the batch-1 cache every fresh prefill allocates
+        self._row_cache_bytes = cache_bank_bytes(self.cache) // B
         self.lengths = jnp.zeros((B,), jnp.int32)
         self.keys = jnp.stack([jax.random.PRNGKey(0)] * B)
         self.greedy = jnp.ones((B,), bool)
@@ -206,13 +209,15 @@ class SlotBatcher:
         vocab = cfg.vocab_size
 
         def tick(params, cache, lengths, last, keys, greedy, temp, active):
-            lg = last[:, :vocab]
-            ks = jax.vmap(jax.random.split)(keys)         # [B, 2, 2]
-            next_keys, subkeys = ks[:, 0], ks[:, 1]
-            filt = filter_logits(lg, temp[:, None], top_k=top_k, top_p=top_p)
-            sampled = jax.vmap(jax.random.categorical)(subkeys, filt)
-            nxt = jnp.where(greedy, jnp.argmax(lg, -1),
-                            sampled).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                lg = last[:, :vocab]
+                ks = jax.vmap(jax.random.split)(keys)     # [B, 2, 2]
+                next_keys, subkeys = ks[:, 0], ks[:, 1]
+                filt = filter_logits(lg, temp[:, None], top_k=top_k,
+                                     top_p=top_p)
+                sampled = jax.vmap(jax.random.categorical)(subkeys, filt)
+                nxt = jnp.where(greedy, jnp.argmax(lg, -1),
+                                sampled).astype(jnp.int32)
             logits, cache = fam.decode_step(params, nxt, cfg, cache,
                                             lengths=lengths)
             # only live slots advance; a freed slot re-writes its own cell
@@ -473,27 +478,38 @@ class SlotBatcher:
         p_first, p_rest = ("prefill_wide", "extend_wide") if wide \
             else ("prefill", "extend")
         S = int(tokens.shape[0])
+        pad = (-S) % C
+        n_chunks = (S + pad) // C
         with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S,
-                              start=start_len, chunk=C):
-            pad = (-S) % C
+                              start=start_len, chunk=C, padded=S + pad,
+                              chunks=n_chunks):
             padded = np.concatenate(
                 [np.asarray(tokens, np.int32),
                  np.zeros((pad,), np.int32)]) if pad else np.asarray(
                      tokens, np.int32)
             chunks = padded.reshape(-1, C)
-            cache = start_cache if start_cache is not None else fam.init_cache(
-                cfg, 1, self.max_len, kv_dtype=self._kv_dtype)
+            if start_cache is not None:
+                cache = start_cache
+            else:
+                with self.tracer.span(SpanName.SERVE_CACHE_ALLOC,
+                                      bytes=self._row_cache_bytes):
+                    cache = fam.init_cache(cfg, 1, self.max_len,
+                                           kv_dtype=self._kv_dtype)
             params = self._engine.params
             lg = None
             for i, ch in enumerate(chunks):
-                dev = jnp.asarray(ch[None])
                 pos = start_len + i * C
-                if pos == 0:
-                    lg, cache = self._p[p_first](params, dev, cache)
-                else:
-                    lg, cache = self._p[p_rest](
-                        params, dev, cache, jnp.asarray([pos], jnp.int32))
-            idx = S - 1 - (len(chunks) - 1) * C
+                program = p_first if pos == 0 else p_rest
+                with self.tracer.span(SpanName.SERVE_PREFILL_CHUNK, index=i,
+                                      pos=pos, program=program):
+                    dev = jnp.asarray(ch[None])
+                    if pos == 0:
+                        lg, cache = self._p[program](params, dev, cache)
+                    else:
+                        lg, cache = self._p[program](
+                            params, dev, cache,
+                            jnp.asarray([pos], jnp.int32))
+            idx = S - 1 - (n_chunks - 1) * C
             p_last = "take_last_wide" if wide else "take_last"
             vec = self._p[p_last](lg, jnp.asarray(idx, jnp.int32))
         return cache, vec, start_len + S
@@ -529,25 +545,26 @@ class SlotBatcher:
         row_dev = jnp.asarray(row, jnp.int32)
         if self._last is None:
             self._last = jnp.zeros((self.slots,) + vec.shape, vec.dtype)
-        self.cache = self._p["write_slot"](self.cache, row_dev, cache)
-        (self.lengths, self._last, self.keys, self.greedy, self.temp,
-         self.active) = self._p["bind"](
-            self.lengths, self._last, self.keys, self.greedy, self.temp,
-            self.active, row_dev, jnp.asarray(frontier, jnp.int32), vec,
-            key, jnp.asarray(bool(greedy)),
-            jnp.asarray(float(temperature), jnp.float32))
-        if self.spec:
-            # lockstep draft admission: the draft prefills the FULL
-            # prompt (prefix/readmit shortcuts spare only target work —
-            # the draft is small, that is its whole point) and the slot's
-            # pending token is seeded from the admission logits
-            self.draft_cache = self._p["draft_write_slot"](
-                self.draft_cache, row_dev,
-                self._draft_prefill(np.asarray(tokens)))
-            self.cur, self.keys = self._p["spec_seed"](
-                self.cur, self.keys, row_dev, vec,
-                jnp.asarray(bool(greedy)),
+        with self.tracer.span(SpanName.SERVE_SLOT_WRITE, slot=row):
+            self.cache = self._p["write_slot"](self.cache, row_dev, cache)
+            (self.lengths, self._last, self.keys, self.greedy, self.temp,
+             self.active) = self._p["bind"](
+                self.lengths, self._last, self.keys, self.greedy, self.temp,
+                self.active, row_dev, jnp.asarray(frontier, jnp.int32), vec,
+                key, jnp.asarray(bool(greedy)),
                 jnp.asarray(float(temperature), jnp.float32))
+            if self.spec:
+                # lockstep draft admission: the draft prefills the FULL
+                # prompt (prefix/readmit shortcuts spare only target work
+                # — the draft is small, that is its whole point) and the
+                # slot's pending token is seeded from the admission logits
+                self.draft_cache = self._p["draft_write_slot"](
+                    self.draft_cache, row_dev,
+                    self._draft_prefill(np.asarray(tokens)))
+                self.cur, self.keys = self._p["spec_seed"](
+                    self.cur, self.keys, row_dev, vec,
+                    jnp.asarray(bool(greedy)),
+                    jnp.asarray(float(temperature), jnp.float32))
         return frontier
 
     def _draft_prefill(self, tokens: np.ndarray):
@@ -610,9 +627,10 @@ class SlotBatcher:
                     self.active)
             self._last = logits
             self.registry.note_host_sync("serving.tick")
-            # the emitted tokens ARE the tick's output boundary:
-            # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
-            return np.asarray(nxt)
+            # the emitted tokens ARE the tick's output boundary
+            with self.tracer.span(SpanName.SERVE_PULL):
+                # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
+                return np.asarray(nxt)
 
     @hot_path
     def _spec_tick(self):
@@ -637,8 +655,9 @@ class SlotBatcher:
                     self.lengths, self.greedy, self.temp, self.active)
                 self.keys = next_keys
             self.registry.note_host_sync("serving.tick")
-            # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
-            return np.asarray(window), np.asarray(adv)
+            with self.tracer.span(SpanName.SERVE_PULL):
+                # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
+                return np.asarray(window), np.asarray(adv)
 
     @hot_path
     def _paused_tick(self) -> np.ndarray:
@@ -665,5 +684,6 @@ class SlotBatcher:
                         self.active)
                 self._last = logits
             self.registry.note_host_sync("serving.tick")
-            # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
-            return np.asarray(nxt)
+            with self.tracer.span(SpanName.SERVE_PULL):
+                # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
+                return np.asarray(nxt)

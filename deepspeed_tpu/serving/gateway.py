@@ -77,9 +77,10 @@ class ServingGateway:
         elif isinstance(config, dict):
             config = ServingConfig.from_dict(config)
         self.config = config
-        #: telemetry tracer (shared with the batcher): serve.admit /
-        #: serve.prefill / serve.tick spans for the unified timeline.
-        #: Callers pass one to record; the default is a disabled no-op.
+        #: telemetry tracer (shared with the batcher): the serve.* spans
+        #: of docs/telemetry.md, per-request ones keyed by ``rid``.
+        #: Callers pass one to keep records; the default keeps none and
+        #: still annotates an attached profiler's trace.
         self.tracer = tracer if tracer is not None else Tracer(
             enabled=False, name="serving")
         #: speculative decoding in the tick loop (docs/serving.md
@@ -295,6 +296,44 @@ class ServingGateway:
         if self._pager is not None:
             snap["paging"] = self._pager.stats()
         return snap
+
+    def probe_logits(self, prompts, ticks: int):
+        """``(replies, logits)`` of chunked prefill and ``ticks`` greedy
+        decode ticks through this gateway's own slot path (its compiled
+        programs, its slot cache): for each prompt the tokens it replied
+        and the float32 logits ``[1 + ticks, padded vocab]`` after the
+        prefill and after each tick.  The public entry for checking the
+        serving path's numerics against a reference.
+
+        It takes the slots over (rows ``0..len(prompts)-1``, whatever
+        they held is released), so it is for a gateway whose scheduler is
+        not running: after :meth:`shutdown`, or before :meth:`start`."""
+        if self._thread.is_alive():
+            raise RuntimeError(
+                "probe_logits drives the slot batch itself: shut the "
+                "gateway down first")
+        if self._spec:
+            raise NotImplementedError(
+                "probe_logits reads one token per tick; a speculative "
+                "gateway emits windows")
+        b = self._batcher
+        n = len(prompts)
+        if n > b.slots:
+            raise ValueError(f"{n} prompts for {b.slots} slots")
+        for row in range(b.slots):
+            b.release(row)
+        for row, p in enumerate(prompts):
+            b.admit(row, np.asarray(p, np.int32), jax.random.PRNGKey(0),
+                    True, 1.0)
+        frontier = lambda: np.asarray(b._last[:n], np.float32)
+        logits, replies = [frontier()], []
+        for _ in range(ticks):
+            replies.append(b.tick()[:n])
+            logits.append(frontier())
+        for row in range(n):
+            b.release(row)
+        return ([[int(t[i]) for t in replies] for i in range(n)],
+                [np.stack([l[i] for l in logits]) for i in range(n)])
 
     def attach_metrics(self, sampler) -> None:
         """Stream this gateway's gauges through a telemetry
@@ -538,8 +577,9 @@ class ServingGateway:
                     return
                 _, req = heapq.heappop(self._queue)
                 row = self._free_rows.pop(0)
+                left = len(self._queue)
             try:
-                self._admit_one(row, req)
+                self._admit_one(row, req, left)
             except BaseException as e:
                 with self._cond:
                     self._active.pop(row, None)
@@ -553,8 +593,14 @@ class ServingGateway:
                 err.__cause__ = e
                 req.handle._finish(RequestState.FAILED, error=err)
 
-    def _admit_one(self, row: int, req: ServeRequest) -> None:
-        with self.tracer.span(SpanName.SERVE_ADMIT, slot=row,
+    def _admit_one(self, row: int, req: ServeRequest, left: int) -> None:
+        """``left``: the queue length the pop left behind."""
+        if self.tracer.enabled:
+            t_submit = req.handle.t_submit
+            self.tracer.record(SpanName.SERVE_QUEUE, t_submit,
+                               time.monotonic() - t_submit, rid=req.rid,
+                               priority=req.priority, depth=left)
+        with self.tracer.span(SpanName.SERVE_ADMIT, rid=req.rid, slot=row,
                               prompt_len=req.prompt_len):
             self._admit_one_inner(row, req)
 
@@ -744,6 +790,14 @@ class ServingGateway:
         # window[b, :counts[b]] this tick — while a plain tick (spec off,
         # or paused by the ladder's spec_pause rung) is a [B] array
         res = self._batcher.tick()
+        with self.tracer.span(SpanName.SERVE_HARVEST,
+                              live=len(self._active)):
+            self._harvest(res)
+
+    def _harvest(self, res) -> None:
+        """Hand one tick's tokens to their requests: append, stamp first
+        tokens, finish rows that hit eos / budget / deadline /
+        cancellation and free their slots."""
         if isinstance(res, tuple):
             tokens, counts = res
         else:
@@ -780,6 +834,10 @@ class ServingGateway:
                 if h.t_first_token is None:
                     h.t_first_token = now
                     self.metrics.record_ttft(h.ttft_s)
+                    if self.tracer.enabled:
+                        self.tracer.record(SpanName.SERVE_FIRST_TOKEN,
+                                           h.t_admit, now - h.t_admit,
+                                           rid=req.rid)
                     if self._overload is not None:
                         self._overload.note_first_token(
                             (now - (h.t_admit or h.t_submit)) * 1e3)
@@ -892,10 +950,8 @@ class ServingGateway:
             convs += sum(1 for r in self._active.values()
                          if r.session_id is None)
         m = self.metrics
-        m.set_value("concurrent_conversations", convs)
         m.set_max("peak_concurrent_conversations", convs)
         m.set_value("pool_blocks_used", st["pool_blocks_used"])
-        m.set_value("park_bytes", st["park_bytes"])
         m.set_value("serving_hbm_bytes", p.hbm_bytes())
         m.set_value("hbm_bytes_per_conversation",
                     p.hbm_bytes() / max(1, convs))

@@ -64,17 +64,12 @@ class ServingMetrics:
         self.pool_evictions = 0
         #: RAM-park capacity spills to the disk tier
         self.park_spills = 0
-        #: parked sessions dropped (capacity without disk, TTL, corrupt)
-        self.park_drops = 0
         self.pages_allocated = 0
-        self.pages_freed = 0
         #: gauges pushed by the gateway after tier changes
         self.hbm_bytes_per_conversation = 0.0
-        self.concurrent_conversations = 0
         self.peak_concurrent_conversations = 0
         self.serving_hbm_bytes = 0
         self.pool_blocks_used = 0
-        self.park_bytes = 0
         #: time-to-first-token, seconds — the shared telemetry histogram
         #: (count/sum exact, reservoir bounded at :data:`_TTFT_CAP`)
         self.ttft = Histogram(MetricName.SERVE_TTFT_S, cap=_TTFT_CAP)
@@ -160,17 +155,13 @@ class ServingMetrics:
                 "readmit_misses": self.readmit_misses,
                 "pool_evictions": self.pool_evictions,
                 "park_spills": self.park_spills,
-                "park_drops": self.park_drops,
                 "pages_allocated": self.pages_allocated,
-                "pages_freed": self.pages_freed,
                 "hbm_bytes_per_conversation":
                     self.hbm_bytes_per_conversation,
-                "concurrent_conversations": self.concurrent_conversations,
                 "peak_concurrent_conversations":
                     self.peak_concurrent_conversations,
                 "serving_hbm_bytes": self.serving_hbm_bytes,
                 "pool_blocks_used": self.pool_blocks_used,
-                "park_bytes": self.park_bytes,
                 "spec_rounds": self.spec_rounds,
                 "spec_accepted": self.spec_accepted,
                 "spec_proposed": self.spec_proposed,

@@ -686,7 +686,6 @@ class SessionPager:
             self.sessions.pop(victim.sid, None)
         for bid in victim.table:
             self.pool.allocator.free(bid)
-            self._count("pages_freed")
         try:
             self._park_arrays(victim.sid, victim.tokens, cache,
                               victim.length)
@@ -698,7 +697,6 @@ class SessionPager:
                        session=victim.sid, reason="park_failed",
                        idle_s=round(time.monotonic() - victim.t_used, 3),
                        bytes=victim.nbytes)
-            self._count("park_drops")
         return True
 
     # -------------------------------------------------------------- retire
@@ -741,14 +739,12 @@ class SessionPager:
         cache = self.pool.read_slot(self._batcher.cache, row, length)
         for bid in led.table:
             self.pool.allocator.free(bid)
-            self._count("pages_freed")
         try:
             self._park_arrays(sid, tokens, cache, length)
         except (OSError, RuntimeError, ValueError) as exc:
             logger.warning(
                 f"[serving] parking session {sid!r} failed ({exc}); "
                 "dropping it — next turn re-prefills")
-            self._count("park_drops")
 
     def _park_arrays(self, sid: str, tokens: np.ndarray, cache,
                      length: int) -> None:
@@ -786,7 +782,6 @@ class SessionPager:
                 self._emit(EventKind.SERVE_EVICT, prefix=None, session=vid,
                            reason="park_capacity", idle_s=None,
                            bytes=vbytes)
-                self._count("park_drops")
 
     def row_released(self, row: int) -> None:
         """A slot freed without a retire (cancel/timeout/failure/shutdown):
@@ -796,7 +791,6 @@ class SessionPager:
             return
         for bid in led.table:
             self.pool.allocator.free(bid)
-            self._count("pages_freed")
 
     def drop_session(self, sid: str, reason: str) -> None:
         with self._lock:
@@ -807,7 +801,6 @@ class SessionPager:
         if sess.table:
             for bid in sess.table:
                 self.pool.allocator.free(bid)
-                self._count("pages_freed")
         self._emit(EventKind.SERVE_EVICT, prefix=None, session=sid,
                    reason=reason,
                    idle_s=round(time.monotonic() - sess.t_used, 3),
@@ -842,7 +835,6 @@ class SessionPager:
         for bid in table:
             last = self.pool.allocator.refs(bid) == 1
             self.pool.allocator.free(bid)
-            self._count("pages_freed")
             if last:
                 freed += self.pool.block_bytes
         return freed
@@ -891,4 +883,3 @@ class SessionPager:
                 self.sessions.pop(sid, None)
             self._emit(EventKind.SERVE_EVICT, prefix=None, session=sid,
                        reason="ttl", idle_s=round(idle, 3), bytes=nbytes)
-            self._count("park_drops")

@@ -13,15 +13,30 @@ rows feed three consumers:
 
 Design constraints, in order:
 
-1. **Near-zero cost when disabled.**  ``span()`` on a disabled tracer is
-   one attribute read and returns a shared no-op context manager — no
-   allocation, no clock read, no lock.
+1. **Near-zero cost when disabled, and still visible to a profiler.**
+   ``span()`` always opens a ``jax.profiler.TraceAnnotation`` of the same
+   name and args, so whatever profiler is attached to the process (the
+   chip benchmark's traced slice, ``telemetry.trace``, an operator's
+   ``jax.profiler.start_server``) shows the program's phases on the host
+   thread lines of the same ``.xplane.pb`` as the device ops, on one
+   clock, with nothing to enable first.  With no profiler running an
+   annotation is an atomic flag read (~0.5 us a site).  ``enabled``
+   decides only whether :class:`SpanRecord` rows are kept: a disabled
+   tracer reads no clock, takes no lock and keeps nothing.
 2. **Dispatch-time by default.**  JAX calls return at *dispatch*; a span
    measures host-side wall time unless the tracer was built with
    ``synced=True``, which blocks on a device barrier at both edges (the
    calibration mode) and notes each barrier through the owning
    ``CompiledProgramRegistry`` as a sanctioned host sync.
-3. **Single-source names.**  Every span name is a :class:`SpanName`
+3. **One request, one id.**  Every span that works for one serving
+   request carries ``rid=<request id>`` in its args.  Children are found
+   by nesting (same ``tid``, interval inside the parent's, ``depth`` one
+   greater).  A wait that begins on one thread or tick and ends on
+   another (queue, first token) cannot be a ``with`` block: its owner
+   notes the start and calls :meth:`Tracer.record` when it ends, and the
+   row lands on the synthetic :data:`WAIT_THREAD` line, apart from every
+   real thread's phases.
+4. **Single-source names.**  Every span name is a :class:`SpanName`
    constant (the ``EventKind`` pattern); dslint's
    ``unregistered-telemetry-name`` rule checks emit sites statically and
    :meth:`Tracer.span` validates at runtime, so the inventory in
@@ -35,9 +50,18 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from ..utils.lock_watch import LockName, TrackedLock
 
-__all__ = ["SpanName", "SPAN_NAMES", "SpanRecord", "Tracer"]
+__all__ = ["SpanName", "SPAN_NAMES", "SpanRecord", "Tracer", "WAIT_THREAD"]
+
+#: the synthetic thread that recorded waits (:meth:`Tracer.record`) land on.
+#: A wait is a phase of no thread: consumers that ask what a thread was
+#: doing (idle-gap naming, nesting) skip rows on this line; consumers that
+#: read a wait's duration find it by name.
+WAIT_THREAD = "waits"
+_WAIT_TID = 1
 
 
 class SpanName:
@@ -67,6 +91,12 @@ class SpanName:
     TRAIN_OPTIMIZER = "train.optimizer"
     #: a sanctioned device→host pull on the step path (label in args)
     TRAIN_HOST_SYNC = "train.host_sync"
+    #: fused path: reshaping the host batch to [gas, ...] and placing it
+    #: on the mesh (device_put)
+    TRAIN_BATCH_PUT = "train.batch_put"
+    #: fused path: the one call of the fused step program (dispatch time;
+    #: a launch that blocks on the device shows here)
+    TRAIN_DISPATCH = "train.dispatch"
     #: engine.save_checkpoint end-to-end (shard writes + manifest)
     CKPT_SAVE = "ckpt.save"
     #: the two-phase commit barrier + marker publish (multi-host protocol)
@@ -79,13 +109,34 @@ class SpanName:
     ELASTIC_ROLLBACK = "elastic.rollback"
     #: one continuous-batching decode tick (all live slots, one token)
     SERVE_TICK = "serve.tick"
+    #: the host blocked on the device for the tick's tokens (the one d2h
+    #: pull per tick; nested in serve.tick)
+    SERVE_PULL = "serve.pull"
+    #: the gateway's per-row bookkeeping after a tick returned: append
+    #: tokens, first-token stamps, finishes and releases (live in args)
+    SERVE_HARVEST = "serve.harvest"
     #: one speculative draft/verify/accept round (nested in serve.tick;
     #: draft_k in args) — all live slots advance 1..draft_k+1 tokens
     SERVE_SPEC = "serve.spec"
+    #: a request's wait in the admission queue: submit -> the instant its
+    #: serve.admit opens (recorded; rid, priority, depth in args)
+    SERVE_QUEUE = "serve.queue"
     #: admission of one request into a free slot (incl. prefill)
     SERVE_ADMIT = "serve.admit"
+    #: allocating the fresh batch-1 cache a prefill fills (bytes in args;
+    #: not entered when a prefix cache is continued)
+    SERVE_CACHE_ALLOC = "serve.cache_alloc"
     #: chunked prefill of a prompt/prefix through the fixed-width programs
+    #: (tokens = real, padded = computed, chunks in args)
     SERVE_PREFILL = "serve.prefill"
+    #: one prefill/extend chunk dispatch (index, pos, program in args)
+    SERVE_PREFILL_CHUNK = "serve.prefill_chunk"
+    #: landing the prefilled cache in its slot: write_slot + bind (and the
+    #: draft's write + pending-token seed under speculation)
+    SERVE_SLOT_WRITE = "serve.slot_write"
+    #: end of admission -> the tick that harvested the request's first
+    #: token (recorded; rid in args)
+    SERVE_FIRST_TOKEN = "serve.first_token"
     #: restoring a tiered session's KV for a follow-up turn (gather or
     #: host rehydrate + remainder prefill)
     SERVE_READMIT = "serve.readmit"
@@ -138,20 +189,18 @@ class SpanRecord:
     depth: int       # nesting depth within this thread (0 = top level)
     args: Optional[Dict[str, Any]] = None
 
-
-class _NoopSpan:
-    """Shared do-nothing context manager for disabled tracers."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+    @property
+    def wait(self) -> bool:
+        """A recorded wait (``Tracer.record``), not a phase of a thread."""
+        return self.thread == WAIT_THREAD
 
 
-_NOOP = _NoopSpan()
+def _check_registered(name: str) -> None:
+    if name not in SPAN_NAMES:
+        raise ValueError(
+            f"span name '{name}' is not registered in SpanName "
+            "(telemetry/spans.py) — register it (and its "
+            "docs/telemetry.md row) first")
 
 
 def _device_barrier() -> None:
@@ -165,31 +214,37 @@ def _device_barrier() -> None:
 
 
 class _Span:
-    """A live span; created only when the tracer is enabled."""
+    """A live span of an enabled tracer: the profiler annotation around
+    the recorded interval."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth", "_note")
 
-    def __init__(self, tracer: "Tracer", name: str,
-                 args: Optional[Dict[str, Any]]):
+    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
         self._name = name
-        self._args = args
+        self._args = args or None
+        self._note = TraceAnnotation(name, **args)
 
     def __enter__(self) -> "_Span":
         tr = self._tracer
-        self._depth = tr._enter_thread()
         if tr.synced:
             tr._sync()
+        self._depth = tr._enter_thread()
         self._t0 = tr._clock()
+        # last, so that nothing that can raise runs with the TraceMe open
+        self._note.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
-        if tr.synced:
-            tr._sync()
-        dur = tr._clock() - self._t0
         tr._exit_thread()
-        tr._record(self._name, self._t0, dur, self._depth, self._args)
+        try:
+            if tr.synced:
+                tr._sync()
+            dur = tr._clock() - self._t0
+            tr._record(self._name, self._t0, dur, self._depth, self._args)
+        finally:
+            self._note.__exit__(*exc)
         return False
 
 
@@ -197,8 +252,9 @@ class Tracer:
     """Collects spans; thread-safe, bounded, cheap to leave disabled.
 
     Args:
-      enabled: record spans (a disabled tracer's :meth:`span` returns a
-        shared no-op context).
+      enabled: keep :class:`SpanRecord` rows.  A disabled tracer's
+        :meth:`span` is the bare profiler annotation and :meth:`record`
+        keeps nothing.
       capacity: raw records kept for export; past it new records are
         DROPPED (counted in :attr:`dropped`) — the per-name aggregates keep
         counting, so breakdown logs and inventories stay exact while the
@@ -231,15 +287,24 @@ class Tracer:
     def span(self, name: str, **args: Any):
         """Context manager timing one phase.  ``name`` must be a
         registered :class:`SpanName`; extra kwargs land in the exported
-        trace event's ``args``."""
+        trace event's ``args`` and, as stats, on the annotation an
+        attached profiler sees."""
+        _check_registered(name)
         if not self.enabled:
-            return _NOOP
-        if name not in SPAN_NAMES:
-            raise ValueError(
-                f"span name '{name}' is not registered in SpanName "
-                "(telemetry/spans.py) — register it (and its "
-                "docs/telemetry.md row) first")
-        return _Span(self, name, args or None)
+            return TraceAnnotation(name, **args)
+        return _Span(self, name, args)
+
+    def record(self, name: str, t0: float, dur: float, **args: Any) -> None:
+        """Keep a finished span whose start was noted earlier: ``t0`` on
+        this tracer's clock (``time.monotonic``), ``dur`` seconds.  For
+        waits that begin on one thread or tick and end on another.  The
+        row lands on the synthetic :data:`WAIT_THREAD` line at depth 0, not
+        on the caller's: a wait overlaps the calling thread's phases and
+        other waits, and is nobody's child.  Nothing is kept, and no
+        profiler annotation written, when disabled."""
+        _check_registered(name)
+        if self.enabled:
+            self._record(name, t0, dur, 0, args or None, wait=True)
 
     def _enter_thread(self) -> int:
         depth = getattr(self._local, "depth", 0)
@@ -255,8 +320,12 @@ class Tracer:
             self._sync_registry.note_host_sync("span.sync")
 
     def _record(self, name: str, t0: float, dur: float, depth: int,
-                args: Optional[Dict[str, Any]]) -> None:
-        th = threading.current_thread()
+                args: Optional[Dict[str, Any]], wait: bool = False) -> None:
+        if wait:
+            tid, thread = _WAIT_TID, WAIT_THREAD
+        else:
+            th = threading.current_thread()
+            tid, thread = th.ident or 0, th.name
         with self._lock:
             count, total = self._agg.get(name, (0, 0.0))
             self._agg[name] = (count + 1, total + dur)
@@ -264,8 +333,8 @@ class Tracer:
                 self.dropped += 1
                 return
             self._records.append(SpanRecord(
-                name=name, t0=t0, dur=dur, tid=th.ident or 0,
-                thread=th.name, depth=depth, args=args))
+                name=name, t0=t0, dur=dur, tid=tid, thread=thread,
+                depth=depth, args=args))
 
     # ------------------------------------------------------------- queries
     def spans(self) -> List[SpanRecord]:

@@ -1,0 +1,158 @@
+"""The serving cycle seen from inside: the spans one request leaves on the
+gateway's tracer, keyed by its id, its waits apart from the scheduler's
+phases, and ``probe_logits``, the public way to the slot path's logits."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu
+from deepspeed_tpu.models import gpt
+from deepspeed_tpu.telemetry.spans import WAIT_THREAD, Tracer
+
+CFG = gpt.GPTConfig(vocab_size=256, max_seq_len=128, n_layer=2, n_head=4,
+                    d_model=64, dtype=jnp.float32, vocab_round_to=128)
+CHUNK = 8
+SERVING = {"slots": 2, "max_len": 64, "prefill_chunk": CHUNK,
+           "queue_capacity": 16}
+
+
+@pytest.fixture(scope="module")
+def engine():
+    params = gpt.init(CFG, jax.random.PRNGKey(0))
+    return deepspeed_tpu.init_inference(model=(CFG, params),
+                                        config={"dtype": "float32"})
+
+
+def _inside(child, parent):
+    return (child.tid == parent.tid and child.t0 >= parent.t0
+            and child.t0 + child.dur <= parent.t0 + parent.dur
+            and child.depth == parent.depth + 1)
+
+
+def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
+    tracer = Tracer(name="serving")
+    gw = engine.serve(config=SERVING, tracer=tracer)
+    prompt = np.arange(1, CHUNK + 2, dtype=np.int32)    # chunk + 1 tokens
+    h = gw.submit(prompt, max_new_tokens=3)
+    assert h.result(timeout=120).shape == (3,)
+    snap = gw.snapshot()
+    gw.shutdown()
+    spans = tracer.spans()
+    by = lambda name: [s for s in spans if s.name == name]
+    one = lambda name: by(name)[0] if len(by(name)) == 1 else pytest.fail(
+        f"{len(by(name))} {name} spans")
+
+    queue, admit, first = (one("serve.queue"), one("serve.admit"),
+                           one("serve.first_token"))
+    assert queue.args == {"rid": h.request_id, "priority": 0, "depth": 0}
+    assert admit.args["rid"] == first.args["rid"] == h.request_id
+    # the request's wait for its first token, piece by piece: the three
+    # spans leave out only the journal line between t_admit and the end of
+    # the admission span
+    assert queue.t0 == h.t_submit
+    assert queue.t0 + queue.dur == pytest.approx(admit.t0, abs=1e-3)
+    assert first.t0 == h.t_admit
+    assert first.t0 + first.dur == h.t_first_token
+    assert queue.dur + admit.dur + first.dur == pytest.approx(
+        h.t_first_token - h.t_submit, abs=1e-3)
+
+    # admission's children, where the work happens
+    prefill, alloc, write = (one("serve.prefill"), one("serve.cache_alloc"),
+                             one("serve.slot_write"))
+    assert _inside(prefill, admit) and _inside(write, admit)
+    assert _inside(alloc, prefill)
+    assert alloc.args["bytes"] == 2 * CFG.n_layer * 64 * CFG.d_model * 4
+    assert prefill.args == {"tokens": CHUNK + 1, "start": 0, "chunk": CHUNK,
+                            "padded": 2 * CHUNK, "chunks": 2}
+    chunks = sorted(by("serve.prefill_chunk"), key=lambda s: s.t0)
+    assert [c.args for c in chunks] == [
+        {"index": 0, "pos": 0, "program": "prefill"},
+        {"index": 1, "pos": CHUNK, "program": "extend"}]
+    assert all(_inside(c, prefill) for c in chunks)
+    assert write.args == {"slot": admit.args["slot"]}
+
+    # every tick: the pull inside it, the harvest after it
+    ticks, pulls, harvests = (by("serve.tick"), by("serve.pull"),
+                              by("serve.harvest"))
+    assert len(ticks) == len(pulls) == len(harvests) == snap["ticks"] == 3
+    for tick, pull, harvest in zip(ticks, pulls, harvests):
+        assert _inside(pull, tick)
+        assert harvest.t0 >= tick.t0 + tick.dur and harvest.args == {
+            "live": 1}
+    assert first.t0 + first.dur <= harvests[0].t0 + harvests[0].dur
+
+    # the two waits sit on the synthetic line, the phases on the scheduler's
+    assert queue.wait and first.wait and not admit.wait
+    assert queue.thread == first.thread == WAIT_THREAD != admit.thread
+
+
+def test_requests_keep_their_own_ids_and_a_continued_prefix_allocates_nothing(
+        engine):
+    """Two requests over one pooled prefix: each admission carries its own
+    ``rid``; the first builds the prefix (a fresh cache) and continues it,
+    the second only continues it, so it has no ``serve.cache_alloc``."""
+    tracer = Tracer(name="serving")
+    gw = engine.serve(config=SERVING, tracer=tracer)
+    rng = np.random.default_rng(3)
+    prefix = rng.integers(1, 256, (CHUNK,)).astype(np.int32)
+    handles = []
+    for n in (3, 5):
+        tail = rng.integers(1, 256, (n,)).astype(np.int32)
+        handles.append(gw.submit(np.concatenate([prefix, tail]),
+                                 max_new_tokens=2, prefix_len=CHUNK))
+        handles[-1].result(timeout=120)
+    gw.shutdown()
+    spans = tracer.spans()
+    for name in ("serve.queue", "serve.admit", "serve.first_token"):
+        assert [s.args["rid"] for s in spans if s.name == name] == [
+            h.request_id for h in handles]
+    admits = [s for s in spans if s.name == "serve.admit"]
+    allocs = [s for s in spans if s.name == "serve.cache_alloc"]
+    assert len(allocs) == 1 and allocs[0].t0 < admits[0].t0 + admits[0].dur
+    prefills = [s.args for s in spans if s.name == "serve.prefill"]
+    assert [(p["tokens"], p["start"], p["padded"]) for p in prefills] == [
+        (CHUNK, 0, CHUNK), (3, CHUNK, CHUNK), (5, CHUNK, CHUNK)]
+    assert sum(p["chunks"] for p in prefills) == 3
+
+
+def test_a_gateway_without_a_tracer_serves_and_keeps_no_record(engine):
+    gw = engine.serve(config=SERVING)
+    h = gw.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+    assert len(h.result(timeout=120)) == 2
+    gw.shutdown()
+    assert not gw.tracer.enabled
+    assert gw.tracer.spans() == [] and gw.tracer.aggregates() == {}
+
+
+def test_probe_logits_matches_the_full_forward_pass(engine):
+    """Chunked prefill and greedy ticks through the gateway's own slot
+    path, against ``gpt.apply`` on prompt + reply at the same positions."""
+    gw = engine.serve(config=SERVING, autostart=False)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 256, (n,)).astype(np.int32)
+               for n in (5, CHUNK + 1)]
+    ticks = 3
+    replies, logits = gw.probe_logits(prompts, ticks)
+    assert [len(r) for r in replies] == [ticks, ticks]
+    for p, reply, got in zip(prompts, replies, logits):
+        assert got.shape == (1 + ticks, CFG.padded_vocab)
+        assert got.dtype == np.float32
+        full = np.concatenate([p, np.asarray(reply, np.int32)])[None]
+        ref = np.asarray(gpt.apply(engine.params, jnp.asarray(full), CFG))[0]
+        np.testing.assert_allclose(got, ref[len(p) - 1:len(p) + ticks],
+                                   atol=2e-4, rtol=2e-4)
+        # greedy: each reply token is the argmax of the logits before it
+        assert reply == [int(np.argmax(l[:CFG.vocab_size]))
+                         for l in got[:ticks]]
+    # the slots are free again, and it asks for a stopped scheduler
+    assert not np.asarray(gw._batcher.active).any()
+    with pytest.raises(ValueError, match="3 prompts for 2 slots"):
+        gw.probe_logits(prompts + prompts[:1], 1)
+    gw.start()
+    with pytest.raises(RuntimeError, match="shut the gateway down first"):
+        gw.probe_logits(prompts, 1)
+    gw.shutdown()
+    assert gw.probe_logits(prompts[:1], 1)[0] == [replies[0][:1]]

@@ -1,13 +1,16 @@
-"""Tracer units: nesting, disable cost, thread safety, capacity, synced
-calibration mode, and name validation."""
+"""Tracer units: nesting, the write-through to the profiler, recorded
+waits, thread safety, capacity, synced calibration mode, and name
+validation."""
 
+import glob
+import os
 import threading
 import time
 
 import pytest
 
-from deepspeed_tpu.telemetry.spans import (SPAN_NAMES, SpanName, Tracer,
-                                           _NOOP)
+from deepspeed_tpu.telemetry.spans import (SPAN_NAMES, WAIT_THREAD, SpanName,
+                                           Tracer)
 
 
 def test_span_records_name_duration_and_args():
@@ -39,15 +42,119 @@ def test_nesting_depth_tracked_per_thread():
         ["train.host_sync", "train.fwd", "train.step"]
 
 
-def test_disabled_tracer_returns_shared_noop_and_records_nothing():
+def test_disabled_tracer_keeps_nothing_and_hands_back_the_bare_annotation():
+    """The contract since the tracer writes through to the profiler: a
+    disabled tracer keeps no record, reads no clock and takes no lock; what
+    it hands back is the bare profiler annotation, not a tracer object."""
+    from jax.profiler import TraceAnnotation
     tr = Tracer(enabled=False)
-    ctx = tr.span(SpanName.TRAIN_FWD)
-    assert ctx is _NOOP                      # no allocation per call
-    assert ctx is tr.span("not-even-a-registered-name")  # no validation cost
+    tr._clock = tr._lock = None              # touching either would raise
+    ctx = tr.span(SpanName.TRAIN_FWD, step=1)
+    assert type(ctx) is TraceAnnotation
     with ctx:
         pass
-    assert tr.spans() == []
-    assert tr.aggregates() == {}
+    assert tr._records == [] and tr._agg == {}
+    # names are checked whether or not records are kept: the annotation
+    # reaches any attached profiler under that name
+    with pytest.raises(ValueError, match="not registered in SpanName"):
+        tr.span("not-even-a-registered-name")
+
+
+def _host_events(logdir):
+    """``{name: (line, stats)}`` of the host plane of the one trace under
+    ``logdir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out[e.name] = (line.name, dict(e.stats))
+    return out
+
+
+def test_spans_reach_an_attached_profiler_enabled_or_not(tmp_path):
+    import jax
+    on, off = Tracer(), Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with on.span(SpanName.SERVE_TICK):
+            with on.span(SpanName.SERVE_PULL):
+                time.sleep(0.002)
+        with off.span(SpanName.TRAIN_DISPATCH, step=7):
+            time.sleep(0.002)
+        off.record(SpanName.SERVE_QUEUE, 1.0, 2.0, rid="req-1")
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    # both tracers' spans sit on a host thread line of the profiler's own
+    # trace, under their bare names, keywords as stats
+    assert {"serve.tick", "serve.pull", "train.dispatch"} <= set(events)
+    assert events["train.dispatch"][1] == {"step": 7}
+    assert events["serve.tick"][0] == events["train.dispatch"][0]
+    assert "serve.queue" not in events       # a record() is the tracer's only
+    assert [r.name for r in on.spans()] == ["serve.pull", "serve.tick"]
+    assert off.spans() == []
+
+
+def test_record_keeps_a_span_whose_start_was_noted_earlier():
+    tr = Tracer()
+    with tr.span(SpanName.SERVE_ADMIT, rid="req-9"):
+        tr.record(SpanName.SERVE_QUEUE, 12.5, 0.25, rid="req-9", depth=3)
+    queue, admit = tr.spans()
+    assert (queue.name, queue.t0, queue.dur) == ("serve.queue", 12.5, 0.25)
+    assert queue.args == {"rid": "req-9", "depth": 3}
+    # a wait is a phase of no thread: it lands on the synthetic line, apart
+    # from what is open on the caller's, and is nobody's child
+    assert queue.depth == 0 and admit.depth == 0
+    assert queue.wait and queue.thread == WAIT_THREAD
+    assert not admit.wait and admit.tid != queue.tid
+    assert tr.aggregates()["serve.queue"] == {"count": 1, "total_s": 0.25}
+    with pytest.raises(ValueError, match="not registered in SpanName"):
+        tr.record("serve.made_up", 0.0, 1.0)
+    off = Tracer(enabled=False)
+    off.record(SpanName.SERVE_QUEUE, 12.5, 0.25, rid="req-9")
+    assert off.spans() == [] and off.aggregates() == {}
+    with pytest.raises(ValueError):
+        off.record("serve.made_up", 0.0, 1.0)
+
+
+def test_a_span_whose_bookkeeping_raises_leaves_no_annotation_open():
+    class Note:
+        log = []
+
+        def __enter__(self):
+            self.log.append("enter")
+
+        def __exit__(self, *exc):
+            self.log.append("exit")
+
+    class Registry:
+        fail = True
+
+        def note_host_sync(self, label):
+            if self.fail:
+                raise RuntimeError("barrier lost")
+
+    reg = Registry()
+    tr = Tracer(synced=True, sync_registry=reg)
+    sp = tr.span(SpanName.TRAIN_STEP)
+    sp._note = Note()
+    with pytest.raises(RuntimeError):       # the entry barrier raises:
+        sp.__enter__()                      # the annotation never opened
+    assert Note.log == []
+    reg.fail = False
+    with pytest.raises(RuntimeError):
+        with sp:
+            reg.fail = True                 # the exit barrier raises:
+    assert Note.log == ["enter", "exit"]    # it is closed all the same
+    reg.fail = False
+    with tr.span(SpanName.TRAIN_FWD):
+        pass
+    assert tr.spans()[-1].depth == 0        # and no depth leaked either time
 
 
 def test_unregistered_name_raises_when_enabled():

@@ -123,7 +123,11 @@ def test_serving_session_emits_spans_with_zero_recompiles(tmp_path):
     gw.shutdown()
     assert snap["recompiles"] == 0
     assert set(tracer.span_inventory()) == {
-        SpanName.SERVE_ADMIT, SpanName.SERVE_PREFILL, SpanName.SERVE_TICK}
+        SpanName.SERVE_QUEUE, SpanName.SERVE_ADMIT,
+        SpanName.SERVE_CACHE_ALLOC, SpanName.SERVE_PREFILL,
+        SpanName.SERVE_PREFILL_CHUNK, SpanName.SERVE_SLOT_WRITE,
+        SpanName.SERVE_TICK, SpanName.SERVE_PULL, SpanName.SERVE_HARVEST,
+        SpanName.SERVE_FIRST_TOKEN}
     # tick spans: one per decode tick; admits: one per request
     agg = tracer.aggregates()
     assert agg["serve.admit"]["count"] == 6

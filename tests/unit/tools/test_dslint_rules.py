@@ -528,6 +528,16 @@ def test_telemetry_name_fires_on_unknown_spanname_attr():
     assert rules_of(findings) == ["unregistered-telemetry-name"]
 
 
+def test_telemetry_name_checks_recorded_spans_like_opened_ones():
+    findings = tlint("""
+        tracer.record("serve.mystery", t0, dur, rid=rid)
+        self.tracer.record(SpanName.SERVE_MYSTERY, t0, dur)
+        tracer.record(SpanName.SERVE_TICK, t0, dur)
+        tuner.record(candidate, value)   # another record(): uninspected
+    """)
+    assert rules_of(findings) == ["unregistered-telemetry-name"] * 2
+
+
 def test_telemetry_name_fires_on_unregistered_metric():
     findings = tlint("""
         reg.gauge("train.bogus").set(1.0)

@@ -8,7 +8,8 @@ the telemetry single-source registries —
 the docs tables (``docs/telemetry.md``), the span-inventory gate
 (``BENCH_TELEMETRY.json``), and the offline report can't account for.
 
-Checked call shapes: ``<obj>.span(<name>, ...)`` and
+Checked call shapes: ``<obj>.span(<name>, ...)``,
+``<obj>.record(<name>, ...)`` (a finished span noted earlier) and
 ``<obj>.counter/gauge/histogram(<name>, ...)``, where ``<name>`` is a
 string literal (must be a registered value) or a ``SpanName.X`` /
 ``MetricName.X`` attribute (``X`` must be a registered name).
@@ -22,7 +23,7 @@ from typing import Iterable
 
 from ..core import FileContext, Finding, Rule
 
-SPAN_METHODS = {"span"}
+SPAN_METHODS = {"span", "record"}
 METRIC_METHODS = {"counter", "gauge", "histogram"}
 
 
