@@ -14,9 +14,19 @@ injected family (GPT-2 learned positions, OPT relu+offset, BLOOM alibi,
 NeoX rotary + parallel residual, untied heads) decodes through this one
 implementation.
 
-Cache layout [L, B, S_max, H, D]: static shapes (XLA requirement), masked by
+Cache layout [L, B, S_max, H*D]: static shapes (XLA requirement), masked by
 the current length; decode attention reads the cache tiled over S_max with
-positions beyond ``pos`` masked.
+positions beyond ``pos`` masked.  A token's heads are folded into ONE row
+because the layout is the point: the TPU lays a bf16 array whose last
+dimension is 64 (half a lane row) out with the long token dimension on the
+lanes, and a [.., S_max, H, D] pool can then take no token's K/V in place —
+the compiler re-lays a whole layer around every write and every kernel
+call (PERF.md, PR 25).  With H*D last the stored order IS row-major: a
+token's K (all heads) is one row, written in place at ``[layer, slot,
+pos]``, and the decode kernel's ``[block_k, H*D]`` blocks are read straight
+out of the pool.  The layer scan carries the stacked banks (never slices a
+layer out), and a caller that donates the cache (the batcher's tick) gets
+it updated in place.
 """
 
 from __future__ import annotations
@@ -40,11 +50,11 @@ PyTree = Any
 class KVCache:
     """``k_scale``/``v_scale`` are ``None`` for a full-precision cache; for
     an int8 cache (``kv_cache_dtype: "int8"``) k/v hold codes and the
-    scales are per-vector fp32 [L, B, S_max, H, 1] — half the cache HBM,
+    scales are per-vector fp32 [L, B, S_max, H] — half the cache HBM,
     dequantized inside the decode kernel's VMEM stream."""
 
-    k: jnp.ndarray        # [L, B, S_max, H, D]
-    v: jnp.ndarray        # [L, B, S_max, H, D]
+    k: jnp.ndarray        # [L, B, S_max, H*D]
+    v: jnp.ndarray        # [L, B, S_max, H*D]
     length: jnp.ndarray   # [] int32 — tokens already cached
     k_scale: Any = None
     v_scale: Any = None
@@ -75,21 +85,24 @@ def init_cache(config: gpt.GPTConfig, batch: int, max_len: int,
     ``jnp.int8`` → int8 codes + per-vector fp32 scales (beyond-reference:
     halves decode HBM traffic and doubles the context/batch a chip's
     cache budget holds)."""
-    shape = (config.n_layer, batch, max_len, config.n_head, config.head_dim)
+    shape = (config.n_layer, batch, max_len, config.n_head * config.head_dim)
     if kv_dtype in ("int8", jnp.int8):
+        scales = shape[:-1] + (config.n_head,)
         return KVCache(k=jnp.zeros(shape, jnp.int8),
                        v=jnp.zeros(shape, jnp.int8),
                        length=jnp.zeros((), jnp.int32),
-                       k_scale=jnp.zeros(shape[:-1] + (1,), jnp.float32),
-                       v_scale=jnp.zeros(shape[:-1] + (1,), jnp.float32))
+                       k_scale=jnp.zeros(scales, jnp.float32),
+                       v_scale=jnp.zeros(scales, jnp.float32))
     return KVCache(k=jnp.zeros(shape, config.dtype),
                    v=jnp.zeros(shape, config.dtype),
                    length=jnp.zeros((), jnp.int32))
 
 
 def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
-                      window=None, k_scale=None, v_scale=None):
-    """q: [B, S_q, H, D] attending to cache[:, :pos+S_q].
+                      window=None, k_scale=None, v_scale=None, layer=None):
+    """q: [B, S_q, H, D] attending to cache[:, :pos+S_q]; with ``layer``
+    the cache operands are the stacked [L, B, S_max, H*D] pool and the
+    decode kernel reads that layer in place.
 
     ``pos`` is the number of tokens already in the cache before this call;
     query i sits at absolute position pos+i and sees cache slots ≤ pos+i.
@@ -117,7 +130,7 @@ def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
         scale = 1.0 / math.sqrt(config.head_dim)
     return cached_attention(q, cache_k, cache_v, pos, sm_scale=scale,
                             k_scale=k_scale, v_scale=v_scale,
-                            window=window, slopes=slopes)
+                            window=window, slopes=slopes, layer=layer)
 
 
 def _block_tail(x, attn, p, config: gpt.GPTConfig):
@@ -132,20 +145,28 @@ def _layer_scan(x, params, cache: KVCache, config: gpt.GPTConfig, positions,
                 write, attn):
     """The one layer-stack scan every cache-filling path shares.
 
-    ``write(buf, val)`` places this step's K/V (or scale) column(s) into
-    the cache buffer; int8 caches quantize per vector first and write
-    codes + scales through the same ``write``.  ``attn(q, k, v, new_ck,
-    new_cv, ksc, vsc, idx)`` computes the sublayer's attention (prefill
-    reads the fresh unpadded k/v; extend/decode read back through the
-    updated cache).  Returns (hidden states, updated KVCache with the
-    caller-provided ``length``-less fields filled in).
+    The stacked banks ride the scan's CARRY, never its ``xs``/``ys``: no
+    layer is sliced out of the pool and none is written back into a
+    second stack.  ``write(bank, layer, val)`` places this step's K/V (or
+    scale) column(s) into layer ``layer`` of the stacked bank, in place;
+    int8 caches quantize per vector first and write codes + scales through
+    the same ``write``.  ``attn(q, k, v, ck, cv, ksc, vsc, layer)``
+    computes the sublayer's attention (prefill reads the fresh unpadded
+    k/v; extend/decode read layer ``layer`` of the updated stacks ``ck``/
+    ``cv`` where it lies).  Returns (hidden states, updated KVCache with
+    the caller-provided ``length``-less fields filled in).
     """
     int8 = cache.int8
     if int8:
         from ..ops.pallas.decode_attention import quantize_kv
 
-    def layer(x, xs):
-        p, ck, cv, ksc, vsc, idx = xs
+    def fold(t):
+        """[B, S, H, *] → [B, S, H * *]: a token's heads as one row."""
+        return t.reshape(t.shape[:2] + (-1,))
+
+    def layer(carry, xs):
+        x, ck, cv, ksc, vsc = carry
+        p, idx = xs
         q, k, v = gpt.qkv_proj(x, p, config, positions=positions)
         # the scopes name, in a profiler's trace, the two places a tick
         # touches the slot cache
@@ -153,26 +174,21 @@ def _layer_scan(x, params, cache: KVCache, config: gpt.GPTConfig, positions,
             if int8:
                 kq, ks = quantize_kv(k)
                 vq, vs = quantize_kv(v)
-                new_ck, new_cv = write(ck, kq), write(cv, vq)
-                ksc, vsc = write(ksc, ks), write(vsc, vs)
+                ck, cv = write(ck, idx, fold(kq)), write(cv, idx, fold(vq))
+                ksc = write(ksc, idx, fold(ks))
+                vsc = write(vsc, idx, fold(vs))
             else:
-                new_ck = write(ck, k.astype(ck.dtype))
-                new_cv = write(cv, v.astype(cv.dtype))
+                ck = write(ck, idx, fold(k.astype(ck.dtype)))
+                cv = write(cv, idx, fold(v.astype(cv.dtype)))
         with jax.named_scope("cache_read"):
-            a = attn(q, k, v, new_ck, new_cv,
-                     ksc if int8 else None, vsc if int8 else None, idx)
-        return _block_tail(x, a, p, config), (new_ck, new_cv, ksc, vsc)
+            a = attn(q, k, v, ck, cv, ksc, vsc, idx)
+        return (_block_tail(x, a, p, config), ck, cv, ksc, vsc), None
 
-    zero = jnp.zeros((config.n_layer,), jnp.int8)  # placeholder, not written
-    x, (new_k, new_v, new_ksc, new_vsc) = lax.scan(
-        layer, x, (params["blocks"], cache.k, cache.v,
-                   cache.k_scale if int8 else zero,
-                   cache.v_scale if int8 else zero,
-                   jnp.arange(config.n_layer)))
-    return x, dataclasses.replace(
-        cache, k=new_k, v=new_v,
-        k_scale=new_ksc if int8 else None,
-        v_scale=new_vsc if int8 else None)
+    (x, new_k, new_v, new_ksc, new_vsc), _ = lax.scan(
+        layer, (x, cache.k, cache.v, cache.k_scale, cache.v_scale),
+        (params["blocks"], jnp.arange(config.n_layer)))
+    return x, dataclasses.replace(cache, k=new_k, v=new_v,
+                                  k_scale=new_ksc, v_scale=new_vsc)
 
 
 def prefill(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
@@ -187,10 +203,10 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
     positions = jnp.arange(S)
     x = gpt.embed(params, tokens, config, positions=positions)
 
-    def write(buf, val):
-        return lax.dynamic_update_slice(buf, val, (0, 0, 0, 0))
+    def write(bank, layer, val):
+        return lax.dynamic_update_slice(bank, val[None], (layer, 0, 0, 0))
 
-    def attn(q, k, v, new_ck, new_cv, ksc, vsc, idx):
+    def attn(q, k, v, ck, cv, ksc, vsc, idx):
         # prefill attention runs on the unpadded k/v (training flash path);
         # only decode reads back through the padded cache
         return gpt._attention(q, k, v, config,
@@ -243,21 +259,22 @@ def extend(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
         rows = jnp.arange(B)[:, None]
         cols = positions
 
-        def write(buf, val):
-            return buf.at[rows, cols].set(val)
+        def write(bank, layer, val):
+            return bank.at[layer, rows, cols].set(val)
     else:
         positions = pos0 + jnp.arange(Sc)   # [S_c], shared across rows
 
-        def write(buf, val):
-            return lax.dynamic_update_slice(buf, val, (0, pos0, 0, 0))
+        def write(bank, layer, val):
+            return lax.dynamic_update_slice(bank, val[None],
+                                            (layer, 0, pos0, 0))
 
     x = gpt.embed(params, tokens, config, positions=positions)
 
-    def attn(q, k, v, new_ck, new_cv, ksc, vsc, idx):
+    def attn(q, k, v, ck, cv, ksc, vsc, idx):
         return _cached_attention(
-            q, new_ck, new_cv, pos0, config,
+            q, ck, cv, pos0, config,
             window=gpt.layer_window(config, idx, cache.max_len),
-            k_scale=ksc, v_scale=vsc)
+            k_scale=ksc, v_scale=vsc, layer=idx)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn)
     logits = gpt.lm_logits(params, x, config)
@@ -291,7 +308,7 @@ def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
             f"cache's {cache.max_len}")
 
     def ins(dst, s):
-        return lax.dynamic_update_slice(dst, s, (0, row, 0, 0, 0))
+        return lax.dynamic_update_slice(dst, s, (0, row, 0, 0))
 
     return dataclasses.replace(
         cache, k=ins(cache.k, src.k), v=ins(cache.v, src.v),
@@ -305,7 +322,7 @@ def reset_slot(cache: KVCache, row) -> KVCache:
     K/V never bleeds into the next tenant, even through a masked read."""
     def z(buf):
         blank = jnp.zeros((buf.shape[0], 1) + buf.shape[2:], buf.dtype)
-        return lax.dynamic_update_slice(buf, blank, (0, row, 0, 0, 0))
+        return lax.dynamic_update_slice(buf, blank, (0, row, 0, 0))
 
     return dataclasses.replace(
         cache, k=z(cache.k), v=z(cache.v),
@@ -318,7 +335,7 @@ def read_slot(cache: KVCache, row, length=None) -> KVCache:
     to a session).  ``length`` is the row's true frontier (the multi-slot
     ``cache.length`` only tracks the max)."""
     def rd(buf):
-        return lax.dynamic_slice(buf, (0, row, 0, 0, 0),
+        return lax.dynamic_slice(buf, (0, row, 0, 0),
                                  (buf.shape[0], 1) + buf.shape[2:])
 
     return KVCache(
@@ -344,17 +361,18 @@ def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
     positions = pos[:, None] if ragged else pos[None]
     x = gpt.embed(params, token[:, None], config, positions=positions)
 
-    def write(buf, val):
-        """One new [B, 1, H, *] column at pos (shared or per-row)."""
+    def write(bank, layer, val):
+        """One new [B, 1, H * *] row per slot at [layer, :, pos] (pos
+        shared or per-row)."""
         if ragged:
-            return buf.at[jnp.arange(B), pos].set(val[:, 0])
-        return lax.dynamic_update_slice(buf, val, (0, pos, 0, 0))
+            return bank.at[layer, jnp.arange(B), pos].set(val[:, 0])
+        return lax.dynamic_update_slice(bank, val[None], (layer, 0, pos, 0))
 
-    def attn(q, k, v, new_ck, new_cv, ksc, vsc, idx):
+    def attn(q, k, v, ck, cv, ksc, vsc, idx):
         return _cached_attention(
-            q, new_ck, new_cv, pos, config,
+            q, ck, cv, pos, config,
             window=gpt.layer_window(config, idx, cache.max_len),
-            k_scale=ksc, v_scale=vsc)
+            k_scale=ksc, v_scale=vsc, layer=idx)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn)
     logits = gpt.lm_logits(params, x[:, 0], config)

@@ -8,6 +8,17 @@ cache.  The Pallas kernel streams cache blocks through VMEM with the
 online-softmax recurrence and skips blocks entirely beyond ``pos`` — the
 decode step's HBM traffic is the live cache prefix, not S_max.
 
+The decode kernel reads the slot pool WHERE IT LIES.  ``gpt_inference``
+stores it ``[L, B, S_max, H*D]`` — a token's heads folded into one row, so
+the stored order is row-major on the TPU (a last dimension of 64 would put
+the tokens on the lanes instead) — and the kernel's blocks are
+``[block_k, H*D]`` tiles of exactly that array: the layer index rides the
+scalar prefetch beside ``pos``, the grid is ``(B, S_max/block_k)`` with all
+heads of a slot in one step, and nothing is sliced, transposed or copied to
+feed it.  The chunk kernel (``extend``: admission, speculative verify)
+still takes one head a step from a ``[B*H, S_max, D]`` view of one layer: a
+64-wide head is half a lane row and cannot be a block of the folded row.
+
 Int8 cache variant (beyond the reference): k/v arrive as int8 codes with
 per-vector fp32 scales and are dequantized IN VMEM after the block load,
 so the HBM stream — the decode bottleneck — ships half the bytes.  Decode
@@ -98,22 +109,37 @@ def _unpack_rest(rest, quantized, windowed, alibi):
             vscale_ref, o_ref, acc_ref, m_ref, l_ref)
 
 
-def _decode_kernel(pos_ref, *rest, sm_scale, block_k, H, quantized,
-                   windowed, alibi):
-    """One online-softmax decode kernel serving every cache layout: with
-    ``quantized`` the k/v blocks arrive as int8 codes plus per-vector fp32
-    scale columns (two extra refs) and dequantize in VMEM — half the HBM
-    bytes on the memory-bound decode path.  ``windowed`` bands visibility
-    to the trailing ``window`` slots (SMEM scalar — it may alternate
-    per layer) and skips blocks wholly below the band; ``alibi`` adds the
-    per-head ``-slope·dist`` bias from an SMEM slope table."""
+def _decode_kernel(pos_ref, layer_ref, *rest, sm_scale, block_k, H, D,
+                   quantized, windowed, alibi):
+    """One online-softmax decode kernel serving every cache layout.  A
+    grid step is one slot's block of ``block_k`` cached tokens with ALL
+    its heads, as the pool stores them: k/v refs are ``(block_k, H*D)``,
+    the query one ``(1, H*D)`` row.  The per-head products ride two plain
+    matmuls: the query is spread to ``(H, H*D)`` with head ``h``'s lanes
+    kept in row ``h`` and zeros elsewhere, so ``qx · kᵀ`` is every head's
+    score row at once, and ``p · v`` leaves head ``h``'s output in row
+    ``h``'s own lanes (the other lanes hold cross-head products nobody
+    reads).  Scores and the running max/sum/accumulator are float32.
+
+    With ``quantized`` the k/v blocks arrive as int8 codes (exact in the
+    compute dtype) and the per-vector fp32 scales ``(block_k, H)``
+    multiply the scores and the probabilities in VMEM instead of every
+    element — half the HBM bytes on the memory-bound decode path.
+    ``windowed`` bands visibility to the trailing ``window`` slots (SMEM
+    scalar — it may alternate per layer) and skips blocks wholly below the
+    band; ``alibi`` adds the per-head ``-slope·dist`` bias from a
+    ``(H, 1)`` slope column.  ``layer_ref`` only feeds the index maps."""
     (window_ref, slopes_ref, q_ref, k_ref, v_ref, kscale_ref, vscale_ref,
      o_ref, acc_ref, m_ref, l_ref) = _unpack_rest(rest, quantized,
                                                   windowed, alibi)
-    bh = pl.program_id(0)
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
-    pos = pos_ref[bh // H]  # per-ROW visibility (ragged decode)
+    pos = pos_ref[pl.program_id(0)]  # per-ROW visibility (ragged decode)
+
+    def own():
+        """(H, H*D) mask: lane c of row h belongs to head h."""
+        return jax.lax.broadcasted_iota(jnp.int32, (H, H * D), 1) // D == \
+            jax.lax.broadcasted_iota(jnp.int32, (H, H * D), 0)
 
     @pl.when(ki == 0)
     def _init():
@@ -129,17 +155,18 @@ def _decode_kernel(pos_ref, *rest, sm_scale, block_k, H, quantized,
 
     @pl.when(live)
     def _update():
-        q = q_ref[0].astype(jnp.float32) * sm_scale    # (1, D)
-        ks = k_ref[0].astype(jnp.float32)              # (BK, D)
-        vs = v_ref[0].astype(jnp.float32)
+        q = q_ref[...]                                     # (1, H*D)
+        qx = jnp.where(own(), q.astype(jnp.float32), 0.0).astype(q.dtype)
+        ks = _to_compute(k_ref[...], q.dtype)              # (BK, H*D)
+        vs = _to_compute(v_ref[...], q.dtype)
+        s = jax.lax.dot_general(qx, ks, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale                                   # (H, BK)
         if quantized:
-            ks = ks * kscale_ref[0]
-            vs = vs * vscale_ref[0]
-        s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (1, BK)
+            s = s * kscale_ref[...].T
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if alibi:
-            s = s - slopes_ref[bh % H] * (pos - k_pos).astype(jnp.float32)
+            s = s - slopes_ref[...] * (pos - k_pos).astype(jnp.float32)
         visible = k_pos <= pos
         if windowed:
             visible = jnp.logical_and(visible, k_pos > pos - window_ref[0])
@@ -150,68 +177,82 @@ def _decode_kernel(pos_ref, *rest, sm_scale, block_k, H, quantized,
         alpha = jnp.exp(m_prev - m_new)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if quantized:
+            p = p * vscale_ref[...].T
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, vs, preferred_element_type=jnp.float32)
+            p.astype(vs.dtype), vs, preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o = jnp.where(own(), acc_ref[...] / l_ref[...], 0.0)
+        o_ref[...] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
-def _decode(q3, k3, v3, pos, sm_scale, block_k, H, ks3=None, vs3=None,
+def _to_compute(x, dtype):
+    """A cache block in the query's dtype: the products then run natively
+    (bf16 x bf16 is exact, accumulated in float32; an fp32 cast before the
+    dot would only multiply the MXU passes).  int8 codes go through
+    float32; every code is exact in bf16."""
+    if x.dtype == jnp.int8:
+        x = x.astype(jnp.float32)
+    return x.astype(dtype)
+
+
+def _decode(q, k, v, layer, pos, sm_scale, block_k, H, ks=None, vs=None,
             window=None, slopes=None):
-    """Single scalar-prefetch build for every decode variant: pos (and
-    window, when banded) are available BEFORE the body, so the k/v index
-    maps clamp dead block indices into each row's live range
-    [band start, causal frontier].  Pallas only re-issues a DMA when the
-    mapped block index changes, so decode streams the live prefix — and
-    a banded or short ragged row only ITS band — instead of O(Smax)
-    cache bytes; ``pl.when`` still elides the dead blocks' compute."""
-    BH, _, D = q3.shape
-    Smax = k3.shape[1]
-    B = BH // H
-    quantized = ks3 is not None
+    """Single scalar-prefetch build for every decode variant, reading the
+    stacked pool ``k``/``v`` [L, B, Smax, H*D] where it lies: ``layer``
+    and ``pos`` (and window, when banded) are available BEFORE the body,
+    so the k/v index maps pick the layer and clamp dead block indices into
+    each row's live range [band start, causal frontier].  Pallas only
+    re-issues a DMA when the mapped block index changes, so decode streams
+    the live prefix — and a banded or short ragged row only ITS band —
+    instead of O(Smax) cache bytes; ``pl.when`` still elides the dead
+    blocks' compute.  Grid ``(B, Smax/block_k)``; ``q`` and the result are
+    ``[B, 1, H*D]``; ``ks``/``vs`` [L, B, Smax, H]."""
+    B, _, HD = q.shape
+    Smax = k.shape[2]
+    quantized = ks is not None
     windowed = window is not None
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                               block_k=block_k, H=H, quantized=quantized,
-                               windowed=windowed, alibi=slopes is not None)
+                               block_k=block_k, H=H, D=HD // H,
+                               quantized=quantized, windowed=windowed,
+                               alibi=slopes is not None)
 
-    def kv_idx(bh, ki, pos_ref, *maybe_win):
-        p = pos_ref[bh // H]
+    def kv_idx(b, ki, pos_ref, layer_ref, *maybe_win):
+        p = pos_ref[b]
         lo = jnp.maximum((p - maybe_win[0][0] + 1) // block_k, 0) \
             if windowed else 0
-        return (bh, jnp.clip(ki, lo, p // block_k), 0)
+        return (layer_ref[0], b, jnp.clip(ki, lo, p // block_k), 0)
 
-    kv_spec = pl.BlockSpec((1, block_k, D), kv_idx)
-    scale_spec = pl.BlockSpec((1, block_k, 1), kv_idx)
-    slope_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] \
+    kv_spec = pl.BlockSpec((None, None, block_k, HD), kv_idx)
+    scale_spec = pl.BlockSpec((None, None, block_k, H), kv_idx)
+    row_spec = pl.BlockSpec((None, 1, HD), lambda b, ki, *_: (b, 0, 0))
+    slope_specs = [pl.BlockSpec((H, 1), lambda b, ki, *_: (0, 0))] \
         if slopes is not None else []
-    slope_args = (jnp.asarray(slopes, jnp.float32),) \
+    slope_args = (jnp.asarray(slopes, jnp.float32).reshape(H, 1),) \
         if slopes is not None else ()
-    win_args = (jnp.asarray(window, jnp.int32).reshape(1),) \
-        if windowed else ()
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    prefetch = (pos_arr, jnp.asarray(layer, jnp.int32).reshape(1)) + \
+        ((jnp.asarray(window, jnp.int32).reshape(1),) if windowed else ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1 + len(win_args),  # pos_arr [, window]
-        grid=(BH, Smax // block_k),
-        in_specs=slope_specs + [
-            pl.BlockSpec((1, 1, D), lambda bh, ki, *_: (bh, 0, 0)),
-            kv_spec, kv_spec,
-        ] + ([scale_spec, scale_spec] if quantized else []),
-        out_specs=pl.BlockSpec((1, 1, D), lambda bh, ki, *_: (bh, 0, 0)),
+        num_scalar_prefetch=len(prefetch),  # pos, layer [, window]
+        grid=(B, Smax // block_k),
+        in_specs=slope_specs + [row_spec, kv_spec, kv_spec]
+        + ([scale_spec, scale_spec] if quantized else []),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((H, HD), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
-    # prefetch refs arrive in arg order — [pos, window?] then slopes? —
-    # matching _unpack_rest's ordering contract
-    args = (pos_arr,) + win_args + slope_args + (q3, k3, v3) + \
-        ((ks3, vs3) if quantized else ())
+    # prefetch refs arrive in arg order — [pos, layer, window?] then
+    # slopes? — matching _unpack_rest's ordering contract
+    args = prefetch + slope_args + (q, k, v) + \
+        ((ks, vs) if quantized else ())
     return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=jax.ShapeDtypeStruct((BH, 1, D),
-                                                         q3.dtype),
+                          out_shape=jax.ShapeDtypeStruct((B, 1, HD), q.dtype),
                           interpret=interpret_mode(),
                           name="decode_attention")(*args)
 
@@ -345,8 +386,16 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
 def cached_attention(q, cache_k, cache_v, pos,
                      sm_scale: Optional[float] = None,
                      k_scale=None, v_scale=None,
-                     window=None, slopes=None):
+                     window=None, slopes=None, layer=None):
     """q [B,Sq,H,D] over a padded cache [B,Smax,H,D], visibility ≤ pos+i.
+
+    With ``layer`` (scalar, may be traced — a layer scan's index) the
+    cache operands are the whole stacked pool as ``gpt_inference`` stores
+    it, [L,B,Smax,H*D] (scales [L,B,Smax,H]), and the decode kernel reads
+    that layer of it WHERE IT LIES: the layer index rides the scalar
+    prefetch beside ``pos``; no layer is sliced out and nothing is
+    transposed to feed the kernel.  A per-layer [B,Smax,H,D] cache is the
+    same call on a stack of one.
 
     ``pos``: scalar, or a per-row [B] vector for ragged decode (each row's
     block sweep stops at ITS live prefix).  Single-token decode (Sq=1)
@@ -355,24 +404,30 @@ def cached_attention(q, cache_k, cache_v, pos,
     O(block) VMEM instead of a dense [Sq, Smax] score tensor; remaining
     shapes use the dense reference.
 
-    With ``k_scale``/``v_scale`` ([B,Smax,H,1] fp32) the cache holds int8
-    codes; the kernels dequantize in VMEM (halving the HBM stream), and
-    the non-kernel fallbacks dequantize before the dense math.
+    With ``k_scale``/``v_scale`` ([B,Smax,H,1] fp32; stacked [L,B,Smax,H])
+    the cache holds int8 codes; the decode kernel streams the codes
+    (halving the HBM stream) and applies the scales to the scores and the
+    probabilities in VMEM, the chunk kernel dequantizes its blocks in
+    VMEM, and the non-kernel fallbacks dequantize before the dense math.
 
     ``window`` (scalar, possibly traced — GPT-Neo's alternating stack
     carries it through a layer scan) bands visibility to the trailing
-    ``window`` slots.  Windowed calls build with a scalar-prefetch grid
-    spec: ``pos``/``window`` feed the k/v index maps, which clamp dead
-    block indices into each row's live range, so out-of-band blocks are
-    neither computed (``pl.when``) nor re-DMA'd — banded decode streams
-    O(window) HBM bytes per step instead of O(Smax), and short rows of a
-    ragged batch stop at their own frontier.  ``slopes`` ([H] fp32) adds
-    the ALiBi ``-slope·dist`` bias (BLOOM family) inside the kernel.
-    Both compose with the int8 cache.
+    ``window`` slots: ``pos``/``window`` feed the k/v index maps, which
+    clamp dead block indices into each row's live range, so out-of-band
+    blocks are neither computed (``pl.when``) nor re-DMA'd — banded decode
+    streams O(window) HBM bytes per step instead of O(Smax), and short
+    rows of a ragged batch stop at their own frontier.  ``slopes`` ([H]
+    fp32) adds the ALiBi ``-slope·dist`` bias (BLOOM family) inside the
+    kernel.  Both compose with the int8 cache.
     """
     B, Sq, H, D = q.shape
-    Smax = cache_k.shape[1]
     int8_cache = k_scale is not None
+    banks = (cache_k, cache_v) + ((k_scale, v_scale) if int8_cache else ())
+    if layer is None:
+        # [B,Smax,H,*] → a pool of one layer, heads folded into the row
+        banks = tuple(x.reshape((1,) + x.shape[:2] + (-1,)) for x in banks)
+        layer = 0
+    Smax = banks[0].shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
     block_k = next((b for b in (256, 128) if Smax % b == 0), None)
     # chunk path: pos may be scalar OR per-row [B] (ragged chunks — the
@@ -382,25 +437,28 @@ def cached_attention(q, cache_k, cache_v, pos,
     block_q = next((b for b in (256, 128, 8) if Sq % b == 0), None) \
         if Sq > 1 else None
 
-    def to3(x, d=D):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], d)
+    if use_pallas() and block_k is not None and Sq == 1:
+        ks, vs = banks[2:] if int8_cache else (None, None)
+        o = _decode(q.reshape(B, 1, H * D), banks[0], banks[1], layer, pos,
+                    scale, block_k, H, ks=ks, vs=vs, window=window,
+                    slopes=slopes)
+        return o.reshape(B, 1, H, D)
 
-    if use_pallas() and block_k is not None:
-        ks3 = to3(k_scale, 1) if int8_cache else None
-        vs3 = to3(v_scale, 1) if int8_cache else None
-        if Sq == 1:
-            o3 = _decode(to3(q), to3(cache_k), to3(cache_v), pos, scale,
-                         block_k, H, ks3=ks3, vs3=vs3, window=window,
-                         slopes=slopes)
-            return o3.reshape(B, H, 1, D).transpose(0, 2, 1, 3)
-        if block_q is not None:
-            o3 = _chunk(to3(q), to3(cache_k), to3(cache_v), pos, scale,
-                        block_q, block_k, H, ks3=ks3, vs3=vs3,
-                        window=window, slopes=slopes)
-            return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    # one layer, heads unfolded: [B,Smax,H,D] (scales [B,Smax,H,1])
+    banks = [jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+             .reshape(B, Smax, H, -1) for x in banks]
+    if use_pallas() and block_k is not None and block_q is not None:
+        def to3(x):
+            return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], -1)
+
+        ks3, vs3 = map(to3, banks[2:]) if int8_cache else (None, None)
+        o3 = _chunk(to3(q), to3(banks[0]), to3(banks[1]), pos, scale,
+                    block_q, block_k, H, ks3=ks3, vs3=vs3,
+                    window=window, slopes=slopes)
+        return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
     if int8_cache:
-        cache_k = dequantize_kv(cache_k, k_scale, q.dtype)
-        cache_v = dequantize_kv(cache_v, v_scale, q.dtype)
-    return cached_attention_reference(q, cache_k, cache_v, pos, scale,
+        banks = [dequantize_kv(banks[0], banks[2], q.dtype),
+                 dequantize_kv(banks[1], banks[3], q.dtype)]
+    return cached_attention_reference(q, banks[0], banks[1], pos, scale,
                                       window=window, slopes=slopes)

@@ -2,7 +2,7 @@
 
 The inference engine's generate paths size a program per call batch; a
 server cannot afford that — traffic is heterogeneous and endless.  The
-batcher instead owns a single ``[L, B=slots, max_len, H, D]`` KV cache and
+batcher instead owns a single ``[L, B=slots, max_len, H*D]`` KV cache and
 drives it with a closed set of compiled programs whose shapes never depend
 on a request:
 
@@ -252,11 +252,19 @@ class SlotBatcher:
             "take_last_wide": jax.jit(
                 lambda lg, i: lax.dynamic_index_in_dim(lg[0], i, 0,
                                                        keepdims=False)),
+            # admission donates the pool like the tick does (never ``src``:
+            # a prefix entry's cache is shared by its forks)
             "write_slot": jax.jit(
-                lambda c, row, src: fam.write_slot(c, row, src)),
+                lambda c, row, src: fam.write_slot(c, row, src),
+                donate_argnums=(0,)),
             "bind": jax.jit(bind),
             "release": jax.jit(release),
-            "tick": jax.jit(tick),
+            # the tick donates the slot pool: its one-row writes land in
+            # the pool where it lies and ``self.cache`` is rebound from the
+            # result on the call's own line, so nothing holds the old
+            # buffers (park/migration read a slot out between ticks and
+            # keep only what they read)
+            "tick": jax.jit(tick, donate_argnums=(1,)),
         })
         if self.spec:
             self._build_spec_programs(config)

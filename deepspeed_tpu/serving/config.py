@@ -347,7 +347,7 @@ class ServingConfig(DeepSpeedConfigModel):
     """Continuous-batching gateway knobs (see ``docs/serving.md``)."""
 
     #: decode-batch width B: how many requests decode concurrently.  The
-    #: slot cache is [L, B, max_len, H, D] — sized once, never resized.
+    #: slot cache is [L, B, max_len, H*D] — sized once, never resized.
     slots: int = 4
     #: per-slot cache length (prompt + reply budget); None = model context.
     #: Bucketed to a power of two so nearby deployments share programs.

@@ -164,9 +164,11 @@ def pad_table(table: List[int], max_blocks: int) -> np.ndarray:
 
 
 def _is_bank(leaf) -> bool:
-    """KV banks (k/v and their scale banks) are rank-5:
-    ``[L, B, S, H, D-or-1]``; the ``length`` scalar is rank-0."""
-    return getattr(leaf, "ndim", None) == 5
+    """KV banks (k/v and their scale banks) lead with ``[L, B, S]``: the
+    dense family's ``[L, B, S, H*D]`` / ``[L, B, S, H]``, the MoE
+    family's ``[L, B, S, H, D-or-1]``; the ``length`` scalar is rank-0.
+    Nothing here reads past the third dimension."""
+    return getattr(leaf, "ndim", 0) >= 4
 
 
 def cache_bank_bytes(cache) -> int:
@@ -190,7 +192,7 @@ def _host_banks(cache, pad_len: int) -> List[np.ndarray]:
 
 def _slot_banks(cache, row: int, length: int) -> List[np.ndarray]:
     """Device→host pull of ONE slot's banks out of a batched cache
-    ``[L, B, S, H, D]``, as batch-1 arrays trimmed to the first
+    ``[L, B, S, ...]``, as batch-1 arrays trimmed to the first
     ``length`` rows — the export half of live session migration (the
     target rebuilds them via ``rebuild_prefix_cache``)."""
     out = []
@@ -217,7 +219,7 @@ class PagedKVPool:
     """The device-resident block pool + its gather/scatter programs.
 
     The pool is a family cache of geometry ``[L, num_blocks,
-    block_tokens, H, D]`` — block *b* is row *b* — so the same tree ops
+    block_tokens, ...]`` — block *b* is row *b* — so the same tree ops
     page every cache family, int8 scale banks included.
     """
 
@@ -246,7 +248,7 @@ class PagedKVPool:
         def gather(pool, table, length):
             """Block table → batch-1 slot-geometry cache."""
             def g(bank):
-                got = bank[:, table]                     # [L, MB, bt, H, *]
+                got = bank[:, table]                     # [L, MB, bt, ...]
                 return got.reshape(bank.shape[0], 1, MB * bt,
                                    *bank.shape[3:])
             out = jax.tree_util.tree_map(

@@ -72,7 +72,7 @@ def test_int8_cache_decode_matches_fp_cache():
         cache_fp = gpt_inference.init_cache(cfg, 2, 64)
         cache_q = gpt_inference.init_cache(cfg, 2, 64, kv_dtype="int8")
         assert cache_q.k.dtype == jnp.int8 and cache_q.int8
-        assert cache_q.k_scale.shape == (cfg.n_layer, 2, 64, cfg.n_head, 1)
+        assert cache_q.k_scale.shape == (cfg.n_layer, 2, 64, cfg.n_head)
 
         lg_fp, cache_fp = gpt_inference.prefill(params, tokens[:, :8], cfg,
                                                 cache_fp)
@@ -396,3 +396,64 @@ def test_ragged_chunk_kernel_matches_reference(pallas_interpret, int8,
     assert not np.isnan(np.asarray(got)).any()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+# the stacked pool [L, B, Smax, H*D] read where it lies: block_k is 256 at
+# this Smax, so the per-row frontiers below sit on both sides of a block edge
+_POOL_SMAX = 512
+_POOL_CASES = {
+    "scalar-pos": dict(pos=100),
+    "row-pos-block-edges": dict(pos=[0, 255, 256, _POOL_SMAX - 1]),
+    "empty-beside-full": dict(pos=[0, _POOL_SMAX - 1, _POOL_SMAX - 1, 0]),
+    "window": dict(pos=[40, 255, 256, _POOL_SMAX - 1], window=48),
+    "slopes": dict(pos=[0, 255, 300, _POOL_SMAX - 1], slopes=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_POOL_CASES))
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_stacked_pool_decode_kernel_matches_reference(pallas_interpret, kind,
+                                                      case):
+    """The decode kernel on the pool as ``gpt_inference`` stores it, layer
+    picked by a TRACED index other than 0 (every layer holds other values,
+    so a wrong layer offset fails), against the dense reference on that
+    layer."""
+    spec = _POOL_CASES[case]
+    L, B, Smax, H, D = 3, 4, _POOL_SMAX, 4, 32
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(kq, (B, 1, H, D), jnp.float32).astype(dtype)
+    k = jax.random.normal(kk, (L, B, Smax, H, D), jnp.float32).astype(dtype)
+    v = jax.random.normal(kv, (L, B, Smax, H, D), jnp.float32).astype(dtype)
+    pos = jnp.asarray(spec["pos"], jnp.int32)
+    window = jnp.int32(spec["window"]) if "window" in spec else None
+    slopes = gpt.alibi_slopes(H) if spec.get("slopes") else None
+
+    def fold(x):                     # [L,B,S,H,*] -> [L,B,S,H * *]
+        return x.reshape(x.shape[:3] + (-1,))
+
+    scales = {}
+    if kind == "int8":
+        (k, k_s), (v, v_s) = quantize_kv(k), quantize_kv(v)
+        scales = dict(k_scale=fold(k_s), v_scale=fold(v_s))
+        ref_k = dequantize_kv(k, k_s, jnp.float32)
+        ref_v = dequantize_kv(v, v_s, jnp.float32)
+    else:
+        ref_k, ref_v = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    layer = 2
+    got = jax.jit(lambda lay: cached_attention(
+        q, fold(k), fold(v), pos, window=window, slopes=slopes, layer=lay,
+        **scales))(jnp.int32(layer))
+    want = cached_attention_reference(
+        q.astype(jnp.float32), ref_k[layer], ref_v[layer], pos,
+        window=window, slopes=slopes)
+    tol = 2e-2 if kind == "bf16" else 2e-5
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=tol, rtol=tol)
+    # the neighbouring layer is another answer: the index is honoured
+    other = cached_attention_reference(
+        q.astype(jnp.float32), ref_k[layer - 1], ref_v[layer - 1], pos,
+        window=window, slopes=slopes)
+    assert float(jnp.max(jnp.abs(other - want))) > 10 * tol

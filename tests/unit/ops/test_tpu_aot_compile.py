@@ -131,6 +131,74 @@ def test_cached_attention(v5e, sq, int8, per_row_pos):
         _compiles_with_kernel(decode.cached_attention, q, k, v, pos)
 
 
+def _root_opcodes(hlo_text):
+    """``(result elements, opcode)`` of every instruction of a compiled
+    module, a fusion counted under the opcode of its root."""
+    import math
+    import re
+    line = re.compile(r"^\s*(ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    roots, rows, name = {}, [], None
+    for text in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\{$", text)
+        if head:
+            name = head.group(1)
+            continue
+        m = line.match(text)
+        if not m:
+            continue
+        root, _, dims, opcode = m.groups()
+        if root:
+            roots[name] = opcode
+        calls = re.search(r"calls=%(\S+?)[,)\s]", text)
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        rows.append((n, opcode, calls.group(1) if calls else None))
+    return [(n, roots.get(calls, op) if op == "fusion" else op)
+            for n, op, calls in rows]
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_decode_tick_leaves_the_pool_in_place(v5e, int8):
+    """The tick's device program at the serving cells' geometry (64 slots x
+    1024 tokens, 16 heads of 64; two layers stand for 24): per-row
+    ``decode_step`` on a donated cache.  Nothing but the kernel may touch a
+    whole layer of the pool: no copy, transpose or slice as large as one
+    layer's K, and the pool's inputs are its outputs.  A pool stored with
+    64 last (``[L, B, S, H, D]``) fails this: the TPU lays it out with the
+    tokens on the lanes and re-lays it around every write and kernel call."""
+    import dataclasses
+
+    from deepspeed_tpu.models import gpt, gpt_inference
+    slots, layers = 64, 2
+    cfg = dataclasses.replace(gpt.GPT2_350M, n_layer=layers, dtype=BF16)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            tree)
+
+    params = described(jax.eval_shape(
+        lambda: gpt.init(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(lambda: gpt_inference.init_cache(
+        cfg, slots, SMAX, kv_dtype="int8" if int8 else None)))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
+    compiled = jax.jit(
+        lambda p, c, tok, lengths: gpt_inference.decode_step(
+            p, tok, cfg, c, lengths=lengths),
+        donate_argnums=(1,)).lower(params, cache, rows, rows).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the tick"
+    layer_k = slots * SMAX * cfg.n_head * cfg.head_dim
+    moved = [(n, op) for n, op in _root_opcodes(text)
+             if n >= layer_k and (op.startswith("copy") or op in (
+                 "transpose", "dynamic-slice", "dynamic-update-slice"))]
+    assert not moved, f"the tick moves whole layers of the pool: {moved}"
+    banks = [x for x in jax.tree_util.tree_leaves(cache) if x.ndim >= 4]
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in banks)
+    assert "input_output_alias" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes, \
+        "the donated pool is not updated in place"
+
+
 @pytest.mark.parametrize("stochastic", [False, True],
                          ids=["nearest", "stochastic"])
 def test_symmetric_quantizer(v5e, stochastic):

@@ -132,6 +132,53 @@ def test_batcher_admit_tick_release_matches_sequential():
     assert all(v <= 1 for v in counts.values()), counts
 
 
+@pytest.mark.parametrize("kv", ["auto", "int8"])
+def test_donated_pool_strands_no_reader(kv):
+    """The tick and the admission donate the slot pool.  A slot read out of
+    it between ticks (what a park or a migration keeps) outlives the ticks
+    that follow, and re-admitted it continues the reply: every token
+    matches the same sequence through the session path."""
+    from deepspeed_tpu.serving.batcher import PrefixEntry
+    eng = _engine(kv_cache_dtype=kv)
+    bat = SlotBatcher(eng, ServingConfig.from_dict(
+        {"slots": 2, "max_len": 64, "prefill_chunk": 8}))
+    bat.prewarm()
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 256, (n,)).astype(np.int32) for n in (9, 6)]
+
+    def reference(p, n):
+        s = eng.start_session(batch=1, max_len=64)
+        s.append(jnp.asarray(p[None]))
+        return np.asarray(s.generate(max_new_tokens=n))[0].tolist()
+
+    want = [reference(p, 6) for p in prompts]
+    key = jax.random.PRNGKey(3)
+    for row, p in enumerate(prompts):
+        bat.admit(row, p, key, True, 1.0)
+    got = np.stack([bat.tick() for _ in range(2)])
+    assert got.T.tolist() == [w[:2] for w in want]
+
+    # row 0 leaves the pool as a park would take it: K/V of the prompt and
+    # of the first reply token (the second is emitted, not yet written)
+    held = len(prompts[0]) + 1
+    read = jax.jit(lambda c, row, n: gpt_inference.read_slot(c, row, n))
+    parked = read(bat.cache, jnp.int32(0), jnp.int32(held))
+    bat.release(0)
+    more = np.stack([bat.tick() for _ in range(2)])      # donate the pool twice
+    assert more[:, 1].tolist() == want[1][2:4]
+    assert int(parked.length) == held and np.asarray(parked.k).any()
+
+    # ... and comes back: the re-admission prefills only the emitted token
+    so_far = np.concatenate([prompts[0], np.asarray(want[0][:2], np.int32)])
+    bat.admit(0, so_far, key, True, 1.0,
+              prefix=PrefixEntry(cache=parked, length=held))
+    last = np.stack([bat.tick() for _ in range(2)])
+    assert last[:, 0].tolist() == want[0][2:4]
+    assert last[:, 1].tolist() == want[1][4:6]
+    counts = bat.compile_counts()
+    assert all(v <= 1 for v in counts.values()), counts
+
+
 def test_batcher_prefix_fork_admission():
     """A pooled prefix admits through zero-copy fork: prefix prefilled
     once, remainder extended at the true frontier — output equals the

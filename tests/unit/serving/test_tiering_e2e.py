@@ -190,7 +190,7 @@ def test_corrupt_ram_park_rejected(engine):
     o1 = gw.submit(p, max_new_tokens=4, session_id="x").result(timeout=60)
     entry = gw._pager.park.entry("x")
     assert entry is not None and entry.arrays is not None
-    entry.arrays[0][0, 0, 0, 0, 0] += 1.0
+    entry.arrays[0][0, 0, 0, 0] += 1.0
     t2 = rng.integers(0, 256, (4,)).astype(np.int32)
     o2 = gw.submit(np.concatenate([p, o1, t2]), max_new_tokens=4,
                    session_id="x").result(timeout=60)
