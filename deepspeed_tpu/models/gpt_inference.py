@@ -99,10 +99,13 @@ def init_cache(config: gpt.GPTConfig, batch: int, max_len: int,
 
 
 def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
-                      window=None, k_scale=None, v_scale=None, layer=None):
+                      window=None, k_scale=None, v_scale=None, layer=None,
+                      active=None, sweep=None):
     """q: [B, S_q, H, D] attending to cache[:, :pos+S_q]; with ``layer``
     the cache operands are the stacked [L, B, S_max, H*D] pool and the
-    decode kernel reads that layer in place.
+    decode kernel reads that layer in place.  ``active`` / ``sweep``
+    (single-token decode only): the live rows and the kernel's work list
+    built from them, see ``cached_attention``.
 
     ``pos`` is the number of tokens already in the cache before this call;
     query i sits at absolute position pos+i and sees cache slots ≤ pos+i.
@@ -130,7 +133,8 @@ def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
         scale = 1.0 / math.sqrt(config.head_dim)
     return cached_attention(q, cache_k, cache_v, pos, sm_scale=scale,
                             k_scale=k_scale, v_scale=v_scale,
-                            window=window, slopes=slopes, layer=layer)
+                            window=window, slopes=slopes, layer=layer,
+                            active=active, sweep=sweep)
 
 
 def _block_tail(x, attn, p, config: gpt.GPTConfig):
@@ -346,12 +350,34 @@ def read_slot(cache: KVCache, row, length=None) -> KVCache:
         v_scale=rd(cache.v_scale) if cache.int8 else None)
 
 
+def _layer_sweeps(pos, B, config: gpt.GPTConfig, max_len, active):
+    """The decode kernel's work list (``decode_sweep``) for every layer,
+    built ONCE, before the layer scan: a function of the step's ``pos`` and
+    ``active`` alone, and, in a banded stack, of each layer's window (all
+    layers' lists in one vectorised build).  Returns ``idx -> sweep``."""
+    from ..ops.pallas.decode_attention import decode_block_k, decode_sweep
+    block_k = decode_block_k(max_len, config.n_head * config.head_dim)
+    if config.local_attention_window <= 0:
+        sweep = decode_sweep(pos, B, max_len, block_k, active)
+        return lambda idx: sweep
+    windows = gpt.layer_window(config, jnp.arange(config.n_layer), max_len)
+    sweeps = jax.vmap(
+        lambda w: decode_sweep(pos, B, max_len, block_k, active, w))(windows)
+    return lambda idx: jax.tree_util.tree_map(lambda a: a[idx], sweeps)
+
+
 def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
-                cache: KVCache, lengths=None) -> Tuple[jnp.ndarray, KVCache]:
+                cache: KVCache, lengths=None,
+                active=None) -> Tuple[jnp.ndarray, KVCache]:
     """One-token decode: token [B] int32 at position cache.length — or,
     with ``lengths`` [B], at per-row positions (ragged right-padded
     prompts: each row's token lands on ITS next slot and sees only ITS
     live prefix; pad-slot K/V is overwritten as rows catch up).
+
+    ``active`` [B] bool (default: every row) names the live rows of a slot
+    batch.  A dead row's attention is not computed — the kernel neither
+    steps nor streams for it — so its logits are junk; its token is still
+    written to its own cell, as every row's is.
 
     Returns (logits [B, padded_vocab] fp32, cache advanced by one).
     """
@@ -360,6 +386,7 @@ def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
     pos = lengths if ragged else cache.length
     positions = pos[:, None] if ragged else pos[None]
     x = gpt.embed(params, token[:, None], config, positions=positions)
+    sweep_of = _layer_sweeps(pos, B, config, cache.max_len, active)
 
     def write(bank, layer, val):
         """One new [B, 1, H * *] row per slot at [layer, :, pos] (pos
@@ -372,7 +399,8 @@ def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
         return _cached_attention(
             q, ck, cv, pos, config,
             window=gpt.layer_window(config, idx, cache.max_len),
-            k_scale=ksc, v_scale=vsc, layer=idx)
+            k_scale=ksc, v_scale=vsc, layer=idx, active=active,
+            sweep=sweep_of(idx))
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn)
     logits = gpt.lm_logits(params, x[:, 0], config)

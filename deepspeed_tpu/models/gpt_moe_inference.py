@@ -140,16 +140,17 @@ def _append_kv(ck, cv, ksc, vsc, k, v, pos, ragged=False):
 
 
 def _attend_decode(x, p, config, ck, cv, pos, positions, ksc=None,
-                   vsc=None, ragged=False):
+                   vsc=None, ragged=False, active=None):
     """Cache-append + cached attention for one sublayer; int8 caches
     dequantize inside the kernel's VMEM stream (dense-family contract).
-    ``ragged``: pos is [B] — per-row append and per-row visibility."""
+    ``ragged``: pos is [B] — per-row append and per-row visibility.
+    ``active``: the live rows of a one-token step (``cached_attention``)."""
     from .gpt_inference import _cached_attention
     q, k, v = gpt.qkv_proj(x, p, config, positions=positions)
     ck, cv, ksc, vsc = _append_kv(ck, cv, ksc, vsc, k, v, pos,
                                   ragged=ragged)
     attn = _cached_attention(q, ck, cv, pos, config, k_scale=ksc,
-                             v_scale=vsc)
+                             v_scale=vsc, active=active)
     return x + gpt.attn_project(attn, p, config), ck, cv, ksc, vsc
 
 
@@ -210,8 +211,8 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
 
 
 def extend(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
-           cache: MoEKVCache,
-           lengths=None) -> Tuple[jnp.ndarray, MoEKVCache]:
+           cache: MoEKVCache, lengths=None,
+           active=None) -> Tuple[jnp.ndarray, MoEKVCache]:
     """Chunked prefill continuation (the MoE counterpart of
     ``gpt_inference.extend``): append ``tokens`` [B, S_c] at positions
     ``cache.length..``, attending causally over prefix + chunk through
@@ -220,7 +221,9 @@ def extend(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
     speculative verify pass rides.  ``lengths`` [B] makes the chunk
     RAGGED (batched speculative verify): row b's S_c tokens land at ITS
     frontier with per-row visibility; ``cache.length`` advances to
-    ``max(lengths) + S_c`` and the caller tracks per-row lengths."""
+    ``max(lengths) + S_c`` and the caller tracks per-row lengths.
+    ``active`` [B] bool is ``decode_step``'s: the live rows of a one-token
+    step, which alone read it."""
     B, Sc = tokens.shape
     ragged = lengths is not None
     pos0 = lengths if ragged else cache.length
@@ -239,11 +242,11 @@ def extend(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
         dense_p, attn_p, moe_p, dck, dcv, mck, mcv, dks, dvs, mks, mvs = xs
         x, dck, dcv, dks, dvs = _attend_decode(
             x, dense_p, config, dck, dcv, pos0, positions, dks, dvs,
-            ragged=ragged)
+            ragged=ragged, active=active)
         x = gpt.mlp_residual(x, dense_p, config)
         x, mck, mcv, mks, mvs = _attend_decode(
             x, attn_p, config, mck, mcv, pos0, positions, mks, mvs,
-            ragged=ragged)
+            ragged=ragged, active=active)
         x = _moe_ffn(x, attn_p, moe_p, moe, config)
         return x, (dck, dcv, mck, mcv, dks, dvs, mks, mvs)
 
@@ -326,14 +329,16 @@ def read_slot(cache: MoEKVCache, row, length=None) -> MoEKVCache:
 
 
 def decode_step(params: PyTree, token: jnp.ndarray, config: GPTMoEConfig,
-                cache: MoEKVCache,
-                lengths=None) -> Tuple[jnp.ndarray, MoEKVCache]:
+                cache: MoEKVCache, lengths=None,
+                active=None) -> Tuple[jnp.ndarray, MoEKVCache]:
     """One-token decode through both banks; token [B] int32 — a 1-token
     ``extend`` with the chunk axis squeezed.  With ``lengths`` [B]
     (ragged right-padded prompts, dense-family contract) each row's
     token lands on ITS next slot and sees only ITS live prefix; dropless
     gating keeps rows independent, so ragged batching cannot perturb a
-    row's routing."""
+    row's routing.  ``active`` [B] bool names the live rows of a slot
+    batch (dense-family contract): the decode kernel neither steps nor
+    streams for a dead row, whose logits are junk."""
     logits, cache = extend(params, token[:, None], config, cache,
-                           lengths=lengths)
+                           lengths=lengths, active=active)
     return logits[:, 0], cache

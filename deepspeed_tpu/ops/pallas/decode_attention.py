@@ -5,17 +5,20 @@ Counterpart of the reference's ``softmax_context`` inference kernel
 the KV cache with the current sequence length masked): one query token per
 (batch, head) attends to cache slots ``0..pos`` of a statically-shaped
 cache.  The Pallas kernel streams cache blocks through VMEM with the
-online-softmax recurrence and skips blocks entirely beyond ``pos`` — the
-decode step's HBM traffic is the live cache prefix, not S_max.
+online-softmax recurrence, and its grid is the LIVE blocks of the LIVE
+rows and nothing else (``decode_sweep``): the decode step's grid steps and
+its HBM traffic follow the live context, not ``B x S_max``.  A block beyond
+a row's ``pos`` (or below its band) costs no step, and a row the caller
+marks dead (a freed slot of a serving batch) neither a step nor a byte.
 
 The decode kernel reads the slot pool WHERE IT LIES.  ``gpt_inference``
 stores it ``[L, B, S_max, H*D]`` — a token's heads folded into one row, so
 the stored order is row-major on the TPU (a last dimension of 64 would put
 the tokens on the lanes instead) — and the kernel's blocks are
 ``[block_k, H*D]`` tiles of exactly that array: the layer index rides the
-scalar prefetch beside ``pos``, the grid is ``(B, S_max/block_k)`` with all
-heads of a slot in one step, and nothing is sliced, transposed or copied to
-feed it.  The chunk kernel (``extend``: admission, speculative verify)
+scalar prefetch beside ``pos`` and the sweep, a grid step takes all heads of
+one block of one slot, and nothing is sliced, transposed or copied to feed
+it.  The chunk kernel (``extend``: admission, speculative verify)
 still takes one head a step from a ``[B*H, S_max, D]`` view of one layer: a
 64-wide head is half a lane row and cannot be a block of the folded row.
 
@@ -91,10 +94,10 @@ M_FLOOR = -1e30
 
 
 def _unpack_rest(rest, quantized, windowed, alibi):
-    """Positional unpack of everything after ``pos_ref``, mirroring the
-    wrappers' argument order: [window?, slopes?, q, k, v, kscale?,
-    vscale?, o, acc, m, l] (pos and window are scalar-prefetch operands,
-    so they lead)."""
+    """Positional unpack of everything after the kernel's named scalar
+    operands, mirroring the wrappers' argument order: [window?, slopes?,
+    q, k, v, kscale?, vscale?, o, acc, m, l] (window is a scalar-prefetch
+    operand like them, so it leads)."""
     i = 0
     window_ref = slopes_ref = kscale_ref = vscale_ref = None
     if windowed:
@@ -109,49 +112,60 @@ def _unpack_rest(rest, quantized, windowed, alibi):
             vscale_ref, o_ref, acc_ref, m_ref, l_ref)
 
 
-def _decode_kernel(pos_ref, layer_ref, *rest, sm_scale, block_k, H, D,
-                   quantized, windowed, alibi):
+def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
+                   sm_scale, block_k, H, D, quantized, windowed, alibi):
     """One online-softmax decode kernel serving every cache layout.  A
-    grid step is one slot's block of ``block_k`` cached tokens with ALL
-    its heads, as the pool stores them: k/v refs are ``(block_k, H*D)``,
-    the query one ``(1, H*D)`` row.  The per-head products ride two plain
-    matmuls: the query is spread to ``(H, H*D)`` with head ``h``'s lanes
-    kept in row ``h`` and zeros elsewhere, so ``qx · kᵀ`` is every head's
-    score row at once, and ``p · v`` leaves head ``h``'s output in row
-    ``h``'s own lanes (the other lanes hold cross-head products nobody
-    reads).  Scores and the running max/sum/accumulator are float32.
+    grid step is ONE LIVE BLOCK of one live row: step ``s`` of the flat
+    grid reads its row and its block from the sweep (``rows_ref[s]``,
+    ``blocks_ref[s]``; ``decode_sweep``), ``block_k`` cached tokens with
+    ALL their heads as the pool stores them: k/v refs are ``(block_k,
+    H*D)``, the query one ``(1, H*D)`` row.  A row's blocks are
+    consecutive steps in rising order; the running max/sum/accumulator are
+    reset on its first and written out on its last.  Steps at or past
+    ``n_ref[0]`` (the tail of a static grid, or the one step of an empty
+    sweep) do nothing.
+
+    The per-head products ride two plain matmuls: the query is spread to
+    ``(H, H*D)`` with head ``h``'s lanes kept in row ``h`` and zeros
+    elsewhere, so ``qx · kᵀ`` is every head's score row at once, and
+    ``p · v`` leaves head ``h``'s output in row ``h``'s own lanes (the
+    other lanes hold cross-head products nobody reads).  Scores and the
+    running max/sum/accumulator are float32.
 
     With ``quantized`` the k/v blocks arrive as int8 codes (exact in the
     compute dtype) and the per-vector fp32 scales ``(block_k, H)``
     multiply the scores and the probabilities in VMEM instead of every
     element — half the HBM bytes on the memory-bound decode path.
     ``windowed`` bands visibility to the trailing ``window`` slots (SMEM
-    scalar — it may alternate per layer) and skips blocks wholly below the
-    band; ``alibi`` adds the per-head ``-slope·dist`` bias from a
-    ``(H, 1)`` slope column.  ``layer_ref`` only feeds the index maps."""
+    scalar — it may alternate per layer; the sweep already left out the
+    blocks wholly below the band); ``alibi`` adds the per-head
+    ``-slope·dist`` bias from a ``(H, 1)`` slope column.  ``layer_ref``
+    only feeds the index maps."""
     (window_ref, slopes_ref, q_ref, k_ref, v_ref, kscale_ref, vscale_ref,
      o_ref, acc_ref, m_ref, l_ref) = _unpack_rest(rest, quantized,
                                                   windowed, alibi)
-    ki = pl.program_id(1)
-    nk = pl.num_programs(1)
-    pos = pos_ref[pl.program_id(0)]  # per-ROW visibility (ragged decode)
+    step = pl.program_id(0)
+    n = n_ref[0]
+    row = rows_ref[step]
+    ki = blocks_ref[step]
+    pos = pos_ref[row]               # per-ROW visibility (ragged decode)
+    live = step < n
+    first = jnp.logical_or(step == 0,
+                           rows_ref[jnp.maximum(step - 1, 0)] != row)
+    last = jnp.logical_or(
+        step == n - 1,
+        rows_ref[jnp.minimum(step + 1, rows_ref.shape[0] - 1)] != row)
 
     def own():
         """(H, H*D) mask: lane c of row h belongs to head h."""
         return jax.lax.broadcasted_iota(jnp.int32, (H, H * D), 1) // D == \
             jax.lax.broadcasted_iota(jnp.int32, (H, H * D), 0)
 
-    @pl.when(ki == 0)
+    @pl.when(jnp.logical_and(live, first))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
         l_ref[...] = jnp.zeros_like(l_ref)
-
-    live = ki * block_k <= pos
-    if windowed:
-        # skip blocks wholly below the band [pos-window+1, pos]
-        live = jnp.logical_and(
-            live, (ki + 1) * block_k - 1 >= pos - window_ref[0] + 1)
 
     @pl.when(live)
     def _update():
@@ -182,7 +196,7 @@ def _decode_kernel(pos_ref, layer_ref, *rest, sm_scale, block_k, H, D,
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p.astype(vs.dtype), vs, preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(jnp.logical_and(live, last))
     def _finalize():
         o = jnp.where(own(), acc_ref[...] / l_ref[...], 0.0)
         o_ref[...] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
@@ -198,46 +212,130 @@ def _to_compute(x, dtype):
     return x.astype(dtype)
 
 
-def _decode(q, k, v, layer, pos, sm_scale, block_k, H, ks=None, vs=None,
-            window=None, slopes=None):
+def decode_block_k(Smax: int, HD: int) -> Optional[int]:
+    """Tokens in one streamed block of the single-token sweep, or None
+    where ``Smax`` does not tile (the dense reference serves it).  A step
+    costs only when its block is live, so the size trades the dead tail of
+    a row's last block against a step's fixed cost (~0.7 us on a v5e):
+    about 2**18 cache elements a step, so 256 tokens of 16 heads of 64 and
+    128 of 32 (measured, PERF.md 6, PR 28: at 64 slots x 1024 a width of
+    2048 takes 297 us a layer with 128, 352 with 256; a width of 1024 takes
+    176 with 256, 179 with 128, 222 with 512).  Elements, not bytes: int8
+    codes stream half the bytes and pay the same per element to become
+    the compute dtype, and measure best at the same 256."""
+    return next((b for b in (256, 128) if Smax % b == 0
+                 and (b * HD <= 1 << 18 or b == 128)), None)
+
+
+def decode_sweep(pos, B: int, Smax: int, block_k: Optional[int],
+                 active=None, window=None):
+    """The single-token sweep's work list: one entry for every live block
+    of every live row, rows in order and each row's blocks rising from its
+    band start to its causal frontier — ``(rows, blocks, n)``, int32
+    ``[B * Smax/block_k]``, ``[B * Smax/block_k]`` and ``[1]``.  Entries
+    from ``n`` on repeat the last live one, so a grid that runs past ``n``
+    maps to blocks already in VMEM and moves nothing.
+
+    ``pos`` (scalar or [B]) is each row's frontier, ``active`` ([B] bool,
+    default all) its liveness: a dead row has no entry.  ``window``
+    (scalar, may be traced) drops the blocks wholly below the band.  The
+    list is a function of the tick's inputs alone: callers that run many
+    layers build it once and hand it to every ``cached_attention`` call.
+    ``block_k`` is ``decode_block_k``'s."""
+    if block_k is None or not use_pallas():
+        return None                  # the dense reference sweeps nothing
+    nb = Smax // block_k
+    p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    hi = jnp.clip(p // block_k, 0, nb - 1)
+    lo = jnp.zeros_like(hi) if window is None else jnp.clip(
+        (p - jnp.asarray(window, jnp.int32) + 1) // block_k, 0, hi)
+    count = hi - lo + 1
+    if active is not None:
+        count = jnp.where(active, count, 0)
+    ends = jnp.cumsum(count)
+    n = ends[-1]
+    s = jnp.minimum(jnp.arange(B * nb, dtype=jnp.int32),
+                    jnp.maximum(n - 1, 0))
+    # entry s belongs to the row after the last that ends at or before s:
+    # one [entries, B] comparison, then sums (no gather, no search loop)
+    ended = (ends[None, :] <= s[:, None]).astype(jnp.int32)
+    rows = jnp.minimum(jnp.sum(ended, axis=1), B - 1)
+    # its block is lo[row] + s - start[row]; (lo - start)[row] summed up
+    # from the row-to-row differences of the rows that have ended
+    shift = lo - (ends - count)
+    step = jnp.diff(shift, append=shift[-1:])
+    blocks = s + shift[0] + jnp.sum(ended * step[None, :], axis=1)
+    return (rows.astype(jnp.int32), blocks.astype(jnp.int32),
+            n.reshape(1).astype(jnp.int32))
+
+
+def sweep_block_counts(positions, rows: int, Smax: int,
+                       block_k: Optional[int], windows=((None, 1),)):
+    """What ``decode_sweep`` lists, counted on the host from lengths the
+    caller already holds (no device read): ``(live, grid)`` blocks over
+    the kernel calls of one decode step.  ``positions`` are the live rows'
+    frontiers, ``rows`` the batch's rows, live or not, and ``windows`` the
+    step's calls as ``(window or None, how many layers)`` pairs.  ``grid``
+    is what a sweep of every block of every row would step."""
+    if block_k is None:
+        return 0, 0
+    nb = Smax // block_k
+    live = 0
+    for window, layers in windows:
+        for p in positions:
+            hi = min(p // block_k, nb - 1)
+            lo = 0 if window is None else \
+                min(max((p - window + 1) // block_k, 0), hi)
+            live += layers * (hi - lo + 1)
+    return live, sum(n for _, n in windows) * rows * nb
+
+
+def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, ks=None,
+            vs=None, window=None, slopes=None):
     """Single scalar-prefetch build for every decode variant, reading the
-    stacked pool ``k``/``v`` [L, B, Smax, H*D] where it lies: ``layer``
-    and ``pos`` (and window, when banded) are available BEFORE the body,
-    so the k/v index maps pick the layer and clamp dead block indices into
-    each row's live range [band start, causal frontier].  Pallas only
-    re-issues a DMA when the mapped block index changes, so decode streams
-    the live prefix — and a banded or short ragged row only ITS band —
-    instead of O(Smax) cache bytes; ``pl.when`` still elides the dead
-    blocks' compute.  Grid ``(B, Smax/block_k)``; ``q`` and the result are
-    ``[B, 1, H*D]``; ``ks``/``vs`` [L, B, Smax, H]."""
+    stacked pool ``k``/``v`` [L, B, Smax, H*D] where it lies.  The sweep
+    (``decode_sweep``), ``pos``, ``layer`` (and window, when banded) are
+    available BEFORE the body, so the index maps pick each step's row,
+    block and layer from them, and Pallas prefetches the next step's block
+    — the next row's first, at a row's end — behind the current one.  The
+    grid is flat, one step per entry of the sweep: on the chip its bound is
+    the live-block count itself (a dynamic grid bound), so a dead block
+    costs no step and a dead row neither a step nor a byte; under the
+    interpreter, which refuses dynamic bounds, the same body runs the
+    static ``B * Smax/block_k`` steps and skips the tail.  ``q`` and the
+    result are ``[B, 1, H*D]`` (a dead row's result is never written);
+    ``ks``/``vs`` [L, B, Smax, H]."""
     B, _, HD = q.shape
-    Smax = k.shape[2]
     quantized = ks is not None
     windowed = window is not None
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                block_k=block_k, H=H, D=HD // H,
                                quantized=quantized, windowed=windowed,
                                alibi=slopes is not None)
+    rows, blocks, n = sweep
 
-    def kv_idx(b, ki, pos_ref, layer_ref, *maybe_win):
-        p = pos_ref[b]
-        lo = jnp.maximum((p - maybe_win[0][0] + 1) // block_k, 0) \
-            if windowed else 0
-        return (layer_ref[0], b, jnp.clip(ki, lo, p // block_k), 0)
+    def row_idx(s, rows_ref, *_):
+        return (rows_ref[s], 0, 0)
+
+    def kv_idx(s, rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *_):
+        return (layer_ref[0], rows_ref[s], blocks_ref[s], 0)
 
     kv_spec = pl.BlockSpec((None, None, block_k, HD), kv_idx)
     scale_spec = pl.BlockSpec((None, None, block_k, H), kv_idx)
-    row_spec = pl.BlockSpec((None, 1, HD), lambda b, ki, *_: (b, 0, 0))
-    slope_specs = [pl.BlockSpec((H, 1), lambda b, ki, *_: (0, 0))] \
+    row_spec = pl.BlockSpec((None, 1, HD), row_idx)
+    slope_specs = [pl.BlockSpec((H, 1), lambda s, *_: (0, 0))] \
         if slopes is not None else []
     slope_args = (jnp.asarray(slopes, jnp.float32).reshape(H, 1),) \
         if slopes is not None else ()
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-    prefetch = (pos_arr, jnp.asarray(layer, jnp.int32).reshape(1)) + \
+    prefetch = (rows, blocks, n, pos_arr,
+                jnp.asarray(layer, jnp.int32).reshape(1)) + \
         ((jnp.asarray(window, jnp.int32).reshape(1),) if windowed else ())
+    interpret = interpret_mode()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # pos, layer [, window]
-        grid=(B, Smax // block_k),
+        # rows, blocks, n, pos, layer [, window]
+        num_scalar_prefetch=len(prefetch),
+        grid=(rows.shape[0] if interpret else jnp.maximum(n[0], 1),),
         in_specs=slope_specs + [row_spec, kv_spec, kv_spec]
         + ([scale_spec, scale_spec] if quantized else []),
         out_specs=row_spec,
@@ -247,13 +345,13 @@ def _decode(q, k, v, layer, pos, sm_scale, block_k, H, ks=None, vs=None,
             pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
-    # prefetch refs arrive in arg order — [pos, layer, window?] then
-    # slopes? — matching _unpack_rest's ordering contract
+    # prefetch refs arrive in arg order — [rows, blocks, n, pos, layer,
+    # window?] then slopes? — matching _unpack_rest's ordering contract
     args = prefetch + slope_args + (q, k, v) + \
         ((ks, vs) if quantized else ())
     return pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=jax.ShapeDtypeStruct((B, 1, HD), q.dtype),
-                          interpret=interpret_mode(),
+                          interpret=interpret,
                           name="decode_attention")(*args)
 
 
@@ -386,7 +484,8 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
 def cached_attention(q, cache_k, cache_v, pos,
                      sm_scale: Optional[float] = None,
                      k_scale=None, v_scale=None,
-                     window=None, slopes=None, layer=None):
+                     window=None, slopes=None, layer=None,
+                     active=None, sweep=None):
     """q [B,Sq,H,D] over a padded cache [B,Smax,H,D], visibility ≤ pos+i.
 
     With ``layer`` (scalar, may be traced — a layer scan's index) the
@@ -397,12 +496,19 @@ def cached_attention(q, cache_k, cache_v, pos,
     transposed to feed the kernel.  A per-layer [B,Smax,H,D] cache is the
     same call on a stack of one.
 
-    ``pos``: scalar, or a per-row [B] vector for ragged decode (each row's
-    block sweep stops at ITS live prefix).  Single-token decode (Sq=1)
-    takes the Pallas streaming kernel; multi-token chunks (chunked
-    prefill / ``extend``) take the chunk kernel when the shapes tile —
-    O(block) VMEM instead of a dense [Sq, Smax] score tensor; remaining
-    shapes use the dense reference.
+    ``pos``: scalar, or a per-row [B] vector for ragged decode.
+    Single-token decode (Sq=1) takes the Pallas streaming kernel, whose
+    steps are the live blocks of the live rows and nothing else
+    (``decode_sweep``): its time and its bytes follow the live context,
+    not ``B x Smax``.  ``active`` ([B] bool, default all rows) names the
+    live rows of a slot batch — ``pos`` cannot, a row at ``pos`` 0 sees one
+    key — and a dead row costs no step, streams nothing and returns zeros.
+    ``sweep`` is ``decode_sweep`` of the same ``pos``, ``active`` and
+    ``window``, built by a caller that makes this call once per layer;
+    left out, it is built here.  Multi-token chunks (chunked prefill /
+    ``extend``) take the chunk kernel when the shapes tile — O(block) VMEM
+    instead of a dense [Sq, Smax] score tensor; remaining shapes use the
+    dense reference.
 
     With ``k_scale``/``v_scale`` ([B,Smax,H,1] fp32; stacked [L,B,Smax,H])
     the cache holds int8 codes; the decode kernel streams the codes
@@ -412,13 +518,13 @@ def cached_attention(q, cache_k, cache_v, pos,
 
     ``window`` (scalar, possibly traced — GPT-Neo's alternating stack
     carries it through a layer scan) bands visibility to the trailing
-    ``window`` slots: ``pos``/``window`` feed the k/v index maps, which
-    clamp dead block indices into each row's live range, so out-of-band
-    blocks are neither computed (``pl.when``) nor re-DMA'd — banded decode
-    streams O(window) HBM bytes per step instead of O(Smax), and short
-    rows of a ragged batch stop at their own frontier.  ``slopes`` ([H]
-    fp32) adds the ALiBi ``-slope·dist`` bias (BLOOM family) inside the
-    kernel.  Both compose with the int8 cache.
+    ``window`` slots: a row's live blocks run from its band's start to its
+    frontier, so banded decode streams O(window) HBM bytes per step
+    instead of O(Smax), and short rows of a ragged batch stop at their own
+    frontier (the chunk kernel clamps dead block indices into the live
+    range instead: no bytes, but a step each).  ``slopes`` ([H] fp32) adds
+    the ALiBi ``-slope·dist`` bias (BLOOM family) inside the kernel.  Both
+    compose with the int8 cache.
     """
     B, Sq, H, D = q.shape
     int8_cache = k_scale is not None
@@ -429,7 +535,7 @@ def cached_attention(q, cache_k, cache_v, pos,
         layer = 0
     Smax = banks[0].shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    block_k = next((b for b in (256, 128) if Smax % b == 0), None)
+    block_k = decode_block_k(Smax, H * D)
     # chunk path: pos may be scalar OR per-row [B] (ragged chunks — the
     # kernel reads its row's frontier from pos_ref[bh // H] everywhere:
     # mask, live range, and DMA clamp); the chunk must tile in the q
@@ -437,12 +543,21 @@ def cached_attention(q, cache_k, cache_v, pos,
     block_q = next((b for b in (256, 128, 8) if Sq % b == 0), None) \
         if Sq > 1 else None
 
+    def dead_rows_zero(o):
+        """The kernel never writes a dead row's result; the dense path
+        computes one nobody may lean on."""
+        if active is None:
+            return o
+        return jnp.where(active[:, None, None, None], o, jnp.zeros_like(o))
+
     if use_pallas() and block_k is not None and Sq == 1:
         ks, vs = banks[2:] if int8_cache else (None, None)
+        if sweep is None:
+            sweep = decode_sweep(pos, B, Smax, block_k, active, window)
         o = _decode(q.reshape(B, 1, H * D), banks[0], banks[1], layer, pos,
-                    scale, block_k, H, ks=ks, vs=vs, window=window,
+                    sweep, scale, block_k, H, ks=ks, vs=vs, window=window,
                     slopes=slopes)
-        return o.reshape(B, 1, H, D)
+        return dead_rows_zero(o.reshape(B, 1, H, D))
 
     # one layer, heads unfolded: [B,Smax,H,D] (scales [B,Smax,H,1])
     banks = [jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
@@ -460,5 +575,6 @@ def cached_attention(q, cache_k, cache_v, pos,
     if int8_cache:
         banks = [dequantize_kv(banks[0], banks[2], q.dtype),
                  dequantize_kv(banks[1], banks[3], q.dtype)]
-    return cached_attention_reference(q, banks[0], banks[1], pos, scale,
-                                      window=window, slopes=slopes)
+    o = cached_attention_reference(q, banks[0], banks[1], pos, scale,
+                                   window=window, slopes=slopes)
+    return dead_rows_zero(o) if Sq == 1 else o

@@ -40,8 +40,9 @@ sizes so tests can assert exactly that.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -53,6 +54,9 @@ from ..inference.bucketing import bucket_cache_len, bucket_draft_k
 from ..inference.sampling import filter_logits
 from ..inference.speculative import (spec_accept_batch, spec_accept_keys,
                                      spec_draft_keys)
+from ..models import gpt
+from ..ops.pallas.decode_attention import (decode_block_k,
+                                            sweep_block_counts)
 from ..telemetry.spans import SpanName, Tracer
 from ..utils.compile_watch import CompiledProgramRegistry, hot_path
 from .config import ServingConfig
@@ -101,6 +105,15 @@ class SlotBatcher:
                                     kv_dtype=self._kv_dtype)
         #: bytes of the batch-1 cache every fresh prefill allocates
         self._row_cache_bytes = cache_bank_bytes(self.cache) // B
+        #: ``sweep_blocks``'s constants: the decode kernel's block and its
+        #: calls in one tick as (window, layers) pairs, one per distinct
+        #: per-layer window
+        self._block_k = decode_block_k(self.max_len,
+                                       cfg.n_head * cfg.head_dim)
+        windows = gpt.layer_window(cfg, np.arange(cfg.n_layer), self.max_len)
+        self._layer_windows = ((None, cfg.n_layer),) if windows is None \
+            else tuple(collections.Counter(
+                int(w) for w in np.asarray(windows)).items())
         self.lengths = jnp.zeros((B,), jnp.int32)
         self.keys = jnp.stack([jax.random.PRNGKey(0)] * B)
         self.greedy = jnp.ones((B,), bool)
@@ -219,7 +232,7 @@ class SlotBatcher:
                 nxt = jnp.where(greedy, jnp.argmax(lg, -1),
                                 sampled).astype(jnp.int32)
             logits, cache = fam.decode_step(params, nxt, cfg, cache,
-                                            lengths=lengths)
+                                            lengths=lengths, active=active)
             # only live slots advance; a freed slot re-writes its own cell
             new_lengths = jnp.where(active, lengths + 1, lengths)
             return nxt, logits, cache, new_lengths, next_keys
@@ -291,7 +304,7 @@ class SlotBatcher:
             the shrunk-``draft_k`` rung is a distinct program set)."""
 
             def draft_step(dparams, dcache, cur, lengths, keys, greedy,
-                           temp):
+                           temp, active):
                 """K ragged draft decodes per slot from its pending
                 token.  Splits each slot's key chain once per round; the
                 proposal draws fold the draft domain + step index into
@@ -303,7 +316,7 @@ class SlotBatcher:
                 def dstep(carry, j):
                     tok, dc, l = carry
                     lg, dc = dfam.decode_step(dparams, tok, dcfg, dc,
-                                              lengths=l)
+                                              lengths=l, active=active)
                     lg = lg[:, :vocab].astype(jnp.float32)
                     f = filter_logits(lg, temp[:, None], top_k=top_k,
                                       top_p=top_p)
@@ -318,7 +331,8 @@ class SlotBatcher:
                     dstep, (cur, dcache, lengths), jnp.arange(K))
                 # feed d_K too, so the draft cache covers a full acceptance
                 _, dcache = dfam.decode_step(dparams, last_d, dcfg, dcache,
-                                             lengths=lengths + K)
+                                             lengths=lengths + K,
+                                             active=active)
                 return drafts, d_probs, dcache, next_keys, round_keys
 
             def verify_extend(params, cache, cur, drafts, lengths):
@@ -365,7 +379,7 @@ class SlotBatcher:
             can carry the chain (bitwise the same greedy chain; sampled
             rows keep drawing from the exact target distribution)."""
             logits, cache = fam.decode_step(params, cur, cfg, cache,
-                                            lengths=lengths)
+                                            lengths=lengths, active=active)
             return cur, logits, cache, jnp.where(active, lengths + 1,
                                                  lengths)
 
@@ -599,16 +613,28 @@ class SlotBatcher:
 
     def release(self, row: int) -> None:
         """Retire a slot: it stops advancing (its tick writes re-hit one
-        dead cell) until the next admission overwrites the whole row."""
+        dead cell, and the decode kernel neither steps nor streams for it)
+        until the next admission overwrites the whole row."""
         self.lengths, self.active = self._p["release"](
             self.lengths, self.active, jnp.asarray(row, jnp.int32))
 
     # ---------------------------------------------------------------- tick
 
+    def sweep_blocks(self, frontiers) -> Tuple[int, int]:
+        """``(live, grid)`` cache blocks of one plain tick whose live rows
+        stand at ``frontiers`` (host ints: prompt length + tokens out):
+        what the decode kernel steps over all layers, and what the whole
+        slot grid holds.  Counted from lengths, never read off the
+        device."""
+        return sweep_block_counts(frontiers, self.slots, self.max_len,
+                                  self._block_k, self._layer_windows)
+
     @hot_path
     def tick(self) -> np.ndarray:
         """One continuous-batching decode step for every slot; returns the
-        [B] int32 tokens just emitted (junk in freed slots).  With
+        [B] int32 tokens just emitted (junk in freed slots: the tick hands
+        the kernel its ``active`` mask, so a freed slot's attention is
+        neither stepped nor streamed, only its one cell re-written).  With
         speculation enabled (and not paused by the ladder), one
         draft/verify ROUND instead: returns ``(window [B, k+1], counts
         [B])`` — row ``b`` emitted ``window[b, :counts[b]]`` this tick
@@ -654,7 +680,8 @@ class SlotBatcher:
                 drafts, d_probs, self.draft_cache, next_keys, round_keys \
                     = self._p["draft_step" + sfx](
                         self._dparams, self.draft_cache, self.cur,
-                        self.lengths, self.keys, self.greedy, self.temp)
+                        self.lengths, self.keys, self.greedy, self.temp,
+                        self.active)
                 window, vlg, self.cache = self._p["verify_extend" + sfx](
                     self._engine.params, self.cache, self.cur, drafts,
                     self.lengths)

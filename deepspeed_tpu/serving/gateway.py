@@ -350,6 +350,7 @@ class ServingGateway:
         out = {
             MetricName.SERVE_QUEUE_DEPTH: snap["queue_depth"],
             MetricName.SERVE_OCCUPANCY: snap["slot_occupancy"],
+            MetricName.SERVE_LIVE_BLOCK_SHARE: snap["live_block_share"],
             MetricName.SERVE_TOKENS_PER_S: snap["tokens_per_s"],
             MetricName.SERVE_TTFT_S: self.metrics.ttft.snapshot(),
         }
@@ -809,6 +810,12 @@ class ServingGateway:
         n_live = len(live)
         harvested = 0
         accepted = 0
+        # the decode kernel's blocks, from where each live row's token of
+        # this tick was decoded (a speculative round's target pass is the
+        # chunk kernel's: nothing to count)
+        kv_blocks = self._batcher.sweep_blocks(
+            [req.frontier + len(req.out) for _, req in live]) \
+            if counts is None else (0, 0)
         for row, req in live:
             h = req.handle
             if h.cancel_requested:
@@ -861,7 +868,7 @@ class ServingGateway:
                         f"{req.rid} deadline passed mid-decode",
                         partial=np.asarray(req.out, np.int32)))
         self.metrics.record_tick(active=n_live, slots=self.config.slots,
-                                 tokens=harvested)
+                                 tokens=harvested, kv_blocks=kv_blocks)
         round_k = self._batcher.round_draft_k
         if counts is not None and n_live:
             proposed = n_live * max(1, round_k)

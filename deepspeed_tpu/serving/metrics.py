@@ -47,6 +47,11 @@ class ServingMetrics:
         self.tokens_out = 0
         self.active_slot_ticks = 0   # sum over ticks of active slots
         self.slot_ticks = 0          # sum over ticks of total slots
+        #: cache blocks the decode kernel stepped (the live blocks of the
+        #: live rows, all layers) and the blocks of the whole slot grid,
+        #: summed over plain ticks from the lengths the gateway holds
+        self.kv_blocks_live = 0
+        self.kv_blocks_grid = 0
         #: post-warmup compiles observed by the gateway's CompileWatch —
         #: nonzero means the zero-recompile serving contract regressed
         self.recompiles = 0
@@ -105,12 +110,17 @@ class ServingMetrics:
         with self._lock:
             setattr(self, field, max(getattr(self, field), value))
 
-    def record_tick(self, active: int, slots: int, tokens: int) -> None:
+    def record_tick(self, active: int, slots: int, tokens: int,
+                    kv_blocks=(0, 0)) -> None:
+        """``kv_blocks``: the tick's ``(live, grid)`` cache blocks
+        (``SlotBatcher.sweep_blocks``)."""
         with self._lock:
             self.ticks += 1
             self.tokens_out += tokens
             self.active_slot_ticks += active
             self.slot_ticks += slots
+            self.kv_blocks_live += kv_blocks[0]
+            self.kv_blocks_grid += kv_blocks[1]
 
     def record_spec_round(self, accepted: int, proposed: int,
                           emitted: int) -> None:
@@ -172,6 +182,14 @@ class ServingMetrics:
                 "tokens_per_s": self.tokens_out / elapsed,
                 "slot_occupancy": (self.active_slot_ticks / self.slot_ticks
                                    if self.slot_ticks else 0.0),
+                "kv_blocks_live": self.kv_blocks_live,
+                "kv_blocks_grid": self.kv_blocks_grid,
+                # the share of the slot grid's cache blocks the decode
+                # kernel stepped; one minus it is what a sweep of the
+                # whole grid would have stepped for nothing
+                "live_block_share": (self.kv_blocks_live
+                                     / self.kv_blocks_grid
+                                     if self.kv_blocks_grid else 0.0),
             }
         snap["ttft_s"] = self.ttft.values()
         snap["readmit_s"] = self.readmit.values()

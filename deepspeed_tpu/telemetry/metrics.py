@@ -76,6 +76,9 @@ class MetricName:
     SERVE_QUEUE_DEPTH = "serve.queue_depth"
     #: lifetime mean slot occupancy (active slot-ticks / slot-ticks)
     SERVE_OCCUPANCY = "serve.occupancy"
+    #: lifetime share of the slot grid's cache blocks that were live, i.e.
+    #: that the decode kernel stepped (live blocks / grid blocks)
+    SERVE_LIVE_BLOCK_SHARE = "serve.live_block_share"
     #: histogram of time-to-first-token seconds
     SERVE_TTFT_S = "serve.ttft_s"
     #: decode tokens emitted per second over the gateway lifetime

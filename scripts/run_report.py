@@ -45,6 +45,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 # ----------------------------------------------------------------- report
+#: the ``serve.*`` gauges of a metrics row that the serving line prints
+_SERVING_FIELDS = ("queue_depth", "occupancy", "live_block_share",
+                   "tokens_per_s")
+
+
 def report(args) -> int:
     from deepspeed_tpu.runtime.supervision.events import (ABORT_KINDS,
                                                           read_events)
@@ -99,6 +104,11 @@ def report(args) -> int:
             "host_rss_bytes": m.get("mem.host_rss_bytes"),
             "rollbacks": m.get("elastic.rollbacks"),
         }
+        # a gateway's gauges, where one streamed through this sampler
+        serving = {field: m[f"serve.{field}"] for field in _SERVING_FIELDS
+                   if f"serve.{field}" in m}
+        if serving:
+            ranks[os.path.basename(p)]["serving"] = serving
     out["metrics"] = ranks
 
     # fleet telemetry ----------------------------------------------------
@@ -143,6 +153,10 @@ def report(args) -> int:
                   f"{r['last_step']}, step p50 "
                   f"{p50 if p50 is None else round(p50, 4)}s, "
                   f"mfu {r['mfu']}")
+            if "serving" in r:
+                print("    serving: " + ", ".join(
+                    f"{field} {round(value, 4)}"
+                    for field, value in r["serving"].items()))
         if "fleet" in out:
             ch = out["fleet"]["chain"]
             print(f"  fleet: span-chain coverage {ch['coverage']} "

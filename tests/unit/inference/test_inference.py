@@ -29,7 +29,17 @@ def pallas_interpret(monkeypatch):
     yield
 
 
-def test_cached_attention_kernel_matches_reference(pallas_interpret):
+@pytest.mark.parametrize("active", [None, (True, False), (False, True),
+                                    (False, False)],
+                         ids=["no-mask", "second-dead", "first-dead",
+                              "all-dead"])
+@pytest.mark.parametrize("pos", [0, 5, 130, 255])
+def test_cached_attention_kernel_matches_reference(pallas_interpret, pos,
+                                                   active):
+    """A per-layer [B,Smax,H,D] cache (a pool of one layer), scalar ``pos``
+    on both sides of the 128-token block edge; with the slot batch's
+    ``active`` mask a dead row returns zeros and a live row what it returned
+    without the mask, bit for bit."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         cached_attention, cached_attention_reference)
     B, H, D, Smax = 2, 2, 32, 256
@@ -37,11 +47,16 @@ def test_cached_attention_kernel_matches_reference(pallas_interpret):
     q = jax.random.normal(ks[0], (B, 1, H, D), jnp.float32)
     ck = jax.random.normal(ks[1], (B, Smax, H, D), jnp.float32)
     cv = jax.random.normal(ks[2], (B, Smax, H, D), jnp.float32)
-    for pos in (0, 5, 130, 255):
-        out = cached_attention(q, ck, cv, jnp.asarray(pos))
-        ref = cached_attention_reference(q, ck, cv, jnp.asarray(pos))
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5, err_msg=f"pos={pos}")
+    unmasked = np.asarray(cached_attention(q, ck, cv, jnp.asarray(pos)))
+    ref = cached_attention_reference(q, ck, cv, jnp.asarray(pos))
+    np.testing.assert_allclose(unmasked, np.asarray(ref), atol=2e-5,
+                               rtol=2e-5)
+    if active is not None:
+        live = np.asarray(active)
+        out = np.asarray(cached_attention(q, ck, cv, jnp.asarray(pos),
+                                          active=jnp.asarray(live)))
+        np.testing.assert_array_equal(out[live], unmasked[live])
+        assert not out[~live].any()
 
 
 def test_prefill_matches_full_forward():

@@ -336,27 +336,36 @@ pytestmark = _pytest_tier.mark.slow
                                      dict(local_attention_window=32),
                                      dict(local_attention_window=32,
                                           local_attention_alternating=True)])
-def test_streaming_decode_traced_window_under_jit(pallas_interpret, variant):
+@pytest.mark.parametrize("masked", [False, True], ids=["all-live", "masked"])
+def test_streaming_decode_traced_window_under_jit(pallas_interpret, variant,
+                                                  masked):
     """Integration: decode_step through the model stack with the kernels
     ON (interpret mode) — the window arrives as a TRACED per-layer scalar
-    from gpt.layer_window inside the layer scan, and the whole step runs
+    from gpt.layer_window inside the layer scan, each layer's sweep is
+    picked from the lists built once before it, and the whole step runs
     under jit, exercising the scalar-prefetch build end-to-end.  Must
-    match the no-kernel (dense fallback) decode bit-for-bit in fp32."""
+    match the no-kernel (dense fallback) decode in fp32; ``masked`` steps a
+    slot batch whose second row is dead (the first row's logits are then
+    what they were, on either path)."""
     import dataclasses
     import os
     cfg = dataclasses.replace(CFG, **variant)
     params = gpt.init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 12), 0, 256)
+    active = jnp.asarray([True, False]) if masked else None
+    live = slice(0, 1) if masked else slice(None)
 
     def run():
         cache = gpt_inference.init_cache(cfg, 2, 256)
         _, cache = gpt_inference.prefill(params, tokens[:, :8], cfg, cache)
-        step = jax.jit(lambda t, c: gpt_inference.decode_step(
-            params, t, cfg, c))
+        step = jax.jit(lambda t, c, l: gpt_inference.decode_step(
+            params, t, cfg, c, lengths=l, active=active))
         outs = []
         for i in range(8, 12):
-            lg, cache = step(tokens[:, i], cache)
-            outs.append(np.asarray(lg))
+            # all live: the cache's own scalar frontier; masked: per row
+            lg, cache = step(tokens[:, i], cache,
+                             jnp.full((2,), i, jnp.int32) if masked else None)
+            outs.append(np.asarray(lg)[live])
         return np.stack(outs)
 
     with_kernel = run()

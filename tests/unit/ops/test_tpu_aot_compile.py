@@ -114,21 +114,25 @@ def _cache(sh, int8):
     return codes, codes, scale, scale
 
 
-@pytest.mark.parametrize("sq", [1, CHUNK], ids=["decode", "chunk"])
+@pytest.mark.parametrize("sq,masked", [(1, False), (1, True), (CHUNK, False)],
+                         ids=["decode", "decode-masked", "chunk"])
 @pytest.mark.parametrize("int8,per_row_pos",
                          [(False, True), (True, False), (True, True)],
                          ids=["bf16-rowpos", "int8-scalarpos", "int8-rowpos"])
-def test_cached_attention(v5e, sq, int8, per_row_pos):
+def test_cached_attention(v5e, sq, masked, int8, per_row_pos):
+    """``masked``: the tick's ``active`` mask rides in; the decode sweep's
+    grid bound is then (as always on the chip) the live-block count, a
+    dynamic bound that only this lowering accepts."""
     q = jax.ShapeDtypeStruct((SLOTS, sq, H, D), BF16, sharding=v5e)
     pos = jax.ShapeDtypeStruct((SLOTS,) if per_row_pos else (), jnp.int32,
                                sharding=v5e)
+    active = jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=v5e) \
+        if masked else None
     k, v, ks, vs = _cache(v5e, int8)
-    if int8:
-        _compiles_with_kernel(
-            lambda q, k, v, pos, ks, vs: decode.cached_attention(
-                q, k, v, pos, k_scale=ks, v_scale=vs), q, k, v, pos, ks, vs)
-    else:
-        _compiles_with_kernel(decode.cached_attention, q, k, v, pos)
+    _compiles_with_kernel(
+        lambda q, k, v, pos, ks, vs, active: decode.cached_attention(
+            q, k, v, pos, k_scale=ks, v_scale=vs, active=active),
+        q, k, v, pos, ks, vs, active)
 
 
 def _root_opcodes(hlo_text):
@@ -156,11 +160,38 @@ def _root_opcodes(hlo_text):
             for n, op, calls in rows]
 
 
+def _sweep_is_built_outside_the_layer_scan(jaxpr):
+    """The kernel's work list is a function of the tick's inputs alone: its
+    running sum (``decode_sweep``'s ``cumsum``) is an equation of the tick
+    and of no layer's body, where the kernel call itself sits."""
+    def names(jp):
+        return [e.primitive.name for e in jp.eqns]
+
+    def inner(eqn):
+        return [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                if hasattr(getattr(v, "jaxpr", v), "eqns")]
+
+    def deep(jp):
+        return names(jp) + [n for e in jp.eqns for sub in inner(e)
+                            for n in deep(sub)]
+
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1, names(jaxpr)
+    body = deep(inner(scans[0])[0])
+    around = [n for e in jaxpr.eqns if e is not scans[0]
+              for sub in inner(e) for n in deep(sub)]
+    assert "cumsum" in around, around
+    assert "pallas_call" in body
+    assert "cumsum" not in body, "the sweep is rebuilt in every layer"
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 def test_decode_tick_leaves_the_pool_in_place(v5e, int8):
     """The tick's device program at the serving cells' geometry (64 slots x
     1024 tokens, 16 heads of 64; two layers stand for 24): per-row
-    ``decode_step`` on a donated cache.  Nothing but the kernel may touch a
+    ``decode_step`` on a donated cache, told which slots are live.  The
+    kernel's work list is built once, outside the layer scan.  Nothing but
+    the kernel may touch a
     whole layer of the pool: no copy, transpose or slice as large as one
     layer's K, and the pool's inputs are its outputs.  A pool stored with
     64 last (``[L, B, S, H, D]``) fails this: the TPU lays it out with the
@@ -181,10 +212,14 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, int8):
     cache = described(jax.eval_shape(lambda: gpt_inference.init_cache(
         cfg, slots, SMAX, kv_dtype="int8" if int8 else None)))
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
-    compiled = jax.jit(
-        lambda p, c, tok, lengths: gpt_inference.decode_step(
-            p, tok, cfg, c, lengths=lengths),
-        donate_argnums=(1,)).lower(params, cache, rows, rows).compile()
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
+    tick = jax.jit(
+        lambda p, c, tok, lengths, active: gpt_inference.decode_step(
+            p, tok, cfg, c, lengths=lengths, active=active),
+        donate_argnums=(1,))
+    _sweep_is_built_outside_the_layer_scan(
+        tick.trace(params, cache, rows, rows, live).jaxpr)
+    compiled = tick.lower(params, cache, rows, rows, live).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the tick"
     layer_k = slots * SMAX * cfg.n_head * cfg.head_dim

@@ -217,3 +217,53 @@ def test_batcher_overflow_and_tick_guards():
     with pytest.raises(ValueError, match="overflows"):
         bat.admit(0, np.zeros(20, np.int32), jax.random.PRNGKey(0), True,
                   1.0)
+
+
+def test_live_block_share_counts_the_live_blocks_of_the_live_rows():
+    """``ServingMetrics.live_block_share`` by hand: a 4-slot pool of
+    512-token slots, 4 heads of 32 (block_k 256, two blocks a slot), two
+    layers of which the second is banded to 64 tokens; three live rows and a
+    freed slot, which counts in the grid and never in the live blocks."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (decode_block_k,
+                                                           sweep_block_counts)
+    from deepspeed_tpu.serving.metrics import ServingMetrics
+    assert decode_block_k(512, 4 * 32) == 256
+    windows = ((None, 1), (64, 1))
+    # frontiers 100 | 300 | 511: the global layer steps 1 + 2 + 2 blocks, the
+    # banded one 1 + 2 (the band [237, 300] straddles the edge) + 1
+    live, grid = sweep_block_counts([100, 300, 511], 4, 512, 256, windows)
+    assert (live, grid) == (5 + 4, 2 * 4 * 2)
+    m = ServingMetrics()
+    assert m.snapshot()["live_block_share"] == 0.0
+    m.record_tick(active=3, slots=4, tokens=3, kv_blocks=(live, grid))
+    # next tick: the row at 511 has finished and freed its slot
+    m.record_tick(active=2, slots=4, tokens=2,
+                  kv_blocks=sweep_block_counts([101, 301], 4, 512, 256,
+                                               windows))
+    snap = m.snapshot()
+    assert (snap["kv_blocks_live"], snap["kv_blocks_grid"]) == (9 + 6, 32)
+    assert snap["live_block_share"] == 15 / 32
+    # a cache that does not tile runs no kernel: nothing to count
+    assert sweep_block_counts([10], 4, 96, decode_block_k(96, 128)) == (0, 0)
+
+
+def test_sweep_block_counts_agree_with_the_device_sweep(monkeypatch):
+    """The device list, rows dead and live, banded and not, is each live
+    row's blocks from its band's start to its frontier, in order, then the
+    last entry repeated; the host's count is its length."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.default_rng(3)
+    for window in (None, 200, 1):
+        pos = rng.integers(0, 1024, 8)
+        active = rng.random(8) < 0.6
+        rows, blocks, n = da.decode_sweep(
+            jnp.asarray(pos), 8, 1024, 256, jnp.asarray(active), window)
+        live, grid = da.sweep_block_counts(
+            [int(p) for p in pos[active]], 8, 1024, 256, ((window, 1),))
+        assert int(n[0]) == live and rows.shape == blocks.shape == (grid,)
+        want = [(b, k) for b in range(8) if active[b]
+                for k in range(max((pos[b] - (window or 1024) + 1) // 256, 0),
+                               pos[b] // 256 + 1)]
+        want += want[-1:] * (grid - live)
+        assert list(zip(rows.tolist(), blocks.tolist())) == want

@@ -5,6 +5,7 @@ samples into a parseable ``metrics.jsonl``, export a schema-valid
 Perfetto trace, and pass ``scripts/run_report.py`` report mode."""
 
 import importlib.util
+import json
 import os
 
 import numpy as np
@@ -178,3 +179,30 @@ def test_report_mode_flags_missing_rank_metrics(tmp_path):
     mod = _run_report()
     assert mod.main([run_dir, "--expect-rank-metrics", "1"]) == 0
     assert mod.main([run_dir, "--expect-rank-metrics", "2"]) == 1
+
+
+def test_report_mode_prints_the_serving_line(tmp_path, capsys):
+    """A gateway's gauges streamed through a sampler reach the report:
+    ``live_block_share`` (the decode kernel's live share of the slot grid)
+    beside occupancy and tokens/s."""
+    from deepspeed_tpu.serving.metrics import ServingMetrics
+    from deepspeed_tpu.telemetry.metrics import (MetricName, MetricsRegistry,
+                                                 MetricsSampler)
+    m = ServingMetrics()
+    m.record_tick(active=3, slots=4, tokens=3, kv_blocks=(9, 16))
+    snap = m.snapshot(queue_depth=2)
+    sampler = MetricsSampler(MetricsRegistry(),
+                             str(tmp_path / "metrics.jsonl"))
+    sampler.attach_source(lambda: {
+        MetricName.SERVE_QUEUE_DEPTH: snap["queue_depth"],
+        MetricName.SERVE_OCCUPANCY: snap["slot_occupancy"],
+        MetricName.SERVE_LIVE_BLOCK_SHARE: snap["live_block_share"]})
+    sampler.start()
+    mod = _run_report()
+    assert mod.main([str(tmp_path)]) == 0
+    assert "serving: queue_depth 2, occupancy 0.75, live_block_share " \
+        "0.5625" in capsys.readouterr().out
+    assert mod.main([str(tmp_path), "--json"]) == 0
+    report = json.loads(capsys.readouterr().out)
+    assert report["metrics"]["metrics.jsonl"]["serving"][
+        "live_block_share"] == 9 / 16
