@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models import gpt, gpt_inference
+from ..models import cache_family, gpt, gpt_inference
 
 PyTree = Any
 
@@ -148,13 +148,9 @@ def speculative_generate(target_params: PyTree, target_cfg: gpt.GPTConfig,
         raise ValueError("draft and target must share a vocabulary "
                          f"({draft_cfg.vocab_size} vs {target_cfg.vocab_size})")
     from .engine import _tile_cache_len
-    from ..models.gpt_moe import GPTMoEConfig
     # family dispatch: the TARGET may be MoE (verify rides its extend);
     # the draft stays dense (a draft's whole point is being small)
-    if isinstance(target_cfg, GPTMoEConfig):
-        from ..models import gpt_moe_inference as tfam
-    else:
-        tfam = gpt_inference
+    tfam = cache_family(target_cfg)
     t_cache_kw = {"kv_dtype": kv_dtype}
     N, K = int(max_new_tokens), int(draft_k)
     V = target_cfg.vocab_size

@@ -12,7 +12,9 @@ the reference (``inference/engine.py:464``), played instead by jit tracing.
 Architecture variants ride the shared ``models/gpt.py`` helpers, so every
 injected family (GPT-2 learned positions, OPT relu+offset, BLOOM alibi,
 NeoX rotary + parallel residual, untied heads) decodes through this one
-implementation.
+implementation — and so does GPT-MoE (``gpt_moe_inference``), which brings
+only its own scan step: the cache class, the layer scan and the slot ops
+below are the one cache family of the tree.
 
 Cache layout [L, B, S_max, H*D]: static shapes (XLA requirement), masked by
 the current length; decode attention reads the cache tiled over S_max with
@@ -145,8 +147,18 @@ def _block_tail(x, attn, p, config: gpt.GPTConfig):
     return gpt.mlp_residual(x + attn_out, p, config)
 
 
+def dense_step(params: PyTree, config: gpt.GPTConfig):
+    """The dense stack's half of :func:`_layer_scan`: ``(stacks, body)``.
+    One scan step is one block: attention at layer ``i``, then its tail."""
+    def body(x, p, i, attend, banks):
+        a, banks = attend(x, p, i, banks)
+        return _block_tail(x, a, p, config), banks
+
+    return params["blocks"], body
+
+
 def _layer_scan(x, params, cache: KVCache, config: gpt.GPTConfig, positions,
-                write, attn):
+                write, attn, step=dense_step):
     """The one layer-stack scan every cache-filling path shares.
 
     The stacked banks ride the scan's CARRY, never its ``xs``/``ys``: no
@@ -157,8 +169,15 @@ def _layer_scan(x, params, cache: KVCache, config: gpt.GPTConfig, positions,
     the same ``write``.  ``attn(q, k, v, ck, cv, ksc, vsc, layer)``
     computes the sublayer's attention (prefill reads the fresh unpadded
     k/v; extend/decode read layer ``layer`` of the updated stacks ``ck``/
-    ``cv`` where it lies).  Returns (hidden states, updated KVCache with
-    the caller-provided ``length``-less fields filled in).
+    ``cv`` where it lies).
+
+    ``step(params, config)`` is the model family's: the parameter stacks
+    the scan walks and ``body(x, p, i, attend, banks) -> (x, banks)``, what
+    scan step ``i`` does with ``attend(x, p, layer, banks) -> (attention
+    output, banks)`` (:func:`dense_step`: one block a step; GPT-MoE: a
+    dense and an expert block, layers ``2i`` and ``2i+1`` of the same
+    pool).  Returns (hidden states, updated KVCache with the
+    caller-provided ``length``-less fields filled in).
     """
     int8 = cache.int8
     if int8:
@@ -168,9 +187,8 @@ def _layer_scan(x, params, cache: KVCache, config: gpt.GPTConfig, positions,
         """[B, S, H, *] → [B, S, H * *]: a token's heads as one row."""
         return t.reshape(t.shape[:2] + (-1,))
 
-    def layer(carry, xs):
-        x, ck, cv, ksc, vsc = carry
-        p, idx = xs
+    def attend(x, p, idx, banks):
+        ck, cv, ksc, vsc = banks
         q, k, v = gpt.qkv_proj(x, p, config, positions=positions)
         # the scopes name, in a profiler's trace, the two places a tick
         # touches the slot cache
@@ -186,22 +204,31 @@ def _layer_scan(x, params, cache: KVCache, config: gpt.GPTConfig, positions,
                 cv = write(cv, idx, fold(v.astype(cv.dtype)))
         with jax.named_scope("cache_read"):
             a = attn(q, k, v, ck, cv, ksc, vsc, idx)
-        return (_block_tail(x, a, p, config), ck, cv, ksc, vsc), None
+        return a, (ck, cv, ksc, vsc)
 
-    (x, new_k, new_v, new_ksc, new_vsc), _ = lax.scan(
-        layer, (x, cache.k, cache.v, cache.k_scale, cache.v_scale),
-        (params["blocks"], jnp.arange(config.n_layer)))
+    stacks, body = step(params, config)
+
+    def layer(carry, xs):
+        (x, banks), (p, i) = carry, xs
+        return body(x, p, i, attend, banks), None
+
+    n_steps = jax.tree_util.tree_leaves(stacks)[0].shape[0]
+    (x, (new_k, new_v, new_ksc, new_vsc)), _ = lax.scan(
+        layer, (x, (cache.k, cache.v, cache.k_scale, cache.v_scale)),
+        (stacks, jnp.arange(n_steps)))
     return x, dataclasses.replace(cache, k=new_k, v=new_v,
                                   k_scale=new_ksc, v_scale=new_vsc)
 
 
 def prefill(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
-            cache: KVCache) -> Tuple[jnp.ndarray, KVCache]:
+            cache: KVCache, step=dense_step) -> Tuple[jnp.ndarray, KVCache]:
     """Run the prompt through the model, filling cache[0:S].
 
     Returns (logits [B, S, padded_vocab] fp32, cache).  Assumes an empty
     cache (length 0) — chunked prefill composes by calling with growing
-    ``cache.length`` via :func:`extend`.
+    ``cache.length`` via :func:`extend`.  ``step`` (here, in ``extend``
+    and in ``decode_step``) is the model family's scan step, see
+    :func:`_layer_scan`.
     """
     B, S = tokens.shape
     positions = jnp.arange(S)
@@ -216,14 +243,16 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
         return gpt._attention(q, k, v, config,
                               window=gpt.layer_window(config, idx, S))
 
-    x, cache = _layer_scan(x, params, cache, config, positions, write, attn)
+    x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
+                           step)
     logits = gpt.lm_logits(params, x, config)
     return logits, dataclasses.replace(cache,
                                        length=jnp.asarray(S, jnp.int32))
 
 
 def extend(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
-           cache: KVCache, lengths=None) -> Tuple[jnp.ndarray, KVCache]:
+           cache: KVCache, lengths=None,
+           step=dense_step) -> Tuple[jnp.ndarray, KVCache]:
     """Chunked prefill: append ``tokens`` [B, S_c] at positions
     ``cache.length .. cache.length+S_c-1``, attending causally over the
     cached prefix + the chunk.
@@ -280,7 +309,8 @@ def extend(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
             window=gpt.layer_window(config, idx, cache.max_len),
             k_scale=ksc, v_scale=vsc, layer=idx)
 
-    x, cache = _layer_scan(x, params, cache, config, positions, write, attn)
+    x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
+                           step)
     logits = gpt.lm_logits(params, x, config)
     return logits, dataclasses.replace(cache,
                                        length=jnp.max(pos0) + Sc)
@@ -367,8 +397,8 @@ def _layer_sweeps(pos, B, config: gpt.GPTConfig, max_len, active):
 
 
 def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
-                cache: KVCache, lengths=None,
-                active=None) -> Tuple[jnp.ndarray, KVCache]:
+                cache: KVCache, lengths=None, active=None,
+                step=dense_step) -> Tuple[jnp.ndarray, KVCache]:
     """One-token decode: token [B] int32 at position cache.length — or,
     with ``lengths`` [B], at per-row positions (ragged right-padded
     prompts: each row's token lands on ITS next slot and sees only ITS
@@ -402,7 +432,8 @@ def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
             k_scale=ksc, v_scale=vsc, layer=idx, active=active,
             sweep=sweep_of(idx))
 
-    x, cache = _layer_scan(x, params, cache, config, positions, write, attn)
+    x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
+                           step)
     logits = gpt.lm_logits(params, x[:, 0], config)
     new_len = (jnp.max(pos) + 1) if ragged else pos + 1
     return logits, dataclasses.replace(cache, length=new_len)

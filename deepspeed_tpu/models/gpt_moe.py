@@ -90,8 +90,8 @@ def init(config: GPTMoEConfig, rng: jax.Array) -> PyTree:
     for k in ("wi", "bi", "wo_mlp", "bo_mlp"):
         moe_attn_blocks.pop(k)
 
-    moe_keys = jax.random.split(ke, n_pairs)
-    moe_stack = jax.vmap(lambda k: moe.init(k, dtype=config.param_dtype))(moe_keys)
+    expert_keys = jax.random.split(ke, n_pairs)
+    moe_stack = jax.vmap(lambda k: moe.init(k, dtype=config.param_dtype))(expert_keys)
 
     from .gpt import init as gpt_init
     outer = gpt_init(_as_gpt_config(config, 1), kt)

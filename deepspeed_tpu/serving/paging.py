@@ -164,10 +164,10 @@ def pad_table(table: List[int], max_blocks: int) -> np.ndarray:
 
 
 def _is_bank(leaf) -> bool:
-    """KV banks (k/v and their scale banks) lead with ``[L, B, S]``: the
-    dense family's ``[L, B, S, H*D]`` / ``[L, B, S, H]``, the MoE
-    family's ``[L, B, S, H, D-or-1]``; the ``length`` scalar is rank-0.
-    Nothing here reads past the third dimension."""
+    """KV banks (k/v ``[L, B, S, H*D]`` and their scale banks ``[L, B, S,
+    H]``, one layout for every model family) lead with ``[L, B, S]``; the
+    ``length`` scalar is rank-0.  Nothing here reads past the third
+    dimension."""
     return getattr(leaf, "ndim", 0) >= 4
 
 

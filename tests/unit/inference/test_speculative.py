@@ -328,8 +328,8 @@ def test_moe_extend_composes_with_prefill():
                                np.asarray(full_logits[:, 16:]),
                                rtol=2e-5, atol=2e-5)
     assert int(ext_cache.length) == int(full_cache.length) == 24
-    np.testing.assert_allclose(np.asarray(ext_cache.moe_k[:, :, :24]),
-                               np.asarray(full_cache.moe_k[:, :, :24]),
+    np.testing.assert_allclose(np.asarray(ext_cache.k[:, :, :24]),
+                               np.asarray(full_cache.k[:, :, :24]),
                                rtol=2e-5, atol=2e-5)
 
 

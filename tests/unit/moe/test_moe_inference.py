@@ -181,8 +181,8 @@ def test_moe_long_prompt_prefill_chunks_match_single_shot(monkeypatch):
     np.testing.assert_allclose(np.asarray(chunked), np.asarray(single),
                                rtol=2e-5, atol=2e-5)
     assert int(c1.length) == int(c2.length) == 150
-    np.testing.assert_allclose(np.asarray(c1.moe_k[:, :, :150]),
-                               np.asarray(c2.moe_k[:, :, :150]),
+    np.testing.assert_allclose(np.asarray(c1.k[:, :, :150]),
+                               np.asarray(c2.k[:, :, :150]),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -200,8 +200,8 @@ def test_moe_int8_cache_decode_tracks_fp_cache():
     tokens = jnp.asarray(rng.integers(0, 128, size=(2, 12)), jnp.int32)
     c_fp = gpt_moe_inference.init_cache(CFG, 2, 32)
     c_q = gpt_moe_inference.init_cache(CFG, 2, 32, kv_dtype="int8")
-    assert c_q.int8 and c_q.moe_k.dtype == jnp.int8
-    assert c_q.moe_k_scale.shape == (CFG.n_pairs, 2, 32, CFG.n_head, 1)
+    assert c_q.int8 and c_q.k.dtype == jnp.int8
+    assert c_q.k_scale.shape == (CFG.n_layer, 2, 32, CFG.n_head)
 
     lg_fp, c_fp = gpt_moe_inference.prefill(params, tokens[:, :8], CFG, c_fp)
     lg_q, c_q = gpt_moe_inference.prefill(params, tokens[:, :8], CFG, c_q)

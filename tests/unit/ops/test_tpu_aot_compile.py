@@ -162,10 +162,14 @@ def _root_opcodes(hlo_text):
 
 def _sweep_is_built_outside_the_layer_scan(jaxpr):
     """The kernel's work list is a function of the tick's inputs alone: its
-    running sum (``decode_sweep``'s ``cumsum``) is an equation of the tick
-    and of no layer's body, where the kernel call itself sits."""
+    running sum (``decode_sweep``'s ``cumsum`` over the rows' block counts,
+    rank 1; an MoE gate's running sums over ``[tokens, experts]`` are not
+    it) is an equation of the tick and of no layer's body, where the kernel
+    call itself sits."""
     def names(jp):
-        return [e.primitive.name for e in jp.eqns]
+        return [e.primitive.name if e.primitive.name != "cumsum"
+                or e.invars[0].aval.ndim != 1 else "sweep_cumsum"
+                for e in jp.eqns]
 
     def inner(eqn):
         return [getattr(v, "jaxpr", v) for v in eqn.params.values()
@@ -180,27 +184,36 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr):
     body = deep(inner(scans[0])[0])
     around = [n for e in jaxpr.eqns if e is not scans[0]
               for sub in inner(e) for n in deep(sub)]
-    assert "cumsum" in around, around
+    assert "sweep_cumsum" in around, around
     assert "pallas_call" in body
-    assert "cumsum" not in body, "the sweep is rebuilt in every layer"
+    assert "sweep_cumsum" not in body, "the sweep is rebuilt in every layer"
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_decode_tick_leaves_the_pool_in_place(v5e, int8):
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     """The tick's device program at the serving cells' geometry (64 slots x
-    1024 tokens, 16 heads of 64; two layers stand for 24): per-row
-    ``decode_step`` on a donated cache, told which slots are live.  The
-    kernel's work list is built once, outside the layer scan.  Nothing but
-    the kernel may touch a
+    1024 tokens, 16 heads of 64; two layers stand for 24), for both model
+    families (GPT-MoE: one (dense, expert) pair of four experts at the same
+    widths): per-row ``decode_step`` on a donated cache, told which slots
+    are live.  The kernel's work list is built once, outside the layer
+    scan.  Nothing but the kernel may touch a
     whole layer of the pool: no copy, transpose or slice as large as one
     layer's K, and the pool's inputs are its outputs.  A pool stored with
     64 last (``[L, B, S, H, D]``) fails this: the TPU lays it out with the
     tokens on the lanes and re-lays it around every write and kernel call."""
     import dataclasses
 
-    from deepspeed_tpu.models import gpt, gpt_inference
+    from deepspeed_tpu.models import cache_family, gpt, gpt_moe
     slots, layers = 64, 2
     cfg = dataclasses.replace(gpt.GPT2_350M, n_layer=layers, dtype=BF16)
+    model = gpt
+    if family == "moe":
+        cfg = gpt_moe.GPTMoEConfig(
+            **{f.name: getattr(cfg, f.name)
+               for f in dataclasses.fields(cfg)}, num_experts=4)
+        model = gpt_moe
+    fam = cache_family(cfg)
 
     def described(tree):
         return jax.tree_util.tree_map(
@@ -208,13 +221,13 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, int8):
             tree)
 
     params = described(jax.eval_shape(
-        lambda: gpt.init(cfg, jax.random.PRNGKey(0))))
-    cache = described(jax.eval_shape(lambda: gpt_inference.init_cache(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(lambda: fam.init_cache(
         cfg, slots, SMAX, kv_dtype="int8" if int8 else None)))
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
     live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
     tick = jax.jit(
-        lambda p, c, tok, lengths, active: gpt_inference.decode_step(
+        lambda p, c, tok, lengths, active: fam.decode_step(
             p, tok, cfg, c, lengths=lengths, active=active),
         donate_argnums=(1,))
     _sweep_is_built_outside_the_layer_scan(

@@ -42,7 +42,12 @@ def test_compressed_allreduce_error_feedback_converges():
     acc = {k: jnp.zeros_like(v) for k, v in x.items()}
     mean_errs = {}
     for t in range(1, 41):
-        out, we, se = fn(x, we, se)
+        # one round in flight at a time: XLA:CPU's in-process all-to-all
+        # needs all eight participants on a pool thread at once, and with
+        # 32 unfenced rounds queued on a busy host the eighth of some round
+        # never gets one (rendezvous: "only 7 of them arrived", the worker
+        # aborts after 40 s)
+        out, we, se = jax.block_until_ready(fn(x, we, se))
         acc = {k: acc[k] + out[k] for k in x}
         if t in (8, 40):
             mean_errs[t] = max(float(jnp.max(jnp.abs(acc[k] / t - x[k])))
@@ -51,7 +56,6 @@ def test_compressed_allreduce_error_feedback_converges():
     # telescoping); sign compression with one global scale converges slowly
     # on heavy-tailed inputs, so assert monotone improvement, not a bound
     assert mean_errs[40] < 0.75 * mean_errs[8], mean_errs
-    reset_mesh_manager()
 
 
 def test_onebit_adam_warmup_matches_adam():

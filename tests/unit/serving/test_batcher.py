@@ -25,32 +25,66 @@ def _engine(**kw):
 
 # ------------------------------------------------------------- slot ops
 
-def test_write_read_reset_slot_dense():
+MOE_CFG = gpt_moe.GPTMoEConfig(vocab_size=128, max_seq_len=64, n_layer=2,
+                               n_head=2, d_model=32, dtype=jnp.float32,
+                               vocab_round_to=128, num_experts=2)
+
+
+FAMILY_NAMES = ("init_cache", "prefill", "extend", "decode_step",
+                "write_slot", "read_slot", "reset_slot")
+
+
+def test_cache_family_picks_by_config():
+    """One helper maps a config to its cache family; both families expose
+    the seven names the engine, batcher, pager and fleet call, and the
+    cache class and slot ops are the same objects in both."""
+    from deepspeed_tpu.models import cache_family
+    assert cache_family(CFG) is gpt_inference
+    assert cache_family(MOE_CFG) is gpt_moe_inference
+    for fam in (gpt_inference, gpt_moe_inference):
+        assert all(callable(getattr(fam, n)) for n in FAMILY_NAMES), fam
+    for name in ("init_cache", "write_slot", "read_slot", "reset_slot"):
+        assert getattr(gpt_moe_inference, name) is getattr(gpt_inference,
+                                                           name)
+    eng = _engine()
+    assert eng._family is gpt_inference
+
+
+@pytest.mark.parametrize("fam, mod, cfg", [
+    pytest.param(gpt_inference, gpt, CFG, id="dense"),
+    pytest.param(gpt_moe_inference, gpt_moe, MOE_CFG, id="moe")])
+def test_write_read_reset_slot(fam, mod, cfg):
     """write_slot inserts a batch-1 cache at one row and ONLY that row;
-    read_slot round-trips it; reset_slot zeroes it."""
-    params = gpt.init(CFG, jax.random.PRNGKey(0))
-    big = gpt_inference.init_cache(CFG, 3, 32)
-    t = jax.random.randint(jax.random.PRNGKey(1), (1, 8), 0, 256)
-    _, small = gpt_inference.prefill(params, t, CFG,
-                                     gpt_inference.init_cache(CFG, 1, 32))
-    big2 = gpt_inference.write_slot(big, jnp.asarray(1), small)
-    np.testing.assert_array_equal(np.asarray(big2.k[:, 1]),
-                                  np.asarray(small.k[:, 0]))
-    # other rows untouched (still zero)
-    assert not np.asarray(big2.k[:, 0]).any()
-    assert not np.asarray(big2.k[:, 2]).any()
-    back = gpt_inference.read_slot(big2, jnp.asarray(1), length=8)
+    read_slot round-trips it; reset_slot zeroes it.  One set of slot ops
+    serves both families: the MoE pool is the dense pool, ``n_layer``
+    deep."""
+    params = mod.init(cfg, jax.random.PRNGKey(0))
+    big = fam.init_cache(cfg, 3, 32)
+    assert big.k.shape == (cfg.n_layer, 3, 32, cfg.n_head * cfg.head_dim)
+    t = jax.random.randint(jax.random.PRNGKey(1), (1, 8), 0, cfg.vocab_size)
+    _, small = fam.prefill(params=params, tokens=t, config=cfg,
+                           cache=fam.init_cache(cfg, 1, 32))
+    # every layer of the stack (dense and MoE sublayers alike) was filled
+    assert np.asarray(small.k[:, 0, :8]).any(axis=(1, 2)).all()
+    big2 = fam.write_slot(big, jnp.asarray(1), small)
+    for bank in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(big2, bank)[:, 1]),
+            np.asarray(getattr(small, bank)[:, 0]), err_msg=bank)
+        # other rows untouched (still zero)
+        assert not np.asarray(getattr(big2, bank)[:, 0]).any()
+        assert not np.asarray(getattr(big2, bank)[:, 2]).any()
+    back = fam.read_slot(big2, jnp.asarray(1), length=8)
     np.testing.assert_array_equal(np.asarray(back.k), np.asarray(small.k))
-    assert int(back.length) == 8
-    wiped = gpt_inference.reset_slot(big2, jnp.asarray(1))
+    assert int(back.length) == 8 and back.batch == 1
+    wiped = fam.reset_slot(big2, jnp.asarray(1))
     assert not np.asarray(wiped.k[:, 1]).any()
+    assert not np.asarray(wiped.v[:, 1]).any()
     # geometry violations are loud
     with pytest.raises(ValueError, match="max_len"):
-        gpt_inference.write_slot(gpt_inference.init_cache(CFG, 3, 16), 0,
-                                 small)
+        fam.write_slot(fam.init_cache(cfg, 3, 16), 0, small)
     with pytest.raises(ValueError, match="int8"):
-        gpt_inference.write_slot(
-            gpt_inference.init_cache(CFG, 3, 32, kv_dtype="int8"), 0, small)
+        fam.write_slot(fam.init_cache(cfg, 3, 32, kv_dtype="int8"), 0, small)
 
 
 def test_write_slot_int8_scales():
@@ -64,28 +98,6 @@ def test_write_slot_int8_scales():
     np.testing.assert_array_equal(np.asarray(big2.k_scale[:, 0]),
                                   np.asarray(small.k_scale[:, 0]))
     assert gpt_inference.read_slot(big2, jnp.asarray(0)).int8
-
-
-def test_write_read_slot_moe_banks():
-    mcfg = gpt_moe.GPTMoEConfig(vocab_size=128, max_seq_len=64, n_layer=2,
-                                n_head=2, d_model=32, dtype=jnp.float32,
-                                vocab_round_to=128, num_experts=2)
-    mparams = gpt_moe.init(mcfg, jax.random.PRNGKey(0))
-    big = gpt_moe_inference.init_cache(mcfg, 2, 32)
-    t = jax.random.randint(jax.random.PRNGKey(3), (1, 5), 0, 128)
-    _, small = gpt_moe_inference.prefill(
-        params=mparams, tokens=t, config=mcfg,
-        cache=gpt_moe_inference.init_cache(mcfg, 1, 32))
-    big2 = gpt_moe_inference.write_slot(big, jnp.asarray(1), small)
-    for bank in ("dense_k", "dense_v", "moe_k", "moe_v"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(big2, bank)[:, 1]),
-            np.asarray(getattr(small, bank)[:, 0]), err_msg=bank)
-        assert not np.asarray(getattr(big2, bank)[:, 0]).any()
-    back = gpt_moe_inference.read_slot(big2, jnp.asarray(1), length=5)
-    assert int(back.length) == 5 and back.batch == 1
-    assert not np.asarray(
-        gpt_moe_inference.reset_slot(big2, jnp.asarray(1)).moe_k[:, 1]).any()
 
 
 # -------------------------------------------------------------- batcher
