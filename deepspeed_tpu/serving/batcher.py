@@ -630,72 +630,89 @@ class SlotBatcher:
                                   self._block_k, self._layer_windows)
 
     @hot_path
-    def tick(self) -> np.ndarray:
-        """One continuous-batching decode step for every slot; returns the
-        [B] int32 tokens just emitted (junk in freed slots: the tick hands
-        the kernel its ``active`` mask, so a freed slot's attention is
-        neither stepped nor streamed, only its one cell re-written).  With
-        speculation enabled (and not paused by the ladder), one
-        draft/verify ROUND instead: returns ``(window [B, k+1], counts
-        [B])`` — row ``b`` emitted ``window[b, :counts[b]]`` this tick
-        (0 in freed slots).  Callers dispatch on the return TYPE (tuple =
-        speculative round), not on config — the spec_pause rung switches
-        a speculative gateway to plain [B] ticks at runtime."""
+    def launch(self):
+        """Dispatch one decode step for every slot and return its tokens
+        UN-PULLED, still on the device: a ``[B]`` int32 array (junk in
+        freed slots: the tick hands the kernel its ``active`` mask, so a
+        freed slot's attention is neither stepped nor streamed, only its
+        one cell re-written), or, with speculation enabled and not paused
+        by the ladder, one draft/verify ROUND's ``(window [B, k+1], counts
+        [B])``.  Everything the next step reads (``cache``, ``lengths``,
+        ``_last``, ``keys``, ``cur``) is rebound here to this step's
+        results, so the next ``launch``, ``admit`` or ``release`` queues
+        behind it on the device and the host need not :meth:`pull` first:
+        the gateway keeps one step in flight that way.  The ``_paused`` /
+        ``spec_level`` transitions happen here, at launch."""
         if self._last is None:
             raise RuntimeError("tick() before any admission")
-        if self.spec:
-            if self.spec_level >= 2:
-                return self._paused_tick()
-            if self._paused:
-                # leaving the pause: re-draw every pending token from the
-                # frontier logits before the next round
-                self.cur, self.keys = self._p["spec_reseed"](
-                    self._last, self.keys, self.greedy, self.temp)
-                self._paused = False
-            return self._spec_tick()
-        with self.tracer.span(SpanName.SERVE_TICK):
-            nxt, logits, self.cache, self.lengths, self.keys = \
-                self._p["tick"](
-                    self._engine.params, self.cache, self.lengths,
-                    self._last, self.keys, self.greedy, self.temp,
-                    self.active)
-            self._last = logits
-            self.registry.note_host_sync("serving.tick")
-            # the emitted tokens ARE the tick's output boundary
-            with self.tracer.span(SpanName.SERVE_PULL):
-                # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
-                return np.asarray(nxt)
+        if not self.spec:
+            return self._plain_launch()
+        if self.spec_level >= 2:
+            return self._paused_launch()
+        if self._paused:
+            # leaving the pause: re-draw every pending token from the
+            # frontier logits before the next round
+            self.cur, self.keys = self._p["spec_reseed"](
+                self._last, self.keys, self.greedy, self.temp)
+            self._paused = False
+        return self._spec_launch()
 
     @hot_path
-    def _spec_tick(self):
+    def pull(self, pending):
+        """The host's wait for what :meth:`launch` returned: ``[B]``
+        tokens, or a round's ``(window, counts)`` — row ``b`` emitted
+        ``window[b, :counts[b]]`` (0 in freed slots).  Callers dispatch on
+        the TYPE (tuple = speculative round), not on config — the
+        spec_pause rung switches a speculative gateway to plain ``[B]``
+        ticks at runtime."""
+        self.registry.note_host_sync("serving.tick")
+        # the emitted tokens ARE the tick's output boundary
+        with self.tracer.span(SpanName.SERVE_PULL):
+            # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
+            return jax.device_get(pending)
+
+    @hot_path
+    def tick(self):
+        """One continuous-batching decode step, synchronous: launch, then
+        pull, in one ``serve.tick`` span.  For callers that want the
+        tokens now (``probe_logits``, the fleet's decode worker, tests);
+        the gateway's loop calls the halves itself."""
+        with self.tracer.span(SpanName.SERVE_TICK):
+            return self.pull(self.launch())
+
+    def _plain_launch(self):
+        nxt, self._last, self.cache, self.lengths, self.keys = \
+            self._p["tick"](
+                self._engine.params, self.cache, self.lengths, self._last,
+                self.keys, self.greedy, self.temp, self.active)
+        return nxt
+
+    def _spec_launch(self):
         """One speculative round for every slot: draft scan → ragged
         verify extend → batched accept/rollback, three chained compiled
-        programs, still one host sync at the output boundary.  At ladder
-        level 1 the round runs the ``draft_k2`` program set instead."""
+        programs and still one host sync, at the pull.  The next round
+        needs only ``cur``, ``lengths`` and ``keys``, all on the device.
+        At ladder level 1 the round runs the ``draft_k2`` program set
+        instead."""
         shrunk = self.spec_level == 1 and self.draft_k2 != self.draft_k
         sfx = "_k2" if shrunk else ""
-        with self.tracer.span(SpanName.SERVE_TICK):
-            with self.tracer.span(SpanName.SERVE_SPEC,
-                                  draft_k=self.round_draft_k):
-                drafts, d_probs, self.draft_cache, next_keys, round_keys \
-                    = self._p["draft_step" + sfx](
-                        self._dparams, self.draft_cache, self.cur,
-                        self.lengths, self.keys, self.greedy, self.temp,
-                        self.active)
-                window, vlg, self.cache = self._p["verify_extend" + sfx](
-                    self._engine.params, self.cache, self.cur, drafts,
-                    self.lengths)
-                adv, self.lengths, self.cur = self._p["spec_accept" + sfx](
-                    vlg, drafts, d_probs, round_keys, self.cur,
-                    self.lengths, self.greedy, self.temp, self.active)
-                self.keys = next_keys
-            self.registry.note_host_sync("serving.tick")
-            with self.tracer.span(SpanName.SERVE_PULL):
-                # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
-                return np.asarray(window), np.asarray(adv)
+        with self.tracer.span(SpanName.SERVE_SPEC,
+                              draft_k=self.round_draft_k):
+            drafts, d_probs, self.draft_cache, next_keys, round_keys \
+                = self._p["draft_step" + sfx](
+                    self._dparams, self.draft_cache, self.cur,
+                    self.lengths, self.keys, self.greedy, self.temp,
+                    self.active)
+            window, vlg, self.cache = self._p["verify_extend" + sfx](
+                self._engine.params, self.cache, self.cur, drafts,
+                self.lengths)
+            adv, self.lengths, self.cur = self._p["spec_accept" + sfx](
+                vlg, drafts, d_probs, round_keys, self.cur,
+                self.lengths, self.greedy, self.temp, self.active)
+            self.keys = next_keys
+        return window, adv
 
-    @hot_path
-    def _paused_tick(self) -> np.ndarray:
+    def _paused_launch(self):
         """One-token ticking while the spec_pause rung is engaged.  The
         first paused tick FLUSHES the pending token (one decode step
         writes its K/V and leaves ``_last`` at the frontier); later ones
@@ -704,21 +721,10 @@ class SlotBatcher:
         draft history that only degrades proposal quality after resume
         (the accept rule stays exact); rows admitted later prefill a
         fresh draft cache and are unaffected."""
-        with self.tracer.span(SpanName.SERVE_TICK):
-            if not self._paused:
-                nxt, self._last, self.cache, self.lengths = \
-                    self._p["spec_flush"](
-                        self._engine.params, self.cache, self.cur,
-                        self.lengths, self.active)
-                self._paused = True
-            else:
-                nxt, logits, self.cache, self.lengths, self.keys = \
-                    self._p["tick"](
-                        self._engine.params, self.cache, self.lengths,
-                        self._last, self.keys, self.greedy, self.temp,
-                        self.active)
-                self._last = logits
-            self.registry.note_host_sync("serving.tick")
-            with self.tracer.span(SpanName.SERVE_PULL):
-                # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
-                return np.asarray(nxt)
+        if self._paused:
+            return self._plain_launch()
+        nxt, self._last, self.cache, self.lengths = self._p["spec_flush"](
+            self._engine.params, self.cache, self.cur, self.lengths,
+            self.active)
+        self._paused = True
+        return nxt

@@ -9,9 +9,17 @@ engine — runs the serve loop:
 2. admit while slots are free: pop the best queued request, prefill its
    prompt (through the LRU prefix pool when it declares a shared prefix)
    into a freed slot;
-3. one continuous-batching decode tick for every live slot; harvest
-   per-slot tokens, finish rows that hit eos / budget / deadline /
-   cancellation, and free their slots for step 2 of the next iteration.
+3. one continuous-batching decode tick for every live slot, with exactly
+   one tick in flight: LAUNCH tick n+1 from the device-resident slot
+   state, then PULL tick n's tokens (the device has been running n+1
+   since) and HARVEST them: finish rows that hit eos / budget / deadline
+   / cancellation, and free their slots for step 2 of the next
+   iteration.  A tick carries the ``row -> request`` snapshot of its
+   launch and its harvest walks that snapshot: a row that finished at
+   harvest n was still live in tick n+1 on the device (its release is one
+   tick late), and tick n+1's token for it is dropped, whoever holds the
+   row by then.  When the last row finishes the tick in flight is pulled
+   before the loop waits; an idle server launches nothing.
 
 Every decision lands in the supervision ``EventJournal`` (``serve.*``
 kinds) and in :class:`ServingMetrics`; the ``serve.request`` /
@@ -26,7 +34,7 @@ import heapq
 import threading
 import time
 from collections import OrderedDict
-from typing import Dict, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -64,6 +72,20 @@ class _PooledPrefix:
         self.length = int(length if entry is None else entry.length)
         self.nbytes = int(nbytes)
         self.last_used = time.monotonic()
+
+
+class _InFlight(NamedTuple):
+    """One launched, un-pulled decode tick."""
+
+    #: what ``SlotBatcher.launch`` returned, still on the device
+    pending: Any
+    #: ``row -> request`` as bound at the launch: the only rows this
+    #: tick's tokens may be handed to
+    rows: List[Tuple[int, ServeRequest]]
+    #: proposals per row of the round (0: a plain tick), at the launch
+    draft_k: int
+    #: launched while the tick before it was un-pulled
+    overlapped: bool
 
 
 class ServingGateway:
@@ -143,6 +165,8 @@ class ServingGateway:
         self._prefixes: "OrderedDict[bytes, _PooledPrefix]" = OrderedDict()
         self._seq = 0
         self._ticks = 0
+        #: the tick in flight (scheduler thread only)
+        self._in_flight: Optional[_InFlight] = None
         self._closed = False
         self._stopped = threading.Event()
         self._base_key = jax.random.PRNGKey(int(config.seed))
@@ -489,7 +513,7 @@ class ServingGateway:
                 self._overload_step()
                 self._admit_ready()
                 self._sweep_prefixes()
-                if self._active:
+                if self._active or self._in_flight is not None:
                     self._decode_tick()
                 else:
                     with self._cond:
@@ -497,9 +521,12 @@ class ServingGateway:
                             break
                         if not self._queue:
                             self._cond.wait(self.config.idle_wait_s)
+            if self._in_flight is not None:
+                self._decode_tick()
         except BaseException as e:  # the loop dying must fail loudly,
             # not leave every caller blocked on a handle forever
             logger.exception(f"[serving] scheduler loop died: {e}")
+            self._discard_in_flight()
             with self._cond:
                 self._closed = True
                 self._fail_pending(RequestFailed(f"scheduler loop died: {e}"))
@@ -784,39 +811,72 @@ class ServingGateway:
             self._pager.sweep(now)
 
     def _decode_tick(self) -> None:
+        """One pass of the decode loop: launch tick n+1, then pull and
+        harvest tick n.  Nothing tick n+1 reads comes from the host, so
+        the pull, the harvest and the loop's next admissions run under
+        the device's work on n+1.  With no row live, or the loop
+        stopping, tick n is only finished."""
         fault_injection.fire("serve.decode_tick", tick=self._ticks,
                              active=len(self._active))
-        # dispatch on the RETURN type, not config: a speculative round is
-        # (window [B, k+1], counts [B]) — row b emitted
-        # window[b, :counts[b]] this tick — while a plain tick (spec off,
-        # or paused by the ladder's spec_pause rung) is a [B] array
-        res = self._batcher.tick()
-        with self.tracer.span(SpanName.SERVE_HARVEST,
-                              live=len(self._active)):
-            self._harvest(res)
+        prev = self._in_flight
+        if prev is None:
+            # a busy period's first tick has no predecessor to pull
+            self._in_flight = self._launch_tick(overlapped=False)
+            return
+        with self.tracer.span(SpanName.SERVE_TICK):
+            # nothing live at the launch: tick n is the busy period's
+            # last, pulled before the loop waits
+            self._in_flight = self._launch_tick(overlapped=True) \
+                if self._active and not self._stopped.is_set() else None
+            res = self._batcher.pull(prev.pending)
+        with self.tracer.span(SpanName.SERVE_HARVEST, live=len(prev.rows)):
+            self._harvest(prev, res)
 
-    def _harvest(self, res) -> None:
-        """Hand one tick's tokens to their requests: append, stamp first
-        tokens, finish rows that hit eos / budget / deadline /
-        cancellation and free their slots."""
+    def _launch_tick(self, overlapped: bool) -> _InFlight:
+        with self._cond:
+            rows = list(self._active.items())
+        return _InFlight(self._batcher.launch(), rows,
+                         self._batcher.round_draft_k, overlapped)
+
+    def _discard_in_flight(self) -> None:
+        """The loop died: wait out the tick in flight and drop its
+        tokens (every request is about to fail)."""
+        prev, self._in_flight = self._in_flight, None
+        if prev is not None:
+            try:
+                jax.block_until_ready(prev.pending)
+            except Exception as e:
+                logger.warning(f"[serving] tick in flight lost: {e}")
+
+    def _harvest(self, tick: _InFlight, res) -> None:
+        """Hand one tick's tokens to the requests bound at its launch:
+        append, stamp first tokens, finish rows that hit eos / budget /
+        deadline / cancellation and free their slots.  A row whose
+        request has finished since the launch ran this tick for nothing
+        (a late row): its token is dropped, whoever holds the row now."""
         if isinstance(res, tuple):
             tokens, counts = res
         else:
             tokens, counts = res, None
         self._ticks += 1
         now = time.monotonic()
+        live = tick.rows
         with self._cond:
-            live = list(self._active.items())
+            late = {row for row, req in live
+                    if self._active.get(row) is not req}
         n_live = len(live)
         harvested = 0
         accepted = 0
-        # the decode kernel's blocks, from where each live row's token of
-        # this tick was decoded (a speculative round's target pass is the
-        # chunk kernel's: nothing to count)
+        # the decode kernel's blocks, from where each row's token of this
+        # tick was decoded, late rows included: what the kernel stepped (a
+        # speculative round's target pass is the chunk kernel's: nothing
+        # to count)
         kv_blocks = self._batcher.sweep_blocks(
             [req.frontier + len(req.out) for _, req in live]) \
             if counts is None else (0, 0)
         for row, req in live:
+            if row in late:
+                continue
             h = req.handle
             if h.cancel_requested:
                 self._finish_row(
@@ -868,10 +928,13 @@ class ServingGateway:
                         f"{req.rid} deadline passed mid-decode",
                         partial=np.asarray(req.out, np.int32)))
         self.metrics.record_tick(active=n_live, slots=self.config.slots,
-                                 tokens=harvested, kv_blocks=kv_blocks)
-        round_k = self._batcher.round_draft_k
-        if counts is not None and n_live:
-            proposed = n_live * max(1, round_k)
+                                 tokens=harvested, kv_blocks=kv_blocks,
+                                 overlapped=tick.overlapped,
+                                 late_rows=len(late))
+        round_k = tick.draft_k
+        n_fed = n_live - len(late)
+        if counts is not None and n_fed:
+            proposed = n_fed * max(1, round_k)
             self.metrics.record_spec_round(accepted=accepted,
                                            proposed=proposed,
                                            emitted=harvested)
@@ -879,13 +942,15 @@ class ServingGateway:
         if every and self._ticks % every == 0:
             with self._cond:
                 depth = len(self._queue)
+            snap = self.metrics.snapshot()
             self._emit(EventKind.SERVE_TICK, tick=self._ticks,
                        active=n_live, queue_depth=depth,
-                       tok_per_s=round(
-                           self.metrics.snapshot()["tokens_per_s"], 3))
-            if counts is not None and n_live:
+                       tok_per_s=round(snap["tokens_per_s"], 3),
+                       overlap_share=round(snap["overlap_share"], 4),
+                       late_row_share=round(snap["late_row_share"], 4))
+            if counts is not None and n_fed:
                 self._emit(EventKind.SERVE_SPEC_ROUND, tick=self._ticks,
-                           active=n_live, draft_k=round_k,
+                           active=n_fed, draft_k=round_k,
                            accepted=accepted, emitted=harvested,
                            accept_rate=round(
                                accepted / max(1, proposed), 4))

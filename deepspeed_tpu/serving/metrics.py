@@ -52,6 +52,14 @@ class ServingMetrics:
         #: summed over plain ticks from the lengths the gateway holds
         self.kv_blocks_live = 0
         self.kv_blocks_grid = 0
+        #: ticks launched while the tick before them was un-pulled (the
+        #: decode loop keeps one in flight; a busy period's first tick
+        #: has no predecessor)
+        self.ticks_overlapped = 0
+        #: row-ticks the device ran for a request that had already
+        #: finished (a row's release is one tick late); they count in
+        #: ``active_slot_ticks``, their tokens nowhere
+        self.late_row_ticks = 0
         #: post-warmup compiles observed by the gateway's CompileWatch —
         #: nonzero means the zero-recompile serving contract regressed
         self.recompiles = 0
@@ -111,8 +119,12 @@ class ServingMetrics:
             setattr(self, field, max(getattr(self, field), value))
 
     def record_tick(self, active: int, slots: int, tokens: int,
-                    kv_blocks=(0, 0)) -> None:
-        """``kv_blocks``: the tick's ``(live, grid)`` cache blocks
+                    kv_blocks=(0, 0), overlapped: bool = False,
+                    late_rows: int = 0) -> None:
+        """``active``: the requests bound at the tick's launch, the
+        ``late_rows`` of them that had finished by its harvest included;
+        ``tokens``: those delivered to a request; ``kv_blocks``: the
+        tick's ``(live, grid)`` cache blocks
         (``SlotBatcher.sweep_blocks``)."""
         with self._lock:
             self.ticks += 1
@@ -121,6 +133,8 @@ class ServingMetrics:
             self.slot_ticks += slots
             self.kv_blocks_live += kv_blocks[0]
             self.kv_blocks_grid += kv_blocks[1]
+            self.ticks_overlapped += bool(overlapped)
+            self.late_row_ticks += late_rows
 
     def record_spec_round(self, accepted: int, proposed: int,
                           emitted: int) -> None:
@@ -190,6 +204,15 @@ class ServingMetrics:
                 "live_block_share": (self.kv_blocks_live
                                      / self.kv_blocks_grid
                                      if self.kv_blocks_grid else 0.0),
+                "ticks_overlapped": self.ticks_overlapped,
+                "late_row_ticks": self.late_row_ticks,
+                # how much of the loop ran with a tick in flight, and
+                # what the one-tick-late release cost the device
+                "overlap_share": (self.ticks_overlapped / self.ticks
+                                  if self.ticks else 0.0),
+                "late_row_share": (self.late_row_ticks
+                                   / self.active_slot_ticks
+                                   if self.active_slot_ticks else 0.0),
             }
         snap["ttft_s"] = self.ttft.values()
         snap["readmit_s"] = self.readmit.values()

@@ -48,10 +48,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 #: the ``serve.*`` gauges of a metrics row that the serving line prints
 _SERVING_FIELDS = ("queue_depth", "occupancy", "live_block_share",
                    "tokens_per_s")
+#: and, beside them, the decode loop's pipelining shares, which the gateway
+#: journals on its ``serve.tick`` events (the newest one is read)
+_SERVING_TICK_FIELDS = ("overlap_share", "late_row_share")
 
 
 def report(args) -> int:
     from deepspeed_tpu.runtime.supervision.events import (ABORT_KINDS,
+                                                          EventKind,
                                                           read_events)
     from deepspeed_tpu.telemetry.export import validate_trace
     from deepspeed_tpu.telemetry.metrics import read_metrics
@@ -79,6 +83,10 @@ def report(args) -> int:
             p = os.path.join(run_dir, f"metrics.rank{r}.jsonl")
             if p not in paths:
                 problems.append(f"rank {r}: no metrics file at {p}")
+    last_tick = next((e for e in reversed(events)
+                      if e.get("kind") == EventKind.SERVE_TICK), {})
+    tick_shares = {field: last_tick[field] for field in _SERVING_TICK_FIELDS
+                   if field in last_tick}
     ranks = {}
     for p in paths:
         rows = read_metrics(p)
@@ -108,6 +116,7 @@ def report(args) -> int:
         serving = {field: m[f"serve.{field}"] for field in _SERVING_FIELDS
                    if f"serve.{field}" in m}
         if serving:
+            serving.update(tick_shares)
             ranks[os.path.basename(p)]["serving"] = serving
     out["metrics"] = ranks
 

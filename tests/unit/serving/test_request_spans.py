@@ -38,8 +38,8 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     prompt = np.arange(1, CHUNK + 2, dtype=np.int32)    # chunk + 1 tokens
     h = gw.submit(prompt, max_new_tokens=3)
     assert h.result(timeout=120).shape == (3,)
-    snap = gw.snapshot()
     gw.shutdown()
+    snap = gw.snapshot()
     spans = tracer.spans()
     by = lambda name: [s for s in spans if s.name == name]
     one = lambda name: by(name)[0] if len(by(name)) == 1 else pytest.fail(
@@ -74,10 +74,15 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     assert all(_inside(c, prefill) for c in chunks)
     assert write.args == {"slot": admit.args["slot"]}
 
-    # every tick: the pull inside it, the harvest after it
+    # every tick: the pull inside it, the harvest after it.  Three ticks
+    # deliver the reply; the fourth was launched before the third's
+    # harvest finished the request, ran its row for nothing and is pulled
+    # before the loop waits
     ticks, pulls, harvests = (by("serve.tick"), by("serve.pull"),
                               by("serve.harvest"))
-    assert len(ticks) == len(pulls) == len(harvests) == snap["ticks"] == 3
+    assert len(ticks) == len(pulls) == len(harvests) == snap["ticks"] == 4
+    assert (snap["tokens_out"], snap["late_row_ticks"],
+            snap["ticks_overlapped"]) == (3, 1, 3)
     for tick, pull, harvest in zip(ticks, pulls, harvests):
         assert _inside(pull, tick)
         assert harvest.t0 >= tick.t0 + tick.dur and harvest.args == {

@@ -120,8 +120,9 @@ def test_serving_session_emits_spans_with_zero_recompiles(tmp_path):
         max_new_tokens=3, seed=i) for i in range(6)]
     for h in handles:
         h.result(timeout=300.0)
-    snap = gw.snapshot()
+    # after the stop: the loop pulls the tick it has in flight first
     gw.shutdown()
+    snap = gw.snapshot()
     assert snap["recompiles"] == 0
     assert set(tracer.span_inventory()) == {
         SpanName.SERVE_QUEUE, SpanName.SERVE_ADMIT,

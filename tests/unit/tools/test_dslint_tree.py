@@ -99,10 +99,11 @@ def test_host_sync_caught_when_real_tick_suppression_removed():
             "tick", "#")
     findings = lint_source(src, "deepspeed_tpu/serving/batcher.py",
                            Project(REPO))
-    # one pull in the plain tick, two (window + counts) in _spec_tick,
-    # one in the spec-pause-rung _paused_tick
-    assert [f.rule for f in findings] == ["host-sync-in-hot-path"] * 4
-    assert all("np.asarray" in f.message for f in findings)
+    # the one pull every tick variant shares (tokens, or a speculative
+    # round's window and counts in one transfer)
+    assert [f.rule for f in findings] == ["host-sync-in-hot-path"]
+    assert "jax.device_get" in findings[0].message \
+        and "'pull'" in findings[0].message
 
 
 def test_lock_registry_parses_from_the_real_module():
