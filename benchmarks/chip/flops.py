@@ -68,3 +68,14 @@ def decode_call(rows_context_tokens: float, n_head: int, head_dim: int,
     ``2 H D`` cache elements read per cached token)."""
     n = rows_context_tokens * n_head * head_dim
     return 4.0 * n, 2.0 * n * kv_bytes
+
+
+def decode_call_dense(config, rows_context_tokens: float
+                      ) -> Tuple[float, float]:
+    """``decode_call`` of a model whose cache row is ``2 H D`` elements a
+    token in the type it is served in (two bytes): the default counting
+    function of the ``decode_roofline`` reader.  A family whose cache row is
+    another (a latent row, a window, a state) brings a function of this
+    signature, ``f(model config, cached tokens) -> (operations, bytes)``,
+    in a module of its own and names it in its metric file's ``args``."""
+    return decode_call(rows_context_tokens, config.n_head, config.head_dim)

@@ -17,6 +17,7 @@ import importlib
 import json
 import os
 import shutil
+import sys
 import tempfile
 import threading
 import time
@@ -96,6 +97,8 @@ class Context:
     scalars: Dict[str, float] = dataclasses.field(default_factory=dict)
     spans: List[Span] = dataclasses.field(default_factory=list)
     checks: Dict[str, bool] = dataclasses.field(default_factory=dict)
+    #: each number a check compared: name -> (the number, its limit)
+    compared: Dict[str, tuple] = dataclasses.field(default_factory=dict)
     attempted: int = 0
     failed: int = 0
     reduced: Any = None                 # trace.reduce.Reduced of the slice
@@ -108,19 +111,34 @@ class Context:
     _compiles: int = 0
 
     # ---- set-up phases -------------------------------------------------
-    def phase(self, name: str) -> None:
-        """Close a phase of set-up: logged with its seconds."""
-        now = time.perf_counter()
+    def phase(self, name: str, until: Optional[float] = None) -> None:
+        """Close a phase of set-up (now, or at ``until`` on
+        ``perf_counter``'s clock): logged with its seconds."""
+        now = time.perf_counter() if until is None else until
         log("setup", done=name, s=round(now - self._phase_t, 3))
         self.scalars[f"setup.{name}_s"] = now - self._phase_t
         self._phase_t = now
 
     def open_window(self) -> float:
-        """Set-up ends here.  Returns ``time.monotonic()`` at the opening."""
-        self.scalars["setup_s"] = time.perf_counter() - self.t_process
+        """Set-up ends here.  Returns ``time.monotonic()`` at the opening.
+
+        ``setup_s`` is process start to here *less the backend's start*
+        (``backend_start_s``, reported beside it: the first, bare
+        ``jax.devices()`` and nothing of the program's): the runtime taking
+        the chips is the one phase that is the machine's and not the tree's,
+        and the only one that moves by seconds between runs of one tree
+        (6.9-11.1 s in 18 processes that import JAX and ask for the
+        devices, whatever ran before them; PERF.md 4).  Importing, weights,
+        engine, warm-up, compilation and fill all stay in."""
+        after = time.perf_counter() - self.t_process
+        backend = self.scalars.get("setup.backend_start_s", 0.0)
+        self.scalars["opening_after_s"] = after
+        self.scalars["backend_start_s"] = backend
+        self.scalars["setup_s"] = after - backend
         self.scalars["compiles_before_window"] = self._compiles
-        log("window", open_after_s=round(self.scalars["setup_s"], 3),
-            seconds=self.seconds)
+        log("window", open_after_s=round(after, 3),
+            backend_start_s=round(backend, 3),
+            setup_s=round(after - backend, 3), seconds=self.seconds)
         return time.monotonic()
 
     def close_window(self) -> None:
@@ -151,15 +169,23 @@ class TraceSlice:
         self.dir = tempfile.mkdtemp(prefix="bench_trace_") if ctx.rehearsal \
             else os.path.join(ctx.cell.root, ".bench_trace", ctx.cell.name)
         self.t_start = self.t_stop = None
+        #: set the moment a start or a stop is *asked for*: ``t_start`` is
+        #: set by the helper thread only once the profiler is up, a second
+        #: or more later, and a poll that tested it would ask twice
+        self.start_asked = self.stop_asked = False
         self._thread = None
 
     def start_async(self) -> None:
         """``start`` from a helper thread: starting the profiler takes a
         second or more, and a load generator must not stall for it."""
-        self._begin(self.start)
+        if not self.start_asked:
+            self.start_asked = True
+            self._begin(self.start)
 
     def stop_async(self) -> None:
-        self._begin(self.stop)
+        if self.start_asked and not self.stop_asked:
+            self.stop_asked = True
+            self._begin(self.stop)
 
     def _begin(self, fn) -> None:
         self.join()
@@ -174,6 +200,7 @@ class TraceSlice:
 
     def start(self) -> None:
         import jax
+        self.start_asked = True
         shutil.rmtree(self.dir, ignore_errors=True)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0     # every Python call is too many
@@ -187,6 +214,7 @@ class TraceSlice:
 
     def stop(self) -> None:
         import jax
+        self.stop_asked = True
         self.t_stop = time.monotonic()
         jax.profiler.stop_trace()
 
@@ -246,13 +274,17 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax
     from .peaks import peaks_of
     rehearsal = rehearsal_peaks is not None
+    t_imported = time.perf_counter()
+    jax.devices()       # the runtime takes the chips: the machine's phase
+    t_backend = time.perf_counter()
     if rehearsal:
         devices, cache = jax.devices(), None
-    else:
+    else:       # the program's own functions stay inside ``setup_s``
         from deepspeed_tpu.utils.platform import (enable_compile_cache,
                                                   require_tpu)
         devices = require_tpu()
         cache = enable_compile_cache()
+    t_platform = time.perf_counter()
     if len(devices) < cell.chips:
         raise SystemExit(f"{cell.name} needs {cell.chips} chip(s); "
                          f"jax.devices() reports {len(devices)}")
@@ -270,7 +302,14 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     log("run", workload=cell.name, seed=seed, seconds=seconds,
         trace=int(trace), device=devices[0].device_kind, chips=cell.chips,
         compile_cache=cache)
-    ctx.phase("imports_and_backend")
+    # importing JAX is the tree's and the installation's; the backend's
+    # start (the bare ``jax.devices()``: the runtime taking the chips) is
+    # the machine's, and is where set-up varies from run to run; importing
+    # the program for its platform check and compile-cache set-up is the
+    # tree's again
+    ctx.phase("imports", until=t_imported)
+    ctx.phase("backend_start", until=t_backend)
+    ctx.phase("platform_and_cache", until=t_platform)
     kind = importlib.import_module(
         f"{__package__}.kinds.{cell.traffic['kind']}")
     try:
@@ -300,4 +339,14 @@ def result_of(ctx: Context) -> dict:
         result["breakdown"] = {
             "device_ops": [[k, v] for k, v in ctx.reduced.top_ops],
             "idle_gaps": [[k, v] for k, v in ctx.reduced.idle_gaps]}
+    # what a reader of one run needs beside the metrics (the driver ignores
+    # both keys): the run's own counts and clocks, and last every number a
+    # check compared beside its limit, which also end standard error
+    result["scalars"] = {k: v for k, v in ctx.scalars.items()
+                         if isinstance(v, (int, float))}
+    result["compared"] = {
+        **{k: [v, limit] for k, (v, limit) in ctx.compared.items()},
+        **{k: [int(v), 1] for k, v in ctx.checks.items()}}
+    for k, (v, limit) in result["compared"].items():
+        print(f"compared {k}={v} limit={limit}", file=sys.stderr, flush=True)
     return result

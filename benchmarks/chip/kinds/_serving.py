@@ -9,9 +9,10 @@ from typing import List
 
 import numpy as np
 
+from ..builders import resolve
 from ..harness import Context, Span, log
 from ..loadgen import Request
-from ..reference import compare, gpt_reference
+from ..reference import compare
 
 
 def build_server(ctx: Context):
@@ -22,12 +23,11 @@ def build_server(ctx: Context):
     import jax.numpy as jnp
 
     import deepspeed_tpu
-    from deepspeed_tpu.models import gpt
     from deepspeed_tpu.telemetry.spans import Tracer
 
     cfg = ctx.build_model_config(dtype=jnp.bfloat16)
-    params = jax.jit(lambda key: jax.tree_util.tree_map(
-        lambda x: x.astype(jnp.bfloat16), gpt.init(cfg, key)))(ctx.seed_key())
+    init = resolve(ctx.cell.config["init"])
+    params = jax.jit(lambda key: init(cfg, key, jnp.bfloat16))(ctx.seed_key())
     jax.block_until_ready(params)
     ctx.phase("weights")
     ctx.reference_params = params
@@ -57,6 +57,20 @@ def submitter(gateway):
     return submit
 
 
+#: the traced slice ends this long before the window's close
+_SLICE_ENDS_BEFORE_CLOSE_S = 0.5
+
+
+def slice_of(ctx: Context):
+    """``(start, length)`` of the traced slice in seconds of the window: its
+    last ``trace_len_s`` seconds but half a second.  Stopping the profiler
+    and writing its trace takes the host seconds, and a server at four
+    fifths of its knee that falls behind in the middle of the window never
+    catches up inside it (PERF.md 5), so the stop falls after the close."""
+    length = float(ctx.cell.traffic["trace_len_s"])
+    return ctx.seconds - length - _SLICE_ENDS_BEFORE_CLOSE_S, length
+
+
 def harvest_spans(ctx: Context, gateway) -> None:
     for r in gateway.tracer.spans():
         ctx.spans.append(Span(r.name, r.t0, r.dur, r.thread, r.args))
@@ -81,39 +95,6 @@ def finish(ctx: Context, engine, gateway) -> None:
     check_logits(ctx, engine, gateway)
 
 
-def slot_path_logits(gateway, prompts, ticks: int):
-    """``(replies, logits)`` of chunked prefill and ``ticks`` greedy decode
-    ticks through the stopped server's own batcher (its compiled programs,
-    its slot cache): for each prompt the tokens it replied and the float32
-    logits ``[1 + ticks, padded vocab]`` after the prefill and each tick.
-
-    The program has no public entry that returns logits (PERF.md 7), so this
-    one function holds every private name the benchmark touches:
-    ``gateway._batcher``, the batcher's ``admit(row, tokens, key, greedy,
-    temperature)``, ``tick()``, ``release(row)`` and ``_last`` (the logits
-    of every slot's frontier).  A gateway that offers
-    ``probe_logits(prompts, ticks)`` with this return value is asked
-    instead, so a PR that changes those internals adds that method and
-    leaves the benchmark alone."""
-    import jax
-    probe = getattr(gateway, "probe_logits", None)
-    if probe is not None:
-        return probe(prompts, ticks)
-    batcher = gateway._batcher
-    for row in range(batcher.slots):     # whatever the window left behind
-        batcher.release(row)
-    rows = range(len(prompts))
-    for row, p in zip(rows, prompts):
-        batcher.admit(row, p, jax.random.PRNGKey(0), True, 1.0)
-    frontier = lambda: np.asarray(batcher._last[:len(prompts)], np.float32)
-    logits, replies = [frontier()], []
-    for _ in range(ticks):
-        replies.append(np.asarray(batcher.tick())[:len(prompts)])
-        logits.append(frontier())
-    return ([[int(t[i]) for t in replies] for i in rows],
-            [np.stack([l[i] for l in logits]) for i in rows])
-
-
 def check_logits(ctx: Context, engine, gateway) -> None:
     """Chunked prefill and then decode through the slot path against the
     plain reference's full forward pass, on logits: a few seeded prompts,
@@ -130,8 +111,11 @@ def check_logits(ctx: Context, engine, gateway) -> None:
     vocab = engine.model_config.vocab_size
     rng = np.random.default_rng(ctx.seed + 7)
     prompts = [rng.integers(0, vocab, n).astype(np.int32) for n in lens]
-    replies, got = slot_path_logits(gateway, prompts, ticks)
-    reference = jax.jit(lambda prm, t: gpt_reference.forward(
+    # the program's public entry for its slot path's logits: the stopped
+    # gateway's own compiled programs and slot cache
+    replies, got = gateway.probe_logits(prompts, ticks)
+    forward = resolve(ctx.cell.config["reference"]).forward
+    reference = jax.jit(lambda prm, t: forward(
         ctx.cell.config, prm, t, ticks + 1))
     worst = rms = 0.0
     for p, reply, logits in zip(prompts, replies, got):

@@ -11,6 +11,22 @@ from ..harness import Context, TraceSlice, log
 from . import _serving
 
 
+def _diagnose(ctx, edges, at_open, at_close) -> None:
+    """What tells one run from another: the admissions inside the window and
+    the largest distance between two tick edges the poll saw (a stall of the
+    machine reads seconds; the poll's own period is 4 ms)."""
+    gaps = [b[0] - a[0] for a, b in zip(edges, edges[1:])]
+    worst = max(range(len(gaps)), key=gaps.__getitem__)
+    ctx.scalars["largest_tick_gap_s"] = gaps[worst]
+    ctx.scalars["admitted_in_window"] = at_close[0] - at_open[0]
+    log("backlog_detail", admitted_at_open=at_open[0],
+        completed_at_open=at_open[1],
+        admitted_in_window=at_close[0] - at_open[0],
+        completed_in_window=at_close[1] - at_open[1],
+        largest_tick_gap_s=round(gaps[worst], 4),
+        at_s=round(edges[worst][0] - edges[0][0], 2))
+
+
 def run(ctx: Context) -> None:
     engine, gateway = _serving.build_server(ctx)
     traffic = ctx.cell.traffic
@@ -31,7 +47,7 @@ def run(ctx: Context) -> None:
 
     state = {"t_open": None, "edges": [], "last_ticks": -1}
     slice_ = TraceSlice(ctx) if ctx.trace else None
-    at, length = float(traffic["trace_at_s"]), float(traffic["trace_len_s"])
+    at, length = _serving.slice_of(ctx)
 
     def on_poll():
         now = time.monotonic()
@@ -41,11 +57,11 @@ def run(ctx: Context) -> None:
             state["edges"].append((now,) + c)
         if slice_ and state["t_open"] is not None:
             t = now - state["t_open"]
-            if slice_.t_start is None and t >= at:
+            if not slice_.start_asked and t >= at:
                 ctx.scalars["context_tokens_at_slice_start"] = \
                     _serving.live_context_tokens(backlog.sent)
                 slice_.start_async()
-            elif slice_.t_start is not None and slice_.t_stop is None \
+            elif slice_.start_asked and not slice_.stop_asked \
                     and t >= at + length:
                 ctx.scalars["context_tokens_at_slice_end"] = \
                     _serving.live_context_tokens(backlog.sent)
@@ -60,6 +76,7 @@ def run(ctx: Context) -> None:
     gc.collect()
     gc.freeze()
     gc.disable()
+    at_open = (m.admitted, m.completed)
     state.update(edges=[], t_open=ctx.open_window())
     t_end = state["t_open"] + ctx.seconds
     backlog.run_until(lambda: time.monotonic() >= t_end)
@@ -77,6 +94,7 @@ def run(ctx: Context) -> None:
     ctx.scalars["slot_occupancy"] = (live_b - live_a) / max(1, n_ticks * slots)
     ctx.scalars["slots"] = slots
     ctx.scalars["ticks"] = n_ticks
+    _diagnose(ctx, edges, at_open, (m.admitted, m.completed))
     errors = [r for r in sent if r.error is not None or (
         r.handle.done() and r.handle.state != "done")]
     ctx.attempted, ctx.failed = len(sent), len(errors)
@@ -88,7 +106,7 @@ def run(ctx: Context) -> None:
         failed=len(errors))
     if slice_:
         slice_.join()
-        if slice_.t_stop is None:
+        if not slice_.stop_asked:
             slice_.stop()
         slice_.reduce()
     _serving.finish(ctx, engine, gateway)

@@ -12,11 +12,30 @@ from ..harness import Context, TraceSlice, log
 from . import _serving
 
 
+def _longest_standstill(seen) -> float:
+    """The longest time the generator saw the server's tick counter stand
+    still: between the first and the last of consecutive sends that read
+    the same count.  With requests live it reads 0 or a few milliseconds; a
+    stall of the machine or of the device reads its length."""
+    longest, first = 0.0, None
+    for t, ticks in seen:
+        if first is None or ticks != first[1]:
+            first = (t, ticks)
+        longest = max(longest, t - first[0])
+    return longest
+
+
 def run(ctx: Context) -> None:
     engine, gateway = _serving.build_server(ctx)
     traffic = ctx.cell.traffic
     vocab = engine.model_config.vocab_size
-    submit = _serving.submitter(gateway)
+    m = gateway.metrics
+    send = _serving.submitter(gateway)
+    seen = []       # (time, tick counter) at every send
+
+    def submit(req):
+        seen.append((time.monotonic(), m.ticks))
+        return send(req)
     schedule = loadgen.open_loop_schedule(traffic, ctx.seed, ctx.seconds,
                                           vocab)
     # steady state before the window: the standing population first, then
@@ -35,12 +54,12 @@ def run(ctx: Context) -> None:
     ctx.phase("standing_population_and_lead_in")
 
     slice_ = TraceSlice(ctx) if ctx.trace else None
-    at, length = float(traffic["trace_at_s"]), float(traffic["trace_len_s"])
+    at, length = _serving.slice_of(ctx)
     gc.collect()
     gc.freeze()
     gc.disable()
+    del seen[:]
     t_open = ctx.open_window()
-    m = gateway.metrics
     occ_open = (m.active_slot_ticks, m.slot_ticks)
     if slice_:      # the loop below is split around the traced slice
         early = [r for r in body if r.due_s < at]
@@ -97,11 +116,15 @@ def run(ctx: Context) -> None:
             tpot.append(gap * 1e3)
     ctx.samples.update(ttft_ms=ttft, tpot_ms=tpot, late_ms=late_ms)
     ctx.attempted, ctx.failed = len(body), failed
+    if ttft:        # steadier statistics beside the tail, for a run's reader
+        ctx.scalars["ttft_p50_ms"] = stats.percentile(ttft, 50.0)
+        ctx.scalars["ttft_p90_ms"] = stats.percentile(ttft, 90.0)
     ctx.scalars["due_in_window"] = len(body)
     ctx.scalars["queue_at_close"] = queue_at_close
     ctx.scalars["slot_occupancy"] = (occ_close[0] - occ_open[0]) / max(
         1, occ_close[1] - occ_open[1])
     ctx.scalars["slots"] = gateway.config.slots
+    ctx.scalars["largest_tick_gap_s"] = _longest_standstill(seen)
     ctx.checks["all_first_tokens"] = failed == 0
     _serving.harvest_spans(ctx, gateway)
     # queue wait: due -> the start of the request's serve.admit span; the
@@ -118,6 +141,7 @@ def run(ctx: Context) -> None:
         failed=failed, finished_in_window=len(tpot),
         standing=len(standing), lead_in=len(head),
         queue_at_close=queue_at_close,
+        largest_tick_gap_s=round(ctx.scalars["largest_tick_gap_s"], 4),
         beyond_p95=stats.samples_beyond(len(ttft), 95.0))
     if slice_:
         slice_.reduce()

@@ -15,8 +15,9 @@ import time
 
 import numpy as np
 
+from ..builders import resolve
 from ..harness import Context, Span, TraceSlice, log
-from ..reference import compare, gpt_reference
+from ..reference import compare
 
 
 def _batches(ctx: Context, rows: int, width: int, vocab: int, stop):
@@ -49,7 +50,7 @@ def run(ctx: Context) -> None:
 
     from deepspeed_tpu.parallel.mesh import (DP_GROUP, ParallelDims,
                                              initialize_mesh)
-    from deepspeed_tpu.runtime.model import from_gpt
+    from deepspeed_tpu.runtime.model import ModelSpec
     from deepspeed_tpu.utils.compile_watch import CompileWatch
 
     job = ctx.cell.traffic
@@ -68,8 +69,17 @@ def run(ctx: Context) -> None:
           "bf16": {"enabled": True},
           "tensor_parallel": {"enabled": mm.tp_world_size > 1,
                               "size": mm.tp_world_size}}
+    if ctx.trace:
+        # the engine's tracer records train.step and its children only in
+        # the traced run: the end-to-end run pays for no instrumentation
+        ds["telemetry"] = {"enabled": True, "spans": {"enabled": True},
+                           "metrics": {"enabled": False}}
+    module = resolve(ctx.cell.config["train_module"])(cfg)
+    if not isinstance(module, ModelSpec):
+        raise TypeError(f"{ctx.cell.config['train_module']} returned "
+                        f"{type(module).__name__}, not a ModelSpec")
     engine, _, _, _ = deepspeed_tpu.initialize(
-        model=from_gpt(cfg), config=ds, mesh_manager=mm, rng=ctx.seed_key())
+        model=module, config=ds, mesh_manager=mm, rng=ctx.seed_key())
     jax.block_until_ready(engine.state)
     ctx.phase("weights_and_engine")
 
@@ -84,6 +94,7 @@ def run(ctx: Context) -> None:
             losses.append(float(jax.block_until_ready(
                 engine.train_batch_fused(batches.get()))))
         watch.mark_warm()
+        engine.tracer.clear()
         ctx.phase("warmup")
 
         slice_ = TraceSlice(ctx) if ctx.trace else None
@@ -130,6 +141,8 @@ def run(ctx: Context) -> None:
     log("train", steps=len(step_end), elapsed_s=round(elapsed, 3),
         first_loss=round(losses[0], 4), last_loss=round(losses[-1], 4),
         compile_counts=engine.compile_counts())
+    ctx.spans += [Span(r.name, r.t0, r.dur, r.thread, r.args)
+                  for r in engine.tracer.spans()]
     if slice_:
         slice_.reduce()
 
@@ -141,14 +154,15 @@ def run(ctx: Context) -> None:
     # relative to the largest logit of the *trained* weights grows with the
     # number of steps a window held (0.010 fresh, 0.016-0.029 after 30-67
     # steps; PERF.md 6) and would judge the window's length, not the code
-    from deepspeed_tpu.models import gpt
+    init = resolve(ctx.cell.config["init"])
+    forward = resolve(ctx.cell.config["reference"]).forward
     n_seq, last_pos = int(job["check_sequences"]), int(job["check_last"])
     last_pos = min(last_pos, seq)
     check = np.random.default_rng(ctx.seed + 7).integers(
         0, cfg.vocab_size, size=(n_seq, seq)).astype(np.int32)
     check = jax.device_put(check, NamedSharding(
         mm.mesh, P(DP_GROUP if n_seq % mm.dp_world_size == 0 else None)))
-    params = jax.jit(lambda key: gpt.init(cfg, key), out_shardings=(
+    params = jax.jit(lambda key: init(cfg, key), out_shardings=(
         jax.tree_util.tree_map(lambda x: x.sharding,
                                engine.state["params"])))(ctx.seed_key())
     served = params
@@ -157,7 +171,7 @@ def run(ctx: Context) -> None:
         served = jax.jit(round_to_int8)(params)
     system = jax.jit(lambda p, t: engine.module.apply_fn(p, t)[
         :, seq - last_pos:, :cfg.vocab_size])(served, check)
-    ref = jax.jit(lambda p, t: gpt_reference.forward(
+    ref = jax.jit(lambda p, t: forward(
         ctx.cell.config, p, t, last_pos))(params, check)
     compare.record(ctx, compare.relative_error(system, ref),
                    compare.rms_error(system, ref), sequences=n_seq,

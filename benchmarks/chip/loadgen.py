@@ -6,11 +6,13 @@ run repeat: the work of a window is a *fixed multiset*.  The (prompt,
 output) lengths are the quantile grid of the file's two distributions, and
 the gaps between arrivals are the quantile grid of the exponential
 distribution at the file's rate, so every seed offers the same lengths and
-the same gaps.  ``--seed`` decides their order and the token ids, nothing
-else.  Locally the arrivals look Poisson (independent shuffled exponential
-gaps, bursts by thinning the same grid); over the window their number and
-their span are constants, so no run holds two or nine 768-token prompts by
-luck.
+the same gaps.  ``--seed`` decides the token ids and, nothing else, the
+order: of a backlog a shuffle of the multiset; of an open loop the place
+where the traffic's one fixed, stratified cycle of arrivals begins
+(``open_loop_schedule``).  Locally the arrivals look Poisson (shuffled
+exponential gaps, bursts by thinning the same grid); over the window, and
+over every ``STRATA`` arrivals of an open loop, their number and their span
+are constants, so no run holds two or nine 768-token prompts by luck.
 """
 
 from __future__ import annotations
@@ -86,10 +88,32 @@ def _warp_bursts(times: List[float], span_s: float, burst: dict
     return out
 
 
+#: arrivals of an open loop are dealt in runs of this many (``stratified_order``)
+STRATA = 16
+
+
 def open_loop_schedule(traffic: dict, seed: int, seconds: float,
                        vocab: int) -> List[Request]:
     """Lead-in requests (due before 0, served, not counted) followed by the
-    window's requests, sorted by due time."""
+    window's requests, sorted by due time.
+
+    The arrival pattern is *fixed and stratified*, the traffic's own and the
+    same for every seed.  Both orders (of the gaps, of the length pairs) are
+    drawn once from the fixed pairing seed by ``stratified_order``: every
+    run of ``STRATA`` consecutive arrivals holds one gap of each stratum of
+    the exponential grid, so it spans the same time, and one pair of each
+    stratum of cost (prompt + output), so it brings the same work.  Inside a
+    run the gaps stay exponential and shuffled (two or three requests still
+    land together), but over ``STRATA`` arrivals their number per time is a
+    constant, so the stream is less bursty than a Poisson process.
+    ``--seed`` decides where in that cycle the span begins, and the token
+    ids: every seed offers the same arrivals with the same lengths, each
+    next to the same neighbours, in another order only in that the cycle
+    starts elsewhere.  A tail is made of coincidences (three arrivals inside
+    20 ms, a long prompt among them), and a shuffle, stratified or not,
+    deals each seed a different hand of them: ``ttft_p95_ms`` spread
+    5.8 / 11.6% with a plain shuffle, 7.0 / 16.1% with a stratified one and
+    3.0 / 5.3% with the one cycle (PERF.md 6)."""
     rng = np.random.default_rng(seed)
     rate = float(traffic["rate_hz"])
     out: List[Request] = []
@@ -101,10 +125,16 @@ def open_loop_schedule(traffic: dict, seed: int, seconds: float,
             continue
         pairs = length_pairs(n, traffic["prompt_len"], traffic["output_len"])
         gaps = exponential_gaps(n, span)
-        times = _arrivals([gaps[i] for i in rng.permutation(n)])
+        own = np.random.default_rng(_PAIRING_SEED + n)
+        gap_order = stratified_order(own, gaps, STRATA)
+        pair_order = stratified_order(own, [p + o for p, o in pairs], STRATA)
+        shift = int(rng.integers(n))
+        gap_order = gap_order[shift:] + gap_order[:shift]
+        pair_order = pair_order[shift:] + pair_order[:shift]
+        times = _arrivals([gaps[i] for i in gap_order])
         if traffic.get("burst"):
             times = _warp_bursts(times, span, traffic["burst"])
-        for t, k in zip(times, rng.permutation(n)):
+        for t, k in zip(times, pair_order):
             plen, olen = pairs[int(k)]
             out.append(Request(
                 due_s=start + t, max_new_tokens=olen, counted=counted,
@@ -122,12 +152,30 @@ def standing_population(traffic: dict, seed: int, vocab: int
     if n == 0:
         return []
     rng = np.random.default_rng(seed + 1)
+    # every seed finds the same population in place
+    order = np.random.default_rng(_PAIRING_SEED + n + 1)
     pairs = length_pairs(n, traffic["prompt_len"], traffic["output_len"])
     left = (np.arange(n) + 0.5) / n
     return [Request(due_s=-math.inf, counted=False,
                     tokens=rng.integers(0, vocab, p).astype(np.int32),
                     max_new_tokens=max(1, int(round(o * left[int(j)]))))
-            for (p, o), j in zip(pairs, rng.permutation(n))]
+            for (p, o), j in zip(pairs, order.permutation(n))]
+
+
+def stratified_order(rng, costs: Sequence[float], strata: int) -> List[int]:
+    """An order of ``range(len(costs))``, drawn from ``rng``, in which every
+    run of ``strata`` consecutive indices holds one item of each stratum of
+    cost.  The items, sorted by cost, are cut into ``strata`` strata as
+    equal as can be; ``rng`` decides which item of a stratum comes when and
+    the order inside each run."""
+    by_cost = sorted(range(len(costs)), key=lambda k: (costs[k], k))
+    columns = [[int(c[int(i)]) for i in rng.permutation(len(c))]
+               for c in np.array_split(by_cost, strata) if len(c)]
+    out = []
+    for i in range(max(len(c) for c in columns)):
+        row = [c[i] for c in columns if i < len(c)]
+        out += [row[int(j)] for j in rng.permutation(len(row))]
+    return out
 
 
 def backlog_requests(traffic: dict, seed: int, vocab: int, slots: int

@@ -4,8 +4,8 @@ the program's spans.
 A traced run's tracer is on from warm-up to drain, so the spans of a standing
 population submitted at once before the window, or of the drain after it,
 would own every tail.  These readers take only spans that *start inside the
-window*.  ``ctx.t_process + setup_s`` is ``time.perf_counter()`` at the
-window's opening; the spans are on ``time.monotonic``.  Where the two are one
+window*.  ``ctx.t_process + opening_after_s`` is ``time.perf_counter()`` at
+the window's opening (``setup_s`` leaves the backend's start out of it); the spans are on ``time.monotonic``.  Where the two are one
 clock (Linux: both ``clock_gettime(CLOCK_MONOTONIC)``) the sum is the opening
 on the spans' clock; where they are not, there is no window to filter by and
 the metric is left out."""
@@ -15,12 +15,12 @@ import time
 
 def window(ctx):
     """``(opening, close)`` on ``time.monotonic``'s clock, or None."""
-    setup_s = ctx.scalars.get("setup_s")
+    after = ctx.scalars.get("opening_after_s")
     info = time.get_clock_info
-    if setup_s is None or info("perf_counter").implementation != \
+    if after is None or info("perf_counter").implementation != \
             info("monotonic").implementation:
         return None
-    opening = ctx.t_process + setup_s
+    opening = ctx.t_process + after
     return opening, opening + ctx.seconds
 
 

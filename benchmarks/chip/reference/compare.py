@@ -67,5 +67,7 @@ def record(ctx, worst: float, rms: float, **fields) -> None:
     ctx.scalars["logits_rms_error"] = rms
     ctx.checks["logits_agree"] = bool(worst <= TOLERANCE
                                       and rms <= RMS_TOLERANCE)
+    ctx.compared["logits_relative_error"] = (worst, TOLERANCE)
+    ctx.compared["logits_rms_error"] = (rms, RMS_TOLERANCE)
     log("reference", relative_error=f"{worst:.5f}", tolerance=TOLERANCE,
         rms_error=f"{rms:.5f}", rms_tolerance=RMS_TOLERANCE, **fields)

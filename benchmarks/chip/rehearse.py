@@ -42,4 +42,6 @@ def rehearse(root: str, workload: str, overrides: dict, seconds: float = 1.0,
     return {"rehearsal": True, "correct": result["correct"],
             "attempted": result["attempted"], "failed": result["failed"],
             "metrics": sorted(result["metrics"]), "checks": dict(ctx.checks),
+            "scalars": sorted(result["scalars"]),
+            "compared": sorted(result["compared"]), "keys": list(result),
             "platform": result["device"]["platform"]}

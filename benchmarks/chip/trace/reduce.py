@@ -32,6 +32,11 @@ _COLLECTIVE = re.compile(r"^(all-gather|all-reduce|reduce-scatter|"
                          r"all-to-all|collective-permute|"
                          r"collective-broadcast)")
 _WRAPPER_OPS = ("while", "conditional", "call")
+#: the thread line of the program's tracer that holds recorded *waits*
+#: (``telemetry/spans.py::WAIT_THREAD``: ``serve.queue``, a first token's
+#: wait): a wait is what a request saw, not what the host did, and one that
+#: ends in a gap's tail would name a gap it did not cause
+WAIT_LINE = "waits"
 #: host events that only wrap others and would name every gap
 _WRAPPERS = re.compile(r"^(\$|PjitFunction|CommonPjRt|ThreadpoolListener|"
                        r"PythonRefManager)")
@@ -171,14 +176,15 @@ def gaps(busy: Sequence[Interval], lo: float, hi: float) -> List[Interval]:
 def name_gap(gap: Interval, events: Sequence[HostEvent]) -> str:
     """The host event that explains an idle gap.  First the local events
     (no longer than twenty times the gap) that overlap a quarter of it or
-    more: the one that overlaps it most, the shorter of two that tie.  Failing that, the shortest event
-    that covers half of it (the innermost call around it); failing that,
-    whatever overlaps it most; else ``(no host event)``."""
+    more: the one that overlaps it most, the shorter of two that tie.
+    Failing that, the shortest event that covers half of it (the innermost
+    call around it); failing that, whatever overlaps it most; else ``(no
+    host event)``.  Events of the ``waits`` line never name a gap."""
     a, b = gap
     local = cover = overlap = None
     for e in events:
         ov = min(b, e.end) - max(a, e.start)
-        if ov <= 0:
+        if ov <= 0 or e.thread == WAIT_LINE:
             continue
         dur = e.end - e.start
         if dur <= 20 * (b - a) and ov >= 0.25 * (b - a) and (

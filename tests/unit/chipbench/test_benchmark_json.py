@@ -8,7 +8,7 @@ import re
 
 import pytest
 
-from .common import BENCH_DIR, ROOT, benchmark
+from .common import BENCH_DIR, ROOT, benchmark, check_configuration
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -81,22 +81,15 @@ def test_cells_and_configs():
         assert any(w["name"] in _cells_of(m) for m in BENCH["per_layer"])
 
 
-WIDTHS = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|head_size")
-
-
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_file(config):
+    """What holds for a configuration of any family (``common.py``): no
+    width in ``reduced``, the hooks, the builder's config against every
+    width of the file, the floors."""
     assert any(config["file"].startswith(p + "/") for p in BENCH["paths"])
     with open(os.path.join(ROOT, config["file"])) as f:
         file = json.load(f)
-    assert file["source"] == config["source"]
-    assert file["reduced"] == config["reduced"] and len(config["reduced"]) <= 16
-    assert not any(WIDTHS.search(k) for k in config["reduced"])
-    module, _, attr = file["builder"].rpartition(".")
-    builder = getattr(importlib.import_module(
-        f"benchmarks.chip.{module}"), attr)
-    cfg = builder(file)
-    assert cfg.d_model == cfg.n_head * cfg.head_dim
+    check_configuration(file, config)
 
 
 def test_the_published_sizes():

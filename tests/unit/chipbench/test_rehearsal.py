@@ -41,6 +41,17 @@ def test_cell_rehearses(cell, trace, capsys):
         assert got == _expected(cell, True) - from_trace
     else:
         assert got == _expected(cell, False)
+    # beside the metrics: the run's own scalars (a stall of the machine and
+    # the backend's start can be told from the server), and last of all the
+    # numbers that were compared, each with its limit
+    assert {"setup_s", "backend_start_s", "opening_after_s"} <= set(
+        out["scalars"])
+    if "serve" in cell["name"]:
+        assert "largest_tick_gap_s" in out["scalars"]
+    assert out["keys"][-1] == "compared" and out["keys"][:5] == [
+        "correct", "attempted", "failed", "metrics", "device"]
+    assert {"logits_rms_error", "logits_relative_error"} <= set(
+        out["compared"])
     # no value of a rehearsal ever leaves it
     assert not any(isinstance(v, (int, float)) for v in out["metrics"])
     assert "[checks] " in printed and '"metrics"' not in printed
@@ -78,17 +89,136 @@ def test_a_lowered_precision_reaches_the_logits_check(cell, control, capsys):
     assert rms[1] > 1.25 * rms[0] > 0
 
 
-def test_a_gateway_that_offers_probe_logits_is_asked():
-    """The one function that touches the batcher's internals steps aside
-    for a public entry, should the program grow one."""
+def test_a_gateway_that_offers_probe_logits_is_asked(monkeypatch):
+    """The logits check asks the program's public ``probe_logits`` and no
+    private name: the fallback into the batcher (dead since PR 24) is gone."""
+    import inspect
+    import types
+
+    import numpy as np
+
     from benchmarks.chip.kinds import _serving
+    from benchmarks.chip.reference import compare
+
+    assert not hasattr(_serving, "slot_path_logits")
+    assert "_batcher" not in inspect.getsource(_serving)
+    asked = []
 
     class Gateway:
         def probe_logits(self, prompts, ticks):
-            return [[7] * ticks for _ in prompts], "logits"
+            asked.append((len(prompts), ticks))
+            return ([[7] * ticks for _ in prompts],
+                    [np.zeros((ticks + 1, 8), np.float32) for _ in prompts])
 
-    assert _serving.slot_path_logits(Gateway(), [[1, 2], [3]], 3) == (
-        [[7, 7, 7], [7, 7, 7]], "logits")
+    reference = types.SimpleNamespace(
+        forward=lambda file, params, tokens, last: np.zeros(
+            (tokens.shape[0], last, 8), np.float32))
+    monkeypatch.setattr(_serving, "resolve", lambda dotted: reference)
+    ctx = types.SimpleNamespace(
+        seed=3, reference_params={}, scalars={}, checks={}, compared={},
+        cell=types.SimpleNamespace(
+            config={"reference": "reference.any"},
+            traffic={"check": {"ticks": 2, "prompt_lens": [3, 5]},
+                     "serving": {"max_len": 16}}))
+    engine = types.SimpleNamespace(
+        model_config=types.SimpleNamespace(vocab_size=8))
+    _serving.check_logits(ctx, engine, Gateway())
+    assert asked == [(2, 2)] and ctx.checks["logits_agree"]
+    assert ctx.compared["logits_rms_error"] == (0.0, compare.RMS_TOLERANCE)
+
+
+def test_the_profiler_is_started_once_however_slow_its_start(monkeypatch):
+    """``backlog``'s poll asks every 4 ms, and the profiler takes a second
+    or more to come up: the flag is set when the start is asked for."""
+    import inspect
+    import time
+    import types
+
+    from benchmarks.chip.kinds import backlog
+
+    calls = {"start": 0, "stop": 0}
+
+    def slow_start(self):
+        calls["start"] += 1
+        time.sleep(0.3)
+        self.t_start = time.monotonic()
+
+    def stop(self):
+        calls["stop"] += 1
+        self.t_stop = time.monotonic()
+
+    monkeypatch.setattr(harness.TraceSlice, "start", slow_start)
+    monkeypatch.setattr(harness.TraceSlice, "stop", stop)
+    slice_ = harness.TraceSlice(types.SimpleNamespace(
+        rehearsal=True, cell=None))
+    slice_.stop_async()                 # nothing was started: nothing stops
+    for _ in range(5):                  # the poll, while the start is slow
+        slice_.start_async()
+        assert slice_.start_asked and slice_.t_start is None
+    for _ in range(3):
+        slice_.stop_async()
+    slice_.join()
+    assert calls == {"start": 1, "stop": 1} and slice_.stop_asked
+    source = inspect.getsource(backlog)
+    assert "slice_.t_start" not in source and "start_asked" in source
+
+
+@pytest.mark.parametrize("length,seconds,start", [(3.0, 45.0, 41.5),
+                                                  (2.5, 45.0, 42.0),
+                                                  (3.0, 10.0, 6.5)])
+def test_the_traced_slice_is_the_windows_last_seconds(length, seconds, start):
+    import types
+
+    from benchmarks.chip.kinds import _serving
+    ctx = types.SimpleNamespace(seconds=seconds, cell=types.SimpleNamespace(
+        traffic={"trace_len_s": length}))
+    assert _serving.slice_of(ctx) == (start, length)
+
+
+def test_the_serving_cells_trace_their_last_seconds():
+    """The profiler's stop falls after the window's close (PERF.md 5): the
+    slice's place is a constant of the serving kinds, no traffic file's."""
+    import json
+    import os
+    import types
+
+    from benchmarks.chip.kinds import _serving
+
+    from .common import BENCH_DIR
+    for cell in BENCH["workloads"]:
+        with open(os.path.join(BENCH_DIR, "traffic",
+                               cell["traffic"] + ".json")) as f:
+            traffic = json.load(f)
+        if traffic["kind"] in ("backlog", "open_loop"):
+            assert "trace_at_s" not in traffic, cell["name"]
+            at, length = _serving.slice_of(types.SimpleNamespace(
+                seconds=BENCH["run_seconds"],
+                cell=types.SimpleNamespace(traffic=traffic)))
+            assert 0 < BENCH["run_seconds"] - at - length <= 1.0
+
+
+def test_setup_leaves_the_backends_start_out():
+    """``setup_s`` is process start to the opening less ``backend_start``,
+    which is reported beside it; the window's place on the spans' clock
+    still counts all of it."""
+    import time
+
+    from benchmarks.chip.metrics.readers import _window
+    ctx = harness.Context(
+        cell=None, seed=0, seconds=5.0, trace=False,
+        t_process=time.perf_counter() - 10.0, devices=[], peaks={})
+    ctx._phase_t = ctx.t_process
+    ctx.phase("imports", until=ctx.t_process + 3.0)
+    ctx.phase("backend_start", until=ctx.t_process + 7.5)
+    ctx.open_window()
+    s = ctx.scalars
+    assert s["backend_start_s"] == pytest.approx(4.5)
+    assert s["setup.imports_s"] == pytest.approx(3.0)
+    assert s["opening_after_s"] == pytest.approx(10.0, abs=0.05)
+    assert s["setup_s"] == pytest.approx(s["opening_after_s"] - 4.5)
+    opening, close = _window.window(ctx)
+    assert opening == pytest.approx(ctx.t_process + s["opening_after_s"])
+    assert close - opening == 5.0
 
 
 def test_unknown_workload_is_refused():

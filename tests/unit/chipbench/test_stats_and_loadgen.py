@@ -186,3 +186,154 @@ def test_backlog_stops_when_it_is_refused():
         SAT, 1, 100, slots=2), outstanding=2, sleep=lambda s: None)
     backlog.run_until(lambda: False)
     assert backlog.refused is not None and backlog.sent[0].error is not None
+
+
+# ---- the orders: a backlog's shuffle, an open loop's one cycle (PR 32) ---
+
+SAT256 = dict(SAT, pairs=256)
+
+
+def _cost(run):
+    return [len(r.tokens) + r.max_new_tokens for r in run]
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5])
+def test_a_stratified_order_gives_every_stretch_the_same_work(seed):
+    rng = np.random.default_rng(seed)
+    costs = _cost(loadgen.backlog_requests(SAT256, 1, 100, 0))
+    order = loadgen.stratified_order(rng, costs, 16)
+    assert sorted(order) == list(range(256))            # one multiset
+    strat = [costs[k] for k in order]
+    plain = [costs[int(k)] for k in rng.permutation(256)]
+    total = sum(costs)
+    for start in range(0, 256, 16):     # every run of 16 holds each stratum
+        run = strat[start:start + 16]
+        assert abs(sum(run) - total / 16) < 0.02 * total / 16
+    by_64 = lambda c: [sum(c[i:i + 64]) for i in range(0, 256, 64)]
+    swing = lambda sums: (max(sums) - min(sums)) / (total / 4)
+    assert swing(by_64(strat)) < 0.01 < swing(by_64(plain))
+    # strata that do not divide the multiset are as equal as can be
+    assert sorted(loadgen.stratified_order(rng, costs, 7)) == sorted(order)
+
+
+def test_a_backlog_is_one_multiset_in_the_seeds_shuffle():
+    a = loadgen.backlog_requests(SAT256, 1, 100, 0)
+    b = loadgen.backlog_requests(SAT256, 2, 100, 0)
+    again = loadgen.backlog_requests(SAT256, 1, 100, 0)
+    assert _cost(a) == _cost(again) and _cost(a) != _cost(b)
+    assert sorted(_cost(a)) == sorted(_cost(b))
+    assert all((x.tokens == y.tokens).all() for x, y in zip(a, again))
+    # the parent's order, which the ledger's history of decode-sat rests on
+    pairs = loadgen.length_pairs(256, SAT["prompt_len"], SAT["output_len"])
+    want = [pairs[int(k)] for k in np.random.default_rng(1).permutation(256)]
+    assert [(len(r.tokens), r.max_new_tokens) for r in a] == want
+
+
+def test_the_arrivals_span_the_same_time_and_bring_the_same_work():
+    import json
+    import os
+
+    from .common import BENCH_DIR
+    with open(os.path.join(BENCH_DIR, "traffic", "chat-steady.json")) as f:
+        chat = json.load(f)
+    runs = [loadgen.open_loop_schedule(chat, seed, 45, 50257)
+            for seed in (1, 2 ** 31 + 5)]
+    body = [[r for r in run if r.counted] for run in runs]
+    assert len(body[0]) == 1890 and _multiset(runs[0]) == _multiset(runs[1])
+    assert [r.due_s for r in body[0]] != [r.due_s for r in body[1]]
+    # a plain shuffle of the same gaps and pairs, for comparison
+    rng = np.random.default_rng(3)
+    gaps = np.diff([0.0] + [r.due_s for r in body[0]])
+    dues = np.cumsum(gaps[rng.permutation(len(gaps))])
+    plain = [(d, body[0][int(k)]) for d, k in
+             zip(dues, rng.permutation(len(body[0])))]
+
+    def swings(run, of):
+        """Largest departure of a 5 s stretch from the mean stretch."""
+        sums = [0.0] * 9
+        for due, r in run:
+            sums[min(8, int(due // 5))] += of(r)
+        mean = sum(sums) / len(sums)
+        return max(abs(x - mean) for x in sums) / mean
+    count = lambda r: 1.0
+    work = lambda r: len(r.tokens) + r.max_new_tokens
+    for run in body:
+        run = [(r.due_s, r) for r in run]
+        assert swings(run, count) < 0.05 and swings(run, work) < 0.07
+    assert swings(plain, work) > 0.08 and swings(plain, count) > 0.06
+    # arrivals stay exponential inside a run of 16: some land together
+    short = lambda dues: int((np.diff(dues) < 0.25 / 42).sum())
+    mine = [r.due_s for r in body[0]]
+    assert short(mine) > 50 and short(mine) > 0.5 * short(dues)
+    assert np.diff(mine).max() > 2.5 / 42
+
+
+def test_the_poll_period_and_the_orders_are_no_knobs_of_a_traffic_file():
+    """One value each was in use (REVIEW of PR 32): the backlog polls every
+    4 ms as it did at the parent, and no traffic file chooses an order."""
+    import inspect
+    import json
+    import os
+
+    from benchmarks.chip.kinds import backlog
+
+    from .common import BENCH_DIR
+    assert "poll_s" not in inspect.getsource(backlog)
+    slept = []
+    b = loadgen.Backlog(lambda r: FakeHandle(done_after_polls=1),
+                        loadgen.backlog_requests(SAT, 1, 100, slots=2),
+                        outstanding=2, sleep=slept.append)
+    b.run_until(lambda: len(slept) >= 3)
+    assert slept == [0.004, 0.004, 0.004]
+    source = inspect.getsource(loadgen)
+    for knob in ("strata", "seed_rotates", "poll_s", "trace_at_s"):
+        assert f'"{knob}"' not in source
+        for name in os.listdir(os.path.join(BENCH_DIR, "traffic")):
+            with open(os.path.join(BENCH_DIR, "traffic", name)) as f:
+                assert knob not in json.load(f), (name, knob)
+
+
+# ---- the two sets' arithmetic (``spread.py``) ---------------------------
+
+def test_spread_is_the_interquartile_distance_over_the_median():
+    import statistics
+
+    from benchmarks.chip import spread
+    values = [100.0, 101.0, 99.0, 100.5, 99.5, 130.0]
+    q = statistics.quantiles(values, n=4)
+    assert spread.spread(values) == pytest.approx(
+        (q[2] - q[0]) / statistics.median(values))
+    assert spread.spread([5.0]) == 0.0
+    # the run farthest from the median is left out for tightness
+    assert 130.0 not in spread.without_farthest(values)
+    assert spread.spread(spread.without_farthest(values)) < 0.02
+    s = spread.summarize(values, [v * 1.02 for v in values])
+    assert s["b_over_a"] == pytest.approx(1.02)
+    assert s["tight"] < 0.02 < s["loose"]
+    assert s["loose"] >= max(s["spread_a"], s["spread_b"])
+
+
+def test_a_rotating_seed_offers_every_seed_the_same_neighbours():
+    chat = dict(CHAT, rate_hz=42.0)
+    runs = [[r for r in loadgen.open_loop_schedule(chat, seed, 45, 50257)
+             if r.counted] for seed in (1, 2, 2 ** 31 + 5)]
+    key = lambda r: (len(r.tokens), r.max_new_tokens)
+    first = [key(r) for r in runs[0]]
+    gaps0 = np.diff([r.due_s for r in runs[0]])
+    for other in runs[1:]:
+        seq = [key(r) for r in other]
+        assert seq != first and sorted(seq) == sorted(first)
+        # one cycle, begun elsewhere: some rotation of it is the first's
+        at = next(i for i in range(len(seq))
+                  if seq[i:] + seq[:i] == first)
+        assert at > 0
+        # the gaps rotate with the lengths: request k of the first run is
+        # request k + at of this one, as far from its neighbour
+        gaps = np.diff([r.due_s for r in other])
+        assert np.allclose(gaps[at:at + 200], gaps0[:200])
+        # and the token ids are the seed's own
+        assert not (other[0].tokens[:8] == runs[0][(len(seq) - at)
+                                                   % len(seq)].tokens[:8]).all()
+    again = [r for r in loadgen.open_loop_schedule(chat, 1, 45, 50257)
+             if r.counted]
+    assert [r.due_s for r in again] == [r.due_s for r in runs[0]]

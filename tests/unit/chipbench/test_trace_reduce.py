@@ -57,6 +57,23 @@ def test_a_gap_is_named_by_the_local_host_event_that_overlaps_it_most():
     assert R.name_gap((30.0, 31.0), host[1:]) == "(no host event)"
 
 
+def test_a_wait_never_names_a_gap():
+    """The tracer's ``waits`` line holds what a request saw (``serve.queue``),
+    not what the host did: a wait that ends in a gap's tail, or covers it
+    whole, leaves the gap to the thread that caused it."""
+    host = [R.HostEvent(R.WAIT_LINE, "serve.queue", 3.0, 6.0),
+            R.HostEvent("scheduler", "serve.admit", 5.5, 9.0),
+            R.HostEvent("scheduler", "serve.prefill", 5.8, 6.4)]
+    assert R.name_gap((5.0, 6.0), host) == "serve.admit"
+    assert R.name_gap((3.5, 4.5), host) == "(no host event)"
+    # the same events on a thread's own line would have named both
+    as_thread = [R.HostEvent("t", e.name, e.start, e.end) for e in host]
+    assert R.name_gap((5.0, 6.0), as_thread) == "serve.queue"
+    assert R.name_gap((3.5, 4.5), as_thread) == "serve.queue"
+    from deepspeed_tpu.telemetry.spans import WAIT_THREAD
+    assert R.WAIT_LINE == WAIT_THREAD
+
+
 def test_busy_idle_and_exposed_collectives_on_two_devices():
     fusion = "%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kLoop"
     done = "%all-gather-done.1 = bf16[8]{0} all-gather-done((bf16[8]) %s)"

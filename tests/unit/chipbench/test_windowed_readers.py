@@ -31,10 +31,11 @@ NEW = {
 
 def _ctx(spans, opening=100.0, seconds=10.0):
     """A context whose window is [opening, opening + seconds) on the
-    spans' clock: the process started at 90, set-up took 10."""
+    spans' clock: the process started at 90 and the window opened 10 s
+    later, 4 s of them the backend's start, which ``setup_s`` leaves out."""
     return types.SimpleNamespace(
         spans=spans, seconds=seconds, t_process=opening - 10.0,
-        scalars={"setup_s": 10.0})
+        scalars={"setup_s": 6.0, "opening_after_s": 10.0})
 
 
 def test_the_window_is_the_openings_perf_counter_on_the_spans_clock():
@@ -138,3 +139,28 @@ def test_the_traced_rehearsal_reports_the_metrics_that_read_the_new_spans(
                             seed=2 ** 31 + 5, trace=True)
     assert out["correct"], capsys.readouterr().out
     assert NEW[cell] <= set(out["metrics"])
+
+
+@pytest.mark.parametrize("metric", ["engine.step_ms_p50.train",
+                                    "engine.step_ms_p50.zero3"])
+def test_the_step_time_reads_the_engines_own_span(metric):
+    """``engine.step_ms_p50.*`` is the median of the engine's ``train.step``
+    spans that start inside the window, not the benchmark's fence."""
+    import json
+    import os
+
+    from .common import BENCH_DIR
+    with open(os.path.join(BENCH_DIR, "metrics", metric + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "span_percentile_windowed"
+    assert spec["args"] == {"span": "train.step", "q": 50}
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    assert entry["source"] == "program_span"
+    spans = [Span("train.step", 99.0, 9.0),          # warm-up: compiles
+             Span("train.step", 100.5, 0.600), Span("train.step", 101.2, 0.610),
+             Span("train.step", 101.9, 0.640),
+             Span("bench.train_step", 100.4, 0.700)]  # the fence: not read
+    assert span_percentile_windowed.read(_ctx(spans), **spec["args"]) == \
+        pytest.approx(610.0)
+    assert span_percentile_windowed.read(_ctx(spans[-1:]),
+                                         **spec["args"]) is None
