@@ -179,8 +179,10 @@ def init_inference(model=None, config=None, **kwargs):
         from .inference.engine import BertInferenceEngine
         return BertInferenceEngine(model[0], model[1], inf_config,
                                    mesh_manager=get_mesh_manager(optional=True))
-    if isinstance(model, tuple) and len(model) == 2 \
-            and isinstance(model[0], gpt_mod.GPTConfig):
+    if isinstance(model, tuple) and len(model) == 2 and (
+            isinstance(model[0], gpt_mod.GPTConfig)
+            # a config class that names its cache family (models/__init__)
+            or getattr(model[0], "cache_family", None) is not None):
         model_config, params = model
     elif isinstance(model, ModelSpec):
         assert model.params is not None, \

@@ -114,19 +114,13 @@ class InferenceEngine:
         # model-family dispatch: dense GPT vs MoE (reference MoE inference,
         # ops/transformer/inference/moe_inference.py + engine.py:190 expert
         # groups — here the expert mesh axis shards the expert stacks)
-        from ..models import cache_family, gpt_inference
+        from ..models import cache_family
         cfg = self.model_config
         self._kv_dtype = ("int8" if config.kv_cache_dtype == "int8"
                           else None)
-        self._family = cache_family(cfg)
-        if self._family is gpt_inference:
-            self._apply_fn = lambda p, t: gpt.apply(p, t, cfg)
-            self._logical_axes = gpt.logical_axes(cfg)
-        else:
-            from ..models import gpt_moe
-            self._apply_fn = lambda p, t: gpt_moe.apply(p, t, cfg,
-                                                        train=False)[0]
-            self._logical_axes = gpt_moe.logical_axes(cfg)
+        self._family = fam = cache_family(cfg)
+        self._apply_fn = lambda p, t: fam.apply(p, t, cfg)
+        self._logical_axes = fam.logical_axes(cfg)
         self.params = _shard_and_quantize(
             self.params, self._logical_axes, self.mesh_manager, want_tp,
             self._weight_int8, int8_compute=self._int8_compute)

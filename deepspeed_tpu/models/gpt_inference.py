@@ -16,6 +16,14 @@ implementation — and so does GPT-MoE (``gpt_moe_inference``), which brings
 only its own scan step: the cache class, the layer scan and the slot ops
 below are the one cache family of the tree.
 
+What a family brings is a :class:`Family`: its scan step, how a layer makes
+its queries and the row it caches, how it attends, its embedding and head.
+The ROW is the family's too (``config.cache_row``: the widths of the banks a
+cached token takes in a layer): the dense block keeps K and V, two banks of
+``H*D``; a latent-attention block (``latent_moe_inference``) keeps one
+compressed row shared by all heads.  ``KVCache``, ``init_cache``, the slot
+ops and the sweep's block take that row, never ``n_head * head_dim``.
+
 Cache layout [L, B, S_max, H*D]: static shapes (XLA requirement), masked by
 the current length; decode attention reads the cache tiled over S_max with
 positions beyond ``pos`` masked.  A token's heads are folded into ONE row
@@ -43,6 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import gpt
+from .gpt import apply, logical_axes  # noqa: F401  (the family's names)
 
 PyTree = Any
 
@@ -50,19 +59,26 @@ PyTree = Any
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KVCache:
-    """``k_scale``/``v_scale`` are ``None`` for a full-precision cache; for
+    """The banks of one cache: ``k`` and ``v`` for a family whose row is two
+    banks, ``k`` alone (``v`` None) for a one-bank row (a latent row).
+    ``k_scale``/``v_scale`` are ``None`` for a full-precision cache; for
     an int8 cache (``kv_cache_dtype: "int8"``) k/v hold codes and the
     scales are per-vector fp32 [L, B, S_max, H] — half the cache HBM,
-    dequantized inside the decode kernel's VMEM stream."""
+    dequantized inside the decode kernel's VMEM stream.  ``stats`` (None
+    for most families) is a small int32 vector of counters a family's scan
+    step adds to on the device (an expert layer's pair counts): it rides
+    the donated cache and reaches the host with the tick's own pull."""
 
-    k: jnp.ndarray        # [L, B, S_max, H*D]
-    v: jnp.ndarray        # [L, B, S_max, H*D]
+    k: jnp.ndarray        # [L, B, S_max, row[0]]
+    v: Any                # [L, B, S_max, row[1]] or None
     length: jnp.ndarray   # [] int32 — tokens already cached
     k_scale: Any = None
     v_scale: Any = None
+    stats: Any = None
 
     def tree_flatten(self):
-        return (self.k, self.v, self.length, self.k_scale, self.v_scale), None
+        return (self.k, self.v, self.length, self.k_scale, self.v_scale,
+                self.stats), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -81,23 +97,39 @@ class KVCache:
         return self.k_scale is not None
 
 
-def init_cache(config: gpt.GPTConfig, batch: int, max_len: int,
-               kv_dtype=None) -> KVCache:
+def cache_row(config) -> Tuple[int, ...]:
+    """The widths of the banks a cached token takes in one layer: the
+    family's declaration (``config.cache_row``), else the dense block's two
+    banks of ``H*D``."""
+    row = getattr(config, "cache_row", None)
+    return tuple(row) if row is not None else \
+        (config.n_head * config.head_dim,) * 2
+
+
+def init_cache(config, batch: int, max_len: int, kv_dtype=None,
+               stats: int = 0) -> KVCache:
     """``kv_dtype``: None → cache in the compute dtype; ``"int8"``/
     ``jnp.int8`` → int8 codes + per-vector fp32 scales (beyond-reference:
     halves decode HBM traffic and doubles the context/batch a chip's
-    cache budget holds)."""
-    shape = (config.n_layer, batch, max_len, config.n_head * config.head_dim)
+    cache budget holds; the two-bank dense row only).  ``stats``: the
+    length of the family's counter vector (0: none)."""
+    row = cache_row(config)
+    shapes = [(config.n_layer, batch, max_len, w) for w in row]
     if kv_dtype in ("int8", jnp.int8):
-        scales = shape[:-1] + (config.n_head,)
-        return KVCache(k=jnp.zeros(shape, jnp.int8),
-                       v=jnp.zeros(shape, jnp.int8),
+        if len(row) != 2:
+            raise NotImplementedError(
+                "the int8 cache (codes and per-head scale banks) exists for "
+                f"the two-bank dense row only; this family's row is {row}")
+        scales = shapes[0][:-1] + (config.n_head,)
+        return KVCache(k=jnp.zeros(shapes[0], jnp.int8),
+                       v=jnp.zeros(shapes[1], jnp.int8),
                        length=jnp.zeros((), jnp.int32),
                        k_scale=jnp.zeros(scales, jnp.float32),
                        v_scale=jnp.zeros(scales, jnp.float32))
-    return KVCache(k=jnp.zeros(shape, config.dtype),
-                   v=jnp.zeros(shape, config.dtype),
-                   length=jnp.zeros((), jnp.int32))
+    banks = [jnp.zeros(shape, config.dtype) for shape in shapes]
+    return KVCache(k=banks[0], v=banks[1] if len(banks) > 1 else None,
+                   length=jnp.zeros((), jnp.int32),
+                   stats=jnp.zeros((stats,), jnp.int32) if stats else None)
 
 
 def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
@@ -148,111 +180,188 @@ def _block_tail(x, attn, p, config: gpt.GPTConfig):
 
 
 def dense_step(params: PyTree, config: gpt.GPTConfig):
-    """The dense stack's half of :func:`_layer_scan`: ``(stacks, body)``.
-    One scan step is one block: attention at layer ``i``, then its tail."""
-    def body(x, p, i, attend, banks):
-        a, banks = attend(x, p, i, banks)
-        return _block_tail(x, a, p, config), banks
+    """The dense stack's half of :func:`_layer_scan`: one segment,
+    ``[(stacks, body)]``.  One scan step is one block: attention at layer
+    ``i``, then its tail."""
+    def body(x, p, i, attend, cache):
+        a, cache = attend(x, p, i, cache)
+        return _block_tail(x, a, p, config), cache
 
-    return params["blocks"], body
+    return [(params["blocks"], body)]
 
 
-def _layer_scan(x, params, cache: KVCache, config: gpt.GPTConfig, positions,
-                write, attn, step=dense_step):
+def _dense_project(x, p, config, positions):
+    q, k, v = gpt.qkv_proj(x, p, config, positions=positions)
+    return q, (k, v)
+
+
+def _dense_attend_fresh(q, fresh, cache, config, idx):
+    # prefill attention runs on the unpadded k/v (training flash path);
+    # only decode reads back through the padded cache
+    k, v = fresh
+    return gpt._attention(q, k, v, config,
+                          window=gpt.layer_window(config, idx, k.shape[1]))
+
+
+def _dense_attend_cached(q, cache, pos, config, idx, active=None,
+                         sweep=None):
+    return _cached_attention(
+        q, cache.k, cache.v, pos, config,
+        window=gpt.layer_window(config, idx, cache.max_len),
+        k_scale=cache.k_scale, v_scale=cache.v_scale, layer=idx,
+        active=active, sweep=sweep)
+
+
+def sweep_geometry(config: gpt.GPTConfig, max_len: int):
+    """``(block_k, windows)``: the decode kernel's block for this family's
+    row, and its calls in one tick as ``(window or None, layers)`` pairs,
+    one per distinct per-layer window (``sweep_block_counts``'s constants,
+    host values)."""
+    import collections
+
+    import numpy as np
+    from ..ops.pallas.decode_attention import decode_block_k
+    windows = gpt.layer_window(config, np.arange(config.n_layer), max_len)
+    return decode_block_k(max_len, cache_row(config)[0]), (
+        ((None, config.n_layer),) if windows is None
+        else tuple(collections.Counter(
+            int(w) for w in np.asarray(windows)).items()))
+
+
+def _dense_sweeps(pos, B, config: gpt.GPTConfig, max_len, active):
+    """The decode kernel's work list (``decode_sweep``) for every layer,
+    built ONCE, before the layer scan: a function of the step's ``pos`` and
+    ``active`` alone, and, in a banded stack, of each layer's window (all
+    layers' lists in one vectorised build).  Returns ``idx -> sweep``."""
+    from ..ops.pallas.decode_attention import decode_block_k, decode_sweep
+    block_k = decode_block_k(max_len, cache_row(config)[0])
+    if config.local_attention_window <= 0:
+        sweep = decode_sweep(pos, B, max_len, block_k, active)
+        return lambda idx: sweep
+    windows = gpt.layer_window(config, jnp.arange(config.n_layer), max_len)
+    sweeps = jax.vmap(
+        lambda w: decode_sweep(pos, B, max_len, block_k, active, w))(windows)
+    return lambda idx: jax.tree_util.tree_map(lambda a: a[idx], sweeps)
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """What a model family brings to the one cache family.
+
+    ``step(params, config)``: the scan's segments, ``[(stacks, body), ...]``
+    in depth order: the parameter stacks a scan walks and ``body(x, p, i,
+    attend, cache) -> (x, cache)``, what step ``i`` of that segment does
+    with ``attend(x, p, layer, cache) -> (attention output, cache)``
+    (:func:`dense_step`: one block a step; GPT-MoE: a dense and an expert
+    block, layers ``2i`` and ``2i+1`` of the same pool; a stack with
+    leading dense layers: two segments).  A body may add to
+    ``cache.stats``.
+    ``project(x, p, config, positions) -> (q, fresh)``: a layer's queries
+    and the row it caches, one array per bank, ``[B, S, ...]`` (trailing
+    dimensions are folded into the bank's one).
+    ``attend_fresh(q, fresh, cache, config, layer)``: a prompt pass from
+    position 0; ``attend_cached(q, cache, pos, config, layer, active,
+    sweep)``: a chunk or one token against layer ``layer`` of the pool.
+    ``sweeps(pos, B, config, max_len, active) -> (layer -> sweep)``: the
+    decode kernel's work lists, built before the scan.
+    ``embed(params, tokens, config, positions)`` and ``logits(params, x,
+    config)``."""
+    step: Any
+    project: Any = _dense_project
+    attend_fresh: Any = _dense_attend_fresh
+    attend_cached: Any = _dense_attend_cached
+    sweeps: Any = _dense_sweeps
+    embed: Any = gpt.embed
+    logits: Any = gpt.lm_logits
+
+
+DENSE = Family(step=dense_step)
+
+
+def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
+                family: Family = DENSE):
     """The one layer-stack scan every cache-filling path shares.
 
-    The stacked banks ride the scan's CARRY, never its ``xs``/``ys``: no
-    layer is sliced out of the pool and none is written back into a
-    second stack.  ``write(bank, layer, val)`` places this step's K/V (or
-    scale) column(s) into layer ``layer`` of the stacked bank, in place;
-    int8 caches quantize per vector first and write codes + scales through
-    the same ``write``.  ``attn(q, k, v, ck, cv, ksc, vsc, layer)``
-    computes the sublayer's attention (prefill reads the fresh unpadded
-    k/v; extend/decode read layer ``layer`` of the updated stacks ``ck``/
-    ``cv`` where it lies).
-
-    ``step(params, config)`` is the model family's: the parameter stacks
-    the scan walks and ``body(x, p, i, attend, banks) -> (x, banks)``, what
-    scan step ``i`` does with ``attend(x, p, layer, banks) -> (attention
-    output, banks)`` (:func:`dense_step`: one block a step; GPT-MoE: a
-    dense and an expert block, layers ``2i`` and ``2i+1`` of the same
-    pool).  Returns (hidden states, updated KVCache with the
-    caller-provided ``length``-less fields filled in).
+    The cache rides the scan's CARRY, never its ``xs``/``ys``: no layer is
+    sliced out of the pool and none is written back into a second stack.
+    ``write(bank, layer, val)`` places this step's row (or scale) column(s)
+    into layer ``layer`` of a stacked bank, in place; int8 caches quantize
+    per vector first and write codes + scales through the same ``write``.
+    ``attn(q, fresh, cache, layer)`` computes the sublayer's attention
+    (prefill reads the fresh unpadded rows; extend/decode read layer
+    ``layer`` of the updated pool where it lies).  ``family``: see
+    :class:`Family`.  Returns (hidden
+    states, updated KVCache, ``length`` untouched).
     """
     int8 = cache.int8
     if int8:
         from ..ops.pallas.decode_attention import quantize_kv
 
     def fold(t):
-        """[B, S, H, *] → [B, S, H * *]: a token's heads as one row."""
+        """[B, S, ...] → [B, S, *]: a token's row in one bank."""
         return t.reshape(t.shape[:2] + (-1,))
 
-    def attend(x, p, idx, banks):
-        ck, cv, ksc, vsc = banks
-        q, k, v = gpt.qkv_proj(x, p, config, positions=positions)
+    def attend(x, p, idx, cache):
+        q, fresh = family.project(x, p, config, positions)
         # the scopes name, in a profiler's trace, the two places a tick
         # touches the slot cache
         with jax.named_scope("cache_update"):
             if int8:
-                kq, ks = quantize_kv(k)
-                vq, vs = quantize_kv(v)
-                ck, cv = write(ck, idx, fold(kq)), write(cv, idx, fold(vq))
-                ksc = write(ksc, idx, fold(ks))
-                vsc = write(vsc, idx, fold(vs))
+                (kq, ks), (vq, vs) = map(quantize_kv, fresh)
+                cache = dataclasses.replace(
+                    cache, k=write(cache.k, idx, fold(kq)),
+                    v=write(cache.v, idx, fold(vq)),
+                    k_scale=write(cache.k_scale, idx, fold(ks)),
+                    v_scale=write(cache.v_scale, idx, fold(vs)))
             else:
-                ck = write(ck, idx, fold(k.astype(ck.dtype)))
-                cv = write(cv, idx, fold(v.astype(cv.dtype)))
+                banks = [write(bank, idx, fold(val.astype(bank.dtype)))
+                         for bank, val in zip((cache.k, cache.v), fresh)]
+                cache = dataclasses.replace(
+                    cache, k=banks[0], v=banks[1] if len(banks) > 1 else None)
         with jax.named_scope("cache_read"):
-            a = attn(q, k, v, ck, cv, ksc, vsc, idx)
-        return a, (ck, cv, ksc, vsc)
+            a = attn(q, fresh, cache, idx)
+        return a, cache
 
-    stacks, body = step(params, config)
+    for stacks, body in family.step(params, config):
+        def layer(carry, xs, body=body):
+            (x, cache), (p, i) = carry, xs
+            return body(x, p, i, attend, cache), None
 
-    def layer(carry, xs):
-        (x, banks), (p, i) = carry, xs
-        return body(x, p, i, attend, banks), None
-
-    n_steps = jax.tree_util.tree_leaves(stacks)[0].shape[0]
-    (x, (new_k, new_v, new_ksc, new_vsc)), _ = lax.scan(
-        layer, (x, (cache.k, cache.v, cache.k_scale, cache.v_scale)),
-        (stacks, jnp.arange(n_steps)))
-    return x, dataclasses.replace(cache, k=new_k, v=new_v,
-                                  k_scale=new_ksc, v_scale=new_vsc)
+        n_steps = jax.tree_util.tree_leaves(stacks)[0].shape[0]
+        (x, cache), _ = lax.scan(layer, (x, cache),
+                                 (stacks, jnp.arange(n_steps)))
+    return x, cache
 
 
-def prefill(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
-            cache: KVCache, step=dense_step) -> Tuple[jnp.ndarray, KVCache]:
+def prefill(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
+            family: Family = DENSE) -> Tuple[jnp.ndarray, KVCache]:
     """Run the prompt through the model, filling cache[0:S].
 
     Returns (logits [B, S, padded_vocab] fp32, cache).  Assumes an empty
     cache (length 0) — chunked prefill composes by calling with growing
-    ``cache.length`` via :func:`extend`.  ``step`` (here, in ``extend``
-    and in ``decode_step``) is the model family's scan step, see
-    :func:`_layer_scan`.
+    ``cache.length`` via :func:`extend`.  ``family`` (here, in ``extend``
+    and in ``decode_step``) is the model family's, see :class:`Family`.
     """
     B, S = tokens.shape
     positions = jnp.arange(S)
-    x = gpt.embed(params, tokens, config, positions=positions)
+    x = family.embed(params, tokens, config, positions=positions)
 
     def write(bank, layer, val):
         return lax.dynamic_update_slice(bank, val[None], (layer, 0, 0, 0))
 
-    def attn(q, k, v, ck, cv, ksc, vsc, idx):
-        # prefill attention runs on the unpadded k/v (training flash path);
-        # only decode reads back through the padded cache
-        return gpt._attention(q, k, v, config,
-                              window=gpt.layer_window(config, idx, S))
+    def attn(q, fresh, cache, idx):
+        return family.attend_fresh(q, fresh, cache, config, idx)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
-                           step)
-    logits = gpt.lm_logits(params, x, config)
+                           family)
+    logits = family.logits(params, x, config)
     return logits, dataclasses.replace(cache,
                                        length=jnp.asarray(S, jnp.int32))
 
 
-def extend(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
-           cache: KVCache, lengths=None,
-           step=dense_step) -> Tuple[jnp.ndarray, KVCache]:
+def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
+           lengths=None,
+           family: Family = DENSE) -> Tuple[jnp.ndarray, KVCache]:
     """Chunked prefill: append ``tokens`` [B, S_c] at positions
     ``cache.length .. cache.length+S_c-1``, attending causally over the
     cached prefix + the chunk.
@@ -301,17 +410,14 @@ def extend(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
             return lax.dynamic_update_slice(bank, val[None],
                                             (layer, 0, pos0, 0))
 
-    x = gpt.embed(params, tokens, config, positions=positions)
+    x = family.embed(params, tokens, config, positions=positions)
 
-    def attn(q, k, v, ck, cv, ksc, vsc, idx):
-        return _cached_attention(
-            q, ck, cv, pos0, config,
-            window=gpt.layer_window(config, idx, cache.max_len),
-            k_scale=ksc, v_scale=vsc, layer=idx)
+    def attn(q, fresh, cache, idx):
+        return family.attend_cached(q, cache, pos0, config, idx)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
-                           step)
-    logits = gpt.lm_logits(params, x, config)
+                           family)
+    logits = family.logits(params, x, config)
     return logits, dataclasses.replace(cache,
                                        length=jnp.max(pos0) + Sc)
 
@@ -322,7 +428,15 @@ def extend(params: PyTree, tokens: jnp.ndarray, config: gpt.GPTConfig,
 # retires/admits conversations per ROW without touching the others.  These
 # three ops are that contract: ``row`` may be a traced scalar, so one
 # compiled program serves every slot — admitting into slot 7 never
-# recompiles the program that admitted into slot 2.
+# recompiles the program that admitted into slot 2.  They walk whatever
+# banks the family's row has (a bank the cache lacks is None and stays so).
+
+
+def _each_bank(f, cache: KVCache, *others: KVCache) -> dict:
+    """``f`` over every bank (and scale bank) the cache holds."""
+    return {name: f(getattr(cache, name), *(getattr(o, name) for o in others))
+            for name in ("k", "v", "k_scale", "v_scale")
+            if getattr(cache, name) is not None}
 
 
 def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
@@ -331,7 +445,7 @@ def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
     finished generation).  ``src`` must share the cache dtype layout;
     its ``max_len`` must not exceed the slot cache's.  ``length`` keeps
     max-frontier semantics — the slot engine tracks per-row lengths
-    itself."""
+    itself.  ``src``'s counters are added to the pool's."""
     if src.int8 != cache.int8:
         raise ValueError(
             f"write_slot dtype mismatch: src int8={src.int8}, "
@@ -345,10 +459,9 @@ def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
         return lax.dynamic_update_slice(dst, s, (0, row, 0, 0))
 
     return dataclasses.replace(
-        cache, k=ins(cache.k, src.k), v=ins(cache.v, src.v),
-        length=jnp.maximum(cache.length, src.length),
-        k_scale=ins(cache.k_scale, src.k_scale) if cache.int8 else None,
-        v_scale=ins(cache.v_scale, src.v_scale) if cache.int8 else None)
+        cache, length=jnp.maximum(cache.length, src.length),
+        stats=None if cache.stats is None else cache.stats + src.stats,
+        **_each_bank(ins, cache, src))
 
 
 def reset_slot(cache: KVCache, row) -> KVCache:
@@ -358,47 +471,28 @@ def reset_slot(cache: KVCache, row) -> KVCache:
         blank = jnp.zeros((buf.shape[0], 1) + buf.shape[2:], buf.dtype)
         return lax.dynamic_update_slice(buf, blank, (0, row, 0, 0))
 
-    return dataclasses.replace(
-        cache, k=z(cache.k), v=z(cache.v),
-        k_scale=z(cache.k_scale) if cache.int8 else None,
-        v_scale=z(cache.v_scale) if cache.int8 else None)
+    return dataclasses.replace(cache, **_each_bank(z, cache))
 
 
 def read_slot(cache: KVCache, row, length=None) -> KVCache:
     """Slot ``row`` as a batch-1 cache (retiring a live conversation back
     to a session).  ``length`` is the row's true frontier (the multi-slot
-    ``cache.length`` only tracks the max)."""
+    ``cache.length`` only tracks the max).  Counters stay with the pool:
+    the copy's start at zero."""
     def rd(buf):
         return lax.dynamic_slice(buf, (0, row, 0, 0),
                                  (buf.shape[0], 1) + buf.shape[2:])
 
-    return KVCache(
-        k=rd(cache.k), v=rd(cache.v),
-        length=jnp.asarray(length if length is not None else cache.length,
-                           jnp.int32),
-        k_scale=rd(cache.k_scale) if cache.int8 else None,
-        v_scale=rd(cache.v_scale) if cache.int8 else None)
+    return dataclasses.replace(
+        cache, length=jnp.asarray(
+            length if length is not None else cache.length, jnp.int32),
+        stats=None if cache.stats is None else jnp.zeros_like(cache.stats),
+        **_each_bank(rd, cache))
 
 
-def _layer_sweeps(pos, B, config: gpt.GPTConfig, max_len, active):
-    """The decode kernel's work list (``decode_sweep``) for every layer,
-    built ONCE, before the layer scan: a function of the step's ``pos`` and
-    ``active`` alone, and, in a banded stack, of each layer's window (all
-    layers' lists in one vectorised build).  Returns ``idx -> sweep``."""
-    from ..ops.pallas.decode_attention import decode_block_k, decode_sweep
-    block_k = decode_block_k(max_len, config.n_head * config.head_dim)
-    if config.local_attention_window <= 0:
-        sweep = decode_sweep(pos, B, max_len, block_k, active)
-        return lambda idx: sweep
-    windows = gpt.layer_window(config, jnp.arange(config.n_layer), max_len)
-    sweeps = jax.vmap(
-        lambda w: decode_sweep(pos, B, max_len, block_k, active, w))(windows)
-    return lambda idx: jax.tree_util.tree_map(lambda a: a[idx], sweeps)
-
-
-def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
-                cache: KVCache, lengths=None, active=None,
-                step=dense_step) -> Tuple[jnp.ndarray, KVCache]:
+def decode_step(params: PyTree, token: jnp.ndarray, config, cache: KVCache,
+                lengths=None, active=None,
+                family: Family = DENSE) -> Tuple[jnp.ndarray, KVCache]:
     """One-token decode: token [B] int32 at position cache.length — or,
     with ``lengths`` [B], at per-row positions (ragged right-padded
     prompts: each row's token lands on ITS next slot and sees only ITS
@@ -415,25 +509,22 @@ def decode_step(params: PyTree, token: jnp.ndarray, config: gpt.GPTConfig,
     ragged = lengths is not None
     pos = lengths if ragged else cache.length
     positions = pos[:, None] if ragged else pos[None]
-    x = gpt.embed(params, token[:, None], config, positions=positions)
-    sweep_of = _layer_sweeps(pos, B, config, cache.max_len, active)
+    x = family.embed(params, token[:, None], config, positions=positions)
+    sweep_of = family.sweeps(pos, B, config, cache.max_len, active)
 
     def write(bank, layer, val):
-        """One new [B, 1, H * *] row per slot at [layer, :, pos] (pos
-        shared or per-row)."""
+        """One new [B, 1, *] row per slot at [layer, :, pos] (pos shared or
+        per-row)."""
         if ragged:
             return bank.at[layer, jnp.arange(B), pos].set(val[:, 0])
         return lax.dynamic_update_slice(bank, val[None], (layer, 0, pos, 0))
 
-    def attn(q, k, v, ck, cv, ksc, vsc, idx):
-        return _cached_attention(
-            q, ck, cv, pos, config,
-            window=gpt.layer_window(config, idx, cache.max_len),
-            k_scale=ksc, v_scale=vsc, layer=idx, active=active,
-            sweep=sweep_of(idx))
+    def attn(q, fresh, cache, idx):
+        return family.attend_cached(q, cache, pos, config, idx,
+                                    active=active, sweep=sweep_of(idx))
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
-                           step)
-    logits = gpt.lm_logits(params, x[:, 0], config)
+                           family)
+    logits = family.logits(params, x[:, 0], config)
     new_len = (jnp.max(pos) + 1) if ragged else pos + 1
     return logits, dataclasses.replace(cache, length=new_len)

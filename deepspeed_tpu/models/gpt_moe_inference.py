@@ -13,20 +13,35 @@ and nothing about its cache differs.  The cache class, the pool layout
 slot ops are ``gpt_inference``'s own; this module supplies what one scan step
 does — a dense block at layer ``2i``, an expert block at layer ``2i+1`` — and
 bounds the gate's dispatch tensors over a long prompt.
+
+What this family could share with ``latent_moe_inference`` and does not yet:
+the expert FFN.  ``moe/held_experts.py`` (a router, then the held experts'
+pairs multiplied grouped, dropless, linear in the tokens of a call) would
+replace ``_moe_infer_obj``'s ``[t, E, t]`` dispatch and with it
+``_PREFILL_CHUNK``'s 128-token walk; it needs the GShard softmax gate as a
+second ``route`` and GELU experts with biases in the grouped product
+(ROADMAP D2/D3).
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Tuple
 
 import jax.numpy as jnp
 
 from . import gpt, gpt_inference
 from .gpt_inference import (KVCache, init_cache, read_slot,  # noqa: F401
-                            reset_slot, write_slot)
-from .gpt_moe import GPTMoEConfig, _moe_obj
+                            reset_slot, sweep_geometry, write_slot)
+from .gpt_moe import GPTMoEConfig, _moe_obj, logical_axes  # noqa: F401
 
 PyTree = Any
+
+
+def apply(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig):
+    """Uncached full-sequence logits (eval gating)."""
+    from . import gpt_moe
+    return gpt_moe.apply(params, tokens, config, train=False)[0]
 
 
 def _moe_infer_obj(config: GPTMoEConfig):
@@ -52,20 +67,25 @@ def _moe_ffn(x, attn_p, moe_p, moe, config: GPTMoEConfig):
 
 
 def moe_step(params: PyTree, config: GPTMoEConfig):
-    """The GPT-MoE half of ``gpt_inference._layer_scan``: one scan step is
-    one (dense, MoE) pair, layers ``2i`` and ``2i+1`` of the one pool."""
+    """The GPT-MoE half of ``gpt_inference._layer_scan``: one segment whose
+    scan step is one (dense, MoE) pair, layers ``2i`` and ``2i+1`` of the
+    one pool."""
     moe = _moe_infer_obj(config)
 
-    def body(x, p, i, attend, banks):
+    def body(x, p, i, attend, cache):
         dense_p, attn_p, moe_p = p
-        a, banks = attend(x, dense_p, 2 * i, banks)
+        a, cache = attend(x, dense_p, 2 * i, cache)
         x = gpt_inference._block_tail(x, a, dense_p, config)
-        a, banks = attend(x, attn_p, 2 * i + 1, banks)
+        a, cache = attend(x, attn_p, 2 * i + 1, cache)
         x = x + gpt.attn_project(a, attn_p, config)
-        return _moe_ffn(x, attn_p, moe_p, moe, config), banks
+        return _moe_ffn(x, attn_p, moe_p, moe, config), cache
 
-    return (params["dense_blocks"], params["moe_attn_blocks"],
-            params["moe_blocks"]), body
+    return [((params["dense_blocks"], params["moe_attn_blocks"],
+              params["moe_blocks"]), body)]
+
+
+#: the dense family's row, projections and attention; only the step differs
+FAMILY = dataclasses.replace(gpt_inference.DENSE, step=moe_step)
 
 
 # dropless gating reserves capacity = tokens-per-call, so the dispatch/
@@ -86,7 +106,7 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
     B, S = tokens.shape
     if B * S <= _PREFILL_CHUNK:
         return gpt_inference.prefill(params, tokens, config, cache,
-                                     step=moe_step)
+                                     family=FAMILY)
     # chunk bounds depend only on the static shape, so this also
     # unrolls under an outer jit (the engine's whole-generate program)
     chunk = max(_PREFILL_CHUNK // B, 1)
@@ -105,7 +125,7 @@ def extend(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
     independent, so neither chunking nor ragged ``lengths`` can perturb a
     token's routing)."""
     return gpt_inference.extend(params, tokens, config, cache,
-                                lengths=lengths, step=moe_step)
+                                lengths=lengths, family=FAMILY)
 
 
 def decode_step(params: PyTree, token: jnp.ndarray, config: GPTMoEConfig,
@@ -114,4 +134,4 @@ def decode_step(params: PyTree, token: jnp.ndarray, config: GPTMoEConfig,
     """``gpt_inference.decode_step`` with the MoE step."""
     return gpt_inference.decode_step(params, token, config, cache,
                                      lengths=lengths, active=active,
-                                     step=moe_step)
+                                     family=FAMILY)

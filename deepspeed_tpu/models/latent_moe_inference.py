@@ -1,0 +1,153 @@
+"""Cached inference for the latent-attention, routed-expert family: a step
+and a row.
+
+The cache class, the layer scan and the slot ops are ``gpt_inference``'s
+own (the one cache family of the tree); this module brings what
+``gpt_inference.Family`` asks of a model family:
+
+- the **row**: one bank, ``[c | R(k_r)]`` (``config.cache_row``: the latent
+  and the shared rotary key, rounded up to whole lane rows), so the pool is
+  ``[L, B, S_max, W]`` and a cached token costs ``W`` elements a layer
+  whatever the number of heads;
+- the **step**: two segments, the leading dense layers (``first_k_dense``)
+  and the expert layers, layers in depth order in the one pool; the expert
+  layers add their pair counts to ``cache.stats`` (``[3 + n_held]`` int32:
+  pairs held here, pairs routed, expert visits, pairs per held expert);
+- projections and attention in the absorbed form, every pass through
+  ``ops/pallas/decode_attention.py``'s latent kernels (a prompt pass is a
+  chunk at position 0: the expert layer's cost is linear in a call's
+  tokens, so no family-side chunk walk bounds it).
+
+Not supported, refused where the cache is made: the int8 cache (its scale
+banks are per head; a latent row has no heads).  Speculation's dense draft
+and paging are the batcher's to refuse (``serving/batcher.py``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Tuple
+
+import jax.numpy as jnp
+
+from . import gpt_inference, latent_moe
+from .gpt_inference import (KVCache, read_slot, reset_slot,  # noqa: F401
+                            write_slot)
+from .latent_moe import (LatentMoEConfig, apply,  # noqa: F401
+                         logical_axes)
+
+PyTree = Any
+
+#: serving features this family is refused at construction, with the reason
+UNSUPPORTED = {
+    "speculative": "a dense draft's proposals are verified by a ragged "
+                   "extend this family has never been tested through",
+    "paging": "parked latent rows have no re-admission test yet",
+}
+
+
+def _stats_len(config: LatentMoEConfig) -> int:
+    return 3 + len(config.held) if config.n_moe_layers else 0
+
+
+def init_cache(config: LatentMoEConfig, batch: int, max_len: int,
+               kv_dtype=None) -> KVCache:
+    if kv_dtype is not None:
+        raise NotImplementedError(
+            "the latent-attention family caches in the compute dtype only: "
+            "the int8 cache's scale banks are per head and a latent row has "
+            f"no heads (kv_cache_dtype={kv_dtype!r})")
+    return gpt_inference.init_cache(config, batch, max_len,
+                                    stats=_stats_len(config))
+
+
+#: the routed experts' matrices: never an ``xs`` of the layer scan (a slice
+#: of a stack handed to a Pallas call is copied out first); the expert
+#: segment's body closes over the whole stacks and reads its layer in place
+_ROUTED = ("w_gu", "w_down")
+
+
+def _step(params: PyTree, config: LatentMoEConfig):
+    first = config.first_k_dense
+    moe = params["moe_blocks"]
+    routed = {k: moe[k] for k in _ROUTED}
+
+    def dense_body(x, p, i, attend, cache):
+        a, cache = attend(x, p, i, cache)
+        x = latent_moe.latent_output(x, a, p, config)
+        return latent_moe.dense_ffn(x, p, config), cache
+
+    def moe_body(x, p, i, attend, cache):
+        a, cache = attend(x, p, first + i, cache)
+        x = latent_moe.latent_output(x, a, p, config)
+        x, counts = latent_moe.expert_ffn(x, p, config, experts=routed,
+                                          layer=i)
+        return x, dataclasses.replace(cache, stats=cache.stats + counts)
+
+    segments = []
+    if first:
+        segments.append((params["dense_blocks"], dense_body))
+    if config.n_moe_layers:
+        segments.append(({k: v for k, v in moe.items() if k not in _ROUTED},
+                         moe_body))
+    return segments
+
+
+def _project(x, p, config: LatentMoEConfig, positions):
+    queries, row = latent_moe.latent_project(x, p, config, positions)
+    return queries, (row,)
+
+
+def _attend_cached(q, cache: KVCache, pos, config: LatentMoEConfig, idx,
+                   active=None, sweep=None):
+    from ..ops.pallas.decode_attention import cached_attention
+    return cached_attention(q, cache.k, None, pos,
+                            sm_scale=config.softmax_scale, layer=idx,
+                            active=active, sweep=sweep,
+                            latent_rank=config.kv_rank)
+
+
+def _attend_fresh(q, fresh, cache, config: LatentMoEConfig, idx):
+    # a prompt pass is a chunk at position 0 of the rows just written
+    return _attend_cached(q, cache, jnp.zeros((), jnp.int32), config, idx)
+
+
+def sweep_geometry(config: LatentMoEConfig, max_len: int):
+    """``gpt_inference.sweep_geometry`` for the latent row: its own block,
+    no banded layer."""
+    from ..ops.pallas.decode_attention import latent_block_k
+    return latent_block_k(max_len), ((None, config.n_layer),)
+
+
+def _sweeps(pos, B, config: LatentMoEConfig, max_len, active):
+    from ..ops.pallas.decode_attention import decode_sweep, latent_block_k
+    sweep = decode_sweep(pos, B, max_len, latent_block_k(max_len), active)
+    return lambda idx: sweep
+
+
+FAMILY = gpt_inference.Family(
+    step=_step, project=_project, attend_fresh=_attend_fresh,
+    attend_cached=_attend_cached, sweeps=_sweeps,
+    embed=lambda params, tokens, config, positions=None:
+        latent_moe.embed(params, tokens, config),
+    logits=latent_moe.lm_logits)
+
+
+def prefill(params: PyTree, tokens, config: LatentMoEConfig,
+            cache: KVCache) -> Tuple[jnp.ndarray, KVCache]:
+    return gpt_inference.prefill(params, tokens, config, cache,
+                                 family=FAMILY)
+
+
+def extend(params: PyTree, tokens, config: LatentMoEConfig, cache: KVCache,
+           lengths=None) -> Tuple[jnp.ndarray, KVCache]:
+    return gpt_inference.extend(params, tokens, config, cache,
+                                lengths=lengths, family=FAMILY)
+
+
+def decode_step(params: PyTree, token, config: LatentMoEConfig,
+                cache: KVCache, lengths=None,
+                active=None) -> Tuple[jnp.ndarray, KVCache]:
+    return gpt_inference.decode_step(params, token, config, cache,
+                                     lengths=lengths, active=active,
+                                     family=FAMILY)

@@ -1,0 +1,129 @@
+"""An expert layer that is told which experts it holds.
+
+Expert parallelism gives each chip a slice of a layer's experts.  The router
+keeps its published width (it scores every expert of the deployment, held
+here or not) and its experts per token; this chip multiplies only the
+(token, expert) pairs that land on the experts it holds and adds their part
+of the result.  What the absent experts would add is another chip's to add:
+on one chip the layer runs without its exchange, and nothing here stands in
+for the chips that are not there.
+
+The router is the published ``noaux_tc`` gate with one group: sigmoid scores
+over all experts **in float32**, the ``k`` largest of ``score + bias`` (the
+bias moves the selection and never the weight), the selected scores
+normalised to sum to one and scaled.
+
+The held pairs are multiplied grouped and dropless.  Pairs are sorted by the
+local index of their expert, held ones first, and the rows of each held
+expert meet that expert's matrices in one grouped product: on a TPU the
+Pallas grouped matmul that ships with JAX (``megablox.gmm``), whose grid is
+the row tiles of the live groups, so an expert's matrices are streamed once
+and the rows of pairs held elsewhere cost no step; elsewhere
+``lax.ragged_dot``, the same product.  No ``[tokens, experts, capacity]``
+tensor exists and the cost is linear in the tokens of a call.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, NamedTuple, Sequence, Tuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..ops.pallas.utils import interpret_mode, use_pallas
+
+#: the grouped matmul's (rows, contraction, columns) tile on the chip: 2 MB
+#: of an expert's matrix a step, streamed behind the product before it
+GMM_TILING = (128, 1024, 1024)
+
+
+class Routing(NamedTuple):
+    """``experts`` [T, k] global ids, ``weights`` [T, k] float32."""
+    experts: jnp.ndarray
+    weights: jnp.ndarray
+
+
+def route(h, w_router, bias, k: int, scale: float,
+          normalize: bool = True) -> Routing:
+    """The sigmoid top-k gate.  ``h`` [T, d] in any dtype, ``w_router``
+    [d, E], ``bias`` [E]: scores in float32 at full precision, selection by
+    ``score + bias``, weights from the scores alone."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, experts = lax.top_k(scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if normalize:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return Routing(experts.astype(jnp.int32), weights * scale)
+
+
+def local_slots(held: Sequence[int], n_experts: int) -> np.ndarray:
+    """``[n_experts]`` int32: a held expert's index among the held, and
+    ``len(held)`` for every expert held elsewhere."""
+    table = np.full((n_experts,), len(held), np.int32)
+    table[np.asarray(held, np.int64)] = np.arange(len(held), dtype=np.int32)
+    return table
+
+
+def _grouped(rows, w, group_sizes, layer=None):
+    """``rows[group g] @ w[g]`` for rows sorted by group; rows past the last
+    group come back as they may (the caller zeroes them).  With ``layer``
+    (a scan's index) ``w`` is the whole stack ``[layers, groups, k, n]`` and
+    the product reads layer ``layer`` of it WHERE IT LIES: the kernel sees
+    ``layers * groups`` groups, all empty but that layer's, and an empty
+    group costs no step.  (Handing a Pallas call one layer of a stack makes
+    the compiler copy that layer out first: 1.06 GB a layer a tick at the
+    published widths.)"""
+    if use_pallas() and not interpret_mode():
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+        if layer is not None:
+            n_groups = group_sizes.shape[0]
+            group_sizes = lax.dynamic_update_slice(
+                jnp.zeros((w.shape[0] * n_groups,), jnp.int32), group_sizes,
+                (layer * n_groups,))
+            w = w.reshape((-1,) + w.shape[2:])
+        return gmm(rows, w, group_sizes, preferred_element_type=rows.dtype,
+                   tiling=GMM_TILING)
+    if layer is not None:
+        w = lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+    return lax.ragged_dot(rows, w.astype(rows.dtype), group_sizes)
+
+
+def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
+                     held: Sequence[int], n_experts: int, layer=None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the layer's result, and the pair counts.
+
+    ``h`` [T, d]; ``p["w_gu"]`` [n_held, d, 2f] (gate beside up) and
+    ``p["w_down"]`` [n_held, f, d], the held experts' SwiGLU matrices in
+    the order of ``held``; with ``layer`` (a layer scan's index) both are
+    the whole stacks ``[layers, n_held, ...]``, read in place.  Returns ``(out [T, d], counts [n_held] int32)``:
+    ``sum_{i in sel, i held} w_i E_i(h)`` and the pairs each held expert
+    took."""
+    T, d = h.shape
+    k = routing.experts.shape[1]
+    n_held = len(held)
+    slot = jnp.asarray(local_slots(held, n_experts))[routing.experts]  # [T,k]
+    flat = slot.reshape(-1)
+    # held pairs first, by expert: a stable sort keeps tokens in order
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.sum(flat[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    with jax.named_scope("moe_routed"):
+        rows = h[order // k]                                   # [T*k, d]
+        gu = _grouped(rows, p["w_gu"], counts, layer)
+        f = gu.shape[-1] // 2
+        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+               * gu[:, f:].astype(jnp.float32)).astype(h.dtype)
+        y = _grouped(act, p["w_down"], counts, layer)              # [T*k, d]
+        w = jnp.where(flat < n_held, routing.weights.reshape(-1), 0.0)[order]
+        y = jnp.where(w[:, None] != 0, y.astype(jnp.float32) * w[:, None],
+                      0.0).astype(h.dtype)
+        # back to (token, choice) order, then each token's choices summed
+        back = jnp.argsort(order)
+        out = y[back].reshape(T, k, d).astype(jnp.float32).sum(axis=1)
+    return out.astype(h.dtype), counts

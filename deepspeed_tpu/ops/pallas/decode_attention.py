@@ -22,6 +22,17 @@ it.  The chunk kernel (``extend``: admission, speculative verify)
 still takes one head a step from a ``[B*H, S_max, D]`` view of one layer: a
 64-wide head is half a lane row and cannot be a block of the folded row.
 
+A latent cache (``models/latent_moe.py``) keeps ONE row per token and layer,
+shared by all heads: ``[c | R(k_r)]``, stored ``[L, B, S_max, W]`` with ``W``
+whole lane rows.  Its two kernels (``_latent_decode``, ``_latent_chunk``)
+compute the absorbed form: every head's query ``[q' | R(q_r)]`` scores the
+row and the probabilities weigh its first ``R`` elements (the latent ``c``
+itself; the value up-projection follows outside).  The decode kernel walks
+the same work list (``decode_sweep``) and reads the pool in place; a block
+is read once for all heads, 121 operations a byte at 64 heads, so it is the
+one kernel here that sits near the ridge and not far under it.
+``cached_attention`` picks them when the cache has one bank.
+
 Int8 cache variant (beyond the reference): k/v arrive as int8 codes with
 per-vector fp32 scales and are dequantized IN VMEM after the block load,
 so the HBM stream — the decode bottleneck — ships half the bytes.  Decode
@@ -112,6 +123,36 @@ def _unpack_rest(rest, quantized, windowed, alibi):
             vscale_ref, o_ref, acc_ref, m_ref, l_ref)
 
 
+def _sweep_position(rows_ref, n_ref):
+    """Where grid step ``program_id(0)`` stands in the sweep's work list:
+    ``(step, row, live, first, last)``: its row, whether it is a live entry
+    (before ``n``), and whether it is the first / the last of its row's
+    consecutive entries."""
+    step = pl.program_id(0)
+    n = n_ref[0]
+    row = rows_ref[step]
+    first = jnp.logical_or(step == 0,
+                           rows_ref[jnp.maximum(step - 1, 0)] != row)
+    last = jnp.logical_or(
+        step == n - 1,
+        rows_ref[jnp.minimum(step + 1, rows_ref.shape[0] - 1)] != row)
+    return step, row, step < n, first, last
+
+
+def _online_softmax_step(s, values, acc_ref, m_ref, l_ref):
+    """One block of the online-softmax recurrence: masked float32 scores
+    ``s`` [rows, keys] and the block's ``values`` [keys, width] into the
+    running max ``m``, sum ``l`` and accumulator ``acc``."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        p.astype(values.dtype), values, preferred_element_type=jnp.float32)
+
+
 def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
                    sm_scale, block_k, H, D, quantized, windowed, alibi):
     """One online-softmax decode kernel serving every cache layout.  A
@@ -144,17 +185,9 @@ def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
     (window_ref, slopes_ref, q_ref, k_ref, v_ref, kscale_ref, vscale_ref,
      o_ref, acc_ref, m_ref, l_ref) = _unpack_rest(rest, quantized,
                                                   windowed, alibi)
-    step = pl.program_id(0)
-    n = n_ref[0]
-    row = rows_ref[step]
+    step, row, live, first, last = _sweep_position(rows_ref, n_ref)
     ki = blocks_ref[step]
     pos = pos_ref[row]               # per-ROW visibility (ragged decode)
-    live = step < n
-    first = jnp.logical_or(step == 0,
-                           rows_ref[jnp.maximum(step - 1, 0)] != row)
-    last = jnp.logical_or(
-        step == n - 1,
-        rows_ref[jnp.minimum(step + 1, rows_ref.shape[0] - 1)] != row)
 
     def own():
         """(H, H*D) mask: lane c of row h belongs to head h."""
@@ -481,12 +514,224 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
                           name="chunk_attention")(*args)
 
 
+# ------------------------------------------------------------ latent rows
+
+def latent_attention_reference(q, bank, pos, sm_scale: float, rank: int):
+    """Ground truth of the absorbed form: ``q`` [B, Sq, H, W] over latent
+    rows ``bank`` [B, Smax, W]; query i (at ``pos + i``) sees rows at or
+    before it and weighs their first ``rank`` elements: [B, Sq, H, rank]."""
+    B, Sq = q.shape[:2]
+    Smax = bank.shape[1]
+    s = jnp.einsum("bqhw,bkw->bhqk", q, bank,
+                   preferred_element_type=jnp.float32) * sm_scale
+    pos = jnp.asarray(pos)
+    q_abs = (pos.reshape(-1, 1) if pos.ndim else pos) + jnp.arange(Sq)
+    mask = jnp.atleast_2d(q_abs)[:, :, None] >= jnp.arange(Smax)[None, None]
+    s = jnp.where(mask[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkr->bqhr", p.astype(q.dtype), bank[..., :rank])
+
+
+def latent_block_k(Smax: int) -> Optional[int]:
+    """Tokens in one streamed block of the latent sweep, or None where
+    ``Smax`` does not tile.  A block is read once for all heads, so a step
+    carries far more work than a dense one and a larger block pays
+    (measured at 128 slots x 8,192, PERF.md 6, PR 33: 5,981 tokens/s with
+    256, 6,390 with 512; at 1,024 the chunk pass overruns its VMEM)."""
+    return next((b for b in (512, 256, 128) if Smax % b == 0), None)
+
+
+def _latent_decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref,
+                          q_ref, k_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                          sm_scale, block_k, H, R):
+    """``_decode_kernel`` for a latent pool: a grid step is one live block
+    of one live row, ``k_ref`` its ``(block_k, W)`` latent rows, ``q_ref``
+    the row's ``(H, W)`` absorbed queries.  Two plain matmuls, no spreading:
+    every head scores the same rows and weighs the same ``R`` columns.  The
+    result leaves as one ``(1, H*R)`` row, head after head."""
+    step, row, live, first, last = _sweep_position(rows_ref, n_ref)
+    ki = blocks_ref[step]
+    pos = pos_ref[row]
+
+    @pl.when(jnp.logical_and(live, first))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(live)
+    def _update():
+        q = q_ref[...]                                     # (H, W)
+        kb = k_ref[...]                                    # (BK, W)
+        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale                                   # (H, BK)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _online_softmax_step(jnp.where(k_pos <= pos, s, NEG_INF), kb[:, :R],
+                             acc_ref, m_ref, l_ref)
+
+    @pl.when(jnp.logical_and(live, last))
+    def _finalize():
+        o = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)    # (H, R)
+        for h in range(H):
+            o_ref[:, h * R:(h + 1) * R] = o[h:h + 1]
+
+
+def _latent_decode(q, bank, layer, pos, sweep, sm_scale, block_k, R):
+    """The latent decode sweep: ``q`` [B, H, W] against layer ``layer`` of
+    the pool ``bank`` [L, B, Smax, W] where it lies; [B, 1, H*R]."""
+    B, H, W = q.shape
+    rows, blocks, n = sweep
+    kernel = functools.partial(_latent_decode_kernel, sm_scale=sm_scale,
+                               block_k=block_k, H=H, R=R)
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    prefetch = (rows, blocks, n, pos_arr,
+                jnp.asarray(layer, jnp.int32).reshape(1))
+    interpret = interpret_mode()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(rows.shape[0] if interpret else jnp.maximum(n[0], 1),),
+        in_specs=[
+            pl.BlockSpec((None, H, W),
+                         lambda s, rows_ref, *_: (rows_ref[s], 0, 0)),
+            pl.BlockSpec((None, None, block_k, W),
+                         lambda s, rows_ref, blocks_ref, n_ref, pos_ref,
+                         layer_ref: (layer_ref[0], rows_ref[s],
+                                     blocks_ref[s], 0)),
+        ],
+        out_specs=pl.BlockSpec((None, 1, H * R),
+                               lambda s, rows_ref, *_: (rows_ref[s], 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, R), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, H * R), q.dtype),
+        interpret=interpret, name="latent_decode_attention")(
+            *prefetch, q, bank)
+
+
+def _latent_chunk_kernel(pos_ref, layer_ref, q_ref, k_ref, o_ref, acc_ref,
+                         m_ref, l_ref, *, sm_scale, block_q, block_k, H, R):
+    """A chunk of queries against latent rows: ``q_ref`` is ``(block_q * H,
+    W)``, the heads of ``block_q`` consecutive positions row after row, so
+    one matmul scores them all against the block's rows; row ``i`` sits at
+    position ``pos + qi * block_q + i // H``."""
+    b = pl.program_id(0)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
+    pos = pos_ref[b]
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(ki * block_k <= pos + (qi + 1) * block_q - 1)
+    def _update():
+        q = q_ref[...]                                     # (BQ*H, W)
+        kb = k_ref[...]                                    # (BK, W)
+        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale
+        q_pos = pos + qi * block_q + \
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // H
+        k_pos = ki * block_k + \
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _online_softmax_step(jnp.where(k_pos <= q_pos, s, NEG_INF),
+                             kb[:, :R], acc_ref, m_ref, l_ref)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _latent_chunk(q, bank, layer, pos, sm_scale, block_q, block_k, R):
+    """``q`` [B, Sq, H, W] against layer ``layer`` of ``bank`` [L, B, Smax,
+    W]: [B, Sq, H, R].  Block indices past a query block's causal frontier
+    clamp onto it, so they move nothing."""
+    B, Sq, H, W = q.shape
+    Smax = bank.shape[2]
+    kernel = functools.partial(_latent_chunk_kernel, sm_scale=sm_scale,
+                               block_q=block_q, block_k=block_k, H=H, R=R)
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+
+    def kv_idx(b, qi, ki, pos_ref, layer_ref):
+        hi = (pos_ref[b] + (qi + 1) * block_q - 1) // block_k
+        return (layer_ref[0], b, jnp.minimum(ki, hi), 0)
+
+    rows = block_q * H
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, Sq // block_q, Smax // block_k),
+        in_specs=[
+            pl.BlockSpec((None, rows, W), lambda b, qi, ki, *_: (b, qi, 0)),
+            pl.BlockSpec((None, None, block_k, W), kv_idx),
+        ],
+        out_specs=pl.BlockSpec((None, rows, R),
+                               lambda b, qi, ki, *_: (b, qi, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rows, R), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+        ],
+    )
+    o = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sq * H, R), q.dtype),
+        interpret=interpret_mode(), name="latent_chunk_attention")(
+            pos_arr, jnp.asarray(layer, jnp.int32).reshape(1),
+            q.reshape(B, Sq * H, W), bank)
+    return o.reshape(B, Sq, H, R)
+
+
+def latent_cached_attention(q, bank, pos, sm_scale: float, rank: int,
+                            layer=None, active=None, sweep=None):
+    """Absorbed latent attention: ``q`` [B, Sq, H, W] over the latent pool
+    ``bank`` [L, B, Smax, W] at ``layer`` (or one layer [B, Smax, W]),
+    visibility ``<= pos + i``; returns each head's weighted sum of the rows'
+    first ``rank`` elements, [B, Sq, H, rank].  ``active`` and ``sweep`` as
+    in ``cached_attention``."""
+    B, Sq, H, W = q.shape
+    if layer is None:
+        bank, layer = bank[None], 0
+    Smax = bank.shape[2]
+    block_k = latent_block_k(Smax)
+    tiles = use_pallas() and block_k is not None
+    if tiles and Sq == 1:
+        if sweep is None:
+            sweep = decode_sweep(pos, B, Smax, block_k, active)
+        o = _latent_decode(q[:, 0], bank, layer, pos, sweep, sm_scale,
+                           block_k, rank).reshape(B, 1, H, rank)
+    else:
+        block_q = next((b for b in (16, 8) if Sq % b == 0), None)
+        if tiles and Sq > 1 and block_q is not None:
+            return _latent_chunk(q, bank, layer, pos, sm_scale, block_q,
+                                 block_k, rank)
+        one = jax.lax.dynamic_index_in_dim(bank, layer, 0, keepdims=False)
+        o = latent_attention_reference(q, one, pos, sm_scale, rank)
+        if Sq > 1:
+            return o
+    if active is None:
+        return o
+    return jnp.where(active[:, None, None, None], o, jnp.zeros_like(o))
+
+
 def cached_attention(q, cache_k, cache_v, pos,
                      sm_scale: Optional[float] = None,
                      k_scale=None, v_scale=None,
                      window=None, slopes=None, layer=None,
-                     active=None, sweep=None):
+                     active=None, sweep=None, latent_rank=None):
     """q [B,Sq,H,D] over a padded cache [B,Smax,H,D], visibility ≤ pos+i.
+
+    A cache of ONE bank (``cache_v`` None) is a latent pool: ``q`` holds the
+    absorbed queries, ``latent_rank`` says how much of a row the
+    probabilities weigh, and ``latent_cached_attention`` serves the call.
 
     With ``layer`` (scalar, may be traced — a layer scan's index) the
     cache operands are the whole stacked pool as ``gpt_inference`` stores
@@ -526,6 +771,10 @@ def cached_attention(q, cache_k, cache_v, pos,
     the ALiBi ``-slope·dist`` bias (BLOOM family) inside the kernel.  Both
     compose with the int8 cache.
     """
+    if cache_v is None:
+        return latent_cached_attention(q, cache_k, pos, sm_scale,
+                                       latent_rank, layer=layer,
+                                       active=active, sweep=sweep)
     B, Sq, H, D = q.shape
     int8_cache = k_scale is not None
     banks = (cache_k, cache_v) + ((k_scale, v_scale) if int8_cache else ())

@@ -40,9 +40,8 @@ sizes so tests can assert exactly that.
 
 from __future__ import annotations
 
-import collections
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -54,13 +53,19 @@ from ..inference.bucketing import bucket_cache_len, bucket_draft_k
 from ..inference.sampling import filter_logits
 from ..inference.speculative import (spec_accept_batch, spec_accept_keys,
                                      spec_draft_keys)
-from ..models import gpt
-from ..ops.pallas.decode_attention import (decode_block_k,
-                                            sweep_block_counts)
+from ..ops.pallas.decode_attention import sweep_block_counts
 from ..telemetry.spans import SpanName, Tracer
 from ..utils.compile_watch import CompiledProgramRegistry, hot_path
 from .config import ServingConfig
 from .paging import cache_bank_bytes
+
+
+class _Counted(NamedTuple):
+    """A plain tick's tokens with the counters the family's scan steps
+    added up on the device since the tick before (``KVCache.stats``): one
+    pytree, so one pull brings both."""
+    tokens: Any
+    counts: Any
 
 
 @dataclasses.dataclass
@@ -101,19 +106,28 @@ class SlotBatcher:
         self._wide = False
         fam = self._fam
         B = self.slots
+        # what the family does not serve is refused here, with its reason
+        for feature, on in (
+                ("speculative", config.speculative_config.enabled),
+                ("paging", config.paging_config.enabled)):
+            why = getattr(fam, "UNSUPPORTED", {}).get(feature)
+            if on and why:
+                raise NotImplementedError(
+                    f"serving.{feature} with {type(cfg).__name__}: {why}")
         self.cache = fam.init_cache(cfg, B, self.max_len,
                                     kv_dtype=self._kv_dtype)
-        #: bytes of the batch-1 cache every fresh prefill allocates
+        #: bytes of the batch-1 cache every fresh prefill allocates: the
+        #: family's row, whatever its banks
         self._row_cache_bytes = cache_bank_bytes(self.cache) // B
-        #: ``sweep_blocks``'s constants: the decode kernel's block and its
-        #: calls in one tick as (window, layers) pairs, one per distinct
-        #: per-layer window
-        self._block_k = decode_block_k(self.max_len,
-                                       cfg.n_head * cfg.head_dim)
-        windows = gpt.layer_window(cfg, np.arange(cfg.n_layer), self.max_len)
-        self._layer_windows = ((None, cfg.n_layer),) if windows is None \
-            else tuple(collections.Counter(
-                int(w) for w in np.asarray(windows)).items())
+        #: ``sweep_blocks``'s constants, the family's: the decode kernel's
+        #: block for its row and its calls in one tick as (window, layers)
+        #: pairs, one per distinct per-layer window
+        self._block_k, self._layer_windows = fam.sweep_geometry(
+            cfg, self.max_len)
+        #: what the family's scan steps counted on the device
+        #: (``KVCache.stats``), summed over the ticks pulled so far; None
+        #: for a family that counts nothing
+        self.device_counts = None
         self.lengths = jnp.zeros((B,), jnp.int32)
         self.keys = jnp.stack([jax.random.PRNGKey(0)] * B)
         self.greedy = jnp.ones((B,), bool)
@@ -235,6 +249,12 @@ class SlotBatcher:
                                             lengths=lengths, active=active)
             # only live slots advance; a freed slot re-writes its own cell
             new_lengths = jnp.where(active, lengths + 1, lengths)
+            if cache.stats is not None:
+                # the counters leave with the tokens (one pull) and start
+                # the next tick at zero
+                nxt = _Counted(nxt, cache.stats)
+                cache = dataclasses.replace(
+                    cache, stats=jnp.zeros_like(cache.stats))
             return nxt, logits, cache, new_lengths, next_keys
 
         def bind(lengths, last, keys, greedy, temp, active,
@@ -669,7 +689,13 @@ class SlotBatcher:
         # the emitted tokens ARE the tick's output boundary
         with self.tracer.span(SpanName.SERVE_PULL):
             # dslint: disable=host-sync-in-hot-path — one d2h pull per tick
-            return jax.device_get(pending)
+            got = jax.device_get(pending)
+        if isinstance(got, _Counted):
+            # a family's device counters came with the tokens
+            self.device_counts = got.counts.astype(np.int64) + (
+                0 if self.device_counts is None else self.device_counts)
+            return got.tokens
+        return got
 
     @hot_path
     def tick(self):

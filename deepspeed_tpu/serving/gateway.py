@@ -931,6 +931,14 @@ class ServingGateway:
                                  tokens=harvested, kv_blocks=kv_blocks,
                                  overlapped=tick.overlapped,
                                  late_rows=len(late))
+        moe = self._batcher.device_counts
+        if moe is not None:
+            self.metrics.record_moe_pairs(moe)
+            if self.tracer.enabled:
+                self.tracer.record(
+                    SpanName.SERVE_MOE_PAIRS, now, 0.0, held=int(moe[0]),
+                    routed=int(moe[1]), visits=int(moe[2]),
+                    per_expert=[int(c) for c in moe[3:]])
         round_k = tick.draft_k
         n_fed = n_live - len(late)
         if counts is not None and n_fed:

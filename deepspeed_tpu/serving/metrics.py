@@ -83,6 +83,16 @@ class ServingMetrics:
         self.peak_concurrent_conversations = 0
         self.serving_hbm_bytes = 0
         self.pool_blocks_used = 0
+        # ---- an expert family's device counters (KVCache.stats) ----
+        #: (token, expert) pairs that landed on an expert held here, pairs
+        #: routed in all, and the pairs each held expert took: cumulative,
+        #: prompt passes included, as of the last harvested tick
+        self.moe_pairs_held = 0
+        self.moe_pairs_routed = 0
+        #: (expert layer call, held expert) with at least one pair: each
+        #: streams that expert's matrices once
+        self.moe_expert_visits = 0
+        self.moe_expert_pairs: list = []
         #: time-to-first-token, seconds — the shared telemetry histogram
         #: (count/sum exact, reservoir bounded at :data:`_TTFT_CAP`)
         self.ttft = Histogram(MetricName.SERVE_TTFT_S, cap=_TTFT_CAP)
@@ -144,6 +154,15 @@ class ServingMetrics:
             self.spec_proposed += proposed
         self.spec_accept_rate.observe(accepted / max(1, proposed))
         self.spec_tokens_per_tick.observe(float(emitted))
+
+    def record_moe_pairs(self, counts) -> None:
+        """``counts``: the batcher's cumulative ``device_counts``, ``[held,
+        routed, visits, pairs of each held expert...]``."""
+        with self._lock:
+            self.moe_pairs_held = int(counts[0])
+            self.moe_pairs_routed = int(counts[1])
+            self.moe_expert_visits = int(counts[2])
+            self.moe_expert_pairs = [int(c) for c in counts[3:]]
 
     def record_ttft(self, seconds: float) -> None:
         self.ttft.observe(float(seconds))
@@ -213,6 +232,14 @@ class ServingMetrics:
                 "late_row_share": (self.late_row_ticks
                                    / self.active_slot_ticks
                                    if self.active_slot_ticks else 0.0),
+                "moe_pairs_held": self.moe_pairs_held,
+                "moe_pairs_routed": self.moe_pairs_routed,
+                "moe_expert_visits": self.moe_expert_visits,
+                "moe_expert_pairs": list(self.moe_expert_pairs),
+                # the busiest held expert's pairs over the mean's
+                "moe_expert_load_max_over_mean": (
+                    max(self.moe_expert_pairs) * len(self.moe_expert_pairs)
+                    / self.moe_pairs_held if self.moe_pairs_held else 0.0),
             }
         snap["ttft_s"] = self.ttft.values()
         snap["readmit_s"] = self.readmit.values()

@@ -134,6 +134,10 @@ class SpanName:
     #: landing the prefilled cache in its slot: write_slot + bind (and the
     #: draft's write + pending-token seed under speculation)
     SERVE_SLOT_WRITE = "serve.slot_write"
+    #: an expert family's pair counts as of one harvested tick, cumulative
+    #: since the server started (recorded, zero length; held, routed,
+    #: visits and per_expert in args): counted on the device, pulled with the tokens
+    SERVE_MOE_PAIRS = "serve.moe_pairs"
     #: end of admission -> the tick that harvested the request's first
     #: token (recorded; rid in args)
     SERVE_FIRST_TOKEN = "serve.first_token"

@@ -21,6 +21,9 @@ _KERNEL_MODULES = [
     for name in ("flash_attention", "decode_attention", "fused_bias_gelu",
                  "quantizer")]
 flash, decode, gelu, quantizer = _KERNEL_MODULES
+# the expert layer's grouped matmul asks the same two questions
+_KERNEL_MODULES.append(importlib.import_module(
+    "deepspeed_tpu.moe.held_experts"))
 
 BF16 = jnp.bfloat16
 # GPT-2 350M: 16 heads of 64; training micro-batch rows x 1024 tokens,
@@ -160,15 +163,16 @@ def _root_opcodes(hlo_text):
             for n, op, calls in rows]
 
 
-def _sweep_is_built_outside_the_layer_scan(jaxpr):
+def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
     """The kernel's work list is a function of the tick's inputs alone: its
     running sum (``decode_sweep``'s ``cumsum`` over the rows' block counts,
-    rank 1; an MoE gate's running sums over ``[tokens, experts]`` are not
-    it) is an equation of the tick and of no layer's body, where the kernel
+    rank 1, one entry a slot; an MoE gate's running sums over ``[tokens,
+    experts]`` and a grouped matmul's over its groups and tiles are not it)
+    is an equation of the tick and of no layer's body, where the kernel
     call itself sits."""
     def names(jp):
         return [e.primitive.name if e.primitive.name != "cumsum"
-                or e.invars[0].aval.ndim != 1 else "sweep_cumsum"
+                or e.invars[0].aval.shape != (slots,) else "sweep_cumsum"
                 for e in jp.eqns]
 
     def inner(eqn):
@@ -179,24 +183,34 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr):
         return names(jp) + [n for e in jp.eqns for sub in inner(e)
                             for n in deep(sub)]
 
+    # one scan a segment of the family's step (a stack with leading dense
+    # layers has two)
     scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
-    assert len(scans) == 1, names(jaxpr)
-    body = deep(inner(scans[0])[0])
-    around = [n for e in jaxpr.eqns if e is not scans[0]
+    assert len(scans) == segments, names(jaxpr)
+    around = [n for e in jaxpr.eqns if e not in scans
               for sub in inner(e) for n in deep(sub)]
     assert "sweep_cumsum" in around, around
-    assert "pallas_call" in body
-    assert "sweep_cumsum" not in body, "the sweep is rebuilt in every layer"
+    for scan in scans:
+        body = deep(inner(scan)[0])
+        assert "pallas_call" in body
+        assert "sweep_cumsum" not in body, \
+            "the sweep is rebuilt in every layer"
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("family", ["dense", "moe"])
+@pytest.mark.parametrize("family,int8", [
+    ("dense", False), ("dense", True), ("moe", False), ("moe", True),
+    ("latent", False)],
+    ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent"])
 def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     """The tick's device program at the serving cells' geometry (64 slots x
     1024 tokens, 16 heads of 64; two layers stand for 24), for both model
     families (GPT-MoE: one (dense, expert) pair of four experts at the same
     widths): per-row ``decode_step`` on a donated cache, told which slots
-    are live.  The kernel's work list is built once, outside the layer
+    are live; and for the latent-attention family at its published widths
+    (64 heads over one 576-element row stored as 640 lanes, 7168 wide; one
+    dense and two expert layers, 4 of 384 experts held: stored as 576 the
+    compiler copies the whole pool around the kernel, PERF.md 6).  The
+    kernel's work list is built once, outside the layer
     scan.  Nothing but the kernel may touch a
     whole layer of the pool: no copy, transpose or slice as large as one
     layer's K, and the pool's inputs are its outputs.  A pool stored with
@@ -205,7 +219,7 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     import dataclasses
 
     from deepspeed_tpu.models import cache_family, gpt, gpt_moe
-    slots, layers = 64, 2
+    slots, layers, smax = 64, 2, SMAX
     cfg = dataclasses.replace(gpt.GPT2_350M, n_layer=layers, dtype=BF16)
     model = gpt
     if family == "moe":
@@ -213,6 +227,20 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
             **{f.name: getattr(cfg, f.name)
                for f in dataclasses.fields(cfg)}, num_experts=4)
         model = gpt_moe
+    if family == "latent":
+        # its cell's own geometry: a layer of the pool is then larger than
+        # any one matrix, so "as large as a layer" still means the pool
+        from deepspeed_tpu.models import latent_moe
+        model = latent_moe
+        slots, smax = 128, 8192
+        cfg = latent_moe.LatentMoEConfig(
+            vocab_size=2048, max_seq_len=smax, n_layer=3, n_head=64,
+            d_model=7168, d_ff=18432, d_expert=2048, q_rank=1536,
+            kv_rank=512, d_nope=128, d_rope=64, d_v=128, n_experts=384,
+            experts_per_token=8, held_experts=(0, 1, 2, 3),
+            routed_scale=2.827, rope_theta=50000.0,
+            yarn=(64.0, 4096, 32.0, 1.0, 1.0, 1.0), dtype=BF16,
+            param_dtype=BF16)
     fam = cache_family(cfg)
 
     def described(tree):
@@ -223,7 +251,7 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     params = described(jax.eval_shape(
         lambda: model.init(cfg, jax.random.PRNGKey(0))))
     cache = described(jax.eval_shape(lambda: fam.init_cache(
-        cfg, slots, SMAX, kv_dtype="int8" if int8 else None)))
+        cfg, slots, smax, kv_dtype="int8" if int8 else None)))
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
     live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
     tick = jax.jit(
@@ -231,11 +259,13 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
             p, tok, cfg, c, lengths=lengths, active=active),
         donate_argnums=(1,))
     _sweep_is_built_outside_the_layer_scan(
-        tick.trace(params, cache, rows, rows, live).jaxpr)
+        tick.trace(params, cache, rows, rows, live).jaxpr, slots,
+        segments=2 if family == "latent" else 1)
     compiled = tick.lower(params, cache, rows, rows, live).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the tick"
-    layer_k = slots * SMAX * cfg.n_head * cfg.head_dim
+    from deepspeed_tpu.models.gpt_inference import cache_row
+    layer_k = slots * smax * cache_row(cfg)[0]
     moved = [(n, op) for n, op in _root_opcodes(text)
              if n >= layer_k and (op.startswith("copy") or op in (
                  "transpose", "dynamic-slice", "dynamic-update-slice"))]
