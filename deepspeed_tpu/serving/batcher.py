@@ -6,12 +6,17 @@ batcher instead owns a single ``[L, B=slots, max_len, H*D]`` KV cache and
 drives it with a closed set of compiled programs whose shapes never depend
 on a request:
 
-- admission **prefill** runs batch-1 through fixed-width chunks (prompts
-  right-pad up to a multiple of ``prefill_chunk``; pad K/V lands beyond
-  the row's frontier where per-row visibility masks it) and the finished
-  batch-1 cache is inserted into a free slot with the model family's
-  ``write_slot`` — ``row`` is traced, so slot 0 and slot 7 share one
-  program;
+- an **admission** is ONE program launch fed by one hand-over of host
+  arrays: inside it the prompt runs batch-1 through fixed-width chunks
+  (prompts right-pad up to a multiple of ``prefill_chunk``; pad K/V lands
+  beyond the row's frontier where per-row visibility masks it) in a loop
+  whose trip count is traced, the finished batch-1 cache is inserted into
+  a free slot with the model family's ``write_slot`` and the row is bound
+  — prompt length and ``row`` are traced, so a 9-token prompt into slot 0
+  and a 700-token one into slot 7 share one program (a second name
+  continues a pooled prefix; :meth:`SlotBatcher.build_prefix` and the
+  fleet's prefill worker, which need a batch-1 cache back, run the same
+  chunks a launch each);
 - each decode **tick** advances every slot one token through the family's
   ragged ``decode_step`` (per-slot frontiers, per-slot RNG keys, per-slot
   greedy/temperature — all traced operands of one compiled program).
@@ -78,6 +83,67 @@ class PrefixEntry:
     length: int
 
 
+def admission(fam, cfg, max_len: int, kv_dtype):
+    """The function of the admission program for model family ``fam`` at
+    ``cfg`` over ``max_len``-token slots: what :class:`SlotBatcher`
+    registers as ``admit`` / ``admit_prefix`` (and again at the wide
+    chunk), and what the compile tests lower for a described chip."""
+
+    def admit(params, pool, lengths, last, keys, greedy, temp, active,
+              tokens, meta, key, prefix=None):
+        """One admission, whole: the chunk loop, the slot write and the
+        bind.  ``tokens`` [max_len // C, C] is the prompt (past the prefix)
+        padded by the host to the slot's chunk count; ``meta`` int32 [7] is
+        ``(row, start, n, greedy, temperature's bits, fold?, fold's
+        bits)``: the prompt's ``n`` real tokens continue ``prefix`` (a
+        batch-1 cache of slot geometry, shared, never donated) at
+        ``start``, or fill a fresh row cache from 0, and the row's key is
+        ``key``, or ``fold_in(key, fold)`` as the host's own
+        ``jax.random.fold_in`` gives it.  The loop's trip count is the
+        traced number of real chunks, so one compiled program serves every
+        prompt length; every chunk is the family's own ``prefill`` / ragged
+        ``extend``, as :meth:`SlotBatcher._chunked_prefill` runs them one
+        launch each."""
+        C = tokens.shape[1]
+        row, start, n = meta[0], meta[1], meta[2]
+
+        def take(lg, i):
+            # the last real token's logits if chunk ``i`` holds it (the
+            # last chunk does; an earlier chunk's row is junk that the next
+            # iteration replaces)
+            idx = jnp.clip(n - 1 - i * C, 0, C - 1)
+            return lax.dynamic_index_in_dim(lg[0], idx, 0, keepdims=False)
+
+        def chunk(i, carry):
+            pos = start + i * C
+            lg, cache = fam.extend(
+                params, lax.dynamic_index_in_dim(tokens, i, 0), cfg,
+                carry[1], lengths=pos[None])
+            return take(lg, i), cache
+
+        if prefix is None:
+            lg, cache = fam.prefill(
+                params, tokens[:1], cfg,
+                fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype))
+            first, carry = 1, (take(lg, 0), cache)
+        else:
+            first, carry = 0, (
+                jnp.zeros(last.shape[1:], last.dtype),
+                dataclasses.replace(prefix, length=start))
+        vec, cache = lax.fori_loop(jnp.int32(first), (n + C - 1) // C,
+                                   chunk, carry)
+        key = jnp.where(meta[5] != 0, jax.random.fold_in(
+            key, lax.bitcast_convert_type(meta[6], jnp.uint32)), key)
+        return (fam.write_slot(pool, row, cache),
+                lengths.at[row].set(start + n), last.at[row].set(vec),
+                keys.at[row].set(key), greedy.at[row].set(meta[3] != 0),
+                temp.at[row].set(
+                    lax.bitcast_convert_type(meta[4], jnp.float32)),
+                active.at[row].set(True), vec)
+
+    return admit
+
+
 class SlotBatcher:
     """Continuous batching over ``config.slots`` decode slots."""
 
@@ -98,10 +164,12 @@ class SlotBatcher:
         # a chunk wider than the slot cannot even land its first write
         self.chunk = min(int(config.prefill_chunk), self.max_len)
         #: degraded-mode prefill chunk (the ladder's ``chunk_widen``
-        #: rung): double width = half the per-chunk dispatch overhead at
-        #: the cost of more pad compute.  Runs through its OWN registered
-        #: programs (``prefill_wide``/``extend_wide``) — re-tracing the
-        #: normal ones at a new shape would count as a recompile.
+        #: rung): double width = half the passes over the weights at the
+        #: cost of more pad compute (the per-chunk dispatch it was made to
+        #: halve is gone: an admission is one launch at either width).
+        #: Runs through its OWN registered programs (the ``*_wide`` names)
+        #: — re-tracing the normal ones at a new shape would count as a
+        #: recompile.
         self.chunk_wide = min(self.chunk * 2, self.max_len)
         self._wide = False
         fam = self._fam
@@ -134,6 +202,11 @@ class SlotBatcher:
         self.temp = jnp.ones((B,), jnp.float32)
         self.active = jnp.zeros((B,), bool)
         self._last = None          # [B, padded_vocab], set on first admit
+        #: program launches made for admissions, every path's: 1 for an
+        #: admission (with or without a prefix), a chunk each where
+        #: :meth:`_chunked_prefill` builds a prefix first, the draft's own
+        #: under speculation
+        self.admit_launches = 0
         #: speculative tick state (None/0 fields when speculation is off)
         self.spec = bool(config.speculative_config.enabled)
         self.draft_k = 0
@@ -257,11 +330,13 @@ class SlotBatcher:
                     cache, stats=jnp.zeros_like(cache.stats))
             return nxt, logits, cache, new_lengths, next_keys
 
-        def bind(lengths, last, keys, greedy, temp, active,
-                 row, length, vec, key, g, t):
-            return (lengths.at[row].set(length), last.at[row].set(vec),
-                    keys.at[row].set(key), greedy.at[row].set(g),
-                    temp.at[row].set(t), active.at[row].set(True))
+        def admission_named(name):
+            # a function object a name: jit objects of one function share a
+            # cache, and a name's compiles are counted from its own; the
+            # name is the program's in a profiler's trace (``jit_admit``)
+            fn = admission(fam, cfg, self.max_len, self._kv_dtype)
+            fn.__name__ = name
+            return fn
 
         def release(lengths, active, row):
             return lengths.at[row].set(0), active.at[row].set(False)
@@ -279,18 +354,14 @@ class SlotBatcher:
                 lambda p, t, c: fam.prefill(p, t, cfg, c)),
             "extend_wide": jax.jit(
                 lambda p, t, c, l: fam.extend(p, t, cfg, c, lengths=l)),
-            "take_last": jax.jit(
-                lambda lg, i: lax.dynamic_index_in_dim(lg[0], i, 0,
-                                                       keepdims=False)),
-            "take_last_wide": jax.jit(
-                lambda lg, i: lax.dynamic_index_in_dim(lg[0], i, 0,
-                                                       keepdims=False)),
-            # admission donates the pool like the tick does (never ``src``:
-            # a prefix entry's cache is shared by its forks)
-            "write_slot": jax.jit(
-                lambda c, row, src: fam.write_slot(c, row, src),
-                donate_argnums=(0,)),
-            "bind": jax.jit(bind),
+            # an admission is ONE launch (plain, or continuing a prefix;
+            # each again at the rung's wide chunk): the same function.  It
+            # donates the pool like the tick does, and the frontier logits
+            # it writes one row of (never the prefix: a pooled entry is
+            # shared by its forks)
+            **{name: jax.jit(admission_named(name), donate_argnums=(1, 3))
+               for name in ("admit", "admit_prefix", "admit_wide",
+                            "admit_prefix_wide")},
             "release": jax.jit(release),
             # the tick donates the slot pool: its one-row writes land in
             # the pool where it lies and ``self.cache`` is rebound from the
@@ -481,16 +552,27 @@ class SlotBatcher:
 
     def prewarm(self) -> None:
         """Compile every program a storm can reach BEFORE traffic
-        arrives: prefill/extend at both chunk widths, the tick at every
-        speculative ladder level, admission bind and release.  The
-        degradation ladder exists to shed work under pressure — a rung
-        whose first engage pays an XLA compile would add seconds of
-        stall at the worst possible moment, so ``serving.warm_start``
-        front-loads them all here.  Runs a throwaway prompt through
-        slot 0 and releases it; call before any real admission."""
+        arrives: the admission with and without a prefix and the chunk
+        pair that builds one, each at both chunk widths, the tick at every
+        speculative ladder level, and release.  The degradation ladder
+        exists to shed work under pressure — a rung whose first engage
+        pays an XLA compile would add seconds of stall at the worst
+        possible moment, so ``serving.warm_start`` front-loads them all
+        here.  Runs a throwaway prompt through slot 0 and releases it;
+        call before any real admission."""
         key = jax.random.PRNGKey(0)
-        n = min(self.chunk + 1, self.max_len)   # cross one chunk boundary
-        self.admit(0, np.zeros((n,), np.int32), key, True, 1.0)
+
+        def admissions(C):
+            # the prompt and the prefix built under it both cross one
+            # chunk boundary: prefill and extend run, launch by launch,
+            # and the prefix's continuation
+            prompt = np.zeros((min(C + 2, self.max_len),), np.int32)
+            self.admit(0, prompt, key, True, 1.0)
+            if prompt.shape[0] > 1:
+                self.admit(0, prompt, key, True, 1.0,
+                           prefix=self.build_prefix(prompt[:-1]))
+
+        admissions(self.chunk)
         self.tick()
         if self.spec:
             for level in (1, 2, 0):   # shrunk round, pause flush, resume
@@ -499,8 +581,7 @@ class SlotBatcher:
         self.release(0)
         if self.chunk_wide != self.chunk:
             self.set_chunk_wide(True)
-            nw = min(self.chunk_wide + 1, self.max_len)
-            self.admit(0, np.zeros((nw,), np.int32), key, True, 1.0)
+            admissions(self.chunk_wide)
             self.set_chunk_wide(False)
             self.release(0)
 
@@ -508,28 +589,23 @@ class SlotBatcher:
 
     def _chunked_prefill(self, tokens: np.ndarray,
                          start_cache=None, start_len: int = 0):
-        """Run ``tokens`` [S] through fixed-width chunks starting at
-        ``start_len`` of a batch-1 slot-geometry cache (fresh unless
-        continuing a shared prefix).  Returns ``(cache, last_vec,
-        frontier)`` — ``last_vec`` the logits at the LAST REAL token
-        (chunk padding sits beyond the frontier, masked by per-row
-        visibility and overwritten as decode advances)."""
+        """Run ``tokens`` [S] through fixed-width chunks, a launch each,
+        starting at ``start_len`` of a batch-1 slot-geometry cache (fresh
+        unless continuing one) and hand the cache BACK: ``(cache,
+        frontier)``.  For what keeps a batch-1 cache on the host's side of
+        the API (:meth:`build_prefix`; the fleet's prefill worker, which
+        fires its faults between chunks); an admission runs the same
+        chunks inside its one program.  Chunk padding sits beyond the
+        frontier, masked by per-row visibility and overwritten as decode
+        advances."""
         fam, cfg = self._fam, self._cfg
-        wide = self._wide
-        C = self.chunk_wide if wide else self.chunk
-        p_first, p_rest = ("prefill_wide", "extend_wide") if wide \
-            else ("prefill", "extend")
+        C, sfx = self._chunk_width()
         S = int(tokens.shape[0])
-        pad = (-S) % C
-        n_chunks = (S + pad) // C
+        n_chunks = -(-S // C)
         with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S,
-                              start=start_len, chunk=C, padded=S + pad,
+                              start=start_len, chunk=C, padded=n_chunks * C,
                               chunks=n_chunks):
-            padded = np.concatenate(
-                [np.asarray(tokens, np.int32),
-                 np.zeros((pad,), np.int32)]) if pad else np.asarray(
-                     tokens, np.int32)
-            chunks = padded.reshape(-1, C)
+            chunks = self._padded_chunks(tokens, C, n_chunks)
             if start_cache is not None:
                 cache = start_cache
             else:
@@ -538,96 +614,129 @@ class SlotBatcher:
                     cache = fam.init_cache(cfg, 1, self.max_len,
                                            kv_dtype=self._kv_dtype)
             params = self._engine.params
-            lg = None
             for i, ch in enumerate(chunks):
                 pos = start_len + i * C
-                program = p_first if pos == 0 else p_rest
+                program = ("extend" if pos else "prefill") + sfx
                 with self.tracer.span(SpanName.SERVE_PREFILL_CHUNK, index=i,
                                       pos=pos, program=program):
-                    dev = jnp.asarray(ch[None])
-                    if pos == 0:
-                        lg, cache = self._p[program](params, dev, cache)
-                    else:
-                        lg, cache = self._p[program](
-                            params, dev, cache,
-                            jnp.asarray([pos], jnp.int32))
-            idx = S - 1 - (n_chunks - 1) * C
-            p_last = "take_last_wide" if wide else "take_last"
-            vec = self._p[p_last](lg, jnp.asarray(idx, jnp.int32))
-        return cache, vec, start_len + S
+                    _, cache = self._launch(
+                        program, params, jnp.asarray(ch[None]), cache,
+                        *((jnp.asarray([pos], jnp.int32),) if pos else ()))
+        return cache, start_len + S
+
+    def _chunk_width(self) -> Tuple[int, str]:
+        """``(C, suffix)``: the chunk width an admission starting now runs
+        at, and what its programs' names end in (``"_wide"`` under the
+        ``chunk_widen`` rung)."""
+        return (self.chunk_wide, "_wide") if self._wide else (self.chunk, "")
+
+    def _launch(self, program: str, *args):
+        """Launch a registered program on an admission's behalf: counted
+        in ``admit_launches``."""
+        self.admit_launches += 1
+        return self._p[program](*args)
+
+    @staticmethod
+    def _padded_chunks(tokens: np.ndarray, C: int, n_chunks: int):
+        """``tokens`` [S] as ``[n_chunks, C]`` int32, zeros past ``S``."""
+        out = np.zeros((n_chunks, C), np.int32)
+        out.reshape(-1)[:tokens.shape[0]] = tokens
+        return out
 
     def build_prefix(self, tokens: np.ndarray) -> PrefixEntry:
         """Prefill a shared prefix once; forks ride it zero-copy."""
-        cache, _vec, frontier = self._chunked_prefill(tokens)
+        cache, frontier = self._chunked_prefill(np.asarray(tokens))
         return PrefixEntry(cache=cache, length=frontier)
 
     # ----------------------------------------------------------- admission
 
     def admit(self, row: int, tokens: np.ndarray, key, greedy: bool,
               temperature: float,
-              prefix: Optional[PrefixEntry] = None) -> int:
-        """Prefill ``tokens`` and land them in slot ``row``; returns the
-        row's frontier (= prompt length).  With ``prefix``, only the
-        remainder past ``prefix.length`` prefills — the prefix K/V is the
-        pooled cache, shared zero-copy."""
+              prefix: Optional[PrefixEntry] = None,
+              fold: Optional[int] = None) -> int:
+        """Prefill ``tokens`` and land them in slot ``row``, in ONE
+        program launch fed by one hand-over of host arrays (the padded
+        prompt and ``meta``); returns the row's frontier (= prompt
+        length).  With ``prefix``, only the remainder past
+        ``prefix.length`` prefills — the prefix K/V is the pooled cache,
+        shared zero-copy, the loop's starting row.  With ``fold``, the
+        row samples from ``jax.random.fold_in(key, fold)``, folded inside
+        the program: a caller that derives a key per request hands over
+        its base key and spares the launches of an eager fold."""
         if int(tokens.shape[0]) > self.max_len:
             raise ValueError(
                 f"prompt of {int(tokens.shape[0])} tokens overflows the "
                 f"{self.max_len}-token slot")
+        start = 0
         if prefix is not None:
             if prefix.length >= tokens.shape[0]:
                 raise ValueError(
                     f"prefix ({prefix.length} tokens) must be shorter than "
                     f"the prompt ({tokens.shape[0]})")
-            cache, vec, frontier = self._chunked_prefill(
-                np.asarray(tokens[prefix.length:]),
-                start_cache=prefix.cache, start_len=prefix.length)
-        else:
-            cache, vec, frontier = self._chunked_prefill(np.asarray(tokens))
-        row_dev = jnp.asarray(row, jnp.int32)
+            start = int(prefix.length)
+        new = np.asarray(tokens[start:], np.int32)
+        S = int(new.shape[0])
+        C, sfx = self._chunk_width()
+        n_chunks = -(-S // C)
+        program = ("admit" if prefix is None else "admit_prefix") + sfx
         if self._last is None:
-            self._last = jnp.zeros((self.slots,) + vec.shape, vec.dtype)
-        with self.tracer.span(SpanName.SERVE_SLOT_WRITE, slot=row):
-            self.cache = self._p["write_slot"](self.cache, row_dev, cache)
-            (self.lengths, self._last, self.keys, self.greedy, self.temp,
-             self.active) = self._p["bind"](
-                self.lengths, self._last, self.keys, self.greedy, self.temp,
-                self.active, row_dev, jnp.asarray(frontier, jnp.int32), vec,
-                key, jnp.asarray(bool(greedy)),
-                jnp.asarray(float(temperature), jnp.float32))
-            if self.spec:
-                # lockstep draft admission: the draft prefills the FULL
-                # prompt (prefix/readmit shortcuts spare only target work
-                # — the draft is small, that is its whole point) and the
-                # slot's pending token is seeded from the admission logits
-                self.draft_cache = self._p["draft_write_slot"](
-                    self.draft_cache, row_dev,
+            lg = self._logits_row()
+            self._last = jnp.zeros((self.slots,) + lg.shape, lg.dtype)
+        meta = np.array([row, start, S, bool(greedy),
+                         np.float32(temperature).view(np.int32),
+                         fold is not None,
+                         np.uint32(fold or 0).view(np.int32)], np.int32)
+        with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S, start=start,
+                              chunk=C, padded=n_chunks * C, chunks=n_chunks):
+            (self.cache, self.lengths, self._last, self.keys, self.greedy,
+             self.temp, self.active, vec) = self._launch(
+                program,
+                self._engine.params, self.cache, self.lengths, self._last,
+                self.keys, self.greedy, self.temp, self.active,
+                self._padded_chunks(new, C, -(-self.max_len // C)), meta,
+                key, *(() if prefix is None else (prefix.cache,)))
+        if self.spec:
+            # lockstep draft admission: the draft prefills the FULL
+            # prompt (prefix/readmit shortcuts spare only target work
+            # — the draft is small, that is its whole point) and the
+            # slot's pending token is seeded from the admission logits
+            row_dev = jnp.asarray(row, jnp.int32)
+            with self.tracer.span(SpanName.SERVE_SLOT_WRITE, slot=row):
+                self.draft_cache = self._launch(
+                    "draft_write_slot", self.draft_cache, row_dev,
                     self._draft_prefill(np.asarray(tokens)))
-                self.cur, self.keys = self._p["spec_seed"](
-                    self.cur, self.keys, row_dev, vec,
+                self.cur, self.keys = self._launch(
+                    "spec_seed", self.cur, self.keys, row_dev, vec,
                     jnp.asarray(bool(greedy)),
                     jnp.asarray(float(temperature), jnp.float32))
-        return frontier
+        return start + S
+
+    def _logits_row(self) -> jax.ShapeDtypeStruct:
+        """One position's logits (the family's padded vocabulary, in the
+        type its head returns), asked of the family's own prefill: traced,
+        never run."""
+        fam, cfg = self._fam, self._cfg
+        lg, _ = jax.eval_shape(
+            lambda p, t: fam.prefill(p, t, cfg, fam.init_cache(
+                cfg, 1, self.max_len, kv_dtype=self._kv_dtype)),
+            self._engine.params,
+            jax.ShapeDtypeStruct((1, self.chunk), jnp.int32))
+        return jax.ShapeDtypeStruct(lg.shape[2:], lg.dtype)
 
     def _draft_prefill(self, tokens: np.ndarray):
         """Chunked prefill of a prompt through the draft's fixed-width
         programs into a fresh batch-1 slot-geometry draft cache."""
         C = self.chunk
-        S = int(tokens.shape[0])
-        pad = (-S) % C
-        padded = np.concatenate(
-            [np.asarray(tokens, np.int32),
-             np.zeros((pad,), np.int32)]) if pad else np.asarray(
-                 tokens, np.int32)
         cache = self._dfam.init_cache(self._dcfg, 1, self.max_len)
-        for i, ch in enumerate(padded.reshape(-1, C)):
+        chunks = self._padded_chunks(tokens, C, -(-int(tokens.shape[0]) // C))
+        for i, ch in enumerate(chunks):
             dev = jnp.asarray(ch[None])
             if i == 0:
-                _, cache = self._p["draft_prefill"](self._dparams, dev,
-                                                    cache)
+                _, cache = self._launch("draft_prefill", self._dparams, dev,
+                                        cache)
             else:
-                _, cache = self._p["draft_extend"](
-                    self._dparams, dev, cache,
+                _, cache = self._launch(
+                    "draft_extend", self._dparams, dev, cache,
                     jnp.asarray([i * C], jnp.int32))
         return cache
 

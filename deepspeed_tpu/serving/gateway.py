@@ -633,6 +633,7 @@ class ServingGateway:
             self._admit_one_inner(row, req)
 
     def _admit_one_inner(self, row: int, req: ServeRequest) -> None:
+        launches = self._batcher.admit_launches
         prefix_hit = False
         prefix = None
         readmit = None
@@ -706,13 +707,12 @@ class ServingGateway:
         # after a readmit must free the re-admitted blocks via the ledger)
         fault_injection.fire("serve.admit", request_id=req.rid, slot=row)
         t_prefill = time.monotonic()
-        # the per-request PRNG key is derived here, not in submit():
-        # identical fold, identical sampling — but the dispatch runs on
-        # the scheduler thread, once per ACCEPTED request
-        key = jax.random.fold_in(self._base_key, req.key)
-        req.frontier = self._batcher.admit(row, req.tokens, key,
+        # the per-request PRNG key is derived inside the admission's one
+        # program: identical fold, identical sampling, no launch of its
+        # own on the scheduler thread
+        req.frontier = self._batcher.admit(row, req.tokens, self._base_key,
                                            req.greedy, req.temperature,
-                                           prefix=prefix)
+                                           prefix=prefix, fold=req.key)
         if self._overload is not None:
             self._overload.note_prefill(
                 (time.monotonic() - t_prefill) * 1e3)
@@ -730,6 +730,8 @@ class ServingGateway:
         self._emit(EventKind.SERVE_ADMIT, request_id=req.rid, slot=row,
                    queued_ms=queued_ms, prefix_hit=prefix_hit)
         self.metrics.count("admitted")
+        self.metrics.count("admit_launches",
+                           self._batcher.admit_launches - launches)
 
     def _try_readmit(self, req: ServeRequest):
         """Attempt the tiered-KV restore for a session follow-up; any
@@ -955,7 +957,9 @@ class ServingGateway:
                        active=n_live, queue_depth=depth,
                        tok_per_s=round(snap["tokens_per_s"], 3),
                        overlap_share=round(snap["overlap_share"], 4),
-                       late_row_share=round(snap["late_row_share"], 4))
+                       late_row_share=round(snap["late_row_share"], 4),
+                       launches_per_admission=round(
+                           snap["launches_per_admission"], 4))
             if counts is not None and n_fed:
                 self._emit(EventKind.SERVE_SPEC_ROUND, tick=self._ticks,
                            active=n_fed, draft_k=round_k,

@@ -28,6 +28,9 @@ class ServingMetrics:
         self.t_start = time.monotonic()
         self.submitted = 0
         self.admitted = 0
+        #: program launches the admissions took, a prefix built on a miss
+        #: included (the batcher's count; one each is the design)
+        self.admit_launches = 0
         self.rejected = 0
         #: submissions shed by the admission controller (each also counts
         #: as rejected — shed is the overload-policy subset)
@@ -178,6 +181,11 @@ class ServingMetrics:
             snap = {
                 "submitted": self.submitted,
                 "admitted": self.admitted,
+                "admit_launches": self.admit_launches,
+                # 1.0 when every admission was its one program launch
+                "launches_per_admission": (
+                    self.admit_launches / self.admitted
+                    if self.admitted else 0.0),
                 "rejected": self.rejected,
                 "shed": self.shed,
                 "degrade_transitions": self.degrade_transitions,

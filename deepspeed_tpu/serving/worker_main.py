@@ -173,7 +173,7 @@ def _prefill_loop(cfg: dict, batcher, journal, spool: str,
     inbox = os.path.join(spool, "prefill", f"w{rank}")
     bundles_dir = os.path.join(spool, "bundles")
     C = batcher.chunk
-    # warm every program this role uses (prefill, extend, take_last)
+    # warm every program this role uses (prefill, extend)
     # BEFORE publishing readiness — the supervisor's prefill timeout must
     # clock prefill work, not first-order compilation
     batcher.build_prefix(np.arange(2 * C, dtype=np.int32) % 256)
@@ -211,7 +211,7 @@ def _prefill_loop(cfg: dict, batcher, journal, spool: str,
                 for pos in range(0, int(prefix.shape[0]), C):
                     fault_injection.fire("serve.prefill_chunk",
                                          step=chunks_done, path=rid)
-                    cache, _last, frontier = batcher._chunked_prefill(
+                    cache, frontier = batcher._chunked_prefill(
                         prefix[pos:pos + C], start_cache=cache,
                         start_len=pos)
                     chunks_done += 1
@@ -304,14 +304,19 @@ def _decode_loop(cfg: dict, batcher, journal, spool: str,
     C, slots = batcher.chunk, int(cfg["slots"])
     metrics_interval = float(cfg.get("metrics_interval_s", 0.2))
 
-    # warm EVERY decode-path program (prefill + extend via a 2-chunk
-    # prompt, take_last, write_slot, bind, tick, release) before declaring
-    # ready — steady state must be compile-free, and the stats snapshot
-    # below is what the recompile test pins against
+    # warm EVERY decode-path program (the admission over a 2-chunk prompt,
+    # tick, release, and the admission that continues a prefix, as every
+    # bundle order's does: here the row just admitted, read back) before
+    # declaring ready — steady state must be compile-free, and the stats
+    # snapshot below is what the recompile test pins against
     warm_tokens = np.arange(C + 2, dtype=np.int32) % 256
-    batcher.admit(0, warm_tokens, jax.random.PRNGKey(0), greedy=True,
-                  temperature=1.0)
+    warm_key = jax.random.PRNGKey(0)
+    batcher.admit(0, warm_tokens, warm_key, greedy=True, temperature=1.0)
     batcher.tick()
+    batcher.release(0)
+    batcher.admit(0, warm_tokens, warm_key, greedy=True, temperature=1.0,
+                  prefix=PrefixEntry(cache=batcher._fam.read_slot(
+                      batcher.cache, 0, C), length=C))
     batcher.release(0)
     warm = batcher.compile_counts()
     _write_stats(run_dir, rank, inc, warm, batcher, 0)

@@ -123,16 +123,20 @@ class SpanName:
     SERVE_QUEUE = "serve.queue"
     #: admission of one request into a free slot (incl. prefill)
     SERVE_ADMIT = "serve.admit"
-    #: allocating the fresh batch-1 cache a prefill fills (bytes in args;
-    #: not entered when a prefix cache is continued)
+    #: allocating the fresh batch-1 cache a prefix build fills (bytes in
+    #: args; not entered when a cache is continued, nor by an admission:
+    #: its row cache is a temporary of its one program)
     SERVE_CACHE_ALLOC = "serve.cache_alloc"
-    #: chunked prefill of a prompt/prefix through the fixed-width programs
-    #: (tokens = real, padded = computed, chunks in args)
+    #: chunked prefill of a prompt/prefix through the fixed-width chunks
+    #: (tokens = real, padded = computed, chunks in args): around an
+    #: admission's ONE launch (chunk loop, slot write and bind), or around
+    #: a prefix build's launch a chunk
     SERVE_PREFILL = "serve.prefill"
-    #: one prefill/extend chunk dispatch (index, pos, program in args)
+    #: one prefill/extend chunk dispatch of a prefix build (index, pos,
+    #: program in args)
     SERVE_PREFILL_CHUNK = "serve.prefill_chunk"
-    #: landing the prefilled cache in its slot: write_slot + bind (and the
-    #: draft's write + pending-token seed under speculation)
+    #: under speculation, the draft's lockstep admission after the
+    #: target's one launch: its slot write + pending-token seed
     SERVE_SLOT_WRITE = "serve.slot_write"
     #: an expert family's pair counts as of one harvested tick, cumulative
     #: since the server started (recorded, zero length; held, routed,

@@ -197,61 +197,76 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
             "the sweep is rebuilt in every layer"
 
 
-@pytest.mark.parametrize("family,int8", [
+_SERVED = pytest.mark.parametrize("family,int8", [
     ("dense", False), ("dense", True), ("moe", False), ("moe", True),
     ("latent", False)],
     ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent"])
+
+
+def _served(family):
+    """``(model module, config, slots, slot length, chunk)`` of a serving
+    cell's geometry: 64 slots x 1024 tokens in chunks of 128, 16 heads of 64
+    (two layers stand for 24), dense or GPT-MoE (one (dense, expert) pair of
+    four experts at the same widths); the latent-attention family at its
+    own cell's 128 x 8,192 in chunks of 512 and its published widths (64
+    heads over one 576-element row stored as 640 lanes, 7168 wide; one
+    dense and two expert layers, 4 of 384 experts held): a layer of the pool
+    is then larger than any one matrix, so "as large as a layer" still
+    means the pool."""
+    import dataclasses
+
+    from deepspeed_tpu.models import gpt, gpt_moe
+    cfg = dataclasses.replace(gpt.GPT2_350M, n_layer=2, dtype=BF16)
+    if family == "dense":
+        return gpt, cfg, 64, SMAX, CHUNK
+    if family == "moe":
+        return gpt_moe, gpt_moe.GPTMoEConfig(
+            **{f.name: getattr(cfg, f.name)
+               for f in dataclasses.fields(cfg)}, num_experts=4), 64, SMAX, \
+            CHUNK
+    from deepspeed_tpu.models import latent_moe
+    smax = 8192
+    return latent_moe, latent_moe.LatentMoEConfig(
+        vocab_size=2048, max_seq_len=smax, n_layer=3, n_head=64,
+        d_model=7168, d_ff=18432, d_expert=2048, q_rank=1536,
+        kv_rank=512, d_nope=128, d_rope=64, d_v=128, n_experts=384,
+        experts_per_token=8, held_experts=(0, 1, 2, 3),
+        routed_scale=2.827, rope_theta=50000.0,
+        yarn=(64.0, 4096, 32.0, 1.0, 1.0, 1.0), dtype=BF16,
+        param_dtype=BF16), 128, smax, 512
+
+
+def _described(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _pool_bytes(cache):
+    return sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(cache) if x.ndim >= 4)
+
+
+@_SERVED
 def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
-    """The tick's device program at the serving cells' geometry (64 slots x
-    1024 tokens, 16 heads of 64; two layers stand for 24), for both model
-    families (GPT-MoE: one (dense, expert) pair of four experts at the same
-    widths): per-row ``decode_step`` on a donated cache, told which slots
-    are live; and for the latent-attention family at its published widths
-    (64 heads over one 576-element row stored as 640 lanes, 7168 wide; one
-    dense and two expert layers, 4 of 384 experts held: stored as 576 the
-    compiler copies the whole pool around the kernel, PERF.md 6).  The
-    kernel's work list is built once, outside the layer
+    """The tick's device program at the serving cells' geometry
+    (:func:`_served`), for every model family: per-row ``decode_step`` on a
+    donated cache, told which slots are live (the latent row stored as 576
+    lanes: the compiler copies the whole pool around the kernel, PERF.md
+    6).  The kernel's work list is built once, outside the layer
     scan.  Nothing but the kernel may touch a
     whole layer of the pool: no copy, transpose or slice as large as one
     layer's K, and the pool's inputs are its outputs.  A pool stored with
     64 last (``[L, B, S, H, D]``) fails this: the TPU lays it out with the
     tokens on the lanes and re-lays it around every write and kernel call."""
-    import dataclasses
-
-    from deepspeed_tpu.models import cache_family, gpt, gpt_moe
-    slots, layers, smax = 64, 2, SMAX
-    cfg = dataclasses.replace(gpt.GPT2_350M, n_layer=layers, dtype=BF16)
-    model = gpt
-    if family == "moe":
-        cfg = gpt_moe.GPTMoEConfig(
-            **{f.name: getattr(cfg, f.name)
-               for f in dataclasses.fields(cfg)}, num_experts=4)
-        model = gpt_moe
-    if family == "latent":
-        # its cell's own geometry: a layer of the pool is then larger than
-        # any one matrix, so "as large as a layer" still means the pool
-        from deepspeed_tpu.models import latent_moe
-        model = latent_moe
-        slots, smax = 128, 8192
-        cfg = latent_moe.LatentMoEConfig(
-            vocab_size=2048, max_seq_len=smax, n_layer=3, n_head=64,
-            d_model=7168, d_ff=18432, d_expert=2048, q_rank=1536,
-            kv_rank=512, d_nope=128, d_rope=64, d_v=128, n_experts=384,
-            experts_per_token=8, held_experts=(0, 1, 2, 3),
-            routed_scale=2.827, rope_theta=50000.0,
-            yarn=(64.0, 4096, 32.0, 1.0, 1.0, 1.0), dtype=BF16,
-            param_dtype=BF16)
+    from deepspeed_tpu.models import cache_family
+    model, cfg, slots, smax, _ = _served(family)
     fam = cache_family(cfg)
 
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
-            tree)
-
-    params = described(jax.eval_shape(
-        lambda: model.init(cfg, jax.random.PRNGKey(0))))
-    cache = described(jax.eval_shape(lambda: fam.init_cache(
-        cfg, slots, smax, kv_dtype="int8" if int8 else None)))
+    params = _described(jax.eval_shape(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))), v5e)
+    cache = _described(jax.eval_shape(lambda: fam.init_cache(
+        cfg, slots, smax, kv_dtype="int8" if int8 else None)), v5e)
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
     live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
     tick = jax.jit(
@@ -270,11 +285,97 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
              if n >= layer_k and (op.startswith("copy") or op in (
                  "transpose", "dynamic-slice", "dynamic-update-slice"))]
     assert not moved, f"the tick moves whole layers of the pool: {moved}"
-    banks = [x for x in jax.tree_util.tree_leaves(cache) if x.ndim >= 4]
-    pool_bytes = sum(x.size * x.dtype.itemsize for x in banks)
     assert "input_output_alias" in text
-    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes, \
-        "the donated pool is not updated in place"
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        _pool_bytes(cache), "the donated pool is not updated in place"
+
+
+def _planned_bytes(compiled):
+    """What the program holds at once: arguments, results that alias none
+    of them, temporaries."""
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def _pool_sized_moves(hlo_text, layer_k):
+    """Copies, transposes and slices whose result is as large as a layer of
+    the pool, and dynamic-update-slices whose UPDATE is (their result is
+    the buffer written into: the pool itself, in place)."""
+    import math
+    import re
+    shape = r"\w+\[([\d,]*)\]\S*"
+    dus = re.compile(rf"dynamic-update-slice\({shape} %\S+, {shape} %")
+    moved = [(n, op) for n, op in _root_opcodes(hlo_text)
+             if n >= layer_k and (op.startswith("copy")
+                                  or op in ("transpose", "dynamic-slice"))]
+    for m in dus.finditer(hlo_text):
+        n = math.prod(int(d) for d in m.group(2).split(",") if d)
+        if n >= layer_k:
+            moved.append((n, "dynamic-update-slice"))
+    return moved
+
+
+@_SERVED
+def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
+    """The admission's device program (``serving.batcher.admission``: the
+    chunk loop, the slot write and the bind) at the serving cells' geometry
+    (:func:`_served`), for every model family.  A program that holds a
+    ``fori_loop`` beside the donated pool must still write the pool where it
+    lies: the pool's inputs are its outputs, nothing copies, slices or
+    updates as much as a layer of it (the slot write's update is one row of
+    every layer), and the plan is what a chunk's ``extend`` on the batch-1
+    row and the pool hold between them today."""
+    from deepspeed_tpu.models import cache_family
+    from deepspeed_tpu.models.gpt_inference import cache_row
+    from deepspeed_tpu.serving.batcher import admission
+    model, cfg, slots, smax, chunk = _served(family)
+    fam = cache_family(cfg)
+    kv = "int8" if int8 else None
+    # the weights in the type they are served in (a cast of the master
+    # weights' is loop-invariant, and hoisted out of the chunk loop it
+    # would count as the program's)
+    params = _described(jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda w: w.astype(BF16), model.init(cfg, jax.random.PRNGKey(0)))),
+        v5e)
+    pool = _described(jax.eval_shape(
+        lambda: fam.init_cache(cfg, slots, smax, kv_dtype=kv)), v5e)
+    row_cache = _described(jax.eval_shape(
+        lambda: fam.init_cache(cfg, 1, smax, kv_dtype=kv)), v5e)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    tokens = arg((1, chunk), jnp.int32)
+    extend = jax.jit(
+        lambda p, t, c, l: fam.extend(p, t, cfg, c, lengths=l)).lower(
+            params, tokens, row_cache, arg((1,), jnp.int32)).compile()
+    vocab = jax.eval_shape(
+        lambda p, t, c: fam.extend(p, t, cfg, c)[0], params, tokens,
+        row_cache).shape[-1]
+    per_slot = [arg((slots,) + tail, dtype) for tail, dtype in (
+        ((), jnp.int32), ((vocab,), jnp.float32), ((2,), jnp.uint32),
+        ((), jnp.bool_), ((), jnp.float32), ((), jnp.bool_))]
+    compiled = jax.jit(
+        admission(fam, cfg, smax, kv), donate_argnums=(1, 3)).lower(
+            params, pool, *per_slot, arg((smax // chunk, chunk), jnp.int32),
+            arg((7,), jnp.int32), arg((2,), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the admission"
+    assert " while(" in text, "no loop over the chunks"
+    moved = _pool_sized_moves(text, slots * smax * cache_row(cfg)[0])
+    assert not moved, f"the admission moves whole layers of the pool: {moved}"
+    assert "input_output_alias" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        _pool_bytes(pool), "the donated pool is not updated in place"
+    # ... to a hundredth: the per-slot state rides along, and what the
+    # compiler hoists out of the chunk loop (a weight re-laid once an
+    # admission, not once a chunk) stays live through it (37 MB of 6.6 GB
+    # for the latent family; 57 MB of 14.0 GB at its cell's depth, under
+    # the tick's own 14.43 GB: PERF.md 6)
+    held_today = _planned_bytes(extend) + _pool_bytes(pool)
+    assert _planned_bytes(compiled) <= 1.01 * held_today, (
+        _planned_bytes(compiled), _planned_bytes(extend), _pool_bytes(pool))
 
 
 @pytest.mark.parametrize("stochastic", [False, True],

@@ -279,3 +279,214 @@ def test_sweep_block_counts_agree_with_the_device_sweep(monkeypatch):
                                pos[b] // 256 + 1)]
         want += want[-1:] * (grid - live)
         assert list(zip(rows.tolist(), blocks.tolist())) == want
+
+
+# ------------------------------------------------- the one-launch admission
+
+CHUNK, SLOT = 8, 64
+
+
+def _latent():
+    """The latent-attention family at the benchmark's tiny sizes."""
+    import dataclasses
+    import json
+    import os
+
+    from benchmarks.chip import latent_moe_family
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    name = "kimi-k2.7-code-ep32.json"
+    with open(os.path.join(root, "benchmarks", "chip", "configs", name)) as f:
+        file = json.load(f)
+    with open(os.path.join(root, "tests", "unit", "chipbench", "tiny",
+                           "configs", name)) as f:
+        file.update(json.load(f))
+    cfg = dataclasses.replace(latent_moe_family.build(file),
+                              dtype=jnp.bfloat16)
+    return cfg, latent_moe_family.init(cfg, jax.random.PRNGKey(0),
+                                       jnp.bfloat16)
+
+
+_SERVED = {}
+
+
+def _served(family, kv):
+    """``(fused, plain)``: two batchers over one bf16 engine of ``family``
+    with cache type ``kv``, built once a module.  ``fused`` admits;
+    ``plain`` is handed, row by row, what today's launches leave."""
+    if (family, kv) not in _SERVED:
+        if family == "latent":
+            cfg, params = _latent()
+        else:
+            mod, cfg = (gpt, CFG) if family == "dense" else (gpt_moe, MOE_CFG)
+            params = mod.init(cfg, jax.random.PRNGKey(0))
+        eng = deepspeed_tpu.init_inference(
+            model=(cfg, params),
+            config={"dtype": "bfloat16", "kv_cache_dtype": kv})
+        serving = ServingConfig.from_dict(
+            {"slots": 3, "max_len": SLOT, "prefill_chunk": CHUNK})
+        _SERVED[family, kv] = (SlotBatcher(eng, serving),
+                               SlotBatcher(eng, serving))
+    return _SERVED[family, kv]
+
+
+def _launch_by_launch(bat, row, tokens, key, greedy, temperature,
+                      prefix=None):
+    """An admission as it was before it was one program: a fresh batch-1
+    cache (or the prefix's), a ``prefill`` / ``extend`` launch a chunk, the
+    last real token's logits taken, ``write_slot`` and the six binds."""
+    fam, cfg, params = bat._fam, bat._cfg, bat._engine.params
+    start = 0 if prefix is None else prefix.length
+    cache = prefix.cache if prefix is not None else fam.init_cache(
+        cfg, 1, bat.max_len, kv_dtype=bat._kv_dtype)
+    new = np.asarray(tokens[start:], np.int32)
+    for at in range(0, len(new), CHUNK):
+        chunk = np.zeros((1, CHUNK), np.int32)
+        chunk[0, :len(new[at:at + CHUNK])] = new[at:at + CHUNK]
+        if start + at == 0:
+            lg, cache = bat._p["prefill"](params, jnp.asarray(chunk), cache)
+        else:
+            lg, cache = bat._p["extend"](
+                params, jnp.asarray(chunk), cache,
+                jnp.asarray([start + at], jnp.int32))
+    vec = lg[0, len(new) - 1 - at]
+    if bat._last is None:
+        bat._last = jnp.zeros((bat.slots,) + vec.shape, vec.dtype)
+    bat.cache = fam.write_slot(bat.cache, jnp.int32(row), cache)
+    bat.lengths = bat.lengths.at[row].set(len(tokens))
+    bat._last = bat._last.at[row].set(vec)
+    bat.keys = bat.keys.at[row].set(key)
+    bat.greedy = bat.greedy.at[row].set(greedy)
+    bat.temp = bat.temp.at[row].set(temperature)
+    bat.active = bat.active.at[row].set(True)
+
+
+@pytest.mark.parametrize("prompt", [
+    "one", "chunk", "chunk+1", "slot", "prefix", "prefix-ragged"])
+@pytest.mark.parametrize("family,kv", [
+    ("dense", "auto"), ("dense", "int8"), ("moe", "auto"), ("moe", "int8"),
+    ("latent", "auto")],
+    ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent"])
+def test_one_launch_admission_equals_the_launches_it_replaced(family, kv,
+                                                              prompt):
+    """One ``admit`` program leaves a slot as ``_chunked_prefill``'s
+    launches, ``write_slot`` and ``bind`` left it: the row's cache up to the
+    frontier, its length, frontier logits, key, sampling mode, and the eight
+    greedy tokens that follow, for prompts of 1, ``C``, ``C + 1`` and
+    ``max_len`` tokens and for prompts that continue a prefix (ending on a
+    chunk's edge, and not)."""
+    fused, plain = _served(family, kv)
+    fam, vocab = fused._fam, fused._cfg.vocab_size
+    rng = np.random.default_rng(len(prompt) + 11 * len(family))
+    n, cut = {"one": (1, 0), "chunk": (CHUNK, 0), "chunk+1": (CHUNK + 1, 0),
+              "slot": (SLOT, 0), "prefix": (3 * CHUNK + 5, 2 * CHUNK),
+              "prefix-ragged": (2 * CHUNK + 3, CHUNK - 3)}[prompt]
+    tokens = rng.integers(0, vocab, (n,)).astype(np.int32)
+    key = jax.random.PRNGKey(n)
+    row = n % 3
+    for bat in (fused, plain):
+        for r in range(bat.slots):
+            bat.release(r)
+    before = fused.admit_launches
+    prefix = [None, None]
+    if cut:
+        # each side continues a prefix of its own making: the same chunks
+        prefix = [bat.build_prefix(tokens[:cut]) for bat in (fused, plain)]
+        before = fused.admit_launches
+    assert fused.admit(row, tokens, key, True, 0.7, prefix=prefix[0]) == n
+    assert fused.admit_launches - before == 1
+    _launch_by_launch(plain, row, tokens, key, True, 0.7, prefix=prefix[1])
+
+    for name in ("lengths", "keys", "greedy", "temp", "active"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(fused, name)),
+            np.asarray(getattr(plain, name)), err_msg=name)
+    assert int(fused.lengths[row]) == n and bool(fused.active[row])
+    np.testing.assert_array_equal(np.asarray(fused._last[row], np.float32),
+                                  np.asarray(plain._last[row], np.float32))
+    got, want = (fam.read_slot(bat.cache, row, n) for bat in (fused, plain))
+    for bank in ("k", "v", "k_scale", "v_scale"):
+        if getattr(want, bank) is not None:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, bank)[:, 0, :n], np.float32),
+                np.asarray(getattr(want, bank)[:, 0, :n], np.float32),
+                err_msg=bank)
+    if n + 8 <= SLOT:
+        replies = [[int(bat.tick()[row]) for _ in range(8)]
+                   for bat in (fused, plain)]
+        assert replies[0] == replies[1]
+
+
+@pytest.mark.parametrize("family,kv", [
+    ("dense", "auto"), ("moe", "int8"), ("latent", "auto")],
+    ids=["bf16-dense", "int8-moe", "bf16-latent"])
+def test_one_admission_program_serves_every_prompt_length(family, kv):
+    """Five prompt lengths, one chunk to the whole slot, a sampled row among
+    them: one compile of ``admit``, one launch an admission, and no launch
+    by ``_chunked_prefill``'s programs at all."""
+    from deepspeed_tpu.serving.metrics import ServingMetrics
+    fused, _ = _served(family, kv)
+    rng = np.random.default_rng(5)
+    before = fused.admit_launches
+    for i, n in enumerate((2, CHUNK, CHUNK + 1, 3 * CHUNK + 2, SLOT - 1)):
+        fused.release(i % 3)
+        fused.admit(i % 3, rng.integers(0, 100, (n,)).astype(np.int32),
+                    jax.random.PRNGKey(i), i != 3, 0.5 + i)
+        fused.tick()
+    assert fused.admit_launches - before == 5
+    counts = fused.compile_counts()
+    assert counts["admit"] == counts["tick"] == 1, counts
+    assert counts["admit_wide"] == counts["admit_prefix_wide"] == 0, counts
+    m = ServingMetrics()
+    m.count("admitted", 5)
+    m.count("admit_launches", fused.admit_launches - before)
+    assert m.snapshot()["launches_per_admission"] == 1.0
+
+
+def test_the_fold_inside_the_admission_is_the_hosts_fold():
+    """``fold=``: the row samples from ``jax.random.fold_in(key, fold)``,
+    bit for bit, for folds over the whole of 32 unsigned bits; without it
+    the key is bound as handed over."""
+    fused, _ = _served("dense", "auto")
+    base = jax.random.PRNGKey(7)
+    tokens = np.arange(5, dtype=np.int32)
+    for fold in (0, 1, 12345, 2**31 + 5, 2**32 - 1):
+        fused.release(1)
+        fused.admit(1, tokens, base, False, 0.9, fold=fold)
+        np.testing.assert_array_equal(
+            np.asarray(fused.keys[1]),
+            np.asarray(jax.random.fold_in(base, fold)), err_msg=str(fold))
+    fused.release(1)
+    fused.admit(1, tokens, base, False, 0.9)
+    np.testing.assert_array_equal(np.asarray(fused.keys[1]),
+                                  np.asarray(base))
+    assert fused.compile_counts()["admit"] == 1
+
+
+def test_the_gateway_counts_one_launch_an_admission_and_a_prefix_build_more():
+    """``launches_per_admission`` is 1.0 on the plain path, and on the
+    prefix path once the prefix exists: the admission that builds the pooled
+    prefix pays the builder's launches (one a chunk) beside its own."""
+    eng = _engine()
+    gw = eng.serve(config={"slots": 2, "max_len": SLOT,
+                           "prefill_chunk": CHUNK, "queue_capacity": 8})
+    rng = np.random.default_rng(2)
+    for n in (3, CHUNK + 4, 5 * CHUNK):
+        gw.submit(rng.integers(0, 256, (n,)).astype(np.int32),
+                  max_new_tokens=2).result(timeout=120)
+    snap = gw.snapshot()
+    assert (snap["admitted"], snap["admit_launches"],
+            snap["launches_per_admission"]) == (3, 3, 1.0)
+    system = rng.integers(0, 256, (2 * CHUNK,)).astype(np.int32)
+    for n in (3, 6):
+        tail = rng.integers(0, 256, (n,)).astype(np.int32)
+        gw.submit(np.concatenate([system, tail]), max_new_tokens=2,
+                  prefix_len=2 * CHUNK).result(timeout=120)
+    gw.shutdown()
+    snap = gw.snapshot()
+    # 3 plain + (2 chunks to build the prefix + 1) + 1 on the pooled prefix
+    assert (snap["admitted"], snap["admit_launches"]) == (5, 3 + 3 + 1)
+    assert snap["prefix_builds"] == 1 and snap["prefix_hits"] == 1
+    assert snap["recompiles"] == 0
+    counts = snap["compile_counts"]
+    assert counts["admit"] == counts["admit_prefix"] == 1, counts

@@ -355,9 +355,13 @@ def test_warm_start_precompiles_every_rung_program(engine):
                               "prefill_chunk": 8, "warm_start": True,
                               "overload": {"enabled": True}})
     counts = gw._batcher.compile_counts()
-    for name in ("prefill", "extend", "take_last", "prefill_wide",
-                 "extend_wide", "take_last_wide", "write_slot", "bind",
-                 "release", "tick"):
+    # an admission with and without a prefix, and the chunk pair that builds
+    # one launch by launch, each at both widths
+    assert sorted(counts) == sorted((
+        "admit", "admit_prefix", "prefill", "extend", "admit_wide",
+        "admit_prefix_wide", "prefill_wide", "extend_wide", "release",
+        "tick"))
+    for name in counts:
         assert counts.get(name) == 1, (name, counts)
     # prewarm left every slot free: real traffic runs immediately...
     outs = [gw.submit(np.arange(4 + i, dtype=np.int32), max_new_tokens=3)
@@ -367,6 +371,9 @@ def test_warm_start_precompiles_every_rung_program(engine):
     gw._batcher.set_chunk_wide(True)
     wide = gw.submit(np.arange(17, dtype=np.int32), max_new_tokens=3)
     assert wide.result(timeout=60).shape == (3,)
+    forked = gw.submit(np.arange(40, dtype=np.int32), max_new_tokens=3,
+                       prefix_len=20)
+    assert forked.result(timeout=60).shape == (3,)
     assert gw._batcher.compile_counts() == counts
     assert gw.snapshot()["recompiles"] == 0
     gw.shutdown()

@@ -240,13 +240,13 @@ def test_pool_scatter_gather_roundtrip_bitwise():
                                          prefill_chunk=8))
     pool = PagedKVPool(bat, block_tokens=8, num_blocks=6)
     prompt = np.arange(11, dtype=np.int32) % 128
-    cache, _vec, frontier = bat._chunked_prefill(prompt)
+    cache, frontier = bat._chunked_prefill(prompt)
     table = [pool.allocator.alloc() for _ in range(2)]   # ceil(11/8)
     pool.scatter(cache, pad_table(table, pool.max_blocks))
     back = pool.gather(table, frontier)
     for src, dst in zip(jax.tree_util.tree_leaves(cache),
                         jax.tree_util.tree_leaves(back)):
-        if getattr(src, "ndim", 0) == 5:
+        if getattr(src, "ndim", 0) == 4:     # a bank: [L, 1, S, H*D]
             np.testing.assert_array_equal(
                 np.asarray(src)[:, :, :16], np.asarray(dst)[:, :, :16])
     assert int(back.length) == frontier

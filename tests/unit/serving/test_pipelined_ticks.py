@@ -448,6 +448,9 @@ def test_the_report_prints_both_shares_on_the_serving_line(engine, draft,
     assert f"live_block_share {round(snap['live_block_share'], 4)}" in line
     assert f"overlap_share {last['overlap_share']}" in line
     assert f"late_row_share {last['late_row_share']}" in line
+    # ... and the launches an admission took: one each
+    assert last["launches_per_admission"] == 1.0
+    assert "launches_per_admission 1.0" in line
     assert mod.main([str(tmp_path), "--json"]) == 0
     serving = json.loads(capsys.readouterr().out)["metrics"][
         "metrics.jsonl"]["serving"]

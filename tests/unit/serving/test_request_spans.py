@@ -59,20 +59,21 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     assert queue.dur + admit.dur + first.dur == pytest.approx(
         h.t_first_token - h.t_submit, abs=1e-3)
 
-    # admission's children, where the work happens
-    prefill, alloc, write = (one("serve.prefill"), one("serve.cache_alloc"),
-                             one("serve.slot_write"))
-    assert _inside(prefill, admit) and _inside(write, admit)
-    assert _inside(alloc, prefill)
-    assert alloc.args["bytes"] == 2 * CFG.n_layer * 64 * CFG.d_model * 4
+    # admission's one child, where the work happens: ``serve.prefill``
+    # around the ONE launch that runs the chunks, writes the slot and binds
+    # the row, with the arguments it always had.  No batch-1 cache is
+    # allocated by the host, no chunk is a launch of its own and the slot
+    # write is no step of its own: their spans are not entered
+    prefill = one("serve.prefill")
+    assert _inside(prefill, admit)
     assert prefill.args == {"tokens": CHUNK + 1, "start": 0, "chunk": CHUNK,
                             "padded": 2 * CHUNK, "chunks": 2}
-    chunks = sorted(by("serve.prefill_chunk"), key=lambda s: s.t0)
-    assert [c.args for c in chunks] == [
-        {"index": 0, "pos": 0, "program": "prefill"},
-        {"index": 1, "pos": CHUNK, "program": "extend"}]
-    assert all(_inside(c, prefill) for c in chunks)
-    assert write.args == {"slot": admit.args["slot"]}
+    assert [s.name for s in spans if _inside(s, admit)] == ["serve.prefill"]
+    for name in ("serve.cache_alloc", "serve.prefill_chunk",
+                 "serve.slot_write"):
+        assert by(name) == []
+    assert (snap["admitted"], snap["admit_launches"],
+            snap["launches_per_admission"]) == (1, 1, 1.0)
 
     # every tick: the pull inside it, the harvest after it.  Three ticks
     # deliver the reply; the fourth was launched before the third's
@@ -94,33 +95,50 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     assert queue.thread == first.thread == WAIT_THREAD != admit.thread
 
 
-def test_requests_keep_their_own_ids_and_a_continued_prefix_allocates_nothing(
+def test_requests_keep_their_own_ids_and_only_a_prefix_build_runs_chunk_by_chunk(
         engine):
     """Two requests over one pooled prefix: each admission carries its own
-    ``rid``; the first builds the prefix (a fresh cache) and continues it,
-    the second only continues it, so it has no ``serve.cache_alloc``."""
+    ``rid``.  The first builds the prefix, which hands a batch-1 cache BACK
+    and so runs launch by launch (``build_prefix``: a fresh cache, a
+    ``serve.prefill_chunk`` a chunk), and then continues it in its one
+    launch; the second only continues it."""
     tracer = Tracer(name="serving")
     gw = engine.serve(config=SERVING, tracer=tracer)
     rng = np.random.default_rng(3)
-    prefix = rng.integers(1, 256, (CHUNK,)).astype(np.int32)
+    prefix = rng.integers(1, 256, (2 * CHUNK,)).astype(np.int32)
     handles = []
     for n in (3, 5):
         tail = rng.integers(1, 256, (n,)).astype(np.int32)
         handles.append(gw.submit(np.concatenate([prefix, tail]),
-                                 max_new_tokens=2, prefix_len=CHUNK))
+                                 max_new_tokens=2, prefix_len=2 * CHUNK))
         handles[-1].result(timeout=120)
     gw.shutdown()
     spans = tracer.spans()
+    by = lambda name: [s for s in spans if s.name == name]
     for name in ("serve.queue", "serve.admit", "serve.first_token"):
-        assert [s.args["rid"] for s in spans if s.name == name] == [
+        assert [s.args["rid"] for s in by(name)] == [
             h.request_id for h in handles]
-    admits = [s for s in spans if s.name == "serve.admit"]
-    allocs = [s for s in spans if s.name == "serve.cache_alloc"]
-    assert len(allocs) == 1 and allocs[0].t0 < admits[0].t0 + admits[0].dur
-    prefills = [s.args for s in spans if s.name == "serve.prefill"]
-    assert [(p["tokens"], p["start"], p["padded"]) for p in prefills] == [
-        (CHUNK, 0, CHUNK), (3, CHUNK, CHUNK), (5, CHUNK, CHUNK)]
-    assert sum(p["chunks"] for p in prefills) == 3
+    admits = by("serve.admit")
+    build, first, second = by("serve.prefill")
+    assert [(p.args["tokens"], p.args["start"], p.args["padded"],
+             p.args["chunks"]) for p in (build, first, second)] == [
+        (2 * CHUNK, 0, 2 * CHUNK, 2), (3, 2 * CHUNK, CHUNK, 1),
+        (5, 2 * CHUNK, CHUNK, 1)]
+    assert _inside(build, admits[0]) and _inside(first, admits[0])
+    assert _inside(second, admits[1])
+    # the builder's children: the batch-1 cache it allocates and a launch a
+    # chunk; an admission's ``serve.prefill`` has none
+    (alloc,) = by("serve.cache_alloc")
+    assert _inside(alloc, build)
+    assert alloc.args["bytes"] == 2 * CFG.n_layer * 64 * CFG.d_model * 4
+    chunks = sorted(by("serve.prefill_chunk"), key=lambda s: s.t0)
+    assert [c.args for c in chunks] == [
+        {"index": 0, "pos": 0, "program": "prefill"},
+        {"index": 1, "pos": CHUNK, "program": "extend"}]
+    assert all(_inside(c, build) for c in chunks)
+    assert by("serve.slot_write") == []
+    snap = gw.snapshot()
+    assert (snap["admitted"], snap["admit_launches"]) == (2, 2 + 1 + 1)
 
 
 def test_a_gateway_without_a_tracer_serves_and_keeps_no_record(engine):
