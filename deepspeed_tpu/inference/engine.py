@@ -179,7 +179,11 @@ class InferenceEngine:
             cache = (fam.init_cache(cfg, B, max_len, kv_dtype=kv_dtype)
                      if kv_dtype is not None else
                      fam.init_cache(cfg, B, max_len))
-            logits, cache = fam.prefill(params, tokens, cfg, cache)
+            # where each row's prompt ends inside the padded width: a family
+            # that keeps state per slot must not let a recurrence take the
+            # padding (attention masks it at read time)
+            logits, cache = fam.prefill(params, tokens, cfg, cache,
+                                        valid=prompt_len)
             # logits at the last *prompt* token predict the first new token
             last = logits[jnp.arange(B), prompt_len - 1]
             out = jnp.full((B, n_bucket), eos if eos is not None else 0,

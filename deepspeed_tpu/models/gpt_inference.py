@@ -24,6 +24,19 @@ cached token takes in a layer): the dense block keeps K and V, two banks of
 compressed row shared by all heads.  ``KVCache``, ``init_cache``, the slot
 ops and the sweep's block take that row, never ``n_head * head_dim``.
 
+Beside the token-indexed banks the cache has ONE optional leaf of another
+kind, ``state``: what a family keeps PER SLOT whatever the conversation's
+length (a state-space layer's running sum and its convolution tail,
+``hybrid_ssm_moe_inference``).  The family declares it beside its row
+(``config.cache_state``: ``(layers, per-slot shape, dtype)`` for each
+array), ``init_cache`` makes it ``[layers, B, ...]`` with no ``S`` axis, and
+the slot ops treat each array like a bank without one; it is None for every
+family that keeps none.  The layers that own banks and the layers that own
+state index two different stacks (``config.cache_layers`` banks' layers,
+default all), and a pass tells the scan how many of a row's tokens are real
+(``valid``): attention masks a padded position at read time, a recurrence
+would carry it forever.
+
 Cache layout [L, B, S_max, H*D]: static shapes (XLA requirement), masked by
 the current length; decode attention reads the cache tiled over S_max with
 positions beyond ``pos`` masked.  A token's heads are folded into ONE row
@@ -67,7 +80,9 @@ class KVCache:
     dequantized inside the decode kernel's VMEM stream.  ``stats`` (None
     for most families) is a small int32 vector of counters a family's scan
     step adds to on the device (an expert layer's pair counts): it rides
-    the donated cache and reaches the host with the tick's own pull."""
+    the donated cache and reaches the host with the tick's own pull.
+    ``state`` (None for most families) is a tuple of per-slot arrays
+    ``[layers, B, ...]`` with no token axis, a reset slot's all zero."""
 
     k: jnp.ndarray        # [L, B, S_max, row[0]]
     v: Any                # [L, B, S_max, row[1]] or None
@@ -75,10 +90,11 @@ class KVCache:
     k_scale: Any = None
     v_scale: Any = None
     stats: Any = None
+    state: Any = None
 
     def tree_flatten(self):
         return (self.k, self.v, self.length, self.k_scale, self.v_scale,
-                self.stats), None
+                self.stats, self.state), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -107,15 +123,25 @@ def cache_row(config) -> Tuple[int, ...]:
 
 
 def init_cache(config, batch: int, max_len: int, kv_dtype=None,
-               stats: int = 0) -> KVCache:
+               stats: Optional[Dict[str, slice]] = None) -> KVCache:
     """``kv_dtype``: None → cache in the compute dtype; ``"int8"``/
     ``jnp.int8`` → int8 codes + per-vector fp32 scales (beyond-reference:
     halves decode HBM traffic and doubles the context/batch a chip's
     cache budget holds; the two-bank dense row only).  ``stats``: the
-    length of the family's counter vector (0: none)."""
+    groups of the family's counter vector, name -> where the group lies
+    (its module's ``stats_groups``, which owns the layout; none: no
+    vector)."""
     row = cache_row(config)
-    shapes = [(config.n_layer, batch, max_len, w) for w in row]
+    layers = getattr(config, "cache_layers", config.n_layer)
+    shapes = [(layers, batch, max_len, w) for w in row]
+    declared = getattr(config, "cache_state", None)
+    state = None if not declared else tuple(
+        jnp.zeros((n, batch) + tuple(shape), dtype)
+        for n, shape, dtype in declared)
     if kv_dtype in ("int8", jnp.int8):
+        if state is not None:
+            raise NotImplementedError(
+                "the int8 cache exists for families without per-slot state")
         if len(row) != 2:
             raise NotImplementedError(
                 "the int8 cache (codes and per-head scale banks) exists for "
@@ -129,7 +155,9 @@ def init_cache(config, batch: int, max_len: int, kv_dtype=None,
     banks = [jnp.zeros(shape, config.dtype) for shape in shapes]
     return KVCache(k=banks[0], v=banks[1] if len(banks) > 1 else None,
                    length=jnp.zeros((), jnp.int32),
-                   stats=jnp.zeros((stats,), jnp.int32) if stats else None)
+                   stats=jnp.zeros((max(g.stop for g in stats.values()),),
+                                   jnp.int32) if stats else None,
+                   state=state)
 
 
 def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
@@ -179,7 +207,7 @@ def _block_tail(x, attn, p, config: gpt.GPTConfig):
     return gpt.mlp_residual(x + attn_out, p, config)
 
 
-def dense_step(params: PyTree, config: gpt.GPTConfig):
+def dense_step(params: PyTree, config: gpt.GPTConfig, valid=None):
     """The dense stack's half of :func:`_layer_scan`: one segment,
     ``[(stacks, body)]``.  One scan step is one block: attention at layer
     ``i``, then its tail."""
@@ -248,14 +276,17 @@ def _dense_sweeps(pos, B, config: gpt.GPTConfig, max_len, active):
 class Family:
     """What a model family brings to the one cache family.
 
-    ``step(params, config)``: the scan's segments, ``[(stacks, body), ...]``
-    in depth order: the parameter stacks a scan walks and ``body(x, p, i,
-    attend, cache) -> (x, cache)``, what step ``i`` of that segment does
-    with ``attend(x, p, layer, cache) -> (attention output, cache)``
-    (:func:`dense_step`: one block a step; GPT-MoE: a dense and an expert
-    block, layers ``2i`` and ``2i+1`` of the same pool; a stack with
-    leading dense layers: two segments).  A body may add to
-    ``cache.stats``.
+    ``step(params, config, valid)``: the scan's segments, ``[(stacks,
+    body), ...]`` in depth order: the parameter stacks a scan walks and
+    ``body(x, p, i, attend, cache) -> (x, cache)``, what step ``i`` of that
+    segment does with ``attend(x, p, layer, cache) -> (attention output,
+    cache)`` (:func:`dense_step`: one block a step; GPT-MoE: a dense and an
+    expert block, layers ``2i`` and ``2i+1`` of the same pool; a stack with
+    leading dense layers: two segments; layers of two kinds: a segment per
+    run of one kind).  A body may add to ``cache.stats`` and advance
+    ``cache.state``; ``valid`` [B] int32 is how many of the call's tokens
+    are real in each row (a tick: 1 for a live row, 0 for a freed one), for
+    a body whose state a padded position would corrupt.
     ``project(x, p, config, positions) -> (q, fresh)``: a layer's queries
     and the row it caches, one array per bank, ``[B, S, ...]`` (trailing
     dimensions are folded into the bank's one).
@@ -279,7 +310,7 @@ DENSE = Family(step=dense_step)
 
 
 def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
-                family: Family = DENSE):
+                family: Family = DENSE, valid=None):
     """The one layer-stack scan every cache-filling path shares.
 
     The cache rides the scan's CARRY, never its ``xs``/``ys``: no layer is
@@ -290,7 +321,7 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
     ``attn(q, fresh, cache, layer)`` computes the sublayer's attention
     (prefill reads the fresh unpadded rows; extend/decode read layer
     ``layer`` of the updated pool where it lies).  ``family``: see
-    :class:`Family`.  Returns (hidden
+    :class:`Family`; ``valid``: its ``step``'s.  Returns (hidden
     states, updated KVCache, ``length`` untouched).
     """
     int8 = cache.int8
@@ -322,7 +353,7 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
             a = attn(q, fresh, cache, idx)
         return a, cache
 
-    for stacks, body in family.step(params, config):
+    for stacks, body in family.step(params, config, valid):
         def layer(carry, xs, body=body):
             (x, cache), (p, i) = carry, xs
             return body(x, p, i, attend, cache), None
@@ -333,14 +364,26 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
     return x, cache
 
 
+def _real_tokens(valid, B: int, S: int):
+    """``valid`` as the ``[B]`` int32 a family's ``step`` takes (default:
+    all ``S`` tokens of every row are real)."""
+    return jnp.full((B,), S, jnp.int32) if valid is None else \
+        jnp.broadcast_to(jnp.asarray(valid, jnp.int32).reshape(-1), (B,))
+
+
 def prefill(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
-            family: Family = DENSE) -> Tuple[jnp.ndarray, KVCache]:
+            family: Family = DENSE,
+            valid=None) -> Tuple[jnp.ndarray, KVCache]:
     """Run the prompt through the model, filling cache[0:S].
 
     Returns (logits [B, S, padded_vocab] fp32, cache).  Assumes an empty
     cache (length 0) — chunked prefill composes by calling with growing
     ``cache.length`` via :func:`extend`.  ``family`` (here, in ``extend``
     and in ``decode_step``) is the model family's, see :class:`Family`.
+    ``valid`` [B] (here and in ``extend``, of every family's): how many of
+    a row's ``S`` tokens are real, when the caller pads (default: all).
+    Only a family with per-slot state reads it; the banks take the padding
+    either way.
     """
     B, S = tokens.shape
     positions = jnp.arange(S)
@@ -353,15 +396,15 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
         return family.attend_fresh(q, fresh, cache, config, idx)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
-                           family)
+                           family, _real_tokens(valid, B, S))
     logits = family.logits(params, x, config)
     return logits, dataclasses.replace(cache,
                                        length=jnp.asarray(S, jnp.int32))
 
 
 def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
-           lengths=None,
-           family: Family = DENSE) -> Tuple[jnp.ndarray, KVCache]:
+           lengths=None, family: Family = DENSE,
+           valid=None) -> Tuple[jnp.ndarray, KVCache]:
     """Chunked prefill: append ``tokens`` [B, S_c] at positions
     ``cache.length .. cache.length+S_c-1``, attending causally over the
     cached prefix + the chunk.
@@ -416,7 +459,7 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
         return family.attend_cached(q, cache, pos0, config, idx)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
-                           family)
+                           family, _real_tokens(valid, B, Sc))
     logits = family.logits(params, x, config)
     return logits, dataclasses.replace(cache,
                                        length=jnp.max(pos0) + Sc)
@@ -429,14 +472,27 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
 # three ops are that contract: ``row`` may be a traced scalar, so one
 # compiled program serves every slot — admitting into slot 7 never
 # recompiles the program that admitted into slot 2.  They walk whatever
-# banks the family's row has (a bank the cache lacks is None and stays so).
+# banks the family's row has (a bank the cache lacks is None and stays so)
+# and the per-slot state where the family keeps one: every such array leads
+# with ``[layers, B]``, and what follows (tokens, or none) is the slot's.
 
 
 def _each_bank(f, cache: KVCache, *others: KVCache) -> dict:
-    """``f`` over every bank (and scale bank) the cache holds."""
-    return {name: f(getattr(cache, name), *(getattr(o, name) for o in others))
-            for name in ("k", "v", "k_scale", "v_scale")
-            if getattr(cache, name) is not None}
+    """``f`` over every bank (and scale bank) the cache holds, and over the
+    arrays of its per-slot state."""
+    out = {name: f(getattr(cache, name), *(getattr(o, name) for o in others))
+           for name in ("k", "v", "k_scale", "v_scale")
+           if getattr(cache, name) is not None}
+    if cache.state is not None:
+        out["state"] = tuple(
+            f(a, *(o.state[i] for o in others))
+            for i, a in enumerate(cache.state))
+    return out
+
+
+def _at_slot(buf, row):
+    """Start indices of slot ``row`` in a ``[layers, B, ...]`` array."""
+    return (0, row) + (0,) * (buf.ndim - 2)
 
 
 def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
@@ -456,7 +512,7 @@ def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
             f"cache's {cache.max_len}")
 
     def ins(dst, s):
-        return lax.dynamic_update_slice(dst, s, (0, row, 0, 0))
+        return lax.dynamic_update_slice(dst, s, _at_slot(dst, row))
 
     return dataclasses.replace(
         cache, length=jnp.maximum(cache.length, src.length),
@@ -465,11 +521,12 @@ def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
 
 
 def reset_slot(cache: KVCache, row) -> KVCache:
-    """Zero slot ``row``'s K/V (and scales): a retired conversation's
-    K/V never bleeds into the next tenant, even through a masked read."""
+    """Zero slot ``row``'s K/V (and scales, and per-slot state): a retired
+    conversation's K/V never bleeds into the next tenant, even through a
+    masked read, and a state-space layer starts from zero."""
     def z(buf):
         blank = jnp.zeros((buf.shape[0], 1) + buf.shape[2:], buf.dtype)
-        return lax.dynamic_update_slice(buf, blank, (0, row, 0, 0))
+        return lax.dynamic_update_slice(buf, blank, _at_slot(buf, row))
 
     return dataclasses.replace(cache, **_each_bank(z, cache))
 
@@ -480,7 +537,7 @@ def read_slot(cache: KVCache, row, length=None) -> KVCache:
     ``cache.length`` only tracks the max).  Counters stay with the pool:
     the copy's start at zero."""
     def rd(buf):
-        return lax.dynamic_slice(buf, (0, row, 0, 0),
+        return lax.dynamic_slice(buf, _at_slot(buf, row),
                                  (buf.shape[0], 1) + buf.shape[2:])
 
     return dataclasses.replace(
@@ -523,8 +580,9 @@ def decode_step(params: PyTree, token: jnp.ndarray, config, cache: KVCache,
         return family.attend_cached(q, cache, pos, config, idx,
                                     active=active, sweep=sweep_of(idx))
 
-    x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
-                           family)
+    x, cache = _layer_scan(
+        x, params, cache, config, positions, write, attn, family,
+        _real_tokens(active, B, 1))
     logits = family.logits(params, x[:, 0], config)
     new_len = (jnp.max(pos) + 1) if ragged else pos + 1
     return logits, dataclasses.replace(cache, length=new_len)

@@ -66,7 +66,7 @@ def _moe_ffn(x, attn_p, moe_p, moe, config: GPTMoEConfig):
     return x + moe_out
 
 
-def moe_step(params: PyTree, config: GPTMoEConfig):
+def moe_step(params: PyTree, config: GPTMoEConfig, valid=None):
     """The GPT-MoE half of ``gpt_inference._layer_scan``: one segment whose
     scan step is one (dense, MoE) pair, layers ``2i`` and ``2i+1`` of the
     one pool."""
@@ -97,12 +97,13 @@ _PREFILL_CHUNK = 128
 
 
 def prefill(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
-            cache: KVCache) -> Tuple[jnp.ndarray, KVCache]:
+            cache: KVCache, valid=None) -> Tuple[jnp.ndarray, KVCache]:
     """Prompt pass filling the cache; returns (logits, cache).
 
     Long prompts (> ``_PREFILL_CHUNK`` gated tokens) run as a chain of
     ``extend`` chunks to keep the dropless dispatch tensors bounded at
-    [B·chunk, E, B·chunk] instead of [B·S, E, B·S]."""
+    [B·chunk, E, B·chunk] instead of [B·S, E, B·S].  ``valid`` (here and in
+    ``extend``) is the families' common signature; no state here reads it."""
     B, S = tokens.shape
     if B * S <= _PREFILL_CHUNK:
         return gpt_inference.prefill(params, tokens, config, cache,
@@ -118,7 +119,8 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
 
 
 def extend(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
-           cache: KVCache, lengths=None) -> Tuple[jnp.ndarray, KVCache]:
+           cache: KVCache, lengths=None,
+           valid=None) -> Tuple[jnp.ndarray, KVCache]:
     """``gpt_inference.extend`` with the MoE step: ``prefill(t[:, :c]) ;
     extend(t[:, c:])`` equals one full ``prefill`` — the contract the
     speculative verify pass rides (dropless gating keeps rows and chunks

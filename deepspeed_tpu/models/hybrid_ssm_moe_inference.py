@@ -1,0 +1,214 @@
+"""Cached inference for the hybrid state-space / attention family: a step, a
+row and a per-slot state.
+
+The cache class, the layer scan and the slot ops are ``gpt_inference``'s
+own (the one cache family of the tree); this module brings what
+``gpt_inference.Family`` asks of a model family:
+
+- the **row** (``config.cache_row``): K and V of the key-value heads, two
+  banks of ``n_kv_head * head_dim``, for the attention layers alone
+  (``config.cache_layers``): the pool is ``[L_attn, B, S_max, row]``;
+- the **state** (``config.cache_state``): what a state-space layer keeps of
+  a conversation whatever its length: the running sum ``H`` ``[L_ssm, B,
+  d_state, heads * head_dim]`` float32 and the last ``conv_kernel - 1``
+  pre-activation inputs of the convolution ``[L_ssm, B, K - 1, d_conv]``.
+  It is the cache's ``state`` leaf; the slot ops insert, read and zero it
+  with the banks;
+- the **step**: one segment per run of consecutive layers of one kind, in
+  depth order (``config.runs``).  An attention layer goes through the
+  scan's ``attend`` at its index among the attention layers; a state-space
+  layer advances layer ``j`` of the state stacks in place: one token a live
+  slot through ``ssm_decode_step`` (a freed slot neither steps nor moves),
+  a chunk through ``ssd_chunk_scan`` to the state after the chunk's last
+  REAL token (``valid``: a padded tail takes ``dt = 0`` and the convolution
+  tail kept is that of the last real tokens).  Every layer ends in the
+  expert layer, whose pair counts go to ``cache.stats`` beside the state
+  counters (``STATE_COUNTERS``), each group where ``stats_groups`` says.
+
+Not supported, refused where it is asked for (``UNSUPPORTED``): the int8
+cache, paging, pooled prefixes and speculation.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..ops.pallas import ssm
+from . import gpt_inference, hybrid_ssm_moe as model
+from .gpt_inference import (KVCache, read_slot, reset_slot,  # noqa: F401
+                            write_slot)
+from .hybrid_ssm_moe import (ATTENTION, MAMBA,  # noqa: F401
+                             HybridSSMMoEConfig, apply, logical_axes)
+
+PyTree = Any
+
+#: serving features this family is refused, with the reason
+UNSUPPORTED = {
+    "speculative": "a rejected draft token would have to be rolled back out "
+                   "of the per-slot state, and a ragged verify pass carries "
+                   "no per-row count of real tokens",
+    "paging": "a parked conversation's per-slot state has no block to live "
+              "in: the pager moves token-indexed banks only",
+    "prefix": "a pooled prefix would need a snapshot of the per-slot state "
+              "at its end; the pool keeps token-indexed banks only",
+}
+
+#: the counters of this family's group ``state_steps`` in ``cache.stats``:
+#: state rows stepped by ticks (live slot x state-space layer), and real and
+#: padded tokens through the chunk scan (token x state-space layer)
+STATE_COUNTERS = ("ssm_rows_stepped", "scan_tokens_real",
+                  "scan_tokens_padded")
+
+#: the routed experts' matrices: never an ``xs`` of the layer scan (a slice
+#: of a stack handed to a Pallas call is copied out first); a segment's body
+#: closes over its run's whole stacks and reads its layer in place
+_ROUTED = ("w_gu", "w_down")
+
+
+def stats_groups(config: HybridSSMMoEConfig) -> Dict[str, slice]:
+    """Where each group of this family's device counters lies in
+    ``cache.stats``: the one place that knows.  ``moe_pairs``: the expert
+    layer's ``pair_counts``; ``state_steps``: ``STATE_COUNTERS``."""
+    pairs = 3 + len(config.held)
+    return {"moe_pairs": slice(0, pairs),
+            "state_steps": slice(pairs, pairs + len(STATE_COUNTERS))}
+
+
+def init_cache(config: HybridSSMMoEConfig, batch: int, max_len: int,
+               kv_dtype=None) -> KVCache:
+    if kv_dtype is not None:
+        raise NotImplementedError(
+            "the hybrid state-space family caches in the compute dtype "
+            f"only (kv_cache_dtype={kv_dtype!r})")
+    return gpt_inference.init_cache(config, batch, max_len,
+                                    stats=stats_groups(config))
+
+
+def _mamba_mixer(x, p, j, cache: KVCache, valid, work,
+                 config: HybridSSMMoEConfig):
+    """A state-space layer's mixer on ``x`` [B, S, d] against layer ``j``
+    of the state stacks; returns ``(x, state, counters [3])``."""
+    B, S, _ = x.shape
+    h_stack, tails = cache.state
+    z, u, dt = model.ssm_inputs(x, p, config)
+    tail = lax.dynamic_index_in_dim(tails, j, 0, keepdims=False)
+    with jax.named_scope("ssm_conv"):
+        u_act, tail = ssm.causal_conv(u, tail, p["conv_w"], p["conv_b"],
+                                      valid)
+    tails = lax.dynamic_update_slice(tails, tail[None], (j, 0, 0, 0))
+    v, dt, a, Bm, Cm = model.ssm_scan_inputs(u_act, dt, p, config)
+    real = jnp.sum(valid)
+    if S == 1:
+        P = config.ssm_head_dim
+        wide = lambda t: jnp.repeat(t[:, 0], P, axis=-1)      # [B, H] -> HP
+        y, h_stack = ssm.ssm_decode_step(
+            h_stack, j, wide(dt) * v.reshape(B, -1).astype(jnp.float32),
+            wide(jnp.exp(dt * a)), Bm[:, 0], Cm[:, 0], active=valid > 0,
+            work=work)
+        y = y[:, None]
+        counters = jnp.stack([real, 0, 0])
+    else:
+        y, h_stack = ssm.ssd_chunk_scan(h_stack, j, v, dt, a, Bm, Cm,
+                                        valid=valid, chunk=config.ssm_chunk)
+        counters = jnp.stack([0, real, B * S - real])
+    return (model.ssm_output(x, y, v, z, p, config), (h_stack, tails),
+            counters.astype(jnp.int32))
+
+
+def _step(params: PyTree, config: HybridSSMMoEConfig, valid):
+    segments = []
+    # a tick's work list, built once for all its state-space layers
+    work = ssm.live_rows(valid > 0, valid.shape[0])
+    no_state = jnp.zeros((len(STATE_COUNTERS),), jnp.int32)
+    groups = stats_groups(config)
+    for (kind, first, n), run in zip(config.runs, params["runs"]):
+        routed = {k: run[k] for k in _ROUTED}
+
+        def ffn(x, p, i, cache, counters, routed=routed):
+            x, counts = model.expert_ffn(x, p, config, experts=routed,
+                                         layer=i)
+            return x, dataclasses.replace(
+                cache, stats=cache.stats.at[groups["moe_pairs"]].add(counts)
+                .at[groups["state_steps"]].add(counters))
+
+        def mamba_body(x, p, i, attend, cache, first=first, ffn=ffn):
+            x, state, counters = _mamba_mixer(x, p, first + i, cache, valid,
+                                              work, config)
+            return ffn(x, p, i, dataclasses.replace(cache, state=state),
+                       counters)
+
+        def attention_body(x, p, i, attend, cache, first=first, ffn=ffn):
+            a, cache = attend(x, p, first + i, cache)
+            return ffn(model.attention_output(x, a, p, config), p, i, cache,
+                       no_state)
+
+        segments.append(({k: v for k, v in run.items() if k not in _ROUTED},
+                         mamba_body if kind == MAMBA else attention_body))
+    return segments
+
+
+def _project(x, p, config: HybridSSMMoEConfig, positions):
+    return model.attention_project(x, p, config)
+
+
+def _attend_cached(q, cache: KVCache, pos, config: HybridSSMMoEConfig, idx,
+                   active=None, sweep=None):
+    from ..ops.pallas.decode_attention import cached_attention
+    return cached_attention(q, cache.k, cache.v, pos,
+                            sm_scale=config.attn_scale, layer=idx,
+                            active=active, sweep=sweep,
+                            kv_heads=config.n_kv_head)
+
+
+def _attend_fresh(q, fresh, cache, config: HybridSSMMoEConfig, idx):
+    # a prompt pass is a chunk at position 0 of the rows just written
+    return _attend_cached(q, cache, jnp.zeros((), jnp.int32), config, idx)
+
+
+def sweep_geometry(config: HybridSSMMoEConfig, max_len: int):
+    """``gpt_inference.sweep_geometry``: the decode kernel's block for the
+    grouped row, one call a tick for each attention layer."""
+    from ..ops.pallas.decode_attention import decode_block_k
+    return decode_block_k(max_len, config.cache_row[0]), (
+        (None, config.cache_layers),)
+
+
+def _sweeps(pos, B, config: HybridSSMMoEConfig, max_len, active):
+    from ..ops.pallas.decode_attention import decode_block_k, decode_sweep
+    sweep = decode_sweep(pos, B, max_len,
+                         decode_block_k(max_len, config.cache_row[0]), active)
+    return lambda idx: sweep
+
+
+FAMILY = gpt_inference.Family(
+    step=_step, project=_project, attend_fresh=_attend_fresh,
+    attend_cached=_attend_cached, sweeps=_sweeps,
+    embed=lambda params, tokens, config, positions=None:
+        model.embed(params, tokens, config),
+    logits=model.lm_logits)
+
+
+def prefill(params: PyTree, tokens, config: HybridSSMMoEConfig,
+            cache: KVCache, valid=None) -> Tuple[jnp.ndarray, KVCache]:
+    return gpt_inference.prefill(params, tokens, config, cache,
+                                 family=FAMILY, valid=valid)
+
+
+def extend(params: PyTree, tokens, config: HybridSSMMoEConfig,
+           cache: KVCache, lengths=None,
+           valid=None) -> Tuple[jnp.ndarray, KVCache]:
+    return gpt_inference.extend(params, tokens, config, cache,
+                                lengths=lengths, family=FAMILY, valid=valid)
+
+
+def decode_step(params: PyTree, token, config: HybridSSMMoEConfig,
+                cache: KVCache, lengths=None,
+                active=None) -> Tuple[jnp.ndarray, KVCache]:
+    return gpt_inference.decode_step(params, token, config, cache,
+                                     lengths=lengths, active=active,
+                                     family=FAMILY)

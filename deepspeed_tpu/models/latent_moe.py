@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..moe.held_experts import held_experts_ffn, route
+from ..moe.held_experts import held_experts_ffn, pair_counts, route
 from .partitioning import EMBED, EXPERT, HEADS, KV, LAYERS, MLP, VOCAB
 
 PyTree = Any
@@ -241,11 +241,7 @@ def expert_ffn(x, p, config: LatentMoEConfig, experts=None, layer=None):
         config.n_experts, layer=layer if experts is not None else None)
     with jax.named_scope("moe_shared"):
         shared = swiglu(h, p["ws_gu"], p["ws_down"], config.dtype)
-    counts = jnp.concatenate([
-        jnp.sum(per_expert, keepdims=True),
-        jnp.full((1,), B * S * config.experts_per_token, jnp.int32),
-        jnp.sum(per_expert > 0, keepdims=True, dtype=jnp.int32),
-        per_expert])
+    counts = pair_counts(per_expert, B * S * config.experts_per_token)
     return x + routed.reshape(B, S, d).astype(jnp.float32) + shared, counts
 
 

@@ -26,7 +26,7 @@ and paging are the batcher's to refuse (``serving/batcher.py``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Tuple
+from typing import Any, Dict, Tuple
 
 import jax.numpy as jnp
 
@@ -46,8 +46,12 @@ UNSUPPORTED = {
 }
 
 
-def _stats_len(config: LatentMoEConfig) -> int:
-    return 3 + len(config.held) if config.n_moe_layers else 0
+def stats_groups(config: LatentMoEConfig) -> Dict[str, slice]:
+    """Where each group of this family's device counters lies in
+    ``cache.stats``: the expert layers' ``pair_counts`` and nothing else."""
+    if not config.n_moe_layers:
+        return {}
+    return {"moe_pairs": slice(0, 3 + len(config.held))}
 
 
 def init_cache(config: LatentMoEConfig, batch: int, max_len: int,
@@ -58,7 +62,7 @@ def init_cache(config: LatentMoEConfig, batch: int, max_len: int,
             "the int8 cache's scale banks are per head and a latent row has "
             f"no heads (kv_cache_dtype={kv_dtype!r})")
     return gpt_inference.init_cache(config, batch, max_len,
-                                    stats=_stats_len(config))
+                                    stats=stats_groups(config))
 
 
 #: the routed experts' matrices: never an ``xs`` of the layer scan (a slice
@@ -67,7 +71,7 @@ def init_cache(config: LatentMoEConfig, batch: int, max_len: int,
 _ROUTED = ("w_gu", "w_down")
 
 
-def _step(params: PyTree, config: LatentMoEConfig):
+def _step(params: PyTree, config: LatentMoEConfig, valid=None):
     first = config.first_k_dense
     moe = params["moe_blocks"]
     routed = {k: moe[k] for k in _ROUTED}
@@ -134,13 +138,15 @@ FAMILY = gpt_inference.Family(
 
 
 def prefill(params: PyTree, tokens, config: LatentMoEConfig,
-            cache: KVCache) -> Tuple[jnp.ndarray, KVCache]:
+            cache: KVCache, valid=None) -> Tuple[jnp.ndarray, KVCache]:
+    # ``valid`` (here and in ``extend``): the families' common signature;
+    # no per-slot state here reads it
     return gpt_inference.prefill(params, tokens, config, cache,
                                  family=FAMILY)
 
 
 def extend(params: PyTree, tokens, config: LatentMoEConfig, cache: KVCache,
-           lengths=None) -> Tuple[jnp.ndarray, KVCache]:
+           lengths=None, valid=None) -> Tuple[jnp.ndarray, KVCache]:
     return gpt_inference.extend(params, tokens, config, cache,
                                 lengths=lengths, family=FAMILY)
 

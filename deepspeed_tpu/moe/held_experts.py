@@ -8,10 +8,12 @@ of the result.  What the absent experts would add is another chip's to add:
 on one chip the layer runs without its exchange, and nothing here stands in
 for the chips that are not there.
 
-The router is the published ``noaux_tc`` gate with one group: sigmoid scores
-over all experts **in float32**, the ``k`` largest of ``score + bias`` (the
-bias moves the selection and never the weight), the selected scores
-normalised to sum to one and scaled.
+Two gates return the same ``Routing``.  ``route`` is the published
+``noaux_tc`` gate with one group: sigmoid scores over all experts **in
+float32**, the ``k`` largest of ``score + bias`` (the bias moves the
+selection and never the weight), the selected scores normalised to sum to
+one and scaled.  ``route_softmax`` takes the ``k`` largest router logits and
+weighs them by a softmax over those ``k`` alone.
 
 The held pairs are multiplied grouped and dropless.  Pairs are sorted by the
 local index of their expert, held ones first, and the rows of each held
@@ -35,9 +37,28 @@ from jax import lax
 
 from ..ops.pallas.utils import interpret_mode, use_pallas
 
-#: the grouped matmul's (rows, contraction, columns) tile on the chip: 2 MB
-#: of an expert's matrix a step, streamed behind the product before it
+#: the grouped matmul's (rows, contraction, columns) tile on the chip at its
+#: largest: 2 MB of an expert's matrix a step, streamed behind the product
+#: before it
 GMM_TILING = (128, 1024, 1024)
+
+
+def gmm_tiling(k: int, n: int) -> Tuple[int, int, int]:
+    """The grouped matmul's tile for matrices ``[k, n]``: ``GMM_TILING``
+    where its sides divide them, else the largest whole number of lane rows
+    that does (768 for a side of 768 or 1536): a tile that overhangs a
+    matrix's edge is masked every step.  Where the column tile comes out
+    narrower than ``GMM_TILING``'s, VMEM has room for the WHOLE contraction
+    side (a block of at most 6 MiB, double-buffered): an expert whose rows
+    straddle two row tiles then asks for the same block twice in a row and
+    it is fetched once (measured at 4096 x 1536, PERF.md 6, PR 35: 0.57 ->
+    0.44 ms with 71 rows an expert, 0.44 -> 0.38 with 18)."""
+    def side(dim, cap):
+        return next((t for t in range(cap, 0, -128) if dim % t == 0), cap)
+    tm, tk, tn = GMM_TILING[0], side(k, GMM_TILING[1]), side(n, GMM_TILING[2])
+    if tn < GMM_TILING[2] and k * tn * 2 <= 6 << 20:
+        tk = k
+    return tm, tk, tn
 
 
 class Routing(NamedTuple):
@@ -59,6 +80,16 @@ def route(h, w_router, bias, k: int, scale: float,
     if normalize:
         weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
     return Routing(experts.astype(jnp.int32), weights * scale)
+
+
+def route_softmax(h, w_router, k: int) -> Routing:
+    """The softmax-of-top-k gate: logits ``W_r h`` in float32 at full
+    precision over all experts, the ``k`` largest chosen, the weights a
+    softmax over the chosen logits alone (no bias, no scale)."""
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    top, experts = lax.top_k(logits, k)
+    return Routing(experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1))
 
 
 def local_slots(held: Sequence[int], n_experts: int) -> np.ndarray:
@@ -87,10 +118,22 @@ def _grouped(rows, w, group_sizes, layer=None):
                 (layer * n_groups,))
             w = w.reshape((-1,) + w.shape[2:])
         return gmm(rows, w, group_sizes, preferred_element_type=rows.dtype,
-                   tiling=GMM_TILING)
+                   tiling=gmm_tiling(*w.shape[-2:]))
     if layer is not None:
         w = lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
     return lax.ragged_dot(rows, w.astype(rows.dtype), group_sizes)
+
+
+def pair_counts(per_expert, routed: int):
+    """An expert layer call's counters ``[3 + n_held]`` int32 from the pairs
+    each held expert took: pairs held here, pairs routed in all, held
+    experts that took at least one pair (each streams its matrices once),
+    pairs per held expert."""
+    return jnp.concatenate([
+        jnp.sum(per_expert, keepdims=True),
+        jnp.full((1,), routed, jnp.int32),
+        jnp.sum(per_expert > 0, keepdims=True, dtype=jnp.int32),
+        per_expert])
 
 
 def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],

@@ -33,6 +33,13 @@ is read once for all heads, 121 operations a byte at 64 heads, so it is the
 one kernel here that sits near the ridge and not far under it.
 ``cached_attention`` picks them when the cache has one bank.
 
+Grouped heads (``kv_heads`` of ``cached_attention``): ``H`` query heads on
+``H / G`` key-value heads.  The row stays ``H/G * D``; the decode kernel
+(``_gqa_decode``) takes the block with all its key-value heads once and the
+``G`` query heads of each share it, the chunk kernel maps query head ``bh``
+to the blocks of key-value head ``bh // G``.  Nothing repeats a key-value
+head out to its query heads.
+
 Int8 cache variant (beyond the reference): k/v arrive as int8 codes with
 per-vector fp32 scales and are dequantized IN VMEM after the block load,
 so the HBM stream — the decode bottleneck — ships half the bytes.  Decode
@@ -459,7 +466,10 @@ def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
 
 
 def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
-           vs3=None, window=None, slopes=None):
+           vs3=None, window=None, slopes=None, group: int = 1):
+    """``group`` query heads share a key-value head (grouped heads): ``q3``
+    is ``[B*H, Sq, D]``, ``k3``/``v3`` ``[B*H/group, Smax, D]`` and query
+    head ``bh`` streams the blocks of key-value head ``bh // group``."""
     BH, Sq, D = q3.shape
     Smax = k3.shape[1]
     B = BH // H
@@ -479,7 +489,7 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
             (p + qi * block_q - maybe_win[0][0] + 1) // block_k, 0) \
             if windowed else 0
         hi = (p + (qi + 1) * block_q - 1) // block_k
-        return (bh, jnp.clip(ki, lo, hi), 0)
+        return (bh // group, jnp.clip(ki, lo, hi), 0)
 
     kv_spec = pl.BlockSpec((1, block_k, D), kv_idx)
     scale_spec = pl.BlockSpec((1, block_k, 1), kv_idx)
@@ -512,6 +522,93 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
                                                          q3.dtype),
                           interpret=interpret_mode(),
                           name="chunk_attention")(*args)
+
+
+# ----------------------------------------------------------- grouped heads
+
+def _gqa_decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref,
+                       q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                       sm_scale, block_k, H, G, D):
+    """``_decode_kernel`` for grouped heads: ``H`` query heads on ``H // G``
+    key-value heads, the row the pool keeps still ``(H // G) * D`` wide.  A
+    grid step is one live block of one live row with ALL its key-value
+    heads; ``q_ref`` is the row's ``(H, D)`` queries, spread to ``(H, H/G *
+    D)`` with head ``h``'s lanes under key-value head ``h // G``, so the
+    ``G`` query heads of a key-value head share the block in VMEM and two
+    plain matmuls score and weigh every head at once.  Head ``h``'s result
+    is row ``h``'s lanes under its key-value head."""
+    step, row, live, first, last = _sweep_position(rows_ref, n_ref)
+    ki = blocks_ref[step]
+    pos = pos_ref[row]
+    W = (H // G) * D
+
+    @pl.when(jnp.logical_and(live, first))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(live)
+    def _update():
+        q = q_ref[...]                                     # (H, D)
+        own = jax.lax.broadcasted_iota(jnp.int32, (H, W), 1) // D == \
+            jax.lax.broadcasted_iota(jnp.int32, (H, W), 0) // G
+        qx = jnp.where(own, jnp.concatenate([q] * (H // G), axis=1)
+                       .astype(jnp.float32), 0.0).astype(q.dtype)
+        s = jax.lax.dot_general(qx, k_ref[...], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale                                   # (H, BK)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _online_softmax_step(jnp.where(k_pos <= pos, s, NEG_INF), v_ref[...],
+                             acc_ref, m_ref, l_ref)
+
+    @pl.when(jnp.logical_and(live, last))
+    def _finalize():
+        o = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)    # (H, W)
+        for h in range(H):
+            kv = h // G
+            o_ref[:, h * D:(h + 1) * D] = o[h:h + 1, kv * D:(kv + 1) * D]
+
+
+def _gqa_decode(q, k, v, layer, pos, sweep, sm_scale, block_k, G):
+    """The grouped-head decode sweep: ``q`` [B, H, D] against layer
+    ``layer`` of the pool ``k``/``v`` [L, B, Smax, H/G * D] where it lies;
+    [B, 1, H*D].  The work list, the dynamic grid and the index maps are
+    ``_decode``'s."""
+    B, H, D = q.shape
+    W = k.shape[-1]
+    rows, blocks, n = sweep
+    kernel = functools.partial(_gqa_decode_kernel, sm_scale=sm_scale,
+                               block_k=block_k, H=H, G=G, D=D)
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    prefetch = (rows, blocks, n, pos_arr,
+                jnp.asarray(layer, jnp.int32).reshape(1))
+    interpret = interpret_mode()
+
+    def kv_idx(s, rows_ref, blocks_ref, n_ref, pos_ref, layer_ref):
+        return (layer_ref[0], rows_ref[s], blocks_ref[s], 0)
+
+    kv_spec = pl.BlockSpec((None, None, block_k, W), kv_idx)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(rows.shape[0] if interpret else jnp.maximum(n[0], 1),),
+        in_specs=[
+            pl.BlockSpec((None, H, D),
+                         lambda s, rows_ref, *_: (rows_ref[s], 0, 0)),
+            kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((None, 1, H * D),
+                               lambda s, rows_ref, *_: (rows_ref[s], 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, W), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, H * D), q.dtype),
+        interpret=interpret, name="gqa_decode_attention")(
+            *prefetch, q, k, v)
 
 
 # ------------------------------------------------------------ latent rows
@@ -726,8 +823,15 @@ def cached_attention(q, cache_k, cache_v, pos,
                      sm_scale: Optional[float] = None,
                      k_scale=None, v_scale=None,
                      window=None, slopes=None, layer=None,
-                     active=None, sweep=None, latent_rank=None):
+                     active=None, sweep=None, latent_rank=None,
+                     kv_heads: Optional[int] = None):
     """q [B,Sq,H,D] over a padded cache [B,Smax,H,D], visibility ≤ pos+i.
+
+    ``kv_heads`` (default ``H``): grouped heads.  The cache holds
+    ``kv_heads`` key-value heads a token (its row is ``kv_heads * D``) and
+    query head ``h`` reads key-value head ``h // (H / kv_heads)``; the row
+    is never repeated out to ``H`` heads.  Full-precision cache, no window,
+    no ALiBi; the lane-aligned kernels want ``D`` a multiple of 128.
 
     A cache of ONE bank (``cache_v`` None) is a latent pool: ``q`` holds the
     absorbed queries, ``latent_rank`` says how much of a row the
@@ -776,7 +880,12 @@ def cached_attention(q, cache_k, cache_v, pos,
                                        latent_rank, layer=layer,
                                        active=active, sweep=sweep)
     B, Sq, H, D = q.shape
+    Hkv = H if kv_heads is None else int(kv_heads)
+    G = H // Hkv
     int8_cache = k_scale is not None
+    if G > 1 and (int8_cache or window is not None or slopes is not None):
+        raise NotImplementedError(
+            "grouped heads: full-precision cache, no window, no ALiBi")
     banks = (cache_k, cache_v) + ((k_scale, v_scale) if int8_cache else ())
     if layer is None:
         # [B,Smax,H,*] → a pool of one layer, heads folded into the row
@@ -784,7 +893,7 @@ def cached_attention(q, cache_k, cache_v, pos,
         layer = 0
     Smax = banks[0].shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    block_k = decode_block_k(Smax, H * D)
+    block_k = decode_block_k(Smax, Hkv * D)
     # chunk path: pos may be scalar OR per-row [B] (ragged chunks — the
     # kernel reads its row's frontier from pos_ref[bh // H] everywhere:
     # mask, live range, and DMA clamp); the chunk must tile in the q
@@ -803,27 +912,35 @@ def cached_attention(q, cache_k, cache_v, pos,
         ks, vs = banks[2:] if int8_cache else (None, None)
         if sweep is None:
             sweep = decode_sweep(pos, B, Smax, block_k, active, window)
-        o = _decode(q.reshape(B, 1, H * D), banks[0], banks[1], layer, pos,
-                    sweep, scale, block_k, H, ks=ks, vs=vs, window=window,
-                    slopes=slopes)
-        return dead_rows_zero(o.reshape(B, 1, H, D))
+        if G > 1 and D % 128 == 0:
+            o = _gqa_decode(q[:, 0], banks[0], banks[1], layer, pos, sweep,
+                            scale, block_k, G)
+            return dead_rows_zero(o.reshape(B, 1, H, D))
+        if G == 1:
+            o = _decode(q.reshape(B, 1, H * D), banks[0], banks[1], layer,
+                        pos, sweep, scale, block_k, H, ks=ks, vs=vs,
+                        window=window, slopes=slopes)
+            return dead_rows_zero(o.reshape(B, 1, H, D))
 
-    # one layer, heads unfolded: [B,Smax,H,D] (scales [B,Smax,H,1])
+    # one layer, heads unfolded: [B,Smax,Hkv,D] (scales [B,Smax,H,1])
     banks = [jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
-             .reshape(B, Smax, H, -1) for x in banks]
+             .reshape(B, Smax, Hkv, -1) for x in banks]
     if use_pallas() and block_k is not None and block_q is not None:
         def to3(x):
-            return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], -1)
+            return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2],
+                                                   x.shape[1], -1)
 
         ks3, vs3 = map(to3, banks[2:]) if int8_cache else (None, None)
         o3 = _chunk(to3(q), to3(banks[0]), to3(banks[1]), pos, scale,
                     block_q, block_k, H, ks3=ks3, vs3=vs3,
-                    window=window, slopes=slopes)
+                    window=window, slopes=slopes, group=G)
         return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
     if int8_cache:
         banks = [dequantize_kv(banks[0], banks[2], q.dtype),
                  dequantize_kv(banks[1], banks[3], q.dtype)]
+    if G > 1:       # the dense formula reads a key-value head per query head
+        banks = [jnp.repeat(x, G, axis=2) for x in banks]
     o = cached_attention_reference(q, banks[0], banks[1], pos, scale,
                                    window=window, slopes=slopes)
     return dead_rows_zero(o) if Sq == 1 else o

@@ -107,6 +107,12 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         C = tokens.shape[1]
         row, start, n = meta[0], meta[1], meta[2]
 
+        def real(i):
+            # where the prompt ends inside chunk ``i``: a family that keeps
+            # state per slot must not let a recurrence take the padding (the
+            # banks take it either way: it lies past the frontier)
+            return jnp.clip(n - i * C, 0, C)[None]
+
         def take(lg, i):
             # the last real token's logits if chunk ``i`` holds it (the
             # last chunk does; an earlier chunk's row is junk that the next
@@ -118,13 +124,14 @@ def admission(fam, cfg, max_len: int, kv_dtype):
             pos = start + i * C
             lg, cache = fam.extend(
                 params, lax.dynamic_index_in_dim(tokens, i, 0), cfg,
-                carry[1], lengths=pos[None])
+                carry[1], lengths=pos[None], valid=real(i))
             return take(lg, i), cache
 
         if prefix is None:
             lg, cache = fam.prefill(
                 params, tokens[:1], cfg,
-                fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype))
+                fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype),
+                valid=real(0))
             first, carry = 1, (take(lg, 0), cache)
         else:
             first, carry = 0, (
@@ -178,10 +185,8 @@ class SlotBatcher:
         for feature, on in (
                 ("speculative", config.speculative_config.enabled),
                 ("paging", config.paging_config.enabled)):
-            why = getattr(fam, "UNSUPPORTED", {}).get(feature)
-            if on and why:
-                raise NotImplementedError(
-                    f"serving.{feature} with {type(cfg).__name__}: {why}")
+            if on:
+                self.refuse(feature)
         self.cache = fam.init_cache(cfg, B, self.max_len,
                                     kv_dtype=self._kv_dtype)
         #: bytes of the batch-1 cache every fresh prefill allocates: the
@@ -196,6 +201,12 @@ class SlotBatcher:
         #: (``KVCache.stats``), summed over the ticks pulled so far; None
         #: for a family that counts nothing
         self.device_counts = None
+        #: where each group of counters lies in them: the family's layout
+        #: (``stats_groups`` of its module), read by name through ``counts``
+        self._stats_groups = getattr(fam, "stats_groups", lambda cfg: {})(cfg)
+        #: the names of the group ``state_steps``, which a family with
+        #: per-slot state has (``STATE_COUNTERS`` of its module)
+        self.state_counters = tuple(getattr(fam, "STATE_COUNTERS", ()))
         self.lengths = jnp.zeros((B,), jnp.int32)
         self.keys = jnp.stack([jax.random.PRNGKey(0)] * B)
         self.greedy = jnp.ones((B,), bool)
@@ -241,6 +252,29 @@ class SlotBatcher:
         #: (gateway CompileWatch, compile_report.py) watches this
         self.registry = CompiledProgramRegistry("serving")
         self._build_programs(config)
+
+    def counts(self, group: str):
+        """One group of the family's cumulative device counters
+        (``moe_pairs``: the expert layers' ``pair_counts``; ``state_steps``:
+        the ``state_counters``), or None where the family has no such group
+        or no tick has been pulled yet."""
+        where = self._stats_groups.get(group)
+        if where is None or self.device_counts is None:
+            return None
+        return self.device_counts[where]
+
+    def unsupported(self, feature: str) -> Optional[str]:
+        """Why the family does not serve ``feature`` (``UNSUPPORTED`` of its
+        module), or None if it does."""
+        return getattr(self._fam, "UNSUPPORTED", {}).get(feature)
+
+    def refuse(self, feature: str) -> None:
+        """Raise if the family does not serve ``feature``, with its
+        reason."""
+        why = self.unsupported(feature)
+        if why:
+            raise NotImplementedError(
+                f"serving.{feature} with {type(self._cfg).__name__}: {why}")
 
     def _init_draft(self, config: ServingConfig, draft) -> None:
         """Resolve the draft model: an engine / ``(cfg, params)`` tuple
@@ -568,7 +602,7 @@ class SlotBatcher:
             # and the prefix's continuation
             prompt = np.zeros((min(C + 2, self.max_len),), np.int32)
             self.admit(0, prompt, key, True, 1.0)
-            if prompt.shape[0] > 1:
+            if prompt.shape[0] > 1 and not self.unsupported("prefix"):
                 self.admit(0, prompt, key, True, 1.0,
                            prefix=self.build_prefix(prompt[:-1]))
 
@@ -645,6 +679,7 @@ class SlotBatcher:
 
     def build_prefix(self, tokens: np.ndarray) -> PrefixEntry:
         """Prefill a shared prefix once; forks ride it zero-copy."""
+        self.refuse("prefix")
         cache, frontier = self._chunked_prefill(np.asarray(tokens))
         return PrefixEntry(cache=cache, length=frontier)
 

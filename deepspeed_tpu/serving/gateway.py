@@ -230,6 +230,8 @@ class ServingGateway:
             raise ValueError(
                 f"prefix_len {prefix_len} must be in [0, prompt_len"
                 f"={tokens.shape[0]})")
+        if prefix_len and cfg.max_cached_prefixes > 0:
+            self._batcher.refuse("prefix")
         handle = RequestHandle(rid)
         # every request is a trace root: workers stitch their spans to it
         ctx = mint_context()
@@ -933,7 +935,9 @@ class ServingGateway:
                                  tokens=harvested, kv_blocks=kv_blocks,
                                  overlapped=tick.overlapped,
                                  late_rows=len(late))
-        moe = self._batcher.device_counts
+        # the family's device counters, each group by its name (the family
+        # owns where a group lies; a family has either, both or neither)
+        moe = self._batcher.counts("moe_pairs")
         if moe is not None:
             self.metrics.record_moe_pairs(moe)
             if self.tracer.enabled:
@@ -941,6 +945,14 @@ class ServingGateway:
                     SpanName.SERVE_MOE_PAIRS, now, 0.0, held=int(moe[0]),
                     routed=int(moe[1]), visits=int(moe[2]),
                     per_expert=[int(c) for c in moe[3:]])
+        state = self._batcher.counts("state_steps")
+        if state is not None:
+            state = {k: int(c) for k, c in
+                     zip(self._batcher.state_counters, state)}
+            self.metrics.record_state_steps(state)
+            if self.tracer.enabled:
+                self.tracer.record(SpanName.SERVE_STATE_STEPS, now, 0.0,
+                                   **state)
         round_k = tick.draft_k
         n_fed = n_live - len(late)
         if counts is not None and n_fed:

@@ -96,6 +96,10 @@ class ServingMetrics:
         #: streams that expert's matrices once
         self.moe_expert_visits = 0
         self.moe_expert_pairs: list = []
+        #: a per-slot-state family's device counters, by name (state rows
+        #: stepped, real and padded tokens through the chunk scan):
+        #: cumulative, as of the last harvested tick
+        self.state_steps: dict = {}
         #: time-to-first-token, seconds — the shared telemetry histogram
         #: (count/sum exact, reservoir bounded at :data:`_TTFT_CAP`)
         self.ttft = Histogram(MetricName.SERVE_TTFT_S, cap=_TTFT_CAP)
@@ -159,13 +163,19 @@ class ServingMetrics:
         self.spec_tokens_per_tick.observe(float(emitted))
 
     def record_moe_pairs(self, counts) -> None:
-        """``counts``: the batcher's cumulative ``device_counts``, ``[held,
+        """``counts``: the batcher's cumulative group ``moe_pairs``, ``[held,
         routed, visits, pairs of each held expert...]``."""
         with self._lock:
             self.moe_pairs_held = int(counts[0])
             self.moe_pairs_routed = int(counts[1])
             self.moe_expert_visits = int(counts[2])
             self.moe_expert_pairs = [int(c) for c in counts[3:]]
+
+    def record_state_steps(self, counts: dict) -> None:
+        """``counts``: name -> cumulative count, the batcher's group
+        ``state_steps`` under the family's ``STATE_COUNTERS``."""
+        with self._lock:
+            self.state_steps = dict(counts)
 
     def record_ttft(self, seconds: float) -> None:
         self.ttft.observe(float(seconds))
@@ -244,6 +254,7 @@ class ServingMetrics:
                 "moe_pairs_routed": self.moe_pairs_routed,
                 "moe_expert_visits": self.moe_expert_visits,
                 "moe_expert_pairs": list(self.moe_expert_pairs),
+                "state_steps": dict(self.state_steps),
                 # the busiest held expert's pairs over the mean's
                 "moe_expert_load_max_over_mean": (
                     max(self.moe_expert_pairs) * len(self.moe_expert_pairs)

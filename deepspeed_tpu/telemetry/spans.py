@@ -142,6 +142,12 @@ class SpanName:
     #: since the server started (recorded, zero length; held, routed,
     #: visits and per_expert in args): counted on the device, pulled with the tokens
     SERVE_MOE_PAIRS = "serve.moe_pairs"
+    #: a per-slot-state family's step counts as of one harvested tick,
+    #: cumulative (recorded, zero length; in args ssm_rows_stepped: live
+    #: slot x state-space layer of the ticks; scan_tokens_real and
+    #: scan_tokens_padded: token x state-space layer of the admissions'
+    #: chunk scans): counted on the device, pulled with the tokens
+    SERVE_STATE_STEPS = "serve.state_steps"
     #: end of admission -> the tick that harvested the request's first
     #: token (recorded; rid in args)
     SERVE_FIRST_TOKEN = "serve.first_token"

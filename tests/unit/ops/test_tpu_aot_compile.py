@@ -21,9 +21,10 @@ _KERNEL_MODULES = [
     for name in ("flash_attention", "decode_attention", "fused_bias_gelu",
                  "quantizer")]
 flash, decode, gelu, quantizer = _KERNEL_MODULES
-# the expert layer's grouped matmul asks the same two questions
-_KERNEL_MODULES.append(importlib.import_module(
-    "deepspeed_tpu.moe.held_experts"))
+# the expert layer's grouped matmul and the state-space kernels ask the
+# same two questions
+_KERNEL_MODULES += [importlib.import_module("deepspeed_tpu.moe.held_experts"),
+                    importlib.import_module("deepspeed_tpu.ops.pallas.ssm")]
 
 BF16 = jnp.bfloat16
 # GPT-2 350M: 16 heads of 64; training micro-batch rows x 1024 tokens,
@@ -199,8 +200,12 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
 
 _SERVED = pytest.mark.parametrize("family,int8", [
     ("dense", False), ("dense", True), ("moe", False), ("moe", True),
-    ("latent", False)],
-    ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent"])
+    ("latent", False), ("hybrid", False)],
+    ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent",
+         "bf16-hybrid"])
+
+#: scans of a tick: one a segment of the family's step
+_SEGMENTS = {"latent": 2, "hybrid": 3}
 
 
 def _served(family):
@@ -212,7 +217,12 @@ def _served(family):
     heads over one 576-element row stored as 640 lanes, 7168 wide; one
     dense and two expert layers, 4 of 384 experts held): a layer of the pool
     is then larger than any one matrix, so "as large as a layer" still
-    means the pool."""
+    means the pool; the hybrid state-space family at its own cell's 128 x
+    5,120 in chunks of 512, its published widths and its whole period of
+    ten layers (5 state-space, 1 attention, 4 state-space; 18 of 72 experts
+    held, a quarter of the vocabulary): 4.9 GB of float32 state a slot pool
+    keeps beside 2.7 GB of grouped KV, which the compiler must not copy
+    even once."""
     import dataclasses
 
     from deepspeed_tpu.models import gpt, gpt_moe
@@ -224,6 +234,18 @@ def _served(family):
             **{f.name: getattr(cfg, f.name)
                for f in dataclasses.fields(cfg)}, num_experts=4), 64, SMAX, \
             CHUNK
+    if family == "hybrid":
+        from deepspeed_tpu.models import hybrid_ssm_moe
+        return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
+            vocab_size=25088, max_seq_len=131072,
+            layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+            d_model=4096, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+            conv_kernel=4, ssm_chunk=256, n_head=32, n_kv_head=8,
+            head_dim=128, attn_scale=1 / 128, n_experts=72,
+            experts_per_token=10, d_expert=768, d_shared=1536,
+            held_experts=tuple(range(18)), embedding_multiplier=12.0,
+            residual_multiplier=0.22, logits_scaling=16.0, dtype=BF16,
+            param_dtype=BF16), 128, 5120, 512
     from deepspeed_tpu.models import latent_moe
     smax = 8192
     return latent_moe, latent_moe.LatentMoEConfig(
@@ -245,6 +267,16 @@ def _described(tree, sharding):
 def _pool_bytes(cache):
     return sum(x.size * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(cache) if x.ndim >= 4)
+
+
+def _layer_elements(cfg, cache, slots, smax):
+    """Elements of one layer of the pool's smallest large stack: a bank's,
+    or the per-slot state's where the family keeps one."""
+    from deepspeed_tpu.models.gpt_inference import cache_row
+    layers = [slots * smax * cache_row(cfg)[0]]
+    if cache.state is not None:
+        layers.append(cache.state[0].size // cache.state[0].shape[0])
+    return min(layers)
 
 
 @_SERVED
@@ -275,12 +307,11 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
         donate_argnums=(1,))
     _sweep_is_built_outside_the_layer_scan(
         tick.trace(params, cache, rows, rows, live).jaxpr, slots,
-        segments=2 if family == "latent" else 1)
+        segments=_SEGMENTS.get(family, 1))
     compiled = tick.lower(params, cache, rows, rows, live).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the tick"
-    from deepspeed_tpu.models.gpt_inference import cache_row
-    layer_k = slots * smax * cache_row(cfg)[0]
+    layer_k = _layer_elements(cfg, cache, slots, smax)
     moved = [(n, op) for n, op in _root_opcodes(text)
              if n >= layer_k and (op.startswith("copy") or op in (
                  "transpose", "dynamic-slice", "dynamic-update-slice"))]
@@ -327,7 +358,6 @@ def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
     every layer), and the plan is what a chunk's ``extend`` on the batch-1
     row and the pool hold between them today."""
     from deepspeed_tpu.models import cache_family
-    from deepspeed_tpu.models.gpt_inference import cache_row
     from deepspeed_tpu.serving.batcher import admission
     model, cfg, slots, smax, chunk = _served(family)
     fam = cache_family(cfg)
@@ -363,7 +393,7 @@ def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the admission"
     assert " while(" in text, "no loop over the chunks"
-    moved = _pool_sized_moves(text, slots * smax * cache_row(cfg)[0])
+    moved = _pool_sized_moves(text, _layer_elements(cfg, pool, slots, smax))
     assert not moved, f"the admission moves whole layers of the pool: {moved}"
     assert "input_output_alias" in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
