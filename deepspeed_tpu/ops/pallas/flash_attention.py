@@ -16,6 +16,9 @@ lives in VMEM scratch that persists across grid steps on the same core.
 
 Causal masking is end-aligned (a query attends to the last ``Sq`` positions
 of ``Sk``), matching :func:`mha_reference` for cross-length decode shapes.
+Blocks wholly above the diagonal are skipped; a block ON the diagonal is
+walked in causal row strips that leave out the sub-tiles above it
+(:func:`_causal_tile`, :func:`causal_tile_plan`), forward and backward.
 
 Layout: [B, S, H, D] (the model's native layout; [B*H, S, D] internally).
 Backward is the standard two-kernel flash backward (dq sweep and dk/dv
@@ -24,6 +27,7 @@ sweep) off saved (O, logsumexp).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -128,12 +132,151 @@ def _band_block_visible(qi, ki, block_q, block_k, offset, window):
     return qi * block_q + offset - ((ki + 1) * block_k - 1) < window
 
 
+def _scores(q, ks, sm_scale):
+    """Scaled float32 scores of query rows against key rows.  MXU operands
+    stay in the input dtype (bf16 in production) with f32 accumulation — an
+    fp32 cast before the dot would run the systolic array at a fraction of
+    its bf16 rate."""
+    return jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * sm_scale
+
+
+def _no_mask(s):
+    return s
+
+
+# ------------------------------------------------------ causal sub-tile plan
+
+#: side of the sub-tile :func:`causal_tile_plan` counts in: the lane width,
+#: the smallest strip height :func:`_causal_tile` hands out
+SUB_TILE = 128
+
+
+def _causal_tile(Sq, Sk, block_q, block_k, causal, use_lens=False,
+                 use_window=False, backward=False) -> int:
+    """Height of the causal row strips a kernel walks a DIAGONAL block in, or
+    0 where it runs the whole-block body: the one place that decides whether
+    the path engages (the kernels and :func:`causal_tile_plan` both ask it).
+
+    It engages on what is static at the call: pure causal attention over
+    equal lengths (so ``offset == 0``), square blocks, no ``kv_lens``, no
+    ``window``.  There the only visible blocks that cross the mask are the
+    ``qi == ki`` ones and the block's own offsets cancel, so every slice is
+    static: strip ``i`` is query rows ``[i T, (i+1) T)`` against keys
+    ``[0, (i+1) T)`` of the resident block, ``n (n+1) / 2`` of the ``n^2``
+    sub-tiles, ``n = block / T``.
+
+    ``T`` is derived from the block, as ``decode_attention.decode_block_k``
+    derives its block from the row; no knob.  Chosen from a sweep on a TPU
+    v5e (PR 37; a kernel's device time in a profiler trace, ms; bf16, D 64,
+    block 1024; whole-block body / T 128 / 256 / 512), at the train cells'
+    per-chip shapes, a head of one block and of 2 x 2:
+
+    ========  =============================  =============================
+    kernel    [24 x 16 heads, 1024, 64]      [8 x 32 heads, 2048, 64]
+    ========  =============================  =============================
+    forward   1.662 / 1.454 / 1.341 / 1.234  3.881 / 4.052 / 3.676 / 3.468
+    backward  2.963 / 2.184 / 2.155 / 2.408  6.493 / 5.374 / 5.340 / 5.655
+    ========  =============================  =============================
+
+    The backward is paced by the matrix unit (96% of its issue slots in the
+    whole-block body), so it gains what the strips leave out, less what
+    short strips lose in filling it: 256 at both shapes, the strips taken
+    bottom to top.  The forward is paced by vector stores and latency and
+    a strip's fixed costs show, so tall strips: 512 at both, top to bottom.
+    (Where a head is ONE block its grid indices are constants, the
+    compiler folds the causal mask and the whole-block body already skips
+    the vector work above the diagonal; what the strips save there is the
+    products and, since they finish their rows, the round trip of the
+    softmax state through scratch, without which 512 reads 1.862 and only
+    128 gains, 1.606.)  Other orders and shapes of the pieces were slower:
+    ``PERF.md`` 6, PR 37.
+    """
+    if not causal or use_lens or use_window or Sq != Sk \
+            or block_q != block_k:
+        return 0
+    tile = 256 if backward else 512
+    if block_q % tile or block_q // tile < 2:
+        return 0
+    return tile
+
+
+def _causal_strips(block, tile):
+    """The lower triangle of a diagonal block as ``(rows, cols)`` strips, top
+    to bottom: query rows ``[i tile, (i+1) tile)`` meet keys
+    ``[0, (i+1) tile)``."""
+    return [(slice(i * tile, (i + 1) * tile), slice(0, (i + 1) * tile))
+            for i in range(block // tile)]
+
+
+def _strip_mask(first_row):
+    """Causal mask of a strip of :func:`_causal_strips` whose first query
+    row is ``first_row`` of the block: rows and keys count from the block's
+    own start, which cancels on the diagonal, so local positions decide."""
+    def mask(s):
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        return jnp.where(col <= row + first_row, s, NEG_INF)
+    return mask
+
+
+def causal_tile_plan(Sq, Sk, block_q, block_k, causal, use_lens=False,
+                     use_window=False) -> tuple:
+    """``(visited, square)`` for one head: the ``SUB_TILE``-square sub-tiles
+    of the score matrix whose products the forward and the backward sweep
+    compute, and those of the whole blocks they computed before a diagonal
+    block was walked in causal strips.  Host arithmetic on the static facts
+    the kernels branch on (:func:`_causal_tile`).  Where the path does not
+    engage ``visited == square``: the blocks the static causal skip leaves
+    (``kv_lens`` and ``window`` skip more at run time, which no static count
+    sees)."""
+    nq, nk = Sq // block_q, Sk // block_k
+    offset = Sk - Sq
+    blocks = sum(1 for qi in range(nq) for ki in range(nk)
+                 if not causal or _block_visible(qi, ki, block_q, block_k,
+                                                 offset))
+    per_block = pl.cdiv(block_q, SUB_TILE) * pl.cdiv(block_k, SUB_TILE)
+    square = 2 * blocks * per_block
+    visited = square
+    for backward in (False, True):
+        tile = _causal_tile(Sq, Sk, block_q, block_k, causal, use_lens,
+                            use_window, backward)
+        if tile:
+            # engaged: Sq == Sk in square blocks, so nq diagonal blocks
+            n, sub = block_q // tile, (tile // SUB_TILE) ** 2
+            visited -= nq * (n * (n - 1) // 2) * sub
+    return visited, square
+
+
+#: the [visited, square] sums opened by :func:`tally_causal_tiles`,
+#: innermost last
+_tallies = []
+
+
+@contextlib.contextmanager
+def tally_causal_tiles():
+    """Sums :func:`causal_tile_plan` over the kernel calls traced inside the
+    block (one head of each call site; the dense fallbacks count nothing)
+    into the ``[visited, square]`` it yields.  The train engine opens it
+    around the trace of its loss and records the two as counters."""
+    tally = [0, 0]
+    _tallies.append(tally)
+    try:
+        yield tally
+    finally:
+        _tallies.pop()
+
+
 # ------------------------------------------------------------------- forward
 
 def _fwd_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *,
                 sm_scale, causal, block_q, block_k, offset, use_lens,
-                use_window, H):
+                use_window, H, tile, carried):
+    """``tile``: height of a diagonal block's causal strips, 0 for the
+    whole-block body (:func:`_causal_tile`).  ``carried``: whether the
+    softmax state lives in scratch across grid steps; not where the strips
+    engage and a head is one block, whose strips finish their rows."""
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -141,11 +284,12 @@ def _fwd_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     kv_len = lens_ref[bh // H] if use_lens else 0
     window = win_ref[0] if use_window else 0
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    if carried:
+        @pl.when(ki == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
     run = _block_visible(qi, ki, block_q, block_k, offset) if causal else True
     if use_lens:
@@ -155,14 +299,10 @@ def _fwd_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             qi, ki, block_q, block_k, offset, window))
 
     def _update(masked: bool):
-        # MXU operands stay in the input dtype (bf16 in production) with
-        # f32 accumulation — an fp32 cast before the dot would run the
-        # systolic array at a fraction of its bf16 rate
         q = q_ref[0]                                       # (BQ, D)
         ks = k_ref[0]                                      # (BK, D)
         vs = v_ref[0]
-        s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
+        s = _scores(q, ks, sm_scale)
         if masked and causal:
             s = _causal_mask(s, qi, ki, block_q, block_k, offset)
         if masked and use_lens:
@@ -183,31 +323,69 @@ def _fwd_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p.astype(vs.dtype), vs, preferred_element_type=jnp.float32)
 
+    def _update_diagonal():
+        # the qi == ki block in causal row strips.  All of the block's keys
+        # are resident, so a strip takes its rows' max once: one softmax
+        # update a strip, carried in from the earlier ki blocks, and no
+        # rescale between strips.  A row's own key is always visible, so
+        # m_new is finite and l >= 1.
+        for rows, cols in _causal_strips(block_q, tile):
+            s = _strip_mask(rows.start)(
+                _scores(q_ref[0, rows, :], k_ref[0, cols, :], sm_scale))
+            m_new = jnp.max(s, axis=1, keepdims=True)
+            if not carried:
+                # the head's one block: a strip finishes its rows, and the
+                # output leaves from values, past the scratch state
+                p = jnp.exp(s - m_new)
+                l = jnp.sum(p, axis=1, keepdims=True)
+                acc = jnp.dot(p.astype(v_ref.dtype), v_ref[0, cols, :],
+                              preferred_element_type=jnp.float32)
+                o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+                lse_ref[0, 0, rows] = (m_new + jnp.log(l))[:, 0]
+                continue
+            m_prev = m_ref[rows, :]
+            m_new = jnp.maximum(m_prev, m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            m_ref[rows, :] = m_new
+            l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
+                p, axis=1, keepdims=True)
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + jnp.dot(
+                p.astype(v_ref.dtype), v_ref[0, cols, :],
+                preferred_element_type=jnp.float32)
+
     if causal or use_lens or use_window:
         crosses = _block_crosses_mask(qi, ki, block_q, block_k, offset,
                                       causal, use_lens, kv_len,
                                       use_window, window)
-        pl.when(jnp.logical_and(run, crosses))(lambda: _update(True))
+        # where the strips engage (_causal_tile) the one visible block that
+        # crosses the mask is the diagonal one
+        pl.when(jnp.logical_and(run, crosses))(
+            _update_diagonal if tile else lambda: _update(True))
         pl.when(jnp.logical_and(run, jnp.logical_not(crosses)))(
             lambda: _update(False))
     else:
         pl.when(run)(lambda: _update(False))
 
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = (m_ref[...] + jnp.log(l))[:, 0]
+    if carried:
+        @pl.when(ki == nk - 1)
+        def _finalize():
+            l = jnp.maximum(l_ref[...], 1e-30)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+            lse_ref[0, 0, :] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
 def _fwd(q3, k3, v3, lens, win, causal, sm_scale, block_q, block_k, H):
     BH, Sq, D = q3.shape
     Sk = k3.shape[1]
     offset = Sk - Sq
+    tile = _causal_tile(Sq, Sk, block_q, block_k, causal, lens is not None,
+                        win is not None)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_q=block_q, block_k=block_k, offset=offset,
                                use_lens=lens is not None,
-                               use_window=win is not None, H=H)
+                               use_window=win is not None, H=H, tile=tile,
+                               carried=not tile or Sk > block_k)
     lens_arr = jnp.asarray(lens if lens is not None else [0], jnp.int32)
     win_arr = jnp.asarray([win] if win is not None else [0],
                           jnp.int32).reshape(1)
@@ -244,9 +422,42 @@ def _fwd(q3, k3, v3, lens, win, causal, sm_scale, block_q, block_k, H):
 
 # ------------------------------------------------------------------ backward
 
+def _bwd_operands(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows,
+                  cols):
+    """Query ``rows`` and key ``cols`` of the resident blocks, as the
+    backward's operands (input dtype: see _scores) and row statistics."""
+    q = q_ref[0, rows, :]
+    ks = k_ref[0, cols, :]
+    vs = v_ref[0, cols, :]
+    do = do_ref[0, rows, :]
+    lse = lse_ref[0, 0, rows][:, None]
+    delta = delta_ref[0, 0, rows][:, None]
+    return q, ks, vs, do, lse, delta
+
+
+def _bwd_ds(p, do, vs, delta, sm_scale, dtype):
+    dp = jax.lax.dot_general(do, vs, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return (p * (dp - delta) * sm_scale).astype(dtype)
+
+
+def _block_mask(qi, ki, kv_len, window, *, causal, block_q, block_k, offset,
+                use_lens, use_window):
+    """The masks a whole crossing block takes, as one ``scores -> scores``."""
+    def mask(s):
+        if causal:
+            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
+        if use_lens:
+            s = _lens_mask(s, ki, block_k, kv_len)
+        if use_window:
+            s = _band_lower_mask(s, qi, ki, block_q, block_k, offset, window)
+        return s
+    return mask
+
+
 def _bwd_dq_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_acc, *, sm_scale, causal, block_q,
-                   block_k, offset, use_lens, use_window, H):
+                   block_k, offset, use_lens, use_window, H, tile):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -265,33 +476,30 @@ def _bwd_dq_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         run = jnp.logical_and(run, _band_block_visible(
             qi, ki, block_q, block_k, offset, window))
 
+    def _accumulate(rows, cols, mask):
+        q, ks, vs, do, lse, delta = _bwd_operands(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols)
+        p = jnp.exp(mask(_scores(q, ks, sm_scale)) - lse)
+        ds = _bwd_ds(p, do, vs, delta, sm_scale, ks.dtype)
+        dq_acc[rows, :] += jnp.dot(ds, ks, preferred_element_type=jnp.float32)
+
     def _update(masked: bool):
-        # input-dtype MXU operands, f32 accumulate (see _fwd_kernel note)
-        q = q_ref[0]                                       # (BQ, D)
-        ks = k_ref[0]                                      # (BK, D)
-        vs = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0, :][:, None]                    # (BQ, 1)
-        delta = delta_ref[0, 0, :][:, None]
-        s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if masked and causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        if masked and use_lens:
-            s = _lens_mask(s, ki, block_k, kv_len)
-        if masked and use_window:
-            s = _band_lower_mask(s, qi, ki, block_q, block_k, offset, window)
-        p = jnp.exp(s - lse)                               # (BQ, BK)
-        dp = jax.lax.dot_general(do, vs, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(ks.dtype)
-        dq_acc[...] += jnp.dot(ds, ks, preferred_element_type=jnp.float32)
+        _accumulate(slice(None), slice(None), _block_mask(
+            qi, ki, kv_len, window, causal=causal, block_q=block_q,
+            block_k=block_k, offset=offset, use_lens=use_lens,
+            use_window=use_window) if masked else _no_mask)
+
+    def _update_diagonal():
+        # p comes from the saved lse: the strips are independent
+        for rows, cols in reversed(_causal_strips(block_q, tile)):
+            _accumulate(rows, cols, _strip_mask(rows.start))
 
     if causal or use_lens or use_window:
         crosses = _block_crosses_mask(qi, ki, block_q, block_k, offset,
                                       causal, use_lens, kv_len,
                                       use_window, window)
-        pl.when(jnp.logical_and(run, crosses))(lambda: _update(True))
+        pl.when(jnp.logical_and(run, crosses))(
+            _update_diagonal if tile else lambda: _update(True))
         pl.when(jnp.logical_and(run, jnp.logical_not(crosses)))(
             lambda: _update(False))
     else:
@@ -305,7 +513,7 @@ def _bwd_dq_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, *rest, sm_scale, causal,
                     block_q, block_k, offset, use_lens, use_window, H,
-                    emit_dq):
+                    emit_dq, tile):
     """K-sweep backward kernel, two forms selected by the static
     ``emit_dq``:
 
@@ -341,34 +549,34 @@ def _bwd_dkv_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         run = jnp.logical_and(run, _band_block_visible(
             qi, ki, block_q, block_k, offset, window))
 
-    def _update(masked: bool):
-        # input-dtype MXU operands, f32 accumulate (see _fwd_kernel note)
-        q = q_ref[0]                                       # (BQ, D)
-        ks = k_ref[0]                                      # (BK, D)
-        vs = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0, :][:, None]
-        delta = delta_ref[0, 0, :][:, None]
-        s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if masked and causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        if masked and use_lens:
-            s = _lens_mask(s, ki, block_k, kv_len)
-        if masked and use_window:
-            s = _band_lower_mask(s, qi, ki, block_q, block_k, offset, window)
-        p = jnp.exp(s - lse)                               # (BQ, BK)
-        dv_acc[...] += jax.lax.dot_general(
+    def _accumulate(rows, cols, mask):
+        q, ks, vs, do, lse, delta = _bwd_operands(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols)
+        p = jnp.exp(mask(_scores(q, ks, sm_scale)) - lse)
+        dv_acc[cols, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, vs, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_acc[...] += jax.lax.dot_general(
+        ds = _bwd_ds(p, do, vs, delta, sm_scale, q.dtype)
+        dk_acc[cols, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         if emit_dq:
-            dqp_ref[0, 0] = jnp.dot(ds, ks,
-                                    preferred_element_type=jnp.float32)
+            # a strip holds all of its rows' keys in this block, so the
+            # partial dq block is still written once, strip by strip
+            dqp_ref[0, 0, rows, :] = jnp.dot(
+                ds, ks, preferred_element_type=jnp.float32)
+
+    def _update(masked: bool):
+        _accumulate(slice(None), slice(None), _block_mask(
+            qi, ki, kv_len, window, causal=causal, block_q=block_q,
+            block_k=block_k, offset=offset, use_lens=use_lens,
+            use_window=use_window) if masked else _no_mask)
+
+    def _update_diagonal():
+        # p comes from the saved lse: the strips are independent.  Bottom
+        # to top, so that the short strips' products fill the matrix unit
+        # behind the long ones (_causal_tile's sweep)
+        for rows, cols in reversed(_causal_strips(block_q, tile)):
+            _accumulate(rows, cols, _strip_mask(rows.start))
 
     def _idle():
         # every dq-partial block must be written (unwritten = garbage)
@@ -378,7 +586,8 @@ def _bwd_dkv_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         crosses = _block_crosses_mask(qi, ki, block_q, block_k, offset,
                                       causal, use_lens, kv_len,
                                       use_window, window)
-        pl.when(jnp.logical_and(run, crosses))(lambda: _update(True))
+        pl.when(jnp.logical_and(run, crosses))(
+            _update_diagonal if tile else lambda: _update(True))
         pl.when(jnp.logical_and(run, jnp.logical_not(crosses)))(
             lambda: _update(False))
         if emit_dq:
@@ -411,7 +620,10 @@ def _bwd(q3, k3, v3, o3, lse, do3, lens, win, causal, sm_scale, block_q,
                     axis=-1)[:, None, :]                   # (BH, 1, Sq)
     common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
                   block_k=block_k, offset=offset, use_lens=use_lens,
-                  use_window=win is not None, H=H)
+                  use_window=win is not None, H=H,
+                  tile=_causal_tile(Sq, Sk, block_q, block_k, causal,
+                                    use_lens, win is not None,
+                                    backward=True))
 
     nk = Sk // block_k
     if nk <= MAX_FUSED_BWD_NK:
@@ -634,6 +846,12 @@ def flash_attention(q, k, v, causal: bool = True,
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              kv_lens=kv_lens)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    if _tallies:
+        plan = causal_tile_plan(Sq, Sk, bq, bk, causal, kv_lens is not None,
+                                window is not None)
+        for tally in _tallies:
+            tally[0] += plan[0]
+            tally[1] += plan[1]
 
     def kernel(q, k, v, kv_lens, window):
         b, _, h, _ = q.shape    # this shard's rows and heads under a mesh

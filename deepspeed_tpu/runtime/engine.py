@@ -1041,7 +1041,18 @@ class DeepSpeedEngine:
 
     # ------------------------------------------------------------------ jitted programs
     def _build_steps(self) -> None:
-        loss_fn = self.module.loss_fn
+        from ..ops.pallas.flash_attention import tally_causal_tiles
+
+        def loss_fn(p, b):
+            # runs when a step program is traced, not when it runs: how far
+            # the flash kernels' causal strips engage in this model's step
+            with tally_causal_tiles() as tiles:
+                loss = self.module.loss_fn(p, b)
+            for name, n in zip((MetricName.FLASH_CAUSAL_TILES_VISITED,
+                                MetricName.FLASH_CAUSAL_TILES_SQUARE), tiles):
+                self.metrics.counter(name).inc(n)
+            return loss
+
         model_grad_fn = self.module.grad_fn
         gas = self.gradient_accumulation_steps()
         grad_div = 1 if self.module.meta.get("pipeline") else gas

@@ -64,6 +64,14 @@ class MetricName:
     STEPS = "train.steps"
     #: engine.skipped_steps (overflow-skipped) at sample time
     SKIPPED_STEPS = "train.skipped_steps"
+    #: score sub-tiles the flash kernels' forward and backward sweeps compute
+    #: (``flash_attention.causal_tile_plan``: one head of every kernel call
+    #: site in the step, counted when the step is traced)
+    FLASH_CAUSAL_TILES_VISITED = "flash.causal_tiles_visited"
+    #: the sub-tiles of the whole blocks those sweeps computed before a
+    #: diagonal block was walked in causal strips; visited / square is how
+    #: far the strips engage (1.0: not at all)
+    FLASH_CAUSAL_TILES_SQUARE = "flash.causal_tiles_square"
     #: host process resident set size, bytes (0 without psutil)
     HOST_RSS_BYTES = "mem.host_rss_bytes"
     #: sum of live jax device-buffer bytes (the HBM census)

@@ -111,6 +111,14 @@ def report(args) -> int:
             else None,
             "mfu": m.get("train.mfu"),
             "tokens_per_s": m.get("train.tokens_per_s"),
+            # how far the flash kernels' causal strips engage in the
+            # traced step (1.0: not at all; None: no flash kernel in it)
+            "flash_causal_tiles_visited": m.get("flash.causal_tiles_visited"),
+            "flash_causal_tiles_square": m.get("flash.causal_tiles_square"),
+            "flash_causal_tile_ratio": round(
+                m["flash.causal_tiles_visited"]
+                / m["flash.causal_tiles_square"], 4)
+            if m.get("flash.causal_tiles_square") else None,
             "host_rss_bytes": m.get("mem.host_rss_bytes"),
             "rollbacks": m.get("elastic.rollbacks"),
         }
@@ -163,7 +171,12 @@ def report(args) -> int:
             print(f"  {name}: {r['samples']} samples, last step "
                   f"{r['last_step']}, step p50 "
                   f"{p50 if p50 is None else round(p50, 4)}s, "
-                  f"mfu {r['mfu']}")
+                  f"mfu {r['mfu']}" + (
+                      ", flash causal tiles visited / square "
+                      f"{r['flash_causal_tiles_visited']} / "
+                      f"{r['flash_causal_tiles_square']} = "
+                      f"{r['flash_causal_tile_ratio']}"
+                      if r["flash_causal_tile_ratio"] else ""))
             if "serving" in r:
                 print("    serving: " + ", ".join(
                     f"{field} {round(value, 4)}"

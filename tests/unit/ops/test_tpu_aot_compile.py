@@ -97,6 +97,49 @@ def test_flash_forward_backward(v5e, heads, head_dim):
                           *_qkv(v5e, B, S, heads, head_dim))
 
 
+# The train cells' per-chip attention (PERF.md 4): gpt2m-train-s1024 is 24
+# rows x 16 heads of one 1024-block, opt1b3-train-zero3-4chip 8 rows x 32
+# heads of 2 x 2.  MiB of scoped VMEM under which PR 37's PARENT compiled
+# (forward, gradient; found in 2 MiB steps): the causal strips of a diagonal
+# block must plan no more (they need 4 and 6 MiB at one block a head; the
+# unchanged below-diagonal body still sets the plan at 2 x 2).
+_TRAIN_CELLS = {"gpt2m-train-s1024": ((24, 1024, 16, 64), (8, 10)),
+                "opt1b3-train-zero3-4chip": ((8, 2048, 32, 64), (10, 12))}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("cell", list(_TRAIN_CELLS))
+def test_flash_at_the_train_cells_shapes(v5e, monkeypatch, cell, grad):
+    """The custom calls keep the names and result shapes by which the
+    benchmark finds and counts them (``readers/flash_roofline.py``,
+    ``flops.flash_call``), inside the parent's VMEM plan."""
+    import functools
+    import re
+    from benchmarks.chip.flops import flash_call
+    (b, s, h, d), mib = _TRAIN_CELLS[cell]
+    monkeypatch.setattr(flash.pltpu, "CompilerParams", functools.partial(
+        flash.pltpu.CompilerParams, vmem_limit_bytes=mib[grad] << 20))
+    fn = jax.grad(_flash_loss, argnums=(0, 1, 2)) if grad else (
+        lambda q, k, v: flash.flash_attention(q, k, v, causal=True))
+    text = jax.jit(fn).lower(*_qkv(v5e, b, s, h, d)).compile().as_text()
+    calls = {m[1]: m[2] for m in re.finditer(
+        r"%(\S*flash_\w+?)[_.\d]* = (.*?) custom-call\(.*tpu_custom_call", text)}
+    bh = b * h
+    fwd = f"(bf16[{bh},{s},{d}], f32[{bh},1,{s}])"
+    bwd = (f"(bf16[{bh},{s},{d}], bf16[{bh},{s},{d}], "
+           f"f32[{bh},{s // 1024},{s},{d}])")
+    strip = lambda shape: re.sub(r"\{[^}]*\}", "", shape)
+    assert strip(calls.pop(next(n for n in calls if n.endswith("flash_fwd")))
+                 ) == fwd
+    assert flash_call(fwd) == (4.0 * bh * s * s * d / 2,
+                               4 * bh * s * d * 2 + 4 * bh * s)
+    if grad:
+        assert strip(calls.pop(next(
+            n for n in calls if n.endswith("flash_bwd")))) == bwd
+        assert flash_call(bwd)[0] == 10.0 * bh * s * s * d / 2
+    assert not calls
+
+
 def test_flash_forward_backward_on_dp2_tp2_mesh(v5e_host):
     """The compiler cannot partition a Mosaic kernel; under a mesh the
     kernel entry maps it over rows and heads itself."""

@@ -9,6 +9,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -209,3 +210,49 @@ def test_report_mode_prints_the_serving_line(tmp_path, capsys):
     report = json.loads(capsys.readouterr().out)
     assert report["metrics"]["metrics.jsonl"]["serving"][
         "live_block_share"] == 9 / 16
+
+
+@pytest.mark.parametrize("model,seq,ratio", [
+    ("gpt", 1024, 88 / 128),     # gpt2m-train-s1024: a head is one block
+    ("gpt", 2048, 304 / 384),    # opt1b3-train-zero3-4chip: 2 x 2 blocks
+    ("bert", 1024, 1.0),         # kv_lens, not causal: the whole-block body
+], ids=["gpt-s1024", "gpt-s2048", "bert-s1024"])
+def test_report_prints_how_far_the_flash_causal_strips_engage(
+        tmp_path, capsys, monkeypatch, model, seq, ratio):
+    """The engine records ``flash_attention.causal_tile_plan`` of the step it
+    traces as two counters; the report prints them and their ratio: the
+    train cells' sequence lengths at the default blocks, and a BERT step."""
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    from deepspeed_tpu.models import bert
+    from deepspeed_tpu.runtime.model import from_gpt
+    from deepspeed_tpu.parallel.mesh import ParallelDims, initialize_mesh
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, 256, size=(1, seq + 1)).astype(np.int32)
+    if model == "gpt":
+        spec = from_gpt(gpt.GPTConfig(
+            vocab_size=256, max_seq_len=seq, n_layer=1, n_head=1, d_model=64,
+            dtype=jnp.float32, vocab_round_to=128))
+        batch = {"tokens": tokens}
+    else:
+        spec = bert.model_spec(bert.BertConfig(
+            vocab_size=256, max_seq_len=seq, n_layer=1, n_head=1, d_model=64,
+            d_ff=128, dtype=jnp.float32))
+        batch = {"tokens": tokens[:, :seq], "mlm_labels": tokens[:, :seq],
+                 "seq_lens": np.asarray([seq - 24], np.int32)}
+    engine, *_ = deepspeed_tpu.initialize(
+        model=spec, mesh_manager=initialize_mesh(
+            ParallelDims(dp=1), devices=jax.devices()[:1]),
+        config=base_config(micro_batch=1, extra={"telemetry": {
+            "enabled": True,
+            "metrics": {"path": str(tmp_path / "metrics.jsonl"),
+                        "interval_steps": 1}}}),
+        rng=jax.random.PRNGKey(0))
+    assert np.isfinite(float(engine.train_batch_fused(batch)))
+    mod = _run_report()
+    assert mod.main([str(tmp_path), "--json"]) == 0
+    row = json.loads(capsys.readouterr().out)["metrics"]["metrics.jsonl"]
+    assert row["flash_causal_tile_ratio"] == round(ratio, 4)
+    assert row["flash_causal_tiles_visited"] == \
+        ratio * row["flash_causal_tiles_square"] > 0
+    assert mod.main([str(tmp_path)]) == 0
+    assert f"= {round(ratio, 4)}" in capsys.readouterr().out
