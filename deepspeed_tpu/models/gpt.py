@@ -227,6 +227,7 @@ def logical_axes(config: GPTConfig) -> PyTree:
 
 # -------------------------------------------------------------------- apply
 
+@jax.named_scope("norm")
 def _layer_norm(x, scale, bias, eps=1e-5):
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -444,6 +445,7 @@ def _wdot(spec, x, w, out_dtype, preferred_element_type=None):
                       preferred_element_type=preferred_element_type)
 
 
+@jax.named_scope("qkv")
 def qkv_proj(x, p, config: GPTConfig, positions=None):
     """LN1 + qkv projection: [B,S,d] → (q, k, v) each [B,S,H,Dh].
 
@@ -463,6 +465,7 @@ def qkv_proj(x, p, config: GPTConfig, positions=None):
     return q, k, v
 
 
+@jax.named_scope("attn_out")
 def attn_project(attn, p, config: GPTConfig):
     """Attention output projection W_o·attn + b_o (no residual) — the one
     definition every train/inference/MoE path shares."""

@@ -333,9 +333,10 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
         return t.reshape(t.shape[:2] + (-1,))
 
     def attend(x, p, idx, cache):
-        q, fresh = family.project(x, p, config, positions)
-        # the scopes name, in a profiler's trace, the two places a tick
-        # touches the slot cache
+        # the scopes name, in a profiler's trace, a layer's queries and
+        # row, and the two places a tick touches the slot cache
+        with jax.named_scope("project"):
+            q, fresh = family.project(x, p, config, positions)
         with jax.named_scope("cache_update"):
             if int8:
                 (kq, ks), (vq, vs) = map(quantize_kv, fresh)
@@ -567,7 +568,8 @@ def decode_step(params: PyTree, token: jnp.ndarray, config, cache: KVCache,
     pos = lengths if ragged else cache.length
     positions = pos[:, None] if ragged else pos[None]
     x = family.embed(params, token[:, None], config, positions=positions)
-    sweep_of = family.sweeps(pos, B, config, cache.max_len, active)
+    with jax.named_scope("sweep"):
+        sweep_of = family.sweeps(pos, B, config, cache.max_len, active)
 
     def write(bank, layer, val):
         """One new [B, 1, *] row per slot at [layer, :, pos] (pos shared or

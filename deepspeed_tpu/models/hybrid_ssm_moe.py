@@ -221,6 +221,7 @@ def attention_project(x, p, config: HybridSSMMoEConfig):
                                      split(v, config.n_kv_head))
 
 
+@jax.named_scope("attn_out")
 def attention_output(x, attn, p, config: HybridSSMMoEConfig):
     """``x + r W_o attn``: ``attn`` [B, S, n_head, D]."""
     cdt = config.dtype

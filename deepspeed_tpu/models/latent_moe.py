@@ -121,6 +121,7 @@ class LatentMoEConfig:
 
 # ------------------------------------------------------------------ pieces
 
+@jax.named_scope("norm")
 def rms_norm(x, scale, eps, dtype=None):
     """In float32; the result in ``dtype`` (default: ``x``'s)."""
     x32 = x.astype(jnp.float32)
@@ -202,6 +203,7 @@ def latent_project(x, p, config: LatentMoEConfig, positions):
     return queries, row
 
 
+@jax.named_scope("attn_out")
 def latent_output(x, weighed, p, config: LatentMoEConfig):
     """``x + W_o concat_h(W_kvb[v] (sum_s p c))``: ``weighed`` [B, S, H,
     kv_rank] is each head's probability-weighted sum of latent rows."""

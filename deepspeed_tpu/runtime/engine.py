@@ -349,6 +349,8 @@ class DeepSpeedEngine:
                              synced=tcfg.spans.synced,
                              sync_registry=self.compile_registry,
                              name="engine")
+        # while the tracer is on, each compile publishes its op map
+        self.compile_registry.tracer = self.tracer
         self.metrics = MetricsRegistry("engine")
         self._mem_interval_s = float(tcfg.metrics.memory_interval_s)
         self._mem_cache = (0.0, 0, 0)  # (refreshed_at, rss, hbm)

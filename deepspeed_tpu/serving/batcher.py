@@ -106,6 +106,13 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         launch each."""
         C = tokens.shape[1]
         row, start, n = meta[0], meta[1], meta[2]
+        # the named scopes are the parts of the one program, by which a
+        # profiler's device time is split (``telemetry.device_time``):
+        # ``admit_row_cache`` the batch-1 row cache's allocation and
+        # zero-fill, ``admit_chunk`` a chunk's pass (the family's own scopes
+        # below it: ``admit_chunk/head`` is the head over all C positions),
+        # ``admit_head`` the one row taken of it, ``admit_slot_write`` the
+        # row's copy into the pool, ``admit_bind`` the slot's vectors
 
         def real(i):
             # where the prompt ends inside chunk ``i``: a family that keeps
@@ -113,6 +120,7 @@ def admission(fam, cfg, max_len: int, kv_dtype):
             # banks take it either way: it lies past the frontier)
             return jnp.clip(n - i * C, 0, C)[None]
 
+        @jax.named_scope("admit_head")
         def take(lg, i):
             # the last real token's logits if chunk ``i`` holds it (the
             # last chunk does; an earlier chunk's row is junk that the next
@@ -122,16 +130,19 @@ def admission(fam, cfg, max_len: int, kv_dtype):
 
         def chunk(i, carry):
             pos = start + i * C
-            lg, cache = fam.extend(
-                params, lax.dynamic_index_in_dim(tokens, i, 0), cfg,
-                carry[1], lengths=pos[None], valid=real(i))
+            with jax.named_scope("admit_chunk"):
+                lg, cache = fam.extend(
+                    params, lax.dynamic_index_in_dim(tokens, i, 0), cfg,
+                    carry[1], lengths=pos[None], valid=real(i))
             return take(lg, i), cache
 
         if prefix is None:
-            lg, cache = fam.prefill(
-                params, tokens[:1], cfg,
-                fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype),
-                valid=real(0))
+            chunk0 = tokens[:1]
+            with jax.named_scope("admit_row_cache"):
+                fresh = fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype)
+            with jax.named_scope("admit_chunk"):
+                lg, cache = fam.prefill(params, chunk0, cfg, fresh,
+                                        valid=real(0))
             first, carry = 1, (take(lg, 0), cache)
         else:
             first, carry = 0, (
@@ -139,14 +150,18 @@ def admission(fam, cfg, max_len: int, kv_dtype):
                 dataclasses.replace(prefix, length=start))
         vec, cache = lax.fori_loop(jnp.int32(first), (n + C - 1) // C,
                                    chunk, carry)
-        key = jnp.where(meta[5] != 0, jax.random.fold_in(
-            key, lax.bitcast_convert_type(meta[6], jnp.uint32)), key)
-        return (fam.write_slot(pool, row, cache),
-                lengths.at[row].set(start + n), last.at[row].set(vec),
-                keys.at[row].set(key), greedy.at[row].set(meta[3] != 0),
-                temp.at[row].set(
-                    lax.bitcast_convert_type(meta[4], jnp.float32)),
-                active.at[row].set(True), vec)
+        with jax.named_scope("admit_bind"):
+            key = jnp.where(meta[5] != 0, jax.random.fold_in(
+                key, lax.bitcast_convert_type(meta[6], jnp.uint32)), key)
+        with jax.named_scope("admit_slot_write"):
+            pool = fam.write_slot(pool, row, cache)
+        with jax.named_scope("admit_bind"):
+            return (pool,
+                    lengths.at[row].set(start + n), last.at[row].set(vec),
+                    keys.at[row].set(key), greedy.at[row].set(meta[3] != 0),
+                    temp.at[row].set(
+                        lax.bitcast_convert_type(meta[4], jnp.float32)),
+                    active.at[row].set(True), vec)
 
     return admit
 
@@ -250,7 +265,8 @@ class SlotBatcher:
         self.spec_overshoot = self.draft_k if self.spec else 0
         #: every program the batcher drives, by name — the serving gate
         #: (gateway CompileWatch, compile_report.py) watches this
-        self.registry = CompiledProgramRegistry("serving")
+        self.registry = CompiledProgramRegistry("serving",
+                                                tracer=self.tracer)
         self._build_programs(config)
 
     def counts(self, group: str):

@@ -14,6 +14,10 @@ all used to re-derive piecemeal:
 - :mod:`.export` — Chrome/Perfetto ``trace_event`` JSON export of the
   collected spans, schema validation, and the opt-in
   ``jax.profiler.trace`` capture window;
+- :mod:`.op_maps` — device seconds by program and by named scope:
+  each compiled program's op map (instruction -> scope), the published
+  table of them, and :func:`device_time`, the join of a profiler trace's
+  device ops to it;
 - :mod:`.config` — the validated ``"telemetry"`` config section;
 - :mod:`.propagate` — cross-process ``trace_id``/``parent_span_id``
   propagation (spool docs, ``DS_TRACE_CONTEXT`` env, clock-sync
@@ -33,6 +37,7 @@ from .critical_path import (MTTR_PHASES, TTFT_PHASES,  # noqa: F401
                             decompose_training_restarts, merge_fleet_trace,
                             missing_worker_telemetry, request_chains,
                             span_chain_coverage, summarize_ttft)
+from .op_maps import device_time  # noqa: F401
 from .export import (profiler_trace, trace_events, validate_trace,  # noqa: F401
                      write_trace)
 from .metrics import (METRIC_NAMES, Counter, Gauge, Histogram,  # noqa: F401

@@ -158,6 +158,13 @@ def profiler_trace(logdir: str, journal=None):
     ``trace.capture`` so a post-mortem knows these steps carried profiler
     overhead.  Degrades to a no-op (with a warning) when the profiler is
     unavailable on this backend.
+
+    When the window closes, every live ``CompiledProgramRegistry``
+    publishes the op maps of its compiled programs and the published table
+    is written as ``programs.json`` beside the trace:
+    ``scripts/run_report.py --device-trace <logdir>`` joins the two into
+    device seconds by program and by named scope
+    (``telemetry/op_maps.py``).
     """
     from ..utils.logging import logger
 
@@ -183,3 +190,8 @@ def profiler_trace(logdir: str, journal=None):
             except Exception as e:
                 logger.warning(
                     f"[telemetry] jax profiler stop failed: {e!r}")
+            from ..utils.compile_watch import registries
+            from .op_maps import write_programs
+            for registry in registries():
+                registry.publish_op_maps()
+            write_programs(logdir)

@@ -24,7 +24,11 @@ Three pieces:
   arg shape/dtype signature, wall seconds) whenever a call grows the
   underlying jit cache.  Re-registering a name folds the old program's
   compiles into a retired counter, so "un-caching" a program (rebuilding
-  it per call) cannot hide from the count.
+  it per call) cannot hide from the count.  A registry also keeps the
+  description of each program's last compile (shapes, no memory) and can
+  lower it again into its **op map**: the named scope of each optimised-HLO
+  instruction, published for ``telemetry.device_time`` while the owner's
+  span tracer is on, or on demand (``telemetry/op_maps.py``).
 - :class:`CompileWatch` — a context manager over one or more registries:
   snapshot, warm up, then any further compile is a *recompile* — reported
   by :meth:`CompileWatch.check`, journaled as a ``perf.recompile`` event
@@ -41,6 +45,7 @@ compile counts/seconds are a diffable per-PR artifact.
 from __future__ import annotations
 
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
@@ -48,8 +53,17 @@ from .lock_watch import LockName, TrackedLock
 
 __all__ = [
     "hot_path", "CompileEvent", "CompiledProgramRegistry", "CompileWatch",
-    "RecompileError",
+    "RecompileError", "registries",
 ]
+
+#: every live registry (weak: a registry dies with its owner)
+_REGISTRIES: "weakref.WeakSet[CompiledProgramRegistry]" = weakref.WeakSet()
+
+
+def registries() -> List["CompiledProgramRegistry"]:
+    """The registries alive in this process: what an operator's capture
+    window (``telemetry.profiler_trace``) asks for their op maps."""
+    return list(_REGISTRIES)
 
 
 def hot_path(fn: Callable) -> Callable:
@@ -92,6 +106,28 @@ def _shape_sig(args: tuple, kwargs: dict) -> str:
     if len(leaves) > _SIG_MAX_LEAVES:
         parts.append(f"...+{len(leaves) - _SIG_MAX_LEAVES}")
     return " ".join(parts)
+
+
+def _described(args: tuple, kwargs: dict):
+    """A call's arguments with every array replaced by its description
+    (shape, dtype, weak type and, of a committed array, its sharding: what
+    a donated array still has); static arguments and Python scalars stay as
+    they were.  Enough to lower the SAME module again, and nothing of the
+    call's memory is held.  An uncommitted array is described without a
+    sharding: with one, the lowering annotates the argument, the module is
+    another, and so is every compiler-made name in it."""
+    import jax
+
+    def describe(leaf):
+        if isinstance(leaf, jax.Array) and not isinstance(
+                leaf, jax.core.Tracer):
+            return jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, weak_type=leaf.weak_type,
+                sharding=leaf.sharding if leaf.committed else None)
+        if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
+            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+        return leaf
+    return jax.tree_util.tree_map(describe, (args, kwargs))
 
 
 @dataclass(frozen=True)
@@ -149,8 +185,15 @@ class CompiledProgramRegistry:
     both touch it.
     """
 
-    def __init__(self, name: str = "programs"):
+    def __init__(self, name: str = "programs", tracer=None):
         self.name = name
+        #: the owner's span tracer: while it is enabled, each compile's op
+        #: map is built and published (``telemetry/op_maps.py``); the
+        #: one switch the spans have, no other
+        self.tracer = tracer
+        #: per program, the description of its last compile's arguments
+        self._last_call: Dict[str, Any] = {}
+        _REGISTRIES.add(self)
         self._lock = TrackedLock(LockName.PERF_COMPILE_REGISTRY)
         self._programs: Dict[str, _WrappedProgram] = {}
         #: compiles owned by programs later re-registered under the same
@@ -171,6 +214,7 @@ class CompiledProgramRegistry:
                                        + prev._prog._cache_size())
             wrapped = _WrappedProgram(prog, self, name)
             self._programs[name] = wrapped
+            self._last_call.pop(name, None)
             return wrapped
 
     def register_all(self, programs: Dict[str, Any],
@@ -180,12 +224,57 @@ class CompiledProgramRegistry:
     def _on_compile(self, name: str, args, kwargs, live: int,
                     seconds: float) -> None:
         sig = _shape_sig(args, kwargs)
+        described = _described(args, kwargs)
         with self._lock:
             count = self._retired.get(name, 0) + live
             self._compile_s[name] = self._compile_s.get(name, 0.0) + seconds
             self._events.append(CompileEvent(
                 registry=self.name, program=name, count=count, shapes=sig,
                 seconds=seconds, ts=time.time()))
+            self._last_call[name] = described
+        if self.tracer is not None and self.tracer.enabled:
+            self._publish(name)
+
+    # ------------------------------------------------------------ op maps
+    def op_map(self, name: str) -> Optional[List[dict]]:
+        """The op map of program ``name`` as last compiled
+        (``telemetry.op_maps.parse_op_map`` of its optimised HLO): the
+        program lowered again from the description of that call's
+        arguments.  It grows no jit cache, and compiles nothing where the
+        process or the persistent cache holds the executable.  None for a
+        program that has not compiled yet."""
+        from ..telemetry.op_maps import parse_op_map
+        with self._lock:
+            wrapped = self._programs.get(name)
+            described = self._last_call.get(name)
+        if wrapped is None or described is None:
+            return None
+        args, kwargs = described
+        # the compiled object goes as soon as its text is read
+        return parse_op_map(
+            wrapped._prog.lower(*args, **kwargs).compile().as_text())
+
+    def _publish(self, name: str) -> bool:
+        from ..telemetry.op_maps import publish
+        try:
+            rows = self.op_map(name)
+        except Exception as e:  # an op map is telemetry: it never stops a run
+            from .logging import logger
+            logger.warning(f"[compile_watch] no op map of {self.name}/{name}:"
+                           f" {e!r}")
+            return False
+        if rows is None:
+            return False
+        publish(self.name, name, rows)
+        return True
+
+    def publish_op_maps(self) -> List[str]:
+        """Build and publish the op map of every program that has compiled
+        (for an operator who captures a trace from a process whose tracers
+        are off); returns the names published."""
+        with self._lock:
+            names = list(self._last_call)
+        return [n for n in names if self._publish(n)]
 
     # ------------------------------------------------------------ queries
     def counts(self) -> Dict[str, int]:

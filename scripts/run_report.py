@@ -23,6 +23,7 @@ Usage:
     python scripts/run_report.py RUN_DIR [--expect-rank-metrics N]
                                  [--trace FILE] [--json]
     python scripts/run_report.py --fleet-dir DIR [--json]
+    python scripts/run_report.py --device-trace LOGDIR
     python scripts/run_report.py --bench [--out BENCH_TELEMETRY.json]
                                  [--baseline FILE] [--steps 5] [--warmup 2]
                                  [--repeats 3]
@@ -393,6 +394,22 @@ def bench(args) -> int:
     return 1 if problems else 0
 
 
+def device_trace(logdir: str) -> int:
+    """Device seconds by program and scope of a capture window
+    (``telemetry.profiler_trace``): its trace joined to the op maps the
+    window wrote beside it (docs/telemetry.md)."""
+    from deepspeed_tpu.telemetry import op_maps as dt
+    try:
+        maps, ops = dt.read_programs(logdir), dt.read_trace_ops(logdir)
+    except (OSError, ValueError, KeyError) as e:
+        print(f"error: {logdir}: {e}", file=sys.stderr)
+        return 1
+    print(f"{len(ops)} device ops, {len(maps)} programs "
+          f"({sum(len(m['ops']) for m in maps)} rows)")
+    print("\n".join(dt.format_report(ops, maps)))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("run_dir", nargs="?", default=None,
@@ -408,6 +425,10 @@ def main(argv=None) -> int:
                          "coverage and fail on missing worker telemetry "
                          "(trace.*.json exports, per-rank metrics); "
                          "scripts/fleet_report.py does the full merge")
+    ap.add_argument("--device-trace", default=None, metavar="LOGDIR",
+                    help="a telemetry.profiler_trace logdir (.xplane.pb + "
+                         "programs.json): print device seconds by program "
+                         "and by named scope, with the unjoined share")
     ap.add_argument("--json", action="store_true", dest="as_json")
     ap.add_argument("--bench", action="store_true",
                     help="run the CPU fixtures and gate BENCH_TELEMETRY.json")
@@ -423,10 +444,13 @@ def main(argv=None) -> int:
 
     if args.bench:
         return bench(args)
+    if args.device_trace:
+        return device_trace(args.device_trace)
     if args.run_dir is None and args.fleet_dir is not None:
         args.run_dir = args.fleet_dir
     if args.run_dir is None:
-        print("error: RUN_DIR, --fleet-dir, or --bench required",
+        print("error: RUN_DIR, --fleet-dir, --device-trace or --bench "
+              "required",
               file=sys.stderr)
         return 2
     return report(args)
