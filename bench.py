@@ -283,10 +283,9 @@ def main() -> None:
         if os.environ.get("BENCH_GAS"):
             gas = int(os.environ["BENCH_GAS"])
         if os.environ.get("BENCH_LOSS_CHUNK"):
-            # sweep knob: chunked loss head — the full fp32 logits
-            # tensor is 4.9 GB at mb24 (write fwd + read bwd); scanning
-            # the head in seq chunks trades that HBM traffic for
-            # recompute inside the chunk scan
+            # sweep knob: a cap on the positions a chunk of the loss head
+            # holds (the head already runs in chunks that follow from the
+            # bytes of its float32 logits: models/gpt.py::_loss_layout)
             config = dataclasses.replace(
                 config, loss_chunk=int(os.environ["BENCH_LOSS_CHUNK"]))
         if os.environ.get("BENCH_REMAT_POLICY"):

@@ -19,6 +19,7 @@ deliberately from a torch port:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -49,18 +50,19 @@ class GPTConfig:
     # jax.checkpoint policy when remat is on: "nothing" recomputes the
     # whole block (min memory); "dots" saves matmul outputs with no batch
     # dims; "attn_out" saves the [B,S,H,D] attention outputs (~48MB/layer)
-    # so the downstream block tail needn't recompute them.  NOTE: the
-    # flash kernel's logsumexp residual is internal to its custom_vjp and
-    # cannot be name-saved, so its backward still replays the fwd kernel
-    # under every policy.
+    # so the downstream block tail needn't recompute them.  The flash
+    # kernel's logsumexp residual is tagged ``ds_attn_lse`` inside its
+    # custom_vjp and saved beside the output under "dots" and "attn_out",
+    # so the backward does not replay the forward kernel under those two.
     remat_policy: str = "nothing"       # nothing | dots | attn_out
     # lax.scan unroll factor for the layer stack (XLA can overlap/fuse
     # across unrolled iterations at the cost of program size)
     scan_unroll: int = 1
-    # sequence-chunked cross-entropy: compute the [B, chunk, V] logits one
-    # chunk at a time (rematerialized in backward) instead of holding the
-    # full [B, S, V] fp32 logits — the head is ~1/4 of a small model's
-    # FLOPs but its logits dominate HBM at large batch.  0 disables.
+    # a cap on the positions of each sequence a chunk of the loss holds.
+    # The head and the cross-entropy run over [B, chunk, V] float32 logits
+    # at a time under their own backward rule wherever the whole [B, S, V]
+    # would not be small (``_loss_layout``: the chunk follows from the
+    # bytes); this caps it further.  0: no cap.
     loss_chunk: int = 0
     use_flash_attention: bool = True    # pallas kernel when available
     vocab_round_to: int = 128           # pad vocab to a lane multiple
@@ -560,8 +562,9 @@ def _head_logits(params: PyTree, h, config: GPTConfig) -> jnp.ndarray:
 
     Inputs stay in the compute dtype so the MXU runs at its bf16 rate; the
     accumulator/output is fp32 (``preferred_element_type``) for a stable
-    softmax.  The ONE head definition — full-logits (lm_logits) and the
-    chunked loss both route here.
+    softmax.  The head of full logits: ``lm_logits`` and the loss's plain
+    path route here; the chunked loss (``_chunked_nll``) multiplies a float
+    head a chunk at a time to the same types.
     """
     head = params["wte"] if config.tie_word_embeddings else params["lm_head"]
     logits = _wdot("...d,vd->...v", h.astype(config.dtype), head,
@@ -678,6 +681,179 @@ def encode(params: PyTree, tokens: jnp.ndarray, config: GPTConfig
     return _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
 
 
+#: float32 logits a device may hold at once in the loss: a ``[B, c, V]``
+#: chunk's bytes on one device stay at or under it.  Where the whole
+#: ``[B, S, V]`` fits, the loss is the plain single pass.
+_LOGITS_CHUNK_BYTES = 5 << 28
+
+#: the ``[head products the loss holds, sequence chunks]`` lists opened by
+#: :func:`tally_head`, innermost last
+_head_tallies = []
+
+
+@contextlib.contextmanager
+def tally_head():
+    """Yields ``[products, chunks]`` of the loss heads traced inside the
+    block: the vocabulary-sized products the traced step holds, forward and
+    backward (3 either way: where the compiler is handed whole logits it
+    may re-make them, in chunks it cannot), and the chunks of the sequence
+    the head walks (1: the plain path).  The train engine opens it around
+    the trace of its loss and records the two as counters."""
+    tally = [0, 0]
+    _head_tallies.append(tally)
+    try:
+        yield tally
+    finally:
+        _head_tallies.pop()
+
+
+def _loss_layout(B, S, V, cap):
+    """How the loss walks ``[B, S, V]`` logits: ``(c, shards, whole)``.
+
+    ``c``: positions of each sequence in a chunk, the largest divisor of
+    ``S`` whose float32 logits ``[B, c, V]``, divided over the devices of
+    the mesh (data shards the batch, model the vocabulary), stay under
+    :data:`_LOGITS_CHUNK_BYTES`, and under ``cap`` positions if set
+    (``GPTConfig.loss_chunk``); ``S`` itself is one chunk, the plain path.
+    ``shards``: the data-parallel shards of the batch, each of which sums
+    its own rows' head gradient, reduced ONCE after the loop.  ``whole``:
+    the sharding of the head inside the loop (vocabulary over model, whole
+    on every data shard: ZeRO-3 gathers it ONCE, before the loop), or None
+    without a mesh."""
+    from jax.sharding import NamedSharding
+    from ..parallel.mesh import get_mesh_manager
+    from .partitioning import TP_RULES, spec_for_axes
+    mm = get_mesh_manager(optional=True)
+    devices = mm.mesh.size if mm is not None else 1
+    limit = min(cap or S, S,
+                max(int(_LOGITS_CHUNK_BYTES * devices // (B * V * 4)), 1))
+    c = next(c for c in range(limit, 0, -1) if S % c == 0)
+    if devices == 1:
+        return c, 1, None
+    shards = mm.dp_world_size if B % mm.dp_world_size == 0 else 1
+    return c, shards, NamedSharding(
+        mm.mesh, spec_for_axes((VOCAB, EMBED), TP_RULES))
+
+
+def _nll_chunks(h, w, bias, targets, whole, with_grads):
+    """``(total, grads)`` of :func:`_chunked_nll`, one chunk of the scan at
+    a time; ``grads`` for a unit cotangent, ``(dh, dw, dbias)`` (``dw`` and
+    ``dbias`` float32 sums), or None."""
+    if whole is not None:
+        w = lax.with_sharding_constraint(w, whole)
+    G = h.shape[1]
+
+    def chunk(acc, xs):
+        total, dw, db = acc
+        hc, tc = xs
+        with jax.named_scope("head"):
+            logits = jnp.einsum("...d,vd->...v", hc, w,
+                                preferred_element_type=jnp.float32)
+            if bias is not None:
+                logits = logits + bias
+        with jax.named_scope("loss"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            live = tc >= 0
+            gold = jnp.take_along_axis(
+                logits, jnp.maximum(tc, 0)[..., None], axis=-1)[..., 0]
+            total = total + jnp.sum(jnp.where(live, lse - gold, 0.0))
+            if not with_grads:
+                return (total, dw, db), None
+            hot = tc[..., None] == lax.broadcasted_iota(
+                jnp.int32, logits.shape, logits.ndim - 1)
+            dl = jnp.where(live[..., None],
+                           jnp.exp(logits - lse[..., None]) - hot, 0.0)
+            if db is not None:
+                db = db + jnp.sum(dl, axis=(1, 2))
+            dl = dl.astype(w.dtype)
+        with jax.named_scope("head"):
+            dh = jnp.einsum("...v,vd->...d", dl, w)
+            dw = dw + jnp.einsum("gbcv,gbcd->gvd", dl, hc,
+                                 preferred_element_type=jnp.float32)
+        return (total, dw, db), dh
+
+    def zeros(x):
+        return jnp.zeros((G,) + x.shape, jnp.float32)
+
+    acc = (jnp.zeros((), jnp.float32),
+           zeros(w) if with_grads else None,
+           zeros(bias) if with_grads and bias is not None else None)
+    (total, dw, db), dh = lax.scan(chunk, acc, (h, targets))
+    return total, (dh, dw, db) if with_grads else None
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_nll(h, w, bias, targets, whole):
+    """Sum of the masked token NLLs of ``h`` ``[n, G, b, c, d]`` (``n``
+    chunks of ``c`` positions of ``G`` data shards' ``b`` rows) against the
+    head ``w`` ``[V, d]`` (both in the compute dtype; ``bias`` float32 or
+    None), a chunk ``[G, b, c, V]`` of float32 logits at a time.
+
+    Its forward rule makes the gradients while it holds a chunk's logits:
+    the softmax's gradient ``(p - onehot) * mask`` cast to the compute
+    dtype, then the hidden states' gradient and the head's, the latter
+    summed in float32 across chunks; the backward rule multiplies them by
+    the cotangent.  That is one head product a chunk forward and two
+    backward, fixed by this code and not by what the compiler would rather
+    re-make.  ``whole``: :func:`_loss_layout`'s."""
+    return _nll_chunks(h, w, bias, targets, whole, False)[0]
+
+
+def _chunked_nll_fwd(h, w, bias, targets, whole):
+    return _nll_chunks(h, w, bias, targets, whole, True)
+
+
+def _chunked_nll_bwd(whole, grads, g):
+    dh, dw, db = grads
+    with jax.named_scope("head"):
+        # a data shard's sum leaves in the compute dtype, as a gradient
+        # crosses devices everywhere else in the step
+        return ((dh.astype(jnp.float32) * g).astype(dh.dtype),
+                jnp.sum((dw * g).astype(dh.dtype), axis=0),
+                None if db is None else jnp.sum(db * g, axis=0), None)
+
+
+_chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
+
+
+def _head_nll(params: PyTree, h, targets, config: GPTConfig):
+    """Head and cross-entropy of final-layernormed ``h`` ``[B, S, d]``:
+    ``(sum of masked NLLs, count)``.  Where the whole float32 logits are
+    small, or the head is not a float matrix (int8 weights go through
+    ``_wdot``), the plain single pass; else :func:`_chunked_nll` over
+    slices of the sequence (every batch row in each, so a device's share of
+    a chunk is its own rows)."""
+    head = params["wte"] if config.tie_word_embeddings else params["lm_head"]
+    B, S, d = h.shape
+    c, shards, whole = S, 1, None
+    if isinstance(head, jax.Array) and jnp.issubdtype(head.dtype,
+                                                      jnp.floating):
+        c, shards, whole = _loss_layout(B, S, head.shape[0],
+                                        config.loss_chunk)
+        if S % min(config.loss_chunk or S, S):
+            logger.warning(f"loss_chunk={config.loss_chunk} does not divide "
+                           f"seq {S}; using chunk {c}")
+    for tally in _head_tallies[-1:]:
+        tally[0] += 3
+        tally[1] += S // c
+    if c == S:
+        return _token_nll(_head_logits(params, h, config), targets)
+
+    def chunks(x):      # [B, S, ...] -> [n, shards, B / shards, c, ...]
+        x = x.reshape((shards, B // shards, S // c, c) + x.shape[2:])
+        return jnp.moveaxis(x, 2, 0)
+
+    with jax.named_scope("head"):
+        w = head.astype(config.dtype)   # ONE copy, outside the chunk loop
+        hc = chunks(h.astype(config.dtype))
+    bias = params.get("lm_head_bias")
+    total = _chunked_nll(
+        hc, w, None if bias is None else bias.astype(jnp.float32),
+        chunks(targets), whole)
+    with jax.named_scope("loss"):
+        return total, jnp.sum((targets >= 0).astype(jnp.float32))
+
+
 def loss_fn(params: PyTree, batch: Dict[str, jnp.ndarray], config: GPTConfig) -> jnp.ndarray:
     """Mean next-token cross-entropy. batch: {'tokens': [B,S+1]} or
     input/target.  A ``_train_rng`` key in the batch (engine-injected)
@@ -692,38 +868,10 @@ def loss_fn(params: PyTree, batch: Dict[str, jnp.ndarray], config: GPTConfig) ->
     else:
         tokens = batch["tokens"]
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    chunk = config.loss_chunk
-    if chunk:
-        S = inputs.shape[1]
-        if S % chunk:
-            # largest divisor of S that fits the requested chunk — honest
-            # degradation instead of silently falling back to full logits
-            eff = next(c for c in range(min(chunk, S), 0, -1) if S % c == 0)
-            logger.warning(f"loss_chunk={chunk} does not divide seq {S}; "
-                           f"using chunk {eff}")
-            chunk = eff
-        x = backbone(params, inputs, config, dropout_rng=dropout_rng,
-                     pld_theta=pld_theta)
-        h = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-        B, S, d = h.shape
-        n = S // chunk
-        hc = h.reshape(B, n, chunk, d).transpose(1, 0, 2, 3)
-        tc = targets.reshape(B, n, chunk).transpose(1, 0, 2)
-
-        def chunk_nll(carry, xs):
-            hcb, tcb = xs
-            tot, cnt = _token_nll(_head_logits(params, hcb, config), tcb)
-            return (carry[0] + tot, carry[1] + cnt), None
-
-        (tot, cnt), _ = lax.scan(
-            jax.checkpoint(chunk_nll,
-                           policy=jax.checkpoint_policies.nothing_saveable),
-            (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (hc, tc))
-        return tot / jnp.maximum(cnt, 1.0)
-    logits = apply(params, inputs, config, dropout_rng=dropout_rng,
-                   pld_theta=pld_theta)
-    tot, cnt = _token_nll(logits, targets)
+    x = backbone(params, inputs, config, dropout_rng=dropout_rng,
+                 pld_theta=pld_theta)
+    h = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    tot, cnt = _head_nll(params, h, targets, config)
     return tot / jnp.maximum(cnt, 1.0)
 
 

@@ -1043,15 +1043,19 @@ class DeepSpeedEngine:
 
     # ------------------------------------------------------------------ jitted programs
     def _build_steps(self) -> None:
+        from ..models.gpt import tally_head
         from ..ops.pallas.flash_attention import tally_causal_tiles
 
         def loss_fn(p, b):
             # runs when a step program is traced, not when it runs: how far
-            # the flash kernels' causal strips engage in this model's step
-            with tally_causal_tiles() as tiles:
+            # the flash kernels' causal strips engage in this model's step,
+            # and how its loss head is laid out
+            with tally_causal_tiles() as tiles, tally_head() as head:
                 loss = self.module.loss_fn(p, b)
             for name, n in zip((MetricName.FLASH_CAUSAL_TILES_VISITED,
-                                MetricName.FLASH_CAUSAL_TILES_SQUARE), tiles):
+                                MetricName.FLASH_CAUSAL_TILES_SQUARE,
+                                MetricName.HEAD_LOGIT_PRODUCTS,
+                                MetricName.HEAD_ROW_CHUNKS), tiles + head):
                 self.metrics.counter(name).inc(n)
             return loss
 

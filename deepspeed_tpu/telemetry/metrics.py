@@ -72,6 +72,13 @@ class MetricName:
     #: diagonal block was walked in causal strips; visited / square is how
     #: far the strips engage (1.0: not at all)
     FLASH_CAUSAL_TILES_SQUARE = "flash.causal_tiles_square"
+    #: vocabulary-sized products the traced step's loss head holds, forward
+    #: and backward (``models.gpt.tally_head``: 3 a head; over chunks of the
+    #: sequence its own backward rule fixes them, of whole logits the
+    #: compiler may re-make them)
+    HEAD_LOGIT_PRODUCTS = "head.logit_products"
+    #: chunks of the sequence that head walks (1: the plain single pass)
+    HEAD_ROW_CHUNKS = "head.row_chunks"
     #: host process resident set size, bytes (0 without psutil)
     HOST_RSS_BYTES = "mem.host_rss_bytes"
     #: sum of live jax device-buffer bytes (the HBM census)

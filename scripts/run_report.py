@@ -120,6 +120,10 @@ def report(args) -> int:
                 m["flash.causal_tiles_visited"]
                 / m["flash.causal_tiles_square"], 4)
             if m.get("flash.causal_tiles_square") else None,
+            # the loss head of the traced step: vocabulary-sized products
+            # it holds and the chunks of the sequence it walks (1: plain)
+            "head_logit_products": m.get("head.logit_products"),
+            "head_row_chunks": m.get("head.row_chunks"),
             "host_rss_bytes": m.get("mem.host_rss_bytes"),
             "rollbacks": m.get("elastic.rollbacks"),
         }
@@ -177,7 +181,10 @@ def report(args) -> int:
                       f"{r['flash_causal_tiles_visited']} / "
                       f"{r['flash_causal_tiles_square']} = "
                       f"{r['flash_causal_tile_ratio']}"
-                      if r["flash_causal_tile_ratio"] else ""))
+                      if r["flash_causal_tile_ratio"] else "") + (
+                      f", head products {r['head_logit_products']} in "
+                      f"{r['head_row_chunks']} chunk(s)"
+                      if r["head_row_chunks"] else ""))
             if "serving" in r:
                 print("    serving: " + ", ".join(
                     f"{field} {round(value, 4)}"

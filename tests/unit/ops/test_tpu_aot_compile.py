@@ -469,3 +469,71 @@ def test_bias_gelu_dropout_forward_backward(v5e):
                        .astype(jnp.float32))
 
     _compiles_with_kernel(jax.grad(loss, argnums=(0, 1)), x, b)
+
+
+# The train step's loss head (PERF.md 6, PR 39): the engine's own fused step
+# at the two train cells' widths, micro-batch and vocabulary, two layers deep
+# (the scan over layers compiles its body once whatever the depth).
+_HEAD_CELLS = {
+    "gpt2m-train-s1024": (("gpt2-medium", "builders.gpt2"), 24, 1024,
+                          dict(chips=1, zero_stage=1)),
+    "opt1b3-train-zero3-4chip": (("opt-1.3b", "builders.opt"), 8, 2048,
+                                 dict(chips=4, zero_stage=3)),
+    # no cell: tensor parallelism shards the vocabulary the logsumexp
+    # reduces over (micro-batch 16, so that the logits are not small)
+    "gpt2m-dp2-tp2": (("gpt2-medium", "builders.gpt2"), 16, 1024,
+                      dict(chips=4, tp=2, zero_stage=3)),
+}
+
+
+@pytest.mark.parametrize("cell", list(_HEAD_CELLS))
+def test_train_step_holds_three_head_products_and_no_whole_logits(
+        v5e_host, cell):
+    """The compiler is handed the head a chunk of the sequence at a time
+    under a backward rule of its own, so it re-makes nothing: three
+    vocabulary-sized products a chunk (the logits and the two gradients), no
+    ``.remat`` copy of any, no buffer the size of the float32 ``[B, S, V]``
+    logits in the plan; and under ZeRO-3 the head is gathered ONCE, before
+    the chunk loops."""
+    import dataclasses
+    import json
+    import re
+    import sys
+    from benchmarks.chip.builders import resolve
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    sys.path.insert(0, os.path.join(root, "scripts"))
+    try:
+        aot = importlib.import_module("aot_train_step")
+    finally:
+        sys.path.remove(os.path.join(root, "scripts"))
+    (name, builder), micro, seq, how = _HEAD_CELLS[cell]
+    with open(os.path.join(root, "benchmarks", "chip", "configs",
+                           name + ".json")) as f:
+        cfg = dataclasses.replace(
+            resolve(builder)(json.load(f)), n_layer=2, max_seq_len=seq,
+            dtype=BF16, remat=True, remat_policy="attn_out")
+    compiled = aot.compile_step(cfg, micro, **how)
+    text, vocab = compiled.as_text(), cfg.padded_vocab
+    lines = text.splitlines()
+
+    products = [ln for ln in lines
+                if " convolution(" in ln and re.search(r'op_name="[^"]*/head/',
+                                                       ln)]
+    assert len(products) == 3, products
+    remade = [ln for ln in lines if re.match(r"\s*%\S*\.remat\d* = ", ln)
+              and (re.search(rf"= [^(]*\b{vocab}\b", ln)
+                   or re.search(r'op_name="[^"]*/(head|loss)/', ln))]
+    assert not remade, remade
+    tp = how.get("tp", 1)
+    whole = micro * seq * (vocab // tp) * 4
+    assert f"f32[{micro},{seq},{vocab // tp}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < whole
+
+    entry = next(i for i, ln in enumerate(lines) if ln.startswith("ENTRY "))
+    gathers = [i for i, ln in enumerate(lines) if re.search(
+        rf"= bf16\[{vocab // tp},{cfg.d_model}\]\S* all-gather\(", ln)]
+    if how["chips"] == 1:
+        assert not gathers
+    else:   # one, as before this head ran in chunks, and outside the loops
+        assert len(gathers) == 1 and gathers[0] > entry, gathers

@@ -254,5 +254,11 @@ def test_report_prints_how_far_the_flash_causal_strips_engage(
     assert row["flash_causal_tile_ratio"] == round(ratio, 4)
     assert row["flash_causal_tiles_visited"] == \
         ratio * row["flash_causal_tiles_square"] > 0
+    # the loss head of a CPU-sized GPT is the plain single pass; BERT's
+    # head is its own and is not counted
+    assert (row["head_logit_products"], row["head_row_chunks"]) == \
+        ((3, 1) if model == "gpt" else (0, 0))
     assert mod.main([str(tmp_path)]) == 0
-    assert f"= {round(ratio, 4)}" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert f"= {round(ratio, 4)}" in out
+    assert ("head products 3 in 1 chunk(s)" in out) == (model == "gpt")
