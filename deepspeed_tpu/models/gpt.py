@@ -370,14 +370,19 @@ def _attention(q, k, v, config: GPTConfig, window=None):
     memory-linear flash path via ``lax.cond`` — only the truly banded
     layers materialize dense scores.
 
-    Every path's output is name-tagged "ds_attn_out" so
-    ``remat_policy="attn_out"`` saves it regardless of variant.
+    Every path's output is name-tagged "ds_attn_out", once, so
+    ``remat_policy="attn_out"`` saves it regardless of variant:
+    ``flash_attention`` tags its own result (the kernel's, which IS the
+    array the output product reads, or its dense fallback's); every other
+    path is tagged here.
     """
-    from jax.ad_checkpoint import checkpoint_name
-
     with jax.named_scope("attention"):
-        return checkpoint_name(_attention_impl(q, k, v, config, window),
-                               "ds_attn_out")
+        return _attention_impl(q, k, v, config, window)
+
+
+def _tagged(attn):
+    from jax.ad_checkpoint import checkpoint_name
+    return checkpoint_name(attn, "ds_attn_out")
 
 
 def _attention_impl(q, k, v, config: GPTConfig, window=None):
@@ -405,32 +410,32 @@ def _attention_impl(q, k, v, config: GPTConfig, window=None):
             return lax.cond(
                 window >= k.shape[1],
                 lambda ops: _attention_impl(*ops, config),
-                lambda ops: _windowed_attention(*ops, config, window),
+                lambda ops: _tagged(_windowed_attention(*ops, config, window)),
                 (q, k, v))
-        return _windowed_attention(q, k, v, config, window)
+        return _tagged(_windowed_attention(q, k, v, config, window))
     if config.pos_embed == "alibi":
-        return _alibi_attention(q, k, v, config)
+        return _tagged(_alibi_attention(q, k, v, config))
     if config.sequence_parallel:
         from ..parallel.mesh import SEQ_AXIS, get_mesh_manager
         mm = get_mesh_manager(optional=True)
         if mm is not None and mm.mesh.shape.get(SEQ_AXIS, 1) > 1:
             from ..parallel.sequence import sp_attention
-            return sp_attention(q, k, v, impl=config.sequence_parallel,
-                                causal=True, mesh=mm.mesh)
+            return _tagged(sp_attention(q, k, v, impl=config.sequence_parallel,
+                                        causal=True, mesh=mm.mesh))
     if config.sparse_attention is not None:
         from ..ops.pallas.block_sparse_attention import block_sparse_attention
         layout = config.sparse_attention.make_layout(q.shape[1])
-        return block_sparse_attention(q, k, v, layout,
-                                      block=config.sparse_attention.block,
-                                      causal=True)
+        return _tagged(block_sparse_attention(
+            q, k, v, layout, block=config.sparse_attention.block,
+            causal=True))
     from ..ops.pallas import flash_attention, mha_reference
     if config.use_flash_attention:
         # pallas kernel on TPU; internally falls back to the dense
         # reference on other backends or non-tiling/short shapes
         return flash_attention(q, k, v, causal=True,
                                sm_scale=config.attn_softmax_scale)
-    return mha_reference(q, k, v, causal=True,
-                         sm_scale=config.attn_softmax_scale)
+    return _tagged(mha_reference(q, k, v, causal=True,
+                                 sm_scale=config.attn_softmax_scale))
 
 
 def _wdot(spec, x, w, out_dtype, preferred_element_type=None):
@@ -445,6 +450,42 @@ def _wdot(spec, x, w, out_dtype, preferred_element_type=None):
                            preferred_element_type or out_dtype)
     return jnp.einsum(spec, x, w.astype(out_dtype),
                       preferred_element_type=preferred_element_type)
+
+
+def _self_attention(x, p, config: GPTConfig, positions=None, window=None):
+    """qkv projection + causal attention of one block: [B,S,d] → the
+    attention output, ``[B,S,H,Dh]`` or (the packed path) ``[B,S,H*Dh]``;
+    :func:`attn_project` reads either.
+
+    Where nothing stands between the product and the kernel (learned or no
+    position embedding in the block, no window, no sequence or sparse
+    variant, float weights whose heads no mesh axis splits) the product is
+    written as rows ``[B,S,3*H*Dh]`` and the flash kernels read q, k, v out
+    of it in place: no slice, no transpose, and one array's gradient back
+    into the weight product."""
+    from ..parallel.mesh import MODEL_AXIS, get_mesh_manager
+    mm = get_mesh_manager(optional=True)
+    if (window is None and config.use_flash_attention
+            and config.pos_embed not in ("rotary", "alibi")
+            and not config.sequence_parallel
+            and config.sparse_attention is None
+            and isinstance(p["wqkv"], jax.Array)
+            and isinstance(p["wo"], jax.Array)
+            and (mm is None or mm.mesh.shape.get(MODEL_AXIS, 1) == 1)):
+        from ..ops.pallas import flash_attention_packed
+        cdt = config.dtype
+        with jax.named_scope("qkv"):
+            h = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
+            qkv = jnp.einsum(
+                "bsd,dk->bsk", h,
+                p["wqkv"].astype(cdt).reshape(config.d_model, -1)) \
+                + p["bqkv"].astype(cdt).reshape(-1)
+        with jax.named_scope("attention"):
+            return flash_attention_packed(
+                qkv, config.n_head, causal=True,
+                sm_scale=config.attn_softmax_scale)
+    q, k, v = qkv_proj(x, p, config, positions=positions)
+    return _attention(q, k, v, config, window=window)
 
 
 @jax.named_scope("qkv")
@@ -472,6 +513,9 @@ def attn_project(attn, p, config: GPTConfig):
     """Attention output projection W_o·attn + b_o (no residual) — the one
     definition every train/inference/MoE path shares."""
     cdt = config.dtype
+    if attn.ndim == 3:      # rows [B,S,H*Dh], as the packed flash path leaves them
+        return jnp.einsum("bsk,kd->bsd", attn, p["wo"].astype(cdt).reshape(
+            -1, config.d_model)) + p["bo"].astype(cdt)
     return _wdot("bshe,hed->bsd", attn, p["wo"], cdt) + p["bo"].astype(cdt)
 
 
@@ -515,8 +559,7 @@ def _attn_residual(x, layer_params, config: GPTConfig, positions=None,
     expert layer instead of mlp_residual.
     """
     p = layer_params
-    q, k, v = qkv_proj(x, p, config, positions=positions)
-    attn = _attention(q, k, v, config, window=window)
+    attn = _self_attention(x, p, config, positions=positions, window=window)
     return attn_out_residual(x, attn, p, config, dropout_key)
 
 
@@ -529,8 +572,8 @@ def _block(x, layer_params, config: GPTConfig, positions=None,
     if config.parallel_residual:
         # NeoX: both sublayers read the SAME input; residual sums them
         p = layer_params
-        q, k, v = qkv_proj(x, p, config, positions=positions)
-        attn = _attention(q, k, v, config, window=window)
+        attn = _self_attention(x, p, config, positions=positions,
+                               window=window)
         return x + _dropout(attn_project(attn, p, config),
                             config.dropout, k_attn) \
             + mlp_out(x, p, config, k_mlp)

@@ -12,13 +12,15 @@ reference), so the whole package is CI-testable on CPU.
 """
 
 from .block_sparse_attention import block_sparse_attention, sparse_mha_reference
-from .flash_attention import flash_attention, mha_reference
+from .flash_attention import (flash_attention, flash_attention_packed,
+                              mha_reference)
 from .fused_adam import fused_adam_step
 from .fused_lamb import fused_lamb_step
 from .quantizer import dequantize, quantize
 
 __all__ = [
     "flash_attention",
+    "flash_attention_packed",
     "mha_reference",
     "block_sparse_attention",
     "sparse_mha_reference",

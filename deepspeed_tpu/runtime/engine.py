@@ -1049,11 +1049,14 @@ class DeepSpeedEngine:
         def loss_fn(p, b):
             # runs when a step program is traced, not when it runs: how far
             # the flash kernels' causal strips engage in this model's step,
-            # and how its loss head is laid out
+            # how many of their calls read the qkv product in place, and how
+            # its loss head is laid out
             with tally_causal_tiles() as tiles, tally_head() as head:
                 loss = self.module.loss_fn(p, b)
             for name, n in zip((MetricName.FLASH_CAUSAL_TILES_VISITED,
                                 MetricName.FLASH_CAUSAL_TILES_SQUARE,
+                                MetricName.FLASH_CALLS,
+                                MetricName.FLASH_CALLS_TOKEN_MAJOR_PACKED,
                                 MetricName.HEAD_LOGIT_PRODUCTS,
                                 MetricName.HEAD_ROW_CHUNKS), tiles + head):
                 self.metrics.counter(name).inc(n)

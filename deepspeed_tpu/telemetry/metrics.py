@@ -72,6 +72,13 @@ class MetricName:
     #: diagonal block was walked in causal strips; visited / square is how
     #: far the strips engage (1.0: not at all)
     FLASH_CAUSAL_TILES_SQUARE = "flash.causal_tiles_square"
+    #: flash kernel call sites in the traced step (forward; the dense
+    #: fallbacks count nothing)
+    FLASH_CALLS = "flash.calls"
+    #: those of them handed the packed qkv product in token-major rows:
+    #: nothing is re-laid around the call, forward, re-forward or backward
+    #: (packed / calls is 1.0 where every layer's attention engages)
+    FLASH_CALLS_TOKEN_MAJOR_PACKED = "flash.calls_token_major_packed"
     #: vocabulary-sized products the traced step's loss head holds, forward
     #: and backward (``models.gpt.tally_head``: 3 a head; over chunks of the
     #: sequence its own backward rule fixes them, of whole logits the

@@ -120,6 +120,15 @@ def report(args) -> int:
                 m["flash.causal_tiles_visited"]
                 / m["flash.causal_tiles_square"], 4)
             if m.get("flash.causal_tiles_square") else None,
+            # flash kernel call sites of the traced step, and the share of
+            # them that read the packed qkv product in token-major rows
+            # (1.0: nothing is re-laid around any call)
+            "flash_calls": m.get("flash.calls"),
+            "flash_calls_token_major_packed":
+                m.get("flash.calls_token_major_packed"),
+            "flash_packed_call_ratio": round(
+                m.get("flash.calls_token_major_packed", 0)
+                / m["flash.calls"], 4) if m.get("flash.calls") else None,
             # the loss head of the traced step: vocabulary-sized products
             # it holds and the chunks of the sequence it walks (1: plain)
             "head_logit_products": m.get("head.logit_products"),
@@ -182,6 +191,11 @@ def report(args) -> int:
                       f"{r['flash_causal_tiles_square']} = "
                       f"{r['flash_causal_tile_ratio']}"
                       if r["flash_causal_tile_ratio"] else "") + (
+                      ", flash calls packed token-major / all "
+                      f"{r['flash_calls_token_major_packed']} / "
+                      f"{r['flash_calls']} = "
+                      f"{r['flash_packed_call_ratio']}"
+                      if r["flash_calls"] else "") + (
                       f", head products {r['head_logit_products']} in "
                       f"{r['head_row_chunks']} chunk(s)"
                       if r["head_row_chunks"] else ""))

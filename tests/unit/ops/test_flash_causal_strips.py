@@ -104,7 +104,9 @@ def test_flash_causal_strips_leave_other_callers_alone(kind):
 def test_causal_tile_plan_counts_what_the_kernels_visit(pallas_interpret,
                                                         monkeypatch, kind):
     """``causal_tile_plan`` against an instrumented run: every score product
-    the kernels execute reports its area, forward and (fused) backward."""
+    the kernels execute reports its area, forward and (fused) backward.  The
+    plan counts one head; the two heads of a 128-lane block each make their
+    own products."""
     fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
     if kind in _NOT_ENGAGING:
         Sq, Sk, bq, bk, kw, (lens, window) = _not_engaging(kind)
@@ -123,7 +125,8 @@ def test_causal_tile_plan_counts_what_the_kernels_visit(pallas_interpret,
 
     monkeypatch.setattr(fa, "_scores", counted)
     ks = jax.random.split(jax.random.PRNGKey(12), 3)
-    q, k, v = (jax.random.normal(kk, (1, s, 1, 64), jnp.float32)
+    heads = 2
+    q, k, v = (jax.random.normal(kk, (1, s, heads, 64), jnp.float32)
                for kk, s in zip(ks, (Sq, Sk, Sk)))
     with fa.tally_causal_tiles() as tally:
         grads = jax.grad(lambda a, b, c: jnp.sum(fa.flash_attention(
@@ -133,6 +136,6 @@ def test_causal_tile_plan_counts_what_the_kernels_visit(pallas_interpret,
     jax.effects_barrier()
     visited, square = fa.causal_tile_plan(Sq, Sk, bq, bk, kw["causal"], lens,
                                           window)
-    assert area[0] == visited * fa.SUB_TILE ** 2
+    assert area[0] == heads * visited * fa.SUB_TILE ** 2
     assert visited / square == ratio
-    assert tally == [visited, square]
+    assert tally == [visited, square, 1, 0]     # one call, three arrays

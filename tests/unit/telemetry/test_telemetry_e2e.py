@@ -221,7 +221,10 @@ def test_report_prints_how_far_the_flash_causal_strips_engage(
         tmp_path, capsys, monkeypatch, model, seq, ratio):
     """The engine records ``flash_attention.causal_tile_plan`` of the step it
     traces as two counters; the report prints them and their ratio: the
-    train cells' sequence lengths at the default blocks, and a BERT step."""
+    train cells' sequence lengths at the default blocks, and a BERT step.
+    Beside them the kernels' call sites and those that read the packed qkv
+    product in token-major rows: every one in a GPT-2-like step (two heads
+    of 64 pair into a 128-lane block), none in BERT's (three arrays)."""
     monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
     from deepspeed_tpu.models import bert
     from deepspeed_tpu.runtime.model import from_gpt
@@ -230,7 +233,7 @@ def test_report_prints_how_far_the_flash_causal_strips_engage(
     tokens = rng.integers(0, 256, size=(1, seq + 1)).astype(np.int32)
     if model == "gpt":
         spec = from_gpt(gpt.GPTConfig(
-            vocab_size=256, max_seq_len=seq, n_layer=1, n_head=1, d_model=64,
+            vocab_size=256, max_seq_len=seq, n_layer=1, n_head=2, d_model=128,
             dtype=jnp.float32, vocab_round_to=128))
         batch = {"tokens": tokens}
     else:
@@ -254,6 +257,11 @@ def test_report_prints_how_far_the_flash_causal_strips_engage(
     assert row["flash_causal_tile_ratio"] == round(ratio, 4)
     assert row["flash_causal_tiles_visited"] == \
         ratio * row["flash_causal_tiles_square"] > 0
+    packed = 1.0 if model == "gpt" else 0.0
+    assert row["flash_calls"] > 0
+    assert row["flash_packed_call_ratio"] == packed
+    assert row["flash_calls_token_major_packed"] == \
+        packed * row["flash_calls"]
     # the loss head of a CPU-sized GPT is the plain single pass; BERT's
     # head is its own and is not counted
     assert (row["head_logit_products"], row["head_row_chunks"]) == \
@@ -261,4 +269,6 @@ def test_report_prints_how_far_the_flash_causal_strips_engage(
     assert mod.main([str(tmp_path)]) == 0
     out = capsys.readouterr().out
     assert f"= {round(ratio, 4)}" in out
+    assert f"flash calls packed token-major / all {row['flash_calls_token_major_packed']} / " \
+        f"{row['flash_calls']} = {packed}" in out
     assert ("head products 3 in 1 chunk(s)" in out) == (model == "gpt")
