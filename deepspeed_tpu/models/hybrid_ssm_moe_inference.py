@@ -14,15 +14,17 @@ own (the one cache family of the tree); this module brings what
   pre-activation inputs of the convolution ``[L_ssm, B, K - 1, d_conv]``.
   It is the cache's ``state`` leaf; the slot ops insert, read and zero it
   with the banks;
-- the **step**: one segment per run of consecutive layers of one kind, in
-  depth order (``config.runs``).  An attention layer goes through the
-  scan's ``attend`` at its index among the attention layers; a state-space
-  layer advances layer ``j`` of the state stacks in place: one token a live
-  slot through ``ssm_decode_step`` (a freed slot neither steps nor moves),
-  a chunk through ``ssd_chunk_scan`` to the state after the chunk's last
-  REAL token (``valid``: a padded tail takes ``dt = 0`` and the convolution
-  tail kept is that of the last real tokens).  Every layer ends in the
-  expert layer, whose pair counts go to ``cache.stats`` beside the state
+- the **step**: one segment per run, in depth order (``config.units``: a
+  unit of kinds repeated, the scan's body the unit's layers in order).  An
+  attention layer goes through the scan's ``attend`` at its index among the
+  attention layers; a state-space layer advances layer ``j`` of the state
+  stacks in place: one token a live slot through ``ssm_decode_step`` (a
+  freed slot neither steps nor moves), a chunk through ``ssd_chunk_scan``
+  to the state after the chunk's last REAL token (``valid``: a padded tail
+  takes ``dt = 0`` and the convolution tail kept is that of the last real
+  tokens); an expert layer owns no row and no state.  The expert layer
+  (every layer's second half, or a layer of its own: ``config.mixer_ffn``)
+  adds its pair counts to ``cache.stats`` and a state-space layer its state
   counters (``STATE_COUNTERS``), each group where ``stats_groups`` says.
 
 Not supported, refused where it is asked for (``UNSUPPORTED``): the int8
@@ -64,11 +66,6 @@ UNSUPPORTED = {
 STATE_COUNTERS = ("ssm_rows_stepped", "scan_tokens_real",
                   "scan_tokens_padded")
 
-#: the routed experts' matrices: never an ``xs`` of the layer scan (a slice
-#: of a stack handed to a Pallas call is copied out first); a segment's body
-#: closes over its run's whole stacks and reads its layer in place
-_ROUTED = ("w_gu", "w_down")
-
 
 def stats_groups(config: HybridSSMMoEConfig) -> Dict[str, slice]:
     """Where each group of this family's device counters lies in
@@ -96,25 +93,28 @@ def _mamba_mixer(x, p, j, cache: KVCache, valid, work,
     B, S, _ = x.shape
     h_stack, tails = cache.state
     z, u, dt = model.ssm_inputs(x, p, config)
-    tail = lax.dynamic_index_in_dim(tails, j, 0, keepdims=False)
     with jax.named_scope("ssm_conv"):
+        tail = lax.dynamic_index_in_dim(tails, j, 0, keepdims=False)
         u_act, tail = ssm.causal_conv(u, tail, p["conv_w"], p["conv_b"],
                                       valid)
-    tails = lax.dynamic_update_slice(tails, tail[None], (j, 0, 0, 0))
-    v, dt, a, Bm, Cm = model.ssm_scan_inputs(u_act, dt, p, config)
+        tails = lax.dynamic_update_slice(tails, tail[None], (j, 0, 0, 0))
+        v, dt, a, Bm, Cm = model.ssm_scan_inputs(u_act, dt, p, config)
     real = jnp.sum(valid)
     if S == 1:
         P = config.ssm_head_dim
         wide = lambda t: jnp.repeat(t[:, 0], P, axis=-1)      # [B, H] -> HP
-        y, h_stack = ssm.ssm_decode_step(
-            h_stack, j, wide(dt) * v.reshape(B, -1).astype(jnp.float32),
-            wide(jnp.exp(dt * a)), Bm[:, 0], Cm[:, 0], active=valid > 0,
-            work=work)
+        with jax.named_scope("ssm_decode_step"):
+            y, h_stack = ssm.ssm_decode_step(
+                h_stack, j, wide(dt) * v.reshape(B, -1).astype(jnp.float32),
+                wide(jnp.exp(dt * a)), Bm[:, 0], Cm[:, 0], active=valid > 0,
+                work=work, groups=config.ssm_groups)
         y = y[:, None]
         counters = jnp.stack([real, 0, 0])
     else:
-        y, h_stack = ssm.ssd_chunk_scan(h_stack, j, v, dt, a, Bm, Cm,
-                                        valid=valid, chunk=config.ssm_chunk)
+        with jax.named_scope("ssd_chunk_scan"):
+            y, h_stack = ssm.ssd_chunk_scan(
+                h_stack, j, v, dt, a, Bm, Cm, valid=valid,
+                chunk=config.ssm_chunk, groups=config.ssm_groups)
         counters = jnp.stack([0, real, B * S - real])
     return (model.ssm_output(x, y, v, z, p, config), (h_stack, tails),
             counters.astype(jnp.int32))
@@ -124,31 +124,44 @@ def _step(params: PyTree, config: HybridSSMMoEConfig, valid):
     segments = []
     # a tick's work list, built once for all its state-space layers
     work = ssm.live_rows(valid > 0, valid.shape[0])
-    no_state = jnp.zeros((len(STATE_COUNTERS),), jnp.int32)
     groups = stats_groups(config)
-    for (kind, first, n), run in zip(config.runs, params["runs"]):
-        routed = {k: run[k] for k in _ROUTED}
+    # the routed experts' matrices are never an ``xs`` of the layer scan (a
+    # slice of a stack handed to a Pallas call is copied out first): a
+    # segment's body closes over its run's whole stacks and reads its layer
+    # in place
+    routed_keys = config.routed_keys
 
-        def ffn(x, p, i, cache, counters, routed=routed):
-            x, counts = model.expert_ffn(x, p, config, experts=routed,
+    def layer(x, kind, p, experts, i, j, attend, cache):
+        """Layer ``j`` of its kind, repetition ``i`` of its run."""
+        stats = cache.stats
+        if kind == MAMBA:
+            x, state, counters = _mamba_mixer(x, p, j, cache, valid, work,
+                                              config)
+            cache = dataclasses.replace(cache, state=state)
+            stats = stats.at[groups["state_steps"]].add(counters)
+        elif kind == ATTENTION:
+            a, cache = attend(x, p, j, cache)
+            x = model.attention_output(x, a, p, config)
+        if model.has_ffn(kind, config):
+            x, counts = model.expert_ffn(x, p, config, experts=experts,
                                          layer=i)
-            return x, dataclasses.replace(
-                cache, stats=cache.stats.at[groups["moe_pairs"]].add(counts)
-                .at[groups["state_steps"]].add(counters))
+            stats = stats.at[groups["moe_pairs"]].add(counts)
+        return x, dataclasses.replace(cache, stats=stats)
 
-        def mamba_body(x, p, i, attend, cache, first=first, ffn=ffn):
-            x, state, counters = _mamba_mixer(x, p, first + i, cache, valid,
-                                              work, config)
-            return ffn(x, p, i, dataclasses.replace(cache, state=state),
-                       counters)
+    for (unit, firsts, n), run in zip(config.units, params["runs"]):
+        parts = model.run_parts(run)
+        routed = [{k: p[k] for k in routed_keys if k in p} for p in parts]
 
-        def attention_body(x, p, i, attend, cache, first=first, ffn=ffn):
-            a, cache = attend(x, p, first + i, cache)
-            return ffn(model.attention_output(x, a, p, config), p, i, cache,
-                       no_state)
+        def body(x, ps, i, attend, cache, unit=unit, firsts=firsts,
+                 routed=routed):
+            for kind, first, p, experts in zip(unit, firsts, ps, routed):
+                x, cache = layer(x, kind, p, experts, i,
+                                 first + i * unit.count(kind), attend, cache)
+            return x, cache
 
-        segments.append(({k: v for k, v in run.items() if k not in _ROUTED},
-                         mamba_body if kind == MAMBA else attention_body))
+        segments.append((tuple({k: v for k, v in p.items()
+                                if k not in routed_keys} for p in parts),
+                         body))
     return segments
 
 

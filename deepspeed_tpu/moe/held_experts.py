@@ -37,6 +37,10 @@ from jax import lax
 
 from ..ops.pallas.utils import interpret_mode, use_pallas
 
+#: an expert's form: three matrices, ``W_d (silu(W_g h) * W_u h)`` with gate
+#: beside up in ``w_gu``; or two, ``W_d relu(W_u h)^2`` with ``w_up``
+SWIGLU, RELU2 = "swiglu", "relu2"
+
 #: the grouped matmul's (rows, contraction, columns) tile on the chip at its
 #: largest: 2 MB of an expert's matrix a step, streamed behind the product
 #: before it
@@ -137,14 +141,15 @@ def pair_counts(per_expert, routed: int):
 
 
 def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
-                     held: Sequence[int], n_experts: int, layer=None
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     held: Sequence[int], n_experts: int, layer=None,
+                     form: str = SWIGLU) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' part of the layer's result, and the pair counts.
 
-    ``h`` [T, d]; ``p["w_gu"]`` [n_held, d, 2f] (gate beside up) and
-    ``p["w_down"]`` [n_held, f, d], the held experts' SwiGLU matrices in
-    the order of ``held``; with ``layer`` (a layer scan's index) both are
-    the whole stacks ``[layers, n_held, ...]``, read in place.  Returns ``(out [T, d], counts [n_held] int32)``:
+    ``h`` [T, d]; ``p["w_gu"]`` [n_held, d, 2f] (gate beside up; ``form``
+    ``RELU2``: ``p["w_up"]`` [n_held, d, f]) and ``p["w_down"]`` [n_held, f,
+    d], the held experts' matrices in the order of ``held``; with ``layer``
+    (a layer scan's index) both are the whole stacks ``[layers, n_held,
+    ...]``, read in place.  Returns ``(out [T, d], counts [n_held] int32)``:
     ``sum_{i in sel, i held} w_i E_i(h)`` and the pairs each held expert
     took."""
     T, d = h.shape
@@ -158,10 +163,15 @@ def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
                      dtype=jnp.int32)
     with jax.named_scope("moe_routed"):
         rows = h[order // k]                                   # [T*k, d]
-        gu = _grouped(rows, p["w_gu"], counts, layer)
-        f = gu.shape[-1] // 2
-        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-               * gu[:, f:].astype(jnp.float32)).astype(h.dtype)
+        if form == RELU2:
+            up = _grouped(rows, p["w_up"], counts, layer)
+            act = jnp.square(jax.nn.relu(up.astype(jnp.float32))
+                             ).astype(h.dtype)
+        else:
+            gu = _grouped(rows, p["w_gu"], counts, layer)
+            f = gu.shape[-1] // 2
+            act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+                   * gu[:, f:].astype(jnp.float32)).astype(h.dtype)
         y = _grouped(act, p["w_down"], counts, layer)              # [T*k, d]
         w = jnp.where(flat < n_held, routing.weights.reshape(-1), 0.0)[order]
         y = jnp.where(w[:, None] != 0, y.astype(jnp.float32) * w[:, None],

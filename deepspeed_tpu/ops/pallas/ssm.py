@@ -6,13 +6,15 @@ length:
 
     H_t = exp(dt_t a) H_{t-1} + dt_t v_t B_t^T        y_t = H_t C_t
 
-``a`` and ``dt`` are per head, ``B`` and ``C`` are shared by all heads (one
-group).  The pool of a serving batch is ``[L_ssm, B, d_state, heads *
+``a`` and ``dt`` are per head; ``B`` and ``C`` are shared by the heads of a
+GROUP (``groups`` of them, consecutive heads each: head ``h`` of ``H`` reads
+group ``h // (H / groups)``; one group: shared by all).  The pool of a serving batch is ``[L_ssm, B, d_state, heads *
 head_dim]``: a layer's state of a slot is one ``[N, H*P]`` matrix with the
 ``(head, channel)`` pairs on the lanes (``H*P`` is the width of a token's
 activation row, so ``y``, ``v`` and a head's decay, repeated over its
 channels, are plain rows of it) and ``d_state`` on the sublanes (``B_t`` and
-``C_t`` are one column, shared by every head).  The mathematics' ``[heads,
+``C_t`` are one column a group, shared by the group's ``H*P / groups``
+lanes; they come as ``[.., groups * d_state]``, group by group).  The mathematics' ``[heads,
 head_dim, d_state]`` is that matrix transposed; stored that way a head's
 64-wide channel axis would be half a lane row and ``v_t`` would have to be
 re-laid as a column for every head.
@@ -94,8 +96,21 @@ def live_rows(active, B: int):
         jnp.sum(active, dtype=jnp.int32).reshape(1)
 
 
-def _tiles(N: int, HP: int) -> bool:
-    return use_pallas() and HP % LANES == 0 and N % 8 == 0
+def _tiles(N: int, HP: int, groups: int = 1) -> bool:
+    """Whether the kernels take the shape: whole lane rows, and with more
+    than one group whole lane rows a group, of state and of ``B`` / ``C``."""
+    return use_pallas() and HP % LANES == 0 and N % 8 == 0 and (
+        groups == 1 or (HP % (groups * LANES) == 0 and N % LANES == 0))
+
+
+def _group_blocks(HP: int, cb: int, groups: int):
+    """How a column block of ``cb`` lanes meets the groups' ``HP / groups``
+    lanes: ``(lanes a group, groups a block, block index of ``B`` / ``C``
+    for column block c)``.  A block holds whole groups or lies inside one."""
+    gl = HP // groups
+    per = max(1, cb // gl)
+    assert cb % gl == 0 or gl % cb == 0, (HP, cb, groups)
+    return gl, per, lambda c: (c * cb) // (gl * per)
 
 
 def _column(row, N: int):
@@ -106,11 +121,12 @@ def _column(row, N: int):
 # ------------------------------------------------------------- decode step
 
 def _decode_kernel(rows_ref, n_ref, layer_ref, x_ref, decay_ref, b_ref,
-                   c_ref, h_ref, y_ref, ho_ref, *, nc: int):
+                   c_ref, h_ref, y_ref, ho_ref, *, nc: int, gl: int):
     """One grid step: block ``s % nc`` of the columns of live slot ``rows[s
     // nc]``.  ``h_ref`` / ``ho_ref`` (N, cb) are the same block of the
     aliased stack; ``x_ref`` (``dt v``) and ``decay_ref`` (``exp(dt a)``)
-    are (1, cb) rows, ``b_ref`` / ``c_ref`` (1, N).  Steps past the live
+    are (1, cb) rows, ``b_ref`` / ``c_ref`` (1, groups in the block * N);
+    ``gl`` the lanes of a group.  Steps past the live
     ones (a static grid's tail) stay on the last live block and do nothing;
     with no live slot at all the one block the grid visits is copied
     through."""
@@ -123,20 +139,23 @@ def _decode_kernel(rows_ref, n_ref, layer_ref, x_ref, decay_ref, b_ref,
 
     @pl.when(pl.program_id(0) < n_ref[0] * nc)
     def _step():
-        bb = _column(b_ref[...].astype(jnp.float32), N)
-        cc = _column(c_ref[...].astype(jnp.float32), N)
-        for j in range(cb // LANES):
-            sl = slice(j * LANES, (j + 1) * LANES)
-            h = decay_ref[:, sl] * h_ref[:, sl] + bb * x_ref[:, sl]
-            ho_ref[:, sl] = h
-            y_ref[:, sl] = jnp.sum(h * cc, axis=0, keepdims=True)
+        for g in range(b_ref.shape[1] // N):
+            of = slice(g * N, (g + 1) * N)
+            bb = _column(b_ref[:, of].astype(jnp.float32), N)
+            cc = _column(c_ref[:, of].astype(jnp.float32), N)
+            for j in range(g * gl // LANES, min(cb, (g + 1) * gl) // LANES):
+                sl = slice(j * LANES, (j + 1) * LANES)
+                h = decay_ref[:, sl] * h_ref[:, sl] + bb * x_ref[:, sl]
+                ho_ref[:, sl] = h
+                y_ref[:, sl] = jnp.sum(h * cc, axis=0, keepdims=True)
 
 
-def _decode_pallas(state, layer, x, decay, Bm, Cm, work):
+def _decode_pallas(state, layer, x, decay, Bm, Cm, work, groups):
     L, B, N, HP = state.shape
     cb = next(c for c in (DECODE_BLOCK, 2048, 1024, 512, 256, LANES)
               if HP % c == 0)
     nc = HP // cb
+    gl, per, group_block = _group_blocks(HP, cb, groups)
     rows, n = work
     interpret = interpret_mode()
 
@@ -148,14 +167,15 @@ def _decode_pallas(state, layer, x, decay, Bm, Cm, work):
         return (rows_ref[s // nc], 0, s % nc)
 
     def vec_idx(s, rows_ref, n_ref, layer_ref):
-        return (rows_ref[at(s, n_ref) // nc], 0, 0)
+        s = at(s, n_ref)
+        return (rows_ref[s // nc], 0, group_block(s % nc))
 
     def state_idx(s, rows_ref, n_ref, layer_ref):
         s = at(s, n_ref)
         return (layer_ref[0], rows_ref[s // nc], 0, s % nc)
 
     row_spec = pl.BlockSpec((None, 1, cb), row_idx)
-    vec_spec = pl.BlockSpec((None, 1, N), vec_idx)
+    vec_spec = pl.BlockSpec((None, 1, per * N), vec_idx)
     state_spec = pl.BlockSpec((None, None, N, cb), state_idx)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -165,7 +185,8 @@ def _decode_pallas(state, layer, x, decay, Bm, Cm, work):
         in_specs=[row_spec, row_spec, vec_spec, vec_spec, state_spec],
         out_specs=[row_spec, state_spec])
     y, state = pl.pallas_call(
-        functools.partial(_decode_kernel, nc=nc), grid_spec=grid_spec,
+        functools.partial(_decode_kernel, nc=nc, gl=gl),
+        grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, 1, HP), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # operand 7 (after the three prefetched scalars) is the stack
@@ -175,33 +196,40 @@ def _decode_pallas(state, layer, x, decay, Bm, Cm, work):
         interpret=interpret, name="ssm_decode_step")(
             rows, n, jnp.asarray(layer, jnp.int32).reshape(1),
             x.reshape(B, 1, HP), decay.reshape(B, 1, HP),
-            Bm.reshape(B, 1, N), Cm.reshape(B, 1, N), state)
+            Bm.reshape(B, 1, groups * N), Cm.reshape(B, 1, groups * N),
+            state)
     return y[:, 0], state
 
 
-def ssm_decode_step(state, layer, x, decay, Bm, Cm, active=None, work=None):
+def ssm_decode_step(state, layer, x, decay, Bm, Cm, active=None, work=None,
+                    groups: int = 1):
     """One token a live slot through layer ``layer`` of the state stack.
 
     ``state`` [L, B, N, H*P] float32 (donate it: the result aliases it);
     ``x`` [B, H*P] float32, ``dt v``; ``decay`` [B, H*P] float32, ``exp(dt
-    a)`` with a head's value on each of its channels; ``Bm``, ``Cm`` [B, N].
-    ``active`` [B] bool (default: every slot); ``work`` is ``live_rows`` of
+    a)`` with a head's value on each of its channels; ``Bm``, ``Cm`` [B,
+    groups * N], group by group.  ``active`` [B] bool (default: every slot); ``work`` is ``live_rows`` of
     it, built once by a caller that steps many layers.  Returns ``(y [B,
     H*P] float32, state)``; a dead slot's ``y`` is zero and its state
     untouched."""
     L, B, N, HP = state.shape
     x = x.astype(jnp.float32)
     decay = decay.astype(jnp.float32)
-    if _tiles(N, HP):
+    if _tiles(N, HP, groups):
         if work is None:
             work = live_rows(active, B)
-        y, state = _decode_pallas(state, layer, x, decay, Bm, Cm, work)
+        y, state = _decode_pallas(state, layer, x, decay, Bm, Cm, work,
+                                  groups)
     else:
         h0 = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
-        h = decay[:, None, :] * h0 \
-            + Bm.astype(jnp.float32)[:, :, None] * x[:, None, :]
-        y = jnp.einsum("bnc,bn->bc", h, Cm.astype(jnp.float32),
-                       precision=lax.Precision.HIGHEST)
+        # [B, N, groups, lanes a group]: a group's column on its lanes
+        bm = jnp.swapaxes(Bm.astype(jnp.float32).reshape(B, groups, N), 1, 2)
+        cm = jnp.swapaxes(Cm.astype(jnp.float32).reshape(B, groups, N), 1, 2)
+        grouped = lambda t: t.reshape(t.shape[:-1] + (groups, HP // groups))
+        h = decay[:, None, :] * h0 + (
+            bm[..., None] * grouped(x)[:, None]).reshape(B, N, HP)
+        y = jnp.einsum("bngc,bng->bgc", grouped(h), cm,
+                       precision=lax.Precision.HIGHEST).reshape(B, HP)
         if active is not None:
             h = jnp.where(active[:, None, None], h, h0)
         state = lax.dynamic_update_slice(state, h[None], (layer, 0, 0, 0))
@@ -213,29 +241,35 @@ def ssm_decode_step(state, layer, x, decay, Bm, Cm, active=None, work=None):
 # -------------------------------------------------------------- chunk scan
 
 def _scan_kernel(layer_ref, x_ref, cs_ref, cst_ref, b_ref, bt_ref, c_ref,
-                 h_ref, y_ref, ho_ref, hs_ref, *, P: int):
+                 h_ref, y_ref, ho_ref, hs_ref, *, P: int, gl: int):
     """One grid step: sub-chunk ``q`` (the innermost, sequential axis) of
     column block ``c`` of row ``b``.  ``x_ref`` (Q, cb) float32 ``dt v``;
     ``cs_ref`` (Q, hb) and ``cst_ref`` (hb, Q) the inclusive cumulative
     ``dt a`` of this sub-chunk for the block's ``hb`` heads, both ways up;
-    ``b_ref`` / ``c_ref`` (Q, N), ``bt_ref`` (N, Q).  ``hs_ref`` (N, cb)
+    ``b_ref`` / ``c_ref`` (Q, groups in the block * N), ``bt_ref`` the
+    former transposed; ``gl`` the lanes of a group.  ``hs_ref`` (N, cb)
     carries the state from sub-chunk to sub-chunk; it is read from the
     stack on the first and written back on the last."""
     q = pl.program_id(2)
     Q, cb = x_ref.shape
+    N = hs_ref.shape[0]
     per = LANES // P                     # heads in one 128-lane slab
 
     @pl.when(q == 0)
     def _load():
         hs_ref[...] = h_ref[...]
 
-    cm = c_ref[...]
-    g = jnp.dot(cm, bt_ref[...], preferred_element_type=jnp.float32)
-    c32, bt32 = cm.astype(jnp.float32), bt_ref[...].astype(jnp.float32)
     causal = lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
         lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     head_of = lax.broadcasted_iota(jnp.int32, (Q, LANES), 1) // P
     for j in range(cb // LANES):
+        if j * LANES % gl == 0:          # the slab opens a group: its C B^T
+            of = slice(j * LANES // gl * N, (j * LANES // gl + 1) * N)
+            cm = c_ref[:, of]
+            g = jnp.dot(cm, bt_ref[of, :],
+                        preferred_element_type=jnp.float32)
+            c32 = cm.astype(jnp.float32)
+            bt32 = bt_ref[of, :].astype(jnp.float32)
         sl = slice(j * LANES, (j + 1) * LANES)
         x = x_ref[:, sl]
         y = jnp.zeros((Q, LANES), jnp.float32)
@@ -265,16 +299,17 @@ def _scan_kernel(layer_ref, x_ref, cs_ref, cst_ref, b_ref, bt_ref, c_ref,
         ho_ref[...] = hs_ref[...]
 
 
-def _scan_pallas(state, layer, x, cs, Bm, Cm, Q: int, P: int):
+def _scan_pallas(state, layer, x, cs, Bm, Cm, Q: int, P: int, groups: int):
     L, B, N, HP = state.shape
     S = x.shape[1]
     nq = S // Q
     cb = next(c for c in (SCAN_BLOCK, 512, 256, LANES) if HP % c == 0)
     hb = cb // P
     nc = HP // cb
+    gl, per, group_block = _group_blocks(HP, cb, groups)
     # the block's heads' cumulative decays, both ways up: [B, nq, nc, Q, hb]
     cs5 = cs.reshape(B, nq, Q, nc, hb).transpose(0, 1, 3, 2, 4)
-    bt = Bm.reshape(B, nq, Q, N).transpose(0, 1, 3, 2)
+    bt = Bm.reshape(B, nq, Q, groups * N).transpose(0, 1, 3, 2)
 
     def seq_idx(b, c, q, *_):
         return (b, q, c)
@@ -283,7 +318,7 @@ def _scan_pallas(state, layer, x, cs, Bm, Cm, Q: int, P: int):
         return (b, q, c, 0, 0)
 
     def shared_idx(b, c, q, *_):
-        return (b, q, 0)
+        return (b, q, group_block(c))
 
     def state_idx(b, c, q, layer_ref):
         return (layer_ref[0], b, 0, c)
@@ -295,15 +330,15 @@ def _scan_pallas(state, layer, x, cs, Bm, Cm, Q: int, P: int):
             pl.BlockSpec((None, Q, cb), seq_idx),
             pl.BlockSpec((None, None, None, Q, hb), head_idx),
             pl.BlockSpec((None, None, None, hb, Q), head_idx),
-            pl.BlockSpec((None, Q, N), shared_idx),
-            pl.BlockSpec((None, None, N, Q),
-                         lambda b, c, q, *_: (b, q, 0, 0)),
-            pl.BlockSpec((None, Q, N), shared_idx),
+            pl.BlockSpec((None, Q, per * N), shared_idx),
+            pl.BlockSpec((None, None, per * N, Q),
+                         lambda b, c, q, *_: (b, q, group_block(c), 0)),
+            pl.BlockSpec((None, Q, per * N), shared_idx),
             state_spec],
         out_specs=[pl.BlockSpec((None, Q, cb), seq_idx), state_spec],
         scratch_shapes=[pltpu.VMEM((N, cb), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_scan_kernel, P=P), grid_spec=grid_spec,
+        functools.partial(_scan_kernel, P=P, gl=gl), grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, S, HP), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         input_output_aliases={7: 1},
@@ -315,30 +350,36 @@ def _scan_pallas(state, layer, x, cs, Bm, Cm, Q: int, P: int):
             cs5.transpose(0, 1, 2, 4, 3), Bm, bt, Cm, state)
 
 
-def _scan_xla(state, layer, x, cs, Bm, Cm, Q: int, P: int):
+def _scan_xla(state, layer, x, cs, Bm, Cm, Q: int, P: int, groups: int):
     """The same sub-chunks in ``jax.numpy``: a ``lax.scan`` over them."""
     L, B, N, HP = state.shape
     S = x.shape[1]
-    H, nq = HP // P, S // Q
+    H, nq, G = HP // P, S // Q, groups
     hi = lax.Precision.HIGHEST
     h0 = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
     causal = jnp.tril(jnp.ones((Q, Q), bool))
 
     def sub(h, xs):
-        x, cs, bm, cm = xs              # [B,Q,HP] [B,Q,H] [B,Q,N] [B,Q,N]
-        bm, cm = bm.astype(jnp.float32), cm.astype(jnp.float32)
-        g = jnp.einsum("btn,bsn->bts", cm, bm, precision=hi)
+        x, cs, bm, cm = xs          # [B,Q,HP] [B,Q,H] [B,Q,G*N] [B,Q,G*N]
+        bm = bm.astype(jnp.float32).reshape(B, Q, G, N)
+        cm = cm.astype(jnp.float32).reshape(B, Q, G, N)
+        g = jnp.einsum("btgn,bsgn->btsg", cm, bm, precision=hi)
         lts = jnp.exp(jnp.minimum(
             cs[:, :, None, :] - cs[:, None, :, :], 0.0))     # [B,t,s,H]
-        m = jnp.where(causal[None, :, :, None], g[..., None] * lts, 0.0)
+        m = jnp.where(causal[None, :, :, None],
+                      jnp.repeat(g, H // G, axis=-1) * lts, 0.0)
         xh = x.reshape(B, Q, H, P)
         y = jnp.einsum("btsh,bshp->bthp", m, xh, precision=hi)
         wide = lambda t: jnp.repeat(t, P, axis=-1)           # [.., H]->HP
+        # a group's lanes apart: [.., G, HP / G]
+        grouped = lambda t: t.reshape(t.shape[:-1] + (G, HP // G))
         y = y.reshape(B, Q, HP) + wide(jnp.exp(cs)) * jnp.einsum(
-            "btn,bnc->btc", cm, h, precision=hi)
+            "btgn,bngc->btgc", cm, grouped(h),
+            precision=hi).reshape(B, Q, HP)
         last = cs[:, -1:]
         h = wide(jnp.exp(last)) * h + jnp.einsum(
-            "bsn,bsc->bnc", bm, x * wide(jnp.exp(last - cs)), precision=hi)
+            "bsgn,bsgc->bngc", bm, grouped(x * wide(jnp.exp(last - cs))),
+            precision=hi).reshape(B, N, HP)
         return h, y
 
     split = lambda t: jnp.moveaxis(
@@ -349,14 +390,15 @@ def _scan_xla(state, layer, x, cs, Bm, Cm, Q: int, P: int):
 
 
 def ssd_chunk_scan(state, layer, v, dt, a, Bm, Cm, valid=None,
-                   chunk: int = 256) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                   chunk: int = 256, groups: int = 1
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """A chunk of every row's prompt through layer ``layer`` of the stack.
 
     ``state`` [L, B, N, H*P] float32: each row starts from what it holds
     there (zeros at the start of a sequence) and is left with the state
     after its last real token.  ``v`` [B, S, H, P]; ``dt`` [B, S, H]
     float32, positive (softplus applied); ``a`` [H] float32, negative;
-    ``Bm``, ``Cm`` [B, S, N].  ``valid`` [B]: the real tokens of each row's
+    ``Bm``, ``Cm`` [B, S, groups * N], group by group.  ``valid`` [B]: the real tokens of each row's
     ``S`` (default all): positions past them take ``dt = 0``.  ``chunk``:
     the sub-chunk of the SSD form.  Returns ``(y [B, S, H*P] float32, ``H_t
     C_t`` at every position (junk past ``valid``), state)``."""
@@ -378,8 +420,8 @@ def ssd_chunk_scan(state, layer, v, dt, a, Bm, Cm, valid=None,
     # the inclusive cumulative dt a inside each sub-chunk
     cs = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(B, Sp // Q, Q, H),
                     axis=2).reshape(B, Sp, H)
-    tiles = _tiles(N, HP) and LANES % P == 0 and Q % 8 == 0 \
+    tiles = _tiles(N, HP, groups) and LANES % P == 0 and Q % 8 == 0 \
         and (Q % LANES == 0 or Sp == Q)
     y, state = (_scan_pallas if tiles else _scan_xla)(
-        state, layer, x, cs, Bm, Cm, Q, P)
+        state, layer, x, cs, Bm, Cm, Q, P, groups)
     return y[:, :S], state

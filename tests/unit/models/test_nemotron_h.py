@@ -1,0 +1,472 @@
+"""The hybrid family's single-part block (``nemotron_h``: a layer is a mixer
+OR the expert layer) against its plain reference at a tiny size on the CPU,
+in float32: the uncached ``apply`` and the slot path (chunked prefill, then
+decode through the per-slot state) against the reference's token-by-token
+recurrence; the grouped state-space kernels against a per-head loop; the
+expert layer's shares against the uncut layer; the runs as repeated units;
+and planted faults, each of which must read over a tolerance (on weights
+drawn ten times louder than the family's, ``LOUD``, so that the layers and
+not the embedding make the logits).
+
+The tolerances.  ``ATOL`` / ``RTOL`` (2e-5, 1e-4) are the Granite family's:
+both sides compute in float32, the program's chunked scan sums a sub-chunk's
+terms in another order than the recurrence and its products run at the
+CPU's default precision, which reads 3e-7 to 4e-6 here on logits of about
+0.6; a fault below reads 1e-3 or more.  The kernels alone are held to 2e-4
+(state and ``y`` of order 1 to 10 over 256 tokens, float64 loop)."""
+
+import contextlib
+import dataclasses
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu
+from benchmarks.chip import nemotron_h_family
+from benchmarks.chip.reference import hybrid_ssm_moe_control as control
+from benchmarks.chip.reference import nemotron_h_reference as reference
+from deepspeed_tpu.models import (cache_family, hybrid_ssm_moe,
+                                  hybrid_ssm_moe_inference)
+from deepspeed_tpu.moe import held_experts
+from deepspeed_tpu.ops.pallas import ssm
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+NAME = "nemotron-3-nano-30b-a3b-ep4"
+CHUNK = 16              # the gateway's prefill chunk; the scan's is 8
+ATOL, RTOL = 2e-5, 1e-4
+LOUD = 0.2
+
+
+def _published():
+    with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
+                           NAME + ".json")) as f:
+        return json.load(f)
+
+
+def _file(**keys):
+    """The benchmark's configuration file at the rehearsal's tiny sizes."""
+    file = _published()
+    with open(os.path.join(ROOT, "tests", "unit", "chipbench", "tiny",
+                           "configs", NAME + ".json")) as f:
+        file.update(json.load(f))
+    return {**file, **keys}
+
+
+def _model(file, seed=0, std=None, **replace):
+    """``std``: weights drawn that much louder than the family's 0.02 (the
+    fault tests: at 0.02 and this width the layers add a thousandth to the
+    logits, and so does any fault in them)."""
+    cfg = dataclasses.replace(nemotron_h_family.build(file),
+                              dtype=jnp.float32, **replace)
+    if std is None:
+        params = nemotron_h_family.init(cfg, jax.random.PRNGKey(seed),
+                                        jnp.float32)
+    else:
+        params = hybrid_ssm_moe.init(
+            dataclasses.replace(cfg, param_dtype=jnp.float32),
+            jax.random.PRNGKey(seed), std=std)
+    # a selection bias large enough to move choices at this size
+    for run in params["runs"]:
+        for part in hybrid_ssm_moe.run_parts(run):
+            if "router_bias" in part:
+                part["router_bias"] = part["router_bias"] * 30
+    return cfg, params
+
+
+def _gateway(cfg, params, **serving):
+    engine = deepspeed_tpu.init_inference(model=(cfg, params),
+                                          config={"dtype": "float32"})
+    gateway = engine.serve(config={"slots": 4, "max_len": 128,
+                                   "prefill_chunk": CHUNK,
+                                   "queue_capacity": 8, **serving})
+    gateway.shutdown(drain=False, timeout=60)
+    return gateway
+
+
+def test_a_layer_is_one_part_and_a_run_is_a_repeated_unit():
+    cfg = nemotron_h_family.build(_published())
+    M, E, A = "mamba", "experts", "attention"
+    # MEMEM*EMEMEM*EMEME: seven runs, three of them scans
+    assert cfg.units == (((M, E), (0, 0), 2), ((M,), (2,), 1),
+                         ((A,), (0,), 1), ((E, M), (2, 3), 3),
+                         ((A,), (1,), 1), ((E, M), (5, 6), 2),
+                         ((E,), (7,), 1))
+    assert [u for u, _ in reference._units(
+        _published()["hybrid_override_pattern"])] == [
+        "ME", "M", "*", "EM", "*", "EM", "E"]
+    assert (cfg.count(M), cfg.count(E), cfg.count(A)) == (8, 8, 2)
+    # the one-kind reading the Granite block has always had
+    one = dataclasses.replace(cfg, layer_types=(M,) * 5 + (A,) + (M,) * 4)
+    assert one.runs == ((M, 0, 5), (A, 0, 1), (M, 5, 4))
+    params = jax.eval_shape(lambda k: nemotron_h_family.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    first = params["runs"][0]
+    assert isinstance(first, list) and len(first) == 2
+    # a mixer holds one norm and no expert layer; an expert layer the other
+    assert "ln1" in first[0] and "ln2" not in first[0] \
+        and "router" not in first[0]
+    assert set(first[1]) == {"ln2", "router", "router_bias", "w_up",
+                             "w_down", "ws_up", "ws_down"}
+    assert first[1]["w_up"].shape == (2, 32, 2688, 1920)
+    assert first[1]["ws_up"].shape == (2, 2688, 3712)
+    assert params["head"].shape == params["wte"].shape == (32768, 2688)
+    axes = hybrid_ssm_moe.logical_axes(cfg)
+    assert jax.tree_util.tree_structure(
+        jax.tree_util.tree_map(lambda a: 0, params)) == \
+        jax.tree_util.tree_structure(jax.tree_util.tree_map(
+            lambda a: 0, axes, is_leaf=lambda a: isinstance(a, tuple)))
+
+
+def test_the_published_sizes():
+    cfg = nemotron_h_family.build(_published())
+    assert (cfg.d_model, cfg.d_inner, cfg.d_conv, cfg.ssm_state,
+            cfg.ssm_chunk, cfg.ssm_groups) == (2688, 4096, 6144, 128, 128, 8)
+    assert (cfg.n_head, cfg.n_kv_head, cfg.head_dim) == (32, 2, 128)
+    assert cfg.attn_scale == 128 ** -0.5
+    assert (cfg.n_experts, len(cfg.held), cfg.experts_per_token,
+            cfg.d_expert, cfg.d_expert_stored, cfg.d_shared,
+            cfg.routed_scale) == (128, 32, 6, 1856, 1920, 3712, 2.5)
+    assert not cfg.tie_head and not cfg.mixer_ffn
+    assert cfg.cache_row == (256, 256) and cfg.cache_layers == 2
+    (n, shape, dtype), (_, tail, _) = cfg.cache_state
+    assert (n, shape, dtype) == (8, (128, 4096), jnp.float32)
+    assert tail == (3, 6144)
+    # a slot: 17.07 MB of state and tail whatever its length, 2,048 B a token
+    assert round(8 * (128 * 4096 * 4 + 3 * 6144 * 2) / 1e6, 2) == 17.07
+    assert cfg.cache_layers * 2 * sum(cfg.cache_row) == 2048
+    ops, nbytes = nemotron_h_family.state_step_count(cfg, 1)
+    assert nbytes == 2 * 128 * 4096 * 4 and ops / nbytes == 0.625
+    assert nemotron_h_family.decode_count(cfg, 1) == (16384.0, 1024.0)
+    # a pair is TWO products of 2688 x 1856, whatever is stored
+    assert nemotron_h_family.expert_count(cfg, 1, 1) == (
+        2.0 * 2 * 2688 * 1856, 2.0 * 2 * 2688 * 1856)
+    # the padded matrices tile: the whole contraction side stays in VMEM
+    assert held_experts.gmm_tiling(2688, 1920) == (128, 2688, 640)
+    assert held_experts.gmm_tiling(1920, 2688) == (128, 1920, 896)
+
+
+@pytest.mark.parametrize("key,value,said", [
+    ("n_group", 2, "no expert groups"), ("mlp_hidden_act", "silu", ""),
+    ("tie_word_embeddings", True, "matrix of its own"),
+    ("mlp_bias", True, "no bias"), ("moe_latent_size", 1024, "latent"),
+    ("num_nextn_predict_layers", 1, "MTP"),
+    ("hybrid_override_pattern", "MEMEM*EMEMEM*EMEM-", "")])
+def test_a_sibling_configuration_is_refused_by_name(key, value, said):
+    with pytest.raises(AssertionError, match=said or None):
+        nemotron_h_family.build({**_published(), key: value})
+
+
+def test_the_cache_holds_two_row_layers_and_state_for_the_mixers_alone():
+    cfg, _ = _model(_file())
+    assert cache_family(cfg) is hybrid_ssm_moe_inference
+    cache = hybrid_ssm_moe_inference.init_cache(cfg, 3, 64)
+    n_ssm, n_attn = cfg.count("mamba"), cfg.count("attention")
+    assert (n_ssm, n_attn, cfg.count("experts")) == (3, 1, 4)
+    assert cache.k.shape == cache.v.shape == (n_attn, 3, 64, 2 * 16)
+    state, tails = cache.state
+    assert state.shape == (n_ssm, 3, 16, 128)
+    assert tails.shape == (n_ssm, 3, 3, 128 + 2 * 2 * 16)
+
+
+def test_apply_equals_the_reference():
+    file = _file()
+    cfg, params = _model(file, seed=1)
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 0,
+                                cfg.vocab_size)
+    got = hybrid_ssm_moe.apply(params, tokens, cfg)[..., :cfg.vocab_size]
+    ref = reference.forward(file, params, tokens, 40)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=ATOL,
+                               rtol=RTOL)
+
+
+def _slot_path(file, cfg, params, n, ticks=8):
+    gateway = _gateway(cfg, params)
+    rng = np.random.default_rng(3 + n)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)]
+    replies, got = gateway.probe_logits(prompts, ticks)
+    full = np.concatenate([prompts[0], np.asarray(replies[0], np.int32)])
+    ref = np.asarray(reference.forward(file, params, full[None],
+                                       ticks + 1))[0]
+    return gateway, got[0][:, :cfg.vocab_size], ref
+
+
+@pytest.mark.parametrize("n", [1, 8, CHUNK, CHUNK + 1, 3 * CHUNK + 5],
+                         ids=["1", "Q", "C", "C+1", "3C+5"])
+def test_slot_path_equals_the_reference_full_forward(n):
+    """Chunked prefill, then 8 decode ticks through the gateway's own
+    programs and slot cache, against the reference's full forward, on
+    logits: prompts that are and are not multiples of the prefill chunk
+    (16) and of the scan's ``chunk_size`` (8)."""
+    file = _file()
+    cfg, params = _model(file)
+    gateway, got, ref = _slot_path(file, cfg, params, n)
+    np.testing.assert_allclose(got, ref, atol=ATOL, rtol=RTOL)
+    # rows stepped = live slots x state-space layers; scan tokens real and
+    # padded; the expert layers alone feed the pair counts
+    named = dict(zip(hybrid_ssm_moe_inference.STATE_COUNTERS,
+                     gateway._batcher.counts("state_steps")))
+    n_ssm, ticks = cfg.count("mamba"), 8
+    padded = -(-n // CHUNK) * CHUNK
+    assert named == {"ssm_rows_stepped": ticks * n_ssm,
+                     "scan_tokens_real": n * n_ssm,
+                     "scan_tokens_padded": (padded - n) * n_ssm}
+    pairs = gateway._batcher.counts("moe_pairs")
+    assert pairs[0] == pairs[3:].sum() > 0 and len(pairs) == 3 + len(cfg.held)
+    # routed in all: every row of every call, the ticks' four slots each
+    assert pairs[1] == (padded + ticks * 4) * cfg.count("experts") \
+        * cfg.experts_per_token
+
+
+# ------------------------------------------------------ the grouped kernels
+
+def _scan_inputs(rng, B, S, H, P, N, G):
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return (f(B, S, H, P), jnp.abs(f(B, S, H)) * 0.3,
+            -jnp.exp(f(H)), f(B, S, G * N), f(B, S, G * N))
+
+
+def _per_head_loop(state, v, dt, a, Bm, Cm, G):
+    """Token by token and HEAD BY HEAD from ``state`` [B, N, H*P], float64:
+    head ``h`` reads columns ``[g N, (g + 1) N)`` of ``B`` and ``C``, ``g =
+    h // (H / G)``."""
+    v, dt, a, Bm, Cm = (np.asarray(t, np.float64)
+                        for t in (v, dt, a, Bm, Cm))
+    B, S, H, P = v.shape
+    N = Bm.shape[-1] // G
+    h_all = np.array(state, np.float64)
+    ys = np.zeros((B, S, H * P))
+    for h in range(H):
+        g = h // (H // G)
+        lanes = slice(h * P, (h + 1) * P)
+        hs = h_all[:, :, lanes]                              # [B, N, P]
+        for t in range(S):
+            b_t = Bm[:, t, g * N:(g + 1) * N]
+            c_t = Cm[:, t, g * N:(g + 1) * N]
+            hs = np.exp(dt[:, t, h] * a[h])[:, None, None] * hs \
+                + b_t[:, :, None] * (dt[:, t, h, None] * v[:, t, h])[:, None]
+            ys[:, t, lanes] = np.einsum("bnp,bn->bp", hs, c_t)
+        h_all[:, :, lanes] = hs
+    return ys, h_all
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
+def test_eight_groups_in_the_chunk_scan_against_a_per_head_loop(
+        monkeypatch, interpret):
+    if interpret:
+        monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.default_rng(0)
+    B, S, H, P, N, G, L = 2, 256, 16, 64, 128, 8, 2
+    v, dt, a, Bm, Cm = _scan_inputs(rng, B, S, H, P, N, G)
+    stack = jnp.asarray(rng.normal(size=(L, B, N, H * P)), jnp.float32)
+    valid = np.array([S, 200])
+    if interpret:
+        assert ssm._tiles(N, H * P, G)
+    y, out = ssm.ssd_chunk_scan(stack, 1, v, dt, a, Bm, Cm,
+                                valid=jnp.asarray(valid), chunk=128,
+                                groups=G)
+    masked = np.where(np.arange(S)[None, :, None] < valid[:, None, None],
+                      np.asarray(dt), 0.0)
+    want_y, want_h = _per_head_loop(stack[1], v, masked, a, Bm, Cm, G)
+    for b in range(B):
+        np.testing.assert_allclose(np.asarray(y)[b, :valid[b]],
+                                   want_y[b, :valid[b]], atol=2e-4,
+                                   rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(out)[1], want_h, atol=2e-4,
+                               rtol=2e-4)
+    assert (np.asarray(out)[0] == np.asarray(stack)[0]).all()
+    # group 0's B and C given to every head is another result
+    tiled = lambda t: jnp.tile(t[..., :N], G)
+    wrong, _ = ssm.ssd_chunk_scan(stack, 1, v, dt, a, tiled(Bm), tiled(Cm),
+                                  valid=jnp.asarray(valid), chunk=128,
+                                  groups=G)
+    assert np.abs(np.asarray(wrong)[0] - want_y[0]).max() > 1.0
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
+def test_eight_groups_in_the_decode_step_against_a_per_head_loop(
+        monkeypatch, interpret):
+    if interpret:
+        monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.default_rng(1)
+    B, H, P, N, G, L = 3, 16, 64, 128, 8, 2
+    v, dt, a, Bm, Cm = _scan_inputs(rng, B, 1, H, P, N, G)
+    stack = jnp.asarray(rng.normal(size=(L, B, N, H * P)), jnp.float32)
+    active = jnp.asarray([True, False, True])
+    wide = lambda t: jnp.repeat(t[:, 0], P, axis=-1)
+    y, out = ssm.ssm_decode_step(
+        stack, 1, wide(dt) * v.reshape(B, -1), wide(jnp.exp(dt * a)),
+        Bm[:, 0], Cm[:, 0], active=active, groups=G)
+    want_y, want_h = _per_head_loop(stack[1], v, dt, a, Bm, Cm, G)
+    live = [0, 2]
+    np.testing.assert_allclose(np.asarray(y)[live], want_y[live, 0],
+                               atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(out)[1, live], want_h[live],
+                               atol=1e-5, rtol=1e-5)
+    assert not np.asarray(y)[1].any()
+    assert (np.asarray(out)[1, 1] == np.asarray(stack)[1, 1]).all()
+    assert (np.asarray(out)[0] == np.asarray(stack)[0]).all()
+
+
+@pytest.mark.parametrize("groups,cb", [(1, 4096), (8, 4096), (8, 1024),
+                                       (2, 1024), (16, 1024)])
+def test_a_column_block_holds_whole_groups_or_lies_inside_one(groups, cb):
+    HP = 4096
+    gl, per, block = ssm._group_blocks(HP, cb, groups)
+    assert gl == HP // groups and per == max(1, cb // gl)
+    for c in range(HP // cb):
+        first_group = c * cb // gl
+        assert block(c) * per == first_group - first_group % per
+
+
+# ----------------------------------------------------------- expert layer
+
+def _expert_layer(cfg, params):
+    """The first expert layer's parameters (layer 1: ``(M E) x 2``)."""
+    return jax.tree_util.tree_map(lambda a: a[0], params["runs"][0][1])
+
+
+def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Experts 0-3, 4-7, 8-11, 12-15 of 16 on four chips: the routed parts
+    the four shares give, with the shared expert counted once, are the
+    uncut layer's result."""
+    file = _file(n_routed_experts=16)
+    cfg, params = _model(file)
+    p = _expert_layer(cfg, params)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 9, cfg.d_model))
+    whole, counts = hybrid_ssm_moe.expert_ffn(x, p, cfg)
+    assert counts[0] == counts[1] == 2 * 9 * cfg.experts_per_token
+    routed, pairs = 0.0, 0
+    for share in range(4):
+        held = tuple(range(4 * share, 4 * share + 4))
+        part = dataclasses.replace(cfg, held_experts=held)
+        mine = {**p, "w_up": p["w_up"][held[0]:held[-1] + 1],
+                "w_down": p["w_down"][held[0]:held[-1] + 1]}
+        out, c = hybrid_ssm_moe.expert_ffn(x, mine, part)
+        alone, _ = hybrid_ssm_moe.expert_ffn(
+            x, {**mine, "w_down": mine["w_down"] * 0}, part)
+        routed = routed + (out - alone)         # this share's routed part
+        shared = alone - x                      # what every chip computes
+        pairs += int(c[0])
+    assert pairs == int(counts[0])
+    np.testing.assert_allclose(np.asarray(x + shared + routed),
+                               np.asarray(whole), atol=1e-6, rtol=1e-5)
+
+
+def test_the_padding_of_an_experts_matrices_is_zero_and_adds_nothing():
+    cfg, params = _model(_file())
+    p = _expert_layer(cfg, params)
+    f, fs = cfg.d_expert, cfg.d_expert_stored
+    assert (f, fs) == (24, 128)
+    assert not np.asarray(p["w_up"])[..., f:].any()
+    assert not np.asarray(p["w_down"])[:, f:].any()
+    assert np.asarray(p["w_up"])[..., :f].any()
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 7, cfg.d_model))
+    cut = {**p, "w_up": p["w_up"][..., :f], "w_down": p["w_down"][:, :f]}
+    np.testing.assert_array_equal(
+        np.asarray(hybrid_ssm_moe.expert_ffn(x, p, cfg)[0]),
+        np.asarray(hybrid_ssm_moe.expert_ffn(x, cut, cfg)[0]))
+
+
+# ----------------------------------------------------------- planted faults
+
+def _rotated(project):
+    """``attention_project`` with a rotary embedding applied to q and k."""
+    def rotate(t):
+        S, D = t.shape[1], t.shape[-1]
+        ang = jnp.arange(S)[:, None] * (
+            10000.0 ** (-jnp.arange(0, D, 2) / D))[None]
+        cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+        a, b = t[..., :D // 2], t[..., D // 2:]
+        return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
+
+    def run(x, p, config):
+        q, (k, v) = project(x, p, config)
+        return rotate(q), (rotate(k), v)
+    return run
+
+
+def _biased_weights(h, w_router, bias, k, scale, normalize=True):
+    """The sigmoid gate with the selection bias left in the weights."""
+    scores = jax.nn.sigmoid(h.astype(jnp.float32) @ w_router) + bias
+    weights, experts = jax.lax.top_k(scores, k)
+    weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return held_experts.Routing(experts.astype(jnp.int32), weights * scale)
+
+
+@contextlib.contextmanager
+def _planted(monkeypatch, fault):
+    """One departure from the published mathematics, in the program (or, for
+    ``relu``, the same departure in the reference: the two then differ by
+    it just the same)."""
+    m = hybrid_ssm_moe
+    with monkeypatch.context() as patch:
+        if fault == "group0_for_every_head":
+            inputs = m.ssm_scan_inputs
+
+            def group0(u_act, dt, p, config):
+                v, dt, a, Bm, Cm = inputs(u_act, dt, p, config)
+                tiled = lambda t: jnp.tile(t[..., :config.ssm_state],
+                                           config.ssm_groups)
+                return v, dt, a, tiled(Bm), tiled(Cm)
+            patch.setattr(m, "ssm_scan_inputs", group0)
+        elif fault == "norm_over_all_channels":
+            output = m.ssm_output
+            patch.setattr(m, "ssm_output", lambda x, y, v, z, p, config:
+                          output(x, y, v, z, p, dataclasses.replace(
+                              config, ssm_groups=1)))
+        elif fault == "relu_for_relu2":
+            patch.setattr(reference, "_relu2", lambda h, up, down:
+                          reference._matmul(jax.nn.relu(
+                              reference._matmul(h, up)), down))
+        elif fault == "bias_in_the_weight":
+            patch.setattr(m, "route", _biased_weights)
+        elif fault == "rotation_applied":
+            patch.setattr(m, "attention_project",
+                          _rotated(m.attention_project))
+        yield
+
+
+@pytest.mark.parametrize("fault", [
+    "none", "group0_for_every_head", "norm_over_all_channels",
+    "relu_for_relu2", "scale_left_out", "bias_in_the_weight",
+    "rotation_applied"])
+def test_a_planted_fault_reads_over_a_tolerance(monkeypatch, fault):
+    """``apply`` against the reference with one departure planted: group
+    0's ``B`` / ``C`` for every head, the gated norm over all channels
+    instead of a group's, ``relu`` for ``relu^2``, the 2.5 left out, the
+    selection bias used in the weight, a rotation applied.  Unplanted the
+    two agree within ``ATOL`` / ``RTOL`` (5e-6 read on logits of 7);
+    planted, some logit is off by 50 times ``ATOL`` or more (each reads 2
+    to 8 whole units)."""
+    file = _file()
+    cfg, params = _model(file, seed=1, std=LOUD, **(
+        {"routed_scale": 1.0} if fault == "scale_left_out" else {}))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 0,
+                                cfg.vocab_size)
+    with _planted(monkeypatch, fault):
+        got = np.asarray(hybrid_ssm_moe.apply(params, tokens, cfg)
+                         )[..., :cfg.vocab_size]
+        ref = np.asarray(reference.forward(file, params, tokens, 40))
+    off = np.abs(got - ref) - RTOL * np.abs(ref)
+    if fault == "none":
+        assert off.max() <= ATOL
+    else:
+        assert off.max() > 50 * ATOL, off.max()
+
+
+def test_a_state_kept_in_bf16_reads_over_a_tolerance():
+    """``H`` rounded to bf16 after every chunk and every tick (the chip
+    control's ``bf16_state``), through the slot path: three chunk edges and
+    8 ticks of rounding (reads 6e-3 where the sound run reads 1.5e-6)."""
+    file = _file()
+    cfg, params = _model(file, std=LOUD)
+    _, got, ref = _slot_path(file, cfg, params, 3 * CHUNK + 5)
+    with control.planted("bf16_state"):
+        _, got, ref = _slot_path(file, cfg, params, 3 * CHUNK + 5)
+    assert (np.abs(got - ref) - RTOL * np.abs(ref)).max() > 50 * ATOL

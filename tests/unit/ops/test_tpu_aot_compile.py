@@ -271,12 +271,12 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
 
 _SERVED = pytest.mark.parametrize("family,int8", [
     ("dense", False), ("dense", True), ("moe", False), ("moe", True),
-    ("latent", False), ("hybrid", False)],
+    ("latent", False), ("hybrid", False), ("single_part", False)],
     ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent",
-         "bf16-hybrid"])
+         "bf16-hybrid", "bf16-single_part"])
 
 #: scans of a tick: one a segment of the family's step
-_SEGMENTS = {"latent": 2, "hybrid": 3}
+_SEGMENTS = {"latent": 2, "hybrid": 3, "single_part": 7}
 
 
 def _served(family):
@@ -293,7 +293,14 @@ def _served(family):
     ten layers (5 state-space, 1 attention, 4 state-space; 18 of 72 experts
     held, a quarter of the vocabulary): 4.9 GB of float32 state a slot pool
     keeps beside 2.7 GB of grouped KV, which the compiler must not copy
-    even once."""
+    even once; the same family's single-part block (a layer is a mixer OR
+    the expert layer) at its own cell's 128 x 16,384 in chunks of 1,024, its
+    published widths and the 18 layers of its cut, ``(ME)x2 M * (EM)x3 *
+    (EM)x2 E`` as seven scans: the state step and the chunk scan with 8
+    groups of ``B`` and ``C``, the grouped-head decode with 16 query heads a
+    key-value head over a 256-wide row, and the grouped matmul at ``[2688,
+    1920]`` / ``[1920, 2688]`` (1856 stored padded), 32 of 128 experts
+    held."""
     import dataclasses
 
     from deepspeed_tpu.models import gpt, gpt_moe
@@ -317,6 +324,21 @@ def _served(family):
             held_experts=tuple(range(18)), embedding_multiplier=12.0,
             residual_multiplier=0.22, logits_scaling=16.0, dtype=BF16,
             param_dtype=BF16), 128, 5120, 512
+    if family == "single_part":
+        from deepspeed_tpu.models import hybrid_ssm_moe
+        M, E, A = "mamba", "experts", "attention"
+        return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
+            vocab_size=32768, max_seq_len=262144,
+            layer_types=(M, E, M, E, M, A, E, M, E, M, E, M, A, E, M, E, M,
+                         E),
+            d_model=2688, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+            conv_kernel=4, ssm_chunk=128, ssm_groups=8, n_head=32,
+            n_kv_head=2, head_dim=128, attn_scale=128 ** -0.5,
+            n_experts=128, experts_per_token=6, d_expert=1856,
+            d_shared=3712, held_experts=tuple(range(32)), mixer_ffn=False,
+            expert_form="relu2", gate="sigmoid", routed_scale=2.5,
+            tie_head=False, dtype=BF16,
+            param_dtype=BF16), 128, 16384, 1024
     from deepspeed_tpu.models import latent_moe
     smax = 8192
     return latent_moe, latent_moe.LatentMoEConfig(
@@ -477,6 +499,59 @@ def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
     held_today = _planned_bytes(extend) + _pool_bytes(pool)
     assert _planned_bytes(compiled) <= 1.01 * held_today, (
         _planned_bytes(compiled), _planned_bytes(extend), _pool_bytes(pool))
+
+
+@pytest.mark.parametrize("kernel", ["decode_step", "chunk_scan",
+                                    "gqa_decode", "grouped_matmul"])
+def test_the_single_part_cells_kernels_at_its_shapes(v5e, kernel):
+    """The four kernels of ``nemotron3n-serve-agent-sat`` alone, at the
+    cell's shapes: the state step and the chunk scan with 8 groups (64
+    heads of 64, state 128, sub-chunks of 128: a column block of the scan
+    holds two groups, the step's block all eight), the grouped-head decode
+    at 16 query heads a key-value head over rows of 16,384 x 256, and the
+    grouped matmul over 32 experts of ``[2688, 1920]`` and ``[1920,
+    2688]``, read where they lie in an 8-layer stack (a chunk's 1,024 x 6
+    pair rows; a tick's are 768)."""
+    held, ssm = _KERNEL_MODULES[-2:]
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    L, slots, N, HP, G = 8, 128, 128, 4096, 8
+    state = arg((L, slots, N, HP))
+    if kernel == "decode_step":
+        assert ssm._tiles(N, HP, G)
+        _compiles_with_kernel(
+            lambda st, x, decay, b, c, live: ssm.ssm_decode_step(
+                st, 3, x, decay, b, c, active=live, groups=G),
+            state, arg((slots, HP)), arg((slots, HP)),
+            arg((slots, G * N), BF16), arg((slots, G * N), BF16),
+            arg((slots,), jnp.bool_))
+    elif kernel == "chunk_scan":
+        row = arg((L, 1, N, HP))
+        _compiles_with_kernel(
+            lambda st, v, dt, a, b, c, n: ssm.ssd_chunk_scan(
+                st, 3, v, dt, a, b, c, valid=n, chunk=128, groups=G),
+            row, arg((1, 1024, 64, 64), BF16), arg((1, 1024, 64)),
+            arg((64,)), arg((1, 1024, G * N), BF16),
+            arg((1, 1024, G * N), BF16), arg((1,), jnp.int32))
+    elif kernel == "gqa_decode":
+        pool = arg((2, slots, 16384, 256), BF16)
+        assert decode.decode_block_k(16384, 256) is not None
+        _compiles_with_kernel(
+            lambda q, k, v, pos, live: decode.cached_attention(
+                q, k, v, pos, sm_scale=128 ** -0.5, layer=1, active=live,
+                kv_heads=2),
+            arg((slots, 1, 32, 128), BF16), pool, pool,
+            arg((slots,), jnp.int32), arg((slots,), jnp.bool_))
+    else:
+        for k, n in ((2688, 1920), (1920, 2688)):
+            assert all(side % tile == 0 for side, tile in zip(
+                (k, n), held.gmm_tiling(k, n)[1:]))
+            _compiles_with_kernel(
+                lambda rows, w, sizes: held._grouped(rows, w, sizes, 3),
+                arg((6144, k), BF16), arg((L, 32, k, n), BF16),
+                arg((32,), jnp.int32))
 
 
 @pytest.mark.parametrize("stochastic", [False, True],
