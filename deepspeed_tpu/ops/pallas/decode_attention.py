@@ -10,6 +10,11 @@ rows and nothing else (``decode_sweep``): the decode step's grid steps and
 its HBM traffic follow the live context, not ``B x S_max``.  A block beyond
 a row's ``pos`` (or below its band) costs no step, and a row the caller
 marks dead (a freed slot of a serving batch) neither a step nor a byte.
+The dense sweep (``_decode``) stops at the frontier INSIDE a row's last
+block too: it copies its own blocks, the live tokens of the last rounded
+up to a tile's 16 rows, so a row of 300 tokens streams 304 and not 512
+(``sweep_token_counts`` counts both; the grouped and the latent sweep still
+stream whole blocks).
 
 The decode kernel reads the slot pool WHERE IT LIES.  ``gpt_inference``
 stores it ``[L, B, S_max, H*D]`` — a token's heads folded into one row, so
@@ -17,9 +22,10 @@ the stored order is row-major on the TPU (a last dimension of 64 would put
 the tokens on the lanes instead) — and the kernel's blocks are
 ``[block_k, H*D]`` tiles of exactly that array: the layer index rides the
 scalar prefetch beside ``pos`` and the sweep, a grid step takes all heads of
-one block of one slot, and nothing is sliced, transposed or copied to feed
-it.  The chunk kernel (``extend``: admission, speculative verify)
-still takes one head a step from a ``[B*H, S_max, D]`` view of one layer: a
+one block of one slot (the dense sweep by its own asynchronous copies out
+of the pool in HBM, the next steps' in flight behind the current one), and
+nothing is sliced, transposed or copied to feed it.  The chunk kernel
+(``extend``: admission, speculative verify) still takes one head a step from a ``[B*H, S_max, D]`` view of one layer: a
 64-wide head is half a lane row and cannot be a block of the folded row.
 
 A latent cache (``models/latent_moe.py``) keeps ONE row per token and layer,
@@ -161,17 +167,37 @@ def _online_softmax_step(s, values, acc_ref, m_ref, l_ref):
 
 
 def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
-                   sm_scale, block_k, H, D, quantized, windowed, alibi):
+                   sm_scale, block_k, H, D, quantized, windowed, alibi,
+                   copy_rows):
     """One online-softmax decode kernel serving every cache layout.  A
     grid step is ONE LIVE BLOCK of one live row: step ``s`` of the flat
     grid reads its row and its block from the sweep (``rows_ref[s]``,
-    ``blocks_ref[s]``; ``decode_sweep``), ``block_k`` cached tokens with
-    ALL their heads as the pool stores them: k/v refs are ``(block_k,
-    H*D)``, the query one ``(1, H*D)`` row.  A row's blocks are
+    ``blocks_ref[s]``; ``decode_sweep``) and takes the block's LIVE tokens
+    with ALL their heads as the pool stores them.  A row's blocks are
     consecutive steps in rising order; the running max/sum/accumulator are
     reset on its first and written out on its last.  Steps at or past
     ``n_ref[0]`` (the tail of a static grid, or the one step of an empty
     sweep) do nothing.
+
+    The copy is the kernel's own.  ``k_ref``/``v_ref`` are the whole
+    pool, left in HBM, and each bank has ``depth`` buffers of one block in
+    VMEM: step ``s`` waits for the copy of its block into buffer ``s %
+    depth`` and, before it computes, starts the copy of step ``s + depth -
+    1`` (the next rows' first blocks at a row's end; the last live steps
+    start none), so the stream runs on while a short block is multiplied.
+    A copy moves the block's live tokens rounded up to ``copy_rows`` (a
+    tile's rows), so a row's last block costs what it holds and not
+    ``block_k``: ``copy_rows`` None (the interpreter, which cannot express
+    a copy of traced size) moves whole blocks through the same buffers and
+    semaphores.  The products stay whole: a step scores and weighs all
+    ``block_k`` rows of its buffer and the mask ``k_pos <= pos`` discards
+    what lies past the frontier (measured, PERF.md 6, PR 45: a step's two
+    products take 0.74-0.86 us where a full block streams in 1.3, and with
+    the next two steps' copies in flight the stream hides them; in slices
+    up to the frontier the same sweep ran 3% slower).  So a dead row of
+    the buffer is multiplied, and ``0 x NaN`` is NaN in ``p @ v``: step 0
+    zeroes the buffers, after which they only ever hold cache rows, which
+    are finite.
 
     The per-head products ride two plain matmuls: the query is spread to
     ``(H, H*D)`` with head ``h``'s lanes kept in row ``h`` and zeros
@@ -183,23 +209,60 @@ def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
     With ``quantized`` the k/v blocks arrive as int8 codes (exact in the
     compute dtype) and the per-vector fp32 scales ``(block_k, H)``
     multiply the scores and the probabilities in VMEM instead of every
-    element — half the HBM bytes on the memory-bound decode path.
-    ``windowed`` bands visibility to the trailing ``window`` slots (SMEM
-    scalar — it may alternate per layer; the sweep already left out the
-    blocks wholly below the band); ``alibi`` adds the per-head
-    ``-slope·dist`` bias from a ``(H, 1)`` slope column.  ``layer_ref``
-    only feeds the index maps."""
+    element — half the HBM bytes on the memory-bound decode path.  The
+    scales stay whole pipelined blocks (a sixteenth of the codes' bytes:
+    Mosaic pads a 16-lane row of HBM to 128 and refuses to slice it), and
+    a dead token's scale only ever meets a masked score or a zero
+    probability.  ``windowed`` bands visibility to the trailing ``window``
+    slots (SMEM scalar — it may alternate per layer; the sweep already
+    left out the blocks wholly below the band); ``alibi`` adds the
+    per-head ``-slope·dist`` bias from a ``(H, 1)`` slope column."""
     (window_ref, slopes_ref, q_ref, k_ref, v_ref, kscale_ref, vscale_ref,
      o_ref, acc_ref, m_ref, l_ref) = _unpack_rest(rest, quantized,
                                                   windowed, alibi)
+    banks = (k_ref, v_ref)
+    bufs, sems = rest[-3:-1], rest[-1]
+    depth = bufs[0].shape[0]         # buffers a bank: copies in flight + 1
     step, row, live, first, last = _sweep_position(rows_ref, n_ref)
     ki = blocks_ref[step]
     pos = pos_ref[row]               # per-ROW visibility (ragged decode)
+    slot = step % depth
+
+    def tokens(s):
+        """Live tokens of step ``s``'s block: to its row's frontier."""
+        return jnp.clip(pos_ref[rows_ref[s]] + 1 - blocks_ref[s] * block_k,
+                        1, block_k)
+
+    def copies(s, slot):
+        """The copies that bring step ``s``'s block into buffer ``slot``,
+        one a bank: the same descriptors start them and wait for them."""
+        n = block_k if copy_rows is None else pl.multiple_of(
+            (tokens(s) + copy_rows - 1) // copy_rows * copy_rows, copy_rows)
+        start = pl.multiple_of(blocks_ref[s] * block_k, block_k)
+        return [pltpu.make_async_copy(
+            bank.at[layer_ref[0], rows_ref[s], pl.ds(start, n)],
+            buf.at[slot, pl.ds(0, n)], sems.at[i, slot])
+            for i, (bank, buf) in enumerate(zip(banks, bufs))]
 
     def own():
         """(H, H*D) mask: lane c of row h belongs to head h."""
         return jax.lax.broadcasted_iota(jnp.int32, (H, H * D), 1) // D == \
             jax.lax.broadcasted_iota(jnp.int32, (H, H * D), 0)
+
+    @pl.when(jnp.logical_and(live, step == 0))
+    def _prime():
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        for ahead in range(depth - 1):
+            @pl.when(ahead < n_ref[0])
+            def _start():
+                for copy in copies(ahead, ahead):
+                    copy.start()
+
+    @pl.when(step + depth - 1 < n_ref[0])
+    def _next():
+        for copy in copies(step + depth - 1, (step + depth - 1) % depth):
+            copy.start()
 
     @pl.when(jnp.logical_and(live, first))
     def _init():
@@ -209,10 +272,12 @@ def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
 
     @pl.when(live)
     def _update():
+        for copy in copies(step, slot):
+            copy.wait()
         q = q_ref[...]                                     # (1, H*D)
         qx = jnp.where(own(), q.astype(jnp.float32), 0.0).astype(q.dtype)
-        ks = _to_compute(k_ref[...], q.dtype)              # (BK, H*D)
-        vs = _to_compute(v_ref[...], q.dtype)
+        ks = _to_compute(bufs[0][slot], q.dtype)           # (BK, H*D)
+        vs = _to_compute(bufs[1][slot], q.dtype)
         s = jax.lax.dot_general(qx, ks, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                                   # (H, BK)
@@ -255,14 +320,19 @@ def _to_compute(x, dtype):
 def decode_block_k(Smax: int, HD: int) -> Optional[int]:
     """Tokens in one streamed block of the single-token sweep, or None
     where ``Smax`` does not tile (the dense reference serves it).  A step
-    costs only when its block is live, so the size trades the dead tail of
-    a row's last block against a step's fixed cost (~0.7 us on a v5e):
-    about 2**18 cache elements a step, so 256 tokens of 16 heads of 64 and
-    128 of 32 (measured, PERF.md 6, PR 28: at 64 slots x 1024 a width of
-    2048 takes 297 us a layer with 128, 352 with 256; a width of 1024 takes
-    176 with 256, 179 with 128, 222 with 512).  Elements, not bytes: int8
-    codes stream half the bytes and pay the same per element to become
-    the compute dtype, and measure best at the same 256."""
+    costs only when its block is live; when these sizes were measured the
+    size traded the dead tail of a row's last block against a step's fixed
+    cost (~0.7 us on a v5e): about 2**18 cache elements a step, so 256
+    tokens of 16 heads of 64 and 128 of 32 (measured, PERF.md 6, PR 28: at
+    64 slots x 1024 a width of 2048 takes 297 us a layer with 128, 352 with
+    256; a width of 1024 takes 176 with 256, 179 with 128, 222 with 512).
+    Since PR 45 the dense sweep's last block streams no dead tail (its
+    copy stops at the frontier), so that side of the trade is gone for it
+    and a larger block may now win: not re-measured, the numbers stand as
+    history; the grouped sweep, which shares this size, still streams
+    whole blocks.  Elements, not bytes: int8 codes stream half the
+    bytes and pay the same per element to become the compute dtype, and
+    measure best at the same 256."""
     return next((b for b in (256, 128) if Smax % b == 0
                  and (b * HD <= 1 << 18 or b == 128)), None)
 
@@ -309,6 +379,15 @@ def decode_sweep(pos, B: int, Smax: int, block_k: Optional[int],
             n.reshape(1).astype(jnp.int32))
 
 
+def _live_blocks(p: int, nb: int, block_k: int, window):
+    """``(lo, hi)``: the first and the last block the sweep lists for a row
+    at frontier ``p`` (``decode_sweep``'s rule, on host ints)."""
+    hi = min(p // block_k, nb - 1)
+    lo = 0 if window is None else \
+        min(max((p - window + 1) // block_k, 0), hi)
+    return lo, hi
+
+
 def sweep_block_counts(positions, rows: int, Smax: int,
                        block_k: Optional[int], windows=((None, 1),)):
     """What ``decode_sweep`` lists, counted on the host from lengths the
@@ -323,11 +402,46 @@ def sweep_block_counts(positions, rows: int, Smax: int,
     live = 0
     for window, layers in windows:
         for p in positions:
-            hi = min(p // block_k, nb - 1)
-            lo = 0 if window is None else \
-                min(max((p - window + 1) // block_k, 0), hi)
+            lo, hi = _live_blocks(p, nb, block_k, window)
             live += layers * (hi - lo + 1)
     return live, sum(n for _, n in windows) * rows * nb
+
+
+def sweep_token_counts(positions, Smax: int, block_k: Optional[int],
+                       windows=((None, 1),), copy_rows: Optional[int] = None):
+    """``sweep_block_counts``'s sibling in tokens: ``(live, streamed)`` over
+    the kernel calls of one decode step, from the same host lengths.
+    ``live`` is what a row's query sees (``p + 1`` tokens a call, a banded
+    layer's ``window`` of them), ``streamed`` what the sweep's copies move:
+    whole blocks from the band's first to the one below the frontier's
+    and, of the last, the live tokens rounded up to ``copy_rows``
+    (``decode_copy_rows``: the dense kernel's copy ends on a tile);
+    ``copy_rows`` None is a kernel that streams whole blocks (the grouped
+    and the latent sweep)."""
+    if block_k is None:
+        return 0, 0
+    nb = Smax // block_k
+    tail = block_k if copy_rows is None else copy_rows
+    live = streamed = 0
+    for window, layers in windows:
+        for p in positions:
+            lo, hi = _live_blocks(p, nb, block_k, window)
+            last = min(p + 1 - hi * block_k, block_k)
+            live += layers * min(p + 1, Smax, window or Smax)
+            streamed += layers * ((hi - lo) * block_k
+                                  + -(-last // tail) * tail)
+    return live, streamed
+
+
+def decode_copy_rows(itemsize: int) -> int:
+    """Rows the dense sweep's copy of a row's last block ends on: a tile of
+    the bank, 16 rows of two bytes (or four), 32 of one."""
+    return max(16, 32 // itemsize)
+
+
+# buffers a bank: the copies of the next ``_BUFFERS - 1`` steps are in
+# flight while a step computes
+_BUFFERS = 3
 
 
 def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, ks=None,
@@ -335,23 +449,29 @@ def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, ks=None,
     """Single scalar-prefetch build for every decode variant, reading the
     stacked pool ``k``/``v`` [L, B, Smax, H*D] where it lies.  The sweep
     (``decode_sweep``), ``pos``, ``layer`` (and window, when banded) are
-    available BEFORE the body, so the index maps pick each step's row,
-    block and layer from them, and Pallas prefetches the next step's block
-    — the next row's first, at a row's end — behind the current one.  The
+    available BEFORE the body, so the index maps pick each step's row from
+    them and the kernel's own copies each step's block and layer, the next
+    step's behind the current one and no further than the row is live
+    (``_decode_kernel``): the pool's banks are handed over whole, in HBM,
+    and ``_BUFFERS`` buffers of one block a bank are the kernel's scoped
+    VMEM.  The
     grid is flat, one step per entry of the sweep: on the chip its bound is
     the live-block count itself (a dynamic grid bound), so a dead block
     costs no step and a dead row neither a step nor a byte; under the
-    interpreter, which refuses dynamic bounds, the same body runs the
-    static ``B * Smax/block_k`` steps and skips the tail.  ``q`` and the
-    result are ``[B, 1, H*D]`` (a dead row's result is never written);
-    ``ks``/``vs`` [L, B, Smax, H]."""
+    interpreter, which refuses dynamic bounds and copies of traced size,
+    the same body runs the static ``B * Smax/block_k`` steps, skips the
+    tail and copies whole blocks.  ``q`` and the result are ``[B, 1, H*D]``
+    (a dead row's result is never written); ``ks``/``vs`` [L, B, Smax,
+    H]."""
     B, _, HD = q.shape
     quantized = ks is not None
     windowed = window is not None
+    interpret = interpret_mode()
+    copy_rows = None if interpret else decode_copy_rows(k.dtype.itemsize)
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                block_k=block_k, H=H, D=HD // H,
                                quantized=quantized, windowed=windowed,
-                               alibi=slopes is not None)
+                               alibi=slopes is not None, copy_rows=copy_rows)
     rows, blocks, n = sweep
 
     def row_idx(s, rows_ref, *_):
@@ -360,9 +480,9 @@ def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, ks=None,
     def kv_idx(s, rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *_):
         return (layer_ref[0], rows_ref[s], blocks_ref[s], 0)
 
-    kv_spec = pl.BlockSpec((None, None, block_k, HD), kv_idx)
-    scale_spec = pl.BlockSpec((None, None, block_k, H), kv_idx)
     row_spec = pl.BlockSpec((None, 1, HD), row_idx)
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    scale_spec = pl.BlockSpec((None, None, block_k, H), kv_idx)
     slope_specs = [pl.BlockSpec((H, 1), lambda s, *_: (0, 0))] \
         if slopes is not None else []
     slope_args = (jnp.asarray(slopes, jnp.float32).reshape(H, 1),) \
@@ -371,22 +491,25 @@ def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, ks=None,
     prefetch = (rows, blocks, n, pos_arr,
                 jnp.asarray(layer, jnp.int32).reshape(1)) + \
         ((jnp.asarray(window, jnp.int32).reshape(1),) if windowed else ())
-    interpret = interpret_mode()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # rows, blocks, n, pos, layer [, window]
         num_scalar_prefetch=len(prefetch),
         grid=(rows.shape[0] if interpret else jnp.maximum(n[0], 1),),
-        in_specs=slope_specs + [row_spec, kv_spec, kv_spec]
+        in_specs=slope_specs + [row_spec, pool_spec, pool_spec]
         + ([scale_spec, scale_spec] if quantized else []),
         out_specs=row_spec,
         scratch_shapes=[
             pltpu.VMEM((H, HD), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((_BUFFERS, block_k, HD), k.dtype),
+            pltpu.VMEM((_BUFFERS, block_k, HD), v.dtype),
+            pltpu.SemaphoreType.DMA((2, _BUFFERS)),
         ],
     )
     # prefetch refs arrive in arg order — [rows, blocks, n, pos, layer,
-    # window?] then slopes? — matching _unpack_rest's ordering contract
+    # window?] then slopes? — matching _unpack_rest's ordering contract;
+    # the buffers and their semaphores follow its scratch
     args = prefetch + slope_args + (q, k, v) + \
         ((ks, vs) if quantized else ())
     return pl.pallas_call(kernel, grid_spec=grid_spec,

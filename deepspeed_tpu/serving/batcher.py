@@ -58,7 +58,9 @@ from ..inference.bucketing import bucket_cache_len, bucket_draft_k
 from ..inference.sampling import filter_logits
 from ..inference.speculative import (spec_accept_batch, spec_accept_keys,
                                      spec_draft_keys)
-from ..ops.pallas.decode_attention import sweep_block_counts
+from ..ops.pallas.decode_attention import (decode_copy_rows,
+                                            sweep_block_counts,
+                                            sweep_token_counts)
 from ..telemetry.spans import SpanName, Tracer
 from ..utils.compile_watch import CompiledProgramRegistry, hot_path
 from .config import ServingConfig
@@ -212,6 +214,13 @@ class SlotBatcher:
         #: pairs, one per distinct per-layer window
         self._block_k, self._layer_windows = fam.sweep_geometry(
             cfg, self.max_len)
+        #: where the decode kernel's copy of a row's last block ends: on
+        #: a tile for the dense sweep (two banks of all heads, which
+        #: ``cached_attention`` gives ``_decode``), on the block's end for
+        #: the grouped and the latent one (None)
+        self._copy_rows = decode_copy_rows(self.cache.k.dtype.itemsize) \
+            if self.cache.v is not None \
+            and self.cache.k.shape[-1] == cfg.n_head * cfg.head_dim else None
         #: what the family's scan steps counted on the device
         #: (``KVCache.stats``), summed over the ticks pulled so far; None
         #: for a family that counts nothing
@@ -808,6 +817,13 @@ class SlotBatcher:
         device."""
         return sweep_block_counts(frontiers, self.slots, self.max_len,
                                   self._block_k, self._layer_windows)
+
+    def sweep_tokens(self, frontiers) -> Tuple[int, int]:
+        """``(live, streamed)`` cached tokens of the same tick: what its
+        rows' queries see over all layers, and what the decode kernel's
+        copies move for them (``sweep_token_counts``)."""
+        return sweep_token_counts(frontiers, self.max_len, self._block_k,
+                                  self._layer_windows, self._copy_rows)
 
     @hot_path
     def launch(self):

@@ -377,6 +377,8 @@ class ServingGateway:
             MetricName.SERVE_QUEUE_DEPTH: snap["queue_depth"],
             MetricName.SERVE_OCCUPANCY: snap["slot_occupancy"],
             MetricName.SERVE_LIVE_BLOCK_SHARE: snap["live_block_share"],
+            MetricName.SERVE_KV_STREAMED_OVER_LIVE:
+                snap["streamed_over_live"],
             MetricName.SERVE_TOKENS_PER_S: snap["tokens_per_s"],
             MetricName.SERVE_TTFT_S: self.metrics.ttft.snapshot(),
         }
@@ -875,9 +877,11 @@ class ServingGateway:
         # tick was decoded, late rows included: what the kernel stepped (a
         # speculative round's target pass is the chunk kernel's: nothing
         # to count)
-        kv_blocks = self._batcher.sweep_blocks(
-            [req.frontier + len(req.out) for _, req in live]) \
-            if counts is None else (0, 0)
+        kv_blocks = kv_tokens = (0, 0)
+        if counts is None:
+            frontiers = [req.frontier + len(req.out) for _, req in live]
+            kv_blocks = self._batcher.sweep_blocks(frontiers)
+            kv_tokens = self._batcher.sweep_tokens(frontiers)
         for row, req in live:
             if row in late:
                 continue
@@ -933,6 +937,7 @@ class ServingGateway:
                         partial=np.asarray(req.out, np.int32)))
         self.metrics.record_tick(active=n_live, slots=self.config.slots,
                                  tokens=harvested, kv_blocks=kv_blocks,
+                                 kv_tokens=kv_tokens,
                                  overlapped=tick.overlapped,
                                  late_rows=len(late))
         # the family's device counters, each group by its name (the family

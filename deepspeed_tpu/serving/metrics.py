@@ -55,6 +55,11 @@ class ServingMetrics:
         #: summed over plain ticks from the lengths the gateway holds
         self.kv_blocks_live = 0
         self.kv_blocks_grid = 0
+        #: cached tokens those ticks' queries saw, and the tokens the
+        #: decode kernel's copies moved for them: whole blocks, and a
+        #: row's last as far as the kernel's copy goes
+        self.kv_tokens_live = 0
+        self.kv_tokens_streamed = 0
         #: ticks launched while the tick before them was un-pulled (the
         #: decode loop keeps one in flight; a busy period's first tick
         #: has no predecessor)
@@ -137,12 +142,13 @@ class ServingMetrics:
 
     def record_tick(self, active: int, slots: int, tokens: int,
                     kv_blocks=(0, 0), overlapped: bool = False,
-                    late_rows: int = 0) -> None:
+                    late_rows: int = 0, kv_tokens=(0, 0)) -> None:
         """``active``: the requests bound at the tick's launch, the
         ``late_rows`` of them that had finished by its harvest included;
         ``tokens``: those delivered to a request; ``kv_blocks``: the
         tick's ``(live, grid)`` cache blocks
-        (``SlotBatcher.sweep_blocks``)."""
+        (``SlotBatcher.sweep_blocks``); ``kv_tokens``: its ``(live,
+        streamed)`` cached tokens (``SlotBatcher.sweep_tokens``)."""
         with self._lock:
             self.ticks += 1
             self.tokens_out += tokens
@@ -150,6 +156,8 @@ class ServingMetrics:
             self.slot_ticks += slots
             self.kv_blocks_live += kv_blocks[0]
             self.kv_blocks_grid += kv_blocks[1]
+            self.kv_tokens_live += kv_tokens[0]
+            self.kv_tokens_streamed += kv_tokens[1]
             self.ticks_overlapped += bool(overlapped)
             self.late_row_ticks += late_rows
 
@@ -241,6 +249,13 @@ class ServingMetrics:
                 "live_block_share": (self.kv_blocks_live
                                      / self.kv_blocks_grid
                                      if self.kv_blocks_grid else 0.0),
+                "kv_tokens_live": self.kv_tokens_live,
+                "kv_tokens_streamed": self.kv_tokens_streamed,
+                # cached tokens the decode kernel moved for each one a
+                # query saw: the dead tail of every row's last block
+                "streamed_over_live": (self.kv_tokens_streamed
+                                       / self.kv_tokens_live
+                                       if self.kv_tokens_live else 0.0),
                 "ticks_overlapped": self.ticks_overlapped,
                 "late_row_ticks": self.late_row_ticks,
                 # how much of the loop ran with a tick in flight, and

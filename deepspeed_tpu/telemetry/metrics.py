@@ -101,6 +101,9 @@ class MetricName:
     #: lifetime share of the slot grid's cache blocks that were live, i.e.
     #: that the decode kernel stepped (live blocks / grid blocks)
     SERVE_LIVE_BLOCK_SHARE = "serve.live_block_share"
+    #: lifetime cached tokens the decode kernel streamed per token a query
+    #: saw (1.0: no dead tail in any row's last block)
+    SERVE_KV_STREAMED_OVER_LIVE = "serve.kv_streamed_over_live"
     #: histogram of time-to-first-token seconds
     SERVE_TTFT_S = "serve.ttft_s"
     #: decode tokens emitted per second over the gateway lifetime

@@ -32,6 +32,19 @@ _MASK_CASES = {
     "row-at-the-end": [(SMAX - 1, True), (40, True), (0, False),
                        (SMAX - 1, True)],
 }
+# every edge of the rule a row's last block is copied by: the copy's 16-row
+# tile, half a block, the 256-token block, the slot's end; dead rows among
+# them
+_EDGES = [(0, True), (14, True), (300, False), (15, True), (16, True),
+          (127, True), (128, True), (255, True), (0, False), (256, True),
+          (257, True), (511, True), (SMAX - 1, True)]
+_MASK_CASES.update({
+    "tile-edges": _EDGES,
+    # the pool holds 1e30-scaled rows in every token beyond a row's frontier
+    "tile-edges-garbage": _EDGES,
+    # a call on a pool of infinities runs first, in the same program
+    "tile-edges-twice": _EDGES,
+})
 
 
 @pytest.mark.parametrize("mask", sorted(_MASK_CASES))
@@ -44,7 +57,9 @@ def test_masked_decode_sweep(pallas_interpret, kind, window, slopes, per_row,
     """The single-token sweep told which rows are live, on layer 2 of a pool
     of 3: live rows match the dense reference AND, bit for bit, the sweep of
     the whole grid that PR 25 left (same blocks, same order); dead rows
-    return zeros."""
+    return zeros.  A row's last block is multiplied whole, its dead tail
+    masked: what the pool (``-garbage``) or an earlier call's buffers
+    (``-twice``) hold past the frontier changes no bit."""
     from tests.unit.ops.dense_grid_decode import dense_grid_decode
     from deepspeed_tpu.ops.pallas.decode_attention import decode_block_k
     rows = _MASK_CASES[mask]
@@ -74,10 +89,35 @@ def test_masked_decode_sweep(pallas_interpret, kind, window, slopes, per_row,
         ref_k, ref_v = k.astype(jnp.float32), v.astype(jnp.float32)
 
     layer = 2
-    got = jax.jit(lambda lay, act: cached_attention(
-        q, fold(k), fold(v), pos, window=win, slopes=slope, layer=lay,
-        active=act, **scales))(jnp.int32(layer), jnp.asarray(active))
+
+    def sweep(lay, act, k, v, scales):
+        return cached_attention(q, fold(k), fold(v), pos, window=win,
+                                slopes=slope, layer=lay, active=act, **scales)
+
+    got = jax.jit(lambda lay, act: sweep(lay, act, k, v, scales))(
+        jnp.int32(layer), jnp.asarray(active))
     got = np.asarray(got, np.float32)
+    if mask.endswith("-garbage"):
+        beyond = (jnp.arange(Smax)[None, :] >
+                  jnp.broadcast_to(pos, (B,))[:, None])[None, :, :, None, None]
+        huge = jnp.where(beyond, 1e30, 1.0)
+        if kind == "int8":      # the codes stay codes: the scales carry it
+            dirty = dict(k_scale=fold(k_s * huge), v_scale=fold(v_s * huge))
+            again = jax.jit(lambda lay, act: sweep(lay, act, k, v, dirty))
+        else:
+            again = jax.jit(lambda lay, act: sweep(
+                lay, act, (k * huge).astype(dtype), (v * huge).astype(dtype),
+                scales))
+        np.testing.assert_array_equal(got, np.asarray(
+            again(jnp.int32(layer), jnp.asarray(active)), np.float32))
+    if mask.endswith("-twice"):
+        inf = {name: jnp.full_like(x, jnp.inf) for name, x in scales.items()}
+        first = (jnp.full_like(k, jnp.inf), jnp.full_like(v, jnp.inf)) \
+            if kind == "bf16" else (k, v)
+        both = jax.jit(lambda lay, act: (sweep(lay, act, *first, inf),
+                                         sweep(lay, act, k, v, scales)))
+        np.testing.assert_array_equal(got, np.asarray(
+            both(jnp.int32(layer), jnp.asarray(active))[1], np.float32))
     assert got.shape == q.shape
     assert not got[~active].any(), "a dead row's result is zeros"
     want = np.asarray(cached_attention_reference(

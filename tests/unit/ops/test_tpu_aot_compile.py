@@ -412,6 +412,32 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     assert "input_output_alias" in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         _pool_bytes(cache), "the donated pool is not updated in place"
+    if family in ("dense", "moe"):
+        _one_dense_sweep_a_layer(text, cfg, cache, slots, smax,
+                                 calls=1 if family == "dense" else 2)
+
+
+def _one_dense_sweep_a_layer(text, cfg, cache, slots, smax, calls):
+    """The dense sweep copies its own blocks (PR 45): the pool's banks go
+    in whole, in HBM, and the kernel holds three buffers of one block a
+    bank.  It is still ONE custom call a layer of the scan's body (the MoE
+    family's body is a dense and an expert layer), named
+    ``decode_attention`` with the result ``bf16[B, 1, H*D]`` by which the
+    benchmark's roofline reader finds it, and its scoped VMEM is the six
+    buffers, 3 MiB of bf16 (1.5 MiB of codes), beside the 64 KiB
+    accumulator and an int8 cache's four pipelined scale blocks of 128
+    KiB: a quarter of the 16 MiB a v5e's kernel may use."""
+    import re
+    HD = cfg.n_head * cfg.head_dim
+    found = re.findall(
+        r"%(decode_attention[.\d]*) = (\w+\[[\d,]*\])\S* custom-call\(", text)
+    assert [shape for _, shape in found] == [f"bf16[{slots},1,{HD}]"] * calls, \
+        found
+    block_k = decode.decode_block_k(smax, HD)
+    buffers = 2 * 3 * block_k * HD * cache.k.dtype.itemsize
+    scales = 0 if cache.k_scale is None else 2 * 2 * block_k * 128 * 4
+    assert buffers == (3 << 20) // (2 // cache.k.dtype.itemsize)
+    assert buffers + scales + cfg.n_head * HD * 4 < (16 << 20) // 4
 
 
 def _planned_bytes(compiled):

@@ -281,6 +281,102 @@ def test_sweep_block_counts_agree_with_the_device_sweep(monkeypatch):
         assert list(zip(rows.tolist(), blocks.tolist())) == want
 
 
+@pytest.mark.parametrize("copy_rows", [None, 16, 32],
+                         ids=["whole-blocks", "tile-16", "tile-32"])
+@pytest.mark.parametrize("window", [None, 200, 1],
+                         ids=["global", "band-200", "band-1"])
+def test_sweep_token_counts_agree_with_the_device_sweep(monkeypatch, window,
+                                                        copy_rows):
+    """``(live, streamed)`` counted on the host from the lengths agree with
+    a count made from the device's work list and ``pos``: an entry streams
+    its block as far as its row's frontier, rounded up to the copy's tile
+    (the whole block where the kernel copies whole blocks), and a query sees
+    ``pos + 1`` tokens, a banded one its window of them."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.default_rng(5)
+    pos = np.concatenate([rng.integers(0, 1024, 10),
+                          [0, 15, 16, 255, 256, 1023]])
+    active = rng.random(len(pos)) < 0.7
+    B = len(pos)
+    rows, blocks, n = da.decode_sweep(
+        jnp.asarray(pos), B, 1024, 256, jnp.asarray(active), window)
+    tail = copy_rows or 256
+    streamed = 0
+    for row, block in zip(rows.tolist()[:int(n[0])],
+                          blocks.tolist()[:int(n[0])]):
+        tokens = min(int(pos[row]) + 1 - block * 256, 256)
+        streamed += -(-tokens // tail) * tail
+    live = sum(min(int(p) + 1, window or 1024) for p in pos[active])
+    got = da.sweep_token_counts([int(p) for p in pos[active]], 1024, 256,
+                                ((window, 3),), copy_rows)
+    assert got == (3 * live, 3 * streamed)
+    assert da.sweep_token_counts([10], 96, da.decode_block_k(96, 128)) == \
+        (0, 0)
+
+
+def _decode_sat_replay(copy_rows, requests=400):
+    """``decode-sat``'s draw (prompts 64-256, outputs 128-384, uniform)
+    replayed through ``ServingMetrics`` at its geometry: 24 layer calls a
+    tick over slots of 1,024 in blocks of 256, a reply's every tick."""
+    from deepspeed_tpu.ops.pallas.decode_attention import sweep_token_counts
+    from deepspeed_tpu.serving.metrics import ServingMetrics
+    rng = np.random.default_rng(45)
+    m = ServingMetrics()
+    for _ in range(requests):
+        prompt, out = rng.integers(64, 257), rng.integers(128, 385)
+        # the tick that decodes token t reads the row up to prompt + t
+        m.record_tick(active=1, slots=1, tokens=int(out),
+                      kv_tokens=sweep_token_counts(
+                          [int(prompt) + t for t in range(out)], 1024, 256,
+                          ((None, 24),), copy_rows))
+    return m.snapshot()
+
+
+@pytest.mark.parametrize("case", ["tile-edges", "parent-geometry",
+                                  "this-kernel"])
+def test_streamed_over_live(case):
+    """``snapshot()["streamed_over_live"]``: 1.0 for rows that end on an
+    edge of the copy's tile, and ``decode-sat``'s replayed draw at whole
+    blocks (the parent's kernel: 1.42) and at the 16-row tile (1.03)."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (decode_copy_rows,
+                                                           sweep_token_counts)
+    from deepspeed_tpu.serving.metrics import ServingMetrics
+    assert decode_copy_rows(2) == 16 and decode_copy_rows(1) == 32
+    if case == "tile-edges":
+        m = ServingMetrics()
+        assert m.snapshot()["streamed_over_live"] == 0.0
+        m.record_tick(active=4, slots=4, tokens=4,
+                      kv_tokens=sweep_token_counts(
+                          [15, 255, 271, 1023], 1024, 256, ((None, 2),), 16))
+        snap = m.snapshot()
+        assert snap["kv_tokens_live"] == 2 * (16 + 256 + 272 + 1024)
+        assert snap["kv_tokens_streamed"] == snap["kv_tokens_live"]
+        assert snap["streamed_over_live"] == 1.0
+        return
+    snap = _decode_sat_replay(None if case == "parent-geometry" else 16)
+    lo, hi = (1.38, 1.46) if case == "parent-geometry" else (1.02, 1.05)
+    assert lo <= snap["streamed_over_live"] <= hi, snap["streamed_over_live"]
+
+
+def test_the_batcher_counts_the_tokens_its_familys_kernel_streams():
+    """``SlotBatcher.sweep_tokens``: the dense family's kernel ends its copy
+    on a tile, so a row at 100 streams 112 tokens a layer where its block
+    holds 256."""
+    cfg = gpt.GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=128,
+                        max_seq_len=512, dtype=jnp.float32,
+                        vocab_round_to=128)
+    eng = deepspeed_tpu.init_inference(
+        model=(cfg, gpt.init(cfg, jax.random.PRNGKey(0))),
+        config={"dtype": "float32"})
+    bat = SlotBatcher(eng, ServingConfig.from_dict(
+        {"slots": 2, "max_len": 512, "prefill_chunk": 8}))
+    assert bat.sweep_blocks([100, 300]) == (2 * 3, 2 * 2 * 2)
+    assert bat.sweep_tokens([100, 300]) == (2 * (101 + 301),
+                                            2 * (112 + 256 + 48))
+    assert bat.sweep_tokens([]) == (0, 0)
+
+
 # ------------------------------------------------- the one-launch admission
 
 CHUNK, SLOT = 8, 64

@@ -212,6 +212,37 @@ def test_report_mode_prints_the_serving_line(tmp_path, capsys):
         "live_block_share"] == 9 / 16
 
 
+def test_report_mode_prints_streamed_over_live(tmp_path, capsys):
+    """``serve.kv_streamed_over_live`` (cached tokens the decode kernel's
+    copies moved per token a query saw) reaches the report's serving line
+    beside ``live_block_share``; a row at 300 of a 256-token block streams
+    256 + 48 tokens with the dense kernel's 16-row tile."""
+    from deepspeed_tpu.ops.pallas.decode_attention import sweep_token_counts
+    from deepspeed_tpu.serving.metrics import ServingMetrics
+    from deepspeed_tpu.telemetry.metrics import (MetricName, MetricsRegistry,
+                                                 MetricsSampler)
+    m = ServingMetrics()
+    m.record_tick(active=1, slots=4, tokens=1, kv_blocks=(2, 16),
+                  kv_tokens=sweep_token_counts([300], 1024, 256,
+                                               copy_rows=16))
+    snap = m.snapshot(queue_depth=0)
+    assert (snap["kv_tokens_live"], snap["kv_tokens_streamed"]) == (301, 304)
+    sampler = MetricsSampler(MetricsRegistry(),
+                             str(tmp_path / "metrics.jsonl"))
+    sampler.attach_source(lambda: {
+        MetricName.SERVE_LIVE_BLOCK_SHARE: snap["live_block_share"],
+        MetricName.SERVE_KV_STREAMED_OVER_LIVE: snap["streamed_over_live"]})
+    sampler.start()
+    mod = _run_report()
+    assert mod.main([str(tmp_path)]) == 0
+    assert "live_block_share 0.125, kv_streamed_over_live 1.01" in \
+        capsys.readouterr().out
+    assert mod.main([str(tmp_path), "--json"]) == 0
+    report = json.loads(capsys.readouterr().out)
+    assert report["metrics"]["metrics.jsonl"]["serving"][
+        "kv_streamed_over_live"] == 304 / 301
+
+
 @pytest.mark.parametrize("model,seq,ratio", [
     ("gpt", 1024, 88 / 128),     # gpt2m-train-s1024: a head is one block
     ("gpt", 2048, 304 / 384),    # opt1b3-train-zero3-4chip: 2 x 2 blocks
