@@ -240,6 +240,14 @@ def _dense_attend_cached(q, cache, pos, config, idx, active=None,
         active=active, sweep=sweep)
 
 
+def _sweep_block_k(config: gpt.GPTConfig, max_len: int):
+    """The decode kernel's block for this family's row: the one value the
+    host's counts (``sweep_geometry``) and the tick's work list
+    (``_dense_sweeps``) both take; the kernel takes the list's."""
+    from ..ops.pallas.decode_attention import decode_block_k
+    return decode_block_k(max_len, cache_row(config)[0])
+
+
 def sweep_geometry(config: gpt.GPTConfig, max_len: int):
     """``(block_k, windows)``: the decode kernel's block for this family's
     row, and its calls in one tick as ``(window or None, layers)`` pairs,
@@ -248,9 +256,8 @@ def sweep_geometry(config: gpt.GPTConfig, max_len: int):
     import collections
 
     import numpy as np
-    from ..ops.pallas.decode_attention import decode_block_k
     windows = gpt.layer_window(config, np.arange(config.n_layer), max_len)
-    return decode_block_k(max_len, cache_row(config)[0]), (
+    return _sweep_block_k(config, max_len), (
         ((None, config.n_layer),) if windows is None
         else tuple(collections.Counter(
             int(w) for w in np.asarray(windows)).items()))
@@ -261,8 +268,8 @@ def _dense_sweeps(pos, B, config: gpt.GPTConfig, max_len, active):
     built ONCE, before the layer scan: a function of the step's ``pos`` and
     ``active`` alone, and, in a banded stack, of each layer's window (all
     layers' lists in one vectorised build).  Returns ``idx -> sweep``."""
-    from ..ops.pallas.decode_attention import decode_block_k, decode_sweep
-    block_k = decode_block_k(max_len, cache_row(config)[0])
+    from ..ops.pallas.decode_attention import decode_sweep
+    block_k = _sweep_block_k(config, max_len)
     if config.local_attention_window <= 0:
         sweep = decode_sweep(pos, B, max_len, block_k, active)
         return lambda idx: sweep

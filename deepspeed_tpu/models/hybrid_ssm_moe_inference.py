@@ -192,9 +192,11 @@ def sweep_geometry(config: HybridSSMMoEConfig, max_len: int):
 
 
 def _sweeps(pos, B, config: HybridSSMMoEConfig, max_len, active):
-    from ..ops.pallas.decode_attention import decode_block_k, decode_sweep
-    sweep = decode_sweep(pos, B, max_len,
-                         decode_block_k(max_len, config.cache_row[0]), active)
+    from ..ops.pallas.decode_attention import decode_sweep
+    # the block the host counts by (``sweep_geometry``) is the list's, and
+    # the list's is the kernel's (``sweep_block_k``)
+    sweep = decode_sweep(pos, B, max_len, sweep_geometry(config, max_len)[0],
+                         active)
     return lambda idx: sweep
 
 

@@ -317,6 +317,14 @@ def _to_compute(x, dtype):
     return x.astype(dtype)
 
 
+def _block_of(sizes, Smax: int, HD: int) -> Optional[int]:
+    """The largest of ``sizes`` (tokens, falling) that tiles ``Smax`` and
+    holds at most 2**18 cache elements of an ``HD``-wide row; 128, the
+    floor, serves any wider row."""
+    return next((b for b in sizes if Smax % b == 0
+                 and (b * HD <= 1 << 18 or b == 128)), None)
+
+
 def decode_block_k(Smax: int, HD: int) -> Optional[int]:
     """Tokens in one streamed block of the single-token sweep, or None
     where ``Smax`` does not tile (the dense reference serves it).  A step
@@ -332,9 +340,34 @@ def decode_block_k(Smax: int, HD: int) -> Optional[int]:
     history; the grouped sweep, which shares this size, still streams
     whole blocks.  Elements, not bytes: int8 codes stream half the
     bytes and pay the same per element to become the compute dtype, and
-    measure best at the same 256."""
-    return next((b for b in (256, 128) if Smax % b == 0
-                 and (b * HD <= 1 << 18 or b == 128)), None)
+    measure best at the same 256.
+
+    Since PR 46 the block follows the row's width UP to those 2**18
+    elements too, and no longer stops at 256 tokens: a 512-wide row takes
+    512, a row of 256 or narrower 1,024 (measured, PERF.md 6, PR 46: the
+    grouped sweep alone at 128 slots x 16,384 of two key-value heads of
+    128, ~8.2k live tokens a row, takes 2,546 us a layer call with 256,
+    0.61 us a step whatever it moves; 1,802 with 512; 1,516-1,604 with
+    1,024, though it streams 1.06 x the live tokens where 256 streams
+    1.015 x; 1,610 with 2,048, 1.125 x).  A 1,024-wide row keeps 256 and a
+    2,048-wide one 128, as before."""
+    return _block_of((1024, 512, 256, 128), Smax, HD)
+
+
+def chunk_block_k(Smax: int, HD: int) -> Optional[int]:
+    """Keys in one block of the chunk kernel (admission, verify): the
+    single-token sweep's size as it was before PR 46 let that one grow for
+    narrow rows.  The chunk pass takes one key-value head a step, ``D``
+    wide whatever the row, and whether it wants larger blocks has not been
+    measured."""
+    return _block_of((256, 128), Smax, HD)
+
+
+def sweep_block_k(sweep, B: int, Smax: int) -> int:
+    """The block a work list was built for: ``decode_sweep`` lists ``B *
+    Smax / block_k`` entries.  The kernel takes its block from the list it
+    is handed, so the two cannot disagree."""
+    return Smax * B // sweep[0].shape[0]
 
 
 def decode_sweep(pos, B: int, Smax: int, block_k: Optional[int],
@@ -350,8 +383,9 @@ def decode_sweep(pos, B: int, Smax: int, block_k: Optional[int],
     default all) its liveness: a dead row has no entry.  ``window``
     (scalar, may be traced) drops the blocks wholly below the band.  The
     list is a function of the tick's inputs alone: callers that run many
-    layers build it once and hand it to every ``cached_attention`` call.
-    ``block_k`` is ``decode_block_k``'s."""
+    layers build it once and hand it to every ``cached_attention`` call,
+    which builds its kernel for the list's block.  ``block_k`` is
+    ``decode_block_k``'s."""
     if block_k is None or not use_pallas():
         return None                  # the dense reference sweeps nothing
     nb = Smax // block_k
@@ -976,8 +1010,10 @@ def cached_attention(q, cache_k, cache_v, pos,
     live rows of a slot batch — ``pos`` cannot, a row at ``pos`` 0 sees one
     key — and a dead row costs no step, streams nothing and returns zeros.
     ``sweep`` is ``decode_sweep`` of the same ``pos``, ``active`` and
-    ``window``, built by a caller that makes this call once per layer;
-    left out, it is built here.  Multi-token chunks (chunked prefill /
+    ``window``, built by a caller that makes this call once per layer, and
+    the kernel's block is the one the list was built for
+    (``sweep_block_k``); left out, it is built here for ``decode_block_k``'s
+    block.  Multi-token chunks (chunked prefill /
     ``extend``) take the chunk kernel when the shapes tile — O(block) VMEM
     instead of a dense [Sq, Smax] score tensor; remaining shapes use the
     dense reference.
@@ -1017,6 +1053,7 @@ def cached_attention(q, cache_k, cache_v, pos,
     Smax = banks[0].shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
     block_k = decode_block_k(Smax, Hkv * D)
+    key_block = chunk_block_k(Smax, Hkv * D)
     # chunk path: pos may be scalar OR per-row [B] (ragged chunks — the
     # kernel reads its row's frontier from pos_ref[bh // H] everywhere:
     # mask, live range, and DMA clamp); the chunk must tile in the q
@@ -1035,6 +1072,7 @@ def cached_attention(q, cache_k, cache_v, pos,
         ks, vs = banks[2:] if int8_cache else (None, None)
         if sweep is None:
             sweep = decode_sweep(pos, B, Smax, block_k, active, window)
+        block_k = sweep_block_k(sweep, B, Smax)
         if G > 1 and D % 128 == 0:
             o = _gqa_decode(q[:, 0], banks[0], banks[1], layer, pos, sweep,
                             scale, block_k, G)
@@ -1048,14 +1086,14 @@ def cached_attention(q, cache_k, cache_v, pos,
     # one layer, heads unfolded: [B,Smax,Hkv,D] (scales [B,Smax,H,1])
     banks = [jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
              .reshape(B, Smax, Hkv, -1) for x in banks]
-    if use_pallas() and block_k is not None and block_q is not None:
+    if use_pallas() and key_block is not None and block_q is not None:
         def to3(x):
             return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2],
                                                    x.shape[1], -1)
 
         ks3, vs3 = map(to3, banks[2:]) if int8_cache else (None, None)
         o3 = _chunk(to3(q), to3(banks[0]), to3(banks[1]), pos, scale,
-                    block_q, block_k, H, ks3=ks3, vs3=vs3,
+                    block_q, key_block, H, ks3=ks3, vs3=vs3,
                     window=window, slopes=slopes, group=G)
         return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 

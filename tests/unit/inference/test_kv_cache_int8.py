@@ -13,7 +13,17 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from deepspeed_tpu.models import gpt, gpt_inference
 from deepspeed_tpu.ops.pallas.decode_attention import (
-    cached_attention, cached_attention_reference, dequantize_kv, quantize_kv)
+    cached_attention, cached_attention_reference, decode_sweep,
+    dequantize_kv, quantize_kv)
+
+
+def _blocks_of_256(pos, B, Smax, window=None):
+    """The single-token sweep's work list for blocks of 256 tokens, two a
+    512-token slot: the rule gives these narrow rows one block of 512
+    (``decode_block_k``, PR 46), and the kernel takes its block from the
+    list it is handed."""
+    return decode_sweep(pos, B, Smax, 256, None, window)
+
 
 CFG = gpt.GPTConfig(vocab_size=256, max_seq_len=256, n_layer=2, n_head=4,
                     d_model=64, dtype=jnp.float32, vocab_round_to=128)
@@ -193,14 +203,17 @@ def test_windowed_decode_kernel_matches_model_semantics(pallas_interpret,
     ck = jax.random.normal(kk, (B, Smax, H, D), jnp.float32)
     cv = jax.random.normal(kv, (B, Smax, H, D), jnp.float32)
     pos = jnp.asarray(pos, jnp.int32)
+    sweep = _blocks_of_256(pos, B, Smax, jnp.int32(window))
     if int8:
         (ck_s, ck_sc), (cv_s, cv_sc) = quantize_kv(ck), quantize_kv(cv)
         got = cached_attention(q, ck_s, cv_s, pos, k_scale=ck_sc,
-                               v_scale=cv_sc, window=jnp.int32(window))
+                               v_scale=cv_sc, window=jnp.int32(window),
+                               sweep=sweep)
         ck = dequantize_kv(ck_s, ck_sc, jnp.float32)
         cv = dequantize_kv(cv_s, cv_sc, jnp.float32)
     else:
-        got = cached_attention(q, ck, cv, pos, window=jnp.int32(window))
+        got = cached_attention(q, ck, cv, pos, window=jnp.int32(window),
+                               sweep=sweep)
     mcfg = dataclasses.replace(CFG, n_head=H,
                                local_attention_window=window)
     want = gpt._windowed_attention(q, ck, cv, mcfg, window, pos=pos)
@@ -225,14 +238,16 @@ def test_alibi_kernels_match_model_semantics(pallas_interpret, int8, pos,
     pos_arr = jnp.asarray(pos, jnp.int32)
     slopes = gpt.alibi_slopes(H)
     # alibi models use the default 1/sqrt(D) scale (BLOOM)
+    sweep = _blocks_of_256(pos_arr, B, Smax) if sq == 1 else None
     if int8:
         (ck_s, ck_sc), (cv_s, cv_sc) = quantize_kv(ck), quantize_kv(cv)
         got = cached_attention(q, ck_s, cv_s, pos_arr, k_scale=ck_sc,
-                               v_scale=cv_sc, slopes=slopes)
+                               v_scale=cv_sc, slopes=slopes, sweep=sweep)
         ck = dequantize_kv(ck_s, ck_sc, jnp.float32)
         cv = dequantize_kv(cv_s, cv_sc, jnp.float32)
     else:
-        got = cached_attention(q, ck, cv, pos_arr, slopes=slopes)
+        got = cached_attention(q, ck, cv, pos_arr, slopes=slopes,
+                               sweep=sweep)
     mcfg = dataclasses.replace(CFG, n_head=H, pos_embed="alibi")
     want = gpt._alibi_attention(q, ck, cv, mcfg,
                                 q_positions=pos_arr + jnp.arange(sq))
@@ -407,8 +422,8 @@ def test_ragged_chunk_kernel_matches_reference(pallas_interpret, int8,
                                atol=2e-5, rtol=2e-5)
 
 
-# the stacked pool [L, B, Smax, H*D] read where it lies: block_k is 256 at
-# this Smax, so the per-row frontiers below sit on both sides of a block edge
+# the stacked pool [L, B, Smax, H*D] read where it lies, in blocks of 256, so
+# the per-row frontiers below sit on both sides of a block edge
 _POOL_SMAX = 512
 _POOL_CASES = {
     "scalar-pos": dict(pos=100),
@@ -453,7 +468,8 @@ def test_stacked_pool_decode_kernel_matches_reference(pallas_interpret, kind,
     layer = 2
     got = jax.jit(lambda lay: cached_attention(
         q, fold(k), fold(v), pos, window=window, slopes=slopes, layer=lay,
-        **scales))(jnp.int32(layer))
+        sweep=_blocks_of_256(pos, B, Smax, window), **scales))(
+            jnp.int32(layer))
     want = cached_attention_reference(
         q.astype(jnp.float32), ref_k[layer], ref_v[layer], pos,
         window=window, slopes=slopes)

@@ -11,9 +11,14 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.ops.pallas.decode_attention import (
-    cached_attention, cached_attention_reference, dequantize_kv, quantize_kv)
+    cached_attention, cached_attention_reference, chunk_block_k,
+    decode_block_k, decode_sweep, dequantize_kv, quantize_kv)
 
 SMAX = 512
+# the sweeps below keep two blocks a slot: the rule gives this narrow row
+# (4 heads of 32) one block of 512 since PR 46, and a work list built for
+# 256 hands the kernel 256 (``sweep_block_k``)
+BLOCK = 256
 
 
 @pytest.fixture
@@ -60,8 +65,25 @@ def test_masked_decode_sweep(pallas_interpret, kind, window, slopes, per_row,
     return zeros.  A row's last block is multiplied whole, its dead tail
     masked: what the pool (``-garbage``) or an earlier call's buffers
     (``-twice``) hold past the frontier changes no bit."""
+    _masked_decode_sweep(kind, window, slopes, per_row, mask, BLOCK)
+
+
+@pytest.mark.parametrize("mask", sorted(_MASK_CASES))
+@pytest.mark.parametrize("window", [None, 48], ids=["global", "window"])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_masked_decode_sweep_at_the_rules_block(pallas_interpret, kind,
+                                                window, mask):
+    """The same rows with no work list handed in: ``cached_attention``
+    builds one for the rule's block, the whole 512-token slot of this
+    128-wide row, and the kernel takes its block from it."""
+    assert decode_block_k(SMAX, 4 * 32) == 512
+    _masked_decode_sweep(kind, window, False, True, mask, None)
+
+
+def _masked_decode_sweep(kind, window, slopes, per_row, mask, block):
+    """``block``: the work list's, built here and handed in; None leaves
+    list and block to ``cached_attention``."""
     from tests.unit.ops.dense_grid_decode import dense_grid_decode
-    from deepspeed_tpu.ops.pallas.decode_attention import decode_block_k
     rows = _MASK_CASES[mask]
     L, B, Smax, H, D = 3, len(rows), SMAX, 4, 32
     dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
@@ -91,8 +113,10 @@ def test_masked_decode_sweep(pallas_interpret, kind, window, slopes, per_row,
     layer = 2
 
     def sweep(lay, act, k, v, scales):
+        work = block and decode_sweep(pos, B, Smax, block, act, win)
         return cached_attention(q, fold(k), fold(v), pos, window=win,
-                                slopes=slope, layer=lay, active=act, **scales)
+                                slopes=slope, layer=lay, active=act,
+                                sweep=work, **scales)
 
     got = jax.jit(lambda lay, act: sweep(lay, act, k, v, scales))(
         jnp.int32(layer), jnp.asarray(active))
@@ -127,6 +151,145 @@ def test_masked_decode_sweep(pallas_interpret, kind, window, slopes, per_row,
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
     was = dense_grid_decode(
         q.reshape(B, 1, H * D), fold(k), fold(v), layer, pos, 1.0 / D ** 0.5,
-        decode_block_k(Smax, H * D), H, *banks, window=win, slopes=slope)
+        block or decode_block_k(Smax, H * D), H, *banks, window=win,
+        slopes=slope)
     was = np.asarray(was, np.float32).reshape(got.shape)
     np.testing.assert_array_equal(got[active], was[active])
+
+
+# --------------------------------------------------- the block's size (PR 46)
+
+# width of the row: the sweep's block at slots of 96, 512, 1,024 and 16,384
+# tokens.  About 2**18 elements a step: a row of 256 or narrower takes the
+# largest block there is, a 1,024-wide one 256, wider ones the floor.
+_SLOTS = (96, 512, 1024, 16384)
+_SWEEP_BLOCK = {
+    128: (None, 512, 1024, 1024),
+    256: (None, 512, 1024, 1024),    # Nemotron's grouped row: 2 heads of 128
+    512: (None, 512, 512, 512),
+    768: (None, 256, 256, 256),      # GPT-2 small
+    1024: (None, 256, 256, 256),     # gpt2-medium, Granite's grouped row
+    2048: (None, 128, 128, 128),     # OPT-1.3B
+}
+
+
+@pytest.mark.parametrize("smax", _SLOTS)
+@pytest.mark.parametrize("width", sorted(_SWEEP_BLOCK))
+def test_the_sweeps_block_follows_the_rows_width(width, smax):
+    """``decode_block_k`` is one function of the slot's length and the row's
+    width; the chunk kernel's key block is the size the sweep had before it
+    grew for narrow rows."""
+    want = _SWEEP_BLOCK[width][_SLOTS.index(smax)]
+    assert decode_block_k(smax, width) == want
+    assert chunk_block_k(smax, width) == (want and min(want, 256))
+    if want:
+        assert smax % want == 0 and (want * width <= 1 << 18 or want == 128)
+
+
+def _tick_families():
+    from deepspeed_tpu.models import hybrid_ssm_moe
+    M, E, A = "mamba", "experts", "attention"
+    small = dict(vocab_size=256, n_layer=2, n_head=4, d_model=128,
+                 dtype=jnp.float32)
+    hybrid = dict(vocab_size=256, d_model=64, ssm_heads=4, ssm_head_dim=32,
+                  ssm_state=16, ssm_chunk=16, head_dim=128, n_experts=4,
+                  experts_per_token=2, d_expert=32, d_shared=32,
+                  dtype=jnp.float32)
+    return {
+        # 4 heads of 32: a 128-wide row
+        "dense-128": (gpt, gpt.GPTConfig(max_seq_len=512, **small), 512,
+                      None),
+        "dense-128-int8": (gpt, gpt.GPTConfig(max_seq_len=512, **small), 512,
+                           "int8"),
+        # GPT-Neo's alternating band: a work list a layer, built in one go
+        "dense-128-banded": (gpt, gpt.GPTConfig(
+            max_seq_len=1024, local_attention_window=64, **small), 1024,
+            None),
+        # 16 heads of 64, both gpt2-medium cells' row
+        "dense-1024": (gpt, gpt.GPTConfig(
+            max_seq_len=1024, **{**small, "n_head": 16, "d_model": 1024,
+                                 "n_layer": 1}), 1024, None),
+        # Granite's grouped row (8 key-value heads of 128) and Nemotron's
+        # (2), the second a single-part stack with two attention layers
+        "hybrid-1024": (hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
+            max_seq_len=1024, layer_types=(M, A, M), n_head=16, n_kv_head=8,
+            **hybrid), 1024, None),
+        "hybrid-256": (hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
+            max_seq_len=1024, layer_types=(M, E, A, E, M, A, E), n_head=8,
+            n_kv_head=2, mixer_ffn=False, **hybrid), 1024, None),
+    }
+
+
+@pytest.mark.parametrize("family", ["dense-128", "dense-128-int8",
+                                    "dense-128-banded", "dense-1024",
+                                    "hybrid-1024", "hybrid-256"])
+def test_a_tick_hands_the_kernel_the_block_its_work_list_was_built_for(
+        pallas_interpret, family):
+    """``sweep_geometry`` (what the batcher counts live blocks and streamed
+    tokens by), ``_sweeps`` (the tick's work list) and ``cached_attention``
+    (the kernel) of a family agree on the block, and it is the rule's for
+    the family's row: a list built for one block and a kernel built for
+    another would read wrong rows in silence."""
+    from deepspeed_tpu.models import cache_family
+    from deepspeed_tpu.models.gpt_inference import cache_row
+    from tests.unit.ops.traced_sweeps import sweep_calls
+    model, cfg, smax, kv_dtype = _tick_families()[family]
+    slots = 3
+    fam = cache_family(cfg)
+    params = jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: fam.init_cache(cfg, slots, smax,
+                                                  kv_dtype=kv_dtype))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    tick = jax.make_jaxpr(
+        lambda p, c, tok, lengths, active: fam.decode_step(
+            p, tok, cfg, c, lengths=lengths, active=active))(
+        params, cache, rows, rows, jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    width = cache_row(cfg)[0]
+    want = decode_block_k(smax, width)
+    assert want == _SWEEP_BLOCK[width][_SLOTS.index(smax)]
+    assert fam.sweep_geometry(cfg, smax)[0] == want
+    calls = sweep_calls(tick.jaxpr, slots, smax, width)
+    assert calls and all(c[1:] == (want, want) for c in calls), calls
+    assert {c[0] for c in calls} == {
+        "gqa_decode_attention" if family.startswith("hybrid")
+        else "decode_attention"}
+
+
+@pytest.mark.parametrize("block", [256, 1024])
+def test_the_grouped_sweep_at_agent_sats_row(pallas_interpret, block):
+    """``_gqa_decode`` at ``nemotron3n-serve-agent-sat``'s geometry, slots of
+    16,384 tokens of a 256-wide row under 32 query heads, in blocks of 256
+    (the size until PR 46) and of 1,024 (the rule's): ragged rows whose
+    frontiers sit on both sides of the first 1,024-token edge and deep in
+    the slot, a dead row among them, against the dense formula."""
+    from deepspeed_tpu.ops.pallas.decode_attention import _gqa_decode
+    smax, Hq, Hkv, D = 16384, 32, 2, 128
+    G = Hq // Hkv
+    assert decode_block_k(smax, Hkv * D) == 1024
+    pos = jnp.asarray([1022, 1023, 7000, 1024, 1025, 12100], jnp.int32)
+    active = jnp.asarray([True, True, False, True, True, True])
+    B = len(pos)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(46), 3)
+    q = jax.random.normal(kq, (B, Hq, D), jnp.float32).astype(jnp.bfloat16)
+    k = jax.random.normal(kk, (2, B, smax, Hkv * D),
+                          jnp.float32).astype(jnp.bfloat16)
+    v = jax.random.normal(kv, k.shape, jnp.float32).astype(jnp.bfloat16)
+    got = jax.jit(lambda q, k, v: _gqa_decode(
+        q, k, v, 1, pos, decode_sweep(pos, B, smax, block, active),
+        D ** -0.5, block, G))(q, k, v)
+    got = np.asarray(got, np.float32).reshape(B, Hq, D)
+
+    def head(qh, kv_head):
+        """One query head against its key-value head (no head is repeated
+        out to the sixteen that share it)."""
+        one = lambda x: x[1].reshape(B, smax, Hkv, D)[:, :, kv_head][:, :, None]
+        return cached_attention_reference(
+            qh[:, None, None].astype(jnp.float32),
+            one(k).astype(jnp.float32), one(v).astype(jnp.float32), pos,
+            D ** -0.5)[:, 0, 0]
+
+    want = np.stack([np.asarray(head(q[:, h], h // G)) for h in range(Hq)], 1)
+    live = np.asarray(active)
+    # results of 0.035 in the mean, 0.23 at most; bf16 probabilities read
+    # 6e-4 off the float32 formula
+    np.testing.assert_allclose(got[live], want[live], atol=2e-3, rtol=0)

@@ -398,9 +398,10 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
         lambda p, c, tok, lengths, active: fam.decode_step(
             p, tok, cfg, c, lengths=lengths, active=active),
         donate_argnums=(1,))
+    traced = tick.trace(params, cache, rows, rows, live).jaxpr
     _sweep_is_built_outside_the_layer_scan(
-        tick.trace(params, cache, rows, rows, live).jaxpr, slots,
-        segments=_SEGMENTS.get(family, 1))
+        traced, slots, segments=_SEGMENTS.get(family, 1))
+    _sweeps_in_blocks_of(traced, cfg, slots, smax, _SWEEP_BLOCK[family])
     compiled = tick.lower(params, cache, rows, rows, live).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the tick"
@@ -415,6 +416,23 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     if family in ("dense", "moe"):
         _one_dense_sweep_a_layer(text, cfg, cache, slots, smax,
                                  calls=1 if family == "dense" else 2)
+
+
+#: tokens in a block of a family's single-token sweep at its cell's
+#: geometry: 256 for the 1,024-wide rows (both ``gpt2-medium`` cells and
+#: Granite's 8 key-value heads of 128), 1,024 for Nemotron's 256-wide row
+#: (PR 46), the latent sweep's own 512
+_SWEEP_BLOCK = {"dense": 256, "moe": 256, "hybrid": 256,
+                "single_part": 1024, "latent": 512}
+
+
+def _sweeps_in_blocks_of(jaxpr, cfg, slots, smax, block):
+    """Every single-token sweep of the traced tick is built for ``block``
+    tokens a step, and so is the work list it walks."""
+    from deepspeed_tpu.models.gpt_inference import cache_row
+    from tests.unit.ops.traced_sweeps import sweep_calls
+    calls = sweep_calls(jaxpr, slots, smax, cache_row(cfg)[0])
+    assert calls and all(c[1:] == (block, block) for c in calls), calls
 
 
 def _one_dense_sweep_a_layer(text, cfg, cache, slots, smax, calls):
@@ -562,14 +580,23 @@ def test_the_single_part_cells_kernels_at_its_shapes(v5e, kernel):
             arg((64,)), arg((1, 1024, G * N), BF16),
             arg((1, 1024, G * N), BF16), arg((1,), jnp.int32))
     elif kernel == "gqa_decode":
-        pool = arg((2, slots, 16384, 256), BF16)
-        assert decode.decode_block_k(16384, 256) is not None
-        _compiles_with_kernel(
-            lambda q, k, v, pos, live: decode.cached_attention(
-                q, k, v, pos, sm_scale=128 ** -0.5, layer=1, active=live,
-                kv_heads=2),
-            arg((slots, 1, 32, 128), BF16), pool, pool,
-            arg((slots,), jnp.int32), arg((slots,), jnp.bool_))
+        # agent-sat's sweep: blocks of 1,024 tokens of the 256-wide row
+        # (PR 46), two banks x the pipeline's two blocks of 512 KiB beside
+        # the (32, 256) float32 accumulator: under a quarter of the 16 MiB
+        # a v5e's kernel may use
+        from tests.unit.ops.traced_sweeps import sweep_calls
+        smax, width, heads = 16384, 256, 32
+        pool = arg((2, slots, smax, width), BF16)
+        assert decode.decode_block_k(smax, width) == 1024
+        sweep = lambda q, k, v, pos, live: decode.cached_attention(
+            q, k, v, pos, sm_scale=128 ** -0.5, layer=1, active=live,
+            kv_heads=2)
+        shapes = (arg((slots, 1, heads, 128), BF16), pool, pool,
+                  arg((slots,), jnp.int32), arg((slots,), jnp.bool_))
+        assert sweep_calls(jax.make_jaxpr(sweep)(*shapes).jaxpr, slots, smax,
+                           width) == [("gqa_decode_attention", 1024, 1024)]
+        assert 2 * 2 * 1024 * width * 2 + heads * width * 4 < (16 << 20) // 4
+        _compiles_with_kernel(sweep, *shapes)
     else:
         for k, n in ((2688, 1920), (1920, 2688)):
             assert all(side % tile == 0 for side, tile in zip(

@@ -233,13 +233,13 @@ def test_batcher_overflow_and_tick_guards():
 
 def test_live_block_share_counts_the_live_blocks_of_the_live_rows():
     """``ServingMetrics.live_block_share`` by hand: a 4-slot pool of
-    512-token slots, 4 heads of 32 (block_k 256, two blocks a slot), two
-    layers of which the second is banded to 64 tokens; three live rows and a
-    freed slot, which counts in the grid and never in the live blocks."""
+    512-token slots in blocks of 256 (two a slot; what the rule gives a
+    row is ``test_decode_sweep.py``'s), two layers of which the second is
+    banded to 64 tokens; three live rows and a freed slot, which counts in
+    the grid and never in the live blocks."""
     from deepspeed_tpu.ops.pallas.decode_attention import (decode_block_k,
                                                            sweep_block_counts)
     from deepspeed_tpu.serving.metrics import ServingMetrics
-    assert decode_block_k(512, 4 * 32) == 256
     windows = ((None, 1), (64, 1))
     # frontiers 100 | 300 | 511: the global layer steps 1 + 2 + 2 blocks, the
     # banded one 1 + 2 (the band [237, 300] straddles the edge) + 1
@@ -315,22 +315,30 @@ def test_sweep_token_counts_agree_with_the_device_sweep(monkeypatch, window,
         (0, 0)
 
 
-def _decode_sat_replay(copy_rows, requests=400):
-    """``decode-sat``'s draw (prompts 64-256, outputs 128-384, uniform)
-    replayed through ``ServingMetrics`` at its geometry: 24 layer calls a
-    tick over slots of 1,024 in blocks of 256, a reply's every tick."""
+def _replay(seed, requests, prompts, outputs, smax, block, layers, copy_rows):
+    """A cell's draw (``prompts`` and ``outputs``: uniform, both ends
+    included) replayed through ``ServingMetrics`` at its geometry:
+    ``layers`` layer calls a tick over slots of ``smax`` in blocks of
+    ``block``, a reply's every tick."""
     from deepspeed_tpu.ops.pallas.decode_attention import sweep_token_counts
     from deepspeed_tpu.serving.metrics import ServingMetrics
-    rng = np.random.default_rng(45)
+    rng = np.random.default_rng(seed)
     m = ServingMetrics()
     for _ in range(requests):
-        prompt, out = rng.integers(64, 257), rng.integers(128, 385)
+        prompt = rng.integers(prompts[0], prompts[1] + 1)
+        out = rng.integers(outputs[0], outputs[1] + 1)
         # the tick that decodes token t reads the row up to prompt + t
         m.record_tick(active=1, slots=1, tokens=int(out),
                       kv_tokens=sweep_token_counts(
-                          [int(prompt) + t for t in range(out)], 1024, 256,
-                          ((None, 24),), copy_rows))
+                          [int(prompt) + t for t in range(out)], smax, block,
+                          ((None, layers),), copy_rows))
     return m.snapshot()
+
+
+def _decode_sat_replay(copy_rows):
+    """``decode-sat``: prompts 64-256, outputs 128-384, 24 layers over
+    slots of 1,024 in blocks of 256."""
+    return _replay(45, 400, (64, 256), (128, 384), 1024, 256, 24, copy_rows)
 
 
 @pytest.mark.parametrize("case", ["tile-edges", "parent-geometry",
@@ -359,10 +367,27 @@ def test_streamed_over_live(case):
     assert lo <= snap["streamed_over_live"] <= hi, snap["streamed_over_live"]
 
 
+@pytest.mark.parametrize("block,lo,hi", [(256, 1.012, 1.020),
+                                         (1024, 1.055, 1.070)],
+                         ids=["blocks-of-256", "the-rules-1024"])
+def test_streamed_over_live_of_agent_sats_whole_blocks(block, lo, hi):
+    """``agent-sat``'s draw (prompts 5,120-7,168, outputs 3,072-5,120,
+    uniform) replayed at its geometry, two grouped layer calls a tick over
+    slots of 16,384 whose sweep streams whole blocks: the tail of a row's
+    last block is 1.6% of what its query sees at 256 tokens and 6% at the
+    rule's 1,024 (PERF.md 6, PR 46: the larger block costs that and wins
+    by the steps it spares)."""
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_block_k
+    assert decode_block_k(16384, 2 * 128) == 1024
+    got = _replay(46, 64, (5120, 7168), (3072, 5120), 16384, block, 2,
+                  None)["streamed_over_live"]
+    assert lo <= got <= hi, got
+
+
 def test_the_batcher_counts_the_tokens_its_familys_kernel_streams():
     """``SlotBatcher.sweep_tokens``: the dense family's kernel ends its copy
-    on a tile, so a row at 100 streams 112 tokens a layer where its block
-    holds 256."""
+    on a tile, so a row at 100 streams 112 tokens a layer where its block,
+    the whole 512-token slot of this 128-wide row, holds 512."""
     cfg = gpt.GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=128,
                         max_seq_len=512, dtype=jnp.float32,
                         vocab_round_to=128)
@@ -371,9 +396,9 @@ def test_the_batcher_counts_the_tokens_its_familys_kernel_streams():
         config={"dtype": "float32"})
     bat = SlotBatcher(eng, ServingConfig.from_dict(
         {"slots": 2, "max_len": 512, "prefill_chunk": 8}))
-    assert bat.sweep_blocks([100, 300]) == (2 * 3, 2 * 2 * 2)
+    assert bat.sweep_blocks([100, 300]) == (2 * 2, 2 * 2 * 1)
     assert bat.sweep_tokens([100, 300]) == (2 * (101 + 301),
-                                            2 * (112 + 256 + 48))
+                                            2 * (112 + 304))
     assert bat.sweep_tokens([]) == (0, 0)
 
 
