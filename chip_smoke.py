@@ -142,7 +142,7 @@ def _tokens(cfg: gpt.GPTConfig, rows: int, seed: int) -> dict:
 def train_phase(cfg: gpt.GPTConfig, micro_batch: int, seed: int, device,
                 kernels: bool) -> None:
     """Adam, ZeRO-1, bf16, remat saving the attention outputs (the
-    configuration ``bench.py`` times) on ``device``."""
+    configuration the ``gpt2m-train-s1024`` cell times) on ``device``."""
     cfg = dataclasses.replace(cfg, remat=True, remat_policy="attn_out")
     mm = initialize_mesh(ParallelDims(dp=1), devices=[device])
     _, losses, text = _train_steps(

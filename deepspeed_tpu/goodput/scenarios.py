@@ -157,7 +157,13 @@ def _kill_one_rank(seed: int) -> Scenario:
                     "the fleet must bounce, consensus-resume from the last "
                     "committed tag, and finish",
         world_size=2, target_steps=12, save_interval=2, seed=seed,
-        faults=(FaultSpec("train.step", "KillAtStep", {"step": step},
+        # the ranks do not wait for each other: a victim that outruns the
+        # publisher reaches its step before anything is committed, and the
+        # fleet would then resume from nothing.  The scenario is "a rank
+        # dies after the fleet has a checkpoint", so the kill waits, at its
+        # step, for the first journaled commit
+        faults=(FaultSpec("train.step", "KillAtStep",
+                          {"step": step, "after_event": "ckpt.committed"},
                           ranks=(victim,)),),
         expect={"min_goodput": 0.5, "max_mttr_s": 90.0,
                 "expect_kinds": ("fleet.rank_exit", "fleet.restart",

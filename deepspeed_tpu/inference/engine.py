@@ -300,7 +300,6 @@ class InferenceEngine:
         be a multiple of 8 so the verify pass rides the chunk kernel
         (default 7).
         """
-        from ..models import gpt_inference
         from ..models.gpt_moe import GPTMoEConfig
         from .speculative import speculative_generate
         if temperature <= 0 and (top_k > 0 or top_p < 1.0):
@@ -309,7 +308,7 @@ class InferenceEngine:
                 "temperature > 0 (temperature=0 is greedy and would "
                 "silently ignore the filters)")
         if isinstance(draft, InferenceEngine):
-            if draft._family is not gpt_inference:
+            if draft._family.unsupported.get("draft"):
                 raise NotImplementedError(
                     "the draft must be a dense GPT-family engine")
             dcfg, dparams = draft.model_config, draft.params
@@ -351,7 +350,7 @@ class InferenceEngine:
         ``append`` prefills/extends with each turn's tokens (chunked
         prefill — the conversation is never re-prefilled), ``generate``
         decodes a reply that stays in the cache.  Serves every family —
-        MoE sessions ride ``gpt_moe_inference.extend`` the same way.
+        MoE sessions ride their family's ``extend`` the same way.
 
         ``max_len`` is bucketed to a power of two (clamped to the model
         context), so sessions with nearby budgets share one cache

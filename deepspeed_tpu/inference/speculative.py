@@ -150,7 +150,7 @@ def speculative_generate(target_params: PyTree, target_cfg: gpt.GPTConfig,
     from .engine import _tile_cache_len
     # family dispatch: the TARGET may be MoE (verify rides its extend);
     # the draft stays dense (a draft's whole point is being small)
-    tfam = cache_family(target_cfg)
+    tfam, dfam = cache_family(target_cfg), gpt_inference.DENSE
     t_cache_kw = {"kv_dtype": kv_dtype}
     N, K = int(max_new_tokens), int(draft_k)
     V = target_cfg.vocab_size
@@ -168,7 +168,7 @@ def speculative_generate(target_params: PyTree, target_cfg: gpt.GPTConfig,
             "token budget")
     tcache = tfam.init_cache(target_cfg, B, _tile_cache_len(need, ctx),
                              **t_cache_kw)
-    dcache = gpt_inference.init_cache(draft_cfg, B, _tile_cache_len(need, ctx))
+    dcache = dfam.init_cache(draft_cfg, B, _tile_cache_len(need, ctx))
 
     sample = float(temperature) > 0.0
     temp = jnp.float32(max(float(temperature), 1e-6))
@@ -181,7 +181,7 @@ def speculative_generate(target_params: PyTree, target_cfg: gpt.GPTConfig,
 
     tlogits, tcache = tfam.prefill(target_params, prompt,
                                    target_cfg, tcache)
-    _, dcache = gpt_inference.prefill(draft_params, prompt, draft_cfg, dcache)
+    _, dcache = dfam.prefill(draft_params, prompt, draft_cfg, dcache)
     last_t = tlogits[:, -1, :V].astype(jnp.float32)
     if sample:
         key0, sub = jax.random.split(key0)
@@ -214,8 +214,8 @@ def speculative_generate(target_params: PyTree, target_cfg: gpt.GPTConfig,
         # every step appends at each row's OWN frontier (ragged decode)
         def dstep(carry, dk):
             tok, dc, l = carry
-            lg, dc = gpt_inference.decode_step(draft_params, tok,
-                                               draft_cfg, dc, lengths=l)
+            lg, dc = dfam.decode_step(draft_params, tok, draft_cfg, dc,
+                                      lengths=l)
             lg = lg[:, :V].astype(jnp.float32)
             if sample:
                 f = flt(lg)
@@ -231,9 +231,8 @@ def speculative_generate(target_params: PyTree, target_cfg: gpt.GPTConfig,
             dstep, (cur, dcache, l_eff), jax.random.split(dkey, K))
         # drafts: [K, B].  Feed d_K too so the draft cache covers a full
         # acceptance
-        _, dcache = gpt_inference.decode_step(draft_params, last_d,
-                                              draft_cfg, dcache,
-                                              lengths=l_eff + K)
+        _, dcache = dfam.decode_step(draft_params, last_d, draft_cfg, dcache,
+                                     lengths=l_eff + K)
 
         # ---- verify: ONE target pass over [cur, d1..dK] per row, each
         # row's chunk at ITS frontier (ragged extend)

@@ -16,8 +16,13 @@ implementation — and so does GPT-MoE (``gpt_moe_inference``), which brings
 only its own scan step: the cache class, the layer scan and the slot ops
 below are the one cache family of the tree.
 
-What a family brings is a :class:`Family`: its scan step, how a layer makes
-its queries and the row it caches, how it attends, its embedding and head.
+What a family brings is a :class:`Family`, the whole of what a family is
+(``models.cache_family(config)`` returns it): its scan step, how a layer
+makes its queries and the row it caches, how it attends, its banded layers,
+its embedding and head, its uncached ``apply``, what it refuses and what it
+counts.  The cache, the passes and the slot ops below are the object's
+methods, so a caller's ``fam.prefill(params, tokens, cfg, cache)`` reads
+the same for every family.
 The ROW is the family's too (``config.cache_row``: the widths of the banks a
 cached token takes in a layer): the dense block keeps K and V, two banks of
 ``H*D``; a latent-attention block (``latent_moe_inference``) keeps one
@@ -54,17 +59,18 @@ it updated in place.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
-from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Mapping, Optional, Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from . import gpt
-from .gpt import apply, logical_axes  # noqa: F401  (the family's names)
 
 PyTree = Any
 
@@ -122,6 +128,11 @@ def cache_row(config) -> Tuple[int, ...]:
         (config.n_head * config.head_dim,) * 2
 
 
+def cache_layers(config) -> int:
+    """The layers that own banks (``config.cache_layers``), else all."""
+    return getattr(config, "cache_layers", config.n_layer)
+
+
 def init_cache(config, batch: int, max_len: int, kv_dtype=None,
                stats: Optional[Dict[str, slice]] = None) -> KVCache:
     """``kv_dtype``: None → cache in the compute dtype; ``"int8"``/
@@ -129,10 +140,10 @@ def init_cache(config, batch: int, max_len: int, kv_dtype=None,
     halves decode HBM traffic and doubles the context/batch a chip's
     cache budget holds; the two-bank dense row only).  ``stats``: the
     groups of the family's counter vector, name -> where the group lies
-    (its module's ``stats_groups``, which owns the layout; none: no
+    (its ``Family.stats_groups``, which owns the layout; none: no
     vector)."""
     row = cache_row(config)
-    layers = getattr(config, "cache_layers", config.n_layer)
+    layers = cache_layers(config)
     shapes = [(layers, batch, max_len, w) for w in row]
     declared = getattr(config, "cache_state", None)
     state = None if not declared else tuple(
@@ -158,6 +169,88 @@ def init_cache(config, batch: int, max_len: int, kv_dtype=None,
                    stats=jnp.zeros((max(g.stop for g in stats.values()),),
                                    jnp.int32) if stats else None,
                    state=state)
+
+
+# ------------------------------------------------------------- slot ops
+#
+# A continuous-batching server owns ONE fixed-geometry multi-slot cache and
+# retires/admits conversations per ROW without touching the others.  These
+# three ops are that contract: ``row`` may be a traced scalar, so one
+# compiled program serves every slot — admitting into slot 7 never
+# recompiles the program that admitted into slot 2.  They walk whatever
+# banks the family's row has (a bank the cache lacks is None and stays so)
+# and the per-slot state where the family keeps one: every such array leads
+# with ``[layers, B]``, and what follows (tokens, or none) is the slot's.
+
+
+def _each_bank(f, cache: KVCache, *others: KVCache) -> dict:
+    """``f`` over every bank (and scale bank) the cache holds, and over the
+    arrays of its per-slot state."""
+    out = {name: f(getattr(cache, name), *(getattr(o, name) for o in others))
+           for name in ("k", "v", "k_scale", "v_scale")
+           if getattr(cache, name) is not None}
+    if cache.state is not None:
+        out["state"] = tuple(
+            f(a, *(o.state[i] for o in others))
+            for i, a in enumerate(cache.state))
+    return out
+
+
+def _at_slot(buf, row):
+    """Start indices of slot ``row`` in a ``[layers, B, ...]`` array."""
+    return (0, row) + (0,) * (buf.ndim - 2)
+
+
+def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
+    """Insert a batch-1 cache into slot ``row`` of a live multi-slot cache
+    (admission: a newly prefilled prompt lands in a slot freed by a
+    finished generation).  ``src`` must share the cache dtype layout;
+    its ``max_len`` must not exceed the slot cache's.  ``length`` keeps
+    max-frontier semantics — the slot engine tracks per-row lengths
+    itself.  ``src``'s counters are added to the pool's."""
+    if src.int8 != cache.int8:
+        raise ValueError(
+            f"write_slot dtype mismatch: src int8={src.int8}, "
+            f"cache int8={cache.int8}")
+    if src.max_len > cache.max_len:
+        raise ValueError(
+            f"write_slot src max_len {src.max_len} exceeds the slot "
+            f"cache's {cache.max_len}")
+
+    def ins(dst, s):
+        return lax.dynamic_update_slice(dst, s, _at_slot(dst, row))
+
+    return dataclasses.replace(
+        cache, length=jnp.maximum(cache.length, src.length),
+        stats=None if cache.stats is None else cache.stats + src.stats,
+        **_each_bank(ins, cache, src))
+
+
+def reset_slot(cache: KVCache, row) -> KVCache:
+    """Zero slot ``row``'s K/V (and scales, and per-slot state): a retired
+    conversation's K/V never bleeds into the next tenant, even through a
+    masked read, and a state-space layer starts from zero."""
+    def z(buf):
+        blank = jnp.zeros((buf.shape[0], 1) + buf.shape[2:], buf.dtype)
+        return lax.dynamic_update_slice(buf, blank, _at_slot(buf, row))
+
+    return dataclasses.replace(cache, **_each_bank(z, cache))
+
+
+def read_slot(cache: KVCache, row, length=None) -> KVCache:
+    """Slot ``row`` as a batch-1 cache (retiring a live conversation back
+    to a session).  ``length`` is the row's true frontier (the multi-slot
+    ``cache.length`` only tracks the max).  Counters stay with the pool:
+    the copy's start at zero."""
+    def rd(buf):
+        return lax.dynamic_slice(buf, _at_slot(buf, row),
+                                 (buf.shape[0], 1) + buf.shape[2:])
+
+    return dataclasses.replace(
+        cache, length=jnp.asarray(
+            length if length is not None else cache.length, jnp.int32),
+        stats=None if cache.stats is None else jnp.zeros_like(cache.stats),
+        **_each_bank(rd, cache))
 
 
 def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
@@ -240,48 +333,40 @@ def _dense_attend_cached(q, cache, pos, config, idx, active=None,
         active=active, sweep=sweep)
 
 
-def _sweep_block_k(config: gpt.GPTConfig, max_len: int):
-    """The decode kernel's block for this family's row: the one value the
-    host's counts (``sweep_geometry``) and the tick's work list
-    (``_dense_sweeps``) both take; the kernel takes the list's."""
-    from ..ops.pallas.decode_attention import decode_block_k
-    return decode_block_k(max_len, cache_row(config)[0])
-
-
-def sweep_geometry(config: gpt.GPTConfig, max_len: int):
-    """``(block_k, windows)``: the decode kernel's block for this family's
-    row, and its calls in one tick as ``(window or None, layers)`` pairs,
-    one per distinct per-layer window (``sweep_block_counts``'s constants,
-    host values)."""
-    import collections
-
-    import numpy as np
-    windows = gpt.layer_window(config, np.arange(config.n_layer), max_len)
-    return _sweep_block_k(config, max_len), (
-        ((None, config.n_layer),) if windows is None
-        else tuple(collections.Counter(
-            int(w) for w in np.asarray(windows)).items()))
-
-
-def _dense_sweeps(pos, B, config: gpt.GPTConfig, max_len, active):
-    """The decode kernel's work list (``decode_sweep``) for every layer,
-    built ONCE, before the layer scan: a function of the step's ``pos`` and
-    ``active`` alone, and, in a banded stack, of each layer's window (all
-    layers' lists in one vectorised build).  Returns ``idx -> sweep``."""
-    from ..ops.pallas.decode_attention import decode_sweep
-    block_k = _sweep_block_k(config, max_len)
+def _dense_windows(config: gpt.GPTConfig, max_len: int):
+    """The dense stack's banded layers: None where it has none, else
+    ``layer indices -> their windows`` (``gpt.layer_window``, the one
+    source of the alternation rule)."""
     if config.local_attention_window <= 0:
-        sweep = decode_sweep(pos, B, max_len, block_k, active)
-        return lambda idx: sweep
-    windows = gpt.layer_window(config, jnp.arange(config.n_layer), max_len)
-    sweeps = jax.vmap(
-        lambda w: decode_sweep(pos, B, max_len, block_k, active, w))(windows)
-    return lambda idx: jax.tree_util.tree_map(lambda a: a[idx], sweeps)
+        return None
+    return lambda idx: gpt.layer_window(config, idx, max_len)
 
 
-@dataclasses.dataclass(frozen=True)
+#: why a family other than the dense one is refused as a speculative draft
+#: (its ``unsupported["draft"]``)
+DENSE_DRAFTS_ONLY = ("a draft's whole point is being small, and the proposal "
+                     "loop rides the dense family")
+
+
+def _row_plan(config, max_len: int, itemsize: int = 2,
+              windows=((None, 1),)):
+    """The kernel file's plan (``decode_attention.sweep_plan``) for this
+    config's row: which single-token sweep serves it, its block and where
+    its last copy ends.  The row is the config's own declaration
+    (``cache_row``; two banks hold ``row / head_dim`` key-value heads)."""
+    from ..ops.pallas.decode_attention import sweep_plan
+    row = cache_row(config)
+    return sweep_plan(
+        row, max_len, config.n_head,
+        kv_heads=row[0] // config.head_dim if len(row) == 2 else None,
+        itemsize=itemsize, windows=windows)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
 class Family:
-    """What a model family brings to the one cache family.
+    """What a model family is: ``models.cache_family(config)`` returns one,
+    and the engine, the batcher and speculative decoding drive a model
+    through nothing else.  Its hooks, each with the dense block's default:
 
     ``step(params, config, valid)``: the scan's segments, ``[(stacks,
     body), ...]`` in depth order: the parameter stacks a scan walks and
@@ -300,19 +385,103 @@ class Family:
     ``attend_fresh(q, fresh, cache, config, layer)``: a prompt pass from
     position 0; ``attend_cached(q, cache, pos, config, layer, active,
     sweep)``: a chunk or one token against layer ``layer`` of the pool.
-    ``sweeps(pos, B, config, max_len, active) -> (layer -> sweep)``: the
-    decode kernel's work lists, built before the scan.
+    ``windows(config, max_len)``: its banded layers, None for none, else
+    ``bank-owning layer indices -> windows``.  With the row the config
+    declares (``cache_row`` / ``cache_layers``) that is all a family says
+    of its single-token sweep: the kernel, its block and its copy boundary
+    are ``decode_attention.sweep_plan``'s, the work list and the host's
+    counts are built from it here (:meth:`sweep_plan`, :func:`_sweeps`).
     ``embed(params, tokens, config, positions)`` and ``logits(params, x,
-    config)``."""
+    config)``.
+    ``prompt_pass(params, tokens, config, cache, family, valid)``: a
+    family's own prompt pass (GPT-MoE bounds its gate's dispatch tensors);
+    None: one :func:`prefill`.
+
+    Beside the hooks: ``apply(params, tokens, config)``, the uncached
+    full-sequence logits, and ``logical_axes(config)``; ``unsupported``:
+    feature (``speculative``, ``paging``, ``prefix``, ``int8``, ``draft``:
+    serving as a speculative draft) -> why the family is refused it;
+    ``stats_groups(config)``: where each group of its device counters lies
+    in ``cache.stats`` (name -> slice; the one place that knows);
+    ``state_counters``: the names of its group ``state_steps``."""
     step: Any
     project: Any = _dense_project
     attend_fresh: Any = _dense_attend_fresh
     attend_cached: Any = _dense_attend_cached
-    sweeps: Any = _dense_sweeps
+    windows: Any = _dense_windows
     embed: Any = gpt.embed
     logits: Any = gpt.lm_logits
+    prompt_pass: Any = None
+    apply: Any = gpt.apply
+    logical_axes: Any = gpt.logical_axes
+    unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
+    stats_groups: Any = lambda config: {}
+    state_counters: Tuple[str, ...] = ()
+
+    # the cache, the passes and the slot ops: the module's functions below
+    # with this family in them, defined once for every family
+
+    def init_cache(self, config, batch: int, max_len: int,
+                   kv_dtype=None) -> KVCache:
+        why = self.unsupported.get("int8")
+        if why and kv_dtype is not None:
+            raise NotImplementedError(f"{why} (kv_cache_dtype={kv_dtype!r})")
+        return init_cache(config, batch, max_len, kv_dtype,
+                          stats=self.stats_groups(config))
+
+    def prefill(self, params, tokens, config, cache, valid=None):
+        return (self.prompt_pass or prefill)(params, tokens, config, cache,
+                                             family=self, valid=valid)
+
+    def extend(self, params, tokens, config, cache, lengths=None,
+               valid=None):
+        return extend(params, tokens, config, cache, lengths=lengths,
+                      family=self, valid=valid)
+
+    def decode_step(self, params, token, config, cache, lengths=None,
+                    active=None):
+        return decode_step(params, token, config, cache, lengths=lengths,
+                           active=active, family=self)
+
+    write_slot = staticmethod(write_slot)
+    read_slot = staticmethod(read_slot)
+    reset_slot = staticmethod(reset_slot)
+
+    def sweep_plan(self, config, max_len: int, itemsize: int = 2):
+        """The plan of this family's single-token sweep over ``max_len``-
+        token slots (``decode_attention.SweepPlan``), its calls in one tick
+        counted by window (host values): what a server counts live blocks
+        and streamed tokens by, with no device read."""
+        layers = cache_layers(config)
+        of = self.windows(config, max_len)
+        return _row_plan(
+            config, max_len, itemsize,
+            windows=((None, layers),) if of is None else tuple(
+                collections.Counter(
+                    int(w) for w in np.asarray(of(np.arange(layers)))
+                ).items()))
 
 
+def _sweeps(family: Family, pos, B, config, max_len, active):
+    """The single-token sweep's work list (``decode_sweep``) for every
+    bank-owning layer, built ONCE, before the layer scan: a function of
+    the step's ``pos`` and ``active`` alone, and, in a banded stack, of
+    each layer's window (all layers' lists in one vectorised build).  Its
+    block is the plan's, which is the host's and the kernel's.  Returns
+    ``layer -> sweep``."""
+    from ..ops.pallas.decode_attention import decode_sweep
+    block_k = _row_plan(config, max_len).block_k
+    of = family.windows(config, max_len)
+    if of is None:
+        sweep = decode_sweep(pos, B, max_len, block_k, active)
+        return lambda idx: sweep
+    windows = of(jnp.arange(cache_layers(config)))
+    sweeps = jax.vmap(
+        lambda w: decode_sweep(pos, B, max_len, block_k, active, w))(windows)
+    return lambda idx: jax.tree_util.tree_map(lambda a: a[idx], sweeps)
+
+
+#: the dense GPT family; also every speculative draft's
 DENSE = Family(step=dense_step)
 
 
@@ -473,88 +642,6 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
                                        length=jnp.max(pos0) + Sc)
 
 
-# ------------------------------------------------------------- slot ops
-#
-# A continuous-batching server owns ONE fixed-geometry multi-slot cache and
-# retires/admits conversations per ROW without touching the others.  These
-# three ops are that contract: ``row`` may be a traced scalar, so one
-# compiled program serves every slot — admitting into slot 7 never
-# recompiles the program that admitted into slot 2.  They walk whatever
-# banks the family's row has (a bank the cache lacks is None and stays so)
-# and the per-slot state where the family keeps one: every such array leads
-# with ``[layers, B]``, and what follows (tokens, or none) is the slot's.
-
-
-def _each_bank(f, cache: KVCache, *others: KVCache) -> dict:
-    """``f`` over every bank (and scale bank) the cache holds, and over the
-    arrays of its per-slot state."""
-    out = {name: f(getattr(cache, name), *(getattr(o, name) for o in others))
-           for name in ("k", "v", "k_scale", "v_scale")
-           if getattr(cache, name) is not None}
-    if cache.state is not None:
-        out["state"] = tuple(
-            f(a, *(o.state[i] for o in others))
-            for i, a in enumerate(cache.state))
-    return out
-
-
-def _at_slot(buf, row):
-    """Start indices of slot ``row`` in a ``[layers, B, ...]`` array."""
-    return (0, row) + (0,) * (buf.ndim - 2)
-
-
-def write_slot(cache: KVCache, row, src: KVCache) -> KVCache:
-    """Insert a batch-1 cache into slot ``row`` of a live multi-slot cache
-    (admission: a newly prefilled prompt lands in a slot freed by a
-    finished generation).  ``src`` must share the cache dtype layout;
-    its ``max_len`` must not exceed the slot cache's.  ``length`` keeps
-    max-frontier semantics — the slot engine tracks per-row lengths
-    itself.  ``src``'s counters are added to the pool's."""
-    if src.int8 != cache.int8:
-        raise ValueError(
-            f"write_slot dtype mismatch: src int8={src.int8}, "
-            f"cache int8={cache.int8}")
-    if src.max_len > cache.max_len:
-        raise ValueError(
-            f"write_slot src max_len {src.max_len} exceeds the slot "
-            f"cache's {cache.max_len}")
-
-    def ins(dst, s):
-        return lax.dynamic_update_slice(dst, s, _at_slot(dst, row))
-
-    return dataclasses.replace(
-        cache, length=jnp.maximum(cache.length, src.length),
-        stats=None if cache.stats is None else cache.stats + src.stats,
-        **_each_bank(ins, cache, src))
-
-
-def reset_slot(cache: KVCache, row) -> KVCache:
-    """Zero slot ``row``'s K/V (and scales, and per-slot state): a retired
-    conversation's K/V never bleeds into the next tenant, even through a
-    masked read, and a state-space layer starts from zero."""
-    def z(buf):
-        blank = jnp.zeros((buf.shape[0], 1) + buf.shape[2:], buf.dtype)
-        return lax.dynamic_update_slice(buf, blank, _at_slot(buf, row))
-
-    return dataclasses.replace(cache, **_each_bank(z, cache))
-
-
-def read_slot(cache: KVCache, row, length=None) -> KVCache:
-    """Slot ``row`` as a batch-1 cache (retiring a live conversation back
-    to a session).  ``length`` is the row's true frontier (the multi-slot
-    ``cache.length`` only tracks the max).  Counters stay with the pool:
-    the copy's start at zero."""
-    def rd(buf):
-        return lax.dynamic_slice(buf, _at_slot(buf, row),
-                                 (buf.shape[0], 1) + buf.shape[2:])
-
-    return dataclasses.replace(
-        cache, length=jnp.asarray(
-            length if length is not None else cache.length, jnp.int32),
-        stats=None if cache.stats is None else jnp.zeros_like(cache.stats),
-        **_each_bank(rd, cache))
-
-
 def decode_step(params: PyTree, token: jnp.ndarray, config, cache: KVCache,
                 lengths=None, active=None,
                 family: Family = DENSE) -> Tuple[jnp.ndarray, KVCache]:
@@ -576,7 +663,7 @@ def decode_step(params: PyTree, token: jnp.ndarray, config, cache: KVCache,
     positions = pos[:, None] if ragged else pos[None]
     x = family.embed(params, token[:, None], config, positions=positions)
     with jax.named_scope("sweep"):
-        sweep_of = family.sweeps(pos, B, config, cache.max_len, active)
+        sweep_of = _sweeps(family, pos, B, config, cache.max_len, active)
 
     def write(bank, layer, val):
         """One new [B, 1, *] row per slot at [layer, :, pos] (pos shared or

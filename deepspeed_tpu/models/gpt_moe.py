@@ -31,6 +31,10 @@ PyTree = Any
 
 @dataclasses.dataclass(frozen=True)
 class GPTMoEConfig(GPTConfig):
+    #: the module whose ``FAMILY`` ``models.cache_family`` serves this
+    #: config through
+    cache_family = "gpt_moe_inference"
+
     num_experts: int = 8
     moe_top_k: int = 1
     capacity_factor: float = 1.25

@@ -10,9 +10,9 @@ reference issues by hand falls out of XLA's dispatch/combine einsums.
 A GPT-MoE stack is the dense stack with an expert FFN on every second layer,
 and nothing about its cache differs.  The cache class, the pool layout
 ``[n_layer, B, S_max, H*D]`` (layers in depth order), the layer scan and the
-slot ops are ``gpt_inference``'s own; this module supplies what one scan step
-does — a dense block at layer ``2i``, an expert block at layer ``2i+1`` — and
-bounds the gate's dispatch tensors over a long prompt.
+slot ops are ``gpt_inference``'s own; this module's ``FAMILY`` supplies what
+one scan step does — a dense block at layer ``2i``, an expert block at layer
+``2i+1`` — and a prompt pass that bounds the gate's dispatch tensors.
 
 What this family could share with ``latent_moe_inference`` and does not yet:
 the expert FFN.  ``moe/held_experts.py`` (a router, then the held experts'
@@ -31,9 +31,8 @@ from typing import Any, Tuple
 import jax.numpy as jnp
 
 from . import gpt, gpt_inference
-from .gpt_inference import (KVCache, init_cache, read_slot,  # noqa: F401
-                            reset_slot, sweep_geometry, write_slot)
-from .gpt_moe import GPTMoEConfig, _moe_obj, logical_axes  # noqa: F401
+from .gpt_inference import KVCache
+from .gpt_moe import GPTMoEConfig, _moe_obj, logical_axes
 
 PyTree = Any
 
@@ -84,10 +83,6 @@ def moe_step(params: PyTree, config: GPTMoEConfig, valid=None):
               params["moe_blocks"]), body)]
 
 
-#: the dense family's row, projections and attention; only the step differs
-FAMILY = dataclasses.replace(gpt_inference.DENSE, step=moe_step)
-
-
 # dropless gating reserves capacity = tokens-per-call, so the dispatch/
 # combine tensors are [t, E, t] — fine for decode/verify chunks, quadratic
 # for a whole long prompt.  Prefill therefore processes at most this many
@@ -96,44 +91,37 @@ FAMILY = dataclasses.replace(gpt_inference.DENSE, step=moe_step)
 _PREFILL_CHUNK = 128
 
 
-def prefill(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
-            cache: KVCache, valid=None) -> Tuple[jnp.ndarray, KVCache]:
+def _bounded_prefill(params: PyTree, tokens: jnp.ndarray,
+                     config: GPTMoEConfig, cache: KVCache, family,
+                     valid=None) -> Tuple[jnp.ndarray, KVCache]:
     """Prompt pass filling the cache; returns (logits, cache).
 
     Long prompts (> ``_PREFILL_CHUNK`` gated tokens) run as a chain of
     ``extend`` chunks to keep the dropless dispatch tensors bounded at
-    [B·chunk, E, B·chunk] instead of [B·S, E, B·S].  ``valid`` (here and in
-    ``extend``) is the families' common signature; no state here reads it."""
+    [B·chunk, E, B·chunk] instead of [B·S, E, B·S]: ``prefill(t[:, :c]) ;
+    extend(t[:, c:])`` equals one full ``prefill``, the contract the
+    speculative verify pass rides too (dropless gating keeps rows and
+    chunks independent, so neither chunking nor ragged ``lengths`` can
+    perturb a token's routing).  ``valid`` is the families' common
+    signature; no state here reads it, and the chunk walk drops it."""
     B, S = tokens.shape
     if B * S <= _PREFILL_CHUNK:
         return gpt_inference.prefill(params, tokens, config, cache,
-                                     family=FAMILY)
+                                     family=family, valid=valid)
     # chunk bounds depend only on the static shape, so this also
     # unrolls under an outer jit (the engine's whole-generate program)
     chunk = max(_PREFILL_CHUNK // B, 1)
     outs = []
     for s0 in range(0, S, chunk):
-        lg, cache = extend(params, tokens[:, s0:s0 + chunk], config, cache)
+        lg, cache = gpt_inference.extend(params, tokens[:, s0:s0 + chunk],
+                                         config, cache, family=family)
         outs.append(lg)
     return jnp.concatenate(outs, axis=1), cache
 
 
-def extend(params: PyTree, tokens: jnp.ndarray, config: GPTMoEConfig,
-           cache: KVCache, lengths=None,
-           valid=None) -> Tuple[jnp.ndarray, KVCache]:
-    """``gpt_inference.extend`` with the MoE step: ``prefill(t[:, :c]) ;
-    extend(t[:, c:])`` equals one full ``prefill`` — the contract the
-    speculative verify pass rides (dropless gating keeps rows and chunks
-    independent, so neither chunking nor ragged ``lengths`` can perturb a
-    token's routing)."""
-    return gpt_inference.extend(params, tokens, config, cache,
-                                lengths=lengths, family=FAMILY)
-
-
-def decode_step(params: PyTree, token: jnp.ndarray, config: GPTMoEConfig,
-                cache: KVCache, lengths=None,
-                active=None) -> Tuple[jnp.ndarray, KVCache]:
-    """``gpt_inference.decode_step`` with the MoE step."""
-    return gpt_inference.decode_step(params, token, config, cache,
-                                     lengths=lengths, active=active,
-                                     family=FAMILY)
+#: the dense family's row, projections and attention; the step, the prompt
+#: pass and the uncached forward differ
+FAMILY = dataclasses.replace(
+    gpt_inference.DENSE, step=moe_step, prompt_pass=_bounded_prefill,
+    apply=apply, logical_axes=logical_axes,
+    unsupported={"draft": gpt_inference.DENSE_DRAFTS_ONLY})

@@ -67,7 +67,8 @@ MAX_UNIT = 4
 
 @dataclasses.dataclass(frozen=True)
 class HybridSSMMoEConfig:
-    #: the module ``models.cache_family`` serves this config through
+    #: the module whose ``FAMILY`` ``models.cache_family`` serves this
+    #: config through
     cache_family = "hybrid_ssm_moe_inference"
 
     vocab_size: int = 1024

@@ -2,8 +2,8 @@
 row and a per-slot state.
 
 The cache class, the layer scan and the slot ops are ``gpt_inference``'s
-own (the one cache family of the tree); this module brings what
-``gpt_inference.Family`` asks of a model family:
+own (the one cache family of the tree); this module's ``FAMILY`` brings
+what ``gpt_inference.Family`` asks of a model family:
 
 - the **row** (``config.cache_row``): K and V of the key-value heads, two
   banks of ``n_kv_head * head_dim``, for the attention layers alone
@@ -28,13 +28,13 @@ own (the one cache family of the tree); this module brings what
   counters (``STATE_COUNTERS``), each group where ``stats_groups`` says.
 
 Not supported, refused where it is asked for (``UNSUPPORTED``): the int8
-cache, paging, pooled prefixes and speculation.
+cache, paging, pooled prefixes, speculation and serving as a draft.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -42,14 +42,12 @@ from jax import lax
 
 from ..ops.pallas import ssm
 from . import gpt_inference, hybrid_ssm_moe as model
-from .gpt_inference import (KVCache, read_slot, reset_slot,  # noqa: F401
-                            write_slot)
-from .hybrid_ssm_moe import (ATTENTION, MAMBA,  # noqa: F401
-                             HybridSSMMoEConfig, apply, logical_axes)
+from .gpt_inference import KVCache
+from .hybrid_ssm_moe import ATTENTION, MAMBA, HybridSSMMoEConfig
 
 PyTree = Any
 
-#: serving features this family is refused, with the reason
+#: what this family is refused, with the reason
 UNSUPPORTED = {
     "speculative": "a rejected draft token would have to be rolled back out "
                    "of the per-slot state, and a ragged verify pass carries "
@@ -58,6 +56,8 @@ UNSUPPORTED = {
               "in: the pager moves token-indexed banks only",
     "prefix": "a pooled prefix would need a snapshot of the per-slot state "
               "at its end; the pool keeps token-indexed banks only",
+    "int8": "the hybrid state-space family caches in the compute dtype only",
+    "draft": gpt_inference.DENSE_DRAFTS_ONLY,
 }
 
 #: the counters of this family's group ``state_steps`` in ``cache.stats``:
@@ -74,16 +74,6 @@ def stats_groups(config: HybridSSMMoEConfig) -> Dict[str, slice]:
     pairs = 3 + len(config.held)
     return {"moe_pairs": slice(0, pairs),
             "state_steps": slice(pairs, pairs + len(STATE_COUNTERS))}
-
-
-def init_cache(config: HybridSSMMoEConfig, batch: int, max_len: int,
-               kv_dtype=None) -> KVCache:
-    if kv_dtype is not None:
-        raise NotImplementedError(
-            "the hybrid state-space family caches in the compute dtype "
-            f"only (kv_cache_dtype={kv_dtype!r})")
-    return gpt_inference.init_cache(config, batch, max_len,
-                                    stats=stats_groups(config))
 
 
 def _mamba_mixer(x, p, j, cache: KVCache, valid, work,
@@ -183,47 +173,11 @@ def _attend_fresh(q, fresh, cache, config: HybridSSMMoEConfig, idx):
     return _attend_cached(q, cache, jnp.zeros((), jnp.int32), config, idx)
 
 
-def sweep_geometry(config: HybridSSMMoEConfig, max_len: int):
-    """``gpt_inference.sweep_geometry``: the decode kernel's block for the
-    grouped row, one call a tick for each attention layer."""
-    from ..ops.pallas.decode_attention import decode_block_k
-    return decode_block_k(max_len, config.cache_row[0]), (
-        (None, config.cache_layers),)
-
-
-def _sweeps(pos, B, config: HybridSSMMoEConfig, max_len, active):
-    from ..ops.pallas.decode_attention import decode_sweep
-    # the block the host counts by (``sweep_geometry``) is the list's, and
-    # the list's is the kernel's (``sweep_block_k``)
-    sweep = decode_sweep(pos, B, max_len, sweep_geometry(config, max_len)[0],
-                         active)
-    return lambda idx: sweep
-
-
 FAMILY = gpt_inference.Family(
     step=_step, project=_project, attend_fresh=_attend_fresh,
-    attend_cached=_attend_cached, sweeps=_sweeps,
+    attend_cached=_attend_cached, windows=lambda config, max_len: None,
     embed=lambda params, tokens, config, positions=None:
         model.embed(params, tokens, config),
-    logits=model.lm_logits)
-
-
-def prefill(params: PyTree, tokens, config: HybridSSMMoEConfig,
-            cache: KVCache, valid=None) -> Tuple[jnp.ndarray, KVCache]:
-    return gpt_inference.prefill(params, tokens, config, cache,
-                                 family=FAMILY, valid=valid)
-
-
-def extend(params: PyTree, tokens, config: HybridSSMMoEConfig,
-           cache: KVCache, lengths=None,
-           valid=None) -> Tuple[jnp.ndarray, KVCache]:
-    return gpt_inference.extend(params, tokens, config, cache,
-                                lengths=lengths, family=FAMILY, valid=valid)
-
-
-def decode_step(params: PyTree, token, config: HybridSSMMoEConfig,
-                cache: KVCache, lengths=None,
-                active=None) -> Tuple[jnp.ndarray, KVCache]:
-    return gpt_inference.decode_step(params, token, config, cache,
-                                     lengths=lengths, active=active,
-                                     family=FAMILY)
+    logits=model.lm_logits, apply=model.apply,
+    logical_axes=model.logical_axes, unsupported=UNSUPPORTED,
+    stats_groups=stats_groups, state_counters=STATE_COUNTERS)

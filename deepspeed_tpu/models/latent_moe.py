@@ -46,7 +46,8 @@ PyTree = Any
 
 @dataclasses.dataclass(frozen=True)
 class LatentMoEConfig:
-    #: the module ``models.cache_family`` serves this config through
+    #: the module whose ``FAMILY`` ``models.cache_family`` serves this
+    #: config through
     cache_family = "latent_moe_inference"
 
     vocab_size: int = 1024

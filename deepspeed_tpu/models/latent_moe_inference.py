@@ -2,8 +2,8 @@
 and a row.
 
 The cache class, the layer scan and the slot ops are ``gpt_inference``'s
-own (the one cache family of the tree); this module brings what
-``gpt_inference.Family`` asks of a model family:
+own (the one cache family of the tree); this module's ``FAMILY`` brings
+what ``gpt_inference.Family`` asks of a model family:
 
 - the **row**: one bank, ``[c | R(k_r)]`` (``config.cache_row``: the latent
   and the shared rotary key, rounded up to whole lane rows), so the pool is
@@ -18,31 +18,34 @@ own (the one cache family of the tree); this module brings what
   chunk at position 0: the expert layer's cost is linear in a call's
   tokens, so no family-side chunk walk bounds it).
 
-Not supported, refused where the cache is made: the int8 cache (its scale
-banks are per head; a latent row has no heads).  Speculation's dense draft
-and paging are the batcher's to refuse (``serving/batcher.py``).
+Not supported (``UNSUPPORTED``), each refused where it is asked for: the
+int8 cache where the cache is made (its scale banks are per head; a latent
+row has no heads), speculation and paging by the batcher, serving as a
+draft by the engine and the batcher.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Tuple
+from typing import Any, Dict
 
 import jax.numpy as jnp
 
 from . import gpt_inference, latent_moe
-from .gpt_inference import (KVCache, read_slot, reset_slot,  # noqa: F401
-                            write_slot)
-from .latent_moe import (LatentMoEConfig, apply,  # noqa: F401
-                         logical_axes)
+from .gpt_inference import KVCache
+from .latent_moe import LatentMoEConfig
 
 PyTree = Any
 
-#: serving features this family is refused at construction, with the reason
+#: what this family is refused, with the reason
 UNSUPPORTED = {
     "speculative": "a dense draft's proposals are verified by a ragged "
                    "extend this family has never been tested through",
     "paging": "parked latent rows have no re-admission test yet",
+    "int8": "the latent-attention family caches in the compute dtype only: "
+            "the int8 cache's scale banks are per head and a latent row has "
+            "no heads",
+    "draft": gpt_inference.DENSE_DRAFTS_ONLY,
 }
 
 
@@ -52,17 +55,6 @@ def stats_groups(config: LatentMoEConfig) -> Dict[str, slice]:
     if not config.n_moe_layers:
         return {}
     return {"moe_pairs": slice(0, 3 + len(config.held))}
-
-
-def init_cache(config: LatentMoEConfig, batch: int, max_len: int,
-               kv_dtype=None) -> KVCache:
-    if kv_dtype is not None:
-        raise NotImplementedError(
-            "the latent-attention family caches in the compute dtype only: "
-            "the int8 cache's scale banks are per head and a latent row has "
-            f"no heads (kv_cache_dtype={kv_dtype!r})")
-    return gpt_inference.init_cache(config, batch, max_len,
-                                    stats=stats_groups(config))
 
 
 #: the routed experts' matrices: never an ``xs`` of the layer scan (a slice
@@ -116,44 +108,11 @@ def _attend_fresh(q, fresh, cache, config: LatentMoEConfig, idx):
     return _attend_cached(q, cache, jnp.zeros((), jnp.int32), config, idx)
 
 
-def sweep_geometry(config: LatentMoEConfig, max_len: int):
-    """``gpt_inference.sweep_geometry`` for the latent row: its own block,
-    no banded layer."""
-    from ..ops.pallas.decode_attention import latent_block_k
-    return latent_block_k(max_len), ((None, config.n_layer),)
-
-
-def _sweeps(pos, B, config: LatentMoEConfig, max_len, active):
-    from ..ops.pallas.decode_attention import decode_sweep, latent_block_k
-    sweep = decode_sweep(pos, B, max_len, latent_block_k(max_len), active)
-    return lambda idx: sweep
-
-
 FAMILY = gpt_inference.Family(
     step=_step, project=_project, attend_fresh=_attend_fresh,
-    attend_cached=_attend_cached, sweeps=_sweeps,
+    attend_cached=_attend_cached, windows=lambda config, max_len: None,
     embed=lambda params, tokens, config, positions=None:
         latent_moe.embed(params, tokens, config),
-    logits=latent_moe.lm_logits)
-
-
-def prefill(params: PyTree, tokens, config: LatentMoEConfig,
-            cache: KVCache, valid=None) -> Tuple[jnp.ndarray, KVCache]:
-    # ``valid`` (here and in ``extend``): the families' common signature;
-    # no per-slot state here reads it
-    return gpt_inference.prefill(params, tokens, config, cache,
-                                 family=FAMILY)
-
-
-def extend(params: PyTree, tokens, config: LatentMoEConfig, cache: KVCache,
-           lengths=None, valid=None) -> Tuple[jnp.ndarray, KVCache]:
-    return gpt_inference.extend(params, tokens, config, cache,
-                                lengths=lengths, family=FAMILY)
-
-
-def decode_step(params: PyTree, token, config: LatentMoEConfig,
-                cache: KVCache, lengths=None,
-                active=None) -> Tuple[jnp.ndarray, KVCache]:
-    return gpt_inference.decode_step(params, token, config, cache,
-                                     lengths=lengths, active=active,
-                                     family=FAMILY)
+    logits=latent_moe.lm_logits, apply=latent_moe.apply,
+    logical_axes=latent_moe.logical_axes, unsupported=UNSUPPORTED,
+    stats_groups=stats_groups)

@@ -57,7 +57,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -473,13 +473,75 @@ def decode_copy_rows(itemsize: int) -> int:
     return max(16, 32 // itemsize)
 
 
+#: the three single-token sweeps, by the name each kernel is launched under
+DENSE_SWEEP = "decode_attention"
+GROUPED_SWEEP = "gqa_decode_attention"
+LATENT_SWEEP = "latent_decode_attention"
+
+
+class SweepPlan(NamedTuple):
+    """The single-token sweep of one cached row (``sweep_plan``).
+
+    ``kernel``: which of the three sweeps serves the row, or None where
+    none does (``Smax`` does not tile, or grouped heads narrower than a
+    lane row: the chunk kernel or the dense reference then serves the
+    token).  ``block_k``: tokens a step, None only where ``Smax`` does not
+    tile.  ``copy_rows``: where the copy of a row's last block ends, a tile
+    for the dense sweep, None for the two that stream whole blocks.
+    ``windows``: the sweep's calls in one decode step as ``(window or None,
+    layers)`` pairs, the caller's."""
+    kernel: Optional[str]
+    block_k: Optional[int]
+    copy_rows: Optional[int]
+    Smax: int
+    windows: Tuple = ((None, 1),)
+
+    def block_counts(self, positions, rows: int):
+        """``sweep_block_counts`` of this plan: ``(live, grid)`` blocks."""
+        return sweep_block_counts(positions, rows, self.Smax, self.block_k,
+                                  self.windows)
+
+    def token_counts(self, positions):
+        """``sweep_token_counts`` of this plan: ``(live, streamed)``
+        tokens."""
+        return sweep_token_counts(positions, self.Smax, self.block_k,
+                                  self.windows, self.copy_rows)
+
+
+def sweep_plan(widths, Smax: int, heads: int, kv_heads: Optional[int] = None,
+               itemsize: int = 2, windows=((None, 1),)) -> SweepPlan:
+    """Which sweep serves a row, its block and where its last copy ends:
+    the one place that says so.  ``cached_attention`` dispatches on it, a
+    family's work list (``decode_sweep``) is built for its ``block_k`` and
+    the host counts by its methods.
+
+    ``widths``: the row's banks (one: a latent row shared by all heads;
+    two: K and V of ``kv_heads`` key-value heads, default ``heads``);
+    ``itemsize``: a bank element's bytes (the dense copy ends on a tile of
+    them).  A latent row's rank plays no part: it says how much of the row
+    the probabilities weigh, not how the row is streamed."""
+    if len(widths) == 1:
+        block_k = latent_block_k(Smax)
+        kernel, copy_rows = LATENT_SWEEP, None
+    else:
+        Hkv = heads if kv_heads is None else int(kv_heads)
+        block_k = decode_block_k(Smax, widths[0])
+        if Hkv == heads:
+            kernel, copy_rows = DENSE_SWEEP, decode_copy_rows(itemsize)
+        else:       # grouped heads: lane-aligned or not served by a sweep
+            kernel = GROUPED_SWEEP if (widths[0] // Hkv) % 128 == 0 else None
+            copy_rows = None
+    return SweepPlan(kernel if block_k is not None else None, block_k,
+                     copy_rows, Smax, tuple(windows))
+
+
 # buffers a bank: the copies of the next ``_BUFFERS - 1`` steps are in
 # flight while a step computes
 _BUFFERS = 3
 
 
-def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, ks=None,
-            vs=None, window=None, slopes=None):
+def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, copy_rows,
+            ks=None, vs=None, window=None, slopes=None):
     """Single scalar-prefetch build for every decode variant, reading the
     stacked pool ``k``/``v`` [L, B, Smax, H*D] where it lies.  The sweep
     (``decode_sweep``), ``pos``, ``layer`` (and window, when banded) are
@@ -496,12 +558,13 @@ def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, ks=None,
     the same body runs the static ``B * Smax/block_k`` steps, skips the
     tail and copies whole blocks.  ``q`` and the result are ``[B, 1, H*D]``
     (a dead row's result is never written); ``ks``/``vs`` [L, B, Smax,
-    H]."""
+    H]; ``copy_rows``: the plan's (``sweep_plan``)."""
     B, _, HD = q.shape
     quantized = ks is not None
     windowed = window is not None
     interpret = interpret_mode()
-    copy_rows = None if interpret else decode_copy_rows(k.dtype.itemsize)
+    if interpret:
+        copy_rows = None
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                block_k=block_k, H=H, D=HD // H,
                                quantized=quantized, windowed=windowed,
@@ -955,8 +1018,9 @@ def latent_cached_attention(q, bank, pos, sm_scale: float, rank: int,
     if layer is None:
         bank, layer = bank[None], 0
     Smax = bank.shape[2]
-    block_k = latent_block_k(Smax)
-    tiles = use_pallas() and block_k is not None
+    plan = sweep_plan((W,), Smax, H)
+    block_k = plan.block_k
+    tiles = use_pallas() and plan.kernel is not None
     if tiles and Sq == 1:
         if sweep is None:
             sweep = decode_sweep(pos, B, Smax, block_k, active)
@@ -1012,8 +1076,8 @@ def cached_attention(q, cache_k, cache_v, pos,
     ``sweep`` is ``decode_sweep`` of the same ``pos``, ``active`` and
     ``window``, built by a caller that makes this call once per layer, and
     the kernel's block is the one the list was built for
-    (``sweep_block_k``); left out, it is built here for ``decode_block_k``'s
-    block.  Multi-token chunks (chunked prefill /
+    (``sweep_block_k``); left out, it is built here for the plan's block
+    (``sweep_plan``, which also says which of the sweeps serves the row).  Multi-token chunks (chunked prefill /
     ``extend``) take the chunk kernel when the shapes tile — O(block) VMEM
     instead of a dense [Sq, Smax] score tensor; remaining shapes use the
     dense reference.
@@ -1052,7 +1116,8 @@ def cached_attention(q, cache_k, cache_v, pos,
         layer = 0
     Smax = banks[0].shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    block_k = decode_block_k(Smax, Hkv * D)
+    plan = sweep_plan((Hkv * D,) * 2, Smax, H, Hkv,
+                      banks[0].dtype.itemsize)
     key_block = chunk_block_k(Smax, Hkv * D)
     # chunk path: pos may be scalar OR per-row [B] (ragged chunks — the
     # kernel reads its row's frontier from pos_ref[bh // H] everywhere:
@@ -1068,20 +1133,19 @@ def cached_attention(q, cache_k, cache_v, pos,
             return o
         return jnp.where(active[:, None, None, None], o, jnp.zeros_like(o))
 
-    if use_pallas() and block_k is not None and Sq == 1:
-        ks, vs = banks[2:] if int8_cache else (None, None)
+    if use_pallas() and plan.kernel is not None and Sq == 1:
         if sweep is None:
-            sweep = decode_sweep(pos, B, Smax, block_k, active, window)
+            sweep = decode_sweep(pos, B, Smax, plan.block_k, active, window)
         block_k = sweep_block_k(sweep, B, Smax)
-        if G > 1 and D % 128 == 0:
+        if plan.kernel == GROUPED_SWEEP:
             o = _gqa_decode(q[:, 0], banks[0], banks[1], layer, pos, sweep,
                             scale, block_k, G)
-            return dead_rows_zero(o.reshape(B, 1, H, D))
-        if G == 1:
+        else:
+            ks, vs = banks[2:] if int8_cache else (None, None)
             o = _decode(q.reshape(B, 1, H * D), banks[0], banks[1], layer,
-                        pos, sweep, scale, block_k, H, ks=ks, vs=vs,
-                        window=window, slopes=slopes)
-            return dead_rows_zero(o.reshape(B, 1, H, D))
+                        pos, sweep, scale, block_k, H, plan.copy_rows,
+                        ks=ks, vs=vs, window=window, slopes=slopes)
+        return dead_rows_zero(o.reshape(B, 1, H, D))
 
     # one layer, heads unfolded: [B,Smax,Hkv,D] (scales [B,Smax,H,1])
     banks = [jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)

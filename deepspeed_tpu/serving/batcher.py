@@ -58,9 +58,6 @@ from ..inference.bucketing import bucket_cache_len, bucket_draft_k
 from ..inference.sampling import filter_logits
 from ..inference.speculative import (spec_accept_batch, spec_accept_keys,
                                      spec_draft_keys)
-from ..ops.pallas.decode_attention import (decode_copy_rows,
-                                            sweep_block_counts,
-                                            sweep_token_counts)
 from ..telemetry.spans import SpanName, Tracer
 from ..utils.compile_watch import CompiledProgramRegistry, hot_path
 from .config import ServingConfig
@@ -86,7 +83,8 @@ class PrefixEntry:
 
 
 def admission(fam, cfg, max_len: int, kv_dtype):
-    """The function of the admission program for model family ``fam`` at
+    """The function of the admission program for model family ``fam`` (a
+    ``gpt_inference.Family``, as ``models.cache_family`` returns it) at
     ``cfg`` over ``max_len``-token slots: what :class:`SlotBatcher`
     registers as ``admit`` / ``admit_prefix`` (and again at the wide
     chunk), and what the compile tests lower for a described chip."""
@@ -209,28 +207,21 @@ class SlotBatcher:
         #: bytes of the batch-1 cache every fresh prefill allocates: the
         #: family's row, whatever its banks
         self._row_cache_bytes = cache_bank_bytes(self.cache) // B
-        #: ``sweep_blocks``'s constants, the family's: the decode kernel's
-        #: block for its row and its calls in one tick as (window, layers)
-        #: pairs, one per distinct per-layer window
-        self._block_k, self._layer_windows = fam.sweep_geometry(
-            cfg, self.max_len)
-        #: where the decode kernel's copy of a row's last block ends: on
-        #: a tile for the dense sweep (two banks of all heads, which
-        #: ``cached_attention`` gives ``_decode``), on the block's end for
-        #: the grouped and the latent one (None)
-        self._copy_rows = decode_copy_rows(self.cache.k.dtype.itemsize) \
-            if self.cache.v is not None \
-            and self.cache.k.shape[-1] == cfg.n_head * cfg.head_dim else None
+        #: the plan of the family's single-token sweep over this pool (its
+        #: kernel, block, copy boundary and calls a tick): what
+        #: ``sweep_blocks`` and ``sweep_tokens`` count by
+        self._sweep = fam.sweep_plan(cfg, self.max_len,
+                                     self.cache.k.dtype.itemsize)
         #: what the family's scan steps counted on the device
         #: (``KVCache.stats``), summed over the ticks pulled so far; None
         #: for a family that counts nothing
         self.device_counts = None
-        #: where each group of counters lies in them: the family's layout
-        #: (``stats_groups`` of its module), read by name through ``counts``
-        self._stats_groups = getattr(fam, "stats_groups", lambda cfg: {})(cfg)
+        #: where each group of counters lies in them: the family's layout,
+        #: read by name through ``counts``
+        self._stats_groups = fam.stats_groups(cfg)
         #: the names of the group ``state_steps``, which a family with
-        #: per-slot state has (``STATE_COUNTERS`` of its module)
-        self.state_counters = tuple(getattr(fam, "STATE_COUNTERS", ()))
+        #: per-slot state has
+        self.state_counters = fam.state_counters
         self.lengths = jnp.zeros((B,), jnp.int32)
         self.keys = jnp.stack([jax.random.PRNGKey(0)] * B)
         self.greedy = jnp.ones((B,), bool)
@@ -289,9 +280,9 @@ class SlotBatcher:
         return self.device_counts[where]
 
     def unsupported(self, feature: str) -> Optional[str]:
-        """Why the family does not serve ``feature`` (``UNSUPPORTED`` of its
-        module), or None if it does."""
-        return getattr(self._fam, "UNSUPPORTED", {}).get(feature)
+        """Why the family does not serve ``feature`` (its ``unsupported``),
+        or None if it does."""
+        return self._fam.unsupported.get(feature)
 
     def refuse(self, feature: str) -> None:
         """Raise if the family does not serve ``feature``, with its
@@ -330,7 +321,7 @@ class SlotBatcher:
             dparams = gpt.init(dcfg, jax.random.PRNGKey(
                 int(d.get("seed", 0))))
         elif hasattr(draft, "model_config") and hasattr(draft, "params"):
-            if draft._family is not gpt_inference:
+            if draft._family.unsupported.get("draft"):
                 raise NotImplementedError(
                     "the serving draft must be a dense GPT-family engine")
             dcfg, dparams = draft.model_config, draft.params
@@ -356,7 +347,7 @@ class SlotBatcher:
         self._dparams = jax.tree_util.tree_map(
             lambda p: p.astype(cfg.dtype)
             if jnp.issubdtype(p.dtype, jnp.floating) else p, dparams)
-        self._dfam = gpt_inference
+        self._dfam = gpt_inference.DENSE
         self.draft_k = bucket_draft_k(int(spec_cfg.draft_k),
                                       cap=self.max_len)
 
@@ -815,15 +806,13 @@ class SlotBatcher:
         what the decode kernel steps over all layers, and what the whole
         slot grid holds.  Counted from lengths, never read off the
         device."""
-        return sweep_block_counts(frontiers, self.slots, self.max_len,
-                                  self._block_k, self._layer_windows)
+        return self._sweep.block_counts(frontiers, self.slots)
 
     def sweep_tokens(self, frontiers) -> Tuple[int, int]:
         """``(live, streamed)`` cached tokens of the same tick: what its
         rows' queries see over all layers, and what the decode kernel's
         copies move for them (``sweep_token_counts``)."""
-        return sweep_token_counts(frontiers, self.max_len, self._block_k,
-                                  self._layer_windows, self._copy_rows)
+        return self._sweep.token_counts(frontiers)
 
     @hot_path
     def launch(self):

@@ -181,7 +181,7 @@ class ServingMetrics:
 
     def record_state_steps(self, counts: dict) -> None:
         """``counts``: name -> cumulative count, the batcher's group
-        ``state_steps`` under the family's ``STATE_COUNTERS``."""
+        ``state_steps`` under the family's ``state_counters``."""
         with self._lock:
             self.state_steps = dict(counts)
 

@@ -18,8 +18,8 @@ at the shared run dir, so telemetry breakage under restarts is a scored
 observable, not a silent gap.
 
 Online MFU rides on the same analytic FLOPs model the benchmarks use
-(``models/gpt.py::flops_per_token`` + the per-generation peak table from
-``bench.py``): :func:`analytic_mfu` is pure arithmetic, unit-tested
+(``models/gpt.py::flops_per_token`` + the per-generation peak table
+below, the chip benchmark's ``benchmarks/chip/peaks.py``): :func:`analytic_mfu` is pure arithmetic, unit-tested
 against a hand-computed fixture.
 """
 
@@ -477,7 +477,8 @@ def read_metrics(path: str) -> List[Dict[str, Any]]:
 
 
 # ------------------------------------------------------------- online MFU
-#: peak dense bf16 FLOP/s per chip by device generation (bench.py's table)
+#: peak dense bf16 FLOP/s per chip by device generation (the figures of
+#: ``benchmarks/chip/peaks.py``)
 _PEAK_BY_KIND = (("v5 lite", 197e12), ("v5e", 197e12), ("v5p", 459e12),
                  ("v5", 459e12), ("v6", 918e12), ("v4", 275e12),
                  ("v3", 123e12), ("v2", 45e12))
@@ -500,7 +501,7 @@ def analytic_mfu(tokens_per_s: float, flops_per_token: float,
     tokens/s × analytic FLOPs/token; MFU = achieved / (peak × chips).
 
     Returns ``{"tflops": ..., "mfu": ...}`` (mfu 0.0 when the peak is
-    unknown, mirroring ``bench.py``)."""
+    unknown)."""
     achieved = float(tokens_per_s) * float(flops_per_token)
     mfu = achieved / (float(peak_flops) * max(1, int(n_chips))) \
         if peak_flops else 0.0

@@ -260,15 +260,46 @@ class CorruptRandomBytes(Fault):
 
 class SignalAtStep(Fault):
     """Deliver ``sig`` to this process when the train loop reaches ``step``
-    (the cloud preemption notice, scripted)."""
+    (the cloud preemption notice, scripted).
 
-    def __init__(self, step: int, sig: int = signal.SIGTERM):
+    ``after_event``: hold the signal, at that step, until an event of this
+    kind stands in the run's journal (``JOURNAL`` in the process's
+    directory, where a fleet's ranks write it), at most ``WAIT_S`` seconds.
+    Ranks that no per-step collective couples run at their own pace, so
+    "step N" alone does not say what the rest of the fleet has done by
+    then."""
+
+    JOURNAL = "events.jsonl"
+    WAIT_S = 60.0
+
+    def __init__(self, step: int, sig: int = signal.SIGTERM,
+                 after_event: Optional[str] = None):
         self.step = step
         self.sig = sig
+        self.after_event = after_event
         self.fired = 0
+
+    def _journaled(self) -> bool:
+        import json as _json
+        try:
+            with open(self.JOURNAL) as f:
+                lines = f.readlines()
+        except OSError:                     # no journal yet
+            return False
+        for line in lines:
+            try:
+                if _json.loads(line).get("kind") == self.after_event:
+                    return True
+            except ValueError:              # a line still being written
+                continue
+        return False
 
     def fire(self, point: str, step: Optional[int] = None, **ctx) -> None:
         if step == self.step:
+            if self.after_event is not None:
+                deadline = time.monotonic() + self.WAIT_S
+                while not self._journaled() and time.monotonic() < deadline:
+                    time.sleep(0.02)
             self.fired += 1
             os.kill(os.getpid(), self.sig)
 
@@ -278,8 +309,9 @@ class KillAtStep(SignalAtStep):
     preemption (no notice, no drain).  The goodput fleet's bread and
     butter: the supervisor must detect the corpse and respawn the rank."""
 
-    def __init__(self, step: int, sig: int = signal.SIGKILL):
-        super().__init__(step, sig=sig)
+    def __init__(self, step: int, sig: int = signal.SIGKILL,
+                 after_event: Optional[str] = None):
+        super().__init__(step, sig=sig, after_event=after_event)
 
 
 class ExitAtStep(Fault):

@@ -39,8 +39,8 @@ def enable_compile_cache() -> str:
 def require_tpu() -> list:
     """``jax.devices()`` of the attached TPU; raises where there is none.
 
-    Measurement entry points (``chip_smoke.py``, ``bench.py``, the timing
-    scripts) call this first: a missing chip is an error, never a CPU
+    Measurement entry points (``chip_smoke.py``, ``benchmarks/chip/run.py``)
+    call this first: a missing chip is an error, never a CPU
     number under a device metric's name.
     """
     import jax

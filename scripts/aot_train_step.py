@@ -107,7 +107,7 @@ def compile_step(config, micro_batch: int, *, chips: int = 1, tp: int = 1,
             (gas, rows, width), jnp.int32,
             sharding=NamedSharding(mm.mesh, P(None, DP_GROUP)))
 
-    if is_bert:   # the batches bench.py feeds each model
+    if is_bert:   # the batches each model's train step takes
         batches = {"tokens": tokens(config.max_seq_len),
                    "mlm_labels": tokens(config.max_seq_len)}
     else:
@@ -159,10 +159,10 @@ def main(argv=None) -> int:
     if jax.default_backend() != "cpu":
         raise SystemExit("run with JAX_PLATFORMS=cpu: this compiles for a "
                          "described chip and must not attach a real one")
-    if args.model == "bert-large":   # as `bench.py bert` configures it
+    if args.model == "bert-large":   # BERT-large at seq 128, remat on
         config = dataclasses.replace(bert.BERT_LARGE, max_seq_len=128,
                                      dtype=jnp.bfloat16, remat=True)
-    else:                            # as `bench.py` and chip_smoke.py do
+    else:                            # as chip_smoke.py's train phase does
         config = dataclasses.replace(
             gpt.PRESETS[args.model], max_seq_len=1024, dtype=jnp.bfloat16,
             remat=True, remat_policy=args.remat_policy)

@@ -26,8 +26,8 @@ Usage:
                                  [--modes none,int8,int4,onebit,zero_int8]
                                  [--out BENCH_COMM.json] [--no-gate]
 
-Prints one JSON summary line to stdout (the ``mfu_sweep.py --set comm``
-row contract); human-readable detail goes to stderr.
+Prints one JSON summary line to stdout (one row, for a caller that
+collects them); human-readable detail goes to stderr.
 """
 
 from __future__ import annotations

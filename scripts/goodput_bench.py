@@ -187,7 +187,7 @@ def main(argv=None) -> int:
                     help="keep per-scenario run dirs under this directory")
     ap.add_argument("--print-json", action="store_true",
                     help="emit a one-line JSON summary on stdout "
-                         "(the mfu_sweep trajectory-log contract)")
+                         "(one row, for a caller that collects them)")
     args = ap.parse_args(argv)
 
     baseline_path = args.baseline or args.out

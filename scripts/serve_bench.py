@@ -638,7 +638,7 @@ def main(argv=None) -> int:
                          "(informational, gates nothing)")
     ap.add_argument("--print-json", action="store_true",
                     help="print the result as one JSON line on stdout "
-                         "(mfu_sweep row protocol)")
+                         "(one row, for a caller that collects them)")
     ap.add_argument("--out", default="BENCH_SERVE.json")
     args = ap.parse_args(argv)
 

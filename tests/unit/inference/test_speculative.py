@@ -309,7 +309,8 @@ def test_speculative_filters_require_temperature():
 def test_moe_extend_composes_with_prefill():
     """MoE chunked prefill: prefill(t[:, :c]) ; extend(t[:, c:]) equals
     one full prefill — the contract the MoE verify pass rides."""
-    from deepspeed_tpu.models import gpt_moe, gpt_moe_inference as mfam
+    from deepspeed_tpu.models import gpt_moe, gpt_moe_inference
+    mfam = gpt_moe_inference.FAMILY
     cfg = gpt_moe.GPTMoEConfig(
         vocab_size=256, max_seq_len=128, n_layer=2, n_head=4, d_model=64,
         dtype=jnp.float32, vocab_round_to=128,
@@ -338,7 +339,8 @@ def test_speculative_moe_target_matches_plain_greedy():
     bit-identical to the MoE model decoding alone (reference MoE
     inference has no speculation at all — this closes the refused
     combo)."""
-    from deepspeed_tpu.models import gpt_moe, gpt_moe_inference as mfam
+    from deepspeed_tpu.models import gpt_moe, gpt_moe_inference
+    mfam = gpt_moe_inference.FAMILY
     cfg = gpt_moe.GPTMoEConfig(
         vocab_size=256, max_seq_len=256, n_layer=2, n_head=4, d_model=64,
         dtype=jnp.float32, vocab_round_to=128,

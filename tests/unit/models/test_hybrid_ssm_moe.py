@@ -61,12 +61,13 @@ def _gateway(cfg, params, **serving):
 
 def test_the_family_shares_the_one_cache_family_and_adds_a_state_leaf():
     cfg, _ = _model(_file())
-    assert cache_family(cfg) is hybrid_ssm_moe_inference
+    fam = cache_family(cfg)
+    assert fam is hybrid_ssm_moe_inference.FAMILY
+    assert isinstance(fam, gpt_inference.Family)
     assert hybrid_ssm_moe_inference.KVCache is gpt_inference.KVCache
     for op in ("write_slot", "read_slot", "reset_slot"):
-        assert getattr(hybrid_ssm_moe_inference, op) is \
-            getattr(gpt_inference, op)
-    cache = hybrid_ssm_moe_inference.init_cache(cfg, 3, 64)
+        assert op not in vars(hybrid_ssm_moe_inference), op
+    cache = fam.init_cache(cfg, 3, 64)
     # the banks belong to the one attention layer, the grouped row; the
     # state to the three state-space layers, with no token axis
     assert cfg.runs == (("mamba", 0, 2), ("attention", 0, 1),
@@ -238,7 +239,7 @@ def test_a_padded_tail_and_an_inactive_slot_leave_the_state_bit_for_bit():
     padding holds; a tick leaves a freed slot's ``H`` and convolution tail
     exactly as they were."""
     cfg, params = _model(_file())
-    fam = hybrid_ssm_moe_inference
+    fam = hybrid_ssm_moe_inference.FAMILY
     rng = np.random.default_rng(5)
     tokens = rng.integers(0, cfg.vocab_size, (1, CHUNK)).astype(np.int32)
     other = tokens.copy()
@@ -270,7 +271,7 @@ def test_a_padded_tail_and_an_inactive_slot_leave_the_state_bit_for_bit():
 
 def test_slot_ops_on_the_state_leaf():
     cfg, params = _model(_file())
-    fam = hybrid_ssm_moe_inference
+    fam = hybrid_ssm_moe_inference.FAMILY
     tokens = jnp.arange(7, dtype=jnp.int32)[None] % cfg.vocab_size
     _, row = fam.prefill(params, tokens, cfg, fam.init_cache(cfg, 1, 32))
     pool = fam.write_slot(fam.init_cache(cfg, 3, 32), 2, row)
@@ -404,7 +405,8 @@ def test_a_pooled_prefix_and_the_int8_cache_are_refused():
     with pytest.raises(NotImplementedError, match="prefix"):
         gateway.submit(tokens, max_new_tokens=2, prefix_len=8)
     with pytest.raises(NotImplementedError, match="compute dtype"):
-        hybrid_ssm_moe_inference.init_cache(cfg, 1, 32, kv_dtype="int8")
+        hybrid_ssm_moe_inference.FAMILY.init_cache(cfg, 1, 32,
+                                                   kv_dtype="int8")
 
 
 def test_the_published_sizes():

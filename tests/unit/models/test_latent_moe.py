@@ -49,11 +49,13 @@ def _model(file, seed=0):
 
 def test_the_family_is_picked_by_its_config_and_shares_the_one_cache_family():
     cfg, _ = _model(_file())
-    assert cache_family(cfg) is latent_moe_inference
+    fam = cache_family(cfg)
+    assert fam is latent_moe_inference.FAMILY
+    assert isinstance(fam, gpt_inference.Family)
     assert latent_moe_inference.KVCache is gpt_inference.KVCache
     for op in ("write_slot", "read_slot", "reset_slot"):
-        assert getattr(latent_moe_inference, op) is getattr(gpt_inference, op)
-    cache = latent_moe_inference.init_cache(cfg, 3, 64)
+        assert op not in vars(latent_moe_inference), op
+    cache = fam.init_cache(cfg, 3, 64)
     # one bank, the latent row in whole lane rows; no second bank
     assert cache.v is None and cache.k.shape == (cfg.n_layer, 3, 64, 128)
     assert cfg.row_elements == 40 and cfg.cache_row == (128,)
@@ -220,7 +222,7 @@ def test_latent_kernels_under_the_interpreter(monkeypatch, sq):
 def test_what_the_family_does_not_serve_is_refused_at_construction():
     cfg, params = _model(_file())
     with pytest.raises(NotImplementedError, match="scale banks"):
-        latent_moe_inference.init_cache(cfg, 2, 64, kv_dtype="int8")
+        latent_moe_inference.FAMILY.init_cache(cfg, 2, 64, kv_dtype="int8")
     engine = deepspeed_tpu.init_inference(model=(cfg, params),
                                           config={"dtype": "float32"})
     base = {"slots": 2, "max_len": 64, "prefill_chunk": 16}

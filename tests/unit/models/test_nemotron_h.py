@@ -164,8 +164,8 @@ def test_a_sibling_configuration_is_refused_by_name(key, value, said):
 
 def test_the_cache_holds_two_row_layers_and_state_for_the_mixers_alone():
     cfg, _ = _model(_file())
-    assert cache_family(cfg) is hybrid_ssm_moe_inference
-    cache = hybrid_ssm_moe_inference.init_cache(cfg, 3, 64)
+    assert cache_family(cfg) is hybrid_ssm_moe_inference.FAMILY
+    cache = cache_family(cfg).init_cache(cfg, 3, 64)
     n_ssm, n_attn = cfg.count("mamba"), cfg.count("attention")
     assert (n_ssm, n_attn, cfg.count("experts")) == (3, 1, 4)
     assert cache.k.shape == cache.v.shape == (n_attn, 3, 64, 2 * 16)

@@ -16,6 +16,9 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from deepspeed_tpu.models import gpt_moe, gpt_moe_inference
 
+#: the family object, as ``models.cache_family`` returns it
+MOE = gpt_moe_inference.FAMILY
+
 CFG = gpt_moe.GPTMoEConfig(
     vocab_size=128, max_seq_len=64, n_layer=2, n_head=2, d_model=32,
     dtype=jnp.float32, vocab_round_to=128, num_experts=4, moe_top_k=1,
@@ -30,8 +33,8 @@ def test_moe_prefill_matches_full_forward():
     params = _params()
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 128)
     full, _aux = gpt_moe.apply(params, tokens, CFG, train=False)
-    cache = gpt_moe_inference.init_cache(CFG, 2, 32)
-    logits, cache = gpt_moe_inference.prefill(params, tokens, CFG, cache)
+    cache = MOE.init_cache(CFG, 2, 32)
+    logits, cache = MOE.prefill(params, tokens, CFG, cache)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(full),
                                atol=2e-4, rtol=2e-4)
     assert int(cache.length) == 12
@@ -41,10 +44,10 @@ def test_moe_decode_matches_full_forward():
     params = _params()
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 12), 0, 128)
     full, _ = gpt_moe.apply(params, tokens, CFG, train=False)
-    cache = gpt_moe_inference.init_cache(CFG, 2, 32)
-    _, cache = gpt_moe_inference.prefill(params, tokens[:, :8], CFG, cache)
+    cache = MOE.init_cache(CFG, 2, 32)
+    _, cache = MOE.prefill(params, tokens[:, :8], CFG, cache)
     for i in range(8, 12):
-        logits, cache = gpt_moe_inference.decode_step(
+        logits, cache = MOE.decode_step(
             params, tokens[:, i], CFG, cache)
         np.testing.assert_allclose(np.asarray(logits),
                                    np.asarray(full[:, i]),
@@ -138,15 +141,15 @@ def test_moe_inference_dropless_under_skewed_routing():
     prompt = jnp.asarray(rng.integers(0, 128, size=(1, 6)), jnp.int32)
     chunk = jnp.asarray(rng.integers(0, 128, size=(1, 8)), jnp.int32)
 
-    _, c_ext = gpt_moe_inference.prefill(
-        params, prompt, cfg, gpt_moe_inference.init_cache(cfg, 1, 32))
-    ext_logits, c_ext = gpt_moe_inference.extend(params, chunk, cfg, c_ext)
+    _, c_ext = MOE.prefill(
+        params, prompt, cfg, MOE.init_cache(cfg, 1, 32))
+    ext_logits, c_ext = MOE.extend(params, chunk, cfg, c_ext)
 
-    _, c_dec = gpt_moe_inference.prefill(
-        params, prompt, cfg, gpt_moe_inference.init_cache(cfg, 1, 32))
+    _, c_dec = MOE.prefill(
+        params, prompt, cfg, MOE.init_cache(cfg, 1, 32))
     dec = []
     for i in range(8):
-        lg, c_dec = gpt_moe_inference.decode_step(params, chunk[:, i],
+        lg, c_dec = MOE.decode_step(params, chunk[:, i],
                                                   cfg, c_dec)
         dec.append(np.asarray(lg))
     np.testing.assert_allclose(np.asarray(ext_logits)[0],
@@ -155,11 +158,11 @@ def test_moe_inference_dropless_under_skewed_routing():
 
 def test_moe_extend_overflow_raises():
     params = _params()
-    cache = gpt_moe_inference.init_cache(CFG, 1, 16)
-    _, cache = gpt_moe_inference.prefill(
+    cache = MOE.init_cache(CFG, 1, 16)
+    _, cache = MOE.prefill(
         params, jnp.zeros((1, 12), jnp.int32), CFG, cache)
     with pytest.raises(ValueError, match="overflows the cache"):
-        gpt_moe_inference.extend(params, jnp.zeros((1, 8), jnp.int32),
+        MOE.extend(params, jnp.zeros((1, 8), jnp.int32),
                                  CFG, cache)
 
 
@@ -173,11 +176,11 @@ def test_moe_long_prompt_prefill_chunks_match_single_shot(monkeypatch):
     params = gpt_moe.init(cfg, jax.random.PRNGKey(0))
     tokens = jnp.asarray(np.random.default_rng(1).integers(0, 128, (1, 150)),
                          jnp.int32)
-    chunked, c1 = gpt_moe_inference.prefill(
-        params, tokens, cfg, gpt_moe_inference.init_cache(cfg, 1, 160))
+    chunked, c1 = MOE.prefill(
+        params, tokens, cfg, MOE.init_cache(cfg, 1, 160))
     monkeypatch.setattr(gpt_moe_inference, "_PREFILL_CHUNK", 10_000)
-    single, c2 = gpt_moe_inference.prefill(
-        params, tokens, cfg, gpt_moe_inference.init_cache(cfg, 1, 160))
+    single, c2 = MOE.prefill(
+        params, tokens, cfg, MOE.init_cache(cfg, 1, 160))
     np.testing.assert_allclose(np.asarray(chunked), np.asarray(single),
                                rtol=2e-5, atol=2e-5)
     assert int(c1.length) == int(c2.length) == 150
@@ -198,20 +201,20 @@ def test_moe_int8_cache_decode_tracks_fp_cache():
     params = _params()
     rng = np.random.default_rng(2)
     tokens = jnp.asarray(rng.integers(0, 128, size=(2, 12)), jnp.int32)
-    c_fp = gpt_moe_inference.init_cache(CFG, 2, 32)
-    c_q = gpt_moe_inference.init_cache(CFG, 2, 32, kv_dtype="int8")
+    c_fp = MOE.init_cache(CFG, 2, 32)
+    c_q = MOE.init_cache(CFG, 2, 32, kv_dtype="int8")
     assert c_q.int8 and c_q.k.dtype == jnp.int8
     assert c_q.k_scale.shape == (CFG.n_layer, 2, 32, CFG.n_head)
 
-    lg_fp, c_fp = gpt_moe_inference.prefill(params, tokens[:, :8], CFG, c_fp)
-    lg_q, c_q = gpt_moe_inference.prefill(params, tokens[:, :8], CFG, c_q)
+    lg_fp, c_fp = MOE.prefill(params, tokens[:, :8], CFG, c_fp)
+    lg_q, c_q = MOE.prefill(params, tokens[:, :8], CFG, c_q)
     # prefill attends to the fresh unpadded fp k/v — logits identical
     np.testing.assert_allclose(np.asarray(lg_q), np.asarray(lg_fp),
                                atol=1e-5, rtol=1e-5)
     for i in range(8, 12):
-        lfp, c_fp = gpt_moe_inference.decode_step(params, tokens[:, i],
+        lfp, c_fp = MOE.decode_step(params, tokens[:, i],
                                                   CFG, c_fp)
-        lq, c_q = gpt_moe_inference.decode_step(params, tokens[:, i],
+        lq, c_q = MOE.decode_step(params, tokens[:, i],
                                                 CFG, c_q)
         np.testing.assert_allclose(np.asarray(lq), np.asarray(lfp),
                                    atol=0.05, rtol=0.05,
@@ -232,13 +235,13 @@ def test_moe_ragged_decode_matches_per_row():
     padded = jnp.asarray(padded)
 
     # batched ragged: prefill the padded batch, then 3 ragged steps
-    cache = gpt_moe_inference.init_cache(CFG, 2, 32)
-    lg, cache = gpt_moe_inference.prefill(params, padded, CFG, cache)
+    cache = MOE.init_cache(CFG, 2, 32)
+    lg, cache = MOE.prefill(params, padded, CFG, cache)
     pos = jnp.asarray(lens, jnp.int32)
     nxt = jnp.argmax(lg[jnp.arange(2), pos - 1, :128], -1).astype(jnp.int32)
     ragged_logits = []
     for _ in range(3):
-        lgs, cache = gpt_moe_inference.decode_step(params, nxt, CFG, cache,
+        lgs, cache = MOE.decode_step(params, nxt, CFG, cache,
                                                    lengths=pos)
         ragged_logits.append(np.asarray(lgs))
         nxt = jnp.argmax(lgs[:, :128], -1).astype(jnp.int32)
@@ -247,12 +250,12 @@ def test_moe_ragged_decode_matches_per_row():
     # per-row solo runs
     for row in range(2):
         L = int(lens[row])
-        c1 = gpt_moe_inference.init_cache(CFG, 1, 32)
-        lg1, c1 = gpt_moe_inference.prefill(params, full[row:row + 1, :L],
+        c1 = MOE.init_cache(CFG, 1, 32)
+        lg1, c1 = MOE.prefill(params, full[row:row + 1, :L],
                                             CFG, c1)
         n1 = jnp.argmax(lg1[:, -1, :128], -1).astype(jnp.int32)
         for s in range(3):
-            l1, c1 = gpt_moe_inference.decode_step(params, n1, CFG, c1)
+            l1, c1 = MOE.decode_step(params, n1, CFG, c1)
             np.testing.assert_allclose(ragged_logits[s][row],
                                        np.asarray(l1)[0],
                                        rtol=2e-5, atol=2e-5,

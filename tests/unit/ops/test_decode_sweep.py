@@ -225,11 +225,11 @@ def _tick_families():
                                     "hybrid-1024", "hybrid-256"])
 def test_a_tick_hands_the_kernel_the_block_its_work_list_was_built_for(
         pallas_interpret, family):
-    """``sweep_geometry`` (what the batcher counts live blocks and streamed
-    tokens by), ``_sweeps`` (the tick's work list) and ``cached_attention``
-    (the kernel) of a family agree on the block, and it is the rule's for
-    the family's row: a list built for one block and a kernel built for
-    another would read wrong rows in silence."""
+    """``Family.sweep_plan`` (what the batcher counts live blocks and
+    streamed tokens by), ``gpt_inference._sweeps`` (the tick's work list)
+    and ``cached_attention`` (the kernel) of a family agree on the block,
+    and it is the rule's for the family's row: a list built for one block
+    and a kernel built for another would read wrong rows in silence."""
     from deepspeed_tpu.models import cache_family
     from deepspeed_tpu.models.gpt_inference import cache_row
     from tests.unit.ops.traced_sweeps import sweep_calls
@@ -247,7 +247,7 @@ def test_a_tick_hands_the_kernel_the_block_its_work_list_was_built_for(
     width = cache_row(cfg)[0]
     want = decode_block_k(smax, width)
     assert want == _SWEEP_BLOCK[width][_SLOTS.index(smax)]
-    assert fam.sweep_geometry(cfg, smax)[0] == want
+    assert fam.sweep_plan(cfg, smax).block_k == want
     calls = sweep_calls(tick.jaxpr, slots, smax, width)
     assert calls and all(c[1:] == (want, want) for c in calls), calls
     assert {c[0] for c in calls} == {

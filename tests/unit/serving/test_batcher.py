@@ -35,24 +35,24 @@ FAMILY_NAMES = ("init_cache", "prefill", "extend", "decode_step",
 
 
 def test_cache_family_picks_by_config():
-    """One helper maps a config to its cache family; both families expose
-    the seven names the engine, batcher, pager and fleet call, and the
-    cache class and slot ops are the same objects in both."""
+    """One helper maps a config to its family, a ``gpt_inference.Family``;
+    both families expose the seven names the engine, batcher, pager and
+    fleet call, defined once on the class."""
     from deepspeed_tpu.models import cache_family
-    assert cache_family(CFG) is gpt_inference
-    assert cache_family(MOE_CFG) is gpt_moe_inference
-    for fam in (gpt_inference, gpt_moe_inference):
+    assert cache_family(CFG) is gpt_inference.DENSE
+    assert cache_family(MOE_CFG) is gpt_moe_inference.FAMILY
+    for fam in (gpt_inference.DENSE, gpt_moe_inference.FAMILY):
+        assert isinstance(fam, gpt_inference.Family)
         assert all(callable(getattr(fam, n)) for n in FAMILY_NAMES), fam
-    for name in ("init_cache", "write_slot", "read_slot", "reset_slot"):
-        assert getattr(gpt_moe_inference, name) is getattr(gpt_inference,
-                                                           name)
+    for name in FAMILY_NAMES:
+        assert name not in vars(gpt_moe_inference), name
     eng = _engine()
-    assert eng._family is gpt_inference
+    assert eng._family is gpt_inference.DENSE
 
 
 @pytest.mark.parametrize("fam, mod, cfg", [
-    pytest.param(gpt_inference, gpt, CFG, id="dense"),
-    pytest.param(gpt_moe_inference, gpt_moe, MOE_CFG, id="moe")])
+    pytest.param(gpt_inference.DENSE, gpt, CFG, id="dense"),
+    pytest.param(gpt_moe_inference.FAMILY, gpt_moe, MOE_CFG, id="moe")])
 def test_write_read_reset_slot(fam, mod, cfg):
     """write_slot inserts a batch-1 cache at one row and ONLY that row;
     read_slot round-trips it; reset_slot zeroes it.  One set of slot ops

@@ -181,7 +181,7 @@ def test_analytic_mfu_hand_computed_fixture():
 
 
 def test_analytic_mfu_matches_bench_formula_for_gpt():
-    # the same arithmetic bench.py uses: mfu = tok/s * f / (peak * chips)
+    # the benchmark's arithmetic: mfu = tok/s * f / (peak * chips)
     from deepspeed_tpu.models import gpt
     cfg = gpt.GPTConfig(vocab_size=512, max_seq_len=128, n_layer=2,
                         n_head=4, d_model=128)

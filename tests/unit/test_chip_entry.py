@@ -25,8 +25,6 @@ def _run(argv, env_extra=None, timeout=300):
 
 @pytest.mark.parametrize("argv", [
     ["chip_smoke.py"], ["chip_smoke.py", "--chips", "4"],
-    ["bench.py"], ["bench.py", "bert"], ["bench.py", "offload"],
-    ["scripts/stall_anatomy.py", os.devnull],
 ], ids=" ".join)
 def test_refuses_to_run_without_a_chip(argv):
     r = _run(argv)

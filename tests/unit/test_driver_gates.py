@@ -3,8 +3,8 @@ import pytest
 pytestmark = pytest.mark.slow
 """The driver's round gates, as tests (round 1 failed on exactly these
 being unexercised): __graft_entry__ must expose a compilable entry() and a
-dryrun that executes real shardings.  (That bench.py and chip_smoke.py
-refuse to run without a chip is a tier-1 test: tests/unit/test_chip_entry.py.)
+dryrun that executes real shardings.  (That chip_smoke.py refuses to run
+without a chip is a tier-1 test: tests/unit/test_chip_entry.py.)
 
 Both run in subprocesses: the gates themselves bootstrap jax platforms,
 which must happen in a fresh interpreter (the latched-backend hazard the
