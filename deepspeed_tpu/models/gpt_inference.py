@@ -88,7 +88,11 @@ class KVCache:
     step adds to on the device (an expert layer's pair counts): it rides
     the donated cache and reaches the host with the tick's own pull.
     ``state`` (None for most families) is a tuple of per-slot arrays
-    ``[layers, B, ...]`` with no token axis, a reset slot's all zero."""
+    ``[layers, B, ...]`` with no token axis, a reset slot's all zero.
+    ``ring`` (None for most families) is a SECOND pool, one bank for each
+    of the row's: ``[L_ring, B, R, w]``, the layers that see a window of the
+    conversation and keep no more of it: the token at position ``p`` lies
+    in cell ``p mod R`` whatever ``S_max`` is."""
 
     k: jnp.ndarray        # [L, B, S_max, row[0]]
     v: Any                # [L, B, S_max, row[1]] or None
@@ -97,10 +101,11 @@ class KVCache:
     v_scale: Any = None
     stats: Any = None
     state: Any = None
+    ring: Any = None
 
     def tree_flatten(self):
         return (self.k, self.v, self.length, self.k_scale, self.v_scale,
-                self.stats, self.state), None
+                self.stats, self.state, self.ring), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -133,6 +138,15 @@ def cache_layers(config) -> int:
     return getattr(config, "cache_layers", config.n_layer)
 
 
+def cache_ring(config, max_len: int) -> Optional[Tuple[int, int]]:
+    """``(layers, R)`` of the family's second pool over ``max_len``-token
+    slots (``config.cache_ring``: the layers that keep a window and the
+    window's length), else None.  A slot shorter than the window never
+    laps its ring, so the ring is no longer than the slot."""
+    ring = getattr(config, "cache_ring", None)
+    return None if not ring else (int(ring[0]), min(int(ring[1]), max_len))
+
+
 def init_cache(config, batch: int, max_len: int, kv_dtype=None,
                stats: Optional[Dict[str, slice]] = None) -> KVCache:
     """``kv_dtype``: None → cache in the compute dtype; ``"int8"``/
@@ -149,10 +163,12 @@ def init_cache(config, batch: int, max_len: int, kv_dtype=None,
     state = None if not declared else tuple(
         jnp.zeros((n, batch) + tuple(shape), dtype)
         for n, shape, dtype in declared)
+    ring = cache_ring(config, max_len)
     if kv_dtype in ("int8", jnp.int8):
-        if state is not None:
+        if state is not None or ring is not None:
             raise NotImplementedError(
-                "the int8 cache exists for families without per-slot state")
+                "the int8 cache exists for families without per-slot state "
+                "and without rings")
         if len(row) != 2:
             raise NotImplementedError(
                 "the int8 cache (codes and per-head scale banks) exists for "
@@ -168,7 +184,10 @@ def init_cache(config, batch: int, max_len: int, kv_dtype=None,
                    length=jnp.zeros((), jnp.int32),
                    stats=jnp.zeros((max(g.stop for g in stats.values()),),
                                    jnp.int32) if stats else None,
-                   state=state)
+                   state=state,
+                   ring=None if ring is None else tuple(
+                       jnp.zeros((ring[0], batch, ring[1], w), config.dtype)
+                       for w in row))
 
 
 # ------------------------------------------------------------- slot ops
@@ -178,21 +197,23 @@ def init_cache(config, batch: int, max_len: int, kv_dtype=None,
 # three ops are that contract: ``row`` may be a traced scalar, so one
 # compiled program serves every slot — admitting into slot 7 never
 # recompiles the program that admitted into slot 2.  They walk whatever
-# banks the family's row has (a bank the cache lacks is None and stays so)
-# and the per-slot state where the family keeps one: every such array leads
-# with ``[layers, B]``, and what follows (tokens, or none) is the slot's.
+# banks the family's row has (a bank the cache lacks is None and stays so),
+# the rings of its second pool and the per-slot state where the family keeps
+# either: every such array leads with ``[layers, B]``, and what follows
+# (tokens, a ring's cells, or none) is the slot's.
 
 
 def _each_bank(f, cache: KVCache, *others: KVCache) -> dict:
     """``f`` over every bank (and scale bank) the cache holds, and over the
-    arrays of its per-slot state."""
+    arrays of its rings and of its per-slot state."""
     out = {name: f(getattr(cache, name), *(getattr(o, name) for o in others))
            for name in ("k", "v", "k_scale", "v_scale")
            if getattr(cache, name) is not None}
-    if cache.state is not None:
-        out["state"] = tuple(
-            f(a, *(o.state[i] for o in others))
-            for i, a in enumerate(cache.state))
+    for name in ("state", "ring"):
+        if getattr(cache, name) is not None:
+            out[name] = tuple(
+                f(a, *(getattr(o, name)[i] for o in others))
+                for i, a in enumerate(getattr(cache, name)))
     return out
 
 
@@ -454,12 +475,18 @@ class Family:
         and streamed tokens by, with no device read."""
         layers = cache_layers(config)
         of = self.windows(config, max_len)
-        return _row_plan(
+        plan = _row_plan(
             config, max_len, itemsize,
             windows=((None, layers),) if of is None else tuple(
                 collections.Counter(
                     int(w) for w in np.asarray(of(np.arange(layers)))
                 ).items()))
+        ring = cache_ring(config, max_len)
+        if ring is None:
+            return plan
+        # the rings are a pool of their own length, planned by it
+        return plan._replace(ring=_row_plan(
+            config, ring[1], itemsize, windows=((None, ring[0]),)))
 
 
 def _sweeps(family: Family, pos, B, config, max_len, active):
@@ -467,14 +494,23 @@ def _sweeps(family: Family, pos, B, config, max_len, active):
     bank-owning layer, built ONCE, before the layer scan: a function of
     the step's ``pos`` and ``active`` alone, and, in a banded stack, of
     each layer's window (all layers' lists in one vectorised build).  Its
-    block is the plan's, which is the host's and the kernel's.  Returns
-    ``layer -> sweep``."""
+    block is the plan's, which is the host's and the kernel's.  A family
+    with rings gets a second list, the rings' (a pool ``R`` long at
+    frontier ``min(pos, R - 1)``: once a ring has lapped, all of it is
+    live).  Returns ``(layer, ring=False) -> sweep``."""
     from ..ops.pallas.decode_attention import decode_sweep
     block_k = _row_plan(config, max_len).block_k
     of = family.windows(config, max_len)
-    if of is None:
+    ring = cache_ring(config, max_len)
+    if of is None and ring is None:
         sweep = decode_sweep(pos, B, max_len, block_k, active)
         return lambda idx: sweep
+    if ring is not None:
+        R = ring[1]
+        sweeps = (decode_sweep(pos, B, max_len, block_k, active),
+                  decode_sweep(jnp.minimum(pos, R - 1), B, R,
+                               _row_plan(config, R).block_k, active))
+        return lambda idx, ring=False: sweeps[bool(ring)]
     windows = of(jnp.arange(cache_layers(config)))
     sweeps = jax.vmap(
         lambda w: decode_sweep(pos, B, max_len, block_k, active, w))(windows)
@@ -499,6 +535,17 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
     ``layer`` of the updated pool where it lies).  ``family``: see
     :class:`Family`; ``valid``: its ``step``'s.  Returns (hidden
     states, updated KVCache, ``length`` untouched).
+
+    A body whose layer keeps a RING (``cache.ring``, layer ``idx`` of that
+    pool) says so statically: ``attend(x, p, idx, cache, ring=True)``.  The
+    family's hooks are then handed ``ring=True`` too (its ``project`` may
+    rotate a window layer by another table), the row goes to cell ``pos mod
+    R`` (``_ring_write``: real tokens only, the last ``R`` of them), and the
+    order depends on the call: a single token is written and then sweeps its
+    ring (it overwrites the token that just left the window), a chunk
+    attends over the ring as it was and its own rows (``fresh=``) and is
+    written after: written first it would overwrite keys its first queries
+    still see.
     """
     int8 = cache.int8
     if int8:
@@ -508,11 +555,29 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
         """[B, S, ...] → [B, S, *]: a token's row in one bank."""
         return t.reshape(t.shape[:2] + (-1,))
 
-    def attend(x, p, idx, cache):
+    def ring_attend(q, fresh, idx, cache):
+        def put(cache):
+            with jax.named_scope("cache_update"):
+                return dataclasses.replace(cache, ring=tuple(
+                    _ring_write(bank, idx, fold(val.astype(bank.dtype)),
+                                positions, valid)
+                    for bank, val in zip(cache.ring, fresh)))
+
+        single = q.shape[1] == 1
+        if single:
+            cache = put(cache)
+        with jax.named_scope("cache_read"):
+            a = attn(q, fresh, cache, idx, ring=True)
+        return a, cache if single else put(cache)
+
+    def attend(x, p, idx, cache, ring=False):
         # the scopes name, in a profiler's trace, a layer's queries and
         # row, and the two places a tick touches the slot cache
         with jax.named_scope("project"):
-            q, fresh = family.project(x, p, config, positions)
+            q, fresh = family.project(x, p, config, positions,
+                                      **({"ring": True} if ring else {}))
+        if ring:
+            return ring_attend(q, fresh, idx, cache)
         with jax.named_scope("cache_update"):
             if int8:
                 (kq, ks), (vq, vs) = map(quantize_kv, fresh)
@@ -539,6 +604,44 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
         (x, cache), _ = lax.scan(layer, (x, cache),
                                  (stacks, jnp.arange(n_steps)))
     return x, cache
+
+
+def _ring_write(bank, layer, val, positions, valid):
+    """``val`` [B, S, w], the rows of the tokens at ``positions`` ([S] or
+    [B, S], consecutive in a row), into layer ``layer`` of a ring bank ``[L,
+    B, R, w]``: position ``p`` to cell ``p mod R``.  Only a row's REAL
+    tokens (``valid`` [B] of them) are written, and of those the last ``R``:
+    padding past a prompt's end would land on cells the next tokens'
+    windows still hold (in a bank of whole rows it lies past the frontier),
+    and a pass longer than the ring would write a cell twice.
+
+    One token a row is one cell a row.  A chunk never scatters: the ``R``
+    rows that may land are rolled to their cells (cell ``c`` takes token
+    ``(c - first) mod R`` of them) and laid over the layer's rings where
+    they are real, one slice read and written."""
+    B, S, _ = val.shape
+    R = bank.shape[2]
+    pos = jnp.broadcast_to(positions if positions.ndim == 2
+                           else positions[None], (B, S))
+    if S == 1:
+        cols = jnp.where(valid > 0, pos[:, 0] % R, R)
+        return bank.at[layer, jnp.arange(B), cols].set(val[:, 0],
+                                                       mode="drop")
+    n = min(S, R)
+    start = jnp.clip(valid - R, 0, S - n)       # the last R real tokens'
+    rows = val if S == n else jax.vmap(
+        lambda v, s: lax.dynamic_slice_in_dim(v, s, n, 0))(val, start)
+    if n < R:
+        rows = jnp.pad(rows, ((0, 0), (0, R - n), (0, 0)))
+    first = (pos[:, 0] + start) % R             # the cell of rows[:, 0]
+    rolled = jax.vmap(lambda r, f: lax.dynamic_slice_in_dim(
+        jnp.concatenate([r, r], 0), R - f, R, 0))(rows, first)
+    landed = (jnp.arange(R)[None] - first[:, None]) % R \
+        < (valid - start)[:, None]
+    old = lax.dynamic_index_in_dim(bank, layer, 0, keepdims=False)
+    return lax.dynamic_update_slice(
+        bank, jnp.where(landed[..., None], rolled, old)[None],
+        (layer, 0, 0, 0))
 
 
 def _real_tokens(valid, B: int, S: int):
@@ -569,8 +672,8 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
     def write(bank, layer, val):
         return lax.dynamic_update_slice(bank, val[None], (layer, 0, 0, 0))
 
-    def attn(q, fresh, cache, idx):
-        return family.attend_fresh(q, fresh, cache, config, idx)
+    def attn(q, fresh, cache, idx, **ring):
+        return family.attend_fresh(q, fresh, cache, config, idx, **ring)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
                            family, _real_tokens(valid, B, S))
@@ -632,8 +735,10 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
 
     x = family.embed(params, tokens, config, positions=positions)
 
-    def attn(q, fresh, cache, idx):
-        return family.attend_cached(q, cache, pos0, config, idx)
+    def attn(q, fresh, cache, idx, **ring):
+        if ring:    # the chunk is not in its ring yet: its rows ride along
+            ring["fresh"] = fresh
+        return family.attend_cached(q, cache, pos0, config, idx, **ring)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
                            family, _real_tokens(valid, B, Sc))
@@ -672,9 +777,10 @@ def decode_step(params: PyTree, token: jnp.ndarray, config, cache: KVCache,
             return bank.at[layer, jnp.arange(B), pos].set(val[:, 0])
         return lax.dynamic_update_slice(bank, val[None], (layer, 0, pos, 0))
 
-    def attn(q, fresh, cache, idx):
+    def attn(q, fresh, cache, idx, **ring):
         return family.attend_cached(q, cache, pos, config, idx,
-                                    active=active, sweep=sweep_of(idx))
+                                    active=active,
+                                    sweep=sweep_of(idx, **ring), **ring)
 
     x, cache = _layer_scan(
         x, params, cache, config, positions, write, attn, family,

@@ -65,6 +65,30 @@ MAMBA, ATTENTION, EXPERTS = "mamba", "attention", "experts"
 MAX_UNIT = 4
 
 
+def layer_units(types: Tuple[str, ...], max_unit: int = MAX_UNIT):
+    """``types`` (a stack's kinds in depth order) as runs: ``(unit, firsts,
+    n)`` each, see ``HybridSSMMoEConfig.units``."""
+    out: List[Tuple[Tuple[str, ...], Tuple[int, ...], int]] = []
+    seen = dict.fromkeys(types, 0)
+    i = 0
+    while i < len(types):
+        u, n = 1, 1
+        for length in range(1, max_unit + 1):
+            unit, times = types[i:i + length], 1
+            while types[i + times * length:
+                        i + (times + 1) * length] == unit:
+                times += 1
+            if (times > 1 or length == 1) and times * length > u * n:
+                u, n = length, times
+        unit = tuple(types[i:i + u])
+        out.append((unit, tuple(seen[k] + unit[:j].count(k)
+                                for j, k in enumerate(unit)), n))
+        for k in unit:
+            seen[k] += n
+        i += u * n
+    return tuple(out)
+
+
 @dataclasses.dataclass(frozen=True)
 class HybridSSMMoEConfig:
     #: the module whose ``FAMILY`` ``models.cache_family`` serves this
@@ -166,26 +190,7 @@ class HybridSSMMoEConfig:
         most layers is taken, a unit of several kinds only where it repeats,
         so ``MMMMMAMMMM`` reads ``M x 5, A, M x 4`` and ``MEMEM*EMEMEM*E``
         reads ``(ME) x 2, M, *, (EM) x 3, *, E``."""
-        types = self.layer_types
-        out: List[Tuple[Tuple[str, ...], Tuple[int, ...], int]] = []
-        seen = {MAMBA: 0, ATTENTION: 0, EXPERTS: 0}
-        i = 0
-        while i < len(types):
-            u, n = 1, 1
-            for length in range(1, MAX_UNIT + 1):
-                unit, times = types[i:i + length], 1
-                while types[i + times * length:
-                            i + (times + 1) * length] == unit:
-                    times += 1
-                if (times > 1 or length == 1) and times * length > u * n:
-                    u, n = length, times
-            unit = tuple(types[i:i + u])
-            out.append((unit, tuple(seen[k] + unit[:j].count(k)
-                                    for j, k in enumerate(unit)), n))
-            for k in unit:
-                seen[k] += n
-            i += u * n
-        return tuple(out)
+        return layer_units(self.layer_types)
 
     @property
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:

@@ -141,26 +141,32 @@ def swiglu(h, w_gu, w_down, cdt):
                       preferred_element_type=jnp.float32)
 
 
-def rotary_inv_freq(config: LatentMoEConfig) -> jnp.ndarray:
-    """``[d_rope / 2]`` float32 rotary frequencies; under YaRN the fast
-    dims keep theirs, the slow ones are divided by ``factor``, with a linear
-    ramp between the correction dims of ``beta_fast`` and ``beta_slow``."""
-    dim = config.d_rope
+def yarn_inv_freq(dim: int, theta: float, yarn=None) -> jnp.ndarray:
+    """``[dim / 2]`` float32 rotary frequencies ``theta^(-2j/dim)``; under
+    YaRN (``yarn``: factor, original positions, beta_fast, beta_slow, ...)
+    the fast dims keep theirs, the slow ones are divided by ``factor``, with
+    a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow``."""
     j = jnp.arange(0, dim, 2, dtype=jnp.float32)
-    freq = config.rope_theta ** (-j / dim)
-    if config.yarn is None:
+    freq = theta ** (-j / dim)
+    if yarn is None:
         return freq
-    factor, original, beta_fast, beta_slow = config.yarn[:4]
+    factor, original, beta_fast, beta_slow = yarn[:4]
 
     def correction_dim(rotations):
         return dim * math.log(original / (rotations * 2 * math.pi)) \
-            / (2 * math.log(config.rope_theta))
+            / (2 * math.log(theta))
     low = max(math.floor(correction_dim(beta_fast)), 0)
     high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
     ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
                     / max(high - low, 1e-3), 0.0, 1.0)
     keep = 1.0 - ramp
     return freq / factor * (1.0 - keep) + freq * keep
+
+
+def rotary_inv_freq(config: LatentMoEConfig) -> jnp.ndarray:
+    """``yarn_inv_freq`` of the config's rotary dims."""
+    return yarn_inv_freq(config.d_rope, config.rope_theta, config.yarn)
 
 
 def rotate(x, positions, config: LatentMoEConfig):

@@ -44,7 +44,19 @@ Grouped heads (``kv_heads`` of ``cached_attention``): ``H`` query heads on
 (``_gqa_decode``) takes the block with all its key-value heads once and the
 ``G`` query heads of each share it, the chunk kernel maps query head ``bh``
 to the blocks of key-value head ``bh // G``.  Nothing repeats a key-value
-head out to its query heads.
+head out to its query heads.  The chunk kernel takes a band (``window``)
+with ``G > 1``; no family sweeps a single token over grouped heads under a
+band (a ring below needs none), and that pair stays refused.
+
+A RING (``gpt_inference.KVCache.ring``: a window layer's last ``R`` tokens,
+position ``p`` in cell ``p mod R``) needs no kernel of its own.  A single
+token sweeps it as a pool ``R`` long at frontier ``min(p, R - 1)``: keys are
+cached rotated, so the order of the cells does not matter to a softmax and
+the sweep needs no band.  A chunk attends BEFORE it is written
+(``ring_attention``): the ring is unrolled into the order of its positions,
+the chunk's rows follow, and the chunk kernel walks a band of ``window +
+chunk`` keys, bounded below by the first cell a token has reached
+(``valid_from``).
 
 Int8 cache variant (beyond the reference): k/v arrive as int8 codes with
 per-vector fp32 scales and are dequantized IN VMEM after the block load,
@@ -85,12 +97,13 @@ def quantize_kv(x):
 
 def cached_attention_reference(q, cache_k, cache_v, pos,
                                sm_scale: Optional[float] = None,
-                               window=None, slopes=None):
+                               window=None, slopes=None, valid_from=None):
     """Ground truth: q [B,Sq,H,D] over cache [B,Smax,H,D]; query i (at
     absolute position pos+i) sees cache slots ≤ pos+i.  ``pos`` may be a
     scalar or a per-row [B] vector (ragged decode).  ``window`` (scalar,
     may be traced) bands visibility to ``0 <= dist < window``; ``slopes``
-    ([H] fp32) adds the ALiBi bias ``-slope·dist``."""
+    ([H] fp32) adds the ALiBi bias ``-slope·dist``; ``valid_from`` (scalar
+    or [B]) hides the slots before it."""
     B, Sq, H, D = q.shape
     Smax = cache_k.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
@@ -103,6 +116,9 @@ def cached_attention_reference(q, cache_k, cache_v, pos,
     mask = dist >= 0
     if window is not None:
         mask = jnp.logical_and(mask, dist < window)
+    if valid_from is not None:
+        mask = jnp.logical_and(mask, k_pos[None, None, :] >= jnp.asarray(
+            valid_from).reshape(-1, 1, 1))
     if slopes is not None:
         s = s - slopes[None, :, None, None] * dist[:, None].astype(jnp.float32)
     s = jnp.where(mask[:, None], s, NEG_INF)
@@ -473,6 +489,9 @@ def decode_copy_rows(itemsize: int) -> int:
     return max(16, 32 // itemsize)
 
 
+#: the pools a sweep plan counts by: whole rows, and rings (``SweepPlan``)
+KINDS = ("full", "window")
+
 #: the three single-token sweeps, by the name each kernel is launched under
 DENSE_SWEEP = "decode_attention"
 GROUPED_SWEEP = "gqa_decode_attention"
@@ -489,23 +508,58 @@ class SweepPlan(NamedTuple):
     tile.  ``copy_rows``: where the copy of a row's last block ends, a tile
     for the dense sweep, None for the two that stream whole blocks.
     ``windows``: the sweep's calls in one decode step as ``(window or None,
-    layers)`` pairs, the caller's."""
+    layers)`` pairs, the caller's.  ``ring``: the plan of the family's
+    SECOND pool, where it has one (``gpt_inference.KVCache.ring``): the
+    window layers' rings, ``ring.Smax`` rows a slot, a token at position
+    ``p`` in cell ``p mod ring.Smax``.  A ring's sweep is a plain sweep of a
+    short pool at frontier ``min(p, ring.Smax - 1)``, which is what the
+    counting functions make of ``p`` for an ``Smax`` that short; the counts
+    below are both pools', and by pool (``KINDS``) in ``by_kind``."""
     kernel: Optional[str]
     block_k: Optional[int]
     copy_rows: Optional[int]
     Smax: int
     windows: Tuple = ((None, 1),)
+    ring: Optional["SweepPlan"] = None
 
     def block_counts(self, positions, rows: int):
         """``sweep_block_counts`` of this plan: ``(live, grid)`` blocks."""
-        return sweep_block_counts(positions, rows, self.Smax, self.block_k,
-                                  self.windows)
+        own = sweep_block_counts(positions, rows, self.Smax, self.block_k,
+                                 self.windows)
+        if self.ring is None:
+            return own
+        ring = self.ring.block_counts(positions, rows)
+        return own[0] + ring[0], own[1] + ring[1]
 
     def token_counts(self, positions):
         """``sweep_token_counts`` of this plan: ``(live, streamed)``
         tokens."""
-        return sweep_token_counts(positions, self.Smax, self.block_k,
-                                  self.windows, self.copy_rows)
+        by_kind = self.by_kind(positions)
+        return (sum(v[0] for v in by_kind.values()),
+                sum(v[1] for v in by_kind.values()))
+
+    def by_kind(self, positions):
+        """``pool -> (live, streamed, calls)`` of one decode step: the
+        tokens as ``sweep_token_counts`` has them and the kernel's calls
+        (one a layer), for the pool of whole rows (``"full"``) and, where
+        there is one, the rings' (``"window"``)."""
+        out = {KINDS[0]: sweep_token_counts(
+            positions, self.Smax, self.block_k, self.windows,
+            self.copy_rows) + (sum(n for _, n in self.windows),)}
+        if self.ring is not None:
+            out[KINDS[1]] = self.ring.by_kind(positions)[KINDS[0]]
+        return out
+
+    @property
+    def share_of_one_geometry(self) -> float:
+        """Cached rows a slot holds over what it would hold with every
+        layer's row ``Smax`` long: 1 without a ring."""
+        own = sum(n for _, n in self.windows)
+        if self.ring is None:
+            return 1.0
+        rings = sum(n for _, n in self.ring.windows)
+        return (own * self.Smax + rings * self.ring.Smax) \
+            / ((own + rings) * self.Smax)
 
 
 def sweep_plan(widths, Smax: int, heads: int, kv_heads: Optional[int] = None,
@@ -616,7 +670,7 @@ def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, copy_rows,
 
 
 def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
-                  windowed, alibi):
+                  windowed, alibi, bounded=False):
     """Chunked-prefill attention over the padded cache: queries are a
     whole chunk at absolute positions ``pos .. pos+Sq-1`` (online softmax
     per row, cache blocks streamed through VMEM, blocks beyond the
@@ -625,7 +679,13 @@ def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
     fallback ``extend`` would otherwise take — O(block) VMEM instead of
     an [Sq, Smax] score tensor.  The running max is floored at
     ``M_FLOOR`` (not -inf): a windowed block can be fully masked for
-    SOME of its q rows, and those rows' recurrences must stay nan-free."""
+    SOME of its q rows, and those rows' recurrences must stay nan-free.
+    ``bounded``: a second scalar-prefetch vector leads ``rest``, each row's
+    first real key (``valid_from`` of ``cached_attention``); the keys
+    before it are masked and the blocks wholly before it skipped."""
+    first_ref = None
+    if bounded:
+        first_ref, rest = rest[0], rest[1:]
     (window_ref, slopes_ref, q_ref, k_ref, v_ref, kscale_ref, vscale_ref,
      o_ref, acc_ref, m_ref, l_ref) = _unpack_rest(rest, quantized,
                                                   windowed, alibi)
@@ -649,6 +709,9 @@ def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
         live = jnp.logical_and(
             live,
             (ki + 1) * block_k - 1 >= pos + qi * block_q - window_ref[0] + 1)
+    if bounded:
+        live = jnp.logical_and(live,
+                               (ki + 1) * block_k - 1 >= first_ref[bh // H])
 
     @pl.when(live)
     def _update():
@@ -670,6 +733,8 @@ def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
         visible = dist >= 0
         if windowed:
             visible = jnp.logical_and(visible, dist < window_ref[0])
+        if bounded:
+            visible = jnp.logical_and(visible, k_pos >= first_ref[bh // H])
         s = jnp.where(visible, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -686,28 +751,35 @@ def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
 
 
 def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
-           vs3=None, window=None, slopes=None, group: int = 1):
+           vs3=None, window=None, slopes=None, group: int = 1,
+           valid_from=None):
     """``group`` query heads share a key-value head (grouped heads): ``q3``
     is ``[B*H, Sq, D]``, ``k3``/``v3`` ``[B*H/group, Smax, D]`` and query
-    head ``bh`` streams the blocks of key-value head ``bh // group``."""
+    head ``bh`` streams the blocks of key-value head ``bh // group``.
+    ``valid_from`` (scalar or [B]): each row's first real key."""
     BH, Sq, D = q3.shape
     Smax = k3.shape[1]
     B = BH // H
     quantized = ks3 is not None
     windowed = window is not None
+    bounded = valid_from is not None
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     kernel = functools.partial(_chunk_kernel, sm_scale=sm_scale,
                                block_q=block_q, block_k=block_k, H=H,
                                quantized=quantized, windowed=windowed,
-                               alibi=slopes is not None)
+                               alibi=slopes is not None, bounded=bounded)
     # single scalar-prefetch build (see _decode): dead k-block indices
-    # clamp into this q block's live range [band start, causal frontier],
-    # so chunked prefill/extend streams only the blocks its rows can see
-    def kv_idx(bh, qi, ki, pos_ref, *maybe_win):
+    # clamp into this q block's live range [band start or first real key,
+    # causal frontier], so chunked prefill/extend streams only the blocks
+    # its rows can see
+    def kv_idx(bh, qi, ki, pos_ref, *more):
         p = pos_ref[bh // H]
-        lo = jnp.maximum(
-            (p + qi * block_q - maybe_win[0][0] + 1) // block_k, 0) \
-            if windowed else 0
+        lo = 0
+        if windowed:
+            lo = jnp.maximum(
+                (p + qi * block_q - more[-1][0] + 1) // block_k, 0)
+        if bounded:
+            lo = jnp.maximum(lo, more[0][bh // H] // block_k)
         hi = (p + (qi + 1) * block_q - 1) // block_k
         return (bh // group, jnp.clip(ki, lo, hi), 0)
 
@@ -719,6 +791,9 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
         if slopes is not None else ()
     win_args = (jnp.asarray(window, jnp.int32).reshape(1),) \
         if windowed else ()
+    if bounded:     # the kernel's ``first_ref`` leads the window
+        win_args = (jnp.broadcast_to(jnp.asarray(
+            valid_from, jnp.int32).reshape(-1), (B,)),) + win_args
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1 + len(win_args),
         grid=(BH, Sq // block_q, Smax // block_k),
@@ -1045,14 +1120,20 @@ def cached_attention(q, cache_k, cache_v, pos,
                      k_scale=None, v_scale=None,
                      window=None, slopes=None, layer=None,
                      active=None, sweep=None, latent_rank=None,
-                     kv_heads: Optional[int] = None):
+                     kv_heads: Optional[int] = None, valid_from=None):
     """q [B,Sq,H,D] over a padded cache [B,Smax,H,D], visibility ≤ pos+i.
 
     ``kv_heads`` (default ``H``): grouped heads.  The cache holds
     ``kv_heads`` key-value heads a token (its row is ``kv_heads * D``) and
     query head ``h`` reads key-value head ``h // (H / kv_heads)``; the row
-    is never repeated out to ``H`` heads.  Full-precision cache, no window,
-    no ALiBi; the lane-aligned kernels want ``D`` a multiple of 128.
+    is never repeated out to ``H`` heads.  Full-precision cache, no ALiBi,
+    a band (``window``) for a chunk's call only (``Sq > 1``: a single token
+    over grouped heads sweeps whole rows or a ring); the lane-aligned
+    kernels want ``D`` a multiple of 128.
+
+    ``valid_from`` (scalar or [B], a chunk's call only): the row's first
+    real key; the slots before it are hidden (``ring_attention``'s unrolled
+    ring starts with the cells no token has reached).
 
     A cache of ONE bank (``cache_v`` None) is a latent pool: ``q`` holds the
     absorbed queries, ``latent_rank`` says how much of a row the
@@ -1106,9 +1187,17 @@ def cached_attention(q, cache_k, cache_v, pos,
     Hkv = H if kv_heads is None else int(kv_heads)
     G = H // Hkv
     int8_cache = k_scale is not None
-    if G > 1 and (int8_cache or window is not None or slopes is not None):
+    if G > 1 and (int8_cache or slopes is not None):
         raise NotImplementedError(
-            "grouped heads: full-precision cache, no window, no ALiBi")
+            "grouped heads: full-precision cache, no ALiBi")
+    if G > 1 and window is not None and Sq == 1:
+        raise NotImplementedError(
+            "grouped heads: no window under a single token's sweep (a "
+            "chunk's call takes one; a ring needs none)")
+    if valid_from is not None and Sq == 1:
+        raise NotImplementedError(
+            "valid_from bounds a chunk's call; a single token's frontier "
+            "is its pos")
     banks = (cache_k, cache_v) + ((k_scale, v_scale) if int8_cache else ())
     if layer is None:
         # [B,Smax,H,*] → a pool of one layer, heads folded into the row
@@ -1158,7 +1247,8 @@ def cached_attention(q, cache_k, cache_v, pos,
         ks3, vs3 = map(to3, banks[2:]) if int8_cache else (None, None)
         o3 = _chunk(to3(q), to3(banks[0]), to3(banks[1]), pos, scale,
                     block_q, key_block, H, ks3=ks3, vs3=vs3,
-                    window=window, slopes=slopes, group=G)
+                    window=window, slopes=slopes, group=G,
+                    valid_from=valid_from)
         return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
     if int8_cache:
@@ -1167,5 +1257,44 @@ def cached_attention(q, cache_k, cache_v, pos,
     if G > 1:       # the dense formula reads a key-value head per query head
         banks = [jnp.repeat(x, G, axis=2) for x in banks]
     o = cached_attention_reference(q, banks[0], banks[1], pos, scale,
-                                   window=window, slopes=slopes)
+                                   window=window, slopes=slopes,
+                                   valid_from=valid_from)
     return dead_rows_zero(o) if Sq == 1 else o
+
+
+def ring_attention(q, ring_k, ring_v, fresh_k, fresh_v, pos, window: int,
+                   layer, sm_scale: Optional[float] = None,
+                   kv_heads: Optional[int] = None):
+    """A chunk's queries over a RING of cached rows and the chunk's own.
+
+    ``ring_k``/``ring_v`` [L, B, R, Hkv*D] keep, at layer ``layer``, the
+    token at position ``p`` in cell ``p mod R``: the last ``R`` tokens
+    BEFORE the chunk (which is not written yet: a chunk written first would
+    overwrite keys its first queries still see).  ``q`` [B, Sq, H, D] sits
+    at ``pos .. pos + Sq - 1`` (``pos`` scalar or [B]) and ``fresh_k`` /
+    ``fresh_v`` [B, Sq, Hkv, D] are the chunk's own rows; query ``i`` sees
+    the keys at ``0 <= pos + i - j < window``.
+
+    The ring is unrolled into the order of its positions (cell ``pos mod
+    R`` first: two slices of it, a copy of ``R`` rows), the chunk's rows
+    follow, and ``cached_attention`` takes the ``R + Sq`` rows as a cache
+    whose first query sits at ``R``, banded by ``window`` and bounded below
+    by the first cell a token has reached (``R - pos``): the chunk kernel
+    visits the key blocks a band of ``window + Sq`` touches and no more."""
+    B, Sq = q.shape[:2]
+    R = ring_k.shape[2]
+    p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+
+    def unrolled(bank, fresh):
+        one = jax.lax.dynamic_index_in_dim(bank, layer, 0, keepdims=False)
+        twice = jnp.concatenate([one, one], axis=1)
+        old = jax.vmap(lambda t, s: jax.lax.dynamic_slice_in_dim(
+            t, s, R, 0))(twice, p % R)
+        rows = jnp.concatenate(
+            [old, fresh.reshape(B, Sq, -1).astype(bank.dtype)], axis=1)
+        return rows.reshape(B, R + Sq, fresh.shape[2], -1)
+
+    return cached_attention(
+        q, unrolled(ring_k, fresh_k), unrolled(ring_v, fresh_v),
+        jnp.full((B,), R, jnp.int32), sm_scale=sm_scale, window=window,
+        kv_heads=kv_heads, valid_from=jnp.maximum(R - p, 0))

@@ -209,7 +209,7 @@ class SlotBatcher:
         self._row_cache_bytes = cache_bank_bytes(self.cache) // B
         #: the plan of the family's single-token sweep over this pool (its
         #: kernel, block, copy boundary and calls a tick): what
-        #: ``sweep_blocks`` and ``sweep_tokens`` count by
+        #: ``sweep_blocks`` and ``sweep_by_kind`` count by
         self._sweep = fam.sweep_plan(cfg, self.max_len,
                                      self.cache.k.dtype.itemsize)
         #: what the family's scan steps counted on the device
@@ -808,11 +808,19 @@ class SlotBatcher:
         device."""
         return self._sweep.block_counts(frontiers, self.slots)
 
-    def sweep_tokens(self, frontiers) -> Tuple[int, int]:
-        """``(live, streamed)`` cached tokens of the same tick: what its
-        rows' queries see over all layers, and what the decode kernel's
-        copies move for them (``sweep_token_counts``)."""
-        return self._sweep.token_counts(frontiers)
+    def sweep_by_kind(self, frontiers) -> Dict[str, Tuple[int, int, int]]:
+        """``pool -> (live, streamed, calls)`` of the same tick
+        (``SweepPlan.by_kind``): the cached tokens its rows' queries see
+        over a pool's layers, what the decode kernel's copies move for them
+        (``sweep_token_counts``) and the sweep kernel's calls, for the pool
+        of whole rows (``"full"``) and, for a family with rings, of those
+        (``"window"``)."""
+        return self._sweep.by_kind(frontiers)
+
+    @property
+    def sweep_plan(self):
+        """The plan those counts follow (``decode_attention.SweepPlan``)."""
+        return self._sweep
 
     @hot_path
     def launch(self):

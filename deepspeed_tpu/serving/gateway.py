@@ -113,6 +113,8 @@ class ServingGateway:
                                     draft=draft)
         self._journal = journal
         self.metrics = ServingMetrics()
+        self.metrics.kv_pool_share = \
+            self._batcher.sweep_plan.share_of_one_geometry
         #: paged KV + session tiering (serving/paging.py) — None keeps
         #: the PR 6 slot-pinned behavior byte for byte
         self._pager: Optional[SessionPager] = None
@@ -379,6 +381,10 @@ class ServingGateway:
             MetricName.SERVE_LIVE_BLOCK_SHARE: snap["live_block_share"],
             MetricName.SERVE_KV_STREAMED_OVER_LIVE:
                 snap["streamed_over_live"],
+            MetricName.SERVE_KV_POOL_SHARE:
+                snap["kv_pool_share_of_one_geometry"],
+            MetricName.SERVE_KV_WINDOW_STREAMED_SHARE:
+                snap["kv_window_streamed_share"],
             MetricName.SERVE_TOKENS_PER_S: snap["tokens_per_s"],
             MetricName.SERVE_TTFT_S: self.metrics.ttft.snapshot(),
         }
@@ -877,11 +883,11 @@ class ServingGateway:
         # tick was decoded, late rows included: what the kernel stepped (a
         # speculative round's target pass is the chunk kernel's: nothing
         # to count)
-        kv_blocks = kv_tokens = (0, 0)
+        kv_blocks, kv_by_kind = (0, 0), {}
         if counts is None:
             frontiers = [req.frontier + len(req.out) for _, req in live]
             kv_blocks = self._batcher.sweep_blocks(frontiers)
-            kv_tokens = self._batcher.sweep_tokens(frontiers)
+            kv_by_kind = self._batcher.sweep_by_kind(frontiers)
         for row, req in live:
             if row in late:
                 continue
@@ -937,9 +943,14 @@ class ServingGateway:
                         partial=np.asarray(req.out, np.int32)))
         self.metrics.record_tick(active=n_live, slots=self.config.slots,
                                  tokens=harvested, kv_blocks=kv_blocks,
-                                 kv_tokens=kv_tokens,
+                                 kv_by_kind=kv_by_kind,
                                  overlapped=tick.overlapped,
                                  late_rows=len(late))
+        # a family with two pools: the sweep's counts by pool (one pool's
+        # are ``kv_tokens_live`` / ``kv_tokens_streamed`` themselves)
+        if len(kv_by_kind) > 1 and self.tracer.enabled:
+            self.tracer.record(SpanName.SERVE_KV_SWEEP, now, 0.0,
+                               **self.metrics.kv_sweep_counters())
         # the family's device counters, each group by its name (the family
         # owns where a group lies; a family has either, both or neither)
         moe = self._batcher.counts("moe_pairs")

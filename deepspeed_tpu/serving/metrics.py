@@ -60,6 +60,13 @@ class ServingMetrics:
         #: row's last as far as the kernel's copy goes
         self.kv_tokens_live = 0
         self.kv_tokens_streamed = 0
+        #: the same by pool (``SweepPlan.by_kind``: ``"full"`` the whole
+        #: rows, ``"window"`` a family's rings): ``[live, streamed, calls]``
+        #: summed over plain ticks, calls the sweep kernel's (one a layer)
+        self.kv_sweep: dict = {}
+        #: cached rows a slot holds over what one geometry for all layers
+        #: would hold (a gauge the gateway sets from the batcher's plan)
+        self.kv_pool_share = 1.0
         #: ticks launched while the tick before them was un-pulled (the
         #: decode loop keeps one in flight; a busy period's first tick
         #: has no predecessor)
@@ -142,13 +149,15 @@ class ServingMetrics:
 
     def record_tick(self, active: int, slots: int, tokens: int,
                     kv_blocks=(0, 0), overlapped: bool = False,
-                    late_rows: int = 0, kv_tokens=(0, 0)) -> None:
+                    late_rows: int = 0, kv_by_kind=None) -> None:
         """``active``: the requests bound at the tick's launch, the
         ``late_rows`` of them that had finished by its harvest included;
         ``tokens``: those delivered to a request; ``kv_blocks``: the
         tick's ``(live, grid)`` cache blocks
-        (``SlotBatcher.sweep_blocks``); ``kv_tokens``: its ``(live,
-        streamed)`` cached tokens (``SlotBatcher.sweep_tokens``)."""
+        (``SlotBatcher.sweep_blocks``); ``kv_by_kind``: its cached tokens
+        by pool with the sweep's calls, ``pool -> (live, streamed, calls)``
+        (``SlotBatcher.sweep_by_kind``; one pool, ``"full"``, for every
+        family but one with rings)."""
         with self._lock:
             self.ticks += 1
             self.tokens_out += tokens
@@ -156,8 +165,12 @@ class ServingMetrics:
             self.slot_ticks += slots
             self.kv_blocks_live += kv_blocks[0]
             self.kv_blocks_grid += kv_blocks[1]
-            self.kv_tokens_live += kv_tokens[0]
-            self.kv_tokens_streamed += kv_tokens[1]
+            for kind, counts in (kv_by_kind or {}).items():
+                self.kv_tokens_live += counts[0]
+                self.kv_tokens_streamed += counts[1]
+                mine = self.kv_sweep.setdefault(kind, [0, 0, 0])
+                for i, c in enumerate(counts):
+                    mine[i] += c
             self.ticks_overlapped += bool(overlapped)
             self.late_row_ticks += late_rows
 
@@ -169,6 +182,15 @@ class ServingMetrics:
             self.spec_proposed += proposed
         self.spec_accept_rate.observe(accepted / max(1, proposed))
         self.spec_tokens_per_tick.observe(float(emitted))
+
+    def kv_sweep_counters(self) -> dict:
+        """The single-token sweep's cumulative counts by pool, flat, as the
+        ``serve.kv_sweep`` record carries them: ``<pool>_tokens_live``,
+        ``<pool>_tokens_streamed`` and ``<pool>_calls``."""
+        with self._lock:
+            return {f"{kind}_{name}": c[i] for kind, c
+                    in self.kv_sweep.items() for i, name in enumerate(
+                        ("tokens_live", "tokens_streamed", "calls"))}
 
     def record_moe_pairs(self, counts) -> None:
         """``counts``: the batcher's cumulative group ``moe_pairs``, ``[held,
@@ -256,6 +278,14 @@ class ServingMetrics:
                 "streamed_over_live": (self.kv_tokens_streamed
                                        / self.kv_tokens_live
                                        if self.kv_tokens_live else 0.0),
+                # the same by pool, and the share of a one-geometry pool
+                # (every layer's row ``max_len`` long) the pools take
+                "kv_sweep": {k: list(v) for k, v in self.kv_sweep.items()},
+                "kv_pool_share_of_one_geometry": self.kv_pool_share,
+                "kv_window_streamed_share": (
+                    self.kv_sweep.get("window", (0, 0))[1]
+                    / self.kv_tokens_streamed
+                    if self.kv_tokens_streamed else 0.0),
                 "ticks_overlapped": self.ticks_overlapped,
                 "late_row_ticks": self.late_row_ticks,
                 # how much of the loop ran with a tick in flight, and

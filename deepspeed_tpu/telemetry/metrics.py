@@ -104,6 +104,13 @@ class MetricName:
     #: lifetime cached tokens the decode kernel streamed per token a query
     #: saw (1.0: no dead tail in any row's last block)
     SERVE_KV_STREAMED_OVER_LIVE = "serve.kv_streamed_over_live"
+    #: cached rows a slot holds over what one geometry for every layer
+    #: would hold (1.0: one pool; a family with rings beside whole rows
+    #: holds less)
+    SERVE_KV_POOL_SHARE = "serve.kv_pool_share"
+    #: lifetime share of the tokens the single-token sweeps streamed that
+    #: the rings' sweeps streamed (0.0 for a family with one pool)
+    SERVE_KV_WINDOW_STREAMED_SHARE = "serve.kv_window_streamed_share"
     #: histogram of time-to-first-token seconds
     SERVE_TTFT_S = "serve.ttft_s"
     #: decode tokens emitted per second over the gateway lifetime

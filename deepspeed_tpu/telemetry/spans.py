@@ -142,6 +142,12 @@ class SpanName:
     #: since the server started (recorded, zero length; held, routed,
     #: visits and per_expert in args): counted on the device, pulled with the tokens
     SERVE_MOE_PAIRS = "serve.moe_pairs"
+    #: the single-token sweep's counts as of one harvested tick, cumulative
+    #: over plain ticks and by pool (recorded, zero length; in args, for
+    #: ``full`` (whole rows) and, where the family has rings, ``window``:
+    #: ``<pool>_tokens_live``, ``<pool>_tokens_streamed``, ``<pool>_calls``):
+    #: counted on the host from the sweep's plan
+    SERVE_KV_SWEEP = "serve.kv_sweep"
     #: a per-slot-state family's step counts as of one harvested tick,
     #: cumulative (recorded, zero length; in args ssm_rows_stepped: live
     #: slot x state-space layer of the ticks; scan_tokens_real and

@@ -48,7 +48,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # ----------------------------------------------------------------- report
 #: the ``serve.*`` gauges of a metrics row that the serving line prints
 _SERVING_FIELDS = ("queue_depth", "occupancy", "live_block_share",
-                   "kv_streamed_over_live", "tokens_per_s")
+                   "kv_streamed_over_live", "kv_pool_share",
+                   "kv_window_streamed_share", "tokens_per_s")
 #: and, beside them, the decode loop's pipelining shares and the launches
 #: an admission took, which the gateway journals on its ``serve.tick``
 #: events (the newest one is read)

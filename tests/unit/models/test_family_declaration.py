@@ -18,11 +18,12 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from benchmarks.chip import (hybrid_ssm_moe_family, latent_moe_family,
-                             nemotron_h_family)
+                             mellum_family, nemotron_h_family)
 from deepspeed_tpu.models import (cache_family, gpt, gpt_inference, gpt_moe,
                                   gpt_moe_inference, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference,
-                                  latent_moe_inference)
+                                  latent_moe_inference, window_moe,
+                                  window_moe_inference)
 from deepspeed_tpu.ops.pallas import decode_attention
 from tests.unit.ops.traced_sweeps import sweep_calls
 
@@ -61,12 +62,14 @@ def _served(name):
         "hybrid": (hybrid_ssm_moe_family, "granite-4.0-h-small-ep4",
                    hybrid_ssm_moe_inference.FAMILY),
         "single_part": (nemotron_h_family, "nemotron-3-nano-30b-a3b-ep4",
-                        hybrid_ssm_moe_inference.FAMILY)}[name]
+                        hybrid_ssm_moe_inference.FAMILY),
+        "window": (mellum_family, "mellum2-12b-a2.5b-ep4",
+                   window_moe_inference.FAMILY)}[name]
     cfg = _tiny(builder, file)
     return cfg, lambda k: builder.init(cfg, k, jnp.float32), family
 
 
-SERVED = ("dense", "moe", "latent", "hybrid", "single_part")
+SERVED = ("dense", "moe", "latent", "hybrid", "single_part", "window")
 
 
 @pytest.mark.parametrize("name", SERVED)
@@ -85,7 +88,8 @@ def test_cache_family_returns_the_whole_declaration(name):
     assert set(fam.stats_groups(cfg)) == {
         "dense": set(), "moe": set(), "latent": {"moe_pairs"},
         "hybrid": {"moe_pairs", "state_steps"},
-        "single_part": {"moe_pairs", "state_steps"}}[name]
+        "single_part": {"moe_pairs", "state_steps"},
+        "window": {"moe_pairs"}}[name]
     assert fam.state_counters == (
         hybrid_ssm_moe_inference.STATE_COUNTERS
         if name in ("hybrid", "single_part") else ())
@@ -180,7 +184,9 @@ SERVING_REFUSALS = [
      "serving.prefix with HybridSSMMoEConfig: a pooled prefix would need a "
      "snapshot of the per-slot state at its end; the pool keeps "
      "token-indexed banks only"),
-]
+] + [("window", feature, f"serving.{feature} with WindowMoEConfig: "
+      + window_moe_inference.UNSUPPORTED[feature])
+     for feature in ("speculative", "paging", "prefix")]
 
 
 def _shell_batcher(name):
@@ -221,6 +227,8 @@ def test_what_a_family_serves_is_not_refused(name, feature):
                "only (kv_cache_dtype='int8')"),
     ("single_part", "the hybrid state-space family caches in the compute "
                     "dtype only (kv_cache_dtype='int8')"),
+    ("window", "the window-and-full family caches in the compute dtype "
+               "only (kv_cache_dtype='int8')"),
 ])
 def test_the_int8_cache_is_refused_where_the_cache_is_made(name, said):
     cfg, _, fam = _served(name)
@@ -297,6 +305,13 @@ def _swept(name):
             max_seq_len=1024, layer_types=(M, E, A, E, M, A, E), n_head=8,
             n_kv_head=2, head_dim=128, mixer_ffn=False, **_HYBRID), 1024, \
             decode_attention.GROUPED_SWEEP
+    if name == "window":        # two pools: whole rows and rings of 512
+        return window_moe, window_moe.WindowMoEConfig(
+            vocab_size=256, max_seq_len=1024, layer_types=("window", "full",
+                                                           "window"),
+            d_model=128, n_head=8, n_kv_head=2, head_dim=128, window=512,
+            n_experts=4, experts_per_token=2, d_expert=32,
+            dtype=jnp.float32), 1024, decode_attention.GROUPED_SWEEP
     assert name == "grouped-64"     # grouped heads narrower than a lane row
     return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
         max_seq_len=1024, layer_types=(M, A, M), n_head=4, n_kv_head=2,
@@ -304,7 +319,8 @@ def _swept(name):
 
 
 @pytest.mark.parametrize("name", ["dense", "dense-banded", "moe", "latent",
-                                  "hybrid", "single_part", "grouped-64"])
+                                  "hybrid", "single_part", "window",
+                                  "grouped-64"])
 def test_the_plan_is_the_work_lists_block_and_the_kernels(monkeypatch, name):
     """One function of the row says which sweep serves it and by which
     block; the family's plan, the tick's work list and the kernel the tick
@@ -315,10 +331,12 @@ def test_the_plan_is_the_work_lists_block_and_the_kernels(monkeypatch, name):
     slots = 3
     plan = fam.sweep_plan(cfg, smax, jnp.dtype(cfg.dtype).itemsize)
     row = gpt_inference.cache_row(cfg)
-    assert plan == decode_attention.sweep_plan(
+    ring = plan.ring
+    assert plan._replace(ring=None) == decode_attention.sweep_plan(
         row, smax, cfg.n_head,
         kv_heads=getattr(cfg, "n_kv_head", None) if len(row) == 2 else None,
         itemsize=4, windows=plan.windows)
+    assert (ring is not None) == (name == "window")
     assert plan.kernel == kernel and plan.Smax == smax
     assert plan.block_k == (
         decode_attention.latent_block_k(smax) if len(row) == 1
@@ -344,8 +362,14 @@ def test_the_plan_is_the_work_lists_block_and_the_kernels(monkeypatch, name):
     if kernel is None:
         assert not calls
         return
-    assert calls and all(
-        c == (kernel, plan.block_k, plan.block_k) for c in calls), calls
+    lists = {(kernel, plan.block_k, plan.block_k)}
+    if ring is not None:    # a ring's list is its own pool's (``sweep_calls``
+        # reads a list's length as if it covered ``smax``)
+        assert ring == decode_attention.sweep_plan(
+            row, 512, cfg.n_head, kv_heads=cfg.n_kv_head, itemsize=4,
+            windows=((None, 2),))
+        lists.add((kernel, ring.block_k, ring.block_k * smax // ring.Smax))
+    assert calls and set(calls) == lists, calls
 
 
 def test_the_plans_counts_are_the_kernel_files():

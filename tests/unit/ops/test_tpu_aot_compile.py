@@ -271,12 +271,13 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
 
 _SERVED = pytest.mark.parametrize("family,int8", [
     ("dense", False), ("dense", True), ("moe", False), ("moe", True),
-    ("latent", False), ("hybrid", False), ("single_part", False)],
+    ("latent", False), ("hybrid", False), ("single_part", False),
+    ("window", False)],
     ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent",
-         "bf16-hybrid", "bf16-single_part"])
+         "bf16-hybrid", "bf16-single_part", "bf16-window"])
 
 #: scans of a tick: one a segment of the family's step
-_SEGMENTS = {"latent": 2, "hybrid": 3, "single_part": 7}
+_SEGMENTS = {"latent": 2, "hybrid": 3, "single_part": 7, "window": 1}
 
 
 def _served(family):
@@ -300,7 +301,14 @@ def _served(family):
     groups of ``B`` and ``C``, the grouped-head decode with 16 query heads a
     key-value head over a 256-wide row, and the grouped matmul at ``[2688,
     1920]`` / ``[1920, 2688]`` (1856 stored padded), 32 of 128 experts
-    held."""
+    held; the window-and-full family at its own cell's 48 x 8,192 in chunks
+    of 1,024, its published widths and all 28 layers, ``(WWWF) x 7`` as one
+    scan: 7 layers of whole rows beside 21 rings of 1,024 cells a slot (7.75
+    GB with 6.97 GB of weights: 87% of the chip), the grouped sweep with 8
+    query heads a key-value head over a 512-wide row in blocks of 512 over
+    both pools, the banded grouped chunk pass over a ring unrolled beside
+    the chunk's rows, the grouped matmul at ``[2304, 1792]`` / ``[896,
+    2304]``, 16 of 64 experts held."""
     import dataclasses
 
     from deepspeed_tpu.models import gpt, gpt_moe
@@ -339,6 +347,17 @@ def _served(family):
             expert_form="relu2", gate="sigmoid", routed_scale=2.5,
             tie_head=False, dtype=BF16,
             param_dtype=BF16), 128, 16384, 1024
+    if family == "window":
+        from deepspeed_tpu.models import window_moe
+        return window_moe, window_moe.WindowMoEConfig(
+            vocab_size=24576, max_seq_len=131072,
+            layer_types=(("window",) * 3 + ("full",)) * 7, d_model=2304,
+            n_head=32, n_kv_head=4, head_dim=128, window=1024,
+            rope_theta=500000.0,
+            yarn=(16.0, 8192, 32.0, 1.0, 1.2772588722239782), n_experts=64,
+            experts_per_token=8, d_expert=896,
+            held_experts=tuple(range(16)), dtype=BF16,
+            param_dtype=BF16), 48, 8192, 1024
     from deepspeed_tpu.models import latent_moe
     smax = 8192
     return latent_moe, latent_moe.LatentMoEConfig(
@@ -362,13 +381,47 @@ def _pool_bytes(cache):
                for x in jax.tree_util.tree_leaves(cache) if x.ndim >= 4)
 
 
+#: The window-and-full family's smallest stack is a RING's layer, 48 slots
+#: of 1,024 cells x 512 (25.2M elements), under which two arrays that are
+#: not the pool fall over the line of "as large as a layer of the pool".
+#: Each is named here with its size, opcode, count and cause; anything else
+#: that large, a weight stack re-laid or one move more of these, fails.
+#: Every other family moves nothing that large and is held to nothing.
+_KNOWN_MOVES = {
+    # the untied head ``[24576, 2304]`` (56.6M elements), fetched ahead into
+    # the compiler's fast memory space by cross-program prefetch
+    # (``copy-start(%p__head__), cross_program_prefetch_index=0``): the same
+    # tiled layout on both sides, no re-lay, and the one read a tick makes
+    # of the head anyway
+    ("window", "tick"): {(24576 * 2304, "copy-done"): 1},
+    # the admission's BATCH-1 row, its two full banks ``[7, 1, 8192, 512]``
+    # (29.4M elements each: 7 layers of one slot are more than one ring
+    # layer of 48): once an admission, after the chunk loop, each bank is
+    # gathered from the loop's carry (``ConcatBitcast`` + ``copy``) and
+    # fetched (``copy-done``) for the slot write.  0.24 GB moved beside the
+    # 0.3 s of a 4k-token admission (PERF.md 5); the accepted families' rows
+    # make the same trip under their thresholds
+    ("window", "admission"): {(7 * 8192 * 512, "copy"): 2,
+                              (7 * 8192 * 512, "copy-done"): 2},
+}
+
+
+def _beyond_the_known(moved, family, program):
+    """``moved`` less what :data:`_KNOWN_MOVES` names for this family's
+    program, each at most as often as it is named."""
+    import collections
+    return sorted((collections.Counter(moved) - collections.Counter(
+        _KNOWN_MOVES.get((family, program), {}))).elements())
+
+
 def _layer_elements(cfg, cache, slots, smax):
     """Elements of one layer of the pool's smallest large stack: a bank's,
     or the per-slot state's where the family keeps one."""
     from deepspeed_tpu.models.gpt_inference import cache_row
     layers = [slots * smax * cache_row(cfg)[0]]
-    if cache.state is not None:
-        layers.append(cache.state[0].size // cache.state[0].shape[0])
+    for stacks in (cache.state, cache.ring):
+        if stacks is not None:
+            layers.append(stacks[0].size // stacks[0].shape[0])
     return min(layers)
 
 
@@ -409,6 +462,7 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     moved = [(n, op) for n, op in _root_opcodes(text)
              if n >= layer_k and (op.startswith("copy") or op in (
                  "transpose", "dynamic-slice", "dynamic-update-slice"))]
+    moved = _beyond_the_known(moved, family, "tick")
     assert not moved, f"the tick moves whole layers of the pool: {moved}"
     assert "input_output_alias" in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
@@ -423,16 +477,21 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
 #: Granite's 8 key-value heads of 128), 1,024 for Nemotron's 256-wide row
 #: (PR 46), the latent sweep's own 512
 _SWEEP_BLOCK = {"dense": 256, "moe": 256, "hybrid": 256,
-                "single_part": 1024, "latent": 512}
+                "single_part": 1024, "latent": 512, "window": 512}
 
 
 def _sweeps_in_blocks_of(jaxpr, cfg, slots, smax, block):
     """Every single-token sweep of the traced tick is built for ``block``
-    tokens a step, and so is the work list it walks."""
-    from deepspeed_tpu.models.gpt_inference import cache_row
+    tokens a step, and so is the work list it walks; a ring's list is its
+    own pool's, ``slots * R / block`` entries (``sweep_calls`` reads a
+    list's length as if it covered ``smax``)."""
+    from deepspeed_tpu.models.gpt_inference import cache_ring, cache_row
     from tests.unit.ops.traced_sweeps import sweep_calls
     calls = sweep_calls(jaxpr, slots, smax, cache_row(cfg)[0])
-    assert calls and all(c[1:] == (block, block) for c in calls), calls
+    ring = cache_ring(cfg, smax)
+    lists = {(block, block)} | (
+        {(block, block * smax // ring[1])} if ring else set())
+    assert calls and {c[1:] for c in calls} == lists, calls
 
 
 def _one_dense_sweep_a_layer(text, cfg, cache, slots, smax, calls):
@@ -530,7 +589,9 @@ def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the admission"
     assert " while(" in text, "no loop over the chunks"
-    moved = _pool_sized_moves(text, _layer_elements(cfg, pool, slots, smax))
+    moved = _beyond_the_known(
+        _pool_sized_moves(text, _layer_elements(cfg, pool, slots, smax)),
+        family, "admission")
     assert not moved, f"the admission moves whole layers of the pool: {moved}"
     assert "input_output_alias" in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \

@@ -329,9 +329,9 @@ def _replay(seed, requests, prompts, outputs, smax, block, layers, copy_rows):
         out = rng.integers(outputs[0], outputs[1] + 1)
         # the tick that decodes token t reads the row up to prompt + t
         m.record_tick(active=1, slots=1, tokens=int(out),
-                      kv_tokens=sweep_token_counts(
+                      kv_by_kind={"full": sweep_token_counts(
                           [int(prompt) + t for t in range(out)], smax, block,
-                          ((None, layers),), copy_rows))
+                          ((None, layers),), copy_rows) + (layers,)})
     return m.snapshot()
 
 
@@ -355,8 +355,9 @@ def test_streamed_over_live(case):
         m = ServingMetrics()
         assert m.snapshot()["streamed_over_live"] == 0.0
         m.record_tick(active=4, slots=4, tokens=4,
-                      kv_tokens=sweep_token_counts(
-                          [15, 255, 271, 1023], 1024, 256, ((None, 2),), 16))
+                      kv_by_kind={"full": sweep_token_counts(
+                          [15, 255, 271, 1023], 1024, 256, ((None, 2),),
+                          16) + (2,)})
         snap = m.snapshot()
         assert snap["kv_tokens_live"] == 2 * (16 + 256 + 272 + 1024)
         assert snap["kv_tokens_streamed"] == snap["kv_tokens_live"]
@@ -385,7 +386,7 @@ def test_streamed_over_live_of_agent_sats_whole_blocks(block, lo, hi):
 
 
 def test_the_batcher_counts_the_tokens_its_familys_kernel_streams():
-    """``SlotBatcher.sweep_tokens``: the dense family's kernel ends its copy
+    """``SlotBatcher.sweep_by_kind``: the dense family's kernel ends its copy
     on a tile, so a row at 100 streams 112 tokens a layer where its block,
     the whole 512-token slot of this 128-wide row, holds 512."""
     cfg = gpt.GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=128,
@@ -397,9 +398,9 @@ def test_the_batcher_counts_the_tokens_its_familys_kernel_streams():
     bat = SlotBatcher(eng, ServingConfig.from_dict(
         {"slots": 2, "max_len": 512, "prefill_chunk": 8}))
     assert bat.sweep_blocks([100, 300]) == (2 * 2, 2 * 2 * 1)
-    assert bat.sweep_tokens([100, 300]) == (2 * (101 + 301),
-                                            2 * (112 + 304))
-    assert bat.sweep_tokens([]) == (0, 0)
+    assert bat.sweep_by_kind([100, 300]) == {
+        "full": (2 * (101 + 301), 2 * (112 + 304), 2)}
+    assert bat.sweep_by_kind([]) == {"full": (0, 0, 2)}
 
 
 # ------------------------------------------------- the one-launch admission

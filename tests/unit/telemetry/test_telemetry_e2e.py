@@ -223,8 +223,8 @@ def test_report_mode_prints_streamed_over_live(tmp_path, capsys):
                                                  MetricsSampler)
     m = ServingMetrics()
     m.record_tick(active=1, slots=4, tokens=1, kv_blocks=(2, 16),
-                  kv_tokens=sweep_token_counts([300], 1024, 256,
-                                               copy_rows=16))
+                  kv_by_kind={"full": sweep_token_counts(
+                      [300], 1024, 256, copy_rows=16) + (1,)})
     snap = m.snapshot(queue_depth=0)
     assert (snap["kv_tokens_live"], snap["kv_tokens_streamed"]) == (301, 304)
     sampler = MetricsSampler(MetricsRegistry(),
