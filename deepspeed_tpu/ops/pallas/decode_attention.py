@@ -25,8 +25,12 @@ scalar prefetch beside ``pos`` and the sweep, a grid step takes all heads of
 one block of one slot (the dense sweep by its own asynchronous copies out
 of the pool in HBM, the next steps' in flight behind the current one), and
 nothing is sliced, transposed or copied to feed it.  The chunk kernel
-(``extend``: admission, speculative verify) still takes one head a step from a ``[B*H, S_max, D]`` view of one layer: a
-64-wide head is half a lane row and cannot be a block of the folded row.
+(``extend``: admission, speculative verify) still takes one KEY-VALUE head a
+step from a ``[B*Hkv, S_max, D]`` view of one layer (a 64-wide head is half
+a lane row and cannot be a block of the folded row), with the whole group
+of query heads that share it: ``G * block_q`` query rows against one key
+block, products in the query's dtype as the sweeps make them
+(``_chunk_kernel``).
 
 A latent cache (``models/latent_moe.py``) keeps ONE row per token and layer,
 shared by all heads: ``[c | R(k_r)]``, stored ``[L, B, S_max, W]`` with ``W``
@@ -42,11 +46,12 @@ one kernel here that sits near the ridge and not far under it.
 Grouped heads (``kv_heads`` of ``cached_attention``): ``H`` query heads on
 ``H / G`` key-value heads.  The row stays ``H/G * D``; the decode kernel
 (``_gqa_decode``) takes the block with all its key-value heads once and the
-``G`` query heads of each share it, the chunk kernel maps query head ``bh``
-to the blocks of key-value head ``bh // G``.  Nothing repeats a key-value
-head out to its query heads.  The chunk kernel takes a band (``window``)
-with ``G > 1``; no family sweeps a single token over grouped heads under a
-band (a ring below needs none), and that pair stays refused.
+``G`` query heads of each share it, the chunk kernel takes a key-value
+head's block once for the ``G`` heads of its group, their rows under one
+another.  Nothing repeats a key-value head out to its query heads.  The
+chunk kernel takes a band (``window``) with ``G > 1``; no family sweeps a
+single token over grouped heads under a band (a ring below needs none), and
+that pair stays refused.
 
 A RING (``gpt_inference.KVCache.ring``: a window layer's last ``R`` tokens,
 position ``p`` in cell ``p mod R``) needs no kernel of its own.  A single
@@ -168,16 +173,20 @@ def _sweep_position(rows_ref, n_ref):
     return step, row, step < n, first, last
 
 
-def _online_softmax_step(s, values, acc_ref, m_ref, l_ref):
+def _online_softmax_step(s, values, acc_ref, m_ref, l_ref, value_scale=None):
     """One block of the online-softmax recurrence: masked float32 scores
     ``s`` [rows, keys] and the block's ``values`` [keys, width] into the
-    running max ``m``, sum ``l`` and accumulator ``acc``."""
+    running max ``m``, sum ``l`` and accumulator ``acc``.  ``value_scale``
+    ([rows or 1, keys] float32): an int8 block's scales, which weigh the
+    float32 probabilities so that the product takes the codes as they are."""
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     m_ref[...] = m_new
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    if value_scale is not None:
+        p = p * value_scale
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
         p.astype(values.dtype), values, preferred_element_type=jnp.float32)
 
@@ -305,17 +314,9 @@ def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
         visible = k_pos <= pos
         if windowed:
             visible = jnp.logical_and(visible, k_pos > pos - window_ref[0])
-        s = jnp.where(visible, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        if quantized:
-            p = p * vscale_ref[...].T
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(vs.dtype), vs, preferred_element_type=jnp.float32)
+        _online_softmax_step(jnp.where(visible, s, NEG_INF), vs, acc_ref,
+                             m_ref, l_ref,
+                             vscale_ref[...].T if quantized else None)
 
     @pl.when(jnp.logical_and(live, last))
     def _finalize():
@@ -324,10 +325,12 @@ def _decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref, *rest,
 
 
 def _to_compute(x, dtype):
-    """A cache block in the query's dtype: the products then run natively
-    (bf16 x bf16 is exact, accumulated in float32; an fp32 cast before the
-    dot would only multiply the MXU passes).  int8 codes go through
-    float32; every code is exact in bf16."""
+    """A cache block in the query's dtype, for the single-token sweeps and
+    the chunk pass alike: the products then run natively (bf16 x bf16 is
+    exact, accumulated in float32; an fp32 cast before the dot would only
+    multiply the MXU passes).  int8 codes go through float32; every code
+    is exact in bf16, and their scales weigh the float32 scores and
+    probabilities instead."""
     if x.dtype == jnp.int8:
         x = x.astype(jnp.float32)
     return x.astype(dtype)
@@ -370,13 +373,43 @@ def decode_block_k(Smax: int, HD: int) -> Optional[int]:
     return _block_of((1024, 512, 256, 128), Smax, HD)
 
 
-def chunk_block_k(Smax: int, HD: int) -> Optional[int]:
-    """Keys in one block of the chunk kernel (admission, verify): the
-    single-token sweep's size as it was before PR 46 let that one grow for
-    narrow rows.  The chunk pass takes one key-value head a step, ``D``
-    wide whatever the row, and whether it wants larger blocks has not been
-    measured."""
-    return _block_of((256, 128), Smax, HD)
+#: score elements a step of the chunk kernel holds at most: float32 scores
+#: of 1,024 rows x 1,024 keys are 4 MB of VMEM, their probabilities as many
+_CHUNK_TILE = 1 << 20
+
+
+def chunk_block_k(Smax: int) -> Optional[int]:
+    """Keys in one block of the chunk kernel (admission, verify), or None
+    where ``Smax`` does not tile.  A step takes ONE key-value head's block,
+    a head wide whatever the row, against the ``G * chunk_block_q`` query
+    rows of its group, and what a step costs beside its two products (the
+    running max and sum of every row, the accumulator's rescale, a step's
+    own fixed cost) does not grow with the keys: so the largest block that
+    leaves a row two blocks (a prefix under half the row then skips half
+    of it), 1,024 keys at most, ``_CHUNK_TILE`` scores a step.  Measured on
+    a v5e at the serving cells' shapes (PERF.md 6, PR 49; ms a call over a
+    prompt's chunks, rows x keys a step): 8 heads a group, ``D`` 128, rows
+    of 8,192 and a ring's 2,048 (three calls in four): 2,048 x 256 0.493,
+    2,048 x 512 0.324, 1,024 x 512 0.402, **1,024 x 1,024 0.269**, 512 x
+    1,024 0.294, 512 x 2,048 0.313 (a head a step, float32 products, as
+    before: 0.769); 16 heads a group, rows of 16,384: 0.673 / **0.470** /
+    0.567 for 2,048 x 512 / 1,024 x 1,024 / 512 x 2,048 (before: 1.935); 4
+    heads a group, rows of 5,120 in chunks of 512: 0.139 / **0.096**
+    (before: 0.289); ungrouped heads of 64, rows of 1,024 in chunks of 128:
+    0.0144 with 512 keys, 0.0157 with the whole row a block (before:
+    0.0158).  At 1,024 x 1,024 the two products are 69% of a step."""
+    return next((b for b in (1024, 512, 256, 128)
+                 if Smax % b == 0 and (2 * b <= Smax or b == 128)), None)
+
+
+def chunk_block_q(Sq: int, G: int, block_k: int) -> Optional[int]:
+    """Positions in one query tile of the chunk kernel, or None where the
+    chunk does not tile in the sublane dimension: the largest that keeps a
+    step's ``G * block_q`` rows x ``block_k`` keys at or under
+    ``_CHUNK_TILE`` scores: against 1,024 keys 256 positions up to 4 query
+    heads a key-value head, 128 for 8, 64 for 16."""
+    return next((b for b in (256, 128, 64, 32, 16, 8) if Sq % b == 0
+                 and (G * b * block_k <= _CHUNK_TILE or b == 8)), None)
 
 
 def sweep_block_k(sweep, B: int, Smax: int) -> int:
@@ -669,17 +702,46 @@ def _decode(q, k, v, layer, pos, sweep, sm_scale, block_k, H, copy_rows,
                           name="decode_attention")(*args)
 
 
-def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
-                  windowed, alibi, bounded=False):
+def _chunk_live_range(q_lo, block_q: int, block_k: int, window, first):
+    """``(lo, hi)``: the first and the last key block a pair of the query
+    tile at ``q_lo`` can be visible in (band start or first real key, causal
+    frontier): the range the chunk kernel's index map clamps a step's block
+    to, so that a dead step maps to a block already in VMEM."""
+    lo = 0
+    if window is not None:
+        lo = jnp.maximum((q_lo - window + 1) // block_k, 0)
+    if first is not None:
+        lo = jnp.maximum(lo, first // block_k)
+    return lo, (q_lo + block_q - 1) // block_k
+
+
+def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, Hkv, G,
+                  quantized, windowed, alibi, bounded=False):
     """Chunked-prefill attention over the padded cache: queries are a
     whole chunk at absolute positions ``pos .. pos+Sq-1`` (online softmax
     per row, cache blocks streamed through VMEM, blocks beyond the
     chunk's causal frontier — and, when windowed, wholly below every
     row's band — skipped).  Memory-linear counterpart of the dense
     fallback ``extend`` would otherwise take — O(block) VMEM instead of
-    an [Sq, Smax] score tensor.  The running max is floored at
-    ``M_FLOOR`` (not -inf): a windowed block can be fully masked for
-    SOME of its q rows, and those rows' recurrences must stay nan-free.
+    an [Sq, Smax] score tensor.
+
+    A grid step is a key-value head's GROUP against a key block: ``q_ref``
+    is ``(1, G, block_q, D)``, ``block_q`` positions of the ``G`` query
+    heads that share key-value head ``program_id(0)``, taken as ``G *
+    block_q`` rows under one another (row ``r`` sits at position ``pos + qi
+    * block_q + r mod block_q``), so a key block is fetched once a group
+    and two products serve all its heads.  ``G == 1`` is the same body on a
+    group of one.  The products are the single-token sweep's: the blocks in
+    the query's dtype (``_to_compute``), float32 scores scaled after the
+    product, the probabilities rounded to the values' dtype
+    (``_online_softmax_step``).
+
+    Every live block takes the mask, and the running max is floored at
+    ``M_FLOOR`` (not -inf): a block on a band's edge can be fully masked
+    for SOME of its q rows, and those rows' recurrences must stay
+    nan-free.  (A block whose every pair is visible could go without its
+    mask; measured, that is worth nothing at this tile: PERF.md 6, PR 49.)
+
     ``bounded``: a second scalar-prefetch vector leads ``rest``, each row's
     first real key (``valid_from`` of ``cached_attention``); the keys
     before it are masked and the blocks wholly before it skipped."""
@@ -693,7 +755,7 @@ def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
-    pos = pos_ref[bh // H]
+    pos = pos_ref[bh // Hkv]
 
     @pl.when(ki == 0)
     def _init():
@@ -701,88 +763,79 @@ def _chunk_kernel(pos_ref, *rest, sm_scale, block_q, block_k, H, quantized,
         m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # highest key this q block may see: pos + (qi+1)*block_q - 1
-    live = ki * block_k <= pos + (qi + 1) * block_q - 1
-    if windowed:
-        # lowest q row is pos + qi*block_q; a block wholly below ITS
-        # band is invisible to every row in the block
-        live = jnp.logical_and(
-            live,
-            (ki + 1) * block_k - 1 >= pos + qi * block_q - window_ref[0] + 1)
-    if bounded:
-        live = jnp.logical_and(live,
-                               (ki + 1) * block_k - 1 >= first_ref[bh // H])
+    q_lo = pos + qi * block_q               # the tile's first position
+    window = window_ref[0] if windowed else None
+    first = first_ref[bh // Hkv] if bounded else None
+    lo, hi = _chunk_live_range(q_lo, block_q, block_k, window, first)
 
-    @pl.when(live)
+    @pl.when(jnp.logical_and(lo <= ki, ki <= hi))
     def _update():
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # (BQ, D)
-        ks = k_ref[0].astype(jnp.float32)                  # (BK, D)
-        vs = v_ref[0].astype(jnp.float32)
-        if quantized:
-            ks = ks * kscale_ref[0]
-            vs = vs * vscale_ref[0]
+        q = q_ref[0].reshape(G * block_q, q_ref.shape[-1])  # (G*BQ, D)
+        ks = _to_compute(k_ref[0], q.dtype)                 # (BK, D)
+        vs = _to_compute(v_ref[0], q.dtype)
         s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (BQ, BK)
-        q_pos = pos + qi * block_q + \
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = ki * block_k + \
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        dist = q_pos - k_pos
-        if alibi:
-            s = s - slopes_ref[bh % H] * dist.astype(jnp.float32)
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale                                    # (G*BQ, BK)
+        if quantized:   # the codes' scales, as the single-token sweep's
+            s = s * kscale_ref[0].T
+        r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        if G > 1:
+            r = jax.lax.rem(r, block_q)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        dist = q_lo + r - k_pos
+        if alibi:       # G == 1 (``_chunk``): the step's one head's slope
+            s = s - slopes_ref[bh % Hkv] * dist.astype(jnp.float32)
         visible = dist >= 0
         if windowed:
-            visible = jnp.logical_and(visible, dist < window_ref[0])
+            visible = jnp.logical_and(visible, dist < window)
         if bounded:
-            visible = jnp.logical_and(visible, k_pos >= first_ref[bh // H])
-        s = jnp.where(visible, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, vs, preferred_element_type=jnp.float32)
+            visible = jnp.logical_and(visible, k_pos >= first)
+        _online_softmax_step(jnp.where(visible, s, NEG_INF), vs, acc_ref,
+                             m_ref, l_ref,
+                             vscale_ref[0].T if quantized else None)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).reshape(
+            o_ref.shape[1:]).astype(o_ref.dtype)
 
 
-def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
-           vs3=None, window=None, slopes=None, group: int = 1,
-           valid_from=None):
-    """``group`` query heads share a key-value head (grouped heads): ``q3``
-    is ``[B*H, Sq, D]``, ``k3``/``v3`` ``[B*H/group, Smax, D]`` and query
-    head ``bh`` streams the blocks of key-value head ``bh // group``.
-    ``valid_from`` (scalar or [B]): each row's first real key."""
-    BH, Sq, D = q3.shape
+def _chunk(q4, k3, v3, pos, sm_scale, block_q, block_k, Hkv, ks3=None,
+           vs3=None, window=None, slopes=None, valid_from=None):
+    """``q4`` is ``[B*Hkv, G, Sq, D]``, the ``G`` query heads of each
+    key-value head beside one another (``G`` 1: every head its own), and
+    ``k3``/``v3`` ``[B*Hkv, Smax, D]`` (scales ``[B*Hkv, Smax, 1]``): grid
+    step ``(bh, qi, ki)`` takes key-value head ``bh``'s whole group, ``G *
+    block_q`` query rows, against key block ``ki``, so a block is fetched
+    once a group.  The result has ``q4``'s shape.  ``valid_from`` (scalar
+    or [B]): each row's first real key."""
+    BH, G, Sq, D = q4.shape
     Smax = k3.shape[1]
-    B = BH // H
+    B = BH // Hkv
     quantized = ks3 is not None
     windowed = window is not None
     bounded = valid_from is not None
+    assert slopes is None or G == 1, "a slope a step: ungrouped heads"
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     kernel = functools.partial(_chunk_kernel, sm_scale=sm_scale,
-                               block_q=block_q, block_k=block_k, H=H,
-                               quantized=quantized, windowed=windowed,
+                               block_q=block_q, block_k=block_k, Hkv=Hkv,
+                               G=G, quantized=quantized, windowed=windowed,
                                alibi=slopes is not None, bounded=bounded)
     # single scalar-prefetch build (see _decode): dead k-block indices
     # clamp into this q block's live range [band start or first real key,
     # causal frontier], so chunked prefill/extend streams only the blocks
     # its rows can see
     def kv_idx(bh, qi, ki, pos_ref, *more):
-        p = pos_ref[bh // H]
-        lo = 0
-        if windowed:
-            lo = jnp.maximum(
-                (p + qi * block_q - more[-1][0] + 1) // block_k, 0)
-        if bounded:
-            lo = jnp.maximum(lo, more[0][bh // H] // block_k)
-        hi = (p + (qi + 1) * block_q - 1) // block_k
-        return (bh // group, jnp.clip(ki, lo, hi), 0)
+        lo, hi = _chunk_live_range(
+            pos_ref[bh // Hkv] + qi * block_q, block_q, block_k,
+            more[-1][0] if windowed else None,
+            more[0][bh // Hkv] if bounded else None)
+        return (bh, jnp.clip(ki, lo, hi), 0)
 
+    def q_idx(bh, qi, ki, *_):
+        return (bh, 0, qi, 0)
+
+    q_spec = pl.BlockSpec((1, G, block_q, D), q_idx)
     kv_spec = pl.BlockSpec((1, block_k, D), kv_idx)
     scale_spec = pl.BlockSpec((1, block_k, 1), kv_idx)
     slope_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] \
@@ -794,27 +847,23 @@ def _chunk(q3, k3, v3, pos, sm_scale, block_q, block_k, H, ks3=None,
     if bounded:     # the kernel's ``first_ref`` leads the window
         win_args = (jnp.broadcast_to(jnp.asarray(
             valid_from, jnp.int32).reshape(-1), (B,)),) + win_args
+    rows = G * block_q
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1 + len(win_args),
         grid=(BH, Sq // block_q, Smax // block_k),
-        in_specs=slope_specs + [
-            pl.BlockSpec((1, block_q, D),
-                         lambda bh, qi, ki, *_: (bh, qi, 0)),
-            kv_spec, kv_spec,
-        ] + ([scale_spec, scale_spec] if quantized else []),
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, qi, ki, *_: (bh, qi, 0)),
+        in_specs=slope_specs + [q_spec, kv_spec, kv_spec]
+        + ([scale_spec, scale_spec] if quantized else []),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((rows, D), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
         ],
     )
-    args = (pos_arr,) + win_args + slope_args + (q3, k3, v3) + \
+    args = (pos_arr,) + win_args + slope_args + (q4, k3, v3) + \
         ((ks3, vs3) if quantized else ())
     return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=jax.ShapeDtypeStruct((BH, Sq, D),
-                                                         q3.dtype),
+                          out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
                           interpret=interpret_mode(),
                           name="chunk_attention")(*args)
 
@@ -1158,16 +1207,21 @@ def cached_attention(q, cache_k, cache_v, pos,
     ``window``, built by a caller that makes this call once per layer, and
     the kernel's block is the one the list was built for
     (``sweep_block_k``); left out, it is built here for the plan's block
-    (``sweep_plan``, which also says which of the sweeps serves the row).  Multi-token chunks (chunked prefill /
-    ``extend``) take the chunk kernel when the shapes tile — O(block) VMEM
-    instead of a dense [Sq, Smax] score tensor; remaining shapes use the
-    dense reference.
+    (``sweep_plan``, which also says which of the sweeps serves the row).
+    Multi-token chunks (chunked prefill / ``extend``) take the chunk kernel
+    when the shapes tile — O(block) VMEM instead of a dense [Sq, Smax]
+    score tensor; remaining shapes use the dense reference.  Its grid step
+    is a key-value head's group against one key block (``q`` re-laid
+    ``[B*Hkv, G, Sq, D]``, ``G`` read from the shapes, 1 for ungrouped
+    heads), its tile ``chunk_block_q`` positions x ``chunk_block_k`` keys
+    from ``(Sq, Smax, G)``.
 
     With ``k_scale``/``v_scale`` ([B,Smax,H,1] fp32; stacked [L,B,Smax,H])
     the cache holds int8 codes; the decode kernel streams the codes
     (halving the HBM stream) and applies the scales to the scores and the
-    probabilities in VMEM, the chunk kernel dequantizes its blocks in
-    VMEM, and the non-kernel fallbacks dequantize before the dense math.
+    probabilities in VMEM, the chunk kernel does the same with the blocks
+    its pipeline brings, and the non-kernel fallbacks dequantize before
+    the dense math.
 
     ``window`` (scalar, possibly traced — GPT-Neo's alternating stack
     carries it through a layer scan) bands visibility to the trailing
@@ -1207,13 +1261,13 @@ def cached_attention(q, cache_k, cache_v, pos,
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
     plan = sweep_plan((Hkv * D,) * 2, Smax, H, Hkv,
                       banks[0].dtype.itemsize)
-    key_block = chunk_block_k(Smax, Hkv * D)
     # chunk path: pos may be scalar OR per-row [B] (ragged chunks — the
-    # kernel reads its row's frontier from pos_ref[bh // H] everywhere:
-    # mask, live range, and DMA clamp); the chunk must tile in the q
-    # (sublane) dimension
-    block_q = next((b for b in (256, 128, 8) if Sq % b == 0), None) \
-        if Sq > 1 else None
+    # kernel reads its row's frontier from pos_ref everywhere: mask, live
+    # range, and DMA clamp); the chunk must tile in the q (sublane)
+    # dimension
+    key_block = chunk_block_k(Smax)
+    block_q = chunk_block_q(Sq, G, key_block) \
+        if Sq > 1 and key_block is not None else None
 
     def dead_rows_zero(o):
         """The kernel never writes a dead row's result; the dense path
@@ -1244,12 +1298,14 @@ def cached_attention(q, cache_k, cache_v, pos,
             return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2],
                                                    x.shape[1], -1)
 
+        # the G query heads of a key-value head beside one another: a
+        # step of the kernel takes the group against one key block
         ks3, vs3 = map(to3, banks[2:]) if int8_cache else (None, None)
-        o3 = _chunk(to3(q), to3(banks[0]), to3(banks[1]), pos, scale,
-                    block_q, key_block, H, ks3=ks3, vs3=vs3,
-                    window=window, slopes=slopes, group=G,
+        o4 = _chunk(to3(q).reshape(B * Hkv, G, Sq, D), to3(banks[0]),
+                    to3(banks[1]), pos, scale, block_q, key_block, Hkv,
+                    ks3=ks3, vs3=vs3, window=window, slopes=slopes,
                     valid_from=valid_from)
-        return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+        return o4.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
     if int8_cache:
         banks = [dequantize_kv(banks[0], banks[2], q.dtype),

@@ -1,0 +1,152 @@
+#!/usr/bin/env python3
+"""The chunk-attention kernel alone, on the chip, at the serving cells'
+admission shapes: device time a call (the kernel's custom calls in a profiler
+trace) for several tiles in one process.  What ``chunk_block_k``'s docstring
+and PERF.md 6 (PR 49) quote was measured with it.
+
+    chiprun -- python scripts/chunk_attention_bench.py [--cells code,agent]
+        [--tiles rule,2048x256,1024x1024] [--parent DIR]
+
+``--tiles``: ``rule`` is the tree's own ``chunk_block_q`` / ``chunk_block_k``;
+``ROWSxKEYS`` overrides them for the run (the most query rows a step and the
+keys a block; a slot the keys do not tile takes the next size down).
+``--parent DIR``: also time ``DIR``'s ``decode_attention.py`` (another
+checkout's kernel) on the same inputs and compare the results.
+
+Chip only: a time is a chip's (``utils.platform.require_tpu``)."""
+
+import argparse
+import glob
+import importlib.util
+import json
+import os
+import sys
+import tempfile
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.ops.pallas import decode_attention as da
+
+REPS = 8
+
+
+def _calls(chunk, smax, positions, ring=None):
+    """A prompt's chunk calls as ``(weight, kwargs)``: over whole rows at
+    each position and, with ``ring = (R, window layers a full layer)``, over
+    the unrolled ring beside the chunk."""
+    out = []
+    for p in positions:
+        out.append((1, dict(Smax=smax, pos=p)))
+        if ring:
+            R, share = ring
+            out.append((share, dict(Smax=R + chunk, pos=R, window=R,
+                                    valid_from=max(R - p, 0))))
+    return out
+
+
+#: cell -> (B, Sq, H, Hkv, D), its calls
+CELLS = {
+    # mellum2-serve-code-sat: 7 full layers of 8,192, 21 rings of 1,024
+    "code": ((1, 1024, 32, 4, 128),
+             _calls(1024, 8192, (0, 1024, 2048, 3072), ring=(1024, 3))),
+    # nemotron3n-serve-agent-sat: 16 query heads a key-value head
+    "agent": ((1, 1024, 32, 2, 128),
+              _calls(1024, 16384, (0, 1024, 2048, 3072, 4096, 5120))),
+    # granite4h-serve-rag-sat
+    "rag": ((1, 512, 32, 8, 128), _calls(512, 5120, (0, 512, 1024))),
+    # gpt2-medium, both serving cells: ungrouped heads of 64
+    "gpt2m": ((1, 128, 16, 16, 64), _calls(128, 1024, (0, 128))),
+    # a verify's few positions under grouped heads: 8-row tiles of bf16
+    "verify": ((1, 8, 32, 4, 128), _calls(8, 2048, (1024, 1531))),
+}
+
+
+def _other_tree(path):
+    """``decode_attention.py`` of another checkout as a module of its own
+    (its ``.utils`` is this tree's)."""
+    spec = importlib.util.spec_from_file_location(
+        "deepspeed_tpu.ops.pallas._other_decode_attention",
+        os.path.join(path, "deepspeed_tpu/ops/pallas/decode_attention.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _program(mod, geo, call):
+    B, Sq, H, Hkv, D = geo
+    keys = jax.random.split(jax.random.PRNGKey(call["Smax"] + call["pos"]), 3)
+    q = jax.random.normal(keys[0], (B, Sq, H, D), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (B, call["Smax"], Hkv, D), jnp.bfloat16)
+            for key in keys[1:])
+    first = jnp.full((B,), call.get("valid_from", 0), jnp.int32)
+    fn = jax.jit(lambda q, k, v, pos, first: mod.cached_attention(
+        q, k, v, pos, window=call.get("window"), kv_heads=Hkv,
+        valid_from=first if "valid_from" in call else None))
+    return fn, (q, k, v, jnp.full((B,), call["pos"], jnp.int32), first)
+
+
+def _kernel_ms(programs):
+    """Median device milliseconds of each program's one Pallas call."""
+    from benchmarks.chip.trace.reduce import read_device_ops
+    with tempfile.TemporaryDirectory() as logdir:
+        jax.profiler.start_trace(logdir)
+        for fn, args in programs:
+            for _ in range(REPS):
+                out = fn(*args)
+            out.block_until_ready()
+        jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(
+            logdir, "plugins/profile/*/*.xplane.pb"))[-1]
+        ops = sorted((o for o in read_device_ops(path) if o.is_kernel),
+                     key=lambda o: o.start)
+    assert len(ops) == REPS * len(programs), len(ops)
+    return [1e3 * float(np.median([o.dur for o in ops[i:i + REPS]]))
+            for i in range(0, len(ops), REPS)], ops[0].shape
+
+
+def main():
+    from deepspeed_tpu.utils.platform import require_tpu
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--cells", default=",".join(CELLS))
+    ap.add_argument("--tiles", default="rule")
+    ap.add_argument("--parent")
+    args = ap.parse_args()
+    require_tpu()
+    rule_k, rule_q = da.chunk_block_k, da.chunk_block_q
+    variants = [("parent", _other_tree(args.parent))] if args.parent else []
+    variants += [(tile, da) for tile in args.tiles.split(",")]
+    for cell in args.cells.split(","):
+        geo, calls = CELLS[cell]
+        want = {}
+        for name, mod in variants:
+            da.chunk_block_k, da.chunk_block_q = rule_k, rule_q
+            if "x" in name:
+                rows, keys = (int(n) for n in name.split("x"))
+                da.chunk_block_k = lambda Smax: next(
+                    b for b in (2048, 1024, 512, 256, 128)
+                    if b <= keys and Smax % b == 0)
+                da.chunk_block_q = lambda Sq, G, block_k: next(
+                    b for b in (256, 128, 64, 32, 16, 8)
+                    if Sq % b == 0 and (G * b <= rows or b == 8))
+            programs = [_program(mod, geo, call) for _, call in calls]
+            for i, (fn, a) in enumerate(programs):    # compile, and compare
+                got = np.asarray(fn(*a), np.float32)
+                err = float(np.abs(got - want.setdefault(i, got)).max())
+                assert err < 0.05, (cell, name, calls[i], err)
+            ms, shape = _kernel_ms(programs)
+            weights = [w for w, _ in calls]
+            print(json.dumps({
+                "cell": cell, "tile": name, "result": shape,
+                "ms_a_call": round(float(np.average(ms, weights=weights)), 4),
+                "calls": [[c["Smax"], c["pos"], c.get("valid_from"),
+                           round(t, 4)] for (_, c), t in zip(calls, ms)]}),
+                flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -1,0 +1,205 @@
+"""The chunk-attention kernel's folded step (PR 49): a grid step takes a
+key-value head's whole GROUP of query heads against one key block.
+Interpret mode, float32, against ``cached_attention_reference``; the live
+range a step's block is clamped to against a brute-force walk of the mask."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops.pallas import decode_attention as da
+from deepspeed_tpu.ops.pallas.decode_attention import (
+    cached_attention, cached_attention_reference, chunk_block_k,
+    chunk_block_q, ring_attention)
+
+WINDOW = 1024      # the served window: a ring of 1,024 cells
+SQ = 256           # a chunk: one query tile up to 8 heads a group, two at 16
+
+
+@pytest.fixture
+def pallas_interpret(monkeypatch):
+    """Route kernels through Pallas interpret mode so the kernel bodies run."""
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+
+
+def _rows(key, *shape):
+    return jax.random.normal(key, shape, jnp.float32)
+
+
+def _grouped(x, G):
+    """[B, S, Hkv, D] -> [B, S, Hkv*G, D]: the reference reads a key-value
+    head per query head."""
+    return jnp.repeat(x, G, axis=2)
+
+
+def _pos(ragged, base):
+    """A batch of two: both rows at ``base``, or the second row ragged, 300
+    positions behind (another block, another place inside it)."""
+    return jnp.asarray([base, base - 300], jnp.int32) if ragged \
+        else jnp.asarray(base, jnp.int32)
+
+
+def _plain(G, D, pos, valid_from, seed=0):
+    """A chunk over whole rows of 2,048: ``(got, want)``."""
+    B, Smax, Hkv = 2, 2048, 2
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = _rows(kq, B, SQ, Hkv * G, D)
+    k, v = _rows(kk, B, Smax, Hkv, D), _rows(kv, B, Smax, Hkv, D)
+    got = cached_attention(q, k, v, pos, kv_heads=Hkv, valid_from=valid_from)
+    want = cached_attention_reference(q, _grouped(k, G), _grouped(v, G), pos,
+                                      valid_from=valid_from)
+    return got, want
+
+
+def _ring(G, D, pos, seed=0, window=WINDOW, sq=SQ):
+    """A chunk beside a ring (``ring_attention``): position ``p`` of the
+    history in cell ``p mod R``, the cells no token has reached (and, once
+    lapped, nothing else) holding junk: ``(got, want)``, the reference a
+    banded pass over the history laid out whole."""
+    B, Hkv, R = 2, 2, window
+    p = np.broadcast_to(np.asarray(pos), (B,))
+    S = int(p.max()) + sq
+    kq, kk, kv, kj = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = _rows(kq, B, sq, Hkv * G, D)
+    hist_k, hist_v = _rows(kk, B, S, Hkv, D), _rows(kv, B, S, Hkv, D)
+    ring_k = np.asarray(_rows(kj, B, R, Hkv * D)) * 50.0      # junk, loud
+    ring_v = ring_k.copy()
+    for b in range(B):
+        at = np.arange(max(p[b] - R, 0), p[b])
+        ring_k[b, at % R] = np.asarray(hist_k)[b, at].reshape(len(at), -1)
+        ring_v[b, at % R] = np.asarray(hist_v)[b, at].reshape(len(at), -1)
+    fresh_k, fresh_v = (jnp.stack([h[b, p[b]:p[b] + sq] for b in range(B)])
+                        for h in (hist_k, hist_v))
+    got = ring_attention(
+        q, jnp.asarray(ring_k)[None], jnp.asarray(ring_v)[None],
+        fresh_k, fresh_v, jnp.asarray(pos), window, 0, kv_heads=Hkv)
+    want = cached_attention_reference(
+        q, _grouped(hist_k, G), _grouped(hist_v, G), jnp.asarray(pos),
+        window=window)
+    return got, want
+
+
+def _close(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all()
+    # the single-token sweep's tests' tolerance in float32
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("D", [128, 64])
+@pytest.mark.parametrize("past", [0, 1], ids=["on-edge", "one-past"])
+@pytest.mark.parametrize("ragged", [False, True], ids=["scalar", "ragged"])
+@pytest.mark.parametrize("band", ["none", "unlapped", "lapped", "valid_from"])
+@pytest.mark.parametrize("G", [1, 4, 8, 16])
+def test_the_folded_step_matches_the_reference(pallas_interpret, G, band,
+                                               ragged, past, D):
+    """One body for every group size: ``G`` query heads a key-value head
+    under one another, whole rows or a ring's band, a scalar or a per-row
+    frontier standing on a key block's edge or one past it."""
+    if band == "none":
+        got, want = _plain(G, D, _pos(ragged, 1024 + past), None, seed=G)
+    elif band == "valid_from":
+        # the first real key inside a block, and, ragged, on a block's edge
+        first = jnp.asarray([300, 256], jnp.int32) if ragged \
+            else jnp.asarray(300, jnp.int32)
+        got, want = _plain(G, D, _pos(ragged, 1024 + past), first, seed=G)
+        # the reference hides the same keys, so the comparison is whole
+    else:
+        base = (512 if band == "unlapped" else 2048) + past
+        got, want = _ring(G, D, np.asarray(_pos(ragged, base)), seed=G)
+    _close(got, want)
+
+
+@pytest.mark.parametrize("G,dtype", [(8, jnp.float32), (8, jnp.bfloat16),
+                                     (16, jnp.float32)])
+def test_a_window_layers_call_at_the_cells_shape(pallas_interpret, G, dtype):
+    """``code-sat``'s window layers: a chunk of 1,024 beside a ring of 1,024
+    once lapped, 2,048 keys in the call; in bf16 the products are the
+    tick's (bf16 x bf16 in float32, probabilities rounded to bf16)."""
+    if dtype == jnp.float32:
+        return _close(*_ring(G, 128, np.asarray([3072, 3072 + 511]), seed=3,
+                             sq=1024))
+    B, Hkv, R, sq = 2, 2, WINDOW, 1024
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = _rows(keys[0], B, sq, Hkv * G, 128).astype(dtype)
+    k = _rows(keys[1], B, R + sq, Hkv, 128).astype(dtype)
+    v = _rows(keys[2], B, R + sq, Hkv, 128).astype(dtype)
+    pos = jnp.full((B,), R, jnp.int32)
+    got = cached_attention(q, k, v, pos, window=WINDOW, kv_heads=Hkv,
+                           valid_from=jnp.zeros((B,), jnp.int32))
+    want = cached_attention_reference(
+        *(x.astype(jnp.float32) for x in (q, _grouped(k, G), _grouped(v, G))),
+        pos, window=WINDOW)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("G", [1, 4])
+def test_an_edge_block_that_masks_some_rows_wholly(pallas_interpret, G):
+    """A band of 32 keys under a chunk that straddles two key blocks: the
+    first block is live (the early rows see it) and FULLY masked for the
+    late rows, whose running max must stay at its floor and not -inf."""
+    B, Smax, Hkv, D, sq = 2, 512, 2, 128, 128
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = _rows(kq, B, sq, Hkv * G, D)
+    k, v = _rows(kk, B, Smax, Hkv, D), _rows(kv, B, Smax, Hkv, D)
+    pos = jnp.asarray([200, 230], jnp.int32)
+    # one query tile, and both of its key blocks live
+    assert (chunk_block_k(Smax), chunk_block_q(sq, G, 256)) == (256, sq)
+    assert tuple(int(b) for b in da._chunk_live_range(
+        200, sq, 256, 32, None)) == (0, 1)
+    got = cached_attention(q, k, v, pos, window=jnp.int32(32), kv_heads=Hkv)
+    want = cached_attention_reference(q, _grouped(k, G), _grouped(v, G), pos,
+                                      window=32)
+    _close(got, want)
+
+
+# -------------------------------------------------------- a tile's live range
+
+def _brute(pos, Sq, Smax, block_q, block_k, window, first):
+    """The key blocks in which any pair of each query tile is visible, by
+    walking the mask itself."""
+    q = pos + np.arange(Sq)[:, None]
+    k = np.arange(Smax)[None, :]
+    seen = (q - k >= 0)
+    if window is not None:
+        seen &= (q - k < window)
+    if first is not None:
+        seen &= (k >= first)
+    return [[ki for ki in range(Smax // block_k)
+             if seen[qi * block_q:(qi + 1) * block_q,
+                     ki * block_k:(ki + 1) * block_k].any()]
+            for qi in range(Sq // block_q)]
+
+
+_CALLS = [
+    # pos, Sq, Smax, block_q, block_k, window, first
+    (0, 1024, 8192, 256, 256, None, None),
+    (3072, 1024, 8192, 128, 1024, None, None),
+    (3073, 1024, 8192, 128, 512, None, None),
+    (1024, 1024, 2048, 128, 1024, 1024, 0),         # a lapped ring
+    (1024, 1024, 2048, 128, 1024, 1024, 1024),      # a ring no token reached
+    (1024, 1024, 2048, 256, 256, 1024, 1024 - 300),  # ... and one part full
+    (1024, 256, 1280, 128, 256, 1024, 511),
+    (5120, 1024, 16384, 64, 1024, None, None),      # agent-sat: G 16
+    (1536, 512, 5120, 256, 1024, None, None),       # rag-sat: G 4
+    (200, 128, 512, 128, 256, 32, None),            # a band under a block
+    (37, 8, 256, 8, 256, 16, None),
+    (700, 128, 1024, 128, 128, 300, 650),
+]
+
+
+@pytest.mark.parametrize("call", _CALLS, ids=[
+    "-".join(str(x) for x in c) for c in _CALLS])
+def test_a_tiles_live_range_is_the_masks(call):
+    """``_chunk_live_range``, which says both which steps of a tile compute
+    and which block a dead step's index clamps to: the blocks in which the
+    mask, walked pair by pair, shows any pair of the tile."""
+    pos, Sq, Smax, block_q, block_k, window, first = call
+    for qi, live in enumerate(_brute(*call)):
+        lo, hi = da._chunk_live_range(pos + qi * block_q, block_q, block_k,
+                                      window, first)
+        assert list(range(int(lo), min(int(hi), Smax // block_k - 1) + 1)) \
+            == live

@@ -177,11 +177,13 @@ _SWEEP_BLOCK = {
 @pytest.mark.parametrize("width", sorted(_SWEEP_BLOCK))
 def test_the_sweeps_block_follows_the_rows_width(width, smax):
     """``decode_block_k`` is one function of the slot's length and the row's
-    width; the chunk kernel's key block is the size the sweep had before it
-    grew for narrow rows."""
+    width; the chunk kernel's key block follows the slot's length alone (a
+    step takes one key-value head, 128 wide whatever the row): the largest
+    up to 1,024 keys that leaves the row two blocks."""
     want = _SWEEP_BLOCK[width][_SLOTS.index(smax)]
     assert decode_block_k(smax, width) == want
-    assert chunk_block_k(smax, width) == (want and min(want, 256))
+    assert chunk_block_k(smax) == \
+        (None, 256, 512, 1024)[_SLOTS.index(smax)]
     if want:
         assert smax % want == 0 and (want * width <= 1 << 18 or want == 128)
 

@@ -668,6 +668,56 @@ def test_the_single_part_cells_kernels_at_its_shapes(v5e, kernel):
                 arg((32,), jnp.int32))
 
 
+# cell: (heads, key-value heads, D, chunk, keys of the call, window) ->
+# (positions a query tile, keys a block)
+_CHUNK_PASSES = {
+    "code-sat-full": ((32, 4, 128, 1024, 8192, None), (128, 1024)),
+    "code-sat-window": ((32, 4, 128, 1024, 2048, 1024), (128, 1024)),
+    "agent-sat": ((32, 2, 128, 1024, 16384, None), (64, 1024)),
+    "rag-sat": ((32, 8, 128, 512, 5120, None), (256, 1024)),
+    "gpt2-medium": ((16, 16, 64, 128, 1024, None), (128, 512)),
+    # a verify's few positions under grouped heads: 8-row tiles of a packed
+    # dtype, a group's under one another
+    "verify-8": ((32, 4, 128, 8, 2048, None), (8, 1024)),
+    "verify-24": ((32, 4, 128, 24, 2048, None), (8, 1024)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CHUNK_PASSES))
+def test_the_chunk_pass_at_the_cells_tiles(v5e, cell):
+    """The chunk kernel alone at every serving cell's admission shape: a
+    grid step is a key-value head's whole group against one key block,
+    about a million scores (1,024 rows x 1,024 keys for 4, 8 and 16 query
+    heads a key-value head: 4 MB of float32 scores beside their
+    probabilities in the 16 MiB a v5e's kernel may use), and the grid and
+    the blocks are the ones the rule gives for the operands."""
+    from tests.unit.ops.traced_sweeps import _deep
+    (H, Hkv, D, chunk, smax, window), (block_q, block_k) = _CHUNK_PASSES[cell]
+    G = H // Hkv
+    assert decode.chunk_block_k(smax) == block_k
+    assert decode.chunk_block_q(chunk, G, block_k) == block_q
+    assert G * block_q * block_k <= 1 << 20
+
+    def arg(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def pass_(q, k, v, pos, first):
+        return decode.cached_attention(
+            q, k, v, pos, kv_heads=Hkv, window=window,
+            valid_from=first if window else None)
+
+    shapes = (arg((1, chunk, H, D)), arg((1, smax, Hkv, D)),
+              arg((1, smax, Hkv, D)), arg((1,), jnp.int32),
+              arg((1,), jnp.int32))
+    calls = [e for e in _deep(jax.make_jaxpr(pass_)(*shapes).jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["chunk_attention"]
+    assert tuple(calls[0].params["grid_mapping"].grid) == (
+        Hkv, chunk // block_q, smax // block_k)
+    assert calls[0].outvars[0].aval.shape == (Hkv, G, chunk, D)
+    _compiles_with_kernel(pass_, *shapes)
+
+
 @pytest.mark.parametrize("stochastic", [False, True],
                          ids=["nearest", "stochastic"])
 def test_symmetric_quantizer(v5e, stochastic):
