@@ -644,6 +644,42 @@ def _ring_write(bank, layer, val, positions, valid):
         (layer, 0, 0, 0))
 
 
+def _chunk_scatter(bank, layer, val, pos0):
+    """``val`` [B, S_c, w], row ``b``'s at cells ``pos0[b] ..`` of ITS row
+    of layer ``layer`` of a bank ``[L, B, S, w]``: a scatter of ``B * S_c``
+    rows, which drops those past the row's end."""
+    B, Sc, _ = val.shape
+    return bank.at[layer, jnp.arange(B)[:, None],
+                   pos0[:, None] + jnp.arange(Sc)].set(val)
+
+
+def _chunk_slice(bank, layer, val, pos0):
+    """:func:`_chunk_scatter` of ONE row (``B == 1``, ``S_c <= S``), whose
+    cells are consecutive: one update slice, the same cells written bit for
+    bit.  An update slice CLAMPS its start where a scatter drops, so a
+    chunk that would pass the row's end starts ``over`` cells early (0
+    everywhere else), its rows moved up by as many over the cells as they
+    were: what lies before the frontier is written back as read, the rows
+    past the end fall off.
+
+    The scatter also held the bank row-major, as the pool is, in a program
+    that makes its own row cache (an admission's).  Left free, the TPU's
+    compiler lays that cache out tokens-on-lanes for the chunk kernel's
+    read and re-lays both banks whole for the slot write (2 x 0.35 ms an
+    admission of GPT-2 350M, by its own estimate): the layout is said
+    here."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+    S, Sc = bank.shape[2], val.shape[1]
+    at = jnp.minimum(pos0[0], S - Sc)
+    over = jnp.minimum(pos0[0] - at, Sc)
+    old = lax.dynamic_slice(bank, (layer, 0, at, 0), (1,) + val.shape)
+    rows = jnp.where((jnp.arange(Sc) >= over)[:, None],
+                     jnp.roll(val, over, axis=1), old[0])
+    return with_layout_constraint(
+        lax.dynamic_update_slice(bank, rows[None], (layer, 0, at, 0)),
+        Layout(major_to_minor=tuple(range(bank.ndim))))
+
+
 def _real_tokens(valid, B: int, S: int):
     """``valid`` as the ``[B]`` int32 a family's ``step`` takes (default:
     all ``S`` tokens of every row are real)."""
@@ -721,11 +757,12 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
             "would clamp and corrupt the cached prefix")
     if ragged:
         positions = pos0[:, None] + jnp.arange(Sc)          # [B, S_c]
-        rows = jnp.arange(B)[:, None]
-        cols = positions
+        # one row's chunk is consecutive cells of that row: a slice
+        place = _chunk_slice if B == 1 and Sc <= cache.max_len \
+            else _chunk_scatter
 
         def write(bank, layer, val):
-            return bank.at[layer, rows, cols].set(val)
+            return place(bank, layer, val, pos0)
     else:
         positions = pos0 + jnp.arange(Sc)   # [S_c], shared across rows
 

@@ -306,3 +306,101 @@ def test_ragged_extend_matches_per_row(variant):
         np.testing.assert_allclose(
             np.asarray(cache.k[:, b, L:L + Sc]),
             np.asarray(c1.k[:, 0, L:L + Sc]), rtol=2e-5, atol=2e-5)
+
+
+def _family(name):
+    """A served family at the rehearsal's tiny sizes, in bf16."""
+    from tests.unit.models.test_family_declaration import _served
+    cfg, init, fam = _served(name)
+    cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
+    params = jax.tree_util.tree_map(
+        lambda w: w.astype(jnp.bfloat16) if w.dtype == jnp.float32 else w,
+        init(jax.random.PRNGKey(0)))
+    return cfg, params, fam
+
+
+def _same(a, b):
+    """Every leaf of two pytrees, bit for bit."""
+    la, lb = map(jax.tree_util.tree_leaves, (a, b))
+    assert len(la) == len(lb)
+    return all(x.dtype == y.dtype and np.array_equal(
+        np.asarray(x.astype(jnp.float32)), np.asarray(y.astype(jnp.float32)))
+        for x, y in zip(la, lb))
+
+
+@pytest.mark.parametrize("start", [0, 5], ids=["fresh", "prefix-off-grid"])
+@pytest.mark.parametrize("name,kv", [
+    ("dense", None), ("dense", "int8"), ("moe", "int8"), ("latent", None),
+    ("hybrid", None)],
+    ids=["bf16-dense", "int8-dense", "int8-moe", "bf16-latent",
+         "bf16-hybrid"])
+def test_one_rows_ragged_chunk_is_the_scatter_bit_for_bit(monkeypatch, name,
+                                                           kv, start):
+    """A batch-1 ragged ``extend`` (an admission's further chunks, a
+    prefix's continuation) writes each bank by one update slice; banks,
+    scale banks, state and logits are the scatter's (the parent's form,
+    ``_chunk_scatter``) bit for bit, chunk after chunk: inside the row,
+    over its end (a slot of 40 cells in chunks of 16: the last chunk's rows
+    past the end fall off and the cached prefix stays as it was, where a
+    clamped slice would have landed on it), and wholly past it."""
+    cfg, params, fam = _family(name)
+    S, C = 40, 16
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (4, 1, C), 0,
+                                cfg.vocab_size)
+
+    def chunks(place):
+        with monkeypatch.context() as m:
+            m.setattr(gpt_inference, "_chunk_slice", place)
+            ext = jax.jit(lambda p, t, c, l, n: fam.extend(
+                p, t, cfg, c, lengths=l, valid=n))
+            _, cache = fam.prefill(params, tokens[0][:, :max(start, 1)], cfg,
+                                   fam.init_cache(cfg, 1, S, kv_dtype=kv))
+            out = []
+            # ... + 16, + 32 (its end passes 40), and one from the row's end
+            for i, pos in enumerate([start, start + C, start + 2 * C, S]):
+                real = int(np.clip(S - pos, 1, C))
+                lg, cache = ext(params, tokens[i], cache,
+                                jnp.asarray([pos], jnp.int32),
+                                jnp.asarray([real], jnp.int32))
+                out.append((lg[:, :real], cache))
+        return out
+
+    sliced = chunks(gpt_inference._chunk_slice)
+    scattered = chunks(gpt_inference._chunk_scatter)
+    assert all(_same(a, b) for a, b in zip(sliced, scattered))
+    # the rows before the last chunk's frontier are the chunk before's
+    pos = start + 2 * C
+    assert pos + C > S
+    before, after = sliced[1][1], sliced[2][1]
+    for name in ("k", "v", "k_scale", "v_scale"):
+        was, now = getattr(before, name), getattr(after, name)
+        assert (now is not None) == (
+            name == "k" or kv is not None
+            or name == "v" and after.v is not None)
+        if now is not None:
+            assert _same(was[:, :, :pos], now[:, :, :pos])
+            assert not _same(was[:, :, pos:], now[:, :, pos:])
+
+
+def test_rows_at_unequal_frontiers_still_scatter(monkeypatch):
+    """The batched verify's form is the parent's: ``B > 1`` never takes the
+    slice (nor does a chunk longer than the row), whatever the lengths; one
+    row does, a one-slot server's verify window of ``K + 1`` tokens
+    included."""
+    params = gpt.init(CFG, jax.random.PRNGKey(0))
+    taken = []
+    slice_ = gpt_inference._chunk_slice
+    monkeypatch.setattr(gpt_inference, "_chunk_slice",
+                        lambda *a: taken.append(a[2].shape) or slice_(*a))
+
+    def text(B, Sc, S=32):
+        cache = gpt_inference.init_cache(CFG, B, S)
+        return str(jax.make_jaxpr(lambda c, t, l: gpt_inference.extend(
+            params, t, CFG, c, lengths=l))(
+                cache, jnp.zeros((B, Sc), jnp.int32),
+                jnp.arange(B, dtype=jnp.int32) + 3))
+
+    assert "scatter" in text(2, 4) and not taken
+    assert "scatter" in text(1, 48) and not taken
+    # two banks, in the layer scan's one body
+    assert "scatter" not in text(1, 5) and taken == [(1, 5, 64)] * 2

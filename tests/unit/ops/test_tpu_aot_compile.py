@@ -543,16 +543,11 @@ def _pool_sized_moves(hlo_text, layer_k):
     return moved
 
 
-@_SERVED
-def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
-    """The admission's device program (``serving.batcher.admission``: the
-    chunk loop, the slot write and the bind) at the serving cells' geometry
-    (:func:`_served`), for every model family.  A program that holds a
-    ``fori_loop`` beside the donated pool must still write the pool where it
-    lies: the pool's inputs are its outputs, nothing copies, slices or
-    updates as much as a layer of it (the slot write's update is one row of
-    every layer), and the plan is what a chunk's ``extend`` on the batch-1
-    row and the pool hold between them today."""
+def _compile_admission(v5e, family, int8):
+    """``serving.batcher.admission`` of a family, and a chunk's ``extend``
+    on its batch-1 row alone, compiled for the described chip at the
+    serving cells' geometry (:func:`_served`): ``(admit, extend, pool, row
+    cache)``, the last two as shapes."""
     from deepspeed_tpu.models import cache_family
     from deepspeed_tpu.serving.batcher import admission
     model, cfg, slots, smax, chunk = _served(family)
@@ -586,6 +581,37 @@ def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
         admission(fam, cfg, smax, kv), donate_argnums=(1, 3)).lower(
             params, pool, *per_slot, arg((smax // chunk, chunk), jnp.int32),
             arg((7,), jnp.int32), arg((2,), jnp.uint32)).compile()
+    return compiled, extend, pool, row_cache
+
+
+@pytest.fixture(scope="module")
+def admission_of(v5e):
+    """:func:`_compile_admission` of ``(family, int8)``, once a family a
+    process: the two guards below read one compile where a worker runs
+    both."""
+    compiled = {}
+
+    def of(family, int8):
+        if (family, int8) not in compiled:
+            compiled[family, int8] = _compile_admission(v5e, family, int8)
+        return compiled[family, int8]
+
+    return of
+
+
+@_SERVED
+def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
+                                                       int8):
+    """The admission's device program (``serving.batcher.admission``: the
+    chunk loop, the slot write and the bind) at the serving cells' geometry
+    (:func:`_served`), for every model family.  A program that holds a
+    ``fori_loop`` beside the donated pool must still write the pool where it
+    lies: the pool's inputs are its outputs, nothing copies, slices or
+    updates as much as a layer of it (the slot write's update is one row of
+    every layer), and the plan is what a chunk's ``extend`` on the batch-1
+    row and the pool hold between them today."""
+    _, cfg, slots, smax, _ = _served(family)
+    compiled, extend, pool, _ = admission_of(family, int8)
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the admission"
     assert " while(" in text, "no loop over the chunks"
@@ -604,6 +630,65 @@ def test_admission_is_one_program_on_the_pool_in_place(v5e, family, int8):
     held_today = _planned_bytes(extend) + _pool_bytes(pool)
     assert _planned_bytes(compiled) <= 1.01 * held_today, (
         _planned_bytes(compiled), _planned_bytes(extend), _pool_bytes(pool))
+
+
+#: ``copy`` ops as large as a bank of the admission's batch-1 row, by
+#: family, counted in the parent's program (the ragged scatter in the
+#: slice's place, PR 49) at these geometries; every other family's has none.
+#: The int8 cache's scale banks ``[L, 1, S, H]`` f32 ride the chunk loop
+#: row-major and are re-laid once into it and once out of it (the pool
+#: keeps them tokens-on-lanes), and at two layers the compiler also moves
+#: the 2 MB code banks; the hybrid family's bank is its one attention
+#: layer, so the chunk kernel's transposed reads of a layer are as large;
+#: the single-part block's and the window family's two banks are gathered
+#: from the loop's carry for the slot write (:data:`_KNOWN_MOVES`).
+_ROW_BANK_COPIES = {("dense", True): 4, ("moe", True): 6,
+                    ("hybrid", False): 8, ("single_part", False): 2,
+                    ("window", False): 2}
+
+
+def _row_bank_ops(hlo_text, row_cache, opcode):
+    """Instructions of ``opcode`` (a fusion under its root's) whose result
+    is as large as a bank of ``row_cache`` (``k``, ``v`` and the int8
+    cache's scale banks: what ``extend``'s ``write`` fills)."""
+    banks = {b.size for b in (row_cache.k, row_cache.v, row_cache.k_scale,
+                              row_cache.v_scale) if b is not None}
+    return [n for n, op in _root_opcodes(hlo_text)
+            if op == opcode and n in banks]
+
+
+@_SERVED
+def test_an_admissions_chunks_write_its_row_by_update_slices(admission_of,
+                                                             family, int8):
+    """A further chunk of an admission lands in the batch-1 row cache by
+    one update slice a bank a layer (``gpt_inference._chunk_slice``): the
+    compiled program holds no ``scatter`` over a bank of that row (the
+    ragged form, 17 us a bank a layer where the slice is one DMA), and the
+    row keeps the pool's layout through the chunk loop: no bank of it is
+    copied whole but where the parent's program copied one too.  The
+    scatter used to pin that layout; left free, the compiler lays the row
+    out tokens-on-lanes for the chunk kernel and re-lays every bank for
+    the slot write."""
+    compiled, _, _, row_cache = admission_of(family, int8)
+    text = compiled.as_text()
+    assert not _row_bank_ops(text, row_cache, "scatter")
+    assert len(_row_bank_ops(text, row_cache, "copy")) <= \
+        _ROW_BANK_COPIES.get((family, int8), 0)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_the_update_slices_plan_is_the_scatters(v5e, admission_of,
+                                                monkeypatch, int8):
+    """... and the plan does not grow with the row: against the same
+    admission with the parent's scatter in the slice's place it holds the
+    chunk's rows more, read back and moved (0.3-0.6 MB, at any depth)."""
+    from deepspeed_tpu.models import gpt_inference
+    sliced = _planned_bytes(admission_of("dense", int8)[0])
+    monkeypatch.setattr(gpt_inference, "_chunk_slice",
+                        gpt_inference._chunk_scatter)
+    scattered, _, _, row_cache = _compile_admission(v5e, "dense", int8)
+    assert _row_bank_ops(scattered.as_text(), row_cache, "scatter")
+    assert sliced <= _planned_bytes(scattered) + (1 << 20)
 
 
 @pytest.mark.parametrize("kernel", ["decode_step", "chunk_scan",

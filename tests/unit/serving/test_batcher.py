@@ -100,6 +100,40 @@ def test_write_slot_int8_scales():
     assert gpt_inference.read_slot(big2, jnp.asarray(0)).int8
 
 
+_CHUNK_WRITES = {
+    name: jax.jit(getattr(gpt_inference, name))
+    for name in ("_chunk_slice", "_chunk_scatter")}
+
+
+@pytest.mark.parametrize("pos", [0, 7, 16, 24, 25, 31, 39, 40, 47, 1000])
+@pytest.mark.parametrize("dtype,width,chunk", [
+    (jnp.bfloat16, 64, 16), (jnp.int8, 64, 16), (jnp.float32, 4, 16),
+    (jnp.bfloat16, 64, 5), (jnp.bfloat16, 64, 40)],
+    ids=["bf16", "int8", "scales", "verify-window", "whole-row"])
+def test_one_rows_chunk_by_update_slice_is_the_scatter(dtype, width, chunk,
+                                                       pos):
+    """``extend``'s batch-1 ragged write, one update slice a bank a layer
+    (``_chunk_slice``), leaves the bank as the scatter of its ``C`` rows
+    does (``_chunk_scatter``: ``B > 1``'s, and the parent's at every ``B``),
+    bit for bit, at every frontier: inside the row of 40 cells, ending on
+    its last cell, over its end (the rows past it fall off; a clamped slice
+    would have landed on the cells before the frontier), and past it."""
+    rng = np.random.default_rng(pos + chunk)
+    bank = jnp.asarray(rng.integers(-100, 100, (3, 1, 40, width)), dtype)
+    val = jnp.asarray(rng.integers(-100, 100, (1, chunk, width)), dtype)
+    at = jnp.asarray([pos], jnp.int32)
+    got, want = (np.asarray(f(bank, jnp.int32(1), val, at), np.float32)
+                 for f in (_CHUNK_WRITES["_chunk_slice"],
+                           _CHUNK_WRITES["_chunk_scatter"]))
+    np.testing.assert_array_equal(got, want)
+    landed = max(0, min(pos + chunk, 40) - pos)
+    changed = (got != np.asarray(bank, np.float32)).any(-1)
+    assert not changed[[0, 2]].any() and not changed[1, 0, :pos].any()
+    assert changed[1, 0].sum() <= landed
+    np.testing.assert_array_equal(got[1, 0, pos:pos + landed],
+                                  np.asarray(val, np.float32)[0, :landed])
+
+
 # -------------------------------------------------------------- batcher
 
 def test_batcher_admit_tick_release_matches_sequential():
