@@ -90,9 +90,10 @@ class KVCache:
     ``state`` (None for most families) is a tuple of per-slot arrays
     ``[layers, B, ...]`` with no token axis, a reset slot's all zero.
     ``ring`` (None for most families) is a SECOND pool, one bank for each
-    of the row's: ``[L_ring, B, R, w]``, the layers that see a window of the
-    conversation and keep no more of it: the token at position ``p`` lies
-    in cell ``p mod R`` whatever ``S_max`` is."""
+    of ITS row's (``cache_ring_row``: the banks' own widths unless the
+    family says otherwise): ``[L_ring, B, R, w]``, the layers that see a
+    window of the conversation and keep no more of it: the token at position
+    ``p`` lies in cell ``p mod R`` whatever ``S_max`` is."""
 
     k: jnp.ndarray        # [L, B, S_max, row[0]]
     v: Any                # [L, B, S_max, row[1]] or None
@@ -147,6 +148,13 @@ def cache_ring(config, max_len: int) -> Optional[Tuple[int, int]]:
     return None if not ring else (int(ring[0]), min(int(ring[1]), max_len))
 
 
+def cache_ring_row(config) -> Tuple[int, ...]:
+    """The widths of a ring's banks (``config.cache_ring_row``: window
+    layers whose cached token is not the full layers'), else the row's."""
+    row = getattr(config, "cache_ring_row", None)
+    return tuple(row) if row is not None else cache_row(config)
+
+
 def init_cache(config, batch: int, max_len: int, kv_dtype=None,
                stats: Optional[Dict[str, slice]] = None) -> KVCache:
     """``kv_dtype``: None → cache in the compute dtype; ``"int8"``/
@@ -187,7 +195,7 @@ def init_cache(config, batch: int, max_len: int, kv_dtype=None,
                    state=state,
                    ring=None if ring is None else tuple(
                        jnp.zeros((ring[0], batch, ring[1], w), config.dtype)
-                       for w in row))
+                       for w in cache_ring_row(config)))
 
 
 # ------------------------------------------------------------- slot ops
@@ -370,13 +378,17 @@ DENSE_DRAFTS_ONLY = ("a draft's whole point is being small, and the proposal "
 
 
 def _row_plan(config, max_len: int, itemsize: int = 2,
-              windows=((None, 1),)):
+              windows=((None, 1),), ring: bool = False):
     """The kernel file's plan (``decode_attention.sweep_plan``) for this
     config's row: which single-token sweep serves it, its block and where
     its last copy ends.  The row is the config's own declaration
-    (``cache_row``; two banks hold ``row / head_dim`` key-value heads)."""
+    (``cache_row``; two banks hold ``row / head_dim`` key-value heads):
+    ``ring``: its rings' (``cache_ring_row``); else, where a bank of the row
+    is not the sweep's to stream (an index's keys beside a latent row), the
+    banks the family names (``config.cache_sweep_row``)."""
     from ..ops.pallas.decode_attention import sweep_plan
-    row = cache_row(config)
+    row = cache_ring_row(config) if ring else \
+        tuple(getattr(config, "cache_sweep_row", None) or cache_row(config))
     return sweep_plan(
         row, max_len, config.n_head,
         kv_heads=row[0] // config.head_dim if len(row) == 2 else None,
@@ -424,7 +436,9 @@ class Family:
     serving as a speculative draft) -> why the family is refused it;
     ``stats_groups(config)``: where each group of its device counters lies
     in ``cache.stats`` (name -> slice; the one place that knows);
-    ``state_counters``: the names of its group ``state_steps``."""
+    ``state_counters``: the names of its group ``state_steps``;
+    ``select_counters``: the names of its group ``sparse_select`` (a family
+    whose queries attend to a selection of their cache)."""
     step: Any
     project: Any = _dense_project
     attend_fresh: Any = _dense_attend_fresh
@@ -438,6 +452,7 @@ class Family:
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
     stats_groups: Any = lambda config: {}
     state_counters: Tuple[str, ...] = ()
+    select_counters: Tuple[str, ...] = ()
 
     # the cache, the passes and the slot ops: the module's functions below
     # with this family in them, defined once for every family
@@ -486,7 +501,8 @@ class Family:
             return plan
         # the rings are a pool of their own length, planned by it
         return plan._replace(ring=_row_plan(
-            config, ring[1], itemsize, windows=((None, ring[0]),)))
+            config, ring[1], itemsize, windows=((None, ring[0]),),
+            ring=True))
 
 
 def _sweeps(family: Family, pos, B, config, max_len, active):
@@ -497,7 +513,9 @@ def _sweeps(family: Family, pos, B, config, max_len, active):
     block is the plan's, which is the host's and the kernel's.  A family
     with rings gets a second list, the rings' (a pool ``R`` long at
     frontier ``min(pos, R - 1)``: once a ring has lapped, all of it is
-    live).  Returns ``(layer, ring=False) -> sweep``."""
+    live), and one that names a second sweep's block
+    (``config.cache_second_sweep_block(max_len)``) a pair of lists for its
+    whole rows.  Returns ``(layer, ring=False) -> sweep``."""
     from ..ops.pallas.decode_attention import decode_sweep
     block_k = _row_plan(config, max_len).block_k
     of = family.windows(config, max_len)
@@ -509,7 +527,15 @@ def _sweeps(family: Family, pos, B, config, max_len, active):
         R = ring[1]
         sweeps = (decode_sweep(pos, B, max_len, block_k, active),
                   decode_sweep(jnp.minimum(pos, R - 1), B, R,
-                               _row_plan(config, R).block_k, active))
+                               _row_plan(config, R, ring=True).block_k,
+                               active))
+        # a second sweep of the same rows at another block (an index over
+        # its own bank of keys): its list rides beside the first
+        second = getattr(config, "cache_second_sweep_block", None)
+        if second is not None:
+            sweeps = ((sweeps[0], decode_sweep(pos, B, max_len,
+                                               second(max_len), active)),
+                      sweeps[1])
         return lambda idx, ring=False: sweeps[bool(ring)]
     windows = of(jnp.arange(cache_layers(config)))
     sweeps = jax.vmap(
@@ -546,6 +572,10 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
     attends over the ring as it was and its own rows (``fresh=``) and is
     written after: written first it would overwrite keys its first queries
     still see.
+
+    An attend hook may count: where ``attn`` returns ``(output, counts)``,
+    ``counts`` (a vector as long as ``cache.stats``, the family's layout) is
+    added to the cache's counters.
     """
     int8 = cache.int8
     if int8:
@@ -554,6 +584,12 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
     def fold(t):
         """[B, S, ...] → [B, S, *]: a token's row in one bank."""
         return t.reshape(t.shape[:2] + (-1,))
+
+    def counted(a, cache):
+        if not isinstance(a, tuple):
+            return a, cache
+        a, counts = a
+        return a, dataclasses.replace(cache, stats=cache.stats + counts)
 
     def ring_attend(q, fresh, idx, cache):
         def put(cache):
@@ -567,7 +603,7 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
         if single:
             cache = put(cache)
         with jax.named_scope("cache_read"):
-            a = attn(q, fresh, cache, idx, ring=True)
+            a, cache = counted(attn(q, fresh, cache, idx, ring=True), cache)
         return a, cache if single else put(cache)
 
     def attend(x, p, idx, cache, ring=False):
@@ -592,8 +628,7 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
                 cache = dataclasses.replace(
                     cache, k=banks[0], v=banks[1] if len(banks) > 1 else None)
         with jax.named_scope("cache_read"):
-            a = attn(q, fresh, cache, idx)
-        return a, cache
+            return counted(attn(q, fresh, cache, idx), cache)
 
     for stacks, body in family.step(params, config, valid):
         def layer(carry, xs, body=body):

@@ -957,10 +957,13 @@ def _gqa_decode(q, k, v, layer, pos, sweep, sm_scale, block_k, G):
 
 # ------------------------------------------------------------ latent rows
 
-def latent_attention_reference(q, bank, pos, sm_scale: float, rank: int):
+def latent_attention_reference(q, bank, pos, sm_scale: float, rank: int,
+                               bias=None):
     """Ground truth of the absorbed form: ``q`` [B, Sq, H, W] over latent
     rows ``bank`` [B, Smax, W]; query i (at ``pos + i``) sees rows at or
-    before it and weighs their first ``rank`` elements: [B, Sq, H, rank]."""
+    before it and weighs their first ``rank`` elements: [B, Sq, H, rank].
+    ``bias`` [B, Sq, Smax] float32 (0 or -inf) is added to every head's
+    scores: a selection, or a ring's band."""
     B, Sq = q.shape[:2]
     Smax = bank.shape[1]
     s = jnp.einsum("bqhw,bkw->bhqk", q, bank,
@@ -968,6 +971,8 @@ def latent_attention_reference(q, bank, pos, sm_scale: float, rank: int):
     pos = jnp.asarray(pos)
     q_abs = (pos.reshape(-1, 1) if pos.ndim else pos) + jnp.arange(Sq)
     mask = jnp.atleast_2d(q_abs)[:, :, None] >= jnp.arange(Smax)[None, None]
+    if bias is not None:
+        s = s + bias[:, None]
     s = jnp.where(mask[:, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkr->bqhr", p.astype(q.dtype), bank[..., :rank])
@@ -983,13 +988,20 @@ def latent_block_k(Smax: int) -> Optional[int]:
 
 
 def _latent_decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref,
-                          q_ref, k_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                          sm_scale, block_k, H, R):
+                          q_ref, k_ref, *rest, sm_scale, block_k, H, R,
+                          biased=False):
     """``_decode_kernel`` for a latent pool: a grid step is one live block
     of one live row, ``k_ref`` its ``(block_k, W)`` latent rows, ``q_ref``
     the row's ``(H, W)`` absorbed queries.  Two plain matmuls, no spreading:
     every head scores the same rows and weighs the same ``R`` columns.  The
-    result leaves as one ``(1, H*R)`` row, head after head."""
+    result leaves as one ``(1, H*R)`` row, head after head.  ``biased``: a
+    ``(1, block_k)`` float32 block of the row's bias (0 or -inf a token: a
+    selection, a ring's band) leads ``rest`` and is added to every head's
+    scores; a block it masks whole leaves the recurrence as it was."""
+    bias_ref = None
+    if biased:
+        bias_ref, rest = rest[0], rest[1:]
+    o_ref, acc_ref, m_ref, l_ref = rest
     step, row, live, first, last = _sweep_position(rows_ref, n_ref)
     ki = blocks_ref[step]
     pos = pos_ref[row]
@@ -1007,6 +1019,8 @@ def _latent_decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref,
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                                   # (H, BK)
+        if biased:
+            s = s + bias_ref[...]
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         _online_softmax_step(jnp.where(k_pos <= pos, s, NEG_INF), kb[:, :R],
                              acc_ref, m_ref, l_ref)
@@ -1018,13 +1032,16 @@ def _latent_decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref,
             o_ref[:, h * R:(h + 1) * R] = o[h:h + 1]
 
 
-def _latent_decode(q, bank, layer, pos, sweep, sm_scale, block_k, R):
+def _latent_decode(q, bank, layer, pos, sweep, sm_scale, block_k, R,
+                   bias=None):
     """The latent decode sweep: ``q`` [B, H, W] against layer ``layer`` of
-    the pool ``bank`` [L, B, Smax, W] where it lies; [B, 1, H*R]."""
+    the pool ``bank`` [L, B, Smax, W] where it lies; [B, 1, H*R].  ``bias``
+    [B, 1, Smax] float32: see the kernel."""
     B, H, W = q.shape
     rows, blocks, n = sweep
+    biased = bias is not None
     kernel = functools.partial(_latent_decode_kernel, sm_scale=sm_scale,
-                               block_k=block_k, H=H, R=R)
+                               block_k=block_k, H=H, R=R, biased=biased)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     prefetch = (rows, blocks, n, pos_arr,
                 jnp.asarray(layer, jnp.int32).reshape(1))
@@ -1039,7 +1056,10 @@ def _latent_decode(q, bank, layer, pos, sweep, sm_scale, block_k, R):
                          lambda s, rows_ref, blocks_ref, n_ref, pos_ref,
                          layer_ref: (layer_ref[0], rows_ref[s],
                                      blocks_ref[s], 0)),
-        ],
+        ] + ([pl.BlockSpec((None, 1, block_k),
+                           lambda s, rows_ref, blocks_ref, *_:
+                           (rows_ref[s], 0, blocks_ref[s]))]
+             if biased else []),
         out_specs=pl.BlockSpec((None, 1, H * R),
                                lambda s, rows_ref, *_: (rows_ref[s], 0, 0)),
         scratch_shapes=[
@@ -1052,15 +1072,21 @@ def _latent_decode(q, bank, layer, pos, sweep, sm_scale, block_k, R):
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H * R), q.dtype),
         interpret=interpret, name="latent_decode_attention")(
-            *prefetch, q, bank)
+            *prefetch, q, bank, *((bias,) if biased else ()))
 
 
-def _latent_chunk_kernel(pos_ref, layer_ref, q_ref, k_ref, o_ref, acc_ref,
-                         m_ref, l_ref, *, sm_scale, block_q, block_k, H, R):
+def _latent_chunk_kernel(pos_ref, layer_ref, q_ref, k_ref, *rest, sm_scale,
+                         block_q, block_k, H, R, biased=False):
     """A chunk of queries against latent rows: ``q_ref`` is ``(block_q * H,
     W)``, the heads of ``block_q`` consecutive positions row after row, so
     one matmul scores them all against the block's rows; row ``i`` sits at
-    position ``pos + qi * block_q + i // H``."""
+    position ``pos + qi * block_q + i // H``.  ``biased``: a ``(block_q,
+    block_k)`` float32 block of the positions' bias (0 or -inf a key) leads
+    ``rest``; each position's row is added to all its heads' scores."""
+    bias_ref = None
+    if biased:
+        bias_ref, rest = rest[0], rest[1:]
+    o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -1080,6 +1106,10 @@ def _latent_chunk_kernel(pos_ref, layer_ref, q_ref, k_ref, o_ref, acc_ref,
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
+        if biased:
+            s = s + jnp.broadcast_to(
+                bias_ref[...][:, None, :], (block_q, H, block_k)
+            ).reshape(block_q * H, block_k)
         q_pos = pos + qi * block_q + \
             jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // H
         k_pos = ki * block_k + \
@@ -1092,14 +1122,18 @@ def _latent_chunk_kernel(pos_ref, layer_ref, q_ref, k_ref, o_ref, acc_ref,
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _latent_chunk(q, bank, layer, pos, sm_scale, block_q, block_k, R):
+def _latent_chunk(q, bank, layer, pos, sm_scale, block_q, block_k, R,
+                  bias=None):
     """``q`` [B, Sq, H, W] against layer ``layer`` of ``bank`` [L, B, Smax,
     W]: [B, Sq, H, R].  Block indices past a query block's causal frontier
-    clamp onto it, so they move nothing."""
+    clamp onto it, so they move nothing.  ``bias`` [B, Sq, Smax] float32:
+    see the kernel."""
     B, Sq, H, W = q.shape
     Smax = bank.shape[2]
+    biased = bias is not None
     kernel = functools.partial(_latent_chunk_kernel, sm_scale=sm_scale,
-                               block_q=block_q, block_k=block_k, H=H, R=R)
+                               block_q=block_q, block_k=block_k, H=H, R=R,
+                               biased=biased)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
 
     def kv_idx(b, qi, ki, pos_ref, layer_ref):
@@ -1113,7 +1147,10 @@ def _latent_chunk(q, bank, layer, pos, sm_scale, block_q, block_k, R):
         in_specs=[
             pl.BlockSpec((None, rows, W), lambda b, qi, ki, *_: (b, qi, 0)),
             pl.BlockSpec((None, None, block_k, W), kv_idx),
-        ],
+        ] + ([pl.BlockSpec((None, block_q, block_k),
+                           lambda b, qi, ki, pos_ref, layer_ref:
+                           (b, qi, kv_idx(b, qi, ki, pos_ref, layer_ref)[2]))]
+             if biased else []),
         out_specs=pl.BlockSpec((None, rows, R),
                                lambda b, qi, ki, *_: (b, qi, 0)),
         scratch_shapes=[
@@ -1127,17 +1164,19 @@ def _latent_chunk(q, bank, layer, pos, sm_scale, block_q, block_k, R):
         out_shape=jax.ShapeDtypeStruct((B, Sq * H, R), q.dtype),
         interpret=interpret_mode(), name="latent_chunk_attention")(
             pos_arr, jnp.asarray(layer, jnp.int32).reshape(1),
-            q.reshape(B, Sq * H, W), bank)
+            q.reshape(B, Sq * H, W), bank, *((bias,) if biased else ()))
     return o.reshape(B, Sq, H, R)
 
 
 def latent_cached_attention(q, bank, pos, sm_scale: float, rank: int,
-                            layer=None, active=None, sweep=None):
+                            layer=None, active=None, sweep=None, bias=None):
     """Absorbed latent attention: ``q`` [B, Sq, H, W] over the latent pool
     ``bank`` [L, B, Smax, W] at ``layer`` (or one layer [B, Smax, W]),
     visibility ``<= pos + i``; returns each head's weighted sum of the rows'
     first ``rank`` elements, [B, Sq, H, rank].  ``active`` and ``sweep`` as
-    in ``cached_attention``."""
+    in ``cached_attention``.  ``bias`` [B, Sq, Smax] float32, 0 or -inf a
+    (query, key): what more than causality hides (a query's selection, a
+    ring's band); the sweep still steps and streams every live block."""
     B, Sq, H, W = q.shape
     if layer is None:
         bank, layer = bank[None], 0
@@ -1149,14 +1188,19 @@ def latent_cached_attention(q, bank, pos, sm_scale: float, rank: int,
         if sweep is None:
             sweep = decode_sweep(pos, B, Smax, block_k, active)
         o = _latent_decode(q[:, 0], bank, layer, pos, sweep, sm_scale,
-                           block_k, rank).reshape(B, 1, H, rank)
+                           block_k, rank, bias=bias).reshape(B, 1, H, rank)
     else:
-        block_q = next((b for b in (16, 8) if Sq % b == 0), None)
+        # a step's ``block_q * H`` query rows of ``W`` lanes: at most 16
+        # positions of 64 heads of a 640-lane row, whose buffers, scores and
+        # accumulator fill the kernel's 16 MB of VMEM; 8 of 128 heads, or
+        # of 64 heads of a 1,152-lane row
+        block_q = next((b for b in (16, 8) if Sq % b == 0
+                        and (b * H * W <= 1024 * 640 or b == 8)), None)
         if tiles and Sq > 1 and block_q is not None:
             return _latent_chunk(q, bank, layer, pos, sm_scale, block_q,
-                                 block_k, rank)
+                                 block_k, rank, bias=bias)
         one = jax.lax.dynamic_index_in_dim(bank, layer, 0, keepdims=False)
-        o = latent_attention_reference(q, one, pos, sm_scale, rank)
+        o = latent_attention_reference(q, one, pos, sm_scale, rank, bias)
         if Sq > 1:
             return o
     if active is None:
@@ -1354,3 +1398,235 @@ def ring_attention(q, ring_k, ring_v, fresh_k, fresh_v, pos, window: int,
         q, unrolled(ring_k, fresh_k), unrolled(ring_v, fresh_v),
         jnp.full((B,), R, jnp.int32), sm_scale=sm_scale, window=window,
         kv_heads=kv_heads, valid_from=jnp.maximum(R - p, 0))
+
+
+# ------------------------------------------------- a learned selection
+
+def index_block_k(Smax: int) -> Optional[int]:
+    """Tokens in one block of the index's kernels, or None where ``Smax``
+    does not tile: an index key is a single lane row, a sixth of a latent
+    row, so a step takes four times the latent sweep's tokens."""
+    return next((b for b in (2048, 1024, 512, 256, 128) if Smax % b == 0),
+                None)
+
+
+def _index_decode_kernel(rows_ref, blocks_ref, n_ref, layer_ref, q_ref, w_ref,
+                         k_ref, o_ref):
+    """One live block of one live row (``decode_sweep``'s list, at the
+    index's block): the row's ``(Hi, Di)`` index queries against the block's
+    ``(block_k, Di)`` index keys, ``relu`` of every head's float32 score,
+    the heads summed under the row's weights ``(Hi, 1)``: one ``(1,
+    block_k)`` block of scores.  Nothing is carried from step to step; a
+    block no step visits keeps what the array held."""
+    @pl.when(pl.program_id(0) < n_ref[0])
+    def _score():
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.sum(jnp.maximum(s, 0.0) * w_ref[...], axis=0,
+                             keepdims=True)
+
+
+def _index_decode(q, w, bank, layer, sweep, block_k):
+    """``q`` [B, Hi, Di], ``w`` [B, Hi] float32, against layer ``layer`` of
+    the index keys ``bank`` [L, B, Smax, Di] where they lie: [B, Smax]
+    float32, written where the sweep is live."""
+    B, Hi, Di = q.shape
+    Smax = bank.shape[2]
+    rows, blocks, n = sweep
+    prefetch = (rows, blocks, n, jnp.asarray(layer, jnp.int32).reshape(1))
+    interpret = interpret_mode()
+    by_row = lambda s, rows_ref, *_: (rows_ref[s], 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(rows.shape[0] if interpret else jnp.maximum(n[0], 1),),
+        in_specs=[
+            pl.BlockSpec((None, Hi, Di), by_row),
+            pl.BlockSpec((None, Hi, 1), by_row),
+            pl.BlockSpec((None, None, block_k, Di),
+                         lambda s, rows_ref, blocks_ref, n_ref, layer_ref:
+                         (layer_ref[0], rows_ref[s], blocks_ref[s], 0)),
+        ],
+        out_specs=pl.BlockSpec((None, 1, block_k),
+                               lambda s, rows_ref, blocks_ref, *_:
+                               (rows_ref[s], 0, blocks_ref[s])),
+    )
+    return pl.pallas_call(
+        _index_decode_kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, Smax), jnp.float32),
+        interpret=interpret, name="index_decode_scores")(
+            *prefetch, q, w.astype(jnp.float32)[..., None], bank)[:, 0]
+
+
+def _index_chunk_kernel(pos_ref, layer_ref, q_ref, w_ref, k_ref, o_ref, *,
+                        block_q, block_k, Hi):
+    """A tile of a chunk's index scores: ``q_ref`` is ``(Hi * block_q,
+    Di)``, HEAD-major (row ``h * block_q + i`` is head ``h`` of the tile's
+    position ``i``), so the heads' sum is ``Hi`` aligned slices added up;
+    ``w_ref`` the rows' weights ``(Hi * block_q, 1)``.  Blocks past the
+    tile's causal frontier are not computed."""
+    b, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki * block_k <= pos_ref[b] + (qi + 1) * block_q - 1)
+    def _score():
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.maximum(s, 0.0) * w_ref[...]
+        acc = s[:block_q]
+        for h in range(1, Hi):
+            acc = acc + s[h * block_q:(h + 1) * block_q]
+        o_ref[...] = acc
+
+
+def _index_chunk(q, w, bank, layer, pos, block_q, block_k):
+    """``q`` [B, Sq, Hi, Di], ``w`` [B, Sq, Hi] against layer ``layer`` of
+    ``bank`` [L, B, Smax, Di]: [B, Sq, Smax] float32, written up to each
+    tile's causal frontier."""
+    B, Sq, Hi, Di = q.shape
+    Smax = bank.shape[2]
+    nq = Sq // block_q
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+
+    def tiles(x):   # [B, Sq, Hi, *] -> [B, nq, Hi * block_q, *], head-major
+        return x.reshape(B, nq, block_q, Hi, -1).transpose(0, 1, 3, 2, 4) \
+            .reshape(B, nq, Hi * block_q, x.shape[-1])
+
+    def kv_idx(b, qi, ki, pos_ref, layer_ref):
+        hi = (pos_ref[b] + (qi + 1) * block_q - 1) // block_k
+        return (layer_ref[0], b, jnp.minimum(ki, hi), 0)
+
+    by_tile = lambda b, qi, ki, *_: (b, qi, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, nq, Smax // block_k),
+        in_specs=[
+            pl.BlockSpec((None, None, Hi * block_q, Di), by_tile),
+            pl.BlockSpec((None, None, Hi * block_q, 1), by_tile),
+            pl.BlockSpec((None, None, block_k, Di), kv_idx),
+        ],
+        out_specs=pl.BlockSpec((None, block_q, block_k),
+                               lambda b, qi, ki, *_: (b, qi, ki)),
+    )
+    kernel = functools.partial(_index_chunk_kernel, block_q=block_q,
+                               block_k=block_k, Hi=Hi)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sq, Smax), jnp.float32),
+        interpret=interpret_mode(), name="index_chunk_scores")(
+            pos_arr, jnp.asarray(layer, jnp.int32).reshape(1), tiles(q),
+            tiles(w.astype(jnp.float32)[..., None]), bank)
+
+
+def index_scores(q, w, keys, pos=None, layer=None, active=None, sweep=None):
+    """The learned index's scores: ``q`` [B, Sq, Hi, Di] index queries and
+    ``w`` [B, Sq, Hi] float32 head weights against the index keys ``keys``
+    [B, Smax, Di] (or, with ``layer``, the bank [L, B, Smax, Di] read where
+    it lies): ``sum_h w[.., h] relu(q[.., h, :] . k)`` in float32, [B, Sq,
+    Smax].  ``pos`` (scalar or [B]; default 0: a whole sequence over
+    itself) is the first query's position; the kernels score no further
+    than a query's causal frontier and a dead row (``active``) not at all,
+    and what they skip holds no value at all: ``topk_bias`` reads only the
+    keys at or before a query.  ``sweep``: a single token's work list
+    (``decode_sweep`` at ``index_block_k``), built here where not given."""
+    B, Sq, Hi, Di = q.shape
+    if layer is None:
+        keys, layer = keys[None], 0
+    Smax = keys.shape[2]
+    pos = jnp.zeros((), jnp.int32) if pos is None else pos
+    block_k = index_block_k(Smax)
+    if use_pallas() and block_k is not None and Hi % 8 == 0:
+        if Sq == 1:
+            if sweep is None:
+                sweep = decode_sweep(pos, B, Smax, block_k, active)
+            return _index_decode(q[:, 0], w[:, 0], keys, layer, sweep,
+                                 block_k)[:, None]
+        block_q = next((b for b in (16, 8) if Sq % b == 0), None)
+        if block_q is not None:
+            return _index_chunk(q, w, keys, layer, pos, block_q,
+                                min(block_k, 512))
+    one = jax.lax.dynamic_index_in_dim(keys, layer, 0, keepdims=False)
+    s = jnp.einsum("bqhd,bkd->bqhk", q, one,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jnp.maximum(s, 0.0) * w.astype(jnp.float32)[..., None],
+                   axis=2)
+
+
+def topk_bias(scores, q_pos, k: int):
+    """The EXACT selection of the ``min(t + 1, k)`` largest of a query's
+    scores among the keys at or before it, ties to the lower position, as
+    the bias of a masked pass: ``scores`` [B, Sq, S] float32, ``q_pos`` [B
+    or 1, Sq] the queries' positions ``t``; returns [B, Sq, S] float32, 0 on
+    a chosen key and -inf on every other.
+
+    No sort: float32 scores order as their bit patterns do (negatives
+    flipped), so the ``kk``-th largest is found bit by bit, 32 counts of
+    ``key >= candidate`` over the row, and a key is chosen if it lies above
+    that threshold or ties with it among the first (by position) that fill
+    the count.  A score of -0.0 counts as 0.0, as a comparison has it."""
+    S = scores.shape[-1]
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    eligible = jnp.arange(S, dtype=jnp.int32) <= q_pos[..., None]
+    s = jnp.where(scores == 0, 0.0, scores).astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(s, jnp.int32)
+    ordered = jnp.where(bits < 0, bits ^ jnp.int32(0x7fffffff), bits)
+    key = jax.lax.bitcast_convert_type(ordered, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+    key = jnp.where(eligible, key, jnp.uint32(0))
+    kk = jnp.minimum(q_pos + 1, k)                      # [B or 1, Sq]
+
+    def refine(i, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        count = jnp.sum(key >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(count >= kk, cand, t)
+
+    threshold = jax.lax.fori_loop(
+        0, 32, refine, jnp.zeros(key.shape[:-1], jnp.uint32))[..., None]
+    above = eligible & (key > threshold)
+    ties = eligible & (key == threshold)
+    room = kk[..., None] - jnp.sum(above, axis=-1, keepdims=True,
+                                   dtype=jnp.int32)
+    chosen = above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
+                              <= room))
+    return jnp.where(chosen, 0.0, NEG_INF).astype(jnp.float32)
+
+
+def ring_bias(pos, R: int, window: int):
+    """A single token's bias over its ring of ``R`` cells, [B, 1, R]
+    float32: the token at ``pos`` ([B]; already written to cell ``pos mod
+    R``) sees the cells whose token lies at most ``window - 1`` positions
+    back, 0 there and -inf on the cells past the window or not yet
+    reached."""
+    p = jnp.asarray(pos, jnp.int32).reshape(-1, 1)
+    back = (p - jnp.arange(R, dtype=jnp.int32)[None]) % R
+    seen = (back < window) & (back <= p)
+    return jnp.where(seen, 0.0, NEG_INF).astype(jnp.float32)[:, None]
+
+
+def latent_ring_attention(q, ring, fresh, pos, window: int, layer,
+                          sm_scale: float, rank: int):
+    """``ring_attention`` for a ring of latent rows: a chunk's absorbed
+    queries ``q`` [B, Sq, H, W] at ``pos .. pos + Sq - 1`` over the ring
+    ``ring`` [L, B, R, W] as it stood BEFORE the chunk (position ``p`` in
+    cell ``p mod R``) and the chunk's own rows ``fresh`` [B, Sq, W]; query
+    ``i`` sees the keys at ``0 <= pos + i - j < window``.  The ring is
+    unrolled into the order of its positions, the chunk's rows follow, and
+    the latent chunk kernel takes the ``R + Sq`` rows as a pool whose first
+    query sits at ``R``, the band and the cells no token has reached hidden
+    by its bias.  [B, Sq, H, rank]."""
+    B, Sq = q.shape[:2]
+    R = ring.shape[2]
+    p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    one = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
+    old = jax.vmap(lambda t, s: jax.lax.dynamic_slice_in_dim(
+        t, s, R, 0))(jnp.concatenate([one, one], axis=1), p % R)
+    rows = jnp.concatenate([old, fresh.astype(ring.dtype)], axis=1)
+    # key j of the unrolled pool lies at position pos - R + j
+    dist = (R + jnp.arange(Sq, dtype=jnp.int32))[:, None] \
+        - jnp.arange(R + Sq, dtype=jnp.int32)[None, :]
+    seen = ((dist >= 0) & (dist < window))[None] \
+        & (jnp.arange(R + Sq, dtype=jnp.int32)[None, None, :]
+           >= (R - p)[:, None, None])
+    return latent_cached_attention(
+        q, rows, jnp.full((B,), R, jnp.int32), sm_scale, rank,
+        bias=jnp.where(seen, 0.0, NEG_INF).astype(jnp.float32))

@@ -222,6 +222,9 @@ class SlotBatcher:
         #: the names of the group ``state_steps``, which a family with
         #: per-slot state has
         self.state_counters = fam.state_counters
+        #: and of the group ``sparse_select`` (a family that attends to a
+        #: selection of its cache)
+        self.select_counters = fam.select_counters
         self.lengths = jnp.zeros((B,), jnp.int32)
         self.keys = jnp.stack([jax.random.PRNGKey(0)] * B)
         self.greedy = jnp.ones((B,), bool)
@@ -272,7 +275,8 @@ class SlotBatcher:
     def counts(self, group: str):
         """One group of the family's cumulative device counters
         (``moe_pairs``: the expert layers' ``pair_counts``; ``state_steps``:
-        the ``state_counters``), or None where the family has no such group
+        the ``state_counters``; ``sparse_select``: the ``select_counters``),
+        or None where the family has no such group
         or no tick has been pulled yet."""
         where = self._stats_groups.get(group)
         if where is None or self.device_counts is None:

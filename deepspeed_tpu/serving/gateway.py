@@ -969,6 +969,14 @@ class ServingGateway:
             if self.tracer.enabled:
                 self.tracer.record(SpanName.SERVE_STATE_STEPS, now, 0.0,
                                    **state)
+        select = self._batcher.counts("sparse_select")
+        if select is not None:
+            select = {k: int(c) for k, c in
+                      zip(self._batcher.select_counters, select)}
+            self.metrics.record_sparse_select(select)
+            if self.tracer.enabled:
+                self.tracer.record(SpanName.SERVE_SPARSE_SELECT, now, 0.0,
+                                   **select)
         round_k = tick.draft_k
         n_fed = n_live - len(late)
         if counts is not None and n_fed:

@@ -112,6 +112,8 @@ class ServingMetrics:
         #: stepped, real and padded tokens through the chunk scan):
         #: cumulative, as of the last harvested tick
         self.state_steps: dict = {}
+        #: a selecting family's cumulative counts (``select_counters``)
+        self.sparse_select: dict = {}
         #: time-to-first-token, seconds — the shared telemetry histogram
         #: (count/sum exact, reservoir bounded at :data:`_TTFT_CAP`)
         self.ttft = Histogram(MetricName.SERVE_TTFT_S, cap=_TTFT_CAP)
@@ -207,6 +209,12 @@ class ServingMetrics:
         with self._lock:
             self.state_steps = dict(counts)
 
+    def record_sparse_select(self, counts: dict) -> None:
+        """``counts``: name -> cumulative count, the batcher's group
+        ``sparse_select`` under the family's ``select_counters``."""
+        with self._lock:
+            self.sparse_select = dict(counts)
+
     def record_ttft(self, seconds: float) -> None:
         self.ttft.observe(float(seconds))
 
@@ -300,6 +308,7 @@ class ServingMetrics:
                 "moe_expert_visits": self.moe_expert_visits,
                 "moe_expert_pairs": list(self.moe_expert_pairs),
                 "state_steps": dict(self.state_steps),
+                "sparse_select": dict(self.sparse_select),
                 # the busiest held expert's pairs over the mean's
                 "moe_expert_load_max_over_mean": (
                     max(self.moe_expert_pairs) * len(self.moe_expert_pairs)

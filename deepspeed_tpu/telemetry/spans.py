@@ -154,6 +154,13 @@ class SpanName:
     #: scan_tokens_padded: token x state-space layer of the admissions'
     #: chunk scans): counted on the device, pulled with the tokens
     SERVE_STATE_STEPS = "serve.state_steps"
+    #: a selecting family's counts as of one harvested tick, cumulative over
+    #: its single-token passes (recorded, zero length; in args eligible:
+    #: cached tokens a full layer's query could have chosen from; selected:
+    #: those it attended to; streamed: latent rows the sweep's copies moved
+    #: for it; ring_live: ring cells a window layer's query saw): counted on
+    #: the device, pulled with the tokens
+    SERVE_SPARSE_SELECT = "serve.sparse_select"
     #: end of admission -> the tick that harvested the request's first
     #: token (recorded; rid in args)
     SERVE_FIRST_TOKEN = "serve.first_token"

@@ -19,10 +19,12 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from benchmarks.chip import (hybrid_ssm_moe_family, latent_moe_family,
                              mellum_family, nemotron_h_family)
+from benchmarks.chip import dots3_family
 from deepspeed_tpu.models import (cache_family, gpt, gpt_inference, gpt_moe,
                                   gpt_moe_inference, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference,
-                                  latent_moe_inference, window_moe,
+                                  latent_moe_inference,
+                                  sparse_latent_moe_inference, window_moe,
                                   window_moe_inference)
 from deepspeed_tpu.ops.pallas import decode_attention
 from tests.unit.ops.traced_sweeps import sweep_calls
@@ -64,12 +66,15 @@ def _served(name):
         "single_part": (nemotron_h_family, "nemotron-3-nano-30b-a3b-ep4",
                         hybrid_ssm_moe_inference.FAMILY),
         "window": (mellum_family, "mellum2-12b-a2.5b-ep4",
-                   window_moe_inference.FAMILY)}[name]
+                   window_moe_inference.FAMILY),
+        "selected": (dots3_family, "dots3-note-prev-ep32",
+                     sparse_latent_moe_inference.FAMILY)}[name]
     cfg = _tiny(builder, file)
     return cfg, lambda k: builder.init(cfg, k, jnp.float32), family
 
 
-SERVED = ("dense", "moe", "latent", "hybrid", "single_part", "window")
+SERVED = ("dense", "moe", "latent", "hybrid", "single_part", "window",
+          "selected")
 
 
 @pytest.mark.parametrize("name", SERVED)
@@ -81,7 +86,8 @@ def test_cache_family_returns_the_whole_declaration(name):
         value = getattr(fam, field.name)
         if field.name == "prompt_pass":      # GPT-MoE's one real override
             assert (value is not None) == (name == "moe")
-        elif field.name in ("unsupported", "state_counters"):
+        elif field.name in ("unsupported", "state_counters",
+                            "select_counters"):
             assert value is not None
         else:
             assert callable(value), field.name
@@ -89,7 +95,11 @@ def test_cache_family_returns_the_whole_declaration(name):
         "dense": set(), "moe": set(), "latent": {"moe_pairs"},
         "hybrid": {"moe_pairs", "state_steps"},
         "single_part": {"moe_pairs", "state_steps"},
-        "window": {"moe_pairs"}}[name]
+        "window": {"moe_pairs"},
+        "selected": {"moe_pairs", "sparse_select"}}[name]
+    assert fam.select_counters == (
+        sparse_latent_moe_inference.SELECT_COUNTERS
+        if name == "selected" else ())
     assert fam.state_counters == (
         hybrid_ssm_moe_inference.STATE_COUNTERS
         if name in ("hybrid", "single_part") else ())
@@ -186,6 +196,9 @@ SERVING_REFUSALS = [
      "token-indexed banks only"),
 ] + [("window", feature, f"serving.{feature} with WindowMoEConfig: "
       + window_moe_inference.UNSUPPORTED[feature])
+     for feature in ("speculative", "paging", "prefix")] \
+  + [("selected", feature, f"serving.{feature} with SparseLatentMoEConfig: "
+      + sparse_latent_moe_inference.UNSUPPORTED[feature])
      for feature in ("speculative", "paging", "prefix")]
 
 

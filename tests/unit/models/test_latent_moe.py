@@ -265,8 +265,26 @@ def test_bf16_passes_and_a_left_out_term_fails():
     assert not compare.agrees(system(no_attention, bf16), ref)
 
 
+@pytest.fixture(scope="module")
+def loud():
+    """``(config, weights as the other matrices are drawn, the program's
+    forward, the reference's logits)`` for the faults below: built once, the
+    sound run held to the comparison here."""
+    from benchmarks.chip.reference import compare
+    file = _file()
+    cfg, _ = _model(file)
+    params = latent_moe.init(cfg, jax.random.PRNGKey(3))
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2, 48)).astype(np.int32)
+    ref = reference.forward(file, params, tokens, 16)
+    system = jax.jit(lambda params: latent_moe.apply(
+        params, tokens, cfg)[:, -16:, :cfg.vocab_size])
+    assert compare.agrees(system(params), ref)
+    return params, system, ref
+
+
 @pytest.mark.parametrize("fault", ["zero", "permute", "layer"])
-def test_a_fault_in_the_routed_experts_fails(fault):
+def test_a_fault_in_the_routed_experts_fails(loud, fault):
     """The routed product left out, a held expert's rows through another
     expert's down-projection, a layer reading another layer's experts
     (``latent_moe_control.FAULTS``): with the routed experts drawn as the
@@ -277,16 +295,7 @@ def test_a_fault_in_the_routed_experts_fails(fault):
     the chip's readings are in PERF.md 6.)"""
     from benchmarks.chip.reference import compare
     from benchmarks.chip.reference.latent_moe_control import FAULTS
-    file = _file()
-    cfg, _ = _model(file)
-    params = latent_moe.init(cfg, jax.random.PRNGKey(3))
-    tokens = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (2, 48)).astype(np.int32)
-    ref = reference.forward(file, params, tokens, 16)
-
-    def system(params):
-        return latent_moe.apply(params, tokens, cfg)[:, -16:, :cfg.vocab_size]
-    assert compare.agrees(system(params), ref)
+    params, system, ref = loud
     assert not compare.agrees(system(FAULTS[fault](params)), ref)
 
 

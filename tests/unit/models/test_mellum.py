@@ -92,6 +92,21 @@ def _gateway(cfg, params, **serving):
     return gateway
 
 
+@pytest.fixture(scope="module")
+def tiny():
+    """``(file, config, weights)`` of the tiny file as the family draws
+    them, built once a module."""
+    file = _file()
+    return (file,) + _model(file)
+
+
+@pytest.fixture(scope="module")
+def served(tiny):
+    """One stopped gateway over :func:`tiny`, its programs compiled once:
+    a case that needs no other serving config probes this one."""
+    return _gateway(*tiny[1:])
+
+
 # ------------------------------------------------------- the configuration
 
 def test_the_file_is_the_sources_but_for_what_reduced_lists():
@@ -207,9 +222,12 @@ def test_apply_equals_the_reference():
                                rtol=RTOL)
 
 
-def _slot_path(file, cfg, params, n, ticks=8, drawn=None, **serving):
-    """``drawn``: the weights the reference takes (default: the server's)."""
-    gateway = _gateway(cfg, params, **serving)
+def _slot_path(file, cfg, params, n, ticks=8, drawn=None, gateway=None,
+               **serving):
+    """``drawn``: the weights the reference takes (default: the server's);
+    ``gateway``: one built already over ``params`` (default: a new one)."""
+    if gateway is None:
+        gateway = _gateway(cfg, params, **serving)
     rng = np.random.default_rng(3 + n)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)]
     replies, got = gateway.probe_logits(prompts, ticks)
@@ -223,18 +241,20 @@ def _slot_path(file, cfg, params, n, ticks=8, drawn=None, **serving):
                                2 * WINDOW - 4, 3 * WINDOW + 5],
                          ids=["1", "short", "decode-laps", "W", "second-lap",
                               "decode-laps-again", "3W+5"])
-def test_slot_path_equals_the_reference_across_the_rings_wrap(n):
+def test_slot_path_equals_the_reference_across_the_rings_wrap(tiny, served,
+                                                              n):
     """Chunked prefill, then 8 decode ticks through the gateway's own
     programs and both pools, against the reference's full forward, on
     logits: a prompt shorter than the window (whose decode crosses the
     ring's first lap at ``W - 3``), one a window long, prompts that end
     inside their second lap (the padded tail of their last chunk must not
     reach the ring), and decode that crosses a lap again."""
-    file = _file()
-    cfg, params = _model(file)
-    gateway, got, ref = _slot_path(file, cfg, params, n)
+    file, cfg, params = tiny
+    before = served._batcher.counts("moe_pairs")    # cumulative
+    gateway, got, ref = _slot_path(file, cfg, params, n, gateway=served)
     np.testing.assert_allclose(got, ref, atol=ATOL, rtol=RTOL)
-    pairs = gateway._batcher.counts("moe_pairs")
+    pairs = gateway._batcher.counts("moe_pairs") \
+        - (0 if before is None else before)
     assert pairs[0] == pairs[3:].sum() > 0 and len(pairs) == 3 + len(cfg.held)
     padded, ticks = -(-n // CHUNK) * CHUNK, 8
     assert pairs[1] == (padded + ticks * 4) * cfg.n_layer \
@@ -253,8 +273,8 @@ def test_a_chunk_that_straddles_the_windows_edge(chunk):
     np.testing.assert_allclose(got, ref, atol=ATOL, rtol=RTOL)
 
 
-def test_slot_write_read_and_reset_walk_both_pools():
-    cfg, params = _model(_file())
+def test_slot_write_read_and_reset_walk_both_pools(tiny):
+    _, cfg, params = tiny
     fam = cache_family(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, 40), 0,
                                 cfg.vocab_size)
@@ -279,9 +299,8 @@ def test_slot_write_read_and_reset_walk_both_pools():
                    for a in blank.ring + (blank.k, blank.v))
 
 
-def test_the_sweeps_counts_follow_both_pools():
-    cfg, params = _model(_file())
-    gateway = _gateway(cfg, params)
+def test_the_sweeps_counts_follow_both_pools(tiny, served):
+    cfg, gateway = tiny[1], served
     bat = gateway._batcher
     assert bat.sweep_plan.ring.Smax == WINDOW
     assert set(bat.sweep_by_kind([5, 40])) == {"full", "window"}

@@ -938,3 +938,95 @@ def test_train_step_relays_nothing_around_the_flash_kernels(v5e_host, cell):
         qkv = operands.split(", ")[2:5]     # after kv_lens and window
         assert len(set(qkv)) == 1, (name, qkv)      # ONE array, three times
         assert f"bf16[{micro},{seq},{h * d}]" in result, (name, result)
+
+
+# --------------------------------- the selecting family at its own geometry
+
+def _selected():
+    """``(model module, config, slots, slot length, chunk)`` of
+    ``dots3n-serve-longgen-sat``: the published widths and the nine layers
+    of its cut at 80 x 16,384 in chunks of 1,024: three kinds of cached
+    state (a 640-lane latent bank and a 128-wide bank of index keys on
+    three layers, a ring of 640 cells of 1,152 lanes on six)."""
+    import json
+    from benchmarks.chip import dots3_family
+    from deepspeed_tpu.models import sparse_latent_moe
+    with open(os.path.join(os.path.dirname(__file__), "..", "..", "..",
+                           "benchmarks", "chip", "configs",
+                           "dots3-note-prev-ep32.json")) as f:
+        cfg = dots3_family.build(json.load(f))
+    import dataclasses
+    return sparse_latent_moe, dataclasses.replace(
+        cfg, param_dtype=BF16), 80, 16384, 1024
+
+
+def _moves_of_a_pool(text, pool):
+    """``(elements, opcode)`` of every copy, transpose or slice of the
+    compiled module that is as large as a layer of one of the pool's stacks
+    (their sizes: the three kinds of cached state differ)."""
+    layers = {x.size // x.shape[0]
+              for x in jax.tree_util.tree_leaves(pool) if x.ndim >= 4}
+    return [(n, op) for n, op in _root_opcodes(text)
+            if any(n % layer == 0 and n // layer <= 8 for layer in layers)
+            and (op.startswith("copy") or op in ("transpose",
+                                                 "dynamic-slice"))]
+
+
+@pytest.mark.parametrize("program", [
+    "tick", pytest.param("admission", marks=pytest.mark.slow)])
+def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
+    """The tick and the admission of the selected-latent / window-latent
+    family at its cell's geometry, for the described chip: the index's two
+    score kernels, the latent sweep and the latent chunk kernel under a bias
+    lower (a ring's chunk pass at 1,152 lanes takes 8 positions a step, not
+    16: 16 overran the kernel's VMEM), the donated pool is updated in place,
+    and nothing copies, transposes or slices as much as a layer of any of
+    the three stacks: the latent bank, the index keys, the rings.  The
+    index's work list is built once a tick, outside the layer scans."""
+    from deepspeed_tpu.models import cache_family
+    from deepspeed_tpu.serving.batcher import admission
+    model, cfg, slots, smax, chunk = _selected()
+    fam = cache_family(cfg)
+    params = _described(jax.eval_shape(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))), v5e)
+    pool = _described(jax.eval_shape(
+        lambda: fam.init_cache(cfg, slots, smax)), v5e)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if program == "tick":
+        tick = jax.jit(
+            lambda p, c, tok, lengths, active: fam.decode_step(
+                p, tok, cfg, c, lengths=lengths, active=active),
+            donate_argnums=(1,))
+        args = (params, pool, arg((slots,), jnp.int32),
+                arg((slots,), jnp.int32), arg((slots,), jnp.bool_))
+        _sweep_is_built_outside_the_layer_scan(tick.trace(*args).jaxpr,
+                                               slots, segments=2)
+        compiled = tick.lower(*args).compile()
+        kernels = ("index_decode_scores", "latent_decode_attention")
+    else:
+        vocab = cfg.padded_vocab
+        per_slot = [arg((slots,) + tail, dtype) for tail, dtype in (
+            ((), jnp.int32), ((vocab,), jnp.float32), ((2,), jnp.uint32),
+            ((), jnp.bool_), ((), jnp.float32), ((), jnp.bool_))]
+        compiled = jax.jit(
+            admission(fam, cfg, smax, None), donate_argnums=(1, 3)).lower(
+                params, pool, *per_slot,
+                arg((smax // chunk, chunk), jnp.int32), arg((7,), jnp.int32),
+                arg((2,), jnp.uint32)).compile()
+        kernels = ("index_chunk_scores", "latent_chunk_attention")
+    text = compiled.as_text()
+    for kernel in kernels:
+        assert f"/{kernel}/pallas_call" in text, kernel
+    moved = _moves_of_a_pool(text, pool)
+    assert not moved, f"the {program} moves whole layers of a pool: {moved}"
+    assert "input_output_alias" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        _pool_bytes(pool), "the donated pool is not updated in place"
+    # weights and pool are 12.9 GB of the chip's 15.75 GiB; the program's
+    # own temporaries fit beside them
+    plan = compiled.memory_analysis()
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
