@@ -82,6 +82,49 @@ class PrefixEntry:
     length: int
 
 
+#: widths of an admission's wide passes, widest first.  A pass over the
+#: weights costs their stream whatever it holds until its rows' products
+#: take longer, on a v5e at 197e12 / 819e9 = 240 rows: a pass of 128 rows
+#: pays for 240 (2.22 ms of GPT-2 medium's device time), the first width
+#: past the ridge runs twice the rows for a quarter more (2.84 ms at 256),
+#: and past it a pass is bound by its products (5.14 ms at 512 for two of
+#: 256's 5.68) while every width is one more body of the model to trace,
+#: lower and load, 0.65 s of a server's start (PERF.md 6, PR 52).  One
+#: width stands; it is a tuple because the admission runs ONE loop over
+#: :func:`pass_widths` (the chunk the last of them) whatever their number,
+#: and the tests patch in widths small enough for their slots.
+WIDE_PASSES = (256,)
+
+
+def pass_widths(chunk: int, max_len: int) -> Tuple[int, ...]:
+    """The widths of an admission's passes at ``prefill_chunk`` ``chunk``
+    over ``max_len``-token slots, descending: those of ``WIDE_PASSES`` that
+    are whole multiples of the chunk, wider than it and no longer than the
+    slot, then the chunk itself (alone at a chunk of 256 and more, and in a
+    slot shorter than 256)."""
+    return tuple(w for w in WIDE_PASSES
+                 if w > chunk and w % chunk == 0 and w <= max_len) + (chunk,)
+
+
+def ladder_passes(n: int, widths: Tuple[int, ...],
+                  first: int = 0) -> Tuple[int, int]:
+    """``(passes, wide)`` of an admission of ``n`` tokens whose ``first``
+    chunks run as chunks (1: a fresh row's first; 0 where it continues a
+    prefix): the chunks the prompt is padded to, taken by each width of
+    ``widths`` in turn in the WHOLE passes that fit what is left, the last
+    (the chunk) the rest; ``wide`` counts the positions of the passes wider
+    than the chunk.  The host's count of the admission program's own trip
+    counts."""
+    chunk = widths[-1]
+    left = max(-(-n // chunk) - first, 0)
+    passes, wide = first, 0
+    for w in widths[:-1]:
+        trips = left // (w // chunk)
+        passes, wide, left = passes + trips, wide + trips * w, \
+            left - trips * (w // chunk)
+    return passes + left, wide
+
+
 def admission(fam, cfg, max_len: int, kv_dtype):
     """The function of the admission program for model family ``fam`` (a
     ``gpt_inference.Family``, as ``models.cache_family`` returns it) at
@@ -91,7 +134,7 @@ def admission(fam, cfg, max_len: int, kv_dtype):
 
     def admit(params, pool, lengths, last, keys, greedy, temp, active,
               tokens, meta, key, prefix=None):
-        """One admission, whole: the chunk loop, the slot write and the
+        """One admission, whole: the chunk loops, the slot write and the
         bind.  ``tokens`` [max_len // C, C] is the prompt (past the prefix)
         padded by the host to the slot's chunk count; ``meta`` int32 [7] is
         ``(row, start, n, greedy, temperature's bits, fold?, fold's
@@ -99,42 +142,54 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         batch-1 cache of slot geometry, shared, never donated) at
         ``start``, or fill a fresh row cache from 0, and the row's key is
         ``key``, or ``fold_in(key, fold)`` as the host's own
-        ``jax.random.fold_in`` gives it.  The loop's trip count is the
-        traced number of real chunks, so one compiled program serves every
-        prompt length; every chunk is the family's own ``prefill`` / ragged
-        ``extend``, as :meth:`SlotBatcher._chunked_prefill` runs them one
-        launch each."""
+        ``jax.random.fold_in`` gives it.  After the first chunk a loop a
+        width of :func:`pass_widths` runs the whole passes of that width
+        that fit the chunks left of the prompt as it is padded (so a wide
+        pass computes the positions its chunks would, and only a prompt's
+        last pass holds padding), the last loop, at ``C``, the rest
+        (:func:`ladder_passes` counts them).  Every loop's trip count is
+        traced, so one compiled program serves every prompt length; every
+        pass is the family's own ``prefill`` / ragged ``extend``, as
+        :meth:`SlotBatcher._chunked_prefill` runs them one launch each at
+        ``C``."""
         C = tokens.shape[1]
         row, start, n = meta[0], meta[1], meta[2]
         # the named scopes are the parts of the one program, by which a
         # profiler's device time is split (``telemetry.device_time``):
         # ``admit_row_cache`` the batch-1 row cache's allocation and
-        # zero-fill, ``admit_chunk`` a chunk's pass (the family's own scopes
-        # below it: ``admit_chunk/head`` is the head over all C positions),
-        # ``admit_head`` the one row taken of it, ``admit_slot_write`` the
-        # row's copy into the pool, ``admit_bind`` the slot's vectors
+        # zero-fill, ``admit_chunk`` a pass of any width (the family's own
+        # scopes below it: ``admit_chunk/head`` is the head over all its
+        # positions), ``admit_head`` the one row taken of it,
+        # ``admit_slot_write`` the row's copy into the pool, ``admit_bind``
+        # the slot's vectors
 
-        def real(i):
-            # where the prompt ends inside chunk ``i``: a family that keeps
-            # state per slot must not let a recurrence take the padding (the
-            # banks take it either way: it lies past the frontier)
-            return jnp.clip(n - i * C, 0, C)[None]
+        def real(at, w):
+            # where the prompt ends inside the pass of ``w`` tokens at
+            # ``at``: a family that keeps state per slot must not let a
+            # recurrence take the padding (the banks take it either way: it
+            # lies past the frontier)
+            return jnp.clip(n - at, 0, w)[None]
 
         @jax.named_scope("admit_head")
-        def take(lg, i):
-            # the last real token's logits if chunk ``i`` holds it (the
-            # last chunk does; an earlier chunk's row is junk that the next
-            # iteration replaces)
-            idx = jnp.clip(n - 1 - i * C, 0, C - 1)
+        def take(lg, at):
+            # the last real token's logits if the pass at ``at`` holds it
+            # (the last pass does; an earlier one's row is junk that the
+            # next iteration replaces)
+            idx = jnp.clip(n - 1 - at, 0, lg.shape[1] - 1)
             return lax.dynamic_index_in_dim(lg[0], idx, 0, keepdims=False)
 
-        def chunk(i, carry):
-            pos = start + i * C
-            with jax.named_scope("admit_chunk"):
-                lg, cache = fam.extend(
-                    params, lax.dynamic_index_in_dim(tokens, i, 0), cfg,
-                    carry[1], lengths=pos[None], valid=real(i))
-            return take(lg, i), cache
+        def passes(w, done):
+            # pass ``i`` of ``w`` tokens after the first ``done``
+            def one(i, carry):
+                at = done + i * w
+                with jax.named_scope("admit_chunk"):
+                    lg, cache = fam.extend(
+                        params,
+                        lax.dynamic_slice(tokens.reshape(-1), (at,), (w,))[
+                            None], cfg, carry[1], lengths=(start + at)[None],
+                        valid=real(at, w))
+                return take(lg, at), cache
+            return one
 
         if prefix is None:
             chunk0 = tokens[:1]
@@ -142,14 +197,18 @@ def admission(fam, cfg, max_len: int, kv_dtype):
                 fresh = fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype)
             with jax.named_scope("admit_chunk"):
                 lg, cache = fam.prefill(params, chunk0, cfg, fresh,
-                                        valid=real(0))
-            first, carry = 1, (take(lg, 0), cache)
+                                        valid=real(0, C))
+            done, carry = 1, (take(lg, 0), cache)
         else:
-            first, carry = 0, (
+            done, carry = 0, (
                 jnp.zeros(last.shape[1:], last.dtype),
                 dataclasses.replace(prefix, length=start))
-        vec, cache = lax.fori_loop(jnp.int32(first), (n + C - 1) // C,
-                                   chunk, carry)
+        for w in pass_widths(C, max_len):
+            trips = jnp.maximum((n + C - 1) // C - done, 0) // (w // C)
+            carry = lax.fori_loop(jnp.int32(0), trips, passes(w, done * C),
+                                  carry)
+            done = done + trips * (w // C)
+        vec, cache = carry
         with jax.named_scope("admit_bind"):
             key = jnp.where(meta[5] != 0, jax.random.fold_in(
                 key, lax.bitcast_convert_type(meta[6], jnp.uint32)), key)
@@ -225,11 +284,14 @@ class SlotBatcher:
         #: and of the group ``sparse_select`` (a family that attends to a
         #: selection of its cache)
         self.select_counters = fam.select_counters
-        self.lengths = jnp.zeros((B,), jnp.int32)
-        self.keys = jnp.stack([jax.random.PRNGKey(0)] * B)
-        self.greedy = jnp.ones((B,), bool)
-        self.temp = jnp.ones((B,), jnp.float32)
-        self.active = jnp.zeros((B,), bool)
+        # the slots' vectors are made on the host and handed over: made on
+        # the device each is a program of its own to compile, at every start
+        self.lengths = jnp.asarray(np.zeros((B,), np.int32))
+        self.keys = jnp.asarray(
+            np.tile(np.asarray(jax.random.PRNGKey(0)), (B, 1)))
+        self.greedy = jnp.asarray(np.ones((B,), bool))
+        self.temp = jnp.asarray(np.ones((B,), np.float32))
+        self.active = jnp.asarray(np.zeros((B,), bool))
         self._last = None          # [B, padded_vocab], set on first admit
         #: program launches made for admissions, every path's: 1 for an
         #: admission (with or without a prefix), a chunk each where
@@ -658,7 +720,7 @@ class SlotBatcher:
         n_chunks = -(-S // C)
         with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S,
                               start=start_len, chunk=C, padded=n_chunks * C,
-                              chunks=n_chunks):
+                              chunks=n_chunks, passes=n_chunks, wide=0):
             chunks = self._padded_chunks(tokens, C, n_chunks)
             if start_cache is not None:
                 cache = start_cache
@@ -736,13 +798,18 @@ class SlotBatcher:
         program = ("admit" if prefix is None else "admit_prefix") + sfx
         if self._last is None:
             lg = self._logits_row()
-            self._last = jnp.zeros((self.slots,) + lg.shape, lg.dtype)
+            self._last = jnp.asarray(       # host-made, as the vectors
+                np.zeros((self.slots,) + lg.shape, lg.dtype))
         meta = np.array([row, start, S, bool(greedy),
                          np.float32(temperature).view(np.int32),
                          fold is not None,
                          np.uint32(fold or 0).view(np.int32)], np.int32)
+        # a fresh row's first chunk is the family's ``prefill``, at ``C``
+        passes, wide = ladder_passes(S, pass_widths(C, self.max_len),
+                                     first=0 if prefix is not None else 1)
         with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S, start=start,
-                              chunk=C, padded=n_chunks * C, chunks=n_chunks):
+                              chunk=C, padded=n_chunks * C, chunks=n_chunks,
+                              passes=passes, wide=wide):
             (self.cache, self.lengths, self._last, self.keys, self.greedy,
              self.temp, self.active, vec) = self._launch(
                 program,
@@ -768,14 +835,15 @@ class SlotBatcher:
 
     def _logits_row(self) -> jax.ShapeDtypeStruct:
         """One position's logits (the family's padded vocabulary, in the
-        type its head returns), asked of the family's own prefill: traced,
-        never run."""
+        type its head returns), asked of the family's own embedding and
+        head: traced, never run, and without the layers between them (a
+        scan hands a position's hidden state on in the type it took it)."""
         fam, cfg = self._fam, self._cfg
-        lg, _ = jax.eval_shape(
-            lambda p, t: fam.prefill(p, t, cfg, fam.init_cache(
-                cfg, 1, self.max_len, kv_dtype=self._kv_dtype)),
-            self._engine.params,
-            jax.ShapeDtypeStruct((1, self.chunk), jnp.int32))
+        lg = jax.eval_shape(
+            lambda p, t: fam.logits(
+                p, fam.embed(p, t, cfg, positions=jnp.arange(t.shape[1])),
+                cfg),
+            self._engine.params, jax.ShapeDtypeStruct((1, 1), jnp.int32))
         return jax.ShapeDtypeStruct(lg.shape[2:], lg.dtype)
 
     def _draft_prefill(self, tokens: np.ndarray):

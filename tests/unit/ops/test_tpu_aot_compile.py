@@ -543,14 +543,20 @@ def _pool_sized_moves(hlo_text, layer_k):
     return moved
 
 
-def _compile_admission(v5e, family, int8):
-    """``serving.batcher.admission`` of a family, and a chunk's ``extend``
-    on its batch-1 row alone, compiled for the described chip at the
-    serving cells' geometry (:func:`_served`): ``(admit, extend, pool, row
-    cache)``, the last two as shapes."""
+def _compile_admission(v5e, family, int8, layers=None):
+    """``serving.batcher.admission`` of a family, and its widest pass's
+    ``extend`` on its batch-1 row alone (GPT-2's, at chunks of 128 in slots
+    of 1,024, is the ladder's 256 tokens; every other family's its chunk),
+    compiled for the described chip at the serving cells' geometry
+    (:func:`_served`; ``layers`` deep where given): ``(admit, extend, pool,
+    row cache)``, the last two as shapes."""
+    import dataclasses
+
     from deepspeed_tpu.models import cache_family
-    from deepspeed_tpu.serving.batcher import admission
+    from deepspeed_tpu.serving.batcher import admission, pass_widths
     model, cfg, slots, smax, chunk = _served(family)
+    if layers:
+        cfg = dataclasses.replace(cfg, n_layer=layers)
     fam = cache_family(cfg)
     kv = "int8" if int8 else None
     # the weights in the type they are served in (a cast of the master
@@ -567,7 +573,7 @@ def _compile_admission(v5e, family, int8):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    tokens = arg((1, chunk), jnp.int32)
+    tokens = arg((1, pass_widths(chunk, smax)[0]), jnp.int32)
     extend = jax.jit(
         lambda p, t, c, l: fam.extend(p, t, cfg, c, lengths=l)).lower(
             params, tokens, row_cache, arg((1,), jnp.int32)).compile()
@@ -586,17 +592,26 @@ def _compile_admission(v5e, family, int8):
 
 @pytest.fixture(scope="module")
 def admission_of(v5e):
-    """:func:`_compile_admission` of ``(family, int8)``, once a family a
-    process: the two guards below read one compile where a worker runs
-    both."""
+    """:func:`_compile_admission` of ``(family, int8, layers)``, once each a
+    process: the guards below read one compile where a worker runs them."""
     compiled = {}
 
-    def of(family, int8):
-        if (family, int8) not in compiled:
-            compiled[family, int8] = _compile_admission(v5e, family, int8)
-        return compiled[family, int8]
+    def of(family, int8, layers=None):
+        if (family, int8, layers) not in compiled:
+            compiled[family, int8, layers] = _compile_admission(
+                v5e, family, int8, layers)
+        return compiled[family, int8, layers]
 
     return of
+
+
+#: the depth of the cell whose admission holds the ladder's wide loop
+#: (``gpt2-medium``'s 24 layers, where :func:`_served` lets two stand for
+#: them): its plan guards are read there.  While a row's banks are a few MB
+#: the compiler fetches them whole into its fast memory for the 256-row
+#: body, and the plan reads 10-17 MB more than the depth-free parts it is
+#: held to (PERF.md 6, PR 52, gives both depths' sizes).
+_LADDER_LAYERS = 24
 
 
 @_SERVED
@@ -608,8 +623,8 @@ def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
     ``fori_loop`` beside the donated pool must still write the pool where it
     lies: the pool's inputs are its outputs, nothing copies, slices or
     updates as much as a layer of it (the slot write's update is one row of
-    every layer), and the plan is what a chunk's ``extend`` on the batch-1
-    row and the pool hold between them today."""
+    every layer), and the plan is what its widest pass's ``extend`` on the
+    batch-1 row and the pool hold between them today."""
     _, cfg, slots, smax, _ = _served(family)
     compiled, extend, pool, _ = admission_of(family, int8)
     text = compiled.as_text()
@@ -626,7 +641,23 @@ def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
     # compiler hoists out of the chunk loop (a weight re-laid once an
     # admission, not once a chunk) stays live through it (37 MB of 6.6 GB
     # for the latent family; 57 MB of 14.0 GB at its cell's depth, under
-    # the tick's own 14.43 GB: PERF.md 6)
+    # the tick's own 14.43 GB: PERF.md 6).  A program that holds the ladder
+    # (GPT-2's: a chunk kernel's call a width) is held to the same
+    # hundredth at its cell's own depth, against its WIDEST pass's
+    # ``extend`` there
+    import re
+
+    from deepspeed_tpu.serving.batcher import pass_widths
+    chunk = _served(family)[-1]
+    widths = pass_widths(chunk, smax)
+    assert widths == ((256, 128) if family in ("dense", "moe")
+                      else (chunk,))
+    if len(widths) > 1:
+        assert {int(w) for w in re.findall(
+            r"%chunk_attention[.\d]* = bf16\[16,1,(\d+),64\]", text)} == \
+            set(widths)
+        compiled, extend, pool, _ = admission_of(family, int8,
+                                                 _LADDER_LAYERS)
     held_today = _planned_bytes(extend) + _pool_bytes(pool)
     assert _planned_bytes(compiled) <= 1.01 * held_today, (
         _planned_bytes(compiled), _planned_bytes(extend), _pool_bytes(pool))
@@ -681,12 +712,14 @@ def test_the_update_slices_plan_is_the_scatters(v5e, admission_of,
                                                 monkeypatch, int8):
     """... and the plan does not grow with the row: against the same
     admission with the parent's scatter in the slice's place it holds the
-    chunk's rows more, read back and moved (0.3-0.6 MB, at any depth)."""
+    chunk's rows more, read back and moved (0.1-0.7 MB), at the depth the
+    ladder's program is served at (:data:`_LADDER_LAYERS`)."""
     from deepspeed_tpu.models import gpt_inference
-    sliced = _planned_bytes(admission_of("dense", int8)[0])
+    sliced = _planned_bytes(admission_of("dense", int8, _LADDER_LAYERS)[0])
     monkeypatch.setattr(gpt_inference, "_chunk_slice",
                         gpt_inference._chunk_scatter)
-    scattered, _, _, row_cache = _compile_admission(v5e, "dense", int8)
+    scattered, _, _, row_cache = _compile_admission(v5e, "dense", int8,
+                                                    _LADDER_LAYERS)
     assert _row_bank_ops(scattered.as_text(), row_cache, "scatter")
     assert sliced <= _planned_bytes(scattered) + (1 << 20)
 

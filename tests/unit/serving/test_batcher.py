@@ -1,6 +1,8 @@
 """Slot ops + SlotBatcher: per-row admission into a live cache, batched
 ragged decode ticks, and the no-recompile contract."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -442,25 +444,28 @@ def test_the_batcher_counts_the_tokens_its_familys_kernel_streams():
 CHUNK, SLOT = 8, 64
 
 
-def _latent():
-    """The latent-attention family at the benchmark's tiny sizes."""
-    import dataclasses
+def _tiny(module, name, dtype=jnp.bfloat16):
+    """``(cfg, params)`` of a family of the benchmark (its module under
+    ``benchmarks/chip``) at the tiny sizes of its configuration ``name``."""
+    import importlib
     import json
     import os
 
-    from benchmarks.chip import latent_moe_family
+    family = importlib.import_module(f"benchmarks.chip.{module}")
     root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
-    name = "kimi-k2.7-code-ep32.json"
     with open(os.path.join(root, "benchmarks", "chip", "configs", name)) as f:
         file = json.load(f)
     with open(os.path.join(root, "tests", "unit", "chipbench", "tiny",
                            "configs", name)) as f:
         file.update(json.load(f))
-    cfg = dataclasses.replace(latent_moe_family.build(file),
-                              dtype=jnp.bfloat16)
-    return cfg, latent_moe_family.init(cfg, jax.random.PRNGKey(0),
-                                       jnp.bfloat16)
+    cfg = dataclasses.replace(family.build(file), dtype=dtype)
+    return cfg, family.init(cfg, jax.random.PRNGKey(0), dtype)
+
+
+def _latent():
+    """The latent-attention family at the benchmark's tiny sizes."""
+    return _tiny("latent_moe_family", "kimi-k2.7-code-ep32.json")
 
 
 _SERVED = {}
@@ -486,6 +491,10 @@ def _served(family, kv):
     return _SERVED[family, kv]
 
 
+#: chunk programs that take ``valid``, a batcher of a family that needs it
+_TOLD_THE_PADDING = {}
+
+
 def _launch_by_launch(bat, row, tokens, key, greedy, temperature,
                       prefix=None):
     """An admission as it was before it was one program: a fresh batch-1
@@ -496,15 +505,28 @@ def _launch_by_launch(bat, row, tokens, key, greedy, temperature,
     cache = prefix.cache if prefix is not None else fam.init_cache(
         cfg, 1, bat.max_len, kv_dtype=bat._kv_dtype)
     new = np.asarray(tokens[start:], np.int32)
-    for at in range(0, len(new), CHUNK):
-        chunk = np.zeros((1, CHUNK), np.int32)
-        chunk[0, :len(new[at:at + CHUNK])] = new[at:at + CHUNK]
+    C = bat.chunk
+    prefill, extend = bat._p["prefill"], bat._p["extend"]
+    if bat.cache.state is not None or bat.cache.ring is not None:
+        # a recurrence or a ring must be told where a padded chunk ends,
+        # which the registered pair (a prefix's builders) cannot say
+        prefill, extend = _TOLD_THE_PADDING.setdefault(id(bat), (
+            jax.jit(lambda p, t, c, valid: fam.prefill(
+                p, t, cfg, c, valid=valid)),
+            jax.jit(lambda p, t, c, l, valid: fam.extend(
+                p, t, cfg, c, lengths=l, valid=valid))))
+    for at in range(0, len(new), C):
+        chunk = np.zeros((1, C), np.int32)
+        real = len(new[at:at + C])
+        chunk[0, :real] = new[at:at + C]
+        valid = (jnp.asarray([real], jnp.int32),) \
+            if prefill is not bat._p["prefill"] else ()
         if start + at == 0:
-            lg, cache = bat._p["prefill"](params, jnp.asarray(chunk), cache)
+            lg, cache = prefill(params, jnp.asarray(chunk), cache, *valid)
         else:
-            lg, cache = bat._p["extend"](
+            lg, cache = extend(
                 params, jnp.asarray(chunk), cache,
-                jnp.asarray([start + at], jnp.int32))
+                jnp.asarray([start + at], jnp.int32), *valid)
     vec = lg[0, len(new) - 1 - at]
     if bat._last is None:
         bat._last = jnp.zeros((bat.slots,) + vec.shape, vec.dtype)
@@ -528,49 +550,63 @@ def test_one_launch_admission_equals_the_launches_it_replaced(family, kv,
     """One ``admit`` program leaves a slot as ``_chunked_prefill``'s
     launches, ``write_slot`` and ``bind`` left it: the row's cache up to the
     frontier, its length, frontier logits, key, sampling mode, and the eight
-    greedy tokens that follow, for prompts of 1, ``C``, ``C + 1`` and
-    ``max_len`` tokens and for prompts that continue a prefix (ending on a
-    chunk's edge, and not)."""
-    fused, plain = _served(family, kv)
-    fam, vocab = fused._fam, fused._cfg.vocab_size
-    rng = np.random.default_rng(len(prompt) + 11 * len(family))
+    greedy tokens that follow, bit for bit, for prompts of 1, ``C``, ``C +
+    1`` and ``max_len`` tokens and for prompts that continue a prefix
+    (ending on a chunk's edge, and not)."""
     n, cut = {"one": (1, 0), "chunk": (CHUNK, 0), "chunk+1": (CHUNK + 1, 0),
               "slot": (SLOT, 0), "prefix": (3 * CHUNK + 5, 2 * CHUNK),
               "prefix-ragged": (2 * CHUNK + 3, CHUNK - 3)}[prompt]
+    _same_slot(*_served(family, kv), n, cut, ticks=8, tol=0)
+
+
+def _same_slot(fused, plain, n, cut, ticks=6, tol=2e-4):
+    """Admit a prompt of ``n`` tokens (past a prefix of ``cut``, each side's
+    of its own making: the same chunks) into ``fused`` by its one launch and
+    into ``plain`` launch by launch, and hold the two slots together within
+    ``tol`` (0: bit for bit): the binds, the frontier logits, every leaf of
+    the row up to the frontier, ``ticks`` greedy tokens; one launch, and no
+    program of ``fused`` compiled twice."""
+    fam, vocab = fused._fam, fused._cfg.vocab_size
+    rng = np.random.default_rng(n + 7 * cut)
     tokens = rng.integers(0, vocab, (n,)).astype(np.int32)
     key = jax.random.PRNGKey(n)
-    row = n % 3
+    row = n % fused.slots
     for bat in (fused, plain):
         for r in range(bat.slots):
             bat.release(r)
-    before = fused.admit_launches
     prefix = [None, None]
     if cut:
-        # each side continues a prefix of its own making: the same chunks
         prefix = [bat.build_prefix(tokens[:cut]) for bat in (fused, plain)]
-        before = fused.admit_launches
+    before = fused.admit_launches
     assert fused.admit(row, tokens, key, True, 0.7, prefix=prefix[0]) == n
     assert fused.admit_launches - before == 1
     _launch_by_launch(plain, row, tokens, key, True, 0.7, prefix=prefix[1])
+
+    def close(a, b, what):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol, err_msg=what)
 
     for name in ("lengths", "keys", "greedy", "temp", "active"):
         np.testing.assert_array_equal(
             np.asarray(getattr(fused, name)),
             np.asarray(getattr(plain, name)), err_msg=name)
     assert int(fused.lengths[row]) == n and bool(fused.active[row])
-    np.testing.assert_array_equal(np.asarray(fused._last[row], np.float32),
-                                  np.asarray(plain._last[row], np.float32))
+    close(fused._last[row], plain._last[row], "frontier logits")
     got, want = (fam.read_slot(bat.cache, row, n) for bat in (fused, plain))
     for bank in ("k", "v", "k_scale", "v_scale"):
         if getattr(want, bank) is not None:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(got, bank)[:, 0, :n], np.float32),
-                np.asarray(getattr(want, bank)[:, 0, :n], np.float32),
-                err_msg=bank)
-    if n + 8 <= SLOT:
-        replies = [[int(bat.tick()[row]) for _ in range(8)]
+            close(getattr(got, bank)[:, 0, :n], getattr(want, bank)[:, 0, :n],
+                  bank)
+    for name in ("state", "ring"):
+        for a, b in zip(getattr(got, name) or (), getattr(want, name) or ()):
+            close(a, b, name)
+    if n + ticks <= fused.max_len:
+        replies = [[int(bat.tick()[row]) for _ in range(ticks)]
                    for bat in (fused, plain)]
         assert replies[0] == replies[1]
+    counts = fused.compile_counts()
+    assert all(v <= 1 for v in counts.values()), counts
 
 
 @pytest.mark.parametrize("family,kv", [
@@ -597,6 +633,261 @@ def test_one_admission_program_serves_every_prompt_length(family, kv):
     m.count("admitted", 5)
     m.count("admit_launches", fused.admit_launches - before)
     assert m.snapshot()["launches_per_admission"] == 1.0
+
+
+# ------------------------------------------------- the ladder of widths
+
+@pytest.mark.parametrize("family", [
+    "dense", "moe", "latent_moe_family:kimi-k2.7-code-ep32.json",
+    "hybrid_ssm_moe_family:granite-4.0-h-small-ep4.json",
+    "nemotron_h_family:nemotron-3-nano-30b-a3b-ep4.json",
+    "mellum_family:mellum2-12b-a2.5b-ep4.json",
+    "dots3_family:dots3-note-prev-ep32.json"])
+def test_the_frontier_logits_row_is_the_prefills(family):
+    """``SlotBatcher._logits_row`` asks only the family's embedding and head
+    for the frontier logits' shape and type (no body of the model is traced
+    for it at a server's start): for every family, in the type its cells
+    serve in, that is the row its ``prefill`` returns and the admission
+    writes."""
+    if ":" in family:
+        cfg, params = _tiny(*family.split(":"))
+    else:
+        mod, cfg = (gpt, CFG) if family == "dense" else (gpt_moe, MOE_CFG)
+        cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
+        params = mod.init(cfg, jax.random.PRNGKey(0))
+    eng = deepspeed_tpu.init_inference(model=(cfg, params),
+                                       config={"dtype": "bfloat16"})
+    bat = SlotBatcher(eng, ServingConfig.from_dict(
+        {"slots": 2, "max_len": SLOT, "prefill_chunk": CHUNK}))
+    fam = bat._fam
+    lg, _ = jax.eval_shape(
+        lambda p, t: fam.prefill(p, t, cfg, fam.init_cache(
+            cfg, 1, bat.max_len, kv_dtype=bat._kv_dtype)),
+        eng.params, jax.ShapeDtypeStruct((1, bat.chunk), jnp.int32))
+    row = bat._logits_row()
+    assert (row.shape, row.dtype) == (lg.shape[2:], lg.dtype)
+
+
+#: a dense model small enough to run slots of GPT-2's length on the CPU
+LONG = gpt.GPTConfig(vocab_size=256, max_seq_len=1024, n_layer=2, n_head=2,
+                     d_model=32, dtype=jnp.float32, vocab_round_to=128)
+
+#: ``(fused, plain)`` a key of :func:`_laddered`
+_LADDERED = {}
+
+
+def _laddered(family):
+    """``(fused, plain)`` as :func:`_served` gives them, in float32 (a wide
+    pass sums in another order than the narrow ones it stands for): the
+    dense model at the cell's geometry, chunks of 128 in slots of 1,024,
+    where the ladder is 256, 128; a family with per-slot state and one
+    with rings at their tiny sizes, chunks of 8 in slots of 64, for which
+    the caller patches the wide widths small."""
+    if family not in _LADDERED:
+        if family == "dense":
+            cfg, params = LONG, gpt.init(LONG, jax.random.PRNGKey(0))
+            geometry = {"slots": 2, "max_len": 1024, "prefill_chunk": 128}
+        else:
+            cfg, params = _tiny(*{
+                "state": ("hybrid_ssm_moe_family",
+                          "granite-4.0-h-small-ep4.json"),
+                "ring": ("mellum_family", "mellum2-12b-a2.5b-ep4.json"),
+            }[family], dtype=jnp.float32)
+            geometry = {"slots": 2, "max_len": SLOT, "prefill_chunk": CHUNK}
+        eng = deepspeed_tpu.init_inference(model=(cfg, params),
+                                           config={"dtype": "float32"})
+        serving = ServingConfig.from_dict(geometry)
+        _LADDERED[family] = (SlotBatcher(eng, serving),
+                             SlotBatcher(eng, serving))
+    return _LADDERED[family]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class _Counts:
+    """What :func:`_counting_family` keeps where a family keeps a cache."""
+    passes: jax.Array
+    wide: jax.Array
+    seen: jax.Array
+    length: jax.Array
+
+
+def _counting_family(vocab=4):
+    """A stand-in for a model family whose "cache" counts what the admission
+    program asks of it: the passes it ran, the tokens of those wider than
+    the chunk (handed over where a family's ``cfg`` goes), how often each
+    position was computed as a real token, and in its "logits" the position
+    each row stands at."""
+    import types
+
+    def init_cache(cfg, batch, max_len, kv_dtype=None):
+        return _Counts(jnp.int32(0), jnp.int32(0),
+                       jnp.zeros((max_len,), jnp.int32), jnp.int32(0))
+
+    def run(tokens, cache, pos0, valid, chunk):
+        w = tokens.shape[1]
+        at = pos0 + jnp.arange(w)
+        seen = cache.seen.at[at].add(
+            (jnp.arange(w) < valid[0]).astype(jnp.int32), mode="drop")
+        lg = jnp.broadcast_to(at.astype(jnp.float32)[None, :, None],
+                              (1, w, vocab))
+        return lg, _Counts(cache.passes + 1,
+                           cache.wide + (w if w > chunk else 0), seen,
+                           pos0 + w)
+
+    return types.SimpleNamespace(
+        init_cache=init_cache,
+        prefill=lambda p, t, cfg, c, valid=None: run(t, c, 0, valid, cfg),
+        extend=lambda p, t, cfg, c, lengths=None, valid=None: run(
+            t, c, lengths[0], valid, cfg),
+        write_slot=lambda pool, row, cache: cache)
+
+
+def _admit_counting(chunk, max_len, start=0):
+    """The admission program over :func:`_counting_family`, compiled once:
+    ``n -> (what the family counted, the frontier logits' position)`` for a
+    prompt of ``n`` tokens past ``start``."""
+    from deepspeed_tpu.serving.batcher import admission
+    fam = _counting_family()
+    slots = jnp.zeros((2,), jnp.int32)
+    prefix = () if not start else (fam.init_cache(chunk, 1, max_len),)
+    admit = jax.jit(admission(fam, chunk, max_len, None))
+
+    def of(n):
+        out = admit(
+            None, None, slots, jnp.zeros((2, 4)),
+            jnp.zeros((2, 2), jnp.uint32), slots > 0, jnp.ones((2,)),
+            slots > 0, jnp.zeros((-(-max_len // chunk), chunk), jnp.int32),
+            jnp.array([1, start, n, 1, 0, 0, 0], jnp.int32),
+            jax.random.PRNGKey(0), *prefix)
+        return out[0], int(out[-1][0])
+
+    return of
+
+
+@pytest.mark.parametrize("chunk,max_len,start,widths", [
+    (128, 1024, 0, (256, 128)), (128, 1024, 77, (256, 128)),
+    (64, 1024, 0, (256, 64)), (256, 1024, 0, (256,)),
+    (512, 1024, 0, (512,)), (128, 192, 0, (128,)), (8, 64, 0, (8,)),
+    (8, 64, 5, (8,))])
+def test_the_hosts_pass_count_is_the_programs_trip_counts(chunk, max_len,
+                                                          start, widths):
+    """``ladder_passes`` (what ``serve.prefill`` carries as ``passes`` and
+    ``wide``) against the admission program's own loops, counted by a
+    stand-in family, for prompts on both sides of every boundary of the
+    ladder (a wide pass takes the chunks a prompt is padded to, so its
+    last may hold padding): every real token is computed once, at its own
+    position, the frontier logits are the last one's, and a pass is 256
+    tokens wide where that is a whole multiple of the chunk, wider than it
+    and fits the slot."""
+    from deepspeed_tpu.serving.batcher import ladder_passes, pass_widths
+    assert pass_widths(chunk, max_len) == widths
+    first = 0 if start else 1
+    edges = {1, chunk, max_len - start}
+    for w in widths:
+        for k in (1, 2, 3):
+            edges.update({k * w, first * chunk + k * w})
+    admit = _admit_counting(chunk, max_len, start)
+    for n in sorted({e + d for e in edges for d in (-1, 0, 1)}):
+        if not 0 < n <= max_len - start:
+            continue
+        counted, at = admit(n)
+        passes, wide = ladder_passes(n, widths, first)
+        assert (int(counted.passes), int(counted.wide)) == (passes, wide), n
+        seen = np.asarray(counted.seen)
+        assert (seen[start:start + n] == 1).all() and seen.sum() == n, n
+        assert at == start + n - 1, n
+
+
+def test_a_704_token_document_is_four_passes_not_six():
+    from deepspeed_tpu.serving.batcher import ladder_passes, pass_widths
+    widths = pass_widths(128, 1024)
+    assert widths == (256, 128)
+    assert ladder_passes(704, widths, 1) == (4, 512)
+    assert ladder_passes(704, (128,), 1) == (6, 0)
+    # a wide pass takes the chunks a prompt is PADDED to: 129 tokens past
+    # the first chunk are the two chunks they were, in one pass
+    assert ladder_passes(256, widths, 1) == (2, 0)
+    assert ladder_passes(257, widths, 1) == (2, 256)
+    assert ladder_passes(384, widths, 1) == (2, 256)
+    assert ladder_passes(385, widths, 1) == (3, 256)
+    # continuing a prefix, the wide passes come first
+    assert ladder_passes(704, widths) == (3, 768)
+    # the widths compose: a ladder of two takes the widest first
+    assert ladder_passes(704, (512, 256, 128), 1) == (3, 512)
+    assert ladder_passes(1024, (512, 256, 128), 1) == (4, 768)
+
+
+def _chunk_loops(chunk, max_len, prefix=False):
+    """The traced-trip-count loops of the admission's jaxpr at the top
+    level of the program (a family's own scans lie below)."""
+    from deepspeed_tpu.serving.batcher import admission
+    fam = gpt_inference.DENSE
+    cfg = LONG
+    shapes = jax.eval_shape(lambda: (
+        gpt.init(cfg, jax.random.PRNGKey(0)),
+        fam.init_cache(cfg, 2, max_len), fam.init_cache(cfg, 1, max_len)))
+    params, pool, row = shapes
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    jaxpr = jax.make_jaxpr(admission(fam, cfg, max_len, None))(
+        params, pool, i32(2), f32(2, cfg.padded_vocab),
+        jax.ShapeDtypeStruct((2, 2), jnp.uint32),
+        jax.ShapeDtypeStruct((2,), bool), f32(2),
+        jax.ShapeDtypeStruct((2,), bool),
+        i32(-(-max_len // chunk), chunk), i32(7),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        *((row,) if prefix else ()))
+    return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "while"]
+
+
+@pytest.mark.parametrize("prefix", [False, True], ids=["admit", "prefix"])
+@pytest.mark.parametrize("chunk,max_len,loops", [
+    (128, 1024, 2), (256, 1024, 1), (512, 1024, 1), (1024, 1024, 1),
+    (512, 8192, 1), (1024, 16384, 1), (128, 192, 1), (8, 64, 1)])
+def test_the_ladder_is_absent_where_the_chunk_is_wide_or_the_slot_short(
+        chunk, max_len, loops, prefix):
+    """The admission holds ONE chunk loop at a ``prefill_chunk`` of 256 and
+    more (every serving cell's but GPT-2's, and the ``chunk_widen`` rung's)
+    and in slots shorter than 256 (nearly every test's), as it did before
+    the ladder.  Two at 128 in slots of 1,024."""
+    assert len(_chunk_loops(chunk, max_len, prefix)) == loops
+
+
+@pytest.mark.parametrize("n,cut", [
+    (127, 0), (128, 0), (129, 0), (383, 0), (384, 0), (385, 0), (639, 0),
+    (640, 0), (641, 0), (896, 0), (1024, 0),
+    (77 + 255, 77), (77 + 256, 77), (77 + 257, 77), (77 + 512, 77),
+    (77 + 513, 77), (77 + 700, 77)])
+def test_wide_passes_leave_the_slot_as_the_chunks_they_stand_for(n, cut):
+    """An admission that runs the whole 256-token passes its prompt holds
+    leaves the slot as launches of 128 leave it (``_chunked_prefill``'s
+    programs, ``write_slot`` and the binds): the frontier logits, the row's
+    cache up to the frontier and the six greedy tokens that follow, for
+    prompts on both sides of every boundary and for prompts that continue a
+    prefix whose length is a multiple of no width; no program compiles a
+    second time over all of them."""
+    _same_slot(*_laddered("dense"), n, cut)
+
+
+@pytest.mark.parametrize("family", ["state", "ring"])
+def test_wide_passes_of_a_state_family_and_of_a_ring_family(monkeypatch,
+                                                            family):
+    """The ladder with its widths patched small (32 and 16 over chunks of 8
+    in slots of 64) for a family that keeps state per slot (a wide pass
+    hands the recurrence four chunks' tokens at once) and for one whose
+    window layers keep rings of 16 cells (a 32-token pass is longer than
+    the ring): the slot as launches of 8 leave it, for prompts with and
+    without a whole wide pass, with and without a prefix."""
+    from deepspeed_tpu.serving import batcher
+    monkeypatch.setattr(batcher, "WIDE_PASSES", (32, 16))
+    fused, plain = _laddered(family)
+    assert batcher.pass_widths(fused.chunk, fused.max_len) == (32, 16, 8)
+    cuts = (0,) if fused.unsupported("prefix") else (0, 5)
+    for n in (7, 23, 24, 25, 40, 41, 57, 64):
+        for cut in cuts:
+            if n - cut > 0 and (cut == 0 or n > 8):
+                _same_slot(fused, plain, n, cut, tol=2e-3)
 
 
 def test_the_fold_inside_the_admission_is_the_hosts_fold():

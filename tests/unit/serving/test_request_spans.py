@@ -61,13 +61,16 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
 
     # admission's one child, where the work happens: ``serve.prefill``
     # around the ONE launch that runs the chunks, writes the slot and binds
-    # the row, with the arguments it always had.  No batch-1 cache is
-    # allocated by the host, no chunk is a launch of its own and the slot
-    # write is no step of its own: their spans are not entered
+    # the row, with the arguments it always had and the passes the program
+    # ran for them (in a slot of 64 none is wider than the chunk).  No
+    # batch-1 cache is allocated by the host, no chunk is a launch of its
+    # own and the slot write is no step of its own: their spans are not
+    # entered
     prefill = one("serve.prefill")
     assert _inside(prefill, admit)
     assert prefill.args == {"tokens": CHUNK + 1, "start": 0, "chunk": CHUNK,
-                            "padded": 2 * CHUNK, "chunks": 2}
+                            "padded": 2 * CHUNK, "chunks": 2, "passes": 2,
+                            "wide": 0}
     assert [s.name for s in spans if _inside(s, admit)] == ["serve.prefill"]
     for name in ("serve.cache_alloc", "serve.prefill_chunk",
                  "serve.slot_write"):
@@ -121,9 +124,10 @@ def test_requests_keep_their_own_ids_and_only_a_prefix_build_runs_chunk_by_chunk
     admits = by("serve.admit")
     build, first, second = by("serve.prefill")
     assert [(p.args["tokens"], p.args["start"], p.args["padded"],
-             p.args["chunks"]) for p in (build, first, second)] == [
-        (2 * CHUNK, 0, 2 * CHUNK, 2), (3, 2 * CHUNK, CHUNK, 1),
-        (5, 2 * CHUNK, CHUNK, 1)]
+             p.args["chunks"], p.args["passes"], p.args["wide"])
+            for p in (build, first, second)] == [
+        (2 * CHUNK, 0, 2 * CHUNK, 2, 2, 0), (3, 2 * CHUNK, CHUNK, 1, 1, 0),
+        (5, 2 * CHUNK, CHUNK, 1, 1, 0)]
     assert _inside(build, admits[0]) and _inside(first, admits[0])
     assert _inside(second, admits[1])
     # the builder's children: the batch-1 cache it allocates and a launch a
@@ -139,6 +143,36 @@ def test_requests_keep_their_own_ids_and_only_a_prefix_build_runs_chunk_by_chunk
     assert by("serve.slot_write") == []
     snap = gw.snapshot()
     assert (snap["admitted"], snap["admit_launches"]) == (2, 2 + 1 + 1)
+
+
+def test_the_prefill_span_counts_the_ladders_passes(engine, monkeypatch):
+    """``serve.prefill`` carries ``passes`` (the chunk passes the admission
+    program ran) and ``wide`` (the tokens of its passes wider than
+    ``chunk``) beside the arguments it had, which keep their meaning: with
+    the wide widths patched to 32 and 16 over chunks of 8, a prompt of 63
+    tokens is a first chunk, 32, 16 and a padded 8 where it was 8 chunks;
+    one that continues a pooled prefix starts with its wide passes; the
+    prefix's builder runs a launch a chunk, none wide."""
+    from deepspeed_tpu.serving import batcher
+    monkeypatch.setattr(batcher, "WIDE_PASSES", (32, 16))
+    tracer = Tracer(name="serving")
+    gw = engine.serve(config=SERVING, tracer=tracer)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, 256, (n,)).astype(np.int32)
+               for n in (CHUNK + 1, 24, 40, 63)]
+    for p in prompts:
+        gw.submit(p, max_new_tokens=1).result(timeout=120)
+    gw.submit(prompts[-1][:60], max_new_tokens=1,
+              prefix_len=CHUNK + 3).result(timeout=120)
+    gw.shutdown()
+    spans = [s.args for s in tracer.spans() if s.name == "serve.prefill"]
+    assert [(a["tokens"], a["start"], a["chunk"], a["padded"], a["chunks"],
+             a["passes"], a["wide"]) for a in spans] == [
+        (9, 0, 8, 16, 2, 2, 0), (24, 0, 8, 24, 3, 2, 16),
+        (40, 0, 8, 40, 5, 2, 32), (63, 0, 8, 64, 8, 4, 48),
+        (11, 0, 8, 16, 2, 2, 0),        # the prefix, built chunk by chunk
+        (49, 11, 8, 56, 7, 3, 48)]      # 32, 16 and one token in a chunk
+    assert gw.snapshot()["recompiles"] == 0
 
 
 def test_a_gateway_without_a_tracer_serves_and_keeps_no_record(engine):
