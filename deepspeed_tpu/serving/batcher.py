@@ -807,9 +807,11 @@ class SlotBatcher:
         # a fresh row's first chunk is the family's ``prefill``, at ``C``
         passes, wide = ladder_passes(S, pass_widths(C, self.max_len),
                                      first=0 if prefix is not None else 1)
-        with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S, start=start,
-                              chunk=C, padded=n_chunks * C, chunks=n_chunks,
-                              passes=passes, wide=wide):
+        # what the launch computes, for its host span and its device span
+        work = dict(tokens=S, chunk=C, padded=n_chunks * C, passes=passes,
+                    wide=wide)
+        with self.tracer.span(SpanName.SERVE_PREFILL, start=start,
+                              chunks=n_chunks, **work):
             (self.cache, self.lengths, self._last, self.keys, self.greedy,
              self.temp, self.active, vec) = self._launch(
                 program,
@@ -817,6 +819,9 @@ class SlotBatcher:
                 self.keys, self.greedy, self.temp, self.active,
                 self._padded_chunks(new, C, -(-self.max_len // C)), meta,
                 key, *(() if prefix is None else (prefix.cache,)))
+            # ``serve.device``: ``vec``, the launch's one logits row, is an
+            # output that nothing later donates
+            self.registry.watch(program, vec, slot=row, **work)
         if self.spec:
             # lockstep draft admission: the draft prefills the FULL
             # prompt (prefix/readmit shortcuts spare only target work
@@ -956,6 +961,8 @@ class SlotBatcher:
             self._p["tick"](
                 self._engine.params, self.cache, self.lengths, self._last,
                 self.keys, self.greedy, self.temp, self.active)
+        # a tick's tokens are pulled, never donated: safe to wait on
+        self.registry.watch("tick", nxt)
         return nxt
 
     def _spec_launch(self):
@@ -981,6 +988,9 @@ class SlotBatcher:
                 vlg, drafts, d_probs, round_keys, self.cur,
                 self.lengths, self.greedy, self.temp, self.active)
             self.keys = next_keys
+        # the round's device time ends with its last program's counts
+        self.registry.watch("spec_accept" + sfx, adv,
+                            draft_k=self.round_draft_k)
         return window, adv
 
     def _paused_launch(self):
@@ -998,4 +1008,5 @@ class SlotBatcher:
             self._engine.params, self.cache, self.cur, self.lengths,
             self.active)
         self._paused = True
+        self.registry.watch("spec_flush", nxt)
         return nxt

@@ -443,6 +443,9 @@ class ServingGateway:
             if self._thread.is_alive():
                 logger.warning("[serving] scheduler thread did not stop "
                                f"within {join_s:.1f}s")
+        # the last launches' ``serve.device`` spans, for whoever reads the
+        # tracer next, and the watcher's end (nothing while the tracer is off)
+        self._batcher.registry.watch_stop(timeout=10.0)
         self._pull_compile_stats()
         self._watch.close()   # journals perf.host_sync totals
 

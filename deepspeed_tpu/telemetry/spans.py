@@ -164,6 +164,15 @@ class SpanName:
     #: end of admission -> the tick that harvested the request's first
     #: token (recorded; rid in args)
     SERVE_FIRST_TOKEN = "serve.first_token"
+    #: one admission's or one tick's launch ON THE DEVICE (recorded by the
+    #: registry's watcher thread, ``CompiledProgramRegistry.watch``; every
+    #: other span is the host's): from the later of the launch's dispatch
+    #: and its predecessor's completion to its own completion, on this
+    #: clock.  program and waited (seconds queued behind its predecessors)
+    #: in args; of an admission also serve.prefill's tokens, padded, passes,
+    #: wide and chunk, and slot.  Launches of one registry never overlap;
+    #: what ran unwatched between two of them (release) falls to the later
+    SERVE_DEVICE = "serve.device"
     #: restoring a tiered session's KV for a follow-up turn (gather or
     #: host rehydrate + remainder prefill)
     SERVE_READMIT = "serve.readmit"

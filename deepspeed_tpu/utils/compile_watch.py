@@ -28,7 +28,11 @@ Three pieces:
   description of each program's last compile (shapes, no memory) and can
   lower it again into its **op map**: the named scope of each optimised-HLO
   instruction, published for ``telemetry.device_time`` while the owner's
-  span tracer is on, or on demand (``telemetry/op_maps.py``).
+  span tracer is on, or on demand (``telemetry/op_maps.py``).  While that
+  tracer is on it also stamps when the device finished each launch its owner
+  asks it to :meth:`~CompiledProgramRegistry.watch`, from one daemon thread,
+  and records the launch's time ON THE DEVICE as a ``serve.device`` span
+  (:func:`device_span`).
 - :class:`CompileWatch` — a context manager over one or more registries:
   snapshot, warm up, then any further compile is a *recompile* — reported
   by :meth:`CompileWatch.check`, journaled as a ``perf.recompile`` event
@@ -44,16 +48,19 @@ compile counts/seconds are a diffable per-PR artifact.
 
 from __future__ import annotations
 
+import queue
+import threading
 import time
 import weakref
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Sequence, Union
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 from .lock_watch import LockName, TrackedLock
 
 __all__ = [
     "hot_path", "CompileEvent", "CompiledProgramRegistry", "CompileWatch",
-    "RecompileError", "registries",
+    "RecompileError", "registries", "device_span",
 ]
 
 #: every live registry (weak: a registry dies with its owner)
@@ -175,6 +182,49 @@ class _WrappedProgram:
         return out
 
 
+def device_span(t_dispatch: float, completed: float,
+                prev_completed: Optional[float]) -> Tuple[float, float, float]:
+    """``(start, dur, waited)`` of one launch on the device, on the clock of
+    its three stamps.  The device runs a process's launches in order, so a
+    launch begins at the later of its own dispatch (``t_dispatch``: the
+    host's clock when the call returned) and its predecessor's completion
+    (``prev_completed``; None for the first), and ends at ``completed``;
+    ``waited`` is how long it stood queued behind its predecessors.  A
+    completion stamped late (the watcher waits for the interpreter's lock)
+    lengthens its launch and shortens the next by as much, so launches of
+    one chain never overlap and their sum holds.  What the stamps cannot
+    tell apart: while the whole process stands still (the host's scheduler
+    took its cores away for a tenth of a second) the launch in flight
+    reads that much longer, whether the device worked or waited."""
+    start = t_dispatch if prev_completed is None \
+        else max(t_dispatch, prev_completed)
+    return start, completed - start, start - t_dispatch
+
+
+def _stamp_launches(registry_ref, launches: "queue.SimpleQueue") -> None:
+    """The watcher thread of one registry: waits for each handed-over
+    launch's output, in launch order, and has the registry record its
+    ``serve.device`` span.  It holds the registry only while it stamps, and
+    ends when handed None (the registry was collected)."""
+    import jax
+    while True:
+        item = launches.get()
+        if item is None:
+            return
+        program, t_dispatch, out, args = item
+        try:
+            jax.block_until_ready(out)
+            done = time.monotonic()
+        except Exception:  # dslint: disable=swallowed-exception — a lost or deleted output drops its stamp (counted by _stamp), never a run
+            done = None
+        del item, out
+        reg = registry_ref()
+        if reg is None:
+            return
+        reg._stamp(program, t_dispatch, done, args)
+        del reg
+
+
 class CompiledProgramRegistry:
     """Every jitted program an owner drives, by name.
 
@@ -202,6 +252,16 @@ class CompiledProgramRegistry:
         self._events: List[CompileEvent] = []
         self._compile_s: Dict[str, float] = {}
         self._host_syncs: Dict[str, int] = {}
+        #: launches handed to the watcher thread, which is started by the
+        #: first :meth:`watch` under an enabled tracer and never otherwise
+        self._launches: Optional["queue.SimpleQueue"] = None
+        self._watcher: Optional[threading.Thread] = None
+        self._watcher_end: Optional[weakref.finalize] = None
+        #: watched launches whose output was lost or deleted before its
+        #: completion could be stamped (beside ``Tracer.dropped``)
+        self.device_spans_lost = 0
+        #: when the device finished the last stamped launch
+        self._prev_done: Optional[float] = None
 
     # ---------------------------------------------------------- programs
     def register(self, name: str, prog) -> _WrappedProgram:
@@ -275,6 +335,73 @@ class CompiledProgramRegistry:
         with self._lock:
             names = list(self._last_call)
         return [n for n in names if self._publish(n)]
+
+    # ------------------------------------------------------- device spans
+    def watch(self, program: str, out, **args: Any) -> None:
+        """Note that ``program`` was just launched (call this as its call
+        returns) and have its time on the device recorded as a
+        ``serve.device`` span of the owner's tracer, with ``args``.  ``out``
+        is an output of the launch that NO later launch donates: the owner
+        says which, the registry does not guess.  Call in launch order from
+        the thread that launches (call order is taken for device order).
+        The wait for ``out`` is the watcher thread's, not a host sync of the
+        caller's.  With the tracer disabled this is one attribute read: no
+        thread, no clock, nothing kept."""
+        tracer = self.tracer
+        if tracer is None or not tracer.enabled:
+            return
+        t_dispatch = time.monotonic()
+        launches = self._launches
+        if launches is None:
+            launches = self._start_watcher()
+        launches.put((program, t_dispatch, out, args))
+
+    def _start_watcher(self) -> "queue.SimpleQueue":
+        with self._lock:
+            if self._launches is None:
+                launches: "queue.SimpleQueue" = queue.SimpleQueue()
+                self._watcher = threading.Thread(
+                    target=_stamp_launches, daemon=True,
+                    name=f"device-spans:{self.name}",
+                    args=(weakref.ref(self), launches))
+                self._watcher.start()
+                # the thread outlives no registry: it holds this one weakly
+                self._watcher_end = weakref.finalize(self, launches.put, None)
+                self._launches = launches
+            return self._launches
+
+    def watch_stop(self, timeout: float = 10.0) -> bool:
+        """Have every launch watched so far stamped (or counted lost), then
+        end the watcher thread, waiting at most ``timeout`` seconds for it;
+        False if it still runs.  For the owner's shutdown and for readers of
+        the spans after the last launch (call it once the launching thread
+        has stopped), never for a hot path; a later :meth:`watch` starts
+        another thread."""
+        with self._lock:
+            launches, thread = self._launches, self._watcher
+            self._launches = self._watcher = None
+        if launches is None:
+            return True
+        self._watcher_end.detach()
+        launches.put(None)      # behind what is pending
+        thread.join(timeout=timeout)
+        return not thread.is_alive()
+
+    def _stamp(self, program: str, t_dispatch: float,
+               done: Optional[float], args: Dict[str, Any]) -> None:
+        """Record the ``serve.device`` span of the launch dispatched at
+        ``t_dispatch`` that the device finished at ``done`` (the watcher
+        thread's call, in launch order).  ``done`` None: its output was
+        lost; what it took falls to the next watched launch, as an
+        unwatched program's does."""
+        from ..telemetry.spans import SpanName
+        if done is None:
+            self.device_spans_lost += 1
+            return
+        start, dur, waited = device_span(t_dispatch, done, self._prev_done)
+        self._prev_done = done
+        self.tracer.record(SpanName.SERVE_DEVICE, start, dur,
+                           program=program, waited=waited, **args)
 
     # ------------------------------------------------------------ queries
     def counts(self) -> Dict[str, int]:

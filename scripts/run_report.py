@@ -37,6 +37,7 @@ from __future__ import annotations
 import argparse
 import glob
 import json
+import math
 import os
 import sys
 import tempfile
@@ -55,6 +56,41 @@ _SERVING_FIELDS = ("queue_depth", "occupancy", "live_block_share",
 #: events (the newest one is read)
 _SERVING_TICK_FIELDS = ("overlap_share", "late_row_share",
                         "launches_per_admission")
+
+
+def device_span_table(trace_events) -> dict:
+    """``program -> {n, p50_ms, p95_ms, us_per_padded_token, share}`` of a
+    trace's ``serve.device`` events (``docs/telemetry.md`` "Device spans":
+    each admission's and each tick's launch as the device ran it): launches,
+    the median and the 95th percentile of their device time, of admissions
+    the device time a computed prompt token (``padded``), and the program's
+    share of the run (the first such span's start to the last one's end).
+    Empty where the trace holds none (a tracer that was off, an older
+    program)."""
+    spans = [e for e in trace_events if isinstance(e, dict)
+             and e.get("ph") == "X" and e.get("name") == "serve.device"]
+    if not spans:
+        return {}
+    run_us = max(e["ts"] + e["dur"] for e in spans) - min(
+        e["ts"] for e in spans)
+    by_program = {}
+    for e in spans:
+        by_program.setdefault((e.get("args") or {}).get("program", "?"),
+                              []).append(e)
+    # nearest rank, as ``telemetry.metrics.Histogram.percentile``
+    rank = lambda xs, q: xs[max(0, math.ceil(q * len(xs)) - 1)]
+    table = {}
+    for program, evs in sorted(by_program.items()):
+        durs = sorted(e["dur"] for e in evs)
+        padded = sum((e.get("args") or {}).get("padded", 0) for e in evs)
+        table[program] = {
+            "n": len(evs),
+            "p50_ms": rank(durs, 0.5) / 1e3,
+            "p95_ms": rank(durs, 0.95) / 1e3,
+            "us_per_padded_token": round(sum(durs) / padded, 3)
+            if padded else None,
+            "share": round(sum(durs) / max(run_us, 1), 4)}
+    return table
 
 
 def report(args) -> int:
@@ -170,7 +206,8 @@ def report(args) -> int:
             for e in spans:
                 names[e.get("name")] = names.get(e.get("name"), 0) + 1
             out["trace"] = {"spans": len(spans), "by_name": names,
-                            "schema_problems": schema}
+                            "schema_problems": schema,
+                            "device_by_program": device_span_table(spans)}
             problems.extend(f"trace: {p}" for p in schema)
 
     out["problems"] = problems
@@ -211,6 +248,18 @@ def report(args) -> int:
         if "trace" in out:
             print(f"  trace: {out['trace']['spans']} spans over "
                   f"{len(out['trace']['by_name'])} names")
+            device = out["trace"]["device_by_program"]
+            if device:
+                # the serving device spans: what each admission's and each
+                # tick's launch took ON THE DEVICE (serve.device)
+                print("    serving, device time by program (serve.device): "
+                      "launches, p50 ms, p95 ms, us a padded token, share "
+                      "of the run")
+                for program, r in device.items():
+                    print(f"      {program}: {r['n']}, {r['p50_ms']:.3f}, "
+                          f"{r['p95_ms']:.3f}, "
+                          f"{r['us_per_padded_token'] or '-'}, "
+                          f"{100 * r['share']:.2f}%")
         for p in problems:
             print(f"  PROBLEM: {p}", file=sys.stderr)
     return 1 if problems else 0

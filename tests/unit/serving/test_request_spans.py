@@ -213,3 +213,57 @@ def test_probe_logits_matches_the_full_forward_pass(engine):
         gw.probe_logits(prompts, 1)
     gw.shutdown()
     assert gw.probe_logits(prompts[:1], 1)[0] == [replies[0][:1]]
+
+
+def _device_watchers(but=()):
+    import threading
+    return [t for t in threading.enumerate()
+            if t.name.startswith("device-spans:") and t not in but]
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["tracer_on", "tracer_off"])
+def test_every_admission_and_every_tick_leaves_a_device_span(engine, on):
+    """``serve.device``: one span a launch, the launch's time on the device
+    as the registry's watcher reconstructs it, apart from every thread's
+    phases; with the tracer off no watcher thread is started at all."""
+    before = _device_watchers()
+    tracer = Tracer(enabled=on, name="serving")
+    gw = engine.serve(config=SERVING, tracer=tracer)
+    rng = np.random.default_rng(3)
+    handles = [gw.submit(rng.integers(1, 256, (n,)).astype(np.int32),
+                         max_new_tokens=3) for n in (5, CHUNK + 1, 20, 3)]
+    for h in handles:
+        h.result(timeout=120)
+    started = _device_watchers(before)
+    gw.shutdown()               # waits for the last launches' stamps
+    snap = gw.snapshot()
+    if not on:
+        assert started == [] and tracer.spans() == []
+        assert gw._batcher.registry.device_spans_lost == 0
+        return
+    assert [t.name for t in started] == ["device-spans:serving"]
+    spans = tracer.spans()
+    device = sorted((s for s in spans if s.name == "serve.device"),
+                    key=lambda s: s.t0)
+    assert all(s.thread == WAIT_THREAD and s.wait and s.depth == 0
+               and s.dur >= 0 and s.args["waited"] >= 0 for s in device)
+    # launches of one registry never overlap: the device runs them in order
+    for a, b in zip(device, device[1:]):
+        assert a.t0 + a.dur <= b.t0
+    admits = [s for s in device if s.args["program"] == "admit"]
+    ticks = [s for s in device if s.args["program"] == "tick"]
+    assert len(admits) + len(ticks) == len(device)
+    assert len(admits) == snap["admitted"] == 4
+    assert len(ticks) == snap["ticks"] > 0
+    # an admission's span carries ``serve.prefill``'s counts and its slot
+    prefills = sorted((s for s in spans if s.name == "serve.prefill"),
+                      key=lambda s: s.t0)
+    keys = ("tokens", "padded", "passes", "wide", "chunk")
+    assert [[a.args[k] for k in keys] for a in admits] == [
+        [p.args[k] for k in keys] for p in prefills]
+    assert [a.args["padded"] for a in admits] == [8, 16, 24, 8]
+    assert {a.args["slot"] for a in admits} <= {0, 1}
+    # it begins no earlier than the host's launch of it could have returned
+    for a, p in zip(admits, prefills):
+        assert p.t0 <= a.t0 + a.dur and a.t0 >= p.t0
+    assert gw._batcher.registry.device_spans_lost == 0

@@ -127,11 +127,12 @@ def test_serving_session_emits_spans_with_zero_recompiles(tmp_path):
     assert snap["recompiles"] == 0
     # an admission is one launch inside serve.prefill: no cache_alloc,
     # prefill_chunk or slot_write of its own (they stay with build_prefix
-    # and the draft's lockstep admission)
+    # and the draft's lockstep admission); each launch's time on the
+    # device is the registry's serve.device
     assert set(tracer.span_inventory()) == {
         SpanName.SERVE_QUEUE, SpanName.SERVE_ADMIT, SpanName.SERVE_PREFILL,
         SpanName.SERVE_TICK, SpanName.SERVE_PULL, SpanName.SERVE_HARVEST,
-        SpanName.SERVE_FIRST_TOKEN}
+        SpanName.SERVE_FIRST_TOKEN, SpanName.SERVE_DEVICE}
     assert snap["launches_per_admission"] == 1.0
     # tick spans: one per decode tick; admits: one per request
     agg = tracer.aggregates()
