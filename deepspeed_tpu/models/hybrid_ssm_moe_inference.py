@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..moe.held_experts import n_pair_counts
 from ..ops.pallas import ssm
 from . import gpt_inference, hybrid_ssm_moe as model
 from .gpt_inference import KVCache
@@ -71,7 +72,7 @@ def stats_groups(config: HybridSSMMoEConfig) -> Dict[str, slice]:
     """Where each group of this family's device counters lies in
     ``cache.stats``: the one place that knows.  ``moe_pairs``: the expert
     layer's ``pair_counts``; ``state_steps``: ``STATE_COUNTERS``."""
-    pairs = 3 + len(config.held)
+    pairs = n_pair_counts(len(config.held))
     return {"moe_pairs": slice(0, pairs),
             "state_steps": slice(pairs, pairs + len(STATE_COUNTERS))}
 

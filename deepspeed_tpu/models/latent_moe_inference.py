@@ -11,8 +11,9 @@ what ``gpt_inference.Family`` asks of a model family:
   whatever the number of heads;
 - the **step**: two segments, the leading dense layers (``first_k_dense``)
   and the expert layers, layers in depth order in the one pool; the expert
-  layers add their pair counts to ``cache.stats`` (``[3 + n_held]`` int32:
-  pairs held here, pairs routed, expert visits, pairs per held expert);
+  layers add their pair counts to ``cache.stats`` (``[4 + n_held]`` int32:
+  pairs held here, pairs routed, expert visits, pairs per held expert, pages
+  of pairs run beyond a call's first);
 - projections and attention in the absorbed form, every pass through
   ``ops/pallas/decode_attention.py``'s latent kernels (a prompt pass is a
   chunk at position 0: the expert layer's cost is linear in a call's
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 
 from . import gpt_inference, latent_moe
 from .gpt_inference import KVCache
+from ..moe.held_experts import n_pair_counts
 from .latent_moe import LatentMoEConfig
 
 PyTree = Any
@@ -54,7 +56,7 @@ def stats_groups(config: LatentMoEConfig) -> Dict[str, slice]:
     ``cache.stats``: the expert layers' ``pair_counts`` and nothing else."""
     if not config.n_moe_layers:
         return {}
-    return {"moe_pairs": slice(0, 3 + len(config.held))}
+    return {"moe_pairs": slice(0, n_pair_counts(len(config.held)))}
 
 
 #: the routed experts' matrices: never an ``xs`` of the layer scan (a slice

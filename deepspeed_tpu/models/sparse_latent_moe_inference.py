@@ -39,6 +39,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from ..moe.held_experts import n_pair_counts
 from . import gpt_inference, sparse_latent_moe as model
 from .gpt_inference import KVCache
 from .hybrid_ssm_moe import run_parts
@@ -84,7 +85,7 @@ def stats_groups(config: SparseLatentMoEConfig) -> Dict[str, slice]:
     """Where each group of this family's device counters lies in
     ``cache.stats``: the expert layers' ``pair_counts``, then
     ``SELECT_COUNTERS``."""
-    pairs = 3 + len(config.held)
+    pairs = n_pair_counts(len(config.held))
     return {"moe_pairs": slice(0, pairs),
             "sparse_select": slice(pairs, pairs + len(SELECT_COUNTERS))}
 

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from . import gpt_inference, window_moe as model
 from .gpt_inference import KVCache
 from .hybrid_ssm_moe import run_parts
+from ..moe.held_experts import n_pair_counts
 from .window_moe import FULL, ROUTED, WINDOW, WindowMoEConfig
 
 PyTree = Any
@@ -66,7 +67,7 @@ SCOPES = {WINDOW: "window_attention", FULL: "full_attention"}
 def stats_groups(config: WindowMoEConfig) -> Dict[str, slice]:
     """Where each group of this family's device counters lies in
     ``cache.stats``: the expert layers' ``pair_counts`` and nothing else."""
-    return {"moe_pairs": slice(0, 3 + len(config.held))}
+    return {"moe_pairs": slice(0, n_pair_counts(len(config.held)))}
 
 
 def _step(params: PyTree, config: WindowMoEConfig, valid):

@@ -19,10 +19,14 @@ The held pairs are multiplied grouped and dropless.  Pairs are sorted by the
 local index of their expert, held ones first, and the rows of each held
 expert meet that expert's matrices in one grouped product: on a TPU the
 Pallas grouped matmul that ships with JAX (``megablox.gmm``), whose grid is
-the row tiles of the live groups, so an expert's matrices are streamed once
-and the rows of pairs held elsewhere cost no step; elsewhere
-``lax.ragged_dot``, the same product.  No ``[tokens, experts, capacity]``
-tensor exists and the cost is linear in the tokens of a call.
+the row tiles of the live groups, so an expert's matrices are streamed once;
+elsewhere ``lax.ragged_dot``, the same product.  The pairs held elsewhere
+cost nothing around the products either: the held ones are brought together
+in a page of ``pairs_cap`` rows (twice a uniform router's share of the
+call), and a call that holds more runs further pages, so nothing is dropped
+whatever the routing.  No ``[tokens, experts, capacity]`` tensor and no
+``[tokens x k, width]`` array exists, and the cost is linear in the pairs
+held here.
 """
 
 from __future__ import annotations
@@ -128,16 +132,33 @@ def _grouped(rows, w, group_sizes, layer=None):
     return lax.ragged_dot(rows, w.astype(rows.dtype), group_sizes)
 
 
-def pair_counts(per_expert, routed: int):
-    """An expert layer call's counters ``[3 + n_held]`` int32 from the pairs
-    each held expert took: pairs held here, pairs routed in all, held
-    experts that took at least one pair (each streams its matrices once),
-    pairs per held expert."""
+def n_pair_counts(n_held: int) -> int:
+    """The length of ``pair_counts``' vector for ``n_held`` held experts."""
+    return 4 + n_held
+
+
+def pair_counts(counts, routed: int):
+    """An expert layer call's counters ``[n_pair_counts(n_held)]`` int32 from
+    ``held_experts_ffn``'s ``counts``: pairs held here, pairs routed in all,
+    held experts that took at least one pair (each streams its matrices
+    once), pairs per held expert, and last the pages of pairs the call ran
+    beyond its first (``pairs_cap``)."""
+    per_expert = counts[:-1]
     return jnp.concatenate([
         jnp.sum(per_expert, keepdims=True),
         jnp.full((1,), routed, jnp.int32),
         jnp.sum(per_expert > 0, keepdims=True, dtype=jnp.int32),
-        per_expert])
+        counts])
+
+
+def pairs_cap(n_pairs: int, n_held: int, n_experts: int) -> int:
+    """Rows of the buffer the held pairs of a call are brought together in:
+    twice the pairs a uniform router sends to ``n_held`` of ``n_experts``,
+    on the grouped product's row tile, and never more than all
+    ``n_pairs``.  A call that holds more runs further pages of as many."""
+    mean = -(-n_pairs * n_held // n_experts)
+    tile = GMM_TILING[0]
+    return min(n_pairs, -(-2 * mean // tile) * tile)
 
 
 def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
@@ -149,34 +170,76 @@ def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
     ``RELU2``: ``p["w_up"]`` [n_held, d, f]) and ``p["w_down"]`` [n_held, f,
     d], the held experts' matrices in the order of ``held``; with ``layer``
     (a layer scan's index) both are the whole stacks ``[layers, n_held,
-    ...]``, read in place.  Returns ``(out [T, d], counts [n_held] int32)``:
-    ``sum_{i in sel, i held} w_i E_i(h)`` and the pairs each held expert
-    took."""
+    ...]``, read in place.  Returns ``(out [T, d], counts [n_held + 1]
+    int32)``: ``sum_{i in sel, i held} w_i E_i(h)``; the pairs each held
+    expert took and, last, the pages run beyond the first.
+
+    Of the ``T * k`` pairs routed anywhere only those held here are
+    multiplied, so only they are moved: sorted by expert they fill the
+    first rows of a page of ``P = pairs_cap(...)`` rows, and the row gather,
+    both grouped products, the activation, the weighting and the way back
+    to tokens (a 0/1 matrix ``[T, P]`` times the weighted rows, accumulated
+    in float32: exact, and its cost follows ``P`` where a gather's follows
+    ``T * k``) all run on ``P`` rows; what is kept a pair of the whole call
+    is its expert's index, its place and its weight.  Dropless for any
+    routing: a call that holds more than ``P`` pairs runs ``ceil(held /
+    P)`` pages through the one body, each with the group sizes clipped to
+    its range and its part added to the result."""
     T, d = h.shape
     k = routing.experts.shape[1]
-    n_held = len(held)
-    slot = jnp.asarray(local_slots(held, n_experts))[routing.experts]  # [T,k]
-    flat = slot.reshape(-1)
-    # held pairs first, by expert: a stable sort keeps tokens in order
-    order = jnp.argsort(flat, stable=True)
-    counts = jnp.sum(flat[:, None] == jnp.arange(n_held)[None, :], axis=0,
-                     dtype=jnp.int32)
+    n_held, n_pairs = len(held), T * k
+    P = pairs_cap(n_pairs, n_held, n_experts)
+    flat = jnp.asarray(local_slots(held, n_experts))[routing.experts
+                                                     ].reshape(-1)
     with jax.named_scope("moe_routed"):
-        rows = h[order // k]                                   # [T*k, d]
-        if form == RELU2:
-            up = _grouped(rows, p["w_up"], counts, layer)
-            act = jnp.square(jax.nn.relu(up.astype(jnp.float32))
-                             ).astype(h.dtype)
+        # held pairs first, by expert: a stable sort keeps tokens in order,
+        # and a pair's weight rides with it (a gather of scalars costs more)
+        _, order, weights = lax.sort(
+            (flat, jnp.arange(n_pairs, dtype=jnp.int32),
+             jnp.where(flat < n_held, routing.weights.reshape(-1), 0.0)),
+            num_keys=1, is_stable=True)
+        counts = jnp.sum(flat[:, None] == jnp.arange(n_held)[None, :],
+                         axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        n_here = ends[-1]
+        # whole pages to slice
+        token = jnp.pad(order // k, (0, -n_pairs % P))
+        weights = jnp.pad(weights, (0, -n_pairs % P))
+
+        def page(at, out):
+            """Rows ``[at, at + P)`` of the sorted pairs, added to ``out``
+            [T, d] float32."""
+            of = lax.dynamic_slice(token, (at,), (P,))
+            w = lax.dynamic_slice(weights, (at,), (P,))
+            sizes = jnp.clip(jnp.minimum(ends, at + P)
+                             - jnp.maximum(ends - counts, at), 0)
+            rows = h[of]                                         # [P, d]
+            if form == RELU2:
+                up = _grouped(rows, p["w_up"], sizes, layer)
+                act = jnp.square(jax.nn.relu(up.astype(jnp.float32))
+                                 ).astype(h.dtype)
+            else:
+                gu = _grouped(rows, p["w_gu"], sizes, layer)
+                f = gu.shape[-1] // 2
+                act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+                       * gu[:, f:].astype(jnp.float32)).astype(h.dtype)
+            y = _grouped(act, p["w_down"], sizes, layer)         # [P, d]
+            # a row past the held pairs weighs nothing, whatever came back
+            y = jnp.where(w[:, None] != 0,
+                          y.astype(jnp.float32) * w[:, None], 0.0
+                          ).astype(h.dtype)
+            # back to tokens: each token's rows summed in float32
+            mine = of[None, :] == jnp.arange(T)[:, None]
+            return out + jnp.dot(mine.astype(h.dtype), y,
+                                 precision=lax.Precision.HIGHEST,
+                                 preferred_element_type=jnp.float32)
+
+        out = jnp.zeros((T, d), jnp.float32)
+        if P == n_pairs:            # one page holds whatever lands here
+            out = page(0, out)
         else:
-            gu = _grouped(rows, p["w_gu"], counts, layer)
-            f = gu.shape[-1] // 2
-            act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-                   * gu[:, f:].astype(jnp.float32)).astype(h.dtype)
-        y = _grouped(act, p["w_down"], counts, layer)              # [T*k, d]
-        w = jnp.where(flat < n_held, routing.weights.reshape(-1), 0.0)[order]
-        y = jnp.where(w[:, None] != 0, y.astype(jnp.float32) * w[:, None],
-                      0.0).astype(h.dtype)
-        # back to (token, choice) order, then each token's choices summed
-        back = jnp.argsort(order)
-        out = y[back].reshape(T, k, d).astype(jnp.float32).sum(axis=1)
-    return out.astype(h.dtype), counts
+            _, out = lax.while_loop(
+                lambda c: c[0] < n_here,
+                lambda c: (c[0] + P, page(c[0], c[1])), (jnp.int32(0), out))
+    more = jnp.maximum(-(-n_here // P) - 1, 0)
+    return out.astype(h.dtype), jnp.concatenate([counts, more[None]])

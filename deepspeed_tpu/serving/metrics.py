@@ -108,6 +108,10 @@ class ServingMetrics:
         #: streams that expert's matrices once
         self.moe_expert_visits = 0
         self.moe_expert_pairs: list = []
+        #: pages of held pairs that expert layer calls ran beyond their
+        #: first (``held_experts.pairs_cap``): 0 unless a call held more
+        #: than twice a uniform router's share
+        self.moe_pages_over_cap = 0
         #: a per-slot-state family's device counters, by name (state rows
         #: stepped, real and padded tokens through the chunk scan):
         #: cumulative, as of the last harvested tick
@@ -196,12 +200,14 @@ class ServingMetrics:
 
     def record_moe_pairs(self, counts) -> None:
         """``counts``: the batcher's cumulative group ``moe_pairs``, ``[held,
-        routed, visits, pairs of each held expert...]``."""
+        routed, visits, pairs of each held expert..., pages over the cap]``
+        (``moe.held_experts.pair_counts``)."""
         with self._lock:
             self.moe_pairs_held = int(counts[0])
             self.moe_pairs_routed = int(counts[1])
             self.moe_expert_visits = int(counts[2])
-            self.moe_expert_pairs = [int(c) for c in counts[3:]]
+            self.moe_expert_pairs = [int(c) for c in counts[3:-1]]
+            self.moe_pages_over_cap = int(counts[-1])
 
     def record_state_steps(self, counts: dict) -> None:
         """``counts``: name -> cumulative count, the batcher's group
@@ -307,6 +313,7 @@ class ServingMetrics:
                 "moe_pairs_routed": self.moe_pairs_routed,
                 "moe_expert_visits": self.moe_expert_visits,
                 "moe_expert_pairs": list(self.moe_expert_pairs),
+                "moe_pages_over_cap": self.moe_pages_over_cap,
                 "state_steps": dict(self.state_steps),
                 "sparse_select": dict(self.sparse_select),
                 # the busiest held expert's pairs over the mean's

@@ -425,6 +425,30 @@ def _layer_elements(cfg, cache, slots, smax):
     return min(layers)
 
 
+def _pair_rows(text, cfg, params, tokens):
+    """Arrays of the compiled module with a row for every (token, choice)
+    pair of a call of ``tokens`` tokens, ``[T * k, w]`` or ``[T, k, w]``, at
+    a width ``w`` of the routed experts' matrices (the model's, an
+    expert's, gate beside up): the held experts' path moves the pairs held
+    HERE, a page of ``held_experts.pairs_cap`` rows, and what it keeps a
+    pair of the whole call is integers (``held_experts_ffn``).  Empty for a
+    family with no held experts."""
+    import re
+    k = getattr(cfg, "experts_per_token", None)
+    if not getattr(cfg, "held", None):
+        return []
+    widths = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if any(getattr(key, "key", None) in ("w_gu", "w_up", "w_down")
+               for key in path):
+            widths |= set(leaf.shape[-2:]) | {leaf.shape[-1] // 2}
+    assert cfg.d_model in widths
+    rows = f"{tokens * k}|{tokens},{k}"
+    return sorted(set(re.findall(
+        rf"\b\w+\[(?:{rows}),(?:{'|'.join(map(str, sorted(widths)))})\]",
+        text)))
+
+
 @_SERVED
 def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     """The tick's device program at the serving cells' geometry
@@ -464,6 +488,8 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
                  "transpose", "dynamic-slice", "dynamic-update-slice"))]
     moved = _beyond_the_known(moved, family, "tick")
     assert not moved, f"the tick moves whole layers of the pool: {moved}"
+    assert not _pair_rows(text, cfg, params, slots), \
+        "the tick builds rows for the pairs held elsewhere"
     assert "input_output_alias" in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         _pool_bytes(cache), "the donated pool is not updated in place"
@@ -624,12 +650,16 @@ def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
     lies: the pool's inputs are its outputs, nothing copies, slices or
     updates as much as a layer of it (the slot write's update is one row of
     every layer), and the plan is what its widest pass's ``extend`` on the
-    batch-1 row and the pool hold between them today."""
-    _, cfg, slots, smax, _ = _served(family)
+    batch-1 row and the pool hold between them today.  A family with held
+    experts moves the pairs held here and no row for the others."""
+    model, cfg, slots, smax, chunk = _served(family)
     compiled, extend, pool, _ = admission_of(family, int8)
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the admission"
     assert " while(" in text, "no loop over the chunks"
+    assert not _pair_rows(text, cfg, jax.eval_shape(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))), chunk), \
+        "a chunk builds rows for the pairs held elsewhere"
     moved = _beyond_the_known(
         _pool_sized_moves(text, _layer_elements(cfg, pool, slots, smax)),
         family, "admission")
@@ -1055,6 +1085,9 @@ def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
         assert f"/{kernel}/pallas_call" in text, kernel
     moved = _moves_of_a_pool(text, pool)
     assert not moved, f"the {program} moves whole layers of a pool: {moved}"
+    assert not _pair_rows(text, cfg, params,
+                          slots if program == "tick" else chunk), \
+        f"the {program} builds rows for the pairs held elsewhere"
     assert "input_output_alias" in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         _pool_bytes(pool), "the donated pool is not updated in place"
