@@ -17,6 +17,10 @@ What differs from ``models/gpt.py``'s block, mechanism by mechanism:
   R(q_r).R(k_r)`` against the row, the probabilities weigh ``c`` itself and
   the value up-projection follows the sum;
 - **YaRN** rotary frequencies on the rotary part alone, pairs interleaved;
+  a config may leave the bottleneck out (``q_rank`` None: queries straight
+  from the stream) and the rotation (``rope`` False: the row's shared key
+  and the queries' part for it are used as projected), as the
+  linear-attention family's latent layers do (``linear_latent_moe``);
 - **SwiGLU** feed-forwards: a dense one in the first ``first_k_dense``
   layers, then an expert layer (``moe/held_experts.py``): a float32 sigmoid
   router over all ``n_experts``, ``experts_per_token`` of them chosen with a
@@ -57,7 +61,8 @@ class LatentMoEConfig:
     d_model: int = 64
     d_ff: int = 256                 # the leading dense layers' SwiGLU width
     d_expert: int = 32              # one expert's SwiGLU width
-    q_rank: int = 48
+    #: the queries' bottleneck; None: none (``wq`` straight from the stream)
+    q_rank: Optional[int] = 48
     kv_rank: int = 32
     d_nope: int = 16
     d_rope: int = 8
@@ -75,6 +80,9 @@ class LatentMoEConfig:
     #: YaRN: (factor, original positions, beta_fast, beta_slow, mscale,
     #: mscale_all_dim), or None for plain rotary frequencies
     yarn: Optional[Tuple[float, int, float, float, float, float]] = None
+    #: False: no rotation at all (the row's last ``d_rope`` elements and the
+    #: queries' are used as projected; positions are unused)
+    rope: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     vocab_round_to: int = 128
@@ -190,19 +198,24 @@ def latent_project(x, p, config: LatentMoEConfig, positions):
     cdt = config.dtype
     H, r = config.n_head, config.kv_rank
     h = rms_norm(x, p["ln1"], config.eps, cdt)
-    c_q = rms_norm(jnp.einsum("bsd,dr->bsr", h, p["wq_a"].astype(cdt)),
-                   p["q_norm"], config.eps)
-    q = jnp.einsum("bsr,rhe->bshe", c_q, p["wq_b"].astype(cdt))
+    if config.q_rank is None:
+        q = jnp.einsum("bsd,dhe->bshe", h, p["wq"].astype(cdt))
+    else:
+        c_q = rms_norm(jnp.einsum("bsd,dr->bsr", h, p["wq_a"].astype(cdt)),
+                       p["q_norm"], config.eps)
+        q = jnp.einsum("bsr,rhe->bshe", c_q, p["wq_b"].astype(cdt))
+    turn = (lambda t: rotate(t, positions, config)) if config.rope \
+        else (lambda t: t)
     q_n, q_r = q[..., :config.d_nope], q[..., config.d_nope:]
     kv = jnp.einsum("bsd,dr->bsr", h, p["wkv_a"].astype(cdt))
     c = rms_norm(kv[..., :r], p["kv_norm"], config.eps)
-    k_r = rotate(kv[..., r:], positions, config)
+    k_r = turn(kv[..., r:])
     # absorb the key up-projection into the query
     q_abs = jnp.einsum("bshe,rhe->bshr", q_n,
                        p["wkv_b"][..., :config.d_nope].astype(cdt))
     pad = config.cache_row[0] - config.row_elements
     queries = jnp.concatenate(
-        [q_abs, rotate(q_r, positions, config)]
+        [q_abs, turn(q_r)]
         + ([jnp.zeros(q_abs.shape[:3] + (pad,), cdt)] if pad else []), -1)
     row = jnp.concatenate(
         [c, k_r] + ([jnp.zeros(c.shape[:2] + (pad,), cdt)] if pad else []),
@@ -270,19 +283,24 @@ def lm_logits(params: PyTree, x, config: LatentMoEConfig):
 
 # -------------------------------------------------------------------- init
 
-def _attention_init(key, config: LatentMoEConfig, n: int, std, out_std):
+def attention_init(key, config: LatentMoEConfig, n: int, std, out_std):
+    """One stack of ``n`` layers' norms and latent-attention matrices (a
+    family that mixes these layers with others draws them here too)."""
     d, H = config.d_model, config.n_head
     pdt = config.param_dtype
     k = jax.random.split(key, 5)
 
     def normal(key, shape, s):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(pdt)
+    e = config.d_nope + config.d_rope
+    queries = {"wq": normal(k[1], (n, d, H, e), std)} \
+        if config.q_rank is None else {
+            "wq_a": normal(k[0], (n, d, config.q_rank), std),
+            "q_norm": jnp.ones((n, config.q_rank), pdt),
+            "wq_b": normal(k[1], (n, config.q_rank, H, e), std)}
     return {
         "ln1": jnp.ones((n, d), pdt), "ln2": jnp.ones((n, d), pdt),
-        "wq_a": normal(k[0], (n, d, config.q_rank), std),
-        "q_norm": jnp.ones((n, config.q_rank), pdt),
-        "wq_b": normal(k[1], (n, config.q_rank, H,
-                              config.d_nope + config.d_rope), std),
+        **queries,
         "wkv_a": normal(k[2], (n, d, config.kv_rank + config.d_rope), std),
         "kv_norm": jnp.ones((n, config.kv_rank), pdt),
         "wkv_b": normal(k[3], (n, config.kv_rank, H,
@@ -306,10 +324,10 @@ def init(config: LatentMoEConfig, rng: jax.Array, std: float = 0.02,
 
     def normal(key, shape, s):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(pdt)
-    dense = _attention_init(k[0], config, n_d, std, out_std)
+    dense = attention_init(k[0], config, n_d, std, out_std)
     dense["w_gu"] = normal(k[1], (n_d, d, 2 * config.d_ff), std)
     dense["w_down"] = normal(k[2], (n_d, config.d_ff, d), out_std)
-    moe = _attention_init(k[3], config, n_m, std, out_std)
+    moe = attention_init(k[3], config, n_m, std, out_std)
     f_s = config.d_expert * config.n_shared_experts
     moe.update({
         "router": normal(k[4], (n_m, d, config.n_experts), std),
@@ -325,14 +343,21 @@ def init(config: LatentMoEConfig, rng: jax.Array, std: float = 0.02,
             "lm_head": normal(k[11], (v, d), std)}
 
 
-def logical_axes(config: LatentMoEConfig) -> PyTree:
-    attn = {
-        "ln1": (LAYERS, EMBED), "ln2": (LAYERS, EMBED),
-        "wq_a": (LAYERS, EMBED, None), "q_norm": (LAYERS, None),
-        "wq_b": (LAYERS, None, HEADS, KV),
+def attention_axes(config) -> PyTree:
+    """The logical axes of one stack of ``attention_init``'s."""
+    queries = {"wq": (LAYERS, EMBED, HEADS, KV)} \
+        if config.q_rank is None else {
+            "wq_a": (LAYERS, EMBED, None), "q_norm": (LAYERS, None),
+            "wq_b": (LAYERS, None, HEADS, KV)}
+    return {
+        "ln1": (LAYERS, EMBED), "ln2": (LAYERS, EMBED), **queries,
         "wkv_a": (LAYERS, EMBED, None), "kv_norm": (LAYERS, None),
         "wkv_b": (LAYERS, None, HEADS, KV), "wo": (LAYERS, HEADS, KV, EMBED),
     }
+
+
+def logical_axes(config: LatentMoEConfig) -> PyTree:
+    attn = attention_axes(config)
     return {
         "wte": (VOCAB, EMBED), "lnf": (EMBED,), "lm_head": (VOCAB, EMBED),
         "dense_blocks": {**attn, "w_gu": (LAYERS, EMBED, MLP),

@@ -19,11 +19,12 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from benchmarks.chip import (hybrid_ssm_moe_family, latent_moe_family,
                              mellum_family, nemotron_h_family)
-from benchmarks.chip import dots3_family
+from benchmarks.chip import dots3_family, kimi_linear_family
 from deepspeed_tpu.models import (cache_family, gpt, gpt_inference, gpt_moe,
                                   gpt_moe_inference, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference,
                                   latent_moe_inference,
+                                  linear_latent_moe_inference,
                                   sparse_latent_moe_inference, window_moe,
                                   window_moe_inference)
 from deepspeed_tpu.ops.pallas import decode_attention
@@ -50,7 +51,7 @@ def _tiny(builder, name):
     return dataclasses.replace(builder.build(file), dtype=jnp.float32)
 
 
-#: the five served configurations: class -> (config, params' init, family)
+#: the served configurations: class -> (config, params' init, family)
 def _served(name):
     if name == "dense":
         return DENSE_CFG, lambda k: gpt.init(DENSE_CFG, k), \
@@ -68,13 +69,15 @@ def _served(name):
         "window": (mellum_family, "mellum2-12b-a2.5b-ep4",
                    window_moe_inference.FAMILY),
         "selected": (dots3_family, "dots3-note-prev-ep32",
-                     sparse_latent_moe_inference.FAMILY)}[name]
+                     sparse_latent_moe_inference.FAMILY),
+        "linear": (kimi_linear_family, "kimi-linear-48b-a3b-ep8",
+                   linear_latent_moe_inference.FAMILY)}[name]
     cfg = _tiny(builder, file)
     return cfg, lambda k: builder.init(cfg, k, jnp.float32), family
 
 
 SERVED = ("dense", "moe", "latent", "hybrid", "single_part", "window",
-          "selected")
+          "selected", "linear")
 
 
 @pytest.mark.parametrize("name", SERVED)
@@ -96,13 +99,14 @@ def test_cache_family_returns_the_whole_declaration(name):
         "hybrid": {"moe_pairs", "state_steps"},
         "single_part": {"moe_pairs", "state_steps"},
         "window": {"moe_pairs"},
-        "selected": {"moe_pairs", "sparse_select"}}[name]
+        "selected": {"moe_pairs", "sparse_select"},
+        "linear": {"moe_pairs", "state_steps"}}[name]
     assert fam.select_counters == (
         sparse_latent_moe_inference.SELECT_COUNTERS
         if name == "selected" else ())
     assert fam.state_counters == (
         hybrid_ssm_moe_inference.STATE_COUNTERS
-        if name in ("hybrid", "single_part") else ())
+        if name in ("hybrid", "single_part", "linear") else ())
     # the dense family alone serves as a draft
     assert ("draft" in fam.unsupported) == (name != "dense")
 
@@ -199,6 +203,9 @@ SERVING_REFUSALS = [
      for feature in ("speculative", "paging", "prefix")] \
   + [("selected", feature, f"serving.{feature} with SparseLatentMoEConfig: "
       + sparse_latent_moe_inference.UNSUPPORTED[feature])
+     for feature in ("speculative", "paging", "prefix")] \
+  + [("linear", feature, f"serving.{feature} with LinearLatentMoEConfig: "
+      + linear_latent_moe_inference.UNSUPPORTED[feature])
      for feature in ("speculative", "paging", "prefix")]
 
 
@@ -242,6 +249,10 @@ def test_what_a_family_serves_is_not_refused(name, feature):
                     "dtype only (kv_cache_dtype='int8')"),
     ("window", "the window-and-full family caches in the compute dtype "
                "only (kv_cache_dtype='int8')"),
+    ("linear", "the linear-attention family caches in the compute dtype "
+               "only: the int8 cache's scale banks are per head, a latent "
+               "row has no heads and the state is float32 "
+               "(kv_cache_dtype='int8')"),
 ])
 def test_the_int8_cache_is_refused_where_the_cache_is_made(name, said):
     cfg, _, fam = _served(name)
@@ -266,7 +277,7 @@ def _draft_engine(name):
         model=(cfg, init(jax.random.PRNGKey(1))), config={"dtype": "float32"})
 
 
-@pytest.mark.parametrize("name", ["moe", "latent", "hybrid"])
+@pytest.mark.parametrize("name", ["moe", "latent", "hybrid", "linear"])
 def test_a_draft_must_be_dense_and_both_callers_say_so(dense_engine, name):
     draft = _draft_engine(name)
     with pytest.raises(NotImplementedError) as e:

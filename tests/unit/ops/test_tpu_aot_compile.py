@@ -21,9 +21,12 @@ _KERNEL_MODULES = [
     for name in ("flash_attention", "decode_attention", "fused_bias_gelu",
                  "quantizer")]
 flash, decode, gelu, quantizer = _KERNEL_MODULES
-# the expert layer's grouped matmul and the state-space kernels ask the
-# same two questions
-_KERNEL_MODULES += [importlib.import_module("deepspeed_tpu.moe.held_experts"),
+delta_rule = importlib.import_module("deepspeed_tpu.ops.pallas.delta_rule")
+# the delta-rule kernels, the expert layer's grouped matmul and the
+# state-space kernels ask the same two questions (the last two are taken by
+# their place)
+_KERNEL_MODULES += [delta_rule,
+                    importlib.import_module("deepspeed_tpu.moe.held_experts"),
                     importlib.import_module("deepspeed_tpu.ops.pallas.ssm")]
 
 BF16 = jnp.bfloat16
@@ -272,12 +275,13 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
 _SERVED = pytest.mark.parametrize("family,int8", [
     ("dense", False), ("dense", True), ("moe", False), ("moe", True),
     ("latent", False), ("hybrid", False), ("single_part", False),
-    ("window", False)],
+    ("window", False), ("linear", False)],
     ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent",
-         "bf16-hybrid", "bf16-single_part", "bf16-window"])
+         "bf16-hybrid", "bf16-single_part", "bf16-window", "bf16-linear"])
 
 #: scans of a tick: one a segment of the family's step
-_SEGMENTS = {"latent": 2, "hybrid": 3, "single_part": 7, "window": 1}
+_SEGMENTS = {"latent": 2, "hybrid": 3, "single_part": 7, "window": 1,
+             "linear": 5}
 
 
 def _served(family):
@@ -308,7 +312,13 @@ def _served(family):
     query heads a key-value head over a 512-wide row in blocks of 512 over
     both pools, the banded grouped chunk pass over a ring unrolled beside
     the chunk's rows, the grouped matmul at ``[2304, 1792]`` / ``[896,
-    2304]``, 16 of 64 experts held."""
+    2304]``, 16 of 64 experts held; the linear-attention family at its own
+    cell's 256 x 8,192 in chunks of 1,024, its published widths and the 8
+    layers of its cut, ``K+dense, K x 2, L, K x 3, L`` as five scans: 3.2 GB
+    of float32 delta-rule state (6 layers x 256 slots x 128 x 4096) beside
+    5.4 GB of latent rows on 2 layers, the KDA step and chunk scan at 32
+    heads of 128 x 128, the latent sweep at 32 heads, 32 of 256 experts of
+    ``[2304, 2048]`` / ``[1024, 2304]`` held."""
     import dataclasses
 
     from deepspeed_tpu.models import gpt, gpt_moe
@@ -358,6 +368,16 @@ def _served(family):
             experts_per_token=8, d_expert=896,
             held_experts=tuple(range(16)), dtype=BF16,
             param_dtype=BF16), 48, 8192, 1024
+    if family == "linear":
+        from deepspeed_tpu.models import linear_latent_moe
+        return linear_latent_moe, linear_latent_moe.LinearLatentMoEConfig(
+            vocab_size=20480, max_seq_len=1048576, n_layer=8,
+            kda_layers=(1, 2, 3, 5, 6, 7), full_attn_layers=(4, 8),
+            d_model=2304, d_ff=9216, d_expert=1024, kda_heads=32,
+            kda_head_dim=128, n_head=32, kv_rank=512, d_nope=128, d_rope=64,
+            d_v=128, n_experts=256, experts_per_token=8,
+            held_experts=tuple(range(32)), routed_scale=2.446, dtype=BF16,
+            param_dtype=BF16), 256, 8192, 1024
     from deepspeed_tpu.models import latent_moe
     smax = 8192
     return latent_moe, latent_moe.LatentMoEConfig(
@@ -503,7 +523,8 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
 #: Granite's 8 key-value heads of 128), 1,024 for Nemotron's 256-wide row
 #: (PR 46), the latent sweep's own 512
 _SWEEP_BLOCK = {"dense": 256, "moe": 256, "hybrid": 256,
-                "single_part": 1024, "latent": 512, "window": 512}
+                "single_part": 1024, "latent": 512, "window": 512,
+                "linear": 512}
 
 
 def _sweeps_in_blocks_of(jaxpr, cfg, slots, smax, block):
@@ -814,6 +835,34 @@ def test_the_single_part_cells_kernels_at_its_shapes(v5e, kernel):
                 lambda rows, w, sizes: held._grouped(rows, w, sizes, 3),
                 arg((6144, k), BF16), arg((L, 32, k, n), BF16),
                 arg((32,), jnp.int32))
+
+
+@pytest.mark.parametrize("kernel", ["decode_step", "chunk_scan"])
+def test_the_linear_cells_kernels_at_its_shapes(v5e, kernel):
+    """The two delta-rule kernels of ``kimilin-serve-think-sat`` alone, at
+    the cell's shapes: the step over 256 slots of a 6-layer stack (32 heads'
+    128 x 128 tiles in one 4,096-lane block, ``q``, ``k`` and the decay as
+    96 columns beside it), and the chunk scan of one row's 1,024-token
+    chunk in sub-chunks of 64 (a column block holds 8 heads; the scores a
+    column at a time from whole sublane rows of 8, the inverse of ``I + A``
+    by 5 squarings of 64 x 64)."""
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    L, slots, K, H = 6, 256, 128, 32
+    assert delta_rule._tiles(K, K)
+    if kernel == "decode_step":
+        _compiles_with_kernel(
+            lambda st, q, k, v, g, beta, live: delta_rule.kda_decode_step(
+                st, 3, q, k, v, g, beta, active=live),
+            arg((L, slots, K, H * K)), *[arg((slots, H, K))] * 4,
+            arg((slots, H)), arg((slots,), jnp.bool_))
+    else:
+        _compiles_with_kernel(
+            lambda st, q, k, v, g, beta, n: delta_rule.kda_chunk_scan(
+                st, 3, q, k, v, g, beta, valid=n, chunk=64),
+            arg((L, 1, K, H * K)), *[arg((1, 1024, H, K))] * 4,
+            arg((1, 1024, H)), arg((1,), jnp.int32))
 
 
 # cell: (heads, key-value heads, D, chunk, keys of the call, window) ->

@@ -97,12 +97,17 @@ def _serve_all(gw, requests, timeout=180):
 
 
 def _idle(gw, timeout=30.0):
-    """Wait for the scheduler to have nothing queued, live or in flight."""
+    """Wait for the scheduler to have nothing queued, live or in flight, on
+    two polls in a row: the loop clears ``_in_flight`` BEFORE it harvests
+    the last tick, so one quiet poll can fall between the two and a
+    snapshot taken then lacks that tick."""
     t_end = time.monotonic() + timeout
+    quiet = 0
     while time.monotonic() < t_end:
         with gw._cond:
             busy = bool(gw._queue or gw._active)
-        if not busy and gw._in_flight is None:
+        quiet = 0 if busy or gw._in_flight is not None else quiet + 1
+        if quiet == 2:
             return
         time.sleep(0.005)
     raise AssertionError("the gateway never went idle")
