@@ -54,9 +54,24 @@ then
     S_C = exp(G_C)[:, None] * S_0 + sum_s (k_s o D[C, s]) u_s^T
 
 and the next sub-chunk starts from ``S_C``.  The per-channel decay sits
-inside every score, so ``A`` and the query scores are no plain products:
-they are built a column ``s`` at a time (``k_s o D[:, s]`` against all rows
-``t``).  ``(I + A)^-1`` is the product ``(I + N)(I + N^2)(I + N^4)...`` with
+inside every score, so ``A`` and the query scores are no plain products of
+``k`` and ``q``.  They are built in TWO LEVELS, over sub-blocks of
+``SUB_BLOCK`` rows.  For a row ``t`` of a sub-block whose first row is
+``r``, and any earlier column ``s < r``, ``G`` does not rise in any channel
+(``g <= 0``), so
+
+    D[t, s] = exp(G_t - G_r) exp(G_r - G_s)        (s < r <= t)
+
+with BOTH exponents <= 0: nothing overflows, and where a factor underflows
+``D[t, s]`` itself is below float32's smallest.  Through that reference row
+the sum over channels is a plain product, the matrix unit's: the sub-block's
+rows ``k_t o exp(G_t - G_r)`` and ``q_t o exp(G_t - G_r)`` against the keys
+before it, ``k_s o exp(G_r - G_s)``, one product a sub-block (float32,
+``HIGHEST``, like every product here).  Only the pairs INSIDE a sub-block
+(the diagonal blocks) keep the direct form, a column ``s`` at a time:
+``k_s o D[:, s]`` against the sub-block's own rows, a ``SUB_BLOCK``-th of
+what a column over the whole sub-chunk costs the vector unit.
+``(I + A)^-1`` is the product ``(I + N)(I + N^2)(I + N^4)...`` with
 ``N = -A`` (``N`` is strictly lower triangular, so ``N^C = 0`` and the
 product ends after ``log2 C`` squarings): products, where forward
 substitution would be ``C`` dependent steps.  Positions at or past ``valid``
@@ -89,6 +104,11 @@ DECODE_BLOCK = 4096
 #: lanes one scan step carries: 8 heads of 128, a 512 KB state in scratch
 SCAN_BLOCK = 1024
 _HI = lax.Precision.HIGHEST
+#: rows of a sub-block of a sub-chunk's scores: pairs inside one are built a
+#: column at a time, pairs across two are products through a reference row
+#: (a whole number of sublane rows of 8; on the chip a 1,024-token chunk a
+#: layer takes 1.70 ms at 8 and 1.79 at 16: PERF.md 6, PR 56)
+SUB_BLOCK = 8
 
 
 def _tiles(K: int, V: int) -> bool:
@@ -255,9 +275,12 @@ def _scan_kernel(layer_ref, q_ref, k_ref, v_ref, g_ref, kt_ref, gt_ref,
     ``gt_ref`` (hb K, C) the keys and ``G`` transposed; ``b_ref`` (C, hb)
     ``beta`` a head a column.  ``hs_ref`` (K, hb V) carries the state from
     sub-chunk to sub-chunk; it is read from the stack on the first and
-    written back on the last."""
+    written back on the last.  The scores in two levels (the module's
+    notes): the diagonal blocks of all the heads first, in one loop over
+    columns, then a head at a time the products across sub-blocks and
+    everything that follows from the scores."""
     c = pl.program_id(2)
-    C = q_ref.shape[0]
+    C, SB = q_ref.shape[0], SUB_BLOCK
     hb = b_ref.shape[1]
     dot = functools.partial(jnp.dot, precision=_HI,
                             preferred_element_type=jnp.float32)
@@ -268,28 +291,55 @@ def _scan_kernel(layer_ref, q_ref, k_ref, v_ref, g_ref, kt_ref, gt_ref,
 
     t_of = lax.broadcasted_iota(jnp.int32, (C, C), 0)
     s_of = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    for i in range(hb):
+    s_sb = lax.broadcasted_iota(jnp.int32, (SB, C), 1)
+
+    def columns(s8, carry):
+        """Columns ``8 s8 .. 8 s8 + 7`` of the DIAGONAL blocks of every
+        head's two score matrices (a dynamic load takes whole sublane rows
+        of 8): ``k_s o D[:, s]`` against the keys and queries of the
+        column's own sub-block.  Row ``i`` of a head's carry is row ``i`` of
+        that sub-block: every column lies in one.  One loop for all the
+        heads: their columns are independent, and side by side in one body
+        each hides the others' lane reductions."""
+        at = pl.ds(pl.multiple_of(s8 * 8, 8), 8)
+        own = pl.ds(pl.multiple_of(s8 * 8 // SB * SB, 8), SB)
+        out = []
+        for i, (a, qk) in enumerate(carry):
+            ks = slice(i * K, (i + 1) * K)
+            k8, g8 = k_ref[at, ks], g_ref[at, ks]
+            kb, qb, Gb = k_ref[own, ks], q_ref[own, ks], g_ref[own, ks]
+            for r in range(8):
+                x = k8[r:r + 1] * jnp.exp(
+                    jnp.minimum(Gb - g8[r:r + 1], 0.0))
+                here = s_sb == s8 * 8 + r
+                a = jnp.where(here, jnp.sum(kb * x, 1, keepdims=True), a)
+                qk = jnp.where(here, jnp.sum(qb * x, 1, keepdims=True), qk)
+            out.append((a, qk))
+        return tuple(out)
+
+    zero = jnp.zeros((SB, C), jnp.float32)
+    diagonals = lax.fori_loop(0, C // 8, columns, ((zero, zero),) * hb)
+    for i, diagonal in enumerate(diagonals):
         ks, vs = slice(i * K, (i + 1) * K), slice(i * V, (i + 1) * V)
         q, k, G = q_ref[:, ks], k_ref[:, ks], g_ref[:, ks]
         beta = b_ref[:, i:i + 1]
-
-        def columns(s8, carry, ks=ks, q=q, k=k, G=G):
-            """Columns ``8 s8 .. 8 s8 + 7`` of both score matrices (a
-            dynamic load takes whole sublane rows of 8): ``k_s o D[:, s]``
-            against every row's key and query."""
-            a, qk = carry
-            at = pl.ds(pl.multiple_of(s8 * 8, 8), 8)
-            k8, g8 = k_ref[at, ks], g_ref[at, ks]
-            for r in range(8):
-                x = k8[r:r + 1] * jnp.exp(
-                    jnp.minimum(G - g8[r:r + 1], 0.0))
-                here = s_of == s8 * 8 + r
-                a = jnp.where(here, jnp.sum(k * x, 1, keepdims=True), a)
-                qk = jnp.where(here, jnp.sum(q * x, 1, keepdims=True), qk)
-            return a, qk
-
-        zero = jnp.zeros((C, C), jnp.float32)
-        a, qk = lax.fori_loop(0, C // 8, columns, (zero, zero))
+        # a sub-block's rows against every EARLIER column, through the
+        # block's first row r: D[t, s] = exp(G_t - G_r) exp(G_r - G_s)
+        blocks = [diagonal]
+        for r0 in range(SB, C, SB):
+            own, first = slice(r0, r0 + SB), slice(r0, r0 + 1)
+            e = jnp.exp(jnp.minimum(G[own] - G[first], 0.0))
+            keys = k[:r0] * jnp.exp(jnp.minimum(G[first] - G[:r0], 0.0))
+            across = lax.dot_general(
+                jnp.concatenate([k[own] * e, q[own] * e], 0),
+                jnp.concatenate(
+                    [keys, jnp.zeros((C - r0, K), jnp.float32)], 0),
+                (((1,), (1,)), ((), ())), precision=_HI,
+                preferred_element_type=jnp.float32)
+            blocks.append(tuple(
+                jnp.where(s_sb < r0, across[j * SB:(j + 1) * SB], diagonal[j])
+                for j in range(2)))
+        a, qk = (jnp.concatenate(rows, 0) for rows in zip(*blocks))
         a = jnp.where(t_of > s_of, beta * a, 0.0)
         qk = jnp.where(t_of >= s_of, qk, 0.0)
         s0 = hs_ref[:, vs]

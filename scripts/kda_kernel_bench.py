@@ -2,21 +2,26 @@
 """The two KDA kernels alone (``ops/pallas/delta_rule.py``), on the chip, at
 the shapes of ``kimilin-serve-think-sat``: wall time a call of the decode
 step (256 slots, one layer of a 6-layer stack) and of the chunk scan (one
-row's 1,024-token chunk) at each sub-chunk asked for, each checked against
-its XLA twin on the way.
+row's 1,024-token chunk) at each sub-chunk and each sub-block of the scores
+asked for, each checked against its XLA twin over the whole chunk on the
+way.
 
     chiprun -- python scripts/kda_kernel_bench.py [--chunks 64,128]
-        [--slots 256] [--tokens 1024]
+        [--sub-blocks 8,16] [--slots 256] [--tokens 1024]
 
 Chip only: a time is a chip's (``utils.platform.require_tpu``)."""
 
 import argparse
+import itertools
 import os
 import sys
 import time
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--chunks", default="64,128")
+ap.add_argument("--sub-blocks", default=None,
+                help="rows of a sub-block of the scores (delta_rule."
+                "SUB_BLOCK, the kernel's own where not given)")
 ap.add_argument("--slots", type=int, default=256)
 ap.add_argument("--tokens", type=int, default=1024)
 ARGS = ap.parse_args()
@@ -76,28 +81,32 @@ def main():
     args = draw(jax.random.fold_in(key, 1), 1, S)
     valid = jnp.asarray([S - 37])
     want = None
-    for C in map(int, ARGS.chunks.split(",")):
+    sub_blocks = ([dr.SUB_BLOCK] if ARGS.sub_blocks is None
+                  else map(int, ARGS.sub_blocks.split(",")))
+    for sb, C in itertools.product(sub_blocks,
+                                   map(int, ARGS.chunks.split(","))):
+        dr.SUB_BLOCK = sb      # read where a call is traced
         scan = jax.jit(lambda st, *a, C=C: dr.kda_chunk_scan(
             st, 3, *a, valid=valid, chunk=C), donate_argnums=0)
         st1 = jnp.zeros((LAYERS, 1, K, H * K), jnp.float32)
         ms, y, st1 = timed(scan, st1, *args)
-        print(f"chunk_scan tokens={S} chunk={C} ms={ms:.3f} "
+        print(f"chunk_scan tokens={S} chunk={C} sub_block={sb} ms={ms:.3f} "
               f"us/token={ms * 1e3 / S:.2f}")
-        y0, s0 = jax.jit(lambda st, *a, C=C: dr.kda_chunk_scan(
+        fresh = lambda: jax.jit(lambda st, *a, C=C: dr.kda_chunk_scan(
             st, 3, *a, valid=valid, chunk=C))(
                 jnp.zeros((LAYERS, 1, K, H * K), jnp.float32), *args)
+        y0, s0 = fresh()
+        # the twin, over the whole chunk: the same sums with no reference row
+        tiles, dr._tiles = dr._tiles, lambda K, V: False
+        yt, stt = fresh()
+        dr._tiles = tiles
+        print(f"chunk={C} sub_block={sb} vs twin:",
+              float(jnp.abs(yt - y0)[:, :S - 37].max()),
+              float(jnp.abs(stt[3] - s0[3]).max()))
         if want is None:
             want = (np.asarray(y0), np.asarray(s0[3]))
-            n = min(S, 256)     # the twin, on the first tokens
-            yt, stt = dr._scan_xla(
-                jnp.zeros((LAYERS, 1, K, H * K), jnp.float32), 3,
-                *(t[:, :n] for t in args[:3]),
-                jnp.cumsum(args[3][:, :n].reshape(1, n // C, C, H, K),
-                           axis=2).reshape(1, n, H, K), args[4][:, :n], C)
-            print("chunk_scan vs twin:",
-                  float(jnp.abs(yt - y0[:, :n]).max()))
         else:
-            print(f"chunk={C} vs first:",
+            print(f"chunk={C} sub_block={sb} vs first:",
                   float(np.abs(np.asarray(y0) - want[0])[:, :S - 37].max()),
                   float(np.abs(np.asarray(s0[3]) - want[1]).max()))
 

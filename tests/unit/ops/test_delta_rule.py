@@ -3,7 +3,11 @@ recurrence they stand for, token by token in float64: the chunked (WY) form
 for sub-chunks of 16 and 64, a ragged ``valid``, a state carried over three
 calls, decays near 0 and near 1; the decode step; a freed slot.  Each both
 ways: the XLA twin, and the Pallas kernel under the interpreter at a head of
-128 x 128 (the shape that tiles).
+128 x 128 (the shape that tiles).  The kernel builds its scores in two
+levels (products through a sub-block's first row, columns only inside a
+sub-block) where the twin states the sums plainly, so the twin is also the
+kernel's independent check: decays that differ by channel inside a head,
+a ``valid`` inside a sub-block and on its edge, both to 1e-5.
 
 The tolerance: both sides are float32 / float64 of the same sums in another
 order; over 192 tokens on a state of order 1 that reads 1e-6 to 1e-5 here
@@ -66,34 +70,91 @@ def head(request, monkeypatch):
     return _head(monkeypatch, request.param)
 
 
-@pytest.mark.parametrize("path,chunk,decay", [
-    ("xla", 16, 1.6), ("xla", 64, 1.6), ("xla", 64, 1e-2), ("xla", 64, 40.0),
+def _twin(monkeypatch, *args, **kwargs):
+    """``kda_chunk_scan`` of the same call down its XLA twin."""
+    with monkeypatch.context() as m:
+        m.setattr(delta_rule, "_tiles", lambda K, V: False)
+        return delta_rule.kda_chunk_scan(*args, **kwargs)
+
+
+#: a decay a KEY CHANNEL inside every head: e^-40 a token beside e^-0.001
+BY_CHANNEL = "by-channel"
+
+
+@pytest.mark.parametrize("path,chunk,decay,valid,against", [
+    ("xla", 16, 1.6, 77, "recurrence"), ("xla", 64, 1.6, 77, "recurrence"),
+    ("xla", 64, 1e-2, 77, "recurrence"), ("xla", 64, 40.0, 77, "recurrence"),
     # the kernel's sub-chunk is a multiple of 64
-    ("kernel", 64, 1.6), ("kernel", 128, 1.6), ("kernel", 64, 1e-2),
-    ("kernel", 64, 40.0)],
+    ("kernel", 64, 1.6, 77, "recurrence"),
+    ("kernel", 128, 1.6, 77, "recurrence"),
+    ("kernel", 64, 1e-2, 77, "recurrence"),
+    ("kernel", 64, 40.0, 77, "recurrence"),
+    ("xla", 64, BY_CHANNEL, 77, "recurrence"),
+    ("kernel", 64, BY_CHANNEL, 77, "recurrence"),
+    ("xla", 64, 1.6, 80, "recurrence"),
+    ("kernel", 64, 1.6, 80, "recurrence"),
+    ("kernel", 64, 1.6, 77, "twin"), ("kernel", 128, 1.6, 80, "twin"),
+    ("kernel", 64, BY_CHANNEL, 80, "twin")],
     ids=["xla-16", "xla-64", "xla-decay-near-1", "xla-decay-near-0",
          "kernel-64", "kernel-128", "kernel-decay-near-1",
-         "kernel-decay-near-0"])
+         "kernel-decay-near-0", "xla-decay-by-channel",
+         "kernel-decay-by-channel", "xla-valid-on-a-sub-block-edge",
+         "kernel-valid-on-a-sub-block-edge", "kernel-64-is-the-twin",
+         "kernel-128-is-the-twin", "kernel-by-channel-is-the-twin"])
 def test_the_chunk_form_equals_the_recurrence(monkeypatch, path, chunk,
-                                              decay):
-    """A ragged ``valid`` (a full row, a row that ends inside a sub-chunk)
-    from a state that is not zero; a decay of e^-40 a token overflows any
-    ``exp(-G_s)`` after three tokens."""
+                                              decay, valid, against):
+    """A ragged ``valid`` (a full row, a row that ends inside a sub-chunk:
+    at 77 inside a sub-block of the kernel's scores, whether of 8 rows or
+    of 16, at 80 on a sub-block's edge) from a state that is not zero; a
+    decay of e^-40 a token overflows any ``exp(-G_s)`` after three tokens,
+    and ``by channel`` puts such channels beside channels that keep
+    e^-0.001 in ONE head, so that the factor through a sub-block's first
+    row underflows in some channels of a score and not in others.
+    ``against`` the twin: the two-level kernel and the plain statement of
+    the same sums sum in another order, and agree to 1e-5."""
     H, K = _head(monkeypatch, path)
     rng = np.random.default_rng(0)
     B, S, L = 2, 128, 3
-    q, k, v, g, beta = _draw(rng, B, S, H, K, decay)
+    q, k, v, g, beta = _draw(rng, B, S, H, K,
+                             1.6 if decay == BY_CHANNEL else decay)
+    if decay == BY_CHANNEL:
+        g = np.where(rng.uniform(size=(H, K)) < 0.5, -40.0, -1e-3) \
+            * rng.uniform(0.9, 1.1, g.shape)
     stack = rng.normal(size=(L, B, K, H * K)) * 0.3
-    valid = np.array([S, 77])
-    o, out = delta_rule.kda_chunk_scan(
-        jnp.asarray(stack, jnp.float32), 1, *_f32(q, k, v, g, beta),
-        valid=jnp.asarray(valid), chunk=chunk)
-    want_o, want_s = _recurrence(stack[1], q, k, v, g, beta, valid)
+    valid = np.array([S, valid])
+    call = (jnp.asarray(stack, jnp.float32), 1, *_f32(q, k, v, g, beta))
+    sizes = dict(valid=jnp.asarray(valid), chunk=chunk)
+    o, out = delta_rule.kda_chunk_scan(*call, **sizes)
+    if against == "twin":
+        want_o, want_s = _twin(monkeypatch, *call, **sizes)
+        want_s, tol = np.asarray(want_s)[1], dict(atol=1e-5, rtol=1e-5)
+    else:
+        want_o, want_s = _recurrence(stack[1], q, k, v, g, beta, valid)
+        tol = TOL
     for b in range(B):
         np.testing.assert_allclose(np.asarray(o)[b, :valid[b]],
-                                   want_o[b, :valid[b]], **TOL)
-    np.testing.assert_allclose(np.asarray(out)[1], want_s, **TOL)
+                                   np.asarray(want_o)[b, :valid[b]], **tol)
+    np.testing.assert_allclose(np.asarray(out)[1], want_s, **tol)
     assert (np.asarray(out)[[0, 2]] == np.float32(stack)[[0, 2]]).all()
+
+
+def test_the_sub_block_is_any_number_of_whole_sublane_rows(monkeypatch):
+    """``SUB_BLOCK`` is a constant of the kernel, chosen on the chip (8
+    over 16); the two levels hold for any size that divides the
+    sub-chunk."""
+    H, K = _head(monkeypatch, "kernel")
+    monkeypatch.setattr(delta_rule, "SUB_BLOCK", 16)
+    rng = np.random.default_rng(4)
+    q, k, v, g, beta = _draw(rng, 1, 128, H, K, 1.6)
+    stack = rng.normal(size=(1, 1, K, H * K)) * 0.3
+    call = (jnp.asarray(stack, jnp.float32), 0, *_f32(q, k, v, g, beta))
+    sizes = dict(valid=jnp.asarray([100]), chunk=64)
+    o, out = delta_rule.kda_chunk_scan(*call, **sizes)
+    want_o, want_s = _twin(monkeypatch, *call, **sizes)
+    np.testing.assert_allclose(np.asarray(o)[:, :100],
+                               np.asarray(want_o)[:, :100], atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_s),
+                               atol=1e-5)
 
 
 def test_a_state_carried_over_three_chunks(head):

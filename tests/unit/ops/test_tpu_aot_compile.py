@@ -8,6 +8,7 @@ run.
 
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
@@ -77,6 +78,7 @@ def mosaic(monkeypatch):
 def _compiles_with_kernel(fn, *shapes):
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
 
 
 def _flash_loss(q, k, v):
@@ -843,9 +845,15 @@ def test_the_linear_cells_kernels_at_its_shapes(v5e, kernel):
     the cell's shapes: the step over 256 slots of a 6-layer stack (32 heads'
     128 x 128 tiles in one 4,096-lane block, ``q``, ``k`` and the decay as
     96 columns beside it), and the chunk scan of one row's 1,024-token
-    chunk in sub-chunks of 64 (a column block holds 8 heads; the scores a
-    column at a time from whole sublane rows of 8, the inverse of ``I + A``
-    by 5 squarings of 64 x 64)."""
+    chunk in sub-chunks of 64 (a column block holds 8 heads; the scores in
+    two levels: sub-blocks of ``SUB_BLOCK`` rows, the pairs across
+    sub-blocks as products through the row block's first row, the diagonal
+    blocks a column at a time from whole sublane rows of 8 in one loop over
+    the block's 8 heads; the inverse of ``I + A``
+    by 5 squarings of 64 x 64).  The scan is ONE custom call that returns
+    the chunk's rows beside the aliased stack: the benchmark's reader
+    (``state_kernels.kernel_of``) tells the kernel by that pair, and a
+    second call would be left out of the time its roofline divides by."""
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
@@ -858,11 +866,17 @@ def test_the_linear_cells_kernels_at_its_shapes(v5e, kernel):
             arg((L, slots, K, H * K)), *[arg((slots, H, K))] * 4,
             arg((slots, H)), arg((slots,), jnp.bool_))
     else:
-        _compiles_with_kernel(
+        text = _compiles_with_kernel(
             lambda st, q, k, v, g, beta, n: delta_rule.kda_chunk_scan(
                 st, 3, q, k, v, g, beta, valid=n, chunk=64),
             arg((L, 1, K, H * K)), *[arg((1, 1024, H, K))] * 4,
             arg((1, 1024, H)), arg((1,), jnp.int32))
+        calls = re.findall(r"%kda_chunk_scan\S* = (\(.*?\)) custom-call\(",
+                           text)
+        assert [re.sub(r"\{[^}]*\}", "", c) for c in calls] == [
+            "(f32[1,1024,4096], f32[6,1,128,4096])"], calls
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert "output_to_operand_aliasing={{1}: (8, {})}" in text
 
 
 # cell: (heads, key-value heads, D, chunk, keys of the call, window) ->
