@@ -191,24 +191,42 @@ def rotate(x, positions, config: LatentMoEConfig):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def latent_project(x, p, config: LatentMoEConfig, positions):
+def lora_rescale(d_model: int, rank: int) -> float:
+    """What a low-rank latent leaves its norm multiplied by where a config
+    asks for it (``mla_scale_q_lora`` / ``mla_scale_kv_lora``):
+    ``sqrt(d_model / rank)``."""
+    return math.sqrt(d_model / rank)
+
+
+def latent_project(x, p, config: LatentMoEConfig, positions,
+                   q_scale: float = 1.0, kv_scale: float = 1.0):
     """One layer's attention inputs from ``x`` [B, S, d]: the absorbed
     queries ``[q' | R(q_r)]`` [B, S, H, row] and the token's cache row
-    ``[c | R(k_r)]`` [B, S, row], both zero past ``row_elements``."""
+    ``[c | R(k_r)]`` [B, S, row], both zero past ``row_elements``.
+    ``q_scale`` / ``kv_scale``: what the query latent and the key-value
+    latent leave their norms multiplied by (``lora_rescale``), in float32
+    before the one rounding; the cached ``c`` is the scaled one, so the
+    absorbed form reads it as it lies (it scales keys AND values)."""
     cdt = config.dtype
     H, r = config.n_head, config.kv_rank
+
+    def latent(t, gain, scale):
+        if scale == 1.0:
+            return rms_norm(t, gain, config.eps)
+        return (rms_norm(t, gain, config.eps, jnp.float32) * scale
+                ).astype(cdt)
     h = rms_norm(x, p["ln1"], config.eps, cdt)
     if config.q_rank is None:
         q = jnp.einsum("bsd,dhe->bshe", h, p["wq"].astype(cdt))
     else:
-        c_q = rms_norm(jnp.einsum("bsd,dr->bsr", h, p["wq_a"].astype(cdt)),
-                       p["q_norm"], config.eps)
+        c_q = latent(jnp.einsum("bsd,dr->bsr", h, p["wq_a"].astype(cdt)),
+                     p["q_norm"], q_scale)
         q = jnp.einsum("bsr,rhe->bshe", c_q, p["wq_b"].astype(cdt))
     turn = (lambda t: rotate(t, positions, config)) if config.rope \
         else (lambda t: t)
     q_n, q_r = q[..., :config.d_nope], q[..., config.d_nope:]
     kv = jnp.einsum("bsd,dr->bsr", h, p["wkv_a"].astype(cdt))
-    c = rms_norm(kv[..., :r], p["kv_norm"], config.eps)
+    c = latent(kv[..., :r], p["kv_norm"], kv_scale)
     k_r = turn(kv[..., r:])
     # absorb the key up-projection into the query
     q_abs = jnp.einsum("bshe,rhe->bshr", q_n,
@@ -246,9 +264,10 @@ def expert_ffn(x, p, config: LatentMoEConfig, experts=None, layer=None):
     matrices as whole stacks ``{"w_gu", "w_down"}`` ``[layers, n_held,
     ...]`` for the grouped product to read in place, where ``p`` does not
     carry one layer of them.  Returns ``(x, counts)`` with ``counts``
-    ``[4 + n_held]`` int32: pairs held here, pairs routed, held experts
+    ``[5 + n_held]`` int32: pairs held here, pairs routed, held experts
     that took at least one pair (each streams its matrices once), pairs
-    per held expert, pages of pairs run beyond the first."""
+    per held expert, pages of pairs run beyond the first, pairs on
+    zero-compute experts (none here: 0)."""
     B, S, d = x.shape
     h32 = rms_norm(x, p["ln2"], config.eps, jnp.float32)
     h = h32.astype(config.dtype)

@@ -46,7 +46,7 @@ from jax import lax
 
 from . import latent_moe
 from .hybrid_ssm_moe import layer_units, run_parts
-from .latent_moe import rms_norm
+from .latent_moe import lora_rescale, rms_norm
 from .partitioning import EMBED, EXPERT, HEADS, KV, LAYERS, MLP, VOCAB
 
 PyTree = Any
@@ -168,7 +168,7 @@ class SparseLatentMoEConfig:
                     self.w_rope_theta)
 
     def rescale(self, rank: int) -> float:
-        return math.sqrt(self.d_model / rank) if self.lora_rescale else 1.0
+        return lora_rescale(self.d_model, rank) if self.lora_rescale else 1.0
 
     def count(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)

@@ -8,12 +8,21 @@ of the result.  What the absent experts would add is another chip's to add:
 on one chip the layer runs without its exchange, and nothing here stands in
 for the chips that are not there.
 
-Two gates return the same ``Routing``.  ``route`` is the published
+Three gates return the same ``Routing``.  ``route`` is the published
 ``noaux_tc`` gate with one group: sigmoid scores over all experts **in
 float32**, the ``k`` largest of ``score + bias`` (the bias moves the
 selection and never the weight), the selected scores normalised to sum to
 one and scaled.  ``route_softmax`` takes the ``k`` largest router logits and
-weighs them by a softmax over those ``k`` alone.
+weighs them by a softmax over those ``k`` alone.  ``route_softmax_all``
+takes a softmax over ALL the router's outputs, chooses by ``p + bias`` and
+weighs by ``scale * p`` with no renormalisation.
+
+A router may be wider than there are matrices: its last ``n_zero`` outputs
+are **zero-compute experts** (identity: ``E(h) = h``).  A pair that chose
+one is held by no chip and multiplied by none; its weight goes to a scalar
+a token that multiplies the layer's own input (``zero_weight``).  That part
+is the token's chip's to add: computed here in full whatever the share
+held, so across the shares of a deployment it counts once.
 
 The held pairs are multiplied grouped and dropless.  Pairs are sorted by the
 local index of their expert, held ones first, and the rows of each held
@@ -100,9 +109,33 @@ def route_softmax(h, w_router, k: int) -> Routing:
     return Routing(experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1))
 
 
+def route_softmax_all(h, w_router, bias, k: int, scale: float) -> Routing:
+    """The softmax-over-all gate.  ``w_router`` [d, E] with ``E`` every
+    output of the router, zero-compute experts included, ``bias`` [E]:
+    logits and softmax in float32 at full precision, the ``k`` largest of
+    ``p + bias`` chosen (the bias moves the choice and never the weight),
+    weights ``scale * p`` of the chosen, not renormalised."""
+    p = jax.nn.softmax(jnp.dot(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    _, experts = lax.top_k(p + bias.astype(jnp.float32), k)
+    return Routing(experts.astype(jnp.int32),
+                   jnp.take_along_axis(p, experts, axis=-1) * scale)
+
+
+def zero_weight(routing: Routing, n_experts: int):
+    """``([T] float32, [] int32)``: the summed weight of each token's pairs
+    on zero-compute experts (ids ``>= n_experts``), and how many such pairs
+    the call has."""
+    zero = routing.experts >= n_experts
+    return (jnp.sum(jnp.where(zero, routing.weights, 0.0), -1),
+            jnp.sum(zero, dtype=jnp.int32))
+
+
 def local_slots(held: Sequence[int], n_experts: int) -> np.ndarray:
-    """``[n_experts]`` int32: a held expert's index among the held, and
-    ``len(held)`` for every expert held elsewhere."""
+    """``[n_experts]`` int32 (``n_experts``: the router's whole width): a
+    held expert's index among the held, and ``len(held)`` for every expert
+    held elsewhere or by no one."""
     table = np.full((n_experts,), len(held), np.int32)
     table[np.asarray(held, np.int64)] = np.arange(len(held), dtype=np.int32)
     return table
@@ -134,16 +167,17 @@ def _grouped(rows, w, group_sizes, layer=None):
 
 def n_pair_counts(n_held: int) -> int:
     """The length of ``pair_counts``' vector for ``n_held`` held experts."""
-    return 4 + n_held
+    return 5 + n_held
 
 
 def pair_counts(counts, routed: int):
     """An expert layer call's counters ``[n_pair_counts(n_held)]`` int32 from
     ``held_experts_ffn``'s ``counts``: pairs held here, pairs routed in all,
     held experts that took at least one pair (each streams its matrices
-    once), pairs per held expert, and last the pages of pairs the call ran
-    beyond its first (``pairs_cap``)."""
-    per_expert = counts[:-1]
+    once), pairs per held expert, the pages of pairs the call ran beyond its
+    first (``pairs_cap``), and last the pairs that chose a zero-compute
+    expert (among the routed; 0 for a router without such)."""
+    per_expert = counts[:-2]
     return jnp.concatenate([
         jnp.sum(per_expert, keepdims=True),
         jnp.full((1,), routed, jnp.int32),
@@ -153,8 +187,9 @@ def pair_counts(counts, routed: int):
 
 def pairs_cap(n_pairs: int, n_held: int, n_experts: int) -> int:
     """Rows of the buffer the held pairs of a call are brought together in:
-    twice the pairs a uniform router sends to ``n_held`` of ``n_experts``,
-    on the grouped product's row tile, and never more than all
+    twice the pairs a uniform router sends to ``n_held`` of ``n_experts``
+    (the router's whole width, zero-compute outputs included), on the
+    grouped product's row tile, and never more than all
     ``n_pairs``.  A call that holds more runs further pages of as many."""
     mean = -(-n_pairs * n_held // n_experts)
     tile = GMM_TILING[0]
@@ -163,16 +198,20 @@ def pairs_cap(n_pairs: int, n_held: int, n_experts: int) -> int:
 
 def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
                      held: Sequence[int], n_experts: int, layer=None,
-                     form: str = SWIGLU) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     form: str = SWIGLU, n_zero: int = 0
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' part of the layer's result, and the pair counts.
 
     ``h`` [T, d]; ``p["w_gu"]`` [n_held, d, 2f] (gate beside up; ``form``
     ``RELU2``: ``p["w_up"]`` [n_held, d, f]) and ``p["w_down"]`` [n_held, f,
     d], the held experts' matrices in the order of ``held``; with ``layer``
     (a layer scan's index) both are the whole stacks ``[layers, n_held,
-    ...]``, read in place.  Returns ``(out [T, d], counts [n_held + 1]
+    ...]``, read in place.  Returns ``(out [T, d], counts [n_held + 2]
     int32)``: ``sum_{i in sel, i held} w_i E_i(h)``; the pairs each held
-    expert took and, last, the pages run beyond the first.
+    expert took, the pages run beyond the first and, last, the pairs on
+    zero-compute experts.  ``n_zero``: the router's last ``n_zero`` outputs
+    (ids ``n_experts ..``) are zero-compute experts, and ``out`` gains
+    ``zero_weight * h``, in full.
 
     Of the ``T * k`` pairs routed anywhere only those held here are
     multiplied, so only they are moved: sorted by expert they fill the
@@ -188,9 +227,9 @@ def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
     T, d = h.shape
     k = routing.experts.shape[1]
     n_held, n_pairs = len(held), T * k
-    P = pairs_cap(n_pairs, n_held, n_experts)
-    flat = jnp.asarray(local_slots(held, n_experts))[routing.experts
-                                                     ].reshape(-1)
+    width = n_experts + n_zero
+    P = pairs_cap(n_pairs, n_held, width)
+    flat = jnp.asarray(local_slots(held, width))[routing.experts].reshape(-1)
     with jax.named_scope("moe_routed"):
         # held pairs first, by expert: a stable sort keeps tokens in order,
         # and a pair's weight rides with it (a gather of scalars costs more)
@@ -242,4 +281,10 @@ def held_experts_ffn(h, routing: Routing, p: Dict[str, jnp.ndarray],
                 lambda c: c[0] < n_here,
                 lambda c: (c[0] + P, page(c[0], c[1])), (jnp.int32(0), out))
     more = jnp.maximum(-(-n_here // P) - 1, 0)
-    return out.astype(h.dtype), jnp.concatenate([counts, more[None]])
+    n_on_zero = jnp.zeros((), jnp.int32)
+    if n_zero:
+        with jax.named_scope("moe_zero"):
+            w_zero, n_on_zero = zero_weight(routing, n_experts)
+            out = out + w_zero[:, None] * h.astype(jnp.float32)
+    return out.astype(h.dtype), jnp.concatenate(
+        [counts, more[None], n_on_zero[None]])

@@ -963,8 +963,8 @@ class ServingGateway:
                 self.tracer.record(
                     SpanName.SERVE_MOE_PAIRS, now, 0.0, held=int(moe[0]),
                     routed=int(moe[1]), visits=int(moe[2]),
-                    per_expert=[int(c) for c in moe[3:-1]],
-                    pages_over_cap=int(moe[-1]))
+                    per_expert=[int(c) for c in moe[3:-2]],
+                    pages_over_cap=int(moe[-2]), zero=int(moe[-1]))
         state = self._batcher.counts("state_steps")
         if state is not None:
             state = {k: int(c) for k, c in

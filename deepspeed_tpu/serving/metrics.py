@@ -112,6 +112,9 @@ class ServingMetrics:
         #: first (``held_experts.pairs_cap``): 0 unless a call held more
         #: than twice a uniform router's share
         self.moe_pages_over_cap = 0
+        #: routed pairs that chose a zero-compute expert (a router wider
+        #: than its matrices); 0 for every other family
+        self.moe_pairs_zero = 0
         #: a per-slot-state family's device counters, by name (state rows
         #: stepped, real and padded tokens through the chunk scan):
         #: cumulative, as of the last harvested tick
@@ -200,14 +203,16 @@ class ServingMetrics:
 
     def record_moe_pairs(self, counts) -> None:
         """``counts``: the batcher's cumulative group ``moe_pairs``, ``[held,
-        routed, visits, pairs of each held expert..., pages over the cap]``
+        routed, visits, pairs of each held expert..., pages over the cap,
+        pairs on zero-compute experts]``
         (``moe.held_experts.pair_counts``)."""
         with self._lock:
             self.moe_pairs_held = int(counts[0])
             self.moe_pairs_routed = int(counts[1])
             self.moe_expert_visits = int(counts[2])
-            self.moe_expert_pairs = [int(c) for c in counts[3:-1]]
-            self.moe_pages_over_cap = int(counts[-1])
+            self.moe_expert_pairs = [int(c) for c in counts[3:-2]]
+            self.moe_pages_over_cap = int(counts[-2])
+            self.moe_pairs_zero = int(counts[-1])
 
     def record_state_steps(self, counts: dict) -> None:
         """``counts``: name -> cumulative count, the batcher's group
@@ -314,6 +319,7 @@ class ServingMetrics:
                 "moe_expert_visits": self.moe_expert_visits,
                 "moe_expert_pairs": list(self.moe_expert_pairs),
                 "moe_pages_over_cap": self.moe_pages_over_cap,
+                "moe_pairs_zero": self.moe_pairs_zero,
                 "state_steps": dict(self.state_steps),
                 "sparse_select": dict(self.sparse_select),
                 # the busiest held expert's pairs over the mean's

@@ -20,11 +20,13 @@ import deepspeed_tpu
 from benchmarks.chip import (hybrid_ssm_moe_family, latent_moe_family,
                              mellum_family, nemotron_h_family)
 from benchmarks.chip import dots3_family, kimi_linear_family
+from benchmarks.chip import longcat_flash_family
 from deepspeed_tpu.models import (cache_family, gpt, gpt_inference, gpt_moe,
                                   gpt_moe_inference, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference,
                                   latent_moe_inference,
                                   linear_latent_moe_inference,
+                                  shortcut_latent_moe_inference,
                                   sparse_latent_moe_inference, window_moe,
                                   window_moe_inference)
 from deepspeed_tpu.ops.pallas import decode_attention
@@ -71,13 +73,15 @@ def _served(name):
         "selected": (dots3_family, "dots3-note-prev-ep32",
                      sparse_latent_moe_inference.FAMILY),
         "linear": (kimi_linear_family, "kimi-linear-48b-a3b-ep8",
-                   linear_latent_moe_inference.FAMILY)}[name]
+                   linear_latent_moe_inference.FAMILY),
+        "shortcut": (longcat_flash_family, "longcat-flash-chat-ep32",
+                     shortcut_latent_moe_inference.FAMILY)}[name]
     cfg = _tiny(builder, file)
     return cfg, lambda k: builder.init(cfg, k, jnp.float32), family
 
 
 SERVED = ("dense", "moe", "latent", "hybrid", "single_part", "window",
-          "selected", "linear")
+          "selected", "linear", "shortcut")
 
 
 @pytest.mark.parametrize("name", SERVED)
@@ -100,7 +104,8 @@ def test_cache_family_returns_the_whole_declaration(name):
         "single_part": {"moe_pairs", "state_steps"},
         "window": {"moe_pairs"},
         "selected": {"moe_pairs", "sparse_select"},
-        "linear": {"moe_pairs", "state_steps"}}[name]
+        "linear": {"moe_pairs", "state_steps"},
+        "shortcut": {"moe_pairs"}}[name]
     assert fam.select_counters == (
         sparse_latent_moe_inference.SELECT_COUNTERS
         if name == "selected" else ())
@@ -206,6 +211,10 @@ SERVING_REFUSALS = [
      for feature in ("speculative", "paging", "prefix")] \
   + [("linear", feature, f"serving.{feature} with LinearLatentMoEConfig: "
       + linear_latent_moe_inference.UNSUPPORTED[feature])
+     for feature in ("speculative", "paging", "prefix")] \
+  + [("shortcut", feature,
+      f"serving.{feature} with ShortcutLatentMoEConfig: "
+      + shortcut_latent_moe_inference.UNSUPPORTED[feature])
      for feature in ("speculative", "paging", "prefix")]
 
 
@@ -253,6 +262,9 @@ def test_what_a_family_serves_is_not_refused(name, feature):
                "only: the int8 cache's scale banks are per head, a latent "
                "row has no heads and the state is float32 "
                "(kv_cache_dtype='int8')"),
+    ("shortcut", "the latent-attention families cache in the compute dtype "
+                 "only: the int8 cache's scale banks are per head and a "
+                 "latent row has no heads (kv_cache_dtype='int8')"),
 ])
 def test_the_int8_cache_is_refused_where_the_cache_is_made(name, said):
     cfg, _, fam = _served(name)
@@ -277,7 +289,8 @@ def _draft_engine(name):
         model=(cfg, init(jax.random.PRNGKey(1))), config={"dtype": "float32"})
 
 
-@pytest.mark.parametrize("name", ["moe", "latent", "hybrid", "linear"])
+@pytest.mark.parametrize("name", ["moe", "latent", "hybrid", "linear",
+                                  "shortcut"])
 def test_a_draft_must_be_dense_and_both_callers_say_so(dense_engine, name):
     draft = _draft_engine(name)
     with pytest.raises(NotImplementedError) as e:

@@ -80,11 +80,14 @@ def test_slot_path_equals_the_reference_full_forward():
     replies, got = gateway.probe_logits(prompts, ticks)
     for p, reply, logits in zip(prompts, replies, got):
         full = np.concatenate([p, np.asarray(reply, np.int32)])[None]
-        ref = np.asarray(reference.forward(file, params, full, ticks + 1))[0]
+        # compiled: op by op the reference compiles every primitive of
+        # every new shape on its own
+        ref = np.asarray(jax.jit(lambda p, t: reference.forward(
+            file, p, t, ticks + 1))(params, full))[0]
         np.testing.assert_allclose(logits[:, :cfg.vocab_size], ref,
                                    atol=2e-5, rtol=1e-4)
     counts = gateway._batcher.device_counts
-    assert counts is not None and counts[0] == counts[3:-1].sum() > 0
+    assert counts is not None and counts[0] == counts[3:-2].sum() > 0
     assert counts[1] > counts[0] >= counts[2] > 0
 
 

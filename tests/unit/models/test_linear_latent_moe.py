@@ -203,7 +203,7 @@ def test_the_cache_holds_a_latent_bank_and_a_state_leaf_together():
     assert state.shape == (5, 3, 16, 64) and state.dtype == jnp.float32
     assert tails.shape == (5, 3, 3, 3 * 64)
     assert cache.ring is None
-    assert cache.stats.shape == (4 + len(cfg.held) + 3,)
+    assert cache.stats.shape == (5 + len(cfg.held) + 3,)
 
 
 def test_apply_equals_the_reference():
@@ -231,8 +231,11 @@ def _slot_path(file, cfg, params, lengths=LENGTHS, ticks=8):
     out = []
     for p, reply, logits in zip(prompts, replies, got):
         full = np.concatenate([p, np.asarray(reply, np.int32)])
+        # compiled: op by op the reference compiles every primitive of
+        # every new shape on its own
         out.append((np.asarray(logits)[:, :cfg.vocab_size], np.asarray(
-            reference.forward(file, params, full[None], ticks + 1))[0]))
+            jax.jit(lambda p, t: reference.forward(file, p, t, ticks + 1))(
+                params, full[None]))[0]))
     return gateway, out
 
 
@@ -256,8 +259,8 @@ def test_slot_path_equals_the_reference_full_forward():
                      "scan_tokens_real": real * n_kda,
                      "scan_tokens_padded": (padded - real) * n_kda}
     pairs = gateway._batcher.counts("moe_pairs")
-    assert pairs[0] == pairs[3:-1].sum() > 0 \
-        and len(pairs) == 4 + len(cfg.held)
+    assert pairs[0] == pairs[3:-2].sum() > 0 == pairs[-1] \
+        and len(pairs) == 5 + len(cfg.held)
 
 
 def test_bf16_passes_the_cells_limits():

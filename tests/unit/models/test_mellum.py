@@ -255,8 +255,8 @@ def test_slot_path_equals_the_reference_across_the_rings_wrap(tiny, served,
     np.testing.assert_allclose(got, ref, atol=ATOL, rtol=RTOL)
     pairs = gateway._batcher.counts("moe_pairs") \
         - (0 if before is None else before)
-    assert pairs[0] == pairs[3:-1].sum() > 0 == pairs[-1] \
-        and len(pairs) == 4 + len(cfg.held)
+    assert pairs[0] == pairs[3:-2].sum() > 0 == pairs[-2] == pairs[-1] \
+        and len(pairs) == 5 + len(cfg.held)
     padded, ticks = -(-n // CHUNK) * CHUNK, 8
     assert pairs[1] == (padded + ticks * 4) * cfg.n_layer \
         * cfg.experts_per_token
@@ -361,7 +361,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
                "w_down": p["w_down"][jnp.asarray(held)]}
         out, counts = window_moe.expert_ffn(x, own, mine)
         added = added + (out - x)
-        assert int(counts[0]) == int(counts[3:-1].sum())
+        assert int(counts[0]) == int(counts[3:-2].sum())
     np.testing.assert_allclose(np.asarray(added), np.asarray(whole - x),
                                atol=ATOL, rtol=RTOL)
     assert float(jnp.abs(whole - x).max()) > 1e-2

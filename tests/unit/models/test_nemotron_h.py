@@ -217,8 +217,8 @@ def test_slot_path_equals_the_reference_full_forward(n):
                      "scan_tokens_real": n * n_ssm,
                      "scan_tokens_padded": (padded - n) * n_ssm}
     pairs = gateway._batcher.counts("moe_pairs")
-    assert pairs[0] == pairs[3:-1].sum() > 0 == pairs[-1] \
-        and len(pairs) == 4 + len(cfg.held)
+    assert pairs[0] == pairs[3:-2].sum() > 0 == pairs[-2] == pairs[-1] \
+        and len(pairs) == 5 + len(cfg.held)
     # routed in all: every row of every call, the ticks' four slots each
     assert pairs[1] == (padded + ticks * 4) * cfg.count("experts") \
         * cfg.experts_per_token

@@ -223,8 +223,8 @@ def test_the_counters_follow_the_selection_and_the_ring(tiny, served):
     n, ticks = 30, 8
     _probe(served, file, cfg, params, n, ticks)
     pairs = served._batcher.counts("moe_pairs") - before["moe_pairs"]
-    assert pairs[0] == pairs[3:-1].sum() > 0 == pairs[-1] \
-        and len(pairs) == 4 + len(cfg.held)
+    assert pairs[0] == pairs[3:-2].sum() > 0 == pairs[-2] == pairs[-1] \
+        and len(pairs) == 5 + len(cfg.held)
     padded = -(-n // CHUNK) * CHUNK
     assert pairs[1] == (padded + ticks * 4) * (cfg.n_layer - 1) \
         * cfg.experts_per_token

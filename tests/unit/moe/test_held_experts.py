@@ -121,7 +121,7 @@ def test_the_held_pairs_in_pages_are_the_reference(name, form, stacked,
         assert here == ROUTINGS[name]
     pages_over = max(-(-here // CAP) - 1, 0)
     assert counts.dtype == jnp.int32
-    assert list(np.asarray(counts)) == per_expert + [pages_over]
+    assert list(np.asarray(counts)) == per_expert + [pages_over, 0]
     if name == "every_pair":
         assert pages_over == N_PAIRS // CAP - 1 > 0
     if name in ("none_here", "the_mean", "one_page", "one_expert_takes_all",
@@ -130,4 +130,4 @@ def test_the_held_pairs_in_pages_are_the_reference(name, form, stacked,
     vector = np.asarray(pair_counts(counts, N_PAIRS))
     assert vector.shape == (n_pair_counts(len(HELD)),)
     assert list(vector) == [here, N_PAIRS, sum(c > 0 for c in per_expert)
-                            ] + per_expert + [pages_over]
+                            ] + per_expert + [pages_over, 0]
