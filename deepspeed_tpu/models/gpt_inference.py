@@ -438,7 +438,10 @@ class Family:
     in ``cache.stats`` (name -> slice; the one place that knows);
     ``state_counters``: the names of its group ``state_steps``;
     ``select_counters``: the names of its group ``sparse_select`` (a family
-    whose queries attend to a selection of their cache)."""
+    whose queries attend to a selection of their cache); ``chunk_form(config,
+    chunk)``: the name of the form its passes of ``chunk`` positions take,
+    for a family whose passes have more than one (the latent families'
+    ``"up_projected"`` / ``"absorbed"``; None: one form, nothing to say)."""
     step: Any
     project: Any = _dense_project
     attend_fresh: Any = _dense_attend_fresh
@@ -453,6 +456,7 @@ class Family:
     stats_groups: Any = lambda config: {}
     state_counters: Tuple[str, ...] = ()
     select_counters: Tuple[str, ...] = ()
+    chunk_form: Any = None
 
     # the cache, the passes and the slot ops: the module's functions below
     # with this family in them, defined once for every family

@@ -12,10 +12,19 @@ What differs from ``models/gpt.py``'s block, mechanism by mechanism:
   values of all heads are up-projections of ONE compressed row per token
   (``kv_rank`` elements) plus one rotary key shared by all heads
   (``d_rope``).  That row, ``[c | R(k_r)]``, is all a cache keeps of a
-  token.  Here every pass is the *absorbed* form: the key up-projection is
-  folded into the query (``q' = W_kvb[k]^T q_n``), scores are ``q'.c +
-  R(q_r).R(k_r)`` against the row, the probabilities weigh ``c`` itself and
-  the value up-projection follows the sum;
+  token.  One algorithm, two forms, and a pass's SHAPE picks
+  (``decode_attention.latent_up_projects``).  The *absorbed* form: the key
+  up-projection is folded into the query (``q' = W_kvb[k]^T q_n``), scores
+  are ``q'.c + R(q_r).R(k_r)`` against the row, the probabilities weigh
+  ``c`` itself and the value up-projection follows the sum: nothing to do
+  a key, 3.4 times the operations a (query, key) pair.  It is a tick's
+  (one query a row: nothing to amortise an up-projection over), a few
+  tokens', the uncached ``apply``'s and every pass's under a bias.  The
+  *up-projected* form: keys and values of all heads made from the rows
+  once a call, as the published model's own prompt pass makes them: a
+  served chunk's (158 positions and more at the published widths), inside
+  the chunk kernel, through the layer's ``W_kvb`` where it lies in a
+  head-major copy of its stack (``head_major``, ``with_up``);
 - **YaRN** rotary frequencies on the rotary part alone, pairs interleaved;
   a config may leave the bottleneck out (``q_rank`` None: queries straight
   from the stream) and the rotation (``rope`` False: the row's shared key
@@ -198,6 +207,51 @@ def lora_rescale(d_model: int, rank: int) -> float:
     return math.sqrt(d_model / rank)
 
 
+def head_major(wkv_b, config):
+    """A stack of ``wkv_b`` ``[n, kv_rank, H, d_nope + d_v]`` as the
+    up-projected chunk kernel reads it: ``[n, H, kv_rank, d_nope + d_v]`` in
+    the compute dtype.  A family's ``step`` makes it ONCE, outside its layer
+    scan (a loop over chunks hoists it whole), and hands a layer's share to
+    the layer's parameters (``with_up``)."""
+    return jnp.swapaxes(wkv_b, 1, 2).astype(config.dtype)
+
+
+#: where ``with_up`` keeps ``(head-major stack, layer)`` in a layer's
+#: parameters: not a matrix of the layer, so no key of any stack
+_UP = "wkv_b_heads"
+
+
+def with_up(p, heads, layer):
+    """Layer ``layer``'s parameters ``p`` with the head-major stack
+    ``heads`` its ``wkv_b`` lies in: what lets ``latent_project`` and
+    ``latent_output`` take the up-projected form for a pass it pays in."""
+    return {**p, _UP: (heads, layer)}
+
+
+def chunk_form(config, S: int) -> str:
+    """The form a served pass of ``S`` positions takes at this config's
+    widths, by name: ``"up_projected"`` where the positions pay for a key's
+    up-projection (``decode_attention.latent_up_projects``), else
+    ``"absorbed"``.  A family's ``Family.chunk_form``: the batcher records
+    it with an admission's work."""
+    from ..ops.pallas.decode_attention import latent_up_projects
+    return "up_projected" if latent_up_projects(
+        S, config.n_head, config.cache_row[0], config.kv_rank, config.d_nope,
+        config.d_rope, config.d_v) else "absorbed"
+
+
+def up_projection(p, config, S: int):
+    """``decode_attention.LatentUp`` for a pass of ``S`` positions through
+    the layer whose parameters are ``p``, or None where the pass keeps the
+    absorbed form: no head-major stack at hand (``apply``; a family that
+    attends under a bias never brings one), or too few positions
+    (``chunk_form``)."""
+    from ..ops.pallas.decode_attention import LatentUp
+    if _UP not in p or chunk_form(config, S) == "absorbed":
+        return None
+    return LatentUp(*p[_UP], config.d_nope)
+
+
 def latent_project(x, p, config: LatentMoEConfig, positions,
                    q_scale: float = 1.0, kv_scale: float = 1.0):
     """One layer's attention inputs from ``x`` [B, S, d]: the absorbed
@@ -205,8 +259,12 @@ def latent_project(x, p, config: LatentMoEConfig, positions,
     ``[c | R(k_r)]`` [B, S, row], both zero past ``row_elements``.
     ``q_scale`` / ``kv_scale``: what the query latent and the key-value
     latent leave their norms multiplied by (``lora_rescale``), in float32
-    before the one rounding; the cached ``c`` is the scaled one, so the
-    absorbed form reads it as it lies (it scales keys AND values)."""
+    before the one rounding; the cached ``c`` is the scaled one, so either
+    form reads it as it lies (it scales keys AND values).
+
+    A pass that takes the up-projected form (``up_projection``) gets its
+    queries UN-ABSORBED, ``([q_n | R(q_r)] [B, S, H, d_nope + d_rope],
+    LatentUp)``, for ``cached_attention(latent_up=)``."""
     cdt = config.dtype
     H, r = config.n_head, config.kv_rank
 
@@ -228,26 +286,33 @@ def latent_project(x, p, config: LatentMoEConfig, positions,
     kv = jnp.einsum("bsd,dr->bsr", h, p["wkv_a"].astype(cdt))
     c = latent(kv[..., :r], p["kv_norm"], kv_scale)
     k_r = turn(kv[..., r:])
-    # absorb the key up-projection into the query
-    q_abs = jnp.einsum("bshe,rhe->bshr", q_n,
-                       p["wkv_b"][..., :config.d_nope].astype(cdt))
     pad = config.cache_row[0] - config.row_elements
-    queries = jnp.concatenate(
-        [q_abs, turn(q_r)]
-        + ([jnp.zeros(q_abs.shape[:3] + (pad,), cdt)] if pad else []), -1)
     row = jnp.concatenate(
         [c, k_r] + ([jnp.zeros(c.shape[:2] + (pad,), cdt)] if pad else []),
         -1)
+    up = up_projection(p, config, x.shape[1])
+    if up is not None:
+        return (jnp.concatenate([q_n, turn(q_r)], -1), up), row
+    # absorb the key up-projection into the query
+    q_abs = jnp.einsum("bshe,rhe->bshr", q_n,
+                       p["wkv_b"][..., :config.d_nope].astype(cdt))
+    queries = jnp.concatenate(
+        [q_abs, turn(q_r)]
+        + ([jnp.zeros(q_abs.shape[:3] + (pad,), cdt)] if pad else []), -1)
     return queries, row
 
 
 @jax.named_scope("attn_out")
 def latent_output(x, weighed, p, config: LatentMoEConfig):
     """``x + W_o concat_h(W_kvb[v] (sum_s p c))``: ``weighed`` [B, S, H,
-    kv_rank] is each head's probability-weighted sum of latent rows."""
+    kv_rank] is each head's probability-weighted sum of latent rows; from a
+    pass in the up-projected form (``up_projection``) it is the heads'
+    outputs [B, S, H, d_v] already."""
     cdt = config.dtype
-    v = jnp.einsum("bshr,rhe->bshe", weighed.astype(cdt),
-                   p["wkv_b"][..., config.d_nope:].astype(cdt))
+    v = weighed.astype(cdt)
+    if up_projection(p, config, x.shape[1]) is None:
+        v = jnp.einsum("bshr,rhe->bshe", v,
+                       p["wkv_b"][..., config.d_nope:].astype(cdt))
     return x + jnp.einsum("bshe,hed->bsd", v, p["wo"].astype(cdt),
                           preferred_element_type=jnp.float32)
 

@@ -14,10 +14,14 @@ what ``gpt_inference.Family`` asks of a model family:
   layers add their pair counts to ``cache.stats`` (``[5 + n_held]`` int32:
   pairs held here, pairs routed, expert visits, pairs per held expert, pages
   of pairs run beyond a call's first, pairs on zero-compute experts);
-- projections and attention in the absorbed form, every pass through
-  ``ops/pallas/decode_attention.py``'s latent kernels (a prompt pass is a
-  chunk at position 0: the expert layer's cost is linear in a call's
-  tokens, so no family-side chunk walk bounds it).
+- projections and attention through ``ops/pallas/decode_attention.py``'s
+  latent kernels, in the form a pass's shape picks
+  (``latent_moe.up_projection``): a tick and a few tokens absorbed, a
+  chunk up-projected inside the chunk kernel, through the head-major copy
+  of each stack's ``wkv_b`` that ``step`` makes outside its scans and
+  hands every layer (``latent_moe.with_up``).  A prompt pass is a chunk at
+  position 0: the expert layer's cost is linear in a call's tokens, so no
+  family-side chunk walk bounds it.
 
 Not supported (``UNSUPPORTED``), each refused where it is asked for: the
 int8 cache where the cache is made (its scale banks are per head; a latent
@@ -69,13 +73,17 @@ def _step(params: PyTree, config: LatentMoEConfig, valid=None):
     first = config.first_k_dense
     moe = params["moe_blocks"]
     routed = {k: moe[k] for k in _ROUTED}
+    heads = {k: latent_moe.head_major(params[k]["wkv_b"], config)
+             for k in ("dense_blocks", "moe_blocks")}
 
     def dense_body(x, p, i, attend, cache):
+        p = latent_moe.with_up(p, heads["dense_blocks"], i)
         a, cache = attend(x, p, i, cache)
         x = latent_moe.latent_output(x, a, p, config)
         return latent_moe.dense_ffn(x, p, config), cache
 
     def moe_body(x, p, i, attend, cache):
+        p = latent_moe.with_up(p, heads["moe_blocks"], i)
         a, cache = attend(x, p, first + i, cache)
         x = latent_moe.latent_output(x, a, p, config)
         x, counts = latent_moe.expert_ffn(x, p, config, experts=routed,
@@ -99,10 +107,12 @@ def _project(x, p, config: LatentMoEConfig, positions):
 def _attend_cached(q, cache: KVCache, pos, config: LatentMoEConfig, idx,
                    active=None, sweep=None):
     from ..ops.pallas.decode_attention import cached_attention
+    # a pass in the up-projected form brings its layer's up-projection
+    q, up = q if isinstance(q, tuple) else (q, None)
     return cached_attention(q, cache.k, None, pos,
                             sm_scale=config.softmax_scale, layer=idx,
                             active=active, sweep=sweep,
-                            latent_rank=config.kv_rank)
+                            latent_rank=config.kv_rank, latent_up=up)
 
 
 def _attend_fresh(q, fresh, cache, config: LatentMoEConfig, idx):
@@ -117,4 +127,4 @@ FAMILY = gpt_inference.Family(
         latent_moe.embed(params, tokens, config),
     logits=latent_moe.lm_logits, apply=latent_moe.apply,
     logical_axes=latent_moe.logical_axes, unsupported=UNSUPPORTED,
-    stats_groups=stats_groups)
+    stats_groups=stats_groups, chunk_form=latent_moe.chunk_form)

@@ -16,7 +16,9 @@ what ``gpt_inference.Family`` asks of a model family:
   ops insert, read and zero it with the bank;
 - the **step**: one segment per run (``config.units``).  A latent layer goes
   through the scan's ``attend`` at its index among the latent layers, every
-  pass through ``ops/pallas/decode_attention.py``'s latent kernels; a KDA
+  pass through ``ops/pallas/decode_attention.py``'s latent kernels in the
+  form its shape picks (``latent_moe.up_projection``; a run's latent
+  ``wkv_b`` stacks have their head-major copies); a KDA
   layer advances layer ``j`` of the state stacks in place: one token a live
   slot through ``kda_decode_step`` (a freed slot neither steps nor moves), a
   chunk through ``kda_chunk_scan`` to the state after the chunk's last REAL
@@ -46,6 +48,9 @@ from . import gpt_inference, latent_moe, linear_latent_moe as model
 from .gpt_inference import KVCache
 from .hybrid_ssm_moe import run_parts
 from .hybrid_ssm_moe_inference import STATE_COUNTERS
+# the latent layers' projection and their two calls of the latent kernels are
+# the latent family's own: they read a config's widths and nothing else
+from .latent_moe_inference import _attend_cached, _attend_fresh, _project
 from .linear_latent_moe import DENSE, KDA, ROUTED, LinearLatentMoEConfig
 
 PyTree = Any
@@ -112,8 +117,9 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
     work = ssm.live_rows(valid > 0, valid.shape[0])
     groups = stats_groups(config)
 
-    def layer(x, label, p, experts, i, j, attend, cache):
-        """Layer ``j`` of its mixer's kind, repetition ``i`` of its run."""
+    def layer(x, label, p, experts, heads, i, j, attend, cache):
+        """Layer ``j`` of its mixer's kind, repetition ``i`` of its run;
+        ``heads``: a latent layer's ``wkv_b`` stack, head-major."""
         stats = cache.stats
         if label.startswith(KDA):
             x, state, counters = _kda_mixer(x, p, j, cache, valid, work,
@@ -122,6 +128,7 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
             stats = stats.at[groups["state_steps"]].add(counters)
         else:
             with jax.named_scope("latent_attention"):
+                p = latent_moe.with_up(p, heads, i)
                 a, cache = attend(x, p, j, cache)
                 x = latent_moe.latent_output(x, a, p, config)
         x, counts = model.ffn(x, p, config, label, experts=experts, layer=i)
@@ -137,12 +144,15 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
         # the body closes over the run's whole stacks
         routed = [None if label.endswith(DENSE) else
                   {k: p[k] for k in ROUTED} for label, p in zip(unit, parts)]
+        heads = [None if kind == KDA else
+                 latent_moe.head_major(p["wkv_b"], config)
+                 for kind, p in zip(kinds, parts)]
 
         def body(x, ps, i, attend, cache, unit=unit, kinds=kinds,
-                 firsts=firsts, routed=routed):
-            for label, kind, first, p, experts in zip(unit, kinds, firsts,
-                                                       ps, routed):
-                x, cache = layer(x, label, p, experts, i,
+                 firsts=firsts, routed=routed, heads=heads):
+            for label, kind, first, p, experts, up in zip(
+                    unit, kinds, firsts, ps, routed, heads):
+                x, cache = layer(x, label, p, experts, up, i,
                                  first + i * kinds.count(kind), attend, cache)
             return x, cache
 
@@ -153,25 +163,6 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
     return segments
 
 
-def _project(x, p, config: LinearLatentMoEConfig, positions):
-    queries, row = latent_moe.latent_project(x, p, config, positions)
-    return queries, (row,)
-
-
-def _attend_cached(q, cache: KVCache, pos, config: LinearLatentMoEConfig,
-                   idx, active=None, sweep=None):
-    from ..ops.pallas.decode_attention import cached_attention
-    return cached_attention(q, cache.k, None, pos,
-                            sm_scale=config.softmax_scale, layer=idx,
-                            active=active, sweep=sweep,
-                            latent_rank=config.kv_rank)
-
-
-def _attend_fresh(q, fresh, cache, config: LinearLatentMoEConfig, idx):
-    # a prompt pass is a chunk at position 0 of the rows just written
-    return _attend_cached(q, cache, jnp.zeros((), jnp.int32), config, idx)
-
-
 FAMILY = gpt_inference.Family(
     step=_step, project=_project, attend_fresh=_attend_fresh,
     attend_cached=_attend_cached, windows=lambda config, max_len: None,
@@ -179,4 +170,5 @@ FAMILY = gpt_inference.Family(
         model.embed(params, tokens, config),
     logits=model.lm_logits, apply=model.apply,
     logical_axes=model.logical_axes, unsupported=UNSUPPORTED,
-    stats_groups=stats_groups, state_counters=STATE_COUNTERS)
+    stats_groups=stats_groups, state_counters=STATE_COUNTERS,
+    chunk_form=latent_moe.chunk_form)

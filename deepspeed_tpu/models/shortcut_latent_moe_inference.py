@@ -16,9 +16,10 @@ what ``gpt_inference.Family`` asks of a model family:
   stacks read in place; the branch's pair counts go to ``cache.stats``
   (``held_experts.pair_counts``: with ``zero``, the pairs on zero-compute
   experts, last);
-- projections and attention in the absorbed form, every pass through
-  ``ops/pallas/decode_attention.py``'s latent kernels, as
-  ``latent_moe_inference``'s.
+- projections and attention through ``ops/pallas/decode_attention.py``'s
+  latent kernels in the form a pass's shape picks, as
+  ``latent_moe_inference``'s: each sublayer's ``wkv_b`` stack has its
+  head-major copy.
 
 Not supported (``UNSUPPORTED``), each refused where it is asked for: the
 int8 cache where the cache is made, speculation, paging and pooled prefixes
@@ -30,7 +31,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict
 
-from . import gpt_inference
+from . import gpt_inference, latent_moe
 from . import shortcut_latent_moe as model
 from .gpt_inference import KVCache  # noqa: F401  (the one cache class)
 from ..moe.held_experts import n_pair_counts
@@ -72,9 +73,14 @@ _ROUTED = ("w_gu", "w_down")
 def _step(params: PyTree, config: ShortcutLatentMoEConfig, valid=None):
     blocks = params["blocks"]
     routed = {k: blocks["moe"][k] for k in _ROUTED}
+    sublayers = ("attn0", "attn1")
+    heads = {k: latent_moe.head_major(blocks[k]["wkv_b"], config)
+             for k in sublayers}
 
     def body(x, p, i, attend, cache):
         held = [cache]
+        p = {**p, **{k: latent_moe.with_up(p[k], heads[k], i)
+                     for k in sublayers}}
 
         def attend_sublayer(x, pa, j):
             a, held[0] = attend(x, pa, 2 * i + j, held[0])
@@ -101,4 +107,4 @@ FAMILY = gpt_inference.Family(
         model.embed(params, tokens, config),
     logits=model.lm_logits, apply=model.apply,
     logical_axes=model.logical_axes, unsupported=UNSUPPORTED,
-    stats_groups=stats_groups)
+    stats_groups=stats_groups, chunk_form=latent_moe.chunk_form)

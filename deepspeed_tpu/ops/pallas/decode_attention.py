@@ -41,7 +41,14 @@ itself; the value up-projection follows outside).  The decode kernel walks
 the same work list (``decode_sweep``) and reads the pool in place; a block
 is read once for all heads, 121 operations a byte at 64 heads, so it is the
 one kernel here that sits near the ridge and not far under it.
-``cached_attention`` picks them when the cache has one bank.
+``cached_attention`` picks them when the cache has one bank.  A chunk long
+enough to pay for it (``latent_up_projects``: the call's static shape
+decides, no option does) takes the UP-PROJECTED form instead, in one kernel
+of its own (``_latent_up_chunk``): un-absorbed queries, every head's keys
+and values made from a block of rows in VMEM through the layer's ``W_kvb``
+(``LatentUp``: a head-major stack, the layer by scalar prefetch), a third
+of the operations a (query, key) pair.  A tick, a few tokens and every
+call under a bias keep the absorbed kernels.
 
 Grouped heads (``kv_heads`` of ``cached_attention``): ``H`` query heads on
 ``H / G`` key-value heads.  The row stays ``H/G * D``; the decode kernel
@@ -529,6 +536,8 @@ KINDS = ("full", "window")
 DENSE_SWEEP = "decode_attention"
 GROUPED_SWEEP = "gqa_decode_attention"
 LATENT_SWEEP = "latent_decode_attention"
+#: the chunk kernel over latent rows that up-projects its keys and values
+LATENT_UP_CHUNK = "latent_chunk_attention_up"
 
 
 class SweepPlan(NamedTuple):
@@ -1168,19 +1177,229 @@ def _latent_chunk(q, bank, layer, pos, sm_scale, block_q, block_k, R,
     return o.reshape(B, Sq, H, R)
 
 
+class LatentUp(NamedTuple):
+    """A layer's key-value up-projection, for a call that hands
+    ``latent_cached_attention`` its queries un-absorbed.  ``w``: a stack
+    ``[layers, H, rank, d_nope + d_v]`` in the compute dtype, HEAD-major
+    (``[k | v]`` of a head side by side), made once outside the layer scan:
+    the kernel reads layer ``layer`` of it where it lies (a layer sliced
+    out first is a copy of 16.8 MB at the published widths).  ``d_nope``:
+    where a head's key part ends."""
+    w: jax.Array
+    layer: jax.Array
+    d_nope: int
+
+
+def latent_up_projects(Sq: int, H: int, W: int, rank: int, d_nope: int,
+                       d_rope: int, d_v: int) -> bool:
+    """Whether a call of ``Sq`` query positions over latent rows is cheaper
+    UP-PROJECTED (every key and value made from its row once a head a call,
+    ``2 rank H (d_nope + d_v)`` operations a key, then ``2 H (d_nope +
+    d_rope + d_v)`` a (query, key) pair) than ABSORBED (``2 H (W + rank)`` a
+    pair against the row as stored and nothing a key): one algorithm, two
+    costs, decided by the call's static widths.  At 64 heads of 128 + 64 |
+    128 over a rank of 512 stored 640 wide: from 158 positions on, so a
+    prompt's chunk up-projects and a tick or a verify's few tokens do
+    not."""
+    return Sq * (2 * H * (W + rank) - 2 * H * (d_nope + d_rope + d_v)) \
+        > 2 * rank * H * (d_nope + d_v)
+
+
+#: bytes of VMEM the up-projected chunk kernel sizes its step to (what it
+#: counts of its buffers and temporaries; the compiler's own scratch comes
+#: on top, and a v5e kernel may use 16 MiB)
+_UP_VMEM = 12 << 20
+
+
+def latent_up_tiles(Sq: int, H: int, W: int, rank: int, d_nope: int,
+                    d_v: int, block_k: int, itemsize: int = 2):
+    """``(heads, block_q)`` of the up-projected chunk kernel's step, or None
+    where its tiles do not fit: every width in whole lane rows and the chunk
+    in whole sublane tiles.  A step holds the chunk's queries of ``heads``
+    heads (the largest group of 4, 2, 1 whose buffers stay under
+    ``_UP_VMEM``) and scores them ``block_q`` positions at a time (512 at
+    most: the float32 scores and probabilities of 512 x 512 are 1 MB
+    each)."""
+    if any(n % 128 for n in (W, rank, d_nope, d_v)) or Sq % 16:
+        return None
+    block_q = Sq if Sq <= 512 else next(
+        b for b in (512, 256, 128, 64, 32, 16) if Sq % b == 0)
+    E, Eq = d_nope + d_v, d_nope + W - rank
+    step = 2 * block_k * W * itemsize \
+        + block_q * block_k * (8 + itemsize) \
+        + block_k * (E * (4 + itemsize) + Eq * itemsize)
+
+    def head(n):    # queries and results twice, the accumulator, max and sum
+        return n * (Sq * (2 * (Eq + d_v) * itemsize + 4 * d_v + 2 * 4 * 128)
+                    + 2 * rank * E * itemsize)
+    return next(((n, block_q) for n in (4, 2, 1)
+                 if H % n == 0 and step + head(n) <= _UP_VMEM), None)
+
+
+def _latent_up_chunk_kernel(pos_ref, layer_ref, up_layer_ref, q_ref, c_ref,
+                            w_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                            sm_scale, block_q, block_k, nk, R, d_nope):
+    """A chunk of UN-ABSORBED queries against latent rows, keys and values
+    up-projected in VMEM.  A grid step is a GROUP OF HEADS against a key
+    block: ``q_ref`` ``(Hg, Sq, Eq)`` holds the whole chunk's queries of
+    the group, ``[q_n | R(q_r) | 0]`` a head (``Eq = d_nope + W - R``
+    lanes), across the key blocks; ``c_ref`` ``(block_k, W)`` the block's
+    rows ``[c | R(k_r) | 0]``; ``w_ref`` ``(Hg, R, d_nope + d_v)`` the
+    group's ``W_kvb``.  For each head the block's ``[k_n | v] = c W_kvb``
+    is ONE product, rounded once to the compute dtype (as the published
+    attention and the plain reference round their keys and values); the
+    head's key is ``[k_n | R(k_r) | 0]``, the row's own lanes past ``R``
+    beside it (shared by the heads; the queries' lanes there are zero where
+    the row's hold nothing), so a score is one product ``Eq`` deep; then the
+    float32 online softmax over values ``d_v`` wide.  So a key is
+    up-projected once a head a call, which is what the form's cost counts.
+
+    The key axis of the grid ends at the chunk's causal frontier (a dynamic
+    bound: no step past it), and a ``block_q`` tile of positions skips a
+    key block wholly past ITS frontier.  Row ``b``'s result is final after
+    its own last block (a shorter row of a ragged batch waits there)."""
+    b = pl.program_id(0)
+    ki = pl.program_id(2)
+    Hg, Sq = q_ref.shape[:2]
+    pos = pos_ref[b]
+    last = jnp.minimum((pos + Sq - 1) // block_k, nk - 1)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def tile(h, t, keys, values):
+        rows = pl.ds(t * block_q, block_q)
+        s = jax.lax.dot_general(q_ref[h, rows, :], keys,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale                                # (BQ, BK)
+        q_pos = pos + t * block_q + \
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = ki * block_k + \
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _online_softmax_step(jnp.where(k_pos <= q_pos, s, NEG_INF), values,
+                             acc_ref.at[h, rows], m_ref.at[h, rows],
+                             l_ref.at[h, rows])
+
+    @pl.when(ki <= last)
+    def _update():
+        c = c_ref[...]                                  # (BK, W)
+        for h in range(Hg):
+            kv = jnp.dot(c[:, :R], w_ref[h],
+                         preferred_element_type=jnp.float32).astype(c.dtype)
+            keys = jnp.concatenate([kv[:, :d_nope], c[:, R:]], axis=1)
+            values = kv[:, d_nope:]                     # (BK, d_v)
+            for t in range(Sq // block_q):
+                if t == Sq // block_q - 1:  # the last tile sees every block
+                    tile(h, t, keys, values)
+                else:
+                    pl.when(ki * block_k <= pos + (t + 1) * block_q - 1)(
+                        functools.partial(tile, h, t, keys, values))
+
+    @pl.when(ki == last)
+    def _finalize():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _latent_up_chunk(q, bank, layer, pos, sm_scale, up: LatentUp, R, tiles):
+    """``q`` [B, Sq, H, d_nope + d_rope] un-absorbed against layer ``layer``
+    of ``bank`` [L, B, Smax, W], through layer ``up.layer`` of ``up.w``:
+    each head's attention output [B, Sq, H, d_v].  ONE custom call; its
+    result is ``[B * H, Sq, d_v]``."""
+    B, Sq, H, e = q.shape
+    Smax, W = bank.shape[2:]
+    Hg, block_q = tiles
+    block_k = latent_block_k(Smax)
+    nk = Smax // block_k
+    E = up.w.shape[-1]
+    d_v, Eq = E - up.d_nope, up.d_nope + W - R
+    # head-major, the lanes beside the row's ``[R(k_r) | 0]`` zero
+    q = jnp.pad(q, ((0, 0),) * 3 + ((0, Eq - e),)).transpose(0, 2, 1, 3)
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    interpret = interpret_mode()
+    # key blocks up to the longest row's causal frontier
+    live = jnp.minimum((jnp.max(pos_arr) + Sq - 1) // block_k + 1, nk)
+    kernel = functools.partial(_latent_up_chunk_kernel, sm_scale=sm_scale,
+                               block_q=block_q, block_k=block_k, nk=nk, R=R,
+                               d_nope=up.d_nope)
+
+    def c_idx(b, g, ki, pos_ref, layer_ref, up_layer_ref):
+        last = jnp.minimum((pos_ref[b] + Sq - 1) // block_k, nk - 1)
+        return (layer_ref[0], b, jnp.minimum(ki, last), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, H // Hg, nk if interpret else live),
+        in_specs=[
+            pl.BlockSpec((None, Hg, Sq, Eq), lambda b, g, ki, *_: (b, g, 0, 0)),
+            pl.BlockSpec((None, None, block_k, W), c_idx),
+            pl.BlockSpec((None, Hg, R, E),
+                         lambda b, g, ki, pos_ref, layer_ref, up_layer_ref:
+                         (up_layer_ref[0], g, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((Hg, Sq, d_v),
+                               lambda b, g, ki, *_: (b * (H // Hg) + g, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((Hg, Sq, d_v), jnp.float32),
+            pltpu.VMEM((Hg, Sq, 1), jnp.float32),
+            pltpu.VMEM((Hg, Sq, 1), jnp.float32),
+        ],
+    )
+    o = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq, d_v), q.dtype),
+        interpret=interpret, name=LATENT_UP_CHUNK)(
+            pos_arr, jnp.asarray(layer, jnp.int32).reshape(1),
+            jnp.asarray(up.layer, jnp.int32).reshape(1), q, bank, up.w)
+    return o.reshape(B, H, Sq, d_v).transpose(0, 2, 1, 3)
+
+
 def latent_cached_attention(q, bank, pos, sm_scale: float, rank: int,
-                            layer=None, active=None, sweep=None, bias=None):
-    """Absorbed latent attention: ``q`` [B, Sq, H, W] over the latent pool
-    ``bank`` [L, B, Smax, W] at ``layer`` (or one layer [B, Smax, W]),
-    visibility ``<= pos + i``; returns each head's weighted sum of the rows'
-    first ``rank`` elements, [B, Sq, H, rank].  ``active`` and ``sweep`` as
-    in ``cached_attention``.  ``bias`` [B, Sq, Smax] float32, 0 or -inf a
-    (query, key): what more than causality hides (a query's selection, a
-    ring's band); the sweep still steps and streams every live block."""
-    B, Sq, H, W = q.shape
+                            layer=None, active=None, sweep=None, bias=None,
+                            up: Optional[LatentUp] = None):
+    """Latent attention: ``q`` over the latent pool ``bank`` [L, B, Smax,
+    W] at ``layer`` (or one layer [B, Smax, W]), visibility ``<= pos + i``.
+
+    ABSORBED (``up`` None): ``q`` [B, Sq, H, W] holds the absorbed queries;
+    returns each head's weighted sum of the rows' first ``rank`` elements,
+    [B, Sq, H, rank].  ``active`` and ``sweep`` as in ``cached_attention``.
+    ``bias`` [B, Sq, Smax] float32, 0 or -inf a (query, key): what more than
+    causality hides (a query's selection, a ring's band); the sweep still
+    steps and streams every live block.
+
+    With ``up`` (``LatentUp``) ``q`` [B, Sq, H, d_nope + d_rope] is
+    UN-ABSORBED and the result each head's attention output [B, Sq, H,
+    d_v].  The call's shape decides the form (``latent_up_projects``): a
+    chunk with no bias whose tiles fit up-projects its keys and values
+    inside the one chunk kernel; any other call absorbs ``up`` into the
+    queries here, takes the absorbed path and up-projects what it
+    returns."""
     if layer is None:
         bank, layer = bank[None], 0
     Smax = bank.shape[2]
+    if up is not None:
+        B, Sq, H, e = q.shape
+        W, d_nope = bank.shape[-1], up.d_nope
+        d_v = up.w.shape[-1] - d_nope
+        block_k = latent_block_k(Smax)
+        tiles = block_k is not None and latent_up_tiles(
+            Sq, H, W, rank, d_nope, d_v, block_k, q.dtype.itemsize)
+        if use_pallas() and tiles and bias is None and latent_up_projects(
+                Sq, H, W, rank, d_nope, e - d_nope, d_v):
+            return _latent_up_chunk(q, bank, layer, pos, sm_scale, up, rank,
+                                    tiles)
+        w = jax.lax.dynamic_index_in_dim(up.w, up.layer, 0, keepdims=False)
+        absorbed = jnp.einsum("bshe,hre->bshr", q[..., :d_nope],
+                              w[..., :d_nope])
+        weighed = latent_cached_attention(
+            jnp.pad(jnp.concatenate([absorbed, q[..., d_nope:]], -1),
+                    ((0, 0),) * 3 + ((0, W - rank - (e - d_nope)),)),
+            bank, pos, sm_scale, rank, layer, active, sweep, bias)
+        return jnp.einsum("bshr,hre->bshe", weighed, w[..., d_nope:])
+    B, Sq, H, W = q.shape
     plan = sweep_plan((W,), Smax, H)
     block_k = plan.block_k
     tiles = use_pallas() and plan.kernel is not None
@@ -1213,7 +1432,8 @@ def cached_attention(q, cache_k, cache_v, pos,
                      k_scale=None, v_scale=None,
                      window=None, slopes=None, layer=None,
                      active=None, sweep=None, latent_rank=None,
-                     kv_heads: Optional[int] = None, valid_from=None):
+                     kv_heads: Optional[int] = None, valid_from=None,
+                     latent_up: Optional[LatentUp] = None):
     """q [B,Sq,H,D] over a padded cache [B,Smax,H,D], visibility ≤ pos+i.
 
     ``kv_heads`` (default ``H``): grouped heads.  The cache holds
@@ -1230,7 +1450,8 @@ def cached_attention(q, cache_k, cache_v, pos,
 
     A cache of ONE bank (``cache_v`` None) is a latent pool: ``q`` holds the
     absorbed queries, ``latent_rank`` says how much of a row the
-    probabilities weigh, and ``latent_cached_attention`` serves the call.
+    probabilities weigh, and ``latent_cached_attention`` serves the call
+    (``latent_up``: its ``up``, with un-absorbed queries).
 
     With ``layer`` (scalar, may be traced — a layer scan's index) the
     cache operands are the whole stacked pool as ``gpt_inference`` stores
@@ -1280,7 +1501,8 @@ def cached_attention(q, cache_k, cache_v, pos,
     if cache_v is None:
         return latent_cached_attention(q, cache_k, pos, sm_scale,
                                        latent_rank, layer=layer,
-                                       active=active, sweep=sweep)
+                                       active=active, sweep=sweep,
+                                       up=latent_up)
     B, Sq, H, D = q.shape
     Hkv = H if kv_heads is None else int(kv_heads)
     G = H // Hkv

@@ -810,6 +810,9 @@ class SlotBatcher:
         # what the launch computes, for its host span and its device span
         work = dict(tokens=S, chunk=C, padded=n_chunks * C, passes=passes,
                     wide=wide)
+        if self._fam.chunk_form is not None:
+            # which of its forms the program's passes take at this chunk
+            work["form"] = self._fam.chunk_form(self._cfg, C)
         with self.tracer.span(SpanName.SERVE_PREFILL, start=start,
                               chunks=n_chunks, **work):
             (self.cache, self.lengths, self._last, self.keys, self.greedy,

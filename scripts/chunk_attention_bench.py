@@ -6,12 +6,23 @@ and PERF.md 6 (PR 49) quote was measured with it.
 
     chiprun -- python scripts/chunk_attention_bench.py [--cells code,agent]
         [--tiles rule,2048x256,1024x1024] [--parent DIR]
+    chiprun -- python scripts/chunk_attention_bench.py \
+        --cells docqa,reason,think [--tiles rule,h1,h4] [--parent DIR]
 
 ``--tiles``: ``rule`` is the tree's own ``chunk_block_q`` / ``chunk_block_k``;
 ``ROWSxKEYS`` overrides them for the run (the most query rows a step and the
 keys a block; a slot the keys do not tile takes the next size down).
 ``--parent DIR``: also time ``DIR``'s ``decode_attention.py`` (another
 checkout's kernel) on the same inputs and compare the results.
+
+The LATENT cells (``docqa``, ``reason``, ``think``: ``LATENT``) time the
+latent chunk kernel's one custom call at a sublayer's shapes over a prompt's
+chunk positions: un-absorbed queries and the layer's ``W_kvb`` through
+``latent_cached_attention(up=)``, whose form the call's shape picks.  A
+tile ``h<n>`` holds ``n`` heads a step of the up-projected kernel
+(``latent_up_tiles``); a tree with no ``LatentUp`` (a ``--parent`` from
+before PR 58) is handed the absorbed queries and up-projects its result
+outside, as its model did, and only its kernel is timed.
 
 Chip only: a time is a chip's (``utils.platform.require_tpu``)."""
 
@@ -65,6 +76,49 @@ CELLS = {
 }
 
 
+#: cell -> (Sq, heads, slot length), its chunk positions; every cell's row is
+#: [c (512) | R(k_r) (64) | 0] in 640 lanes, heads of 128 + 64 | 128
+LATENT = {
+    # longcat-serve-docqa-sat: 2,560-3,584 tokens in chunks of 512
+    "docqa": ((512, 64, 6144), (0, 512, 1024, 1536, 2048, 2560, 3072)),
+    # kimik2-serve-reason-sat: 1,024-4,000 tokens in chunks of 512
+    "reason": ((512, 64, 8192), (0, 512, 1024, 1536, 2048, 2560)),
+    # kimilin-serve-think-sat: 1,536-2,560 tokens in chunks of 1,024
+    "think": ((1024, 32, 8192), (0, 1024, 2048)),
+}
+_RANK, _NOPE, _ROPE, _V, _ROW = 512, 128, 64, 128, 640
+
+
+def _latent_program(mod, geo, pos):
+    """One sublayer's chunk call at ``pos``: layer 1 of a pool of two
+    through layer 1 of a stack of two."""
+    Sq, H, Smax = geo
+    keys = jax.random.split(jax.random.PRNGKey(Smax + pos), 3)
+    q = jax.random.normal(keys[0], (1, Sq, H, _NOPE + _ROPE), jnp.bfloat16)
+    bank = jax.random.normal(keys[1], (2, 1, Smax, _ROW), jnp.bfloat16)
+    bank = bank.at[..., _RANK + _ROPE:].set(0)
+    w = (jax.random.normal(keys[2], (2, H, _RANK, _NOPE + _V), jnp.float32)
+         * _RANK ** -0.5).astype(jnp.bfloat16)
+    scale = (_NOPE + _ROPE) ** -0.5
+
+    def up_projected(q, bank, w, pos):
+        return mod.latent_cached_attention(
+            q, bank, pos, scale, _RANK, layer=1,
+            up=mod.LatentUp(w, jnp.int32(1), _NOPE))
+
+    def absorbed(q, bank, w, pos):
+        q_abs = jnp.einsum("bshe,hre->bshr", q[..., :_NOPE],
+                           w[1, ..., :_NOPE])
+        queries = jnp.pad(jnp.concatenate([q_abs, q[..., _NOPE:]], -1),
+                          ((0, 0),) * 3 + ((0, _ROW - _RANK - _ROPE),))
+        weighed = mod.latent_cached_attention(queries, bank, pos, scale,
+                                              _RANK, layer=1)
+        return jnp.einsum("bshr,hre->bshe", weighed, w[1, ..., _NOPE:])
+
+    fn = jax.jit(up_projected if hasattr(mod, "LatentUp") else absorbed)
+    return fn, (q, bank, w, jnp.full((1,), pos, jnp.int32))
+
+
 def _other_tree(path):
     """``decode_attention.py`` of another checkout as a module of its own
     (its ``.utils`` is this tree's)."""
@@ -112,7 +166,8 @@ def _kernel_ms(programs):
 def main():
     from deepspeed_tpu.utils.platform import require_tpu
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--cells", default=",".join(CELLS))
+    ap.add_argument("--cells", default=",".join(CELLS),
+                    help=f"of {', '.join(list(CELLS) + list(LATENT))}")
     ap.add_argument("--tiles", default="rule")
     ap.add_argument("--parent")
     args = ap.parse_args()
@@ -120,12 +175,20 @@ def main():
     rule_k, rule_q = da.chunk_block_k, da.chunk_block_q
     variants = [("parent", _other_tree(args.parent))] if args.parent else []
     variants += [(tile, da) for tile in args.tiles.split(",")]
+    rule_up = da.latent_up_tiles
     for cell in args.cells.split(","):
-        geo, calls = CELLS[cell]
+        latent = cell in LATENT
+        geo, calls = LATENT[cell] if latent else CELLS[cell]
+        if latent:
+            calls = [(1, dict(Smax=geo[2], pos=p)) for p in calls]
         want = {}
         for name, mod in variants:
             da.chunk_block_k, da.chunk_block_q = rule_k, rule_q
-            if "x" in name:
+            da.latent_up_tiles = rule_up
+            if latent and name.startswith("h"):
+                da.latent_up_tiles = lambda *a, n=int(name[1:]), **kw: \
+                    (n, rule_up(*a, **kw)[1])
+            elif "x" in name:
                 rows, keys = (int(n) for n in name.split("x"))
                 da.chunk_block_k = lambda Smax: next(
                     b for b in (2048, 1024, 512, 256, 128)
@@ -133,7 +196,8 @@ def main():
                 da.chunk_block_q = lambda Sq, G, block_k: next(
                     b for b in (256, 128, 64, 32, 16, 8)
                     if Sq % b == 0 and (G * b <= rows or b == 8))
-            programs = [_program(mod, geo, call) for _, call in calls]
+            programs = [_latent_program(mod, geo, call["pos"]) if latent
+                        else _program(mod, geo, call) for _, call in calls]
             for i, (fn, a) in enumerate(programs):    # compile, and compare
                 got = np.asarray(fn(*a), np.float32)
                 err = float(np.abs(got - want.setdefault(i, got)).max())

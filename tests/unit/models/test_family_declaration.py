@@ -93,6 +93,11 @@ def test_cache_family_returns_the_whole_declaration(name):
         value = getattr(fam, field.name)
         if field.name == "prompt_pass":      # GPT-MoE's one real override
             assert (value is not None) == (name == "moe")
+        elif field.name == "chunk_form":
+            # the families whose latent chunk passes have two forms (the
+            # selecting one's are all biased: the absorbed form alone)
+            assert (value is not None) == (name in ("latent", "linear",
+                                                    "shortcut"))
         elif field.name in ("unsupported", "state_counters",
                             "select_counters"):
             assert value is not None

@@ -89,7 +89,10 @@ def test_apply_equals_the_reference():
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 0,
                                 cfg.vocab_size)
     got = hybrid_ssm_moe.apply(params, tokens, cfg)[..., :cfg.vocab_size]
-    ref = reference.forward(file, params, tokens, 40)
+    # compiled: op by op the reference compiles every primitive of every
+    # new shape on its own
+    ref = jax.jit(lambda p, t: reference.forward(file, p, t, 40))(params,
+                                                                  tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5,
                                rtol=1e-4)
 
@@ -109,8 +112,10 @@ def test_slot_path_equals_the_reference_full_forward(n):
     ticks = 8
     replies, got = gateway.probe_logits(prompts, ticks)
     full = np.concatenate([prompts[0], np.asarray(replies[0], np.int32)])
-    ref = np.asarray(reference.forward(file, params, full[None],
-                                       ticks + 1))[0]
+    # compiled: op by op the reference compiles every primitive of every
+    # new shape on its own
+    ref = np.asarray(jax.jit(lambda p, t: reference.forward(
+        file, p, t, ticks + 1))(params, full[None]))[0]
     np.testing.assert_allclose(got[0][:, :cfg.vocab_size], ref, atol=2e-5,
                                rtol=1e-4)
     # the counters, each group where the family's layout puts it

@@ -222,6 +222,119 @@ def test_latent_kernels_under_the_interpreter(monkeypatch, sq):
         assert not np.asarray(got)[1].any()      # a dead row returns zeros
 
 
+def _published_heads(heads, dtype, **keys):
+    """One attention layer at the published head widths (128 + 64 | 128)
+    over a small rank and stream, a stack of two."""
+    cfg = latent_moe.LatentMoEConfig(**{**dict(
+        n_head=heads, d_model=64, q_rank=32, kv_rank=128, d_nope=128,
+        d_rope=64, d_v=128, dtype=dtype, param_dtype=dtype), **keys})
+    stack = latent_moe.attention_init(jax.random.PRNGKey(heads), cfg, 2,
+                                      0.1, 0.02)
+    return cfg, stack
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("heads,keys,kv_scale", [
+    (32, {}, 1.0), (64, {}, 1.0), (32, {"rope": False, "q_rank": None}, 1.0),
+    (32, {}, 3.46)], ids=["32", "64", "32-nope", "32-kv_scale"])
+def test_the_up_projected_chunk_kernel_under_the_interpreter(
+        monkeypatch, heads, keys, kv_scale, dtype):
+    """A chunk through the layer's own calls in the up-projected form
+    (``with_up`` -> ``latent_project`` -> ``cached_attention(latent_up=)``
+    -> ``latent_output``), the kernel under the interpreter, against the
+    absorbed form through the dense reference and ``W_kvb[v]``: a chunk
+    that starts at no multiple of the key block and runs into the next, in
+    layer 1 of a pool and of a stack of two."""
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    # the form for a chunk this short (the rule's own test is the next)
+    monkeypatch.setattr(decode_attention, "latent_up_projects",
+                        lambda *a: True)
+    cfg, stack = _published_heads(heads, dtype, **keys)
+    p = jax.tree_util.tree_map(lambda a: a[1], stack)
+    S, Sq, at = 1024, 32, 500
+    rng = jax.random.split(jax.random.PRNGKey(7), 2)
+    x = jax.random.normal(rng[0], (1, at + Sq, cfg.d_model), jnp.float32)
+    positions = jnp.arange(at + Sq)
+    _, rows = latent_moe.latent_project(x, p, cfg, positions, 1.0, kv_scale)
+    bank = jnp.zeros((2, 1, S, cfg.cache_row[0]), dtype).at[1, :, :at + Sq] \
+        .set(rows.astype(dtype))
+    chunk, where = x[:, at:], positions[at:]
+
+    def layer(p, attention):
+        q, _ = latent_moe.latent_project(chunk, p, cfg, where, 1.0, kv_scale)
+        return latent_moe.latent_output(chunk, attention(q), p, cfg)
+
+    def kernel(q):
+        q, up = q
+        assert q.shape[-1] == cfg.d_nope + cfg.d_rope
+        return decode_attention.cached_attention(
+            q, bank, None, jnp.asarray([at]), sm_scale=cfg.softmax_scale,
+            layer=1, latent_rank=cfg.kv_rank, latent_up=up)
+    got = layer(latent_moe.with_up(p, latent_moe.head_major(
+        stack["wkv_b"], cfg), 1), kernel)
+    want = layer(p, lambda q: decode_attention.latent_attention_reference(
+        q, bank[1], at, cfg.softmax_scale, cfg.kv_rank))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+        return
+    # bf16: no further from the absorbed form's dense reference than the
+    # absorbed KERNEL is (both round their probabilities; this one its keys
+    # and values too, where the absorbed one rounds its queries)
+    absorbed = layer(p, lambda q: decode_attention.cached_attention(
+        q, bank, None, jnp.asarray([at]), sm_scale=cfg.softmax_scale,
+        layer=1, latent_rank=cfg.kv_rank))
+    far = lambda a: float(jnp.max(jnp.abs(a - want)))
+    assert far(got) <= max(2 * far(absorbed), 0.02), (far(got),
+                                                       far(absorbed))
+
+
+def _kernels(jaxpr):
+    from tests.unit.ops.traced_sweeps import _deep
+    return [e.params["name"] for e in _deep(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("sq,biased,kernel", [
+    (1, False, decode_attention.LATENT_SWEEP),
+    (8, False, "latent_chunk_attention"),
+    (8, True, "latent_chunk_attention"),
+    (256, True, "latent_chunk_attention"),
+    (512, True, "latent_chunk_attention"),
+    (256, False, decode_attention.LATENT_UP_CHUNK),
+    (512, False, decode_attention.LATENT_UP_CHUNK)])
+def test_the_calls_shape_and_bias_pick_the_form(monkeypatch, sq, biased,
+                                                kernel):
+    """One call site, un-absorbed queries and the layer's up-projection, at
+    the published widths (64 heads of 128 + 64 | 128 over a rank of 512 in
+    640 lanes): a tick, a verify's few tokens and any call under a bias run
+    the absorbed kernels, a prompt's chunk the up-projected one.  Traced,
+    never run."""
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    H, R, W, S = 64, 512, 640, 2048
+    assert decode_attention.latent_up_projects(158, H, W, R, 128, 64, 128) \
+        and not decode_attention.latent_up_projects(157, H, W, R, 128, 64,
+                                                    128)
+    # ... which is what a family tells the batcher of a chunk's passes
+    published = latent_moe.LatentMoEConfig(
+        n_head=H, kv_rank=R, d_nope=128, d_rope=64, d_v=128)
+    assert latent_moe_inference.FAMILY.chunk_form(published, sq) == (
+        "up_projected" if sq >= 158 else "absorbed")
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16)
+
+    def call(q, bank, w, bias):
+        return decode_attention.latent_cached_attention(
+            q, bank, jnp.asarray([300]), 0.07, R, layer=1,
+            bias=bias if biased else None,
+            up=decode_attention.LatentUp(w, 1, 128))
+    jaxpr = jax.make_jaxpr(call)(
+        shape(1, sq, H, 192), shape(2, 1, S, W), shape(2, H, R, 256),
+        jax.ShapeDtypeStruct((1, sq, S), jnp.float32))
+    assert _kernels(jaxpr.jaxpr) == [kernel]
+    assert jaxpr.out_avals[0].shape == (1, sq, H, 128)
+
+
 def test_what_the_family_does_not_serve_is_refused_at_construction():
     cfg, params = _model(_file())
     with pytest.raises(NotImplementedError, match="scale banks"):

@@ -217,7 +217,10 @@ def test_apply_equals_the_reference():
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 50), 0,
                                 cfg.vocab_size)
     got = window_moe.apply(params, tokens, cfg)[..., :cfg.vocab_size]
-    ref = reference.forward(file, params, tokens, 50)
+    # compiled: op by op the reference compiles every primitive of every
+    # new shape on its own
+    ref = jax.jit(lambda p, t: reference.forward(file, p, t, 50))(params,
+                                                                  tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=ATOL,
                                rtol=RTOL)
 
@@ -232,8 +235,11 @@ def _slot_path(file, cfg, params, n, ticks=8, drawn=None, gateway=None,
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)]
     replies, got = gateway.probe_logits(prompts, ticks)
     full = np.concatenate([prompts[0], np.asarray(replies[0], np.int32)])
-    ref = np.asarray(reference.forward(
-        file, params if drawn is None else drawn, full[None], ticks + 1))[0]
+    # compiled: op by op the reference compiles every primitive of every
+    # new shape on its own
+    ref = np.asarray(jax.jit(lambda p, t: reference.forward(
+        file, p, t, ticks + 1))(params if drawn is None else drawn,
+                                full[None]))[0]
     return gateway, got[0][:, :cfg.vocab_size], ref
 
 

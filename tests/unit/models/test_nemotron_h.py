@@ -180,7 +180,10 @@ def test_apply_equals_the_reference():
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 0,
                                 cfg.vocab_size)
     got = hybrid_ssm_moe.apply(params, tokens, cfg)[..., :cfg.vocab_size]
-    ref = reference.forward(file, params, tokens, 40)
+    # compiled: op by op the reference compiles every primitive of every
+    # new shape on its own
+    ref = jax.jit(lambda p, t: reference.forward(file, p, t, 40))(params,
+                                                                  tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=ATOL,
                                rtol=RTOL)
 
@@ -191,8 +194,10 @@ def _slot_path(file, cfg, params, n, ticks=8):
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)]
     replies, got = gateway.probe_logits(prompts, ticks)
     full = np.concatenate([prompts[0], np.asarray(replies[0], np.int32)])
-    ref = np.asarray(reference.forward(file, params, full[None],
-                                       ticks + 1))[0]
+    # compiled: op by op the reference compiles every primitive of every
+    # new shape on its own
+    ref = np.asarray(jax.jit(lambda p, t: reference.forward(
+        file, p, t, ticks + 1))(params, full[None]))[0]
     return gateway, got[0][:, :cfg.vocab_size], ref
 
 
@@ -453,7 +458,9 @@ def test_a_planted_fault_reads_over_a_tolerance(monkeypatch, fault):
     with _planted(monkeypatch, fault):
         got = np.asarray(hybrid_ssm_moe.apply(params, tokens, cfg)
                          )[..., :cfg.vocab_size]
-        ref = np.asarray(reference.forward(file, params, tokens, 40))
+        # compiled (inside the patch: traced with the fault planted)
+        ref = np.asarray(jax.jit(lambda p, t: reference.forward(
+            file, p, t, 40))(params, tokens))
     off = np.abs(got - ref) - RTOL * np.abs(ref)
     if fault == "none":
         assert off.max() <= ATOL

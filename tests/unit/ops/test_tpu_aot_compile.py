@@ -566,6 +566,39 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     if family in ("dense", "moe"):
         _one_dense_sweep_a_layer(text, cfg, cache, slots, smax,
                                  calls=1 if family == "dense" else 2)
+    if family in _LATENT_TICKS:
+        # a chunk's form is not the tick's affair: one query a row keeps
+        # the absorbed sweep, its custom calls and its plan the ones the
+        # program had before a chunk could up-project (PR 58)
+        calls, planned = _LATENT_TICKS[family]
+        assert sorted(_custom_calls(text)) == sorted(calls)
+        assert _planned_bytes(compiled) <= planned
+
+
+def _custom_calls(hlo_text):
+    """``(kernel, result)`` of every Mosaic custom call of a compiled
+    module, the kernel by the name it was launched under."""
+    import re
+    return [(name.split(".")[0], shape) for name, shape in re.findall(
+        r"%([\w.]+) = (\w+\[[\d,]*\])\S* custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", hlo_text)]
+
+
+#: the tick of the three families whose chunks may up-project, as it
+#: compiled BEFORE they could (compiler, PR 58, the parent's programs at
+#: these geometries): the absorbed sweep once a sublayer body, the held
+#: experts' two grouped products, and the bytes the program plans
+_LATENT_TICKS = {
+    "latent": ([("gmm", "bf16[128,4096]"), ("gmm", "bf16[128,7168]")]
+               + [("latent_decode_attention", "bf16[128,1,32768]")] * 2,
+               6430162944),
+    "linear": ([("gmm", "bf16[512,2048]"), ("gmm", "bf16[512,2304]")] * 4
+               + [("latent_decode_attention", "bf16[256,1,16384]")] * 2,
+               13081361920),
+    "shortcut": ([("gmm", "bf16[128,4096]"), ("gmm", "bf16[128,6144]")]
+                 + [("latent_decode_attention", "bf16[64,1,32768]")] * 2,
+                 14382130176),
+}
 
 
 #: tokens in a block of a family's single-token sweep at its cell's
@@ -739,6 +772,8 @@ def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
     assert "input_output_alias" in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         _pool_bytes(pool), "the donated pool is not updated in place"
+    if family in _LATENT_TICKS:
+        _one_up_projected_chunk_call_a_sublayer(text, cfg, chunk, smax)
     # ... to a hundredth: the per-slot state rides along, and what the
     # compiler hoists out of the chunk loop (a weight re-laid once an
     # admission, not once a chunk) stays live through it (37 MB of 6.6 GB
@@ -771,6 +806,81 @@ def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
     room = 1.016 if family == "shortcut" else 1.01
     assert _planned_bytes(compiled) <= room * held_today, (
         _planned_bytes(compiled), _planned_bytes(extend), _pool_bytes(pool))
+
+
+def _in_loops(hlo_text):
+    """``(result dimensions, opcode)`` of every instruction a ``while`` of
+    the module runs: its body's, and those of every computation the body
+    calls (a fusion counted under its root's opcode, as ``_root_opcodes``
+    counts it)."""
+    import re
+    bodies, calls, rows, roots, name = set(), {}, {}, {}, None
+    line = re.compile(r"^\s*(ROOT )?%\S+ = \(?\w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    for text in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\{$", text)
+        if head:
+            name = head.group(1)
+            continue
+        bodies |= set(re.findall(r"body=%([\w.]+)", text))
+        called = re.findall(
+            r"(?:calls|body|condition|to_apply)=%([\w.]+)", text)
+        calls.setdefault(name, set()).update(called)
+        m = line.match(text)
+        if m:
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            fused = re.search(r"calls=%([\w.]+)", text)
+            rows.setdefault(name, []).append(
+                (dims, m.group(3), fused.group(1) if fused else None))
+            if m.group(1):
+                roots[name] = m.group(3)
+    seen, todo = set(), list(bodies)
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += calls.get(c, ())
+    return [(n, roots.get(fused, op) if op == "fusion" else op)
+            for c in seen for n, op, fused in rows.get(c, ())]
+
+
+def _one_up_projected_chunk_call_a_sublayer(text, cfg, chunk, smax):
+    """An admission's latent chunk passes at the cells' chunk (512 and
+    1,024 positions: over the 158 from which a key's up-projection pays,
+    ``decode_attention.latent_up_projects``) run the UP-PROJECTED form: ONE
+    custom call a sublayer body, twice in the program (the first chunk's
+    ``prefill`` and the loop's ``extend``), each with the one rank-3 result
+    ``[heads, chunk, d_v]`` by which the benchmark's roofline reader finds
+    it and no helper call beside it (a rank-2 result would read as a
+    grouped matmul, a second rank-3 one would double the reader's calls);
+    the absorbed chunk kernel is gone from the program.  That the program
+    compiled says the kernel's scoped VMEM is under the 16 MiB a v5e's
+    kernel may use (``latent_up_tiles`` sizes its step to 12; over the
+    limit the compiler refuses the program).  ``W_kvb`` reaches the kernel
+    as the head-major copy of its whole stack, made once an admission:
+    inside the chunk loop nothing as large as ONE layer of it is copied or
+    transposed."""
+    import re
+    kernels = [(k, shape) for k, shape in _custom_calls(text)
+               if k != "gmm"]
+    H, rank, e = cfg.n_head, cfg.kv_rank, cfg.d_nope + cfg.d_v
+    assert kernels == [(decode.LATENT_UP_CHUNK,
+                        f"bf16[{H},{chunk},{cfg.d_v}]")] * 4, kernels
+    assert decode.latent_up_tiles(
+        chunk, H, cfg.cache_row[0], rank, cfg.d_nope, cfg.d_v,
+        decode.latent_block_k(smax)) is not None
+    # the calls' last operand is a WHOLE stack, layers leading
+    stacks = re.findall(
+        rf"%{decode.LATENT_UP_CHUNK}[.\d]* = [^\n]*?bf16\[(\d+),{H},{rank},"
+        rf"{e}\]\{{3,2,1,0\}}\}}, frontend_attributes", text)
+    assert len(stacks) == 4, stacks
+    # ... and no layer of it, in the stack's order or the head-major one
+    # (but where that is the queries' own ``[heads, chunk, 256]``), is
+    # copied or transposed inside a loop
+    layers = {(rank, H, e)} | ({(H, rank, e)} - {(H, chunk, e)})
+    moved = [(dims, op) for dims, op in _in_loops(text)
+             if tuple(d for d in dims if d > 1) in layers
+             and op in ("copy", "transpose")]
+    assert not moved, f"a layer's W_kvb is re-laid inside a loop: {moved}"
 
 
 #: ``copy`` ops as large as a bank of the admission's batch-1 row, by
@@ -1203,6 +1313,9 @@ def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
     text = compiled.as_text()
     for kernel in kernels:
         assert f"/{kernel}/pallas_call" in text, kernel
+    # every latent chunk pass of this family carries a bias (a selection, a
+    # ring's band): none takes the up-projected form
+    assert decode.LATENT_UP_CHUNK not in text
     moved = _moves_of_a_pool(text, pool)
     assert not moved, f"the {program} moves whole layers of a pool: {moved}"
     assert not _pair_rows(text, cfg, params,
