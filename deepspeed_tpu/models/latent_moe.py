@@ -329,9 +329,9 @@ def expert_ffn(x, p, config: LatentMoEConfig, experts=None, layer=None):
     matrices as whole stacks ``{"w_gu", "w_down"}`` ``[layers, n_held,
     ...]`` for the grouped product to read in place, where ``p`` does not
     carry one layer of them.  Returns ``(x, counts)`` with ``counts``
-    ``[5 + n_held]`` int32: pairs held here, pairs routed, held experts
-    that took at least one pair (each streams its matrices once), pairs
-    per held expert, pages of pairs run beyond the first, pairs on
+    ``[n_pair_counts(n_held)]`` int32: pairs held here, pairs routed, held
+    experts that took at least one pair (each streams its matrices once),
+    pairs per held expert, pages of pairs run beyond the first, pairs on
     zero-compute experts (none here: 0)."""
     B, S, d = x.shape
     h32 = rms_norm(x, p["ln2"], config.eps, jnp.float32)

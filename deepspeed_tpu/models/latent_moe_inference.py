@@ -11,7 +11,7 @@ what ``gpt_inference.Family`` asks of a model family:
   whatever the number of heads;
 - the **step**: two segments, the leading dense layers (``first_k_dense``)
   and the expert layers, layers in depth order in the one pool; the expert
-  layers add their pair counts to ``cache.stats`` (``[5 + n_held]`` int32:
+  layers add their pair counts to ``cache.stats`` (``[n_pair_counts(n_held)]``:
   pairs held here, pairs routed, expert visits, pairs per held expert, pages
   of pairs run beyond a call's first, pairs on zero-compute experts);
 - projections and attention through ``ops/pallas/decode_attention.py``'s

@@ -191,9 +191,10 @@ def attention_output(x, attn, p, config: WindowMoEConfig):
 
 def expert_ffn(x, p, config: WindowMoEConfig, experts=None, layer=None):
     """The expert layer: ``x + routed(norm_2(x))``.  ``experts`` / ``layer``
-    and the counts ``[5 + n_held]`` are ``latent_moe.expert_ffn``'s: pairs
-    held here, pairs routed, held experts that took a pair, pairs per held
-    expert, pages of pairs run beyond the first."""
+    and the counts ``[n_pair_counts(n_held)]`` are
+    ``latent_moe.expert_ffn``'s: pairs held here, pairs routed, held experts
+    that took a pair, pairs per held expert, pages of pairs run beyond the
+    first."""
     B, S, d = x.shape
     k = config.experts_per_token
     with jax.named_scope("moe_router"):

@@ -185,6 +185,18 @@ def pair_counts(counts, routed: int):
         counts])
 
 
+def read_pair_counts(vector) -> Dict[str, object]:
+    """``pair_counts``' vector back on the host, each counter under the name
+    the span ``serve.moe_pairs`` gives it: ``held``, ``routed``, ``visits``,
+    ``per_expert`` (a list, one entry a held expert), ``pages_over_cap`` and
+    ``zero``.  The one place that knows the vector's order."""
+    held, routed, visits, *per_expert, pages_over_cap, zero = (
+        int(c) for c in np.asarray(vector))
+    return {"held": held, "routed": routed, "visits": visits,
+            "per_expert": per_expert, "pages_over_cap": pages_over_cap,
+            "zero": zero}
+
+
 def pairs_cap(n_pairs: int, n_held: int, n_experts: int) -> int:
     """Rows of the buffer the held pairs of a call are brought together in:
     twice the pairs a uniform router sends to ``n_held`` of ``n_experts``

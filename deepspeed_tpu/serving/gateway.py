@@ -40,6 +40,7 @@ import numpy as np
 
 import jax
 
+from ..moe.held_experts import read_pair_counts
 from ..runtime.supervision.events import EventJournal, EventKind
 from ..telemetry.metrics import MetricName, lock_watch_metrics
 from ..telemetry.propagate import mint_context
@@ -960,11 +961,8 @@ class ServingGateway:
         if moe is not None:
             self.metrics.record_moe_pairs(moe)
             if self.tracer.enabled:
-                self.tracer.record(
-                    SpanName.SERVE_MOE_PAIRS, now, 0.0, held=int(moe[0]),
-                    routed=int(moe[1]), visits=int(moe[2]),
-                    per_expert=[int(c) for c in moe[3:-2]],
-                    pages_over_cap=int(moe[-2]), zero=int(moe[-1]))
+                self.tracer.record(SpanName.SERVE_MOE_PAIRS, now, 0.0,
+                                   **read_pair_counts(moe))
         state = self._batcher.counts("state_steps")
         if state is not None:
             state = {k: int(c) for k, c in

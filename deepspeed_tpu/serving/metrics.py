@@ -15,6 +15,7 @@ from __future__ import annotations
 import time
 from typing import Dict, Optional
 
+from ..moe.held_experts import read_pair_counts
 from ..telemetry.metrics import Histogram, MetricName
 from ..utils.lock_watch import LockName, TrackedLock
 
@@ -206,13 +207,14 @@ class ServingMetrics:
         routed, visits, pairs of each held expert..., pages over the cap,
         pairs on zero-compute experts]``
         (``moe.held_experts.pair_counts``)."""
+        read = read_pair_counts(counts)
         with self._lock:
-            self.moe_pairs_held = int(counts[0])
-            self.moe_pairs_routed = int(counts[1])
-            self.moe_expert_visits = int(counts[2])
-            self.moe_expert_pairs = [int(c) for c in counts[3:-2]]
-            self.moe_pages_over_cap = int(counts[-2])
-            self.moe_pairs_zero = int(counts[-1])
+            self.moe_pairs_held = read["held"]
+            self.moe_pairs_routed = read["routed"]
+            self.moe_expert_visits = read["visits"]
+            self.moe_expert_pairs = read["per_expert"]
+            self.moe_pages_over_cap = read["pages_over_cap"]
+            self.moe_pairs_zero = read["zero"]
 
     def record_state_steps(self, counts: dict) -> None:
         """``counts``: name -> cumulative count, the batcher's group

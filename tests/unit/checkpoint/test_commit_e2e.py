@@ -37,6 +37,11 @@ def build():
     return engine, loader
 
 
+# Both hosts wait this long for the other's vote; it costs nothing when the
+# vote arrives (0.1 s here), and is under the chaos deadline of 120 s.
+CONSENSUS_DEADLINE_S = 60.0
+
+
 def fast_commit_cfg():
     return CheckpointCommitConfig(barrier_deadline_s=0.3, barrier_poll_s=0.01,
                                   barrier_backoff_max_s=0.05)
@@ -80,8 +85,8 @@ def test_rank_killed_midsave_then_consensus_resume_bitwise(tmp_path):
     ctx0 = cp.CommitContext(
         world_size=2, rank=0, config=fast_commit_cfg(),
         journal=runner2.journal,
-        channel=cp.FileConsensusChannel(shared, 0, 2, deadline_s=10.0,
-                                        poll_s=0.01))
+        channel=cp.FileConsensusChannel(
+            shared, 0, 2, deadline_s=CONSENSUS_DEADLINE_S, poll_s=0.01))
     engine2.set_commit_context(ctx0)
     runner2.commit_ctx = ctx0
     peer_result = {}
@@ -90,18 +95,36 @@ def test_rank_killed_midsave_then_consensus_resume_bitwise(tmp_path):
         # host B: same shared checkpoint dir, own consensus identity
         ctx1 = cp.CommitContext(
             world_size=2, rank=1, config=fast_commit_cfg(),
-            channel=cp.FileConsensusChannel(shared, 1, 2, deadline_s=10.0,
-                                            poll_s=0.01))
+            channel=cp.FileConsensusChannel(
+                shared, 1, 2, deadline_s=CONSENSUS_DEADLINE_S, poll_s=0.01))
         try:
             peer_result["tag"] = cp.agree_resume_tag(save, ctx1)
         except Exception as e:  # surfaced via the assert below
             peer_result["tag"] = e
 
+    # The peer votes once the coordinator has swept the stale rounds.  Voting
+    # DURING the sweep is a race the channel loses about once in 18 runs
+    # beside busy workers (ROADMAP.md D0 v): the sweep's ``rmtree`` takes the
+    # round's directory from under the peer's re-asserted vote, whose write
+    # makes its parent once and retries for 0.15 s; if rank 0 has not opened
+    # the round by then the peer dies of an ``OSError`` and rank 0 waits out
+    # its deadline for a vote that never comes (loud, as the channel
+    # promises, but not what this test is about:
+    # ``test_commit_protocol.py::test_a_sweep_that_lands_under_a_vote_loses_
+    # it_today`` holds the hole for the repair to turn round).
     t = threading.Thread(target=peer_host)
-    t.start()
+    sweep = ctx0.channel.sweep_rounds
+
+    def sweep_then_start_the_peer():
+        sweep()
+        if t.ident is None:         # a thread starts once, a sweep may repeat
+            t.start()
+
+    ctx0.channel.sweep_rounds = sweep_then_start_the_peer
     engine2.set_data_iterator(loader2)
     resumed_at = runner2.resume()
-    t.join()
+    t.join(CONSENSUS_DEADLINE_S)
+    assert t.ident is not None and not t.is_alive()
 
     # every host landed on the same prior committed tag
     assert peer_result["tag"] == "elastic_step4"

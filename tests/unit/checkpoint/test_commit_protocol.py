@@ -388,6 +388,34 @@ def test_consensus_round_sweep_clears_stale_rounds(tmp_path):
     assert not os.path.isdir(shared)
 
 
+def test_a_sweep_that_lands_under_a_vote_loses_it_today(tmp_path,
+                                                        monkeypatch):
+    """The hole ROADMAP.md D0 (v) records, held as it IS so that the repair
+    has a case to turn round: the coordinator's ``sweep_rounds`` landing
+    between a peer's making its round's directory and its writing its vote.
+    ``atomic_write_text`` makes the parent once and retries the write alone,
+    so the vote dies of ``FileNotFoundError`` where the channel's docstring
+    promises a re-assert or a loud ``ResumeConsensusError``.  The repair
+    (re-make the directory on every attempt) makes this ``agree_min``
+    return 4: assert that then."""
+    from deepspeed_tpu.runtime.checkpoint_engine import storage
+    shared = tmp_path / "shared"
+    ch = cp.FileConsensusChannel(str(shared), 0, 1, deadline_s=1.0)
+    made = storage._ensure_parent
+    swept = []
+
+    def made_then_swept(path):
+        made(path)
+        if not swept:
+            swept.append(path)
+            ch.sweep_rounds()
+
+    monkeypatch.setattr(storage, "_ensure_parent", made_then_swept)
+    with pytest.raises(FileNotFoundError):
+        ch.agree_min(4)
+    assert swept and not os.path.isdir(shared)
+
+
 # ------------------------------------------------------------ cross-engine
 
 def test_cross_engine_async_commit_sync_resume(tmp_path):

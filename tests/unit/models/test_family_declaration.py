@@ -7,7 +7,6 @@ kernel, and that ``serving/`` asks the family and nothing below it."""
 
 import ast
 import dataclasses
-import json
 import os
 
 import numpy as np
@@ -30,6 +29,7 @@ from deepspeed_tpu.models import (cache_family, gpt, gpt_inference, gpt_moe,
                                   sparse_latent_moe_inference, window_moe,
                                   window_moe_inference)
 from deepspeed_tpu.ops.pallas import decode_attention
+from tests.unit.models.family_harness import tiny_file
 from tests.unit.ops.traced_sweeps import sweep_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
@@ -40,17 +40,6 @@ DENSE_CFG = gpt.GPTConfig(vocab_size=128, max_seq_len=64, n_layer=2,
 MOE_CFG = gpt_moe.GPTMoEConfig(vocab_size=128, max_seq_len=64, n_layer=2,
                                n_head=2, d_model=32, dtype=jnp.float32,
                                vocab_round_to=128, num_experts=2)
-
-
-def _tiny(builder, name):
-    """A benchmark configuration at the rehearsal's tiny sizes."""
-    with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
-                           name + ".json")) as f:
-        file = json.load(f)
-    with open(os.path.join(ROOT, "tests", "unit", "chipbench", "tiny",
-                           "configs", name + ".json")) as f:
-        file.update(json.load(f))
-    return dataclasses.replace(builder.build(file), dtype=jnp.float32)
 
 
 #: the served configurations: class -> (config, params' init, family)
@@ -76,7 +65,8 @@ def _served(name):
                    linear_latent_moe_inference.FAMILY),
         "shortcut": (longcat_flash_family, "longcat-flash-chat-ep32",
                      shortcut_latent_moe_inference.FAMILY)}[name]
-    cfg = _tiny(builder, file)
+    cfg = dataclasses.replace(builder.build(tiny_file(file)),
+                              dtype=jnp.float32)
     return cfg, lambda k: builder.init(cfg, k, jnp.float32), family
 
 
@@ -184,44 +174,11 @@ def test_the_object_computes_what_the_module_computes():
 
 # ------------------------------------------------------------- refusals
 #
-# Every refusal a user could meet at the parent of this change, with the
-# words it used: the batcher's (``serving.<feature> with <Config>: <why>``),
-# the int8 cache's where the cache is made, and the two draft refusals.
-
-SERVING_REFUSALS = [
-    ("latent", "speculative",
-     "serving.speculative with LatentMoEConfig: a dense draft's proposals "
-     "are verified by a ragged extend this family has never been tested "
-     "through"),
-    ("latent", "paging",
-     "serving.paging with LatentMoEConfig: parked latent rows have no "
-     "re-admission test yet"),
-    ("hybrid", "speculative",
-     "serving.speculative with HybridSSMMoEConfig: a rejected draft token "
-     "would have to be rolled back out of the per-slot state, and a ragged "
-     "verify pass carries no per-row count of real tokens"),
-    ("hybrid", "paging",
-     "serving.paging with HybridSSMMoEConfig: a parked conversation's "
-     "per-slot state has no block to live in: the pager moves token-indexed "
-     "banks only"),
-    ("hybrid", "prefix",
-     "serving.prefix with HybridSSMMoEConfig: a pooled prefix would need a "
-     "snapshot of the per-slot state at its end; the pool keeps "
-     "token-indexed banks only"),
-] + [("window", feature, f"serving.{feature} with WindowMoEConfig: "
-      + window_moe_inference.UNSUPPORTED[feature])
-     for feature in ("speculative", "paging", "prefix")] \
-  + [("selected", feature, f"serving.{feature} with SparseLatentMoEConfig: "
-      + sparse_latent_moe_inference.UNSUPPORTED[feature])
-     for feature in ("speculative", "paging", "prefix")] \
-  + [("linear", feature, f"serving.{feature} with LinearLatentMoEConfig: "
-      + linear_latent_moe_inference.UNSUPPORTED[feature])
-     for feature in ("speculative", "paging", "prefix")] \
-  + [("shortcut", feature,
-      f"serving.{feature} with ShortcutLatentMoEConfig: "
-      + shortcut_latent_moe_inference.UNSUPPORTED[feature])
-     for feature in ("speculative", "paging", "prefix")]
-
+# Every refusal a user could meet, with the words it used: the batcher's
+# (``serving.<feature> with <Config>: <why>``) are
+# ``test_family_conformance.py``'s, a case a family and feature; here what
+# the two GPT families serve, the int8 cache's refusal where the cache is
+# made, and the two draft refusals.
 
 def _shell_batcher(name):
     """A batcher that holds a family and a config and nothing else: what
@@ -231,16 +188,6 @@ def _shell_batcher(name):
     b = object.__new__(SlotBatcher)
     b._fam, b._cfg = fam, cfg
     return b
-
-
-@pytest.mark.parametrize("name,feature,said", SERVING_REFUSALS,
-                         ids=[f"{n}-{f}" for n, f, _ in SERVING_REFUSALS])
-def test_the_batcher_refuses_in_the_familys_words(name, feature, said):
-    b = _shell_batcher(name)
-    assert said.endswith(b.unsupported(feature))
-    with pytest.raises(NotImplementedError) as e:
-        b.refuse(feature)
-    assert str(e.value) == said
 
 
 @pytest.mark.parametrize("name", ["dense", "moe"])
@@ -290,8 +237,10 @@ def _draft_engine(name):
     cfg, init, _ = _served(name)
     if name == "moe":   # a draft shares the target's vocabulary
         assert cfg.vocab_size == DENSE_CFG.vocab_size
+    # drawn in one program (op by op every initializer compiles on its own)
     return deepspeed_tpu.init_inference(
-        model=(cfg, init(jax.random.PRNGKey(1))), config={"dtype": "float32"})
+        model=(cfg, jax.jit(init)(jax.random.PRNGKey(1))),
+        config={"dtype": "float32"})
 
 
 @pytest.mark.parametrize("name", ["moe", "latent", "hybrid", "linear",

@@ -1,15 +1,10 @@
-"""The hybrid state-space / attention family against its plain reference at
-a tiny size on the CPU, in float32: the uncached ``apply`` and the slot path
-(chunked prefill, then decode through the per-slot state) against the
-reference's token-by-token recurrence; the chunked scan against the
-recurrence from a non-zero state; what a padded tail and an inactive slot
-leave behind; the slot ops on the state leaf; the expert layer's shares
-against the uncut layer; the softmax gate; grouped heads in the decode
-sweep; the admission as one launch; and what the family refuses."""
-
-import dataclasses
-import json
-import os
+"""What the hybrid state-space / attention family alone has (the probes
+every family answers are ``test_family_conformance.py``'s): the state leaf
+beside the banks, the counter groups the gateway records, the chunked scan
+against the recurrence from a non-zero state, what a padded tail and an
+inactive slot leave behind, the slot ops on the state leaf, the softmax
+gate, grouped heads in the decode sweep, the admission as one launch, the
+published sizes and a ragged ``generate``."""
 
 import numpy as np
 import pytest
@@ -17,50 +12,19 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import deepspeed_tpu
 from benchmarks.chip import hybrid_ssm_moe_family
-from benchmarks.chip.reference import hybrid_ssm_moe_reference as reference
 from deepspeed_tpu.models import (cache_family, gpt_inference,
-                                  hybrid_ssm_moe, hybrid_ssm_moe_inference)
+                                  hybrid_ssm_moe_inference)
 from deepspeed_tpu.moe import held_experts
 from deepspeed_tpu.ops.pallas import decode_attention, ssm
+from tests.unit.models import family_harness as harness
+from tests.unit.models.family_harness import CHUNK
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
-NAME = "granite-4.0-h-small-ep4"
-CHUNK = 16
-
-
-def _file(**keys):
-    """The benchmark's configuration file at the rehearsal's tiny sizes."""
-    with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
-                           NAME + ".json")) as f:
-        file = json.load(f)
-    with open(os.path.join(ROOT, "tests", "unit", "chipbench", "tiny",
-                           "configs", NAME + ".json")) as f:
-        file.update(json.load(f))
-    return {**file, **keys}
-
-
-def _model(file, seed=0):
-    cfg = dataclasses.replace(hybrid_ssm_moe_family.build(file),
-                              dtype=jnp.float32)
-    return cfg, hybrid_ssm_moe_family.init(cfg, jax.random.PRNGKey(seed),
-                                           jnp.float32)
-
-
-def _gateway(cfg, params, **serving):
-    engine = deepspeed_tpu.init_inference(model=(cfg, params),
-                                          config={"dtype": "float32"})
-    gateway = engine.serve(config={"slots": 4, "max_len": 128,
-                                   "prefill_chunk": CHUNK,
-                                   "queue_capacity": 8, **serving})
-    gateway.shutdown(drain=False, timeout=60)
-    return gateway
+SPEC = harness.SPECS["granite-4.0-h-small-ep4"]
 
 
 def test_the_family_shares_the_one_cache_family_and_adds_a_state_leaf():
-    cfg, _ = _model(_file())
+    cfg, _ = harness.model(SPEC)
     fam = cache_family(cfg)
     assert fam is hybrid_ssm_moe_inference.FAMILY
     assert isinstance(fam, gpt_inference.Family)
@@ -83,55 +47,6 @@ def test_the_family_shares_the_one_cache_family_and_adds_a_state_leaf():
     assert dense.state is None
 
 
-def test_apply_equals_the_reference():
-    file = _file()
-    cfg, params = _model(file, seed=1)
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 0,
-                                cfg.vocab_size)
-    got = hybrid_ssm_moe.apply(params, tokens, cfg)[..., :cfg.vocab_size]
-    # compiled: op by op the reference compiles every primitive of every
-    # new shape on its own
-    ref = jax.jit(lambda p, t: reference.forward(file, p, t, 40))(params,
-                                                                  tokens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5,
-                               rtol=1e-4)
-
-
-@pytest.mark.parametrize("n", [1, CHUNK, CHUNK + 1, 3 * CHUNK + 5],
-                         ids=["1", "C", "C+1", "3C+5"])
-def test_slot_path_equals_the_reference_full_forward(n):
-    """Chunked prefill, then 8 decode ticks through the gateway's own
-    programs and slot cache, against the reference's full forward, on
-    logits: a prompt shorter than a chunk, exactly one, one more, several
-    and a ragged tail."""
-    file = _file()
-    cfg, params = _model(file)
-    gateway = _gateway(cfg, params)
-    rng = np.random.default_rng(3 + n)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)]
-    ticks = 8
-    replies, got = gateway.probe_logits(prompts, ticks)
-    full = np.concatenate([prompts[0], np.asarray(replies[0], np.int32)])
-    # compiled: op by op the reference compiles every primitive of every
-    # new shape on its own
-    ref = np.asarray(jax.jit(lambda p, t: reference.forward(
-        file, p, t, ticks + 1))(params, full[None]))[0]
-    np.testing.assert_allclose(got[0][:, :cfg.vocab_size], ref, atol=2e-5,
-                               rtol=1e-4)
-    # the counters, each group where the family's layout puts it
-    named = dict(zip(hybrid_ssm_moe_inference.STATE_COUNTERS,
-                     gateway._batcher.counts("state_steps")))
-    n_ssm = cfg.count("mamba")
-    padded = -(-n // CHUNK) * CHUNK
-    assert named == {"ssm_rows_stepped": ticks * n_ssm,
-                     "scan_tokens_real": n * n_ssm,
-                     "scan_tokens_padded": (padded - n) * n_ssm}
-    pairs = gateway._batcher.counts("moe_pairs")
-    assert pairs[0] == pairs[3:-2].sum() > 0 == pairs[-2] == pairs[-1] \
-        and len(pairs) == 5 + len(cfg.held)
-    assert gateway.metrics.snapshot()["state_steps"] == {}  # no harvest ran
-
-
 @pytest.mark.parametrize("groups", [("moe_pairs", "state_steps"),
                                     ("state_steps",), ("moe_pairs",), ()],
                          ids=lambda g: "+".join(g) or "none")
@@ -140,12 +55,10 @@ def test_the_gateway_records_the_counter_groups_a_family_has(groups):
     scheduler's harvest records each group it finds by name, and a family
     with per-slot state and no expert layer (``state_steps`` alone), or
     with neither, is served like any other."""
-    cfg, params = _model(_file())
-    engine = deepspeed_tpu.init_inference(model=(cfg, params),
-                                          config={"dtype": "float32"})
-    gateway = engine.serve(config={"slots": 2, "max_len": 64,
-                                   "prefill_chunk": CHUNK,
-                                   "queue_capacity": 4})
+    cfg, params = harness.model(SPEC)
+    gateway = harness.engine(cfg, params).serve(config={
+        "slots": 2, "max_len": 64, "prefill_chunk": CHUNK,
+        "queue_capacity": 4})
     try:
         b = gateway._batcher
         assert b._stats_groups == hybrid_ssm_moe_inference.stats_groups(cfg)
@@ -244,7 +157,7 @@ def test_a_padded_tail_and_an_inactive_slot_leave_the_state_bit_for_bit():
     of padding leaves what a chunk of those 5 alone leaves, whatever the
     padding holds; a tick leaves a freed slot's ``H`` and convolution tail
     exactly as they were."""
-    cfg, params = _model(_file())
+    cfg, params = harness.model(SPEC)
     fam = hybrid_ssm_moe_inference.FAMILY
     rng = np.random.default_rng(5)
     tokens = rng.integers(0, cfg.vocab_size, (1, CHUNK)).astype(np.int32)
@@ -276,7 +189,7 @@ def test_a_padded_tail_and_an_inactive_slot_leave_the_state_bit_for_bit():
 
 
 def test_slot_ops_on_the_state_leaf():
-    cfg, params = _model(_file())
+    cfg, params = harness.model(SPEC)
     fam = hybrid_ssm_moe_inference.FAMILY
     tokens = jnp.arange(7, dtype=jnp.int32)[None] % cfg.vocab_size
     _, row = fam.prefill(params, tokens, cfg, fam.init_cache(cfg, 1, 32))
@@ -290,33 +203,6 @@ def test_slot_ops_on_the_state_leaf():
     cleared = fam.reset_slot(pool, 2)
     assert not any(np.asarray(s).any() for s in cleared.state)
     assert not np.asarray(cleared.k).any()
-
-
-def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
-    """Experts 0-3, 4-7, 8-11, 12-15 of 16 on four chips: the routed parts
-    the four shares give, with the shared MLP counted once, are the uncut
-    layer's result."""
-    file = _file(num_local_experts=16)
-    cfg, params = _model(file)
-    p = jax.tree_util.tree_map(lambda a: a[0], params["runs"][0])
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 9, cfg.d_model))
-    whole, counts = hybrid_ssm_moe.expert_ffn(x, p, cfg)
-    assert counts[0] == counts[1] == 2 * 9 * cfg.experts_per_token
-    routed, pairs = 0.0, 0
-    for share in range(4):
-        held = tuple(range(4 * share, 4 * share + 4))
-        part = dataclasses.replace(cfg, held_experts=held)
-        mine = {**p, "w_gu": p["w_gu"][held[0]:held[-1] + 1],
-                "w_down": p["w_down"][held[0]:held[-1] + 1]}
-        out, c = hybrid_ssm_moe.expert_ffn(x, mine, part)
-        alone, _ = hybrid_ssm_moe.expert_ffn(
-            x, {**mine, "w_down": mine["w_down"] * 0}, part)
-        routed = routed + (out - alone)         # this share's routed part
-        shared = alone - x                      # what every chip computes
-        pairs += int(c[0])
-    assert pairs == int(counts[0])
-    np.testing.assert_allclose(np.asarray(x + shared + routed),
-                               np.asarray(whole), atol=1e-6, rtol=1e-5)
 
 
 def test_the_softmax_gate_against_its_definition():
@@ -374,8 +260,8 @@ def test_gmm_tile_follows_the_matrices():
 def test_an_admission_is_one_launch_and_one_compile():
     """Five prompt lengths (under a chunk, a chunk, over one, several):
     one launch an admission, one compile of the admission program."""
-    cfg, params = _model(_file())
-    b = _gateway(cfg, params)._batcher
+    cfg, params = harness.model(SPEC)
+    b = harness.gateway(cfg, params)._batcher
     rng = np.random.default_rng(8)
     lengths = (1, CHUNK - 1, CHUNK, CHUNK + 1, 3 * CHUNK + 5)
     for row, n in enumerate(lengths):
@@ -386,40 +272,8 @@ def test_an_admission_is_one_launch_and_one_compile():
     assert counts["admit"] == 1 and all(n <= 1 for n in counts.values())
 
 
-@pytest.mark.parametrize("feature,serving", [
-    ("speculative", {"speculative": {"enabled": True, "draft_k": 2,
-                                     "draft": {"n_layer": 1}}}),
-    ("paging", {"paging": {"enabled": True, "block_size": 16,
-                           "hbm_blocks": 32}}),
-], ids=["speculative", "paging"])
-def test_what_the_family_does_not_serve_is_refused_at_construction(
-        feature, serving):
-    cfg, params = _model(_file())
-    engine = deepspeed_tpu.init_inference(model=(cfg, params),
-                                          config={"dtype": "float32"})
-    with pytest.raises(NotImplementedError, match=feature):
-        engine.serve(config={"slots": 2, "max_len": 64, "prefill_chunk": 16,
-                             **serving})
-
-
-def test_a_pooled_prefix_and_the_int8_cache_are_refused():
-    cfg, params = _model(_file())
-    gateway = _gateway(cfg, params)
-    tokens = np.arange(20, dtype=np.int32) % cfg.vocab_size
-    with pytest.raises(NotImplementedError, match="prefix"):
-        gateway._batcher.build_prefix(tokens[:8])
-    with pytest.raises(NotImplementedError, match="prefix"):
-        gateway.submit(tokens, max_new_tokens=2, prefix_len=8)
-    with pytest.raises(NotImplementedError, match="compute dtype"):
-        hybrid_ssm_moe_inference.FAMILY.init_cache(cfg, 1, 32,
-                                                   kv_dtype="int8")
-
-
 def test_the_published_sizes():
-    with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
-                           NAME + ".json")) as f:
-        file = json.load(f)
-    cfg = hybrid_ssm_moe_family.build(file)
+    cfg = hybrid_ssm_moe_family.build(harness.published(SPEC.name))
     assert cfg.runs == (("mamba", 0, 5), ("attention", 0, 1),
                         ("mamba", 5, 4))
     assert (cfg.d_model, cfg.d_inner, cfg.d_conv, cfg.ssm_state,
@@ -444,9 +298,8 @@ def test_ragged_generate_equals_each_row_alone():
     """``engine.generate`` with right-padded prompts tells the family where
     each row's prompt ends: a padded row generates what it generates
     alone."""
-    cfg, params = _model(_file())
-    engine = deepspeed_tpu.init_inference(model=(cfg, params),
-                                          config={"dtype": "float32"})
+    cfg, params = harness.model(SPEC)
+    engine = harness.engine(cfg, params)
     rng = np.random.default_rng(9)
     tokens = rng.integers(0, cfg.vocab_size, (2, 12)).astype(np.int32)
     both = np.asarray(engine.generate(tokens, max_new_tokens=5,
@@ -454,31 +307,3 @@ def test_ragged_generate_equals_each_row_alone():
     alone = np.asarray(engine.generate(tokens[1:, :7], max_new_tokens=5))
     assert (both[1] == alone[0]).all()
 
-
-def _probe(cfg, params, fault):
-    from benchmarks.chip.reference import hybrid_ssm_moe_control as control
-    rng = np.random.default_rng(1)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
-               for n in (5, 2 * CHUNK + 5)]
-    with control.planted(fault):
-        gateway = _gateway(
-            cfg, control.WEIGHTS.get(fault, lambda p: p)(params))
-        _, got = gateway.probe_logits(prompts, 4)
-    return np.concatenate([np.asarray(g) for g in got])
-
-
-@pytest.mark.parametrize("fault", ["chunk_edge", "pad_advance", "no_tail",
-                                   "zero", "bf16_state", "int8"])
-def test_every_planted_fault_reaches_the_slot_paths_logits(fault):
-    """The chip's negative controls (``reference/hybrid_ssm_moe_control.py``)
-    plant their faults by replacing functions of ``ops/pallas/ssm.py`` or in
-    the server's weights: each
-    must change what the server's own programs compute (whether it reads
-    over ``compare.py``'s limits is the chip's to say, at the published
-    widths), and leave the module as it was."""
-    cfg, params = _model(_file())
-    kept = {n: getattr(ssm, n) for n in ("ssd_chunk_scan", "ssm_decode_step",
-                                         "causal_conv")}
-    clean, faulty = _probe(cfg, params, "none"), _probe(cfg, params, fault)
-    assert np.abs(faulty - clean).max() > 0
-    assert all(getattr(ssm, n) is fn for n, fn in kept.items())

@@ -1,21 +1,13 @@
-"""The window-and-full attention family (``mellum``) against its plain
-reference at a tiny size on the CPU, in float32: the uncached ``apply`` and
-the slot path (chunked prefill through the rings, then decode) against the
-reference's full causal pass with the band as a mask, across the ring's wrap;
-the slot ops over both pools; the expert layer's shares against the uncut
-layer; the gate; grouped heads with a band through ``cached_attention``; and
-planted faults, each of which must read over a tolerance.
-
-The tolerances.  ``ATOL`` / ``RTOL`` (2e-5, 1e-4) are the other expert
-families': both sides compute in float32, the program's products run at the
-CPU's default precision and its softmax is blocked another way, which reads
-1e-7 to 2e-6 here on logits of about 0.7; a fault below reads 1e-3 or more
-(on weights drawn ten times louder than the family's, ``LOUD``, so that the
-layers and not the embedding make the logits)."""
+"""What the window-and-full attention family alone has (``mellum``; the
+probes every family answers are ``test_family_conformance.py``'s, the ring's
+wrap among its slot paths): the file against the source's config, the
+published sizes, the count of a prompt pass, both pools of the cache and the
+slot ops over them, a chunk that straddles the window's edge, the sweep's
+counts by pool, the gate, grouped heads with a band through
+``cached_attention``, and the ring against the band over the whole
+history."""
 
 import dataclasses
-import json
-import os
 
 import numpy as np
 import pytest
@@ -23,22 +15,17 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import deepspeed_tpu
 from benchmarks.chip import mellum_family
-from benchmarks.chip.reference import mellum_control as control
-from benchmarks.chip.reference import mellum_reference as reference
 from deepspeed_tpu.models import (cache_family, window_moe,
                                   window_moe_inference)
 from deepspeed_tpu.moe import held_experts
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from tests.unit.chipbench.common import check_configuration
+from tests.unit.models import family_harness as harness
+from tests.unit.models.family_harness import ATOL, RTOL
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
-NAME = "mellum2-12b-a2.5b-ep4"
-CHUNK, WINDOW = 16, 16      # the tiny file's: a chunk is a window long
-ATOL, RTOL = 2e-5, 1e-4
-LOUD = 0.2
+SPEC = harness.SPECS["mellum2-12b-a2.5b-ep4"]
+WINDOW = 16                 # the tiny file's: a chunk is a window long
 #: the numbers of the source's config.json (the catalog's row, whose
 #: ``source_url`` the file's ``source`` is)
 SOURCE = {
@@ -59,61 +46,10 @@ SOURCE = {
                               "rope_theta": 500000}}}
 
 
-def _published():
-    with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
-                           NAME + ".json")) as f:
-        return json.load(f)
-
-
-def _file(**keys):
-    """The benchmark's configuration file at the rehearsal's tiny sizes."""
-    file = _published()
-    with open(os.path.join(ROOT, "tests", "unit", "chipbench", "tiny",
-                           "configs", NAME + ".json")) as f:
-        file.update(json.load(f))
-    return {**file, **keys}
-
-
-def _model(file, seed=0, std=None):
-    cfg = dataclasses.replace(mellum_family.build(file), dtype=jnp.float32)
-    if std is None:
-        return cfg, mellum_family.init(cfg, jax.random.PRNGKey(seed),
-                                       jnp.float32)
-    return cfg, window_moe.init(cfg, jax.random.PRNGKey(seed), std=std)
-
-
-def _gateway(cfg, params, **serving):
-    engine = deepspeed_tpu.init_inference(model=(cfg, params),
-                                          config={"dtype": "float32"})
-    gateway = engine.serve(config={"slots": 4, "max_len": 128,
-                                   "prefill_chunk": CHUNK,
-                                   "queue_capacity": 8, **serving})
-    gateway.shutdown(drain=False, timeout=60)
-    return gateway
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    """``(file, config, weights)`` of the tiny file as the family draws
-    them, built once a module."""
-    file = _file()
-    return (file,) + _model(file)
-
-
-@pytest.fixture(scope="module")
-def served(tiny):
-    """One stopped gateway over :func:`tiny`, its programs compiled once:
-    a case that needs no other serving config probes this one."""
-    return _gateway(*tiny[1:])
-
-
 # ------------------------------------------------------- the configuration
 
 def test_the_file_is_the_sources_but_for_what_reduced_lists():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        entry = next(c for c in json.load(f)["configs"]
-                     if c["name"] == NAME)
-    file = _published()
+    entry, file = harness.entry(SPEC.name), harness.published(SPEC.name)
     cfg = check_configuration(file, entry, SOURCE)
     assert entry["reduced"] == ["num_experts", "vocab_size"]
     assert file["published"] == {"num_experts": 64, "vocab_size": 98304}
@@ -125,7 +61,7 @@ def test_the_file_is_the_sources_but_for_what_reduced_lists():
 
 
 def test_the_published_sizes():
-    cfg = mellum_family.build(_published())
+    cfg = mellum_family.build(harness.published(SPEC.name))
     assert (cfg.d_model, cfg.n_head, cfg.n_kv_head, cfg.head_dim,
             cfg.window, cfg.qk_norm) == (2304, 32, 4, 128, 1024, True)
     assert (cfg.n_experts, len(cfg.held), cfg.experts_per_token,
@@ -169,19 +105,8 @@ def test_the_published_sizes():
             lambda a: 0, axes, is_leaf=lambda a: isinstance(a, tuple)))
 
 
-@pytest.mark.parametrize("key,value,said", [
-    ("model_type", "qwen3_moe", ""), ("attention_bias", True, ""),
-    ("tie_word_embeddings", True, "matrix of its own"),
-    ("norm_topk_prob", False, "softmax over the chosen"),
-    ("mlp_layer_types", ["dense"] + ["sparse"] * 27, "no dense block"),
-    ("layer_types", ["linear_attention"] * 28, "")])
-def test_a_sibling_configuration_is_refused_by_name(key, value, said):
-    with pytest.raises(AssertionError, match=said or None):
-        mellum_family.build({**_published(), key: value})
-
-
 def test_the_count_of_a_prompt_pass_follows_the_band():
-    cfg = mellum_family.build(_published())
+    cfg = mellum_family.build(harness.published(SPEC.name))
     # one admission of 2 chunks of 512 from 0: a window layer's query sees
     # min(p + 1, 1,024) keys, so both kinds see the same pairs here ...
     ops, nbytes, calls = mellum_family.chunk_count(cfg, [(0, 2, 512)])
@@ -196,7 +121,7 @@ def test_the_count_of_a_prompt_pass_follows_the_band():
 # ------------------------------------------------------------- the passes
 
 def test_the_cache_holds_whole_rows_for_full_layers_and_rings_for_window():
-    cfg, _ = _model(_file())
+    cfg, _ = harness.model(SPEC)
     fam = cache_family(cfg)
     assert fam is window_moe_inference.FAMILY
     cache = fam.init_cache(cfg, 3, 64)
@@ -211,77 +136,21 @@ def test_the_cache_holds_whole_rows_for_full_layers_and_rings_for_window():
         fam.init_cache(cfg, 1, 64, kv_dtype="int8")
 
 
-def test_apply_equals_the_reference():
-    file = _file()
-    cfg, params = _model(file, seed=1)
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 50), 0,
-                                cfg.vocab_size)
-    got = window_moe.apply(params, tokens, cfg)[..., :cfg.vocab_size]
-    # compiled: op by op the reference compiles every primitive of every
-    # new shape on its own
-    ref = jax.jit(lambda p, t: reference.forward(file, p, t, 50))(params,
-                                                                  tokens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=ATOL,
-                               rtol=RTOL)
-
-
-def _slot_path(file, cfg, params, n, ticks=8, drawn=None, gateway=None,
-               **serving):
-    """``drawn``: the weights the reference takes (default: the server's);
-    ``gateway``: one built already over ``params`` (default: a new one)."""
-    if gateway is None:
-        gateway = _gateway(cfg, params, **serving)
-    rng = np.random.default_rng(3 + n)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)]
-    replies, got = gateway.probe_logits(prompts, ticks)
-    full = np.concatenate([prompts[0], np.asarray(replies[0], np.int32)])
-    # compiled: op by op the reference compiles every primitive of every
-    # new shape on its own
-    ref = np.asarray(jax.jit(lambda p, t: reference.forward(
-        file, p, t, ticks + 1))(params if drawn is None else drawn,
-                                full[None]))[0]
-    return gateway, got[0][:, :cfg.vocab_size], ref
-
-
-@pytest.mark.parametrize("n", [1, 5, WINDOW - 3, WINDOW, WINDOW + 9,
-                               2 * WINDOW - 4, 3 * WINDOW + 5],
-                         ids=["1", "short", "decode-laps", "W", "second-lap",
-                              "decode-laps-again", "3W+5"])
-def test_slot_path_equals_the_reference_across_the_rings_wrap(tiny, served,
-                                                              n):
-    """Chunked prefill, then 8 decode ticks through the gateway's own
-    programs and both pools, against the reference's full forward, on
-    logits: a prompt shorter than the window (whose decode crosses the
-    ring's first lap at ``W - 3``), one a window long, prompts that end
-    inside their second lap (the padded tail of their last chunk must not
-    reach the ring), and decode that crosses a lap again."""
-    file, cfg, params = tiny
-    before = served._batcher.counts("moe_pairs")    # cumulative
-    gateway, got, ref = _slot_path(file, cfg, params, n, gateway=served)
-    np.testing.assert_allclose(got, ref, atol=ATOL, rtol=RTOL)
-    pairs = gateway._batcher.counts("moe_pairs") \
-        - (0 if before is None else before)
-    assert pairs[0] == pairs[3:-2].sum() > 0 == pairs[-2] == pairs[-1] \
-        and len(pairs) == 5 + len(cfg.held)
-    padded, ticks = -(-n // CHUNK) * CHUNK, 8
-    assert pairs[1] == (padded + ticks * 4) * cfg.n_layer \
-        * cfg.experts_per_token
-
-
 @pytest.mark.parametrize("chunk", [8, 24, 40], ids=["W/2", "1.5W", "2.5W"])
 def test_a_chunk_that_straddles_the_windows_edge(chunk):
     """Chunks shorter and LONGER than the window (a chunk of 24 or 40 keeps
     its last 16 rows in the ring and its first queries still see the ring
     as it was): 61 tokens, so every chunk but the first straddles the edge
     of some query's window and the last is padded."""
-    file = _file()
-    cfg, params = _model(file, seed=4)
-    _, got, ref = _slot_path(file, cfg, params, 61, prefill_chunk=chunk)
+    cfg, params = harness.model(SPEC, seed=4)
+    (got, ref), = harness.slot_path_logits(
+        SPEC, harness.gateway(cfg, params, prefill_chunk=chunk), params,
+        (61,))
     np.testing.assert_allclose(got, ref, atol=ATOL, rtol=RTOL)
 
 
-def test_slot_write_read_and_reset_walk_both_pools(tiny):
-    _, cfg, params = tiny
+def test_slot_write_read_and_reset_walk_both_pools():
+    cfg, params = harness.model(SPEC)
     fam = cache_family(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, 40), 0,
                                 cfg.vocab_size)
@@ -306,8 +175,8 @@ def test_slot_write_read_and_reset_walk_both_pools(tiny):
                    for a in blank.ring + (blank.k, blank.v))
 
 
-def test_the_sweeps_counts_follow_both_pools(tiny, served):
-    cfg, gateway = tiny[1], served
+def test_the_sweeps_counts_follow_both_pools():
+    cfg, gateway = harness.model(SPEC)[0], harness.served(SPEC)
     bat = gateway._batcher
     assert bat.sweep_plan.ring.Smax == WINDOW
     assert set(bat.sweep_by_kind([5, 40])) == {"full", "window"}
@@ -343,34 +212,6 @@ def test_route_softmax_is_the_softmax_over_all_renormalised_over_the_chosen():
                                want / want.sum(-1, keepdims=True), rtol=1e-5)
     assert (np.sort(chosen, -1) == np.sort(
         np.argsort(-every, -1)[:, :4], -1)).all()
-
-
-def test_the_four_shares_add_up_to_the_uncut_layer():
-    """EP4 at the tiny size: 16 experts over 4 shares of 4.  What each
-    share's expert layer adds to the stream, summed, is what the layer
-    holding all 16 adds, in the program and in the reference."""
-    file = _file(num_experts=16)            # every expert held: the uncut
-    cfg, params = _model(file, seed=6, std=LOUD)
-    part = params["runs"][0][0]
-    p = {k: v[0] for k, v in part.items()}
-    x = jax.random.normal(jax.random.PRNGKey(7), (2, 12, cfg.d_model))
-    whole, _ = window_moe.expert_ffn(x, p, cfg)
-    ref = reference._expert_layer(
-        file, x[0], p, lambda e: p["w_gu"][e], lambda e: p["w_down"][e])
-    np.testing.assert_allclose(np.asarray(whole[0]), np.asarray(ref),
-                               atol=ATOL, rtol=RTOL)
-    added = 0.0
-    for share in range(4):
-        held = tuple(range(4 * share, 4 * share + 4))
-        mine = dataclasses.replace(cfg, held_experts=held)
-        own = {**p, "w_gu": p["w_gu"][jnp.asarray(held)],
-               "w_down": p["w_down"][jnp.asarray(held)]}
-        out, counts = window_moe.expert_ffn(x, own, mine)
-        added = added + (out - x)
-        assert int(counts[0]) == int(counts[3:-2].sum())
-    np.testing.assert_allclose(np.asarray(added), np.asarray(whole - x),
-                               atol=ATOL, rtol=RTOL)
-    assert float(jnp.abs(whole - x).max()) > 1e-2
 
 
 # --------------------------------------------- grouped heads with a band
@@ -436,17 +277,3 @@ def test_ring_attention_is_the_band_over_the_whole_history(monkeypatch, pos):
 
 
 # ----------------------------------------------------------------- faults
-
-@pytest.mark.parametrize("fault", ["window_all", "write_first", "ring_short",
-                                   "ring_one_short", "yarn_window", "zero"])
-def test_a_planted_fault_reads_over_the_tolerance(fault):
-    """``reference/mellum_control.py``'s faults at the tiny size, on loud
-    weights: each moves the slot path's logits by far more than the
-    tolerance the sound path is held to above."""
-    file = _file()
-    cfg, params = _model(file, seed=8, std=LOUD)
-    faulty = control.WEIGHTS.get(fault, lambda p: p)(params)
-    with control.planted(fault):
-        _, got, ref = _slot_path(file, cfg, faulty, 3 * WINDOW + 5,
-                                 drawn=params)
-    assert np.abs(got - ref).max() > 50 * ATOL, np.abs(got - ref).max()

@@ -1,24 +1,14 @@
-"""The hybrid family's single-part block (``nemotron_h``: a layer is a mixer
-OR the expert layer) against its plain reference at a tiny size on the CPU,
-in float32: the uncached ``apply`` and the slot path (chunked prefill, then
-decode through the per-slot state) against the reference's token-by-token
-recurrence; the grouped state-space kernels against a per-head loop; the
-expert layer's shares against the uncut layer; the runs as repeated units;
-and planted faults, each of which must read over a tolerance (on weights
-drawn ten times louder than the family's, ``LOUD``, so that the layers and
-not the embedding make the logits).
-
-The tolerances.  ``ATOL`` / ``RTOL`` (2e-5, 1e-4) are the Granite family's:
-both sides compute in float32, the program's chunked scan sums a sub-chunk's
-terms in another order than the recurrence and its products run at the
-CPU's default precision, which reads 3e-7 to 4e-6 here on logits of about
-0.6; a fault below reads 1e-3 or more.  The kernels alone are held to 2e-4
-(state and ``y`` of order 1 to 10 over 256 tokens, float64 loop)."""
+"""What the hybrid family's single-part block alone has (``nemotron_h``: a
+layer is a mixer OR the expert layer; the probes every family answers are
+``test_family_conformance.py``'s): the runs as repeated units, the published
+sizes, the grouped state-space kernels against a per-head loop (held to 2e-4:
+state and ``y`` of order 1 to 10 over 256 tokens, float64 loop), the padding
+of an expert's matrices, and the family's own departures from the published
+mathematics, each of which must read over a tolerance (on weights drawn at
+``harness.LOUD``)."""
 
 import contextlib
 import dataclasses
-import json
-import os
 
 import numpy as np
 import pytest
@@ -26,71 +16,20 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import deepspeed_tpu
 from benchmarks.chip import nemotron_h_family
-from benchmarks.chip.reference import hybrid_ssm_moe_control as control
 from benchmarks.chip.reference import nemotron_h_reference as reference
 from deepspeed_tpu.models import (cache_family, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference)
 from deepspeed_tpu.moe import held_experts
 from deepspeed_tpu.ops.pallas import ssm
+from tests.unit.models import family_harness as harness
+from tests.unit.models.family_harness import ATOL, LOUD
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
-NAME = "nemotron-3-nano-30b-a3b-ep4"
-CHUNK = 16              # the gateway's prefill chunk; the scan's is 8
-ATOL, RTOL = 2e-5, 1e-4
-LOUD = 0.2
-
-
-def _published():
-    with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
-                           NAME + ".json")) as f:
-        return json.load(f)
-
-
-def _file(**keys):
-    """The benchmark's configuration file at the rehearsal's tiny sizes."""
-    file = _published()
-    with open(os.path.join(ROOT, "tests", "unit", "chipbench", "tiny",
-                           "configs", NAME + ".json")) as f:
-        file.update(json.load(f))
-    return {**file, **keys}
-
-
-def _model(file, seed=0, std=None, **replace):
-    """``std``: weights drawn that much louder than the family's 0.02 (the
-    fault tests: at 0.02 and this width the layers add a thousandth to the
-    logits, and so does any fault in them)."""
-    cfg = dataclasses.replace(nemotron_h_family.build(file),
-                              dtype=jnp.float32, **replace)
-    if std is None:
-        params = nemotron_h_family.init(cfg, jax.random.PRNGKey(seed),
-                                        jnp.float32)
-    else:
-        params = hybrid_ssm_moe.init(
-            dataclasses.replace(cfg, param_dtype=jnp.float32),
-            jax.random.PRNGKey(seed), std=std)
-    # a selection bias large enough to move choices at this size
-    for run in params["runs"]:
-        for part in hybrid_ssm_moe.run_parts(run):
-            if "router_bias" in part:
-                part["router_bias"] = part["router_bias"] * 30
-    return cfg, params
-
-
-def _gateway(cfg, params, **serving):
-    engine = deepspeed_tpu.init_inference(model=(cfg, params),
-                                          config={"dtype": "float32"})
-    gateway = engine.serve(config={"slots": 4, "max_len": 128,
-                                   "prefill_chunk": CHUNK,
-                                   "queue_capacity": 8, **serving})
-    gateway.shutdown(drain=False, timeout=60)
-    return gateway
+SPEC = harness.SPECS["nemotron-3-nano-30b-a3b-ep4"]
 
 
 def test_a_layer_is_one_part_and_a_run_is_a_repeated_unit():
-    cfg = nemotron_h_family.build(_published())
+    cfg = nemotron_h_family.build(harness.published(SPEC.name))
     M, E, A = "mamba", "experts", "attention"
     # MEMEM*EMEMEM*EMEME: seven runs, three of them scans
     assert cfg.units == (((M, E), (0, 0), 2), ((M,), (2,), 1),
@@ -98,7 +37,7 @@ def test_a_layer_is_one_part_and_a_run_is_a_repeated_unit():
                          ((A,), (1,), 1), ((E, M), (5, 6), 2),
                          ((E,), (7,), 1))
     assert [u for u, _ in reference._units(
-        _published()["hybrid_override_pattern"])] == [
+        harness.published(SPEC.name)["hybrid_override_pattern"])] == [
         "ME", "M", "*", "EM", "*", "EM", "E"]
     assert (cfg.count(M), cfg.count(E), cfg.count(A)) == (8, 8, 2)
     # the one-kind reading the Granite block has always had
@@ -124,7 +63,7 @@ def test_a_layer_is_one_part_and_a_run_is_a_repeated_unit():
 
 
 def test_the_published_sizes():
-    cfg = nemotron_h_family.build(_published())
+    cfg = nemotron_h_family.build(harness.published(SPEC.name))
     assert (cfg.d_model, cfg.d_inner, cfg.d_conv, cfg.ssm_state,
             cfg.ssm_chunk, cfg.ssm_groups) == (2688, 4096, 6144, 128, 128, 8)
     assert (cfg.n_head, cfg.n_kv_head, cfg.head_dim) == (32, 2, 128)
@@ -151,19 +90,8 @@ def test_the_published_sizes():
     assert held_experts.gmm_tiling(1920, 2688) == (128, 1920, 896)
 
 
-@pytest.mark.parametrize("key,value,said", [
-    ("n_group", 2, "no expert groups"), ("mlp_hidden_act", "silu", ""),
-    ("tie_word_embeddings", True, "matrix of its own"),
-    ("mlp_bias", True, "no bias"), ("moe_latent_size", 1024, "latent"),
-    ("num_nextn_predict_layers", 1, "MTP"),
-    ("hybrid_override_pattern", "MEMEM*EMEMEM*EMEM-", "")])
-def test_a_sibling_configuration_is_refused_by_name(key, value, said):
-    with pytest.raises(AssertionError, match=said or None):
-        nemotron_h_family.build({**_published(), key: value})
-
-
 def test_the_cache_holds_two_row_layers_and_state_for_the_mixers_alone():
-    cfg, _ = _model(_file())
+    cfg, _ = harness.model(SPEC)
     assert cache_family(cfg) is hybrid_ssm_moe_inference.FAMILY
     cache = cache_family(cfg).init_cache(cfg, 3, 64)
     n_ssm, n_attn = cfg.count("mamba"), cfg.count("attention")
@@ -172,61 +100,6 @@ def test_the_cache_holds_two_row_layers_and_state_for_the_mixers_alone():
     state, tails = cache.state
     assert state.shape == (n_ssm, 3, 16, 128)
     assert tails.shape == (n_ssm, 3, 3, 128 + 2 * 2 * 16)
-
-
-def test_apply_equals_the_reference():
-    file = _file()
-    cfg, params = _model(file, seed=1)
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 0,
-                                cfg.vocab_size)
-    got = hybrid_ssm_moe.apply(params, tokens, cfg)[..., :cfg.vocab_size]
-    # compiled: op by op the reference compiles every primitive of every
-    # new shape on its own
-    ref = jax.jit(lambda p, t: reference.forward(file, p, t, 40))(params,
-                                                                  tokens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=ATOL,
-                               rtol=RTOL)
-
-
-def _slot_path(file, cfg, params, n, ticks=8):
-    gateway = _gateway(cfg, params)
-    rng = np.random.default_rng(3 + n)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)]
-    replies, got = gateway.probe_logits(prompts, ticks)
-    full = np.concatenate([prompts[0], np.asarray(replies[0], np.int32)])
-    # compiled: op by op the reference compiles every primitive of every
-    # new shape on its own
-    ref = np.asarray(jax.jit(lambda p, t: reference.forward(
-        file, p, t, ticks + 1))(params, full[None]))[0]
-    return gateway, got[0][:, :cfg.vocab_size], ref
-
-
-@pytest.mark.parametrize("n", [1, 8, CHUNK, CHUNK + 1, 3 * CHUNK + 5],
-                         ids=["1", "Q", "C", "C+1", "3C+5"])
-def test_slot_path_equals_the_reference_full_forward(n):
-    """Chunked prefill, then 8 decode ticks through the gateway's own
-    programs and slot cache, against the reference's full forward, on
-    logits: prompts that are and are not multiples of the prefill chunk
-    (16) and of the scan's ``chunk_size`` (8)."""
-    file = _file()
-    cfg, params = _model(file)
-    gateway, got, ref = _slot_path(file, cfg, params, n)
-    np.testing.assert_allclose(got, ref, atol=ATOL, rtol=RTOL)
-    # rows stepped = live slots x state-space layers; scan tokens real and
-    # padded; the expert layers alone feed the pair counts
-    named = dict(zip(hybrid_ssm_moe_inference.STATE_COUNTERS,
-                     gateway._batcher.counts("state_steps")))
-    n_ssm, ticks = cfg.count("mamba"), 8
-    padded = -(-n // CHUNK) * CHUNK
-    assert named == {"ssm_rows_stepped": ticks * n_ssm,
-                     "scan_tokens_real": n * n_ssm,
-                     "scan_tokens_padded": (padded - n) * n_ssm}
-    pairs = gateway._batcher.counts("moe_pairs")
-    assert pairs[0] == pairs[3:-2].sum() > 0 == pairs[-2] == pairs[-1] \
-        and len(pairs) == 5 + len(cfg.held)
-    # routed in all: every row of every call, the ticks' four slots each
-    assert pairs[1] == (padded + ticks * 4) * cfg.count("experts") \
-        * cfg.experts_per_token
 
 
 # ------------------------------------------------------ the grouped kernels
@@ -337,35 +210,8 @@ def _expert_layer(cfg, params):
     return jax.tree_util.tree_map(lambda a: a[0], params["runs"][0][1])
 
 
-def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
-    """Experts 0-3, 4-7, 8-11, 12-15 of 16 on four chips: the routed parts
-    the four shares give, with the shared expert counted once, are the
-    uncut layer's result."""
-    file = _file(n_routed_experts=16)
-    cfg, params = _model(file)
-    p = _expert_layer(cfg, params)
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 9, cfg.d_model))
-    whole, counts = hybrid_ssm_moe.expert_ffn(x, p, cfg)
-    assert counts[0] == counts[1] == 2 * 9 * cfg.experts_per_token
-    routed, pairs = 0.0, 0
-    for share in range(4):
-        held = tuple(range(4 * share, 4 * share + 4))
-        part = dataclasses.replace(cfg, held_experts=held)
-        mine = {**p, "w_up": p["w_up"][held[0]:held[-1] + 1],
-                "w_down": p["w_down"][held[0]:held[-1] + 1]}
-        out, c = hybrid_ssm_moe.expert_ffn(x, mine, part)
-        alone, _ = hybrid_ssm_moe.expert_ffn(
-            x, {**mine, "w_down": mine["w_down"] * 0}, part)
-        routed = routed + (out - alone)         # this share's routed part
-        shared = alone - x                      # what every chip computes
-        pairs += int(c[0])
-    assert pairs == int(counts[0])
-    np.testing.assert_allclose(np.asarray(x + shared + routed),
-                               np.asarray(whole), atol=1e-6, rtol=1e-5)
-
-
 def test_the_padding_of_an_experts_matrices_is_zero_and_adds_nothing():
-    cfg, params = _model(_file())
+    cfg, params = harness.model(SPEC)
     p = _expert_layer(cfg, params)
     f, fs = cfg.d_expert, cfg.d_expert_stored
     assert (f, fs) == (24, 128)
@@ -450,31 +296,20 @@ def test_a_planted_fault_reads_over_a_tolerance(monkeypatch, fault):
     two agree within ``ATOL`` / ``RTOL`` (5e-6 read on logits of 7);
     planted, some logit is off by 50 times ``ATOL`` or more (each reads 2
     to 8 whole units)."""
-    file = _file()
-    cfg, params = _model(file, seed=1, std=LOUD, **(
-        {"routed_scale": 1.0} if fault == "scale_left_out" else {}))
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 0,
-                                cfg.vocab_size)
+    file = harness.tiny_file(SPEC.name)
+    cfg, params = harness.model(SPEC, seed=1, std=LOUD)
+    if fault == "scale_left_out":
+        cfg = dataclasses.replace(cfg, routed_scale=1.0)
+    tokens = harness.tokens(cfg)
     with _planted(monkeypatch, fault):
-        got = np.asarray(hybrid_ssm_moe.apply(params, tokens, cfg)
-                         )[..., :cfg.vocab_size]
-        # compiled (inside the patch: traced with the fault planted)
+        got = np.asarray(jax.jit(lambda p, t: hybrid_ssm_moe.apply(
+            p, t, cfg))(params, tokens))[..., :cfg.vocab_size]
+        # compiled inside the patch (traced with the fault planted), so not
+        # the harness's cached program
         ref = np.asarray(jax.jit(lambda p, t: reference.forward(
             file, p, t, 40))(params, tokens))
-    off = np.abs(got - ref) - RTOL * np.abs(ref)
     if fault == "none":
-        assert off.max() <= ATOL
+        assert harness.off(got, ref) <= ATOL
     else:
-        assert off.max() > 50 * ATOL, off.max()
+        assert harness.off(got, ref) > 50 * ATOL, harness.off(got, ref)
 
-
-def test_a_state_kept_in_bf16_reads_over_a_tolerance():
-    """``H`` rounded to bf16 after every chunk and every tick (the chip
-    control's ``bf16_state``), through the slot path: three chunk edges and
-    8 ticks of rounding (reads 6e-3 where the sound run reads 1.5e-6)."""
-    file = _file()
-    cfg, params = _model(file, std=LOUD)
-    _, got, ref = _slot_path(file, cfg, params, 3 * CHUNK + 5)
-    with control.planted("bf16_state"):
-        _, got, ref = _slot_path(file, cfg, params, 3 * CHUNK + 5)
-    assert (np.abs(got - ref) - RTOL * np.abs(ref)).max() > 50 * ATOL

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.moe.held_experts import (RELU2, SWIGLU, Routing,
                                             held_experts_ffn, n_pair_counts,
-                                            pair_counts, pairs_cap, route,
+                                            pair_counts, pairs_cap,
+                                            read_pair_counts, route,
                                             route_softmax)
 
 T, K, D, F = 128, 4, 32, 16
@@ -131,3 +132,10 @@ def test_the_held_pairs_in_pages_are_the_reference(name, form, stacked,
     assert vector.shape == (n_pair_counts(len(HELD)),)
     assert list(vector) == [here, N_PAIRS, sum(c > 0 for c in per_expert)
                             ] + per_expert + [pages_over, 0]
+    named = read_pair_counts(vector)
+    assert list(named) == ["held", "routed", "visits", "per_expert",
+                           "pages_over_cap", "zero"]
+    assert named["per_expert"] == per_expert and all(
+        type(c) is int for c in named["per_expert"])
+    assert [named[k] for k in named if k != "per_expert"] == [
+        here, N_PAIRS, sum(c > 0 for c in per_expert), pages_over, 0]

@@ -12,7 +12,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.moe.held_experts import (Routing, held_experts_ffn,
                                             local_slots, n_pair_counts,
-                                            pair_counts, pairs_cap, route,
+                                            pair_counts, pairs_cap,
+                                            read_pair_counts, route,
                                             route_softmax,
                                             route_softmax_all, zero_weight)
 
@@ -155,6 +156,11 @@ def test_held_and_identity_parts_against_the_reference(which):
     assert vector.shape == (n_pair_counts(len(HELD)),)
     assert list(vector) == [here, T * K, sum(c > 0 for c in per_expert)
                             ] + per_expert + [pages_over, zero]
+    # the one reader of the vector's order: the names the span uses
+    assert read_pair_counts(vector) == {
+        "held": here, "routed": T * K,
+        "visits": sum(c > 0 for c in per_expert), "per_expert": per_expert,
+        "pages_over_cap": pages_over, "zero": zero}
     if which == "all_held":
         # dropless: every pair of the call lands here, pages beyond the cap
         assert here == T * K and cap < T * K and pages_over > 0 == zero
@@ -205,5 +211,5 @@ def test_the_other_gates_count_no_zero_compute_pair(gate):
     out, counts = held_experts_ffn(jnp.asarray(h), routing, stack, HELD,
                                    N_EXPERTS)
     assert counts.shape == (len(HELD) + 2,) and int(counts[-1]) == 0
-    vector = np.asarray(pair_counts(counts, T * K))
-    assert vector[-1] == 0 and vector[0] == vector[3:-2].sum() > 0
+    named = read_pair_counts(pair_counts(counts, T * K))
+    assert named["zero"] == 0 and named["held"] == sum(named["per_expert"]) > 0

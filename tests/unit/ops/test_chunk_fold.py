@@ -3,6 +3,8 @@ key-value head's whole GROUP of query heads against one key block.
 Interpret mode, float32, against ``cached_attention_reference``; the live
 range a step's block is clamped to against a brute-force walk of the mask."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,26 +42,49 @@ def _pos(ragged, base):
         else jnp.asarray(base, jnp.int32)
 
 
+# The kernel's call and its comparator as ONE jitted program each: what is
+# static is the group size (and, to the tracer, the operands' shapes and
+# whether there is a ``valid_from``); the frontiers, the first real keys and
+# the rows are arguments, so the cases that differ in where a frontier
+# stands share a trace, a lowering and a compile.
+
+@functools.partial(jax.jit, static_argnames="G")
+def _plain_pair(q, k, v, pos, valid_from, G):
+    return (cached_attention(q, k, v, pos, kv_heads=k.shape[2],
+                             valid_from=valid_from),
+            cached_attention_reference(q, _grouped(k, G), _grouped(v, G),
+                                       pos, valid_from=valid_from))
+
+
+@functools.partial(jax.jit, static_argnames=("G", "window"))
+def _ring_pair(q, ring_k, ring_v, fresh_k, fresh_v, hist_k, hist_v, pos, G,
+               window):
+    return (ring_attention(q, ring_k[None], ring_v[None], fresh_k, fresh_v,
+                           pos, window, 0, kv_heads=hist_k.shape[2]),
+            cached_attention_reference(q, _grouped(hist_k, G),
+                                       _grouped(hist_v, G), pos,
+                                       window=window))
+
+
 def _plain(G, D, pos, valid_from, seed=0):
     """A chunk over whole rows of 2,048: ``(got, want)``."""
     B, Smax, Hkv = 2, 2048, 2
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = _rows(kq, B, SQ, Hkv * G, D)
     k, v = _rows(kk, B, Smax, Hkv, D), _rows(kv, B, Smax, Hkv, D)
-    got = cached_attention(q, k, v, pos, kv_heads=Hkv, valid_from=valid_from)
-    want = cached_attention_reference(q, _grouped(k, G), _grouped(v, G), pos,
-                                      valid_from=valid_from)
-    return got, want
+    return _plain_pair(q, k, v, pos, valid_from, G)
 
 
-def _ring(G, D, pos, seed=0, window=WINDOW, sq=SQ):
+def _ring(G, D, pos, seed=0, window=WINDOW, sq=SQ, history=None):
     """A chunk beside a ring (``ring_attention``): position ``p`` of the
     history in cell ``p mod R``, the cells no token has reached (and, once
     lapped, nothing else) holding junk: ``(got, want)``, the reference a
-    banded pass over the history laid out whole."""
+    banded pass over the history laid out whole (``history`` tokens of it
+    where given: what lies past a chunk's last query no query sees, and one
+    length for every frontier is one program)."""
     B, Hkv, R = 2, 2, window
     p = np.broadcast_to(np.asarray(pos), (B,))
-    S = int(p.max()) + sq
+    S = history or int(p.max()) + sq
     kq, kk, kv, kj = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = _rows(kq, B, sq, Hkv * G, D)
     hist_k, hist_v = _rows(kk, B, S, Hkv, D), _rows(kv, B, S, Hkv, D)
@@ -71,13 +96,8 @@ def _ring(G, D, pos, seed=0, window=WINDOW, sq=SQ):
         ring_v[b, at % R] = np.asarray(hist_v)[b, at].reshape(len(at), -1)
     fresh_k, fresh_v = (jnp.stack([h[b, p[b]:p[b] + sq] for b in range(B)])
                         for h in (hist_k, hist_v))
-    got = ring_attention(
-        q, jnp.asarray(ring_k)[None], jnp.asarray(ring_v)[None],
-        fresh_k, fresh_v, jnp.asarray(pos), window, 0, kv_heads=Hkv)
-    want = cached_attention_reference(
-        q, _grouped(hist_k, G), _grouped(hist_v, G), jnp.asarray(pos),
-        window=window)
-    return got, want
+    return _ring_pair(q, jnp.asarray(ring_k), jnp.asarray(ring_v), fresh_k,
+                      fresh_v, hist_k, hist_v, jnp.asarray(pos), G, window)
 
 
 def _close(got, want):
@@ -107,7 +127,8 @@ def test_the_folded_step_matches_the_reference(pallas_interpret, G, band,
         # the reference hides the same keys, so the comparison is whole
     else:
         base = (512 if band == "unlapped" else 2048) + past
-        got, want = _ring(G, D, np.asarray(_pos(ragged, base)), seed=G)
+        got, want = _ring(G, D, np.asarray(_pos(ragged, base)), seed=G,
+                          history=2048 + 1 + SQ)
     _close(got, want)
 
 

@@ -3,6 +3,8 @@ under the interpreter, in the fast tier: the serving tests here take the
 dense path (no chip, no interpreter), so without this file the driver's run
 would compile the sweep for a described v5e and never execute its body."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -67,9 +69,11 @@ def _repeats(slopes, per_row, mask) -> bool:
                               "slopes" if slopes else "plain",
                               "rowpos" if per_row else "scalar", mask]),
                  marks=[pytest.mark.slow] * _repeats(slopes, per_row, mask))
-    for mask in sorted(_MASK_CASES) for per_row in (False, True)
-    for slopes in (False, True) for window in (None, 48)
-    for kind in ("bf16", "int8")])
+    # the mask varies fastest: the cases of one program (what is static: the
+    # rest, and a mask's number of rows) run one after another
+    for kind in ("bf16", "int8") for window in (None, 48)
+    for slopes in (False, True) for per_row in (False, True)
+    for mask in sorted(_MASK_CASES)])
 def test_masked_decode_sweep(pallas_interpret, kind, window, slopes, per_row,
                              mask):
     """The single-token sweep told which rows are live, on layer 2 of a pool
@@ -93,10 +97,47 @@ def test_masked_decode_sweep_at_the_rules_block(pallas_interpret, kind,
     _masked_decode_sweep(kind, window, False, True, mask, None)
 
 
-def _masked_decode_sweep(kind, window, slopes, per_row, mask, block):
-    """``block``: the work list's, built here and handed in; None leaves
-    list and block to ``cached_attention``."""
+def _fold(x):
+    return x.reshape(x.shape[:3] + (-1,))
+
+
+@functools.lru_cache(maxsize=None)
+def _sweep_programs(kind, windowed, slopes, per_row, B, block):
+    """``(sweep, twice, reference, dense grid)``, each ONE jitted program a
+    process for what is static here (the banks' type, a window or none,
+    slopes or none, a scalar or a per-row frontier, the pool's rows, the
+    work list's block): a case's frontiers, live flags, window, banks and
+    garbage are ARGUMENTS, so the cases of a mask, its ``-garbage`` and its
+    ``-twice`` trace, lower and compile nothing of their own."""
     from tests.unit.ops.dense_grid_decode import dense_grid_decode
+    H, D = 4, 32
+    slope = gpt.alibi_slopes(H) if slopes else None
+
+    def sweep(lay, act, pos, win, q, k, v, scales):
+        work = block and decode_sweep(pos, B, SMAX, block, act, win)
+        return cached_attention(q, _fold(k), _fold(v), pos, window=win,
+                                slopes=slope, layer=lay, active=act,
+                                sweep=work, **scales)
+
+    def twice(lay, act, pos, win, q, first, inf, k, v, scales):
+        return (sweep(lay, act, pos, win, q, *first, inf),
+                sweep(lay, act, pos, win, q, k, v, scales))
+
+    def reference(q, k, v, pos, win):
+        return cached_attention_reference(q, k, v, pos, window=win,
+                                          slopes=slope)
+
+    def dense(q, k, v, lay, pos, win, banks):
+        return dense_grid_decode(
+            q.reshape(B, 1, H * D), _fold(k), _fold(v), lay, pos,
+            1.0 / D ** 0.5, block or decode_block_k(SMAX, H * D), H, *banks,
+            window=win, slopes=slope)
+    return tuple(jax.jit(f) for f in (sweep, twice, reference, dense))
+
+
+def _masked_decode_sweep(kind, window, slopes, per_row, mask, block):
+    """``block``: the work list's, built in the program and handed to the
+    kernel; None leaves list and block to ``cached_attention``."""
     rows = _MASK_CASES[mask]
     L, B, Smax, H, D = 3, len(rows), SMAX, 4, 32
     dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
@@ -108,65 +149,49 @@ def _masked_decode_sweep(kind, window, slopes, per_row, mask, block):
                       jnp.int32)
     active = np.asarray([a for _, a in rows])
     win = None if window is None else jnp.int32(window)
-    slope = gpt.alibi_slopes(H) if slopes else None
-
-    def fold(x):
-        return x.reshape(x.shape[:3] + (-1,))
+    sweep, twice, reference, dense = _sweep_programs(
+        kind, window is not None, slopes, per_row, B, block)
 
     scales, banks = {}, ()
     if kind == "int8":
         (k, k_s), (v, v_s) = quantize_kv(k), quantize_kv(v)
-        scales = dict(k_scale=fold(k_s), v_scale=fold(v_s))
-        banks = (fold(k_s), fold(v_s))
+        scales = dict(k_scale=_fold(k_s), v_scale=_fold(v_s))
+        banks = (_fold(k_s), _fold(v_s))
         ref_k = dequantize_kv(k, k_s, jnp.float32)
         ref_v = dequantize_kv(v, v_s, jnp.float32)
     else:
         ref_k, ref_v = k.astype(jnp.float32), v.astype(jnp.float32)
 
-    layer = 2
-
-    def sweep(lay, act, k, v, scales):
-        work = block and decode_sweep(pos, B, Smax, block, act, win)
-        return cached_attention(q, fold(k), fold(v), pos, window=win,
-                                slopes=slope, layer=lay, active=act,
-                                sweep=work, **scales)
-
-    got = jax.jit(lambda lay, act: sweep(lay, act, k, v, scales))(
-        jnp.int32(layer), jnp.asarray(active))
-    got = np.asarray(got, np.float32)
+    layer = jnp.int32(2)
+    live = jnp.asarray(active)
+    got = np.asarray(sweep(layer, live, pos, win, q, k, v, scales),
+                     np.float32)
     if mask.endswith("-garbage"):
         beyond = (jnp.arange(Smax)[None, :] >
                   jnp.broadcast_to(pos, (B,))[:, None])[None, :, :, None, None]
         huge = jnp.where(beyond, 1e30, 1.0)
         if kind == "int8":      # the codes stay codes: the scales carry it
-            dirty = dict(k_scale=fold(k_s * huge), v_scale=fold(v_s * huge))
-            again = jax.jit(lambda lay, act: sweep(lay, act, k, v, dirty))
+            again = sweep(layer, live, pos, win, q, k, v, dict(
+                k_scale=_fold(k_s * huge), v_scale=_fold(v_s * huge)))
         else:
-            again = jax.jit(lambda lay, act: sweep(
-                lay, act, (k * huge).astype(dtype), (v * huge).astype(dtype),
-                scales))
-        np.testing.assert_array_equal(got, np.asarray(
-            again(jnp.int32(layer), jnp.asarray(active)), np.float32))
+            again = sweep(layer, live, pos, win, q, (k * huge).astype(dtype),
+                          (v * huge).astype(dtype), scales)
+        np.testing.assert_array_equal(got, np.asarray(again, np.float32))
     if mask.endswith("-twice"):
         inf = {name: jnp.full_like(x, jnp.inf) for name, x in scales.items()}
         first = (jnp.full_like(k, jnp.inf), jnp.full_like(v, jnp.inf)) \
             if kind == "bf16" else (k, v)
-        both = jax.jit(lambda lay, act: (sweep(lay, act, *first, inf),
-                                         sweep(lay, act, k, v, scales)))
-        np.testing.assert_array_equal(got, np.asarray(
-            both(jnp.int32(layer), jnp.asarray(active))[1], np.float32))
+        np.testing.assert_array_equal(got, np.asarray(twice(
+            layer, live, pos, win, q, first, inf, k, v, scales)[1],
+            np.float32))
     assert got.shape == q.shape
     assert not got[~active].any(), "a dead row's result is zeros"
-    want = np.asarray(cached_attention_reference(
-        q.astype(jnp.float32), ref_k[layer], ref_v[layer], pos,
-        window=win, slopes=slope))
+    want = np.asarray(reference(q.astype(jnp.float32), ref_k[2], ref_v[2],
+                                pos, win))
     tol = 2e-2 if kind == "bf16" else 2e-5
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
-    was = dense_grid_decode(
-        q.reshape(B, 1, H * D), fold(k), fold(v), layer, pos, 1.0 / D ** 0.5,
-        block or decode_block_k(Smax, H * D), H, *banks, window=win,
-        slopes=slope)
-    was = np.asarray(was, np.float32).reshape(got.shape)
+    was = np.asarray(dense(q, k, v, layer, pos, win, banks),
+                     np.float32).reshape(got.shape)
     np.testing.assert_array_equal(got[active], was[active])
 
 
@@ -276,12 +301,14 @@ def test_the_grouped_sweep_at_agent_sats_row(pallas_interpret, block):
     16,384 tokens of a 256-wide row under 32 query heads, in blocks of 256
     (the size until PR 46) and of 1,024 (the rule's): ragged rows whose
     frontiers sit on both sides of the first 1,024-token edge and deep in
-    the slot, a dead row among them, against the dense formula."""
+    the slot (6,100 of 16,384: past five blocks of 1,024; it stood at 12,100
+    until PR 59, twice the interpreter's steps and no other kind of block), a
+    dead row among them, against the dense formula."""
     from deepspeed_tpu.ops.pallas.decode_attention import _gqa_decode
     smax, Hq, Hkv, D = 16384, 32, 2, 128
     G = Hq // Hkv
     assert decode_block_k(smax, Hkv * D) == 1024
-    pos = jnp.asarray([1022, 1023, 7000, 1024, 1025, 12100], jnp.int32)
+    pos = jnp.asarray([1022, 1023, 7000, 1024, 1025, 6100], jnp.int32)
     active = jnp.asarray([True, True, False, True, True, True])
     B = len(pos)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(46), 3)
@@ -294,16 +321,16 @@ def test_the_grouped_sweep_at_agent_sats_row(pallas_interpret, block):
         D ** -0.5, block, G))(q, k, v)
     got = np.asarray(got, np.float32).reshape(B, Hq, D)
 
-    def head(qh, kv_head):
-        """One query head against its key-value head (no head is repeated
-        out to the sixteen that share it)."""
-        one = lambda x: x[1].reshape(B, smax, Hkv, D)[:, :, kv_head][:, :, None]
-        return cached_attention_reference(
-            qh[:, None, None].astype(jnp.float32),
-            one(k).astype(jnp.float32), one(v).astype(jnp.float32), pos,
-            D ** -0.5)[:, 0, 0]
-
-    want = np.stack([np.asarray(head(q[:, h], h // G)) for h in range(Hq)], 1)
+    # one query head against its key-value head (no head is repeated out to
+    # the sixteen that share it), in ONE program for the 32
+    head = jax.jit(lambda qh, kh, vh: cached_attention_reference(
+        qh[:, None, None].astype(jnp.float32), kh, vh, pos,
+        D ** -0.5)[:, 0, 0])
+    one = lambda x, kv_head: x[1].reshape(B, smax, Hkv, D)[
+        :, :, kv_head][:, :, None].astype(jnp.float32)
+    banks = [(one(k, g), one(v, g)) for g in range(Hkv)]
+    want = np.stack([np.asarray(head(q[:, h], *banks[h // G]))
+                     for h in range(Hq)], 1)
     live = np.asarray(active)
     # results of 0.035 in the mean, 0.23 at most; bf16 probabilities read
     # 6e-4 off the float32 formula

@@ -7,6 +7,7 @@ three-array call and every kind of caller; the heads that do not tile a row
 fall back to a head a row.  Fast tier, like ``test_flash_causal_strips.py``.
 """
 
+import functools
 import importlib
 
 import numpy as np
@@ -67,6 +68,37 @@ _CALLS = [(case, form) for case in _CASES for form in ("packed", "three")
           if form == "three" or case not in _THREE_ONLY]
 
 
+def _operands(case):
+    B, Sq, Sk, H, D = _CASES[case][:5]
+    ks = jax.random.split(jax.random.PRNGKey(43), 4)
+    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
+    k, v = (jax.random.normal(kk, (B, Sk, H, D), jnp.float32)
+            for kk in ks[1:3])
+    return q, k, v, jax.random.normal(ks[3], (B, Sq, H, D), jnp.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def _dense_of(case):
+    """``(output, (dq, dk, dv))`` of the dense reference for a case's
+    operands, in ONE jitted program, once a process: the comparator of both
+    forms and both backward forms of a case (op by op it was two thirds of
+    a case's time)."""
+    kw = _CASES[case][6]
+    q, k, v, w = _operands(case)
+
+    def dense(q, k, v):
+        if "window" in kw:
+            return _dense_window(q, k, v, kw["window"])
+        return mha_reference(q, k, v, **{
+            n: x for n, x in kw.items() if n != "window"})
+
+    def both(q, k, v):
+        out, pull = jax.vjp(dense, q, k, v)
+        return out, pull(w)
+    out, grads = jax.jit(both)(q, k, v)
+    return np.asarray(out), [np.asarray(g) for g in grads]
+
+
 @pytest.mark.parametrize("two_kernel", [False, True],
                          ids=["fused-bwd", "two-kernel-bwd"])
 @pytest.mark.parametrize("case,form", _CALLS)
@@ -78,18 +110,9 @@ def test_flash_token_major_matches_reference(monkeypatch, case, form,
     assert fa.token_major(H, D) == tiles
     if two_kernel:
         monkeypatch.setattr(fa, "MAX_FUSED_BWD_NK", 0)
-    ks = jax.random.split(jax.random.PRNGKey(43), 4)
-    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
-    k, v = (jax.random.normal(kk, (B, Sk, H, D), jnp.float32)
-            for kk in ks[1:3])
-    w = jax.random.normal(ks[3], (B, Sq, H, D), jnp.float32)
+    q, k, v, w = _operands(case)
     blocks = dict(block_q=block, block_k=block)
-    ref_kw = {n: x for n, x in kw.items() if n != "window"}
-
-    def dense(q, k, v):
-        if "window" in kw:
-            return _dense_window(q, k, v, kw["window"])
-        return mha_reference(q, k, v, **ref_kw)
+    dense_out, want = _dense_of(case)
 
     if form == "packed":
         def kernel(q, k, v):
@@ -106,12 +129,10 @@ def test_flash_token_major_matches_reference(monkeypatch, case, form,
     with fa.tally_causal_tiles() as tally:
         out = kernel(q, k, v)
     assert tally[2:] == [1, int(form == "packed" and tiles)]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense(q, k, v)),
-                               atol=2e-5, rtol=2e-5)
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
+    np.testing.assert_allclose(np.asarray(out), dense_out, atol=2e-5,
+                               rtol=2e-5)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(kernel(*a) * w),
+                           argnums=(0, 1, 2)))(q, k, v)
     for g, r, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-4,
                                    rtol=1e-4, err_msg=f"d{name}")

@@ -294,133 +294,121 @@ _SERVED = pytest.mark.parametrize("family,int8", [
          "bf16-hybrid", "bf16-single_part", "bf16-window", "bf16-linear",
          "bf16-shortcut"])
 
-#: scans of a tick: one a segment of the family's step
-_SEGMENTS = {"latent": 2, "hybrid": 3, "single_part": 7, "window": 1,
-             "linear": 5}
+def _cell_files(cell):
+    """``(configuration file, traffic file)`` of one ``workloads`` entry of
+    ``BENCHMARK.json``: what the cell runs."""
+    import json
+    root = os.path.join(os.path.dirname(__file__), "..", "..", "..")
+
+    def read(*parts):
+        with open(os.path.join(root, *parts)) as f:
+            return json.load(f)
+    entry = next(w for w in read("BENCHMARK.json")["workloads"]
+                 if w["name"] == cell)
+    return (read("benchmarks", "chip", "configs", entry["config"] + ".json"),
+            read("benchmarks", "chip", "traffic", entry["traffic"] + ".json"))
 
 
-def _served(family):
-    """``(model module, config, slots, slot length, chunk)`` of a serving
-    cell's geometry: 64 slots x 1024 tokens in chunks of 128, 16 heads of 64
-    (two layers stand for 24), dense or GPT-MoE (one (dense, expert) pair of
-    four experts at the same widths); the latent-attention family at its
-    own cell's 128 x 8,192 in chunks of 512 and its published widths (64
-    heads over one 576-element row stored as 640 lanes, 7168 wide; one
-    dense and two expert layers, 4 of 384 experts held): a layer of the pool
-    is then larger than any one matrix, so "as large as a layer" still
-    means the pool; the hybrid state-space family at its own cell's 128 x
-    5,120 in chunks of 512, its published widths and its whole period of
-    ten layers (5 state-space, 1 attention, 4 state-space; 18 of 72 experts
-    held, a quarter of the vocabulary): 4.9 GB of float32 state a slot pool
-    keeps beside 2.7 GB of grouped KV, which the compiler must not copy
-    even once; the same family's single-part block (a layer is a mixer OR
-    the expert layer) at its own cell's 128 x 16,384 in chunks of 1,024, its
-    published widths and the 18 layers of its cut, ``(ME)x2 M * (EM)x3 *
-    (EM)x2 E`` as seven scans: the state step and the chunk scan with 8
-    groups of ``B`` and ``C``, the grouped-head decode with 16 query heads a
-    key-value head over a 256-wide row, and the grouped matmul at ``[2688,
-    1920]`` / ``[1920, 2688]`` (1856 stored padded), 32 of 128 experts
-    held; the window-and-full family at its own cell's 48 x 8,192 in chunks
-    of 1,024, its published widths and all 28 layers, ``(WWWF) x 7`` as one
-    scan: 7 layers of whole rows beside 21 rings of 1,024 cells a slot (7.75
-    GB with 6.97 GB of weights: 87% of the chip), the grouped sweep with 8
-    query heads a key-value head over a 512-wide row in blocks of 512 over
-    both pools, the banded grouped chunk pass over a ring unrolled beside
-    the chunk's rows, the grouped matmul at ``[2304, 1792]`` / ``[896,
-    2304]``, 16 of 64 experts held; the linear-attention family at its own
-    cell's 256 x 8,192 in chunks of 1,024, its published widths and the 8
-    layers of its cut, ``K+dense, K x 2, L, K x 3, L`` as five scans: 3.2 GB
-    of float32 delta-rule state (6 layers x 256 slots x 128 x 4096) beside
-    5.4 GB of latent rows on 2 layers, the KDA step and chunk scan at 32
-    heads of 128 x 128, the latent sweep at 32 heads, 32 of 256 experts of
-    ``[2304, 2048]`` / ``[1024, 2304]`` held; the shortcut-connected
-    double-layer family at its own cell's 64 x 6,144 in chunks of 512, its
-    published widths and the 4 double layers of its cut as one scan: 10.35
-    GB of weights beside 4.03 GB of latent rows on 8 cache layers (two a
-    layer), the latent sweep and chunk pass at 64 heads twice a scan step,
-    two dense FFNs of ``[6144, 24576]`` / ``[12288, 6144]`` each a stack of
-    its own (as ``[layers, 2, ...]`` the scan's slice of them was copied out
-    whole every step: 864 MB, PERF.md 6, PR 57), 16 of 512 experts of
-    ``[6144, 4096]`` / ``[2048, 6144]`` held behind a router 768 wide."""
+#: family -> (cell, cut).  The model, its configuration and its
+#: weights' shapes come from the cell's own configuration file through its
+#: ``builder`` and ``init`` hooks (as ``kinds/_serving.py`` builds them), the
+#: slots, slot length and chunk from its traffic file's ``serving`` block:
+#: nothing of a cell is written here, so a file that changes changes what the
+#: guards compile (``test_the_guards_compile_the_cells_own_files``).  ``cut``
+#: is what every guard compiles shallower or narrower than the cell, said in
+#: the row; a row without one compiles the cell's own stack.
+_GUARDED = {
+    # two layers stand for 24 (the ladder's plan is read at 24:
+    # ``_LADDER_LAYERS``)
+    "dense": ("gpt2m-serve-decode-sat", dict(n_layer=2)),
+    # no cell of its own: GPT-MoE at ``gpt2-medium``'s widths, one (dense,
+    # expert) pair of four experts
+    "moe": ("gpt2m-serve-decode-sat", dict(n_layer=2)),
+    # one dense and two expert layers of the cell's five, 4 of its 12 held
+    # experts, a tenth of its vocabulary: a layer of the pool is then larger
+    # than any one matrix, so "as large as a layer" still means the pool
+    "latent": ("kimik2-serve-reason-sat", dict(
+        n_layer=3, held_experts=(0, 1, 2, 3), vocab_size=2048)),
+    # the whole period of ten layers, ``M x 5 * M x 4`` as three scans: 4.9
+    # GB of float32 state a slot pool keeps beside 2.7 GB of grouped KV,
+    # which the compiler must not copy even once
+    "hybrid": ("granite4h-serve-rag-sat", {}),
+    # ``(ME)x2 M * (EM)x3 * (EM)x2 E``: all 18 layers as seven scans
+    "single_part": ("nemotron3n-serve-agent-sat", {}),
+    # all 28 layers, ``(WWWF) x 7`` as ONE scan: depth costs the compiler
+    # nothing, and 7 layers of one slot's whole rows against one ring layer
+    # of 48 slots is what ``_KNOWN_MOVES`` is sized by
+    "window": ("mellum2-serve-code-sat", {}),
+    # ``K+dense, K x 2, L, K x 3, L``: all 8 layers as five scans
+    "linear": ("kimilin-serve-think-sat", {}),
+    "shortcut": ("longcat-serve-docqa-sat", {}),
+    "selected": ("dots3n-serve-longgen-sat", {}),
+}
+
+
+def _served(family, **deeper):
+    """``(init, config, slots, slot length, chunk)`` of a row of
+    :data:`_GUARDED`: ``init(key)`` the weights in the type they are served
+    in, as the cell's kind draws them; ``deeper``: fields put back over the
+    row's cut."""
     import dataclasses
 
-    from deepspeed_tpu.models import gpt, gpt_moe
-    cfg = dataclasses.replace(gpt.GPT2_350M, n_layer=2, dtype=BF16)
-    if family == "dense":
-        return gpt, cfg, 64, SMAX, CHUNK
+    from benchmarks.chip.builders import resolve
+    cell, cut = _GUARDED[family]
+    file, traffic = _cell_files(cell)
+    cfg = resolve(file["builder"])(file)
+    draw = resolve(file["init"])
     if family == "moe":
-        return gpt_moe, gpt_moe.GPTMoEConfig(
+        from deepspeed_tpu.models import gpt_moe
+        cfg = gpt_moe.GPTMoEConfig(
             **{f.name: getattr(cfg, f.name)
-               for f in dataclasses.fields(cfg)}, num_experts=4), 64, SMAX, \
-            CHUNK
-    if family == "hybrid":
-        from deepspeed_tpu.models import hybrid_ssm_moe
-        return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
-            vocab_size=25088, max_seq_len=131072,
-            layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
-            d_model=4096, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
-            conv_kernel=4, ssm_chunk=256, n_head=32, n_kv_head=8,
-            head_dim=128, attn_scale=1 / 128, n_experts=72,
-            experts_per_token=10, d_expert=768, d_shared=1536,
-            held_experts=tuple(range(18)), embedding_multiplier=12.0,
-            residual_multiplier=0.22, logits_scaling=16.0, dtype=BF16,
-            param_dtype=BF16), 128, 5120, 512
-    if family == "single_part":
-        from deepspeed_tpu.models import hybrid_ssm_moe
-        M, E, A = "mamba", "experts", "attention"
-        return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
-            vocab_size=32768, max_seq_len=262144,
-            layer_types=(M, E, M, E, M, A, E, M, E, M, E, M, A, E, M, E, M,
-                         E),
-            d_model=2688, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
-            conv_kernel=4, ssm_chunk=128, ssm_groups=8, n_head=32,
-            n_kv_head=2, head_dim=128, attn_scale=128 ** -0.5,
-            n_experts=128, experts_per_token=6, d_expert=1856,
-            d_shared=3712, held_experts=tuple(range(32)), mixer_ffn=False,
-            expert_form="relu2", gate="sigmoid", routed_scale=2.5,
-            tie_head=False, dtype=BF16,
-            param_dtype=BF16), 128, 16384, 1024
-    if family == "window":
-        from deepspeed_tpu.models import window_moe
-        return window_moe, window_moe.WindowMoEConfig(
-            vocab_size=24576, max_seq_len=131072,
-            layer_types=(("window",) * 3 + ("full",)) * 7, d_model=2304,
-            n_head=32, n_kv_head=4, head_dim=128, window=1024,
-            rope_theta=500000.0,
-            yarn=(16.0, 8192, 32.0, 1.0, 1.2772588722239782), n_experts=64,
-            experts_per_token=8, d_expert=896,
-            held_experts=tuple(range(16)), dtype=BF16,
-            param_dtype=BF16), 48, 8192, 1024
-    if family == "linear":
-        from deepspeed_tpu.models import linear_latent_moe
-        return linear_latent_moe, linear_latent_moe.LinearLatentMoEConfig(
-            vocab_size=20480, max_seq_len=1048576, n_layer=8,
-            kda_layers=(1, 2, 3, 5, 6, 7), full_attn_layers=(4, 8),
-            d_model=2304, d_ff=9216, d_expert=1024, kda_heads=32,
-            kda_head_dim=128, n_head=32, kv_rank=512, d_nope=128, d_rope=64,
-            d_v=128, n_experts=256, experts_per_token=8,
-            held_experts=tuple(range(32)), routed_scale=2.446, dtype=BF16,
-            param_dtype=BF16), 256, 8192, 1024
-    if family == "shortcut":
-        from deepspeed_tpu.models import shortcut_latent_moe
-        return shortcut_latent_moe, \
-            shortcut_latent_moe.ShortcutLatentMoEConfig(
-                vocab_size=16384, max_seq_len=131072, n_layer=4, n_head=64,
-                d_model=6144, d_ff=12288, d_expert=2048, q_rank=1536,
-                kv_rank=512, d_nope=128, d_rope=64, d_v=128, n_experts=512,
-                n_zero_experts=256, experts_per_token=12,
-                held_experts=tuple(range(16)), routed_scale=6.0,
-                rope_theta=1e7, dtype=BF16, param_dtype=BF16), 64, 6144, 512
-    from deepspeed_tpu.models import latent_moe
-    smax = 8192
-    return latent_moe, latent_moe.LatentMoEConfig(
-        vocab_size=2048, max_seq_len=smax, n_layer=3, n_head=64,
-        d_model=7168, d_ff=18432, d_expert=2048, q_rank=1536,
-        kv_rank=512, d_nope=128, d_rope=64, d_v=128, n_experts=384,
-        experts_per_token=8, held_experts=(0, 1, 2, 3),
-        routed_scale=2.827, rope_theta=50000.0,
-        yarn=(64.0, 4096, 32.0, 1.0, 1.0, 1.0), dtype=BF16,
-        param_dtype=BF16), 128, smax, 512
+               for f in dataclasses.fields(cfg)}, num_experts=4)
+        draw = lambda cfg, key, dtype: jax.tree_util.tree_map(
+            lambda w: w.astype(dtype), gpt_moe.init(cfg, key))
+    cfg = dataclasses.replace(cfg, **{**cut, **deeper})
+    serving = traffic["serving"]
+    return (lambda key: draw(cfg, key, BF16)), cfg, serving["slots"], \
+        serving["max_len"], serving["prefill_chunk"]
+
+
+@pytest.mark.parametrize("family", sorted(_GUARDED))
+def test_the_guards_compile_the_cells_own_files(family):
+    """A row of :data:`_GUARDED` departs from its cell's files in what its
+    ``cut`` names and in nothing else: every other field of
+    the configuration is the cell's builder's, the geometry is the traffic
+    file's, and the weights are its ``init``'s in bf16."""
+    import dataclasses
+
+    from benchmarks.chip.builders import resolve
+    cell, cut = _GUARDED[family]
+    file, traffic = _cell_files(cell)
+    init, cfg, slots, smax, chunk = _served(family)
+    own = resolve(file["builder"])(file)
+    named = set(cut) | ({"num_experts"} if family == "moe" else set())
+    differs = {f.name for f in dataclasses.fields(own)
+               if getattr(own, f.name) != getattr(cfg, f.name)}
+    assert differs <= named, differs - named
+    assert bool(differs) == bool(named)     # and a named cut does cut
+    assert (slots, smax, chunk) == tuple(
+        traffic["serving"][k] for k in ("slots", "max_len", "prefill_chunk"))
+    leaves = jax.tree_util.tree_leaves(
+        jax.eval_shape(init, jax.random.PRNGKey(0)))
+    assert leaves and all(
+        x.dtype in (BF16, jnp.float32, jnp.int32) for x in leaves)
+    assert any(x.dtype == BF16 for x in leaves)
+    # a cut is shallower or narrower, never wider
+    for key, value in cut.items():
+        was = getattr(own, key)
+        size = lambda v: len(v) if isinstance(v, tuple) else v
+        assert size(value) <= size(was), key
+
+
+def _segments(fam, cfg, params):
+    """Scans of a tick: one a segment of the family's step."""
+    found = []
+    jax.eval_shape(lambda p: found.append(len(fam.step(
+        p, cfg, jnp.ones((1,), jnp.int32)))), params)
+    return found[0]
 
 
 def _described(tree, sharding):
@@ -532,11 +520,10 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
     64 last (``[L, B, S, H, D]``) fails this: the TPU lays it out with the
     tokens on the lanes and re-lays it around every write and kernel call."""
     from deepspeed_tpu.models import cache_family
-    model, cfg, slots, smax, _ = _served(family)
+    init, cfg, slots, smax, _ = _served(family)
     fam = cache_family(cfg)
 
-    params = _described(jax.eval_shape(
-        lambda: model.init(cfg, jax.random.PRNGKey(0))), v5e)
+    params = _described(jax.eval_shape(init, jax.random.PRNGKey(0)), v5e)
     cache = _described(jax.eval_shape(lambda: fam.init_cache(
         cfg, slots, smax, kv_dtype="int8" if int8 else None)), v5e)
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
@@ -547,7 +534,7 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
         donate_argnums=(1,))
     traced = tick.trace(params, cache, rows, rows, live).jaxpr
     _sweep_is_built_outside_the_layer_scan(
-        traced, slots, segments=_SEGMENTS.get(family, 1))
+        traced, slots, segments=_segments(fam, cfg, params))
     _sweeps_in_blocks_of(traced, cfg, slots, smax, _SWEEP_BLOCK[family])
     compiled = tick.lower(params, cache, rows, rows, live).compile()
     text = compiled.as_text()
@@ -680,21 +667,16 @@ def _compile_admission(v5e, family, int8, layers=None):
     compiled for the described chip at the serving cells' geometry
     (:func:`_served`; ``layers`` deep where given): ``(admit, extend, pool,
     row cache)``, the last two as shapes."""
-    import dataclasses
-
     from deepspeed_tpu.models import cache_family
     from deepspeed_tpu.serving.batcher import admission, pass_widths
-    model, cfg, slots, smax, chunk = _served(family)
-    if layers:
-        cfg = dataclasses.replace(cfg, n_layer=layers)
+    init, cfg, slots, smax, chunk = _served(
+        family, **({"n_layer": layers} if layers else {}))
     fam = cache_family(cfg)
     kv = "int8" if int8 else None
     # the weights in the type they are served in (a cast of the master
     # weights' is loop-invariant, and hoisted out of the chunk loop it
     # would count as the program's)
-    params = _described(jax.eval_shape(lambda: jax.tree_util.tree_map(
-        lambda w: w.astype(BF16), model.init(cfg, jax.random.PRNGKey(0)))),
-        v5e)
+    params = _described(jax.eval_shape(init, jax.random.PRNGKey(0)), v5e)
     pool = _described(jax.eval_shape(
         lambda: fam.init_cache(cfg, slots, smax, kv_dtype=kv)), v5e)
     row_cache = _described(jax.eval_shape(
@@ -726,11 +708,10 @@ def admission_of(v5e):
     process: the guards below read one compile where a worker runs them."""
     compiled = {}
 
-    def of(family, int8, layers=None):
-        if (family, int8, layers) not in compiled:
-            compiled[family, int8, layers] = _compile_admission(
-                v5e, family, int8, layers)
-        return compiled[family, int8, layers]
+    def of(*asked):
+        if asked not in compiled:
+            compiled[asked] = _compile_admission(v5e, *asked)
+        return compiled[asked]
 
     return of
 
@@ -744,9 +725,8 @@ def admission_of(v5e):
 _LADDER_LAYERS = 24
 
 
-@_SERVED
-def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
-                                                       int8):
+def _admission_is_one_program_on_the_pool_in_place(admission_of, family,
+                                                   int8):
     """The admission's device program (``serving.batcher.admission``: the
     chunk loop, the slot write and the bind) at the serving cells' geometry
     (:func:`_served`), for every model family.  A program that holds a
@@ -756,13 +736,13 @@ def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
     every layer), and the plan is what its widest pass's ``extend`` on the
     batch-1 row and the pool hold between them today.  A family with held
     experts moves the pairs held here and no row for the others."""
-    model, cfg, slots, smax, chunk = _served(family)
-    compiled, extend, pool, _ = admission_of(family, int8)
+    init, cfg, slots, smax, chunk = _served(family)
+    compiled, extend, pool, _ = admission_of(family, int8, None)
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the admission"
     assert " while(" in text, "no loop over the chunks"
     assert not _pair_rows(text, cfg, jax.eval_shape(
-        lambda: model.init(cfg, jax.random.PRNGKey(0))), chunk,
+        init, jax.random.PRNGKey(0)), chunk,
         family in _PAIRS_AS_MANY_AS_A_MATRIX_IS_TALL), \
         "a chunk builds rows for the pairs held elsewhere"
     moved = _beyond_the_known(
@@ -785,7 +765,6 @@ def test_admission_is_one_program_on_the_pool_in_place(admission_of, family,
     import re
 
     from deepspeed_tpu.serving.batcher import pass_widths
-    chunk = _served(family)[-1]
     widths = pass_widths(chunk, smax)
     assert widths == ((256, 128) if family in ("dense", "moe")
                       else (chunk,))
@@ -908,9 +887,8 @@ def _row_bank_ops(hlo_text, row_cache, opcode):
             if op == opcode and n in banks]
 
 
-@_SERVED
-def test_an_admissions_chunks_write_its_row_by_update_slices(admission_of,
-                                                             family, int8):
+def _an_admissions_chunks_write_its_row_by_update_slices(
+        admission_of, family, int8):
     """A further chunk of an admission lands in the batch-1 row cache by
     one update slice a bank a layer (``gpt_inference._chunk_slice``): the
     compiled program holds no ``scatter`` over a bank of that row (the
@@ -920,11 +898,28 @@ def test_an_admissions_chunks_write_its_row_by_update_slices(admission_of,
     scatter used to pin that layout; left free, the compiler lays the row
     out tokens-on-lanes for the chunk kernel and re-lays every bank for
     the slot write."""
-    compiled, _, _, row_cache = admission_of(family, int8)
+    compiled, _, _, row_cache = admission_of(family, int8, None)
     text = compiled.as_text()
     assert not _row_bank_ops(text, row_cache, "scatter")
     assert len(_row_bank_ops(text, row_cache, "copy")) <= \
         _ROW_BANK_COPIES.get((family, int8), 0)
+
+
+_ADMISSION_GUARDS = {
+    "one_program_in_place": _admission_is_one_program_on_the_pool_in_place,
+    "row_by_update_slices":
+        _an_admissions_chunks_write_its_row_by_update_slices}
+
+
+@pytest.mark.parametrize("guard", list(_ADMISSION_GUARDS))
+@_SERVED
+def test_an_admission(admission_of, family, int8, guard):
+    """The two guards that read one compile of a family's admission
+    (``admission_of``, once a process), as cases of one function so that
+    they run one after the other: apart, the scheduler hands them to two
+    workers about every other run and each compiles the program (99 s the
+    second time for one family in a whole run of this PR)."""
+    _ADMISSION_GUARDS[guard](admission_of, family, int8)
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
@@ -1160,9 +1155,7 @@ def _compiled_step(cell):
     return _STEPS[cell]
 
 
-@pytest.mark.parametrize("cell", list(_HEAD_CELLS))
-def test_train_step_holds_three_head_products_and_no_whole_logits(
-        v5e_host, cell):
+def _train_step_holds_three_head_products_and_no_whole_logits(cell):
     """The compiler is handed the head a chunk of the sequence at a time
     under a backward rule of its own, so it re-makes nothing: three
     vocabulary-sized products a chunk (the logits and the two gradients), no
@@ -1197,9 +1190,7 @@ def test_train_step_holds_three_head_products_and_no_whole_logits(
         assert len(gathers) == 1 and gathers[0] > entry, gathers
 
 
-@pytest.mark.parametrize("cell", ["gpt2m-train-s1024",
-                                  "opt1b3-train-zero3-4chip"])
-def test_train_step_relays_nothing_around_the_flash_kernels(v5e_host, cell):
+def _train_step_relays_nothing_around_the_flash_kernels(cell):
     """Between the qkv product and the output product of a layer nothing is
     re-laid (PERF.md 6, PR 43): the kernels' operands are the packed product
     as its projection wrote it, three times over, and rows that the output
@@ -1233,25 +1224,25 @@ def test_train_step_relays_nothing_around_the_flash_kernels(v5e_host, cell):
         assert f"bf16[{micro},{seq},{h * d}]" in result, (name, result)
 
 
+_STEP_GUARDS = {
+    "three_head_products_and_no_whole_logits":
+        _train_step_holds_three_head_products_and_no_whole_logits,
+    "nothing_relaid_around_the_flash_kernels":
+        _train_step_relays_nothing_around_the_flash_kernels}
+
+
+@pytest.mark.parametrize("cell,guard", [
+    (cell, guard) for cell in _HEAD_CELLS for guard in _STEP_GUARDS
+    # the kernels' operands are read in the two train cells' steps
+    if cell != "gpt2m-dp2-tp2" or guard.startswith("three")])
+def test_the_train_step(v5e_host, cell, guard):
+    """The guards that read one compile of a cell's fused step
+    (:func:`_compiled_step`, once a process), a cell's one after the
+    other."""
+    _STEP_GUARDS[guard](cell)
+
+
 # --------------------------------- the selecting family at its own geometry
-
-def _selected():
-    """``(model module, config, slots, slot length, chunk)`` of
-    ``dots3n-serve-longgen-sat``: the published widths and the nine layers
-    of its cut at 80 x 16,384 in chunks of 1,024: three kinds of cached
-    state (a 640-lane latent bank and a 128-wide bank of index keys on
-    three layers, a ring of 640 cells of 1,152 lanes on six)."""
-    import json
-    from benchmarks.chip import dots3_family
-    from deepspeed_tpu.models import sparse_latent_moe
-    with open(os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                           "benchmarks", "chip", "configs",
-                           "dots3-note-prev-ep32.json")) as f:
-        cfg = dots3_family.build(json.load(f))
-    import dataclasses
-    return sparse_latent_moe, dataclasses.replace(
-        cfg, param_dtype=BF16), 80, 16384, 1024
-
 
 def _moves_of_a_pool(text, pool):
     """``(elements, opcode)`` of every copy, transpose or slice of the
@@ -1278,10 +1269,13 @@ def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
     index's work list is built once a tick, outside the layer scans."""
     from deepspeed_tpu.models import cache_family
     from deepspeed_tpu.serving.batcher import admission
-    model, cfg, slots, smax, chunk = _selected()
+    # ``dots3n-serve-longgen-sat``: the nine layers of its cut at 80 x
+    # 16,384 in chunks of 1,024, three kinds of cached state (a 640-lane
+    # latent bank and a 128-wide bank of index keys on three layers, a ring
+    # of 640 cells of 1,152 lanes on six)
+    init, cfg, slots, smax, chunk = _served("selected")
     fam = cache_family(cfg)
-    params = _described(jax.eval_shape(
-        lambda: model.init(cfg, jax.random.PRNGKey(0))), v5e)
+    params = _described(jax.eval_shape(init, jax.random.PRNGKey(0)), v5e)
     pool = _described(jax.eval_shape(
         lambda: fam.init_cache(cfg, slots, smax)), v5e)
 
@@ -1295,8 +1289,9 @@ def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
             donate_argnums=(1,))
         args = (params, pool, arg((slots,), jnp.int32),
                 arg((slots,), jnp.int32), arg((slots,), jnp.bool_))
-        _sweep_is_built_outside_the_layer_scan(tick.trace(*args).jaxpr,
-                                               slots, segments=2)
+        _sweep_is_built_outside_the_layer_scan(
+            tick.trace(*args).jaxpr, slots,
+            segments=_segments(fam, cfg, params))
         compiled = tick.lower(*args).compile()
         kernels = ("index_decode_scores", "latent_decode_attention")
     else:

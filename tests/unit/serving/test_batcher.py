@@ -444,31 +444,29 @@ def test_the_batcher_counts_the_tokens_its_familys_kernel_streams():
 CHUNK, SLOT = 8, 64
 
 
-def _tiny(module, name, dtype=jnp.bfloat16):
-    """``(cfg, params)`` of a family of the benchmark (its module under
-    ``benchmarks/chip``) at the tiny sizes of its configuration ``name``."""
-    import importlib
-    import json
-    import os
-
-    family = importlib.import_module(f"benchmarks.chip.{module}")
-    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", name)) as f:
-        file = json.load(f)
-    with open(os.path.join(root, "tests", "unit", "chipbench", "tiny",
-                           "configs", name)) as f:
-        file.update(json.load(f))
-    cfg = dataclasses.replace(family.build(file), dtype=dtype)
-    return cfg, family.init(cfg, jax.random.PRNGKey(0), dtype)
+def _tiny(name, dtype=jnp.bfloat16):
+    """``(cfg, params)`` of a family of the benchmark at the tiny sizes of
+    its configuration ``name``, computing and served in ``dtype`` (the
+    families' harness: drawn once a process, in one program)."""
+    from tests.unit.models import family_harness as harness
+    return harness.model(harness.SPECS[name], dtype=dtype, weights=dtype)
 
 
 def _latent():
     """The latent-attention family at the benchmark's tiny sizes."""
-    return _tiny("latent_moe_family", "kimi-k2.7-code-ep32.json")
+    return _tiny("kimi-k2.7-code-ep32")
 
 
 _SERVED = {}
+
+
+def _two_batchers(eng, serving):
+    """``(fused, plain)`` over one engine: each keeps its own slots, and the
+    two launch ONE set of compiled programs (a program is its engine's and
+    its geometry's, not its batcher's: compiled twice it is the same)."""
+    fused, plain = SlotBatcher(eng, serving), SlotBatcher(eng, serving)
+    plain.registry, plain._p = fused.registry, fused._p
+    return fused, plain
 
 
 def _served(family, kv):
@@ -486,8 +484,7 @@ def _served(family, kv):
             config={"dtype": "bfloat16", "kv_cache_dtype": kv})
         serving = ServingConfig.from_dict(
             {"slots": 3, "max_len": SLOT, "prefill_chunk": CHUNK})
-        _SERVED[family, kv] = (SlotBatcher(eng, serving),
-                               SlotBatcher(eng, serving))
+        _SERVED[family, kv] = _two_batchers(eng, serving)
     return _SERVED[family, kv]
 
 
@@ -650,7 +647,7 @@ def test_the_frontier_logits_row_is_the_prefills(family):
     serve in, that is the row its ``prefill`` returns and the admission
     writes."""
     if ":" in family:
-        cfg, params = _tiny(*family.split(":"))
+        cfg, params = _tiny(family.split(":")[1][:-len(".json")])
     else:
         mod, cfg = (gpt, CFG) if family == "dense" else (gpt_moe, MOE_CFG)
         cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
@@ -688,17 +685,14 @@ def _laddered(family):
             cfg, params = LONG, gpt.init(LONG, jax.random.PRNGKey(0))
             geometry = {"slots": 2, "max_len": 1024, "prefill_chunk": 128}
         else:
-            cfg, params = _tiny(*{
-                "state": ("hybrid_ssm_moe_family",
-                          "granite-4.0-h-small-ep4.json"),
-                "ring": ("mellum_family", "mellum2-12b-a2.5b-ep4.json"),
-            }[family], dtype=jnp.float32)
+            cfg, params = _tiny({
+                "state": "granite-4.0-h-small-ep4",
+                "ring": "mellum2-12b-a2.5b-ep4"}[family], dtype=jnp.float32)
             geometry = {"slots": 2, "max_len": SLOT, "prefill_chunk": CHUNK}
         eng = deepspeed_tpu.init_inference(model=(cfg, params),
                                            config={"dtype": "float32"})
         serving = ServingConfig.from_dict(geometry)
-        _LADDERED[family] = (SlotBatcher(eng, serving),
-                             SlotBatcher(eng, serving))
+        _LADDERED[family] = _two_batchers(eng, serving)
     return _LADDERED[family]
 
 
