@@ -603,7 +603,7 @@ def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
                                 positions, valid)
                     for bank, val in zip(cache.ring, fresh)))
 
-        single = q.shape[1] == 1
+        single = fresh[0].shape[1] == 1
         if single:
             cache = put(cache)
         with jax.named_scope("cache_read"):

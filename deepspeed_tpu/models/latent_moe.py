@@ -19,12 +19,12 @@ What differs from ``models/gpt.py``'s block, mechanism by mechanism:
   ``c`` itself and the value up-projection follows the sum: nothing to do
   a key, 3.4 times the operations a (query, key) pair.  It is a tick's
   (one query a row: nothing to amortise an up-projection over), a few
-  tokens', the uncached ``apply``'s and every pass's under a bias.  The
-  *up-projected* form: keys and values of all heads made from the rows
-  once a call, as the published model's own prompt pass makes them: a
-  served chunk's (158 positions and more at the published widths), inside
-  the chunk kernel, through the layer's ``W_kvb`` where it lies in a
-  head-major copy of its stack (``head_major``, ``with_up``);
+  tokens' and the uncached ``apply``'s.  The *up-projected* form: keys and
+  values of all heads made from the rows once a call, as the published
+  model's own prompt pass makes them: a served chunk's (158 positions and
+  more at the published widths), under a bias or under none, inside the
+  chunk kernel, through the layer's ``W_kvb`` where it lies in a head-major
+  copy of its stack (``head_major``, ``with_up``);
 - **YaRN** rotary frequencies on the rotary part alone, pairs interleaved;
   a config may leave the bottleneck out (``q_rank`` None: queries straight
   from the stream) and the rotation (``rope`` False: the row's shared key
@@ -207,13 +207,29 @@ def lora_rescale(d_model: int, rank: int) -> float:
     return math.sqrt(d_model / rank)
 
 
-def head_major(wkv_b, config):
+def lane_rows(n: int) -> int:
+    """``n`` elements in whole lane rows of 128."""
+    return -(-n // 128) * 128
+
+
+def head_major(wkv_b, config, dims=None):
     """A stack of ``wkv_b`` ``[n, kv_rank, H, d_nope + d_v]`` as the
-    up-projected chunk kernel reads it: ``[n, H, kv_rank, d_nope + d_v]`` in
-    the compute dtype.  A family's ``step`` makes it ONCE, outside its layer
-    scan (a loop over chunks hoists it whole), and hands a layer's share to
-    the layer's parameters (``with_up``)."""
-    return jnp.swapaxes(wkv_b, 1, 2).astype(config.dtype)
+    up-projected chunk kernel reads it: ``[n, H, kv_rank, lane_rows(d_nope)
+    + d_v]`` in the compute dtype, a head's key part in whole lane rows
+    (zero columns past ``d_nope``, which meet zero lanes of the un-absorbed
+    queries: a width that is whole already is padded by nothing).  ``dims``:
+    the widths of the layer's kind where a config has more than one
+    (default: the config's own).  A family's ``step`` makes it ONCE, outside
+    its layer scan (a loop over chunks hoists it whole), and hands a layer's
+    share to the layer's parameters (``with_up``)."""
+    d_nope = (dims or config).d_nope
+    w = jnp.swapaxes(wkv_b, 1, 2).astype(config.dtype)
+    pad = lane_rows(d_nope) - d_nope
+    if not pad:
+        return w
+    return jnp.concatenate(
+        [w[..., :d_nope], jnp.zeros(w.shape[:-1] + (pad,), w.dtype),
+         w[..., d_nope:]], -1)
 
 
 #: where ``with_up`` keeps ``(head-major stack, layer)`` in a layer's
@@ -231,25 +247,36 @@ def with_up(p, heads, layer):
 def chunk_form(config, S: int) -> str:
     """The form a served pass of ``S`` positions takes at this config's
     widths, by name: ``"up_projected"`` where the positions pay for a key's
-    up-projection (``decode_attention.latent_up_projects``), else
-    ``"absorbed"``.  A family's ``Family.chunk_form``: the batcher records
-    it with an admission's work."""
+    up-projection (``decode_attention.latent_up_projects``, at the widths
+    the head-major copy has: ``lane_rows(d_nope)``), else ``"absorbed"``.  A
+    family's ``Family.chunk_form``: the batcher records it with an
+    admission's work.  ``config``: a config of this family, or the widths of
+    one kind of layer under the same names (``sparse_latent_moe.Dims``)."""
     from ..ops.pallas.decode_attention import latent_up_projects
     return "up_projected" if latent_up_projects(
-        S, config.n_head, config.cache_row[0], config.kv_rank, config.d_nope,
-        config.d_rope, config.d_v) else "absorbed"
+        S, config.n_head, config.cache_row[0], config.kv_rank,
+        lane_rows(config.d_nope), config.d_rope, config.d_v) else "absorbed"
+
+
+def unabsorbed(q_n, q_r):
+    """A pass's UN-ABSORBED queries ``[q_n | 0 | R(q_r)]`` from their two
+    parts [B, S, H, .], the key part in whole lane rows as ``head_major``
+    lays a head's out."""
+    pad = lane_rows(q_n.shape[-1]) - q_n.shape[-1]
+    return jnp.concatenate(
+        [q_n] + ([jnp.zeros(q_n.shape[:-1] + (pad,), q_n.dtype)] if pad
+                 else []) + [q_r], -1)
 
 
 def up_projection(p, config, S: int):
     """``decode_attention.LatentUp`` for a pass of ``S`` positions through
     the layer whose parameters are ``p``, or None where the pass keeps the
-    absorbed form: no head-major stack at hand (``apply``; a family that
-    attends under a bias never brings one), or too few positions
-    (``chunk_form``)."""
+    absorbed form: no head-major stack at hand (``apply``), or too few
+    positions (``chunk_form``).  ``config``: as ``chunk_form``'s."""
     from ..ops.pallas.decode_attention import LatentUp
     if _UP not in p or chunk_form(config, S) == "absorbed":
         return None
-    return LatentUp(*p[_UP], config.d_nope)
+    return LatentUp(*p[_UP], lane_rows(config.d_nope))
 
 
 def latent_project(x, p, config: LatentMoEConfig, positions,
@@ -264,7 +291,7 @@ def latent_project(x, p, config: LatentMoEConfig, positions,
 
     A pass that takes the up-projected form (``up_projection``) gets its
     queries UN-ABSORBED, ``([q_n | R(q_r)] [B, S, H, d_nope + d_rope],
-    LatentUp)``, for ``cached_attention(latent_up=)``."""
+    LatentUp)`` (``unabsorbed``), for ``cached_attention(latent_up=)``."""
     cdt = config.dtype
     H, r = config.n_head, config.kv_rank
 
@@ -292,7 +319,7 @@ def latent_project(x, p, config: LatentMoEConfig, positions,
         -1)
     up = up_projection(p, config, x.shape[1])
     if up is not None:
-        return (jnp.concatenate([q_n, turn(q_r)], -1), up), row
+        return (unabsorbed(q_n, turn(q_r)), up), row
     # absorb the key up-projection into the query
     q_abs = jnp.einsum("bshe,rhe->bshr", q_n,
                        p["wkv_b"][..., :config.d_nope].astype(cdt))

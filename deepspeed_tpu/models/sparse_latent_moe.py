@@ -11,10 +11,13 @@ What differs from ``models/latent_moe.py``'s block, mechanism by mechanism:
   position of the unit, so each position knows its kind statically;
 - **two sets of widths** (``Dims``): a full layer's latent attention
   (``n_head``, ``q_rank``, ``kv_rank``, ``d_nope``, ``d_rope``, ``d_v``,
-  ``rope_theta``) and a window layer's (the ``w_`` fields); both the
-  absorbed form of ``latent_moe`` with plain rotary frequencies, pairs
-  interleaved.  With ``lora_rescale`` the two low-rank latents leave their
-  norms multiplied by ``sqrt(d_model / rank)``;
+  ``rope_theta``) and a window layer's (the ``w_`` fields); both
+  ``latent_moe``'s one algorithm in the form a pass's shape picks
+  (absorbed: a tick, a few tokens, ``apply``; up-projected: a served
+  chunk, each kind by its own widths, ``latent_moe.up_projection``), with
+  plain rotary frequencies, pairs interleaved.  With ``lora_rescale`` the
+  two low-rank latents leave their norms multiplied by ``sqrt(d_model /
+  rank)``;
 - **the index** (full layers): ``index_heads`` small heads score every
   earlier token, ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s]) *
   index_heads^-1/2 * index_dim^-1/2`` with ``q_I = W_qI c_q``, ``k_I =
@@ -79,7 +82,13 @@ class Dims(NamedTuple):
     @property
     def lanes(self) -> int:
         """``row_elements`` in whole lane rows: how the row is stored."""
-        return -(-self.row_elements // 128) * 128
+        return latent_moe.lane_rows(self.row_elements)
+
+    @property
+    def cache_row(self) -> Tuple[int, ...]:
+        """``(lanes,)``: the name ``latent_moe.chunk_form`` reads a
+        config's stored row under."""
+        return (self.lanes,)
 
     @property
     def softmax_scale(self) -> float:
@@ -279,13 +288,37 @@ class Index(NamedTuple):
     w: jnp.ndarray
 
 
+class Queries(NamedTuple):
+    """What a layer's attention is asked with: ``q`` the latent attention's
+    queries [B, S, H, .] (absorbed; un-absorbed where ``up`` is given);
+    ``index``: a full layer's :class:`Index`, None on a window layer;
+    ``up``: the layer's ``decode_attention.LatentUp`` where the pass takes
+    the up-projected form (``latent_moe.up_projection``), else None."""
+    q: jnp.ndarray
+    index: Optional[Index] = None
+    up: Any = None
+
+
+def chunk_form(config: "SparseLatentMoEConfig", S: int) -> str:
+    """``latent_moe.chunk_form`` for a stack of two kinds of layer, each
+    with widths of its own: a span holds one name, so ``"up_projected"``
+    only where the latent chunk call of EVERY kind the stack has takes that
+    form at ``S`` positions, else ``"absorbed"``."""
+    forms = {latent_moe.chunk_form(config.dims(kind), S)
+             for kind in set(config.layer_types)}
+    return forms.pop() if len(forms) == 1 else "absorbed"
+
+
 def attention_project(x, p, config: SparseLatentMoEConfig, positions,
                       kind: str):
     """One layer's attention inputs from ``x`` [B, S, d]: the absorbed
     queries ``[q' | R(q_r)]`` [B, S, H, lanes] and the token's cache row
     ``[c | R(k_r)]`` [B, S, lanes], both zero past ``row_elements``; on a
     full layer also the index's queries (:class:`Index`) and the token's
-    index key [B, S, index_dim]: ``(queries, index or None), (row[, key])``.
+    index key [B, S, index_dim]: ``Queries, (row[, key])``.  A pass that
+    takes the up-projected form (``p`` brings its head-major stack,
+    ``latent_moe.with_up``, and its positions pay) gets its queries
+    UN-ABSORBED (``latent_moe.unabsorbed``) beside the layer's ``LatentUp``.
     """
     cdt = config.dtype
     dm = config.dims(kind)
@@ -300,18 +333,24 @@ def attention_project(x, p, config: SparseLatentMoEConfig, positions,
     c = rms_norm(kv[..., :r], p["kv_norm"], config.eps, jnp.float32)
     c = (c * config.rescale(r)).astype(cdt)
     k_r = rotate(kv[..., r:], positions, dm.rope_theta)
-    # absorb the key up-projection into the query
-    q_abs = jnp.einsum("bshe,rhe->bshr", q_n,
-                       p["wkv_b"][..., :dm.d_nope].astype(cdt))
+    q_r = rotate(q_r, positions, dm.rope_theta)
     pad = dm.lanes - dm.row_elements
-    queries = jnp.concatenate(
-        [q_abs, rotate(q_r, positions, dm.rope_theta)]
-        + ([jnp.zeros(q_abs.shape[:3] + (pad,), cdt)] if pad else []), -1)
+    up = latent_moe.up_projection(p, dm, x.shape[1])
+    if up is not None:
+        queries = latent_moe.unabsorbed(q_n, q_r)
+    else:
+        # absorb the key up-projection into the query
+        q_abs = jnp.einsum("bshe,rhe->bshr", q_n,
+                           p["wkv_b"][..., :dm.d_nope].astype(cdt))
+        queries = jnp.concatenate(
+            [q_abs, q_r]
+            + ([jnp.zeros(q_abs.shape[:3] + (pad,), cdt)] if pad else []),
+            -1)
     row = jnp.concatenate(
         [c, k_r] + ([jnp.zeros(c.shape[:2] + (pad,), cdt)] if pad else []),
         -1)
     if kind != FULL:
-        return queries, (row,)
+        return Queries(queries, up=up), (row,)
     with jax.named_scope("index_project"):
         dr = config.d_rope
         q_i = jnp.einsum("bsr,rhe->bshe", c_q, p["wi_q"].astype(cdt))
@@ -326,7 +365,7 @@ def attention_project(x, p, config: SparseLatentMoEConfig, positions,
         w = jnp.einsum("bsd,dh->bsh", h, p["wi_w"].astype(cdt),
                        preferred_element_type=jnp.float32) \
             * (config.index_heads ** -0.5 * config.index_dim ** -0.5)
-    return (queries, Index(q_i, w)), (row, k_i)
+    return Queries(queries, Index(q_i, w), up), (row, k_i)
 
 
 def head_gate(h, w_gate, cdt):
@@ -338,14 +377,18 @@ def head_gate(h, w_gate, cdt):
 @jax.named_scope("attn_out")
 def attention_output(x, weighed, p, config: SparseLatentMoEConfig, kind: str):
     """``x + W_o concat_h(g_h * W_kvb[v] (sum_s p c))``: ``weighed`` [B, S,
-    H, kv_rank] is each head's probability-weighted sum of latent rows, ``g
-    = sigmoid(W_g norm_1(x))`` one scalar a head."""
+    H, kv_rank] is each head's probability-weighted sum of latent rows
+    (from a pass in the up-projected form, ``latent_moe.up_projection``, the
+    heads' outputs [B, S, H, d_v] already), ``g = sigmoid(W_g norm_1(x))``
+    one scalar a head."""
     cdt = config.dtype
     dm = config.dims(kind)
     g = head_gate(rms_norm(x, p["ln1"], config.eps, cdt), p["w_gate"], cdt)
-    v = jnp.einsum("bshr,rhe->bshe", weighed.astype(cdt),
-                   p["wkv_b"][..., dm.d_nope:].astype(cdt),
-                   preferred_element_type=jnp.float32)
+    v = weighed.astype(cdt)
+    if latent_moe.up_projection(p, dm, x.shape[1]) is None:
+        v = jnp.einsum("bshr,rhe->bshe", v,
+                       p["wkv_b"][..., dm.d_nope:].astype(cdt),
+                       preferred_element_type=jnp.float32)
     v = (v * g[..., None]).astype(cdt)
     return x + jnp.einsum("bshe,hed->bsd", v, p["wo"].astype(cdt),
                           preferred_element_type=jnp.float32)
@@ -487,8 +530,9 @@ def _causal_attention(q, fresh, config: SparseLatentMoEConfig, kind: str):
     from ..ops.pallas.decode_attention import index_scores, topk_bias
     S = fresh[0].shape[1]
     dist = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    q, index = q.q, q.index
     if kind == FULL:
-        (q, index), (row, k_i) = q, fresh
+        row, k_i = fresh
         scores = index_scores(index.q, index.w, k_i)
         bias = topk_bias(scores, jnp.arange(S)[None], config.index_topk)
     else:

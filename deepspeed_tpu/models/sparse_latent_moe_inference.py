@@ -20,6 +20,12 @@ what ``gpt_inference.Family`` asks of a model family:
   largest as a bias (``topk_bias``), and the latent kernels under that bias:
   a MASKED sweep, which steps and streams every live block and attends to
   the chosen tokens alone.  A prompt pass is a chunk at position 0;
+- the **form** of a latent pass, by its shape as in ``latent_moe_inference``
+  (``latent_moe.up_projection``, each kind of layer by its own widths): a
+  tick and a few tokens absorbed, a chunk UP-PROJECTED inside the chunk
+  kernel under its bias (the selection's, or a ring's band), through the
+  head-major copy of each stack's ``wkv_b`` that ``step`` makes outside its
+  scans (a window layer's 192-wide key part in 256 lanes);
 - the **step**: one segment per run (``config.runs``), the scan's body the
   unit's layers in order, each position knowing its kind and its FFN's form
   statically; attention runs under the named scope of its kind
@@ -40,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..moe.held_experts import n_pair_counts
-from . import gpt_inference, sparse_latent_moe as model
+from . import gpt_inference, latent_moe, sparse_latent_moe as model
 from .gpt_inference import KVCache
 from .hybrid_ssm_moe import run_parts
 from .sparse_latent_moe import (DENSE, FULL, ROUTED, WINDOW,
@@ -110,11 +116,14 @@ def _step(params: PyTree, config: SparseLatentMoEConfig, valid):
         # first): the body closes over the run's whole stacks
         routed = [None if label.endswith(DENSE) else
                   {k: p[k] for k in ROUTED} for label, p in zip(unit, parts)]
+        heads = [latent_moe.head_major(p["wkv_b"], config, config.dims(kind))
+                 for kind, p in zip(kinds, parts)]
 
         def body(x, ps, i, attend, cache, unit=unit, kinds=kinds,
-                 firsts=firsts, routed=routed):
-            for label, kind, first, p, experts in zip(unit, kinds, firsts,
-                                                       ps, routed):
+                 firsts=firsts, routed=routed, heads=heads):
+            for label, kind, first, p, experts, up in zip(
+                    unit, kinds, firsts, ps, routed, heads):
+                p = latent_moe.with_up(p, up, i)
                 with jax.named_scope(SCOPES[kind]):
                     a, cache = attend(x, p, first + i * kinds.count(kind),
                                       cache, ring=kind == WINDOW)
@@ -142,7 +151,7 @@ def _attend_cached(q, cache: KVCache, pos, config: SparseLatentMoEConfig,
                    idx, active=None, sweep=None, ring=False, fresh=None):
     from ..ops.pallas import decode_attention as da
     dm = config.dims(WINDOW if ring else FULL)
-    queries = q if ring else q[0]
+    queries, index, up = q
     B, Sq = queries.shape[:2]
     p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     live = jnp.ones((B,), bool) if active is None else active
@@ -151,14 +160,13 @@ def _attend_cached(q, cache: KVCache, pos, config: SparseLatentMoEConfig,
         bank, = cache.ring
         if Sq > 1:
             return da.latent_ring_attention(queries, bank, fresh[0], p,
-                                            config.window, idx, **kw)
+                                            config.window, idx, up=up, **kw)
         R = bank.shape[2]       # written already: a short pool under a bias
         a = da.latent_cached_attention(
             queries, bank, jnp.minimum(p, R - 1), layer=idx, active=active,
             sweep=sweep, bias=da.ring_bias(p, R, config.window), **kw)
         return a, _counts(config, ring_live=jnp.sum(
             jnp.where(live, jnp.minimum(p + 1, config.window), 0)))
-    index = q[1]
     # a tick's two work lists, built once before the layer scan: the latent
     # sweep's and the index's (``config.cache_second_sweep_block``)
     sweep, index_sweep = sweep if isinstance(sweep, tuple) and len(sweep) == 2 \
@@ -170,7 +178,7 @@ def _attend_cached(q, cache: KVCache, pos, config: SparseLatentMoEConfig,
                             config.index_topk)
     a = da.latent_cached_attention(queries, cache.k, pos, layer=idx,
                                    active=active, sweep=sweep, bias=bias,
-                                   **kw)
+                                   up=up, **kw)
     if Sq > 1:
         return a
     block_k = gpt_inference._row_plan(config, cache.max_len).block_k or 1
@@ -196,4 +204,5 @@ FAMILY = gpt_inference.Family(
         model.embed(params, tokens, config),
     logits=model.lm_logits, apply=model.apply,
     logical_axes=model.logical_axes, unsupported=UNSUPPORTED,
-    stats_groups=stats_groups, select_counters=SELECT_COUNTERS)
+    stats_groups=stats_groups, select_counters=SELECT_COUNTERS,
+    chunk_form=model.chunk_form)

@@ -47,8 +47,9 @@ decides, no option does) takes the UP-PROJECTED form instead, in one kernel
 of its own (``_latent_up_chunk``): un-absorbed queries, every head's keys
 and values made from a block of rows in VMEM through the layer's ``W_kvb``
 (``LatentUp``: a head-major stack, the layer by scalar prefetch), a third
-of the operations a (query, key) pair.  A tick, a few tokens and every
-call under a bias keep the absorbed kernels.
+of the operations a (query, key) pair; a bias (a selection, a ring's band)
+rides along as an int8 mask.  A tick and a few tokens keep the absorbed
+kernels, under a bias or under none.
 
 Grouped heads (``kv_heads`` of ``cached_attention``): ``H`` query heads on
 ``H / G`` key-value heads.  The row stays ``H/G * D``; the decode kernel
@@ -1184,7 +1185,9 @@ class LatentUp(NamedTuple):
     (``[k | v]`` of a head side by side), made once outside the layer scan:
     the kernel reads layer ``layer`` of it where it lies (a layer sliced
     out first is a copy of 16.8 MB at the published widths).  ``d_nope``:
-    where a head's key part ends."""
+    where a head's key part ends (whole lane rows: a narrower key part is
+    padded with zero columns where the copy is made, and the queries with
+    zero lanes)."""
     w: jax.Array
     layer: jax.Array
     d_nope: int
@@ -1212,33 +1215,45 @@ _UP_VMEM = 12 << 20
 
 
 def latent_up_tiles(Sq: int, H: int, W: int, rank: int, d_nope: int,
-                    d_v: int, block_k: int, itemsize: int = 2):
+                    d_v: int, block_k: int, itemsize: int = 2,
+                    biased: bool = False):
     """``(heads, block_q)`` of the up-projected chunk kernel's step, or None
     where its tiles do not fit: every width in whole lane rows and the chunk
-    in whole sublane tiles.  A step holds the chunk's queries of ``heads``
-    heads (the largest group of 4, 2, 1 whose buffers stay under
-    ``_UP_VMEM``) and scores them ``block_q`` positions at a time (512 at
-    most: the float32 scores and probabilities of 512 x 512 are 1 MB
-    each)."""
-    if any(n % 128 for n in (W, rank, d_nope, d_v)) or Sq % 16:
+    in whole sublane tiles (of 32 rows under a bias: its mask is int8).  A
+    step holds the chunk's queries of ``heads`` heads (the largest group of
+    4, 2, 1 whose buffers stay under ``_UP_VMEM``) and scores them
+    ``block_q`` positions at a time (512 at most: the float32 scores and
+    probabilities of 512 x 512 are 1 MB each; half as many, and half
+    again, where not even one head fits beside them).  ``biased``: the step
+    also holds, twice, the chunk's ``(Sq, block_k)`` mask of the key
+    block."""
+    tile = 32 if biased else 16
+    if any(n % 128 for n in (W, rank, d_nope, d_v)) or Sq % tile:
         return None
     block_q = Sq if Sq <= 512 else next(
         b for b in (512, 256, 128, 64, 32, 16) if Sq % b == 0)
     E, Eq = d_nope + d_v, d_nope + W - rank
-    step = 2 * block_k * W * itemsize \
-        + block_q * block_k * (8 + itemsize) \
-        + block_k * (E * (4 + itemsize) + Eq * itemsize)
+
+    def step(block_q):
+        return 2 * block_k * W * itemsize \
+            + block_q * block_k * (8 + itemsize) \
+            + block_k * (E * (4 + itemsize) + Eq * itemsize) \
+            + (2 * Sq * block_k if biased else 0)
 
     def head(n):    # queries and results twice, the accumulator, max and sum
         return n * (Sq * (2 * (Eq + d_v) * itemsize + 4 * d_v + 2 * 4 * 128)
                     + 2 * rank * E * itemsize)
-    return next(((n, block_q) for n in (4, 2, 1)
-                 if H % n == 0 and step + head(n) <= _UP_VMEM), None)
+    while block_q % tile == 0:
+        for n in (4, 2, 1):
+            if H % n == 0 and step(block_q) + head(n) <= _UP_VMEM:
+                return n, block_q
+        block_q //= 2
+    return None
 
 
 def _latent_up_chunk_kernel(pos_ref, layer_ref, up_layer_ref, q_ref, c_ref,
-                            w_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                            sm_scale, block_q, block_k, nk, R, d_nope):
+                            w_ref, *rest, sm_scale, block_q, block_k, nk, R,
+                            d_nope, biased=False):
     """A chunk of UN-ABSORBED queries against latent rows, keys and values
     up-projected in VMEM.  A grid step is a GROUP OF HEADS against a key
     block: ``q_ref`` ``(Hg, Sq, Eq)`` holds the whole chunk's queries of
@@ -1257,7 +1272,18 @@ def _latent_up_chunk_kernel(pos_ref, layer_ref, up_layer_ref, q_ref, c_ref,
     The key axis of the grid ends at the chunk's causal frontier (a dynamic
     bound: no step past it), and a ``block_q`` tile of positions skips a
     key block wholly past ITS frontier.  Row ``b``'s result is final after
-    its own last block (a shorter row of a ragged batch waits there)."""
+    its own last block (a shorter row of a ragged batch waits there).
+
+    ``biased``: the chunk's ``(Sq, block_k)`` int8 mask of the key block
+    leads ``rest``, 1 where a position may see a key and 0 where its bias
+    is -inf (a selection, a ring's band): a key is scored where the mask
+    AND the causal order allow, which is the bias added before the causal
+    mask, to the bit.  A step is a group of heads, so the mask is read once
+    a head group a key block: a quarter of the float32 bias's bytes."""
+    mask_ref = None
+    if biased:
+        mask_ref, rest = rest[0], rest[1:]
+    o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
     ki = pl.program_id(2)
     Hg, Sq = q_ref.shape[:2]
@@ -1280,7 +1306,10 @@ def _latent_up_chunk_kernel(pos_ref, layer_ref, up_layer_ref, q_ref, c_ref,
             jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_pos = ki * block_k + \
             jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _online_softmax_step(jnp.where(k_pos <= q_pos, s, NEG_INF), values,
+        seen = k_pos <= q_pos
+        if biased:
+            seen = seen & (mask_ref[rows, :].astype(jnp.int32) != 0)
+        _online_softmax_step(jnp.where(seen, s, NEG_INF), values,
                              acc_ref.at[h, rows], m_ref.at[h, rows],
                              l_ref.at[h, rows])
 
@@ -1304,11 +1333,13 @@ def _latent_up_chunk_kernel(pos_ref, layer_ref, up_layer_ref, q_ref, c_ref,
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _latent_up_chunk(q, bank, layer, pos, sm_scale, up: LatentUp, R, tiles):
+def _latent_up_chunk(q, bank, layer, pos, sm_scale, up: LatentUp, R, tiles,
+                     bias=None):
     """``q`` [B, Sq, H, d_nope + d_rope] un-absorbed against layer ``layer``
     of ``bank`` [L, B, Smax, W], through layer ``up.layer`` of ``up.w``:
     each head's attention output [B, Sq, H, d_v].  ONE custom call; its
-    result is ``[B * H, Sq, d_v]``."""
+    result is ``[B * H, Sq, d_v]``.  ``bias`` [B, Sq, Smax] float32, 0 or
+    -inf: it reaches the kernel as the int8 mask of its zeros."""
     B, Sq, H, e = q.shape
     Smax, W = bank.shape[2:]
     Hg, block_q = tiles
@@ -1322,9 +1353,10 @@ def _latent_up_chunk(q, bank, layer, pos, sm_scale, up: LatentUp, R, tiles):
     interpret = interpret_mode()
     # key blocks up to the longest row's causal frontier
     live = jnp.minimum((jnp.max(pos_arr) + Sq - 1) // block_k + 1, nk)
+    biased = bias is not None
     kernel = functools.partial(_latent_up_chunk_kernel, sm_scale=sm_scale,
                                block_q=block_q, block_k=block_k, nk=nk, R=R,
-                               d_nope=up.d_nope)
+                               d_nope=up.d_nope, biased=biased)
 
     def c_idx(b, g, ki, pos_ref, layer_ref, up_layer_ref):
         last = jnp.minimum((pos_ref[b] + Sq - 1) // block_k, nk - 1)
@@ -1339,7 +1371,10 @@ def _latent_up_chunk(q, bank, layer, pos, sm_scale, up: LatentUp, R, tiles):
             pl.BlockSpec((None, Hg, R, E),
                          lambda b, g, ki, pos_ref, layer_ref, up_layer_ref:
                          (up_layer_ref[0], g, 0, 0)),
-        ],
+        ] + ([pl.BlockSpec((None, Sq, block_k),
+                           lambda b, g, ki, *refs:
+                           (b, 0, c_idx(b, g, ki, *refs)[2]))]
+             if biased else []),
         out_specs=pl.BlockSpec((Hg, Sq, d_v),
                                lambda b, g, ki, *_: (b * (H // Hg) + g, 0, 0)),
         scratch_shapes=[
@@ -1353,7 +1388,8 @@ def _latent_up_chunk(q, bank, layer, pos, sm_scale, up: LatentUp, R, tiles):
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, d_v), q.dtype),
         interpret=interpret, name=LATENT_UP_CHUNK)(
             pos_arr, jnp.asarray(layer, jnp.int32).reshape(1),
-            jnp.asarray(up.layer, jnp.int32).reshape(1), q, bank, up.w)
+            jnp.asarray(up.layer, jnp.int32).reshape(1), q, bank, up.w,
+            *(((bias == 0).astype(jnp.int8),) if biased else ()))
     return o.reshape(B, H, Sq, d_v).transpose(0, 2, 1, 3)
 
 
@@ -1373,10 +1409,10 @@ def latent_cached_attention(q, bank, pos, sm_scale: float, rank: int,
     With ``up`` (``LatentUp``) ``q`` [B, Sq, H, d_nope + d_rope] is
     UN-ABSORBED and the result each head's attention output [B, Sq, H,
     d_v].  The call's shape decides the form (``latent_up_projects``): a
-    chunk with no bias whose tiles fit up-projects its keys and values
-    inside the one chunk kernel; any other call absorbs ``up`` into the
-    queries here, takes the absorbed path and up-projects what it
-    returns."""
+    chunk whose tiles fit (``latent_up_tiles``, which counts a bias's mask)
+    up-projects its keys and values inside the one chunk kernel, under its
+    ``bias`` or under none; any other call absorbs ``up`` into the queries
+    here, takes the absorbed path and up-projects what it returns."""
     if layer is None:
         bank, layer = bank[None], 0
     Smax = bank.shape[2]
@@ -1386,11 +1422,12 @@ def latent_cached_attention(q, bank, pos, sm_scale: float, rank: int,
         d_v = up.w.shape[-1] - d_nope
         block_k = latent_block_k(Smax)
         tiles = block_k is not None and latent_up_tiles(
-            Sq, H, W, rank, d_nope, d_v, block_k, q.dtype.itemsize)
-        if use_pallas() and tiles and bias is None and latent_up_projects(
+            Sq, H, W, rank, d_nope, d_v, block_k, q.dtype.itemsize,
+            biased=bias is not None)
+        if use_pallas() and tiles and latent_up_projects(
                 Sq, H, W, rank, d_nope, e - d_nope, d_v):
             return _latent_up_chunk(q, bank, layer, pos, sm_scale, up, rank,
-                                    tiles)
+                                    tiles, bias=bias)
         w = jax.lax.dynamic_index_in_dim(up.w, up.layer, 0, keepdims=False)
         absorbed = jnp.einsum("bshe,hre->bshr", q[..., :d_nope],
                               w[..., :d_nope])
@@ -1826,29 +1863,41 @@ def ring_bias(pos, R: int, window: int):
 
 
 def latent_ring_attention(q, ring, fresh, pos, window: int, layer,
-                          sm_scale: float, rank: int):
+                          sm_scale: float, rank: int,
+                          up: Optional[LatentUp] = None):
     """``ring_attention`` for a ring of latent rows: a chunk's absorbed
     queries ``q`` [B, Sq, H, W] at ``pos .. pos + Sq - 1`` over the ring
     ``ring`` [L, B, R, W] as it stood BEFORE the chunk (position ``p`` in
     cell ``p mod R``) and the chunk's own rows ``fresh`` [B, Sq, W]; query
     ``i`` sees the keys at ``0 <= pos + i - j < window``.  The ring is
     unrolled into the order of its positions, the chunk's rows follow, and
-    the latent chunk kernel takes the ``R + Sq`` rows as a pool whose first
-    query sits at ``R``, the band and the cells no token has reached hidden
-    by its bias.  [B, Sq, H, rank]."""
+    the latent chunk kernel takes the ``R + Sq`` rows (in whole key blocks)
+    as a pool whose first query sits at ``R``, the band and the cells no
+    token has reached hidden by its bias.  [B, Sq, H, rank]; with ``up``,
+    ``q`` un-absorbed and the result the heads' outputs, as
+    ``latent_cached_attention`` has them."""
     B, Sq = q.shape[:2]
     R = ring.shape[2]
     p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     one = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
     old = jax.vmap(lambda t, s: jax.lax.dynamic_slice_in_dim(
         t, s, R, 0))(jnp.concatenate([one, one], axis=1), p % R)
-    rows = jnp.concatenate([old, fresh.astype(ring.dtype)], axis=1)
+    # the pool is this call's own, so it is as long as suits the kernels:
+    # whole key blocks of their largest size (``latent_block_k``), the rows
+    # past the chunk's zeros that no query's causal frontier reaches
+    keys = R + Sq
+    if keys > 512:
+        keys = -(-keys // 512) * 512
+    rows = jnp.concatenate(
+        [old, fresh.astype(ring.dtype)]
+        + ([jnp.zeros((B, keys - R - Sq, ring.shape[-1]), ring.dtype)]
+           if keys > R + Sq else []), axis=1)
     # key j of the unrolled pool lies at position pos - R + j
     dist = (R + jnp.arange(Sq, dtype=jnp.int32))[:, None] \
-        - jnp.arange(R + Sq, dtype=jnp.int32)[None, :]
+        - jnp.arange(keys, dtype=jnp.int32)[None, :]
     seen = ((dist >= 0) & (dist < window))[None] \
-        & (jnp.arange(R + Sq, dtype=jnp.int32)[None, None, :]
+        & (jnp.arange(keys, dtype=jnp.int32)[None, None, :]
            >= (R - p)[:, None, None])
     return latent_cached_attention(
         q, rows, jnp.full((B,), R, jnp.int32), sm_scale, rank,
-        bias=jnp.where(seen, 0.0, NEG_INF).astype(jnp.float32))
+        bias=jnp.where(seen, 0.0, NEG_INF).astype(jnp.float32), up=up)

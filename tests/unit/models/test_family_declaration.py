@@ -85,9 +85,9 @@ def test_cache_family_returns_the_whole_declaration(name):
             assert (value is not None) == (name == "moe")
         elif field.name == "chunk_form":
             # the families whose latent chunk passes have two forms (the
-            # selecting one's are all biased: the absorbed form alone)
-            assert (value is not None) == (name in ("latent", "linear",
-                                                    "shortcut"))
+            # selecting one's under their biases too)
+            assert (value is not None) == (name in (
+                "latent", "linear", "shortcut", "selected"))
         elif field.name in ("unsupported", "state_counters",
                             "select_counters"):
             assert value is not None

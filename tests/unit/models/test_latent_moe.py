@@ -200,29 +200,43 @@ def _kernels(jaxpr):
             if e.primitive.name == "pallas_call"]
 
 
-@pytest.mark.parametrize("sq,biased,kernel", [
-    (1, False, decode_attention.LATENT_SWEEP),
-    (8, False, "latent_chunk_attention"),
-    (8, True, "latent_chunk_attention"),
-    (256, True, "latent_chunk_attention"),
-    (512, True, "latent_chunk_attention"),
-    (256, False, decode_attention.LATENT_UP_CHUNK),
-    (512, False, decode_attention.LATENT_UP_CHUNK)])
-def test_the_calls_shape_and_bias_pick_the_form(monkeypatch, sq, biased,
-                                                kernel):
+#: heads, rank, lanes of the row, d_nope as the head-major copy has it,
+#: keys of the call: the published latent attention, and the selecting
+#: family's two kinds of layer (a window layer's 192-wide key part in 256
+#: lanes, its call over the unrolled ring and the chunk)
+_WIDTHS = {"published": (64, 512, 640, 128, 2048),
+           "selecting-full": (128, 512, 640, 128, 16384),
+           "selecting-window": (64, 1024, 1152, 256, 640 + 1024)}
+
+
+@pytest.mark.parametrize("widths,sq,biased,kernel", [
+    ("published", 1, False, decode_attention.LATENT_SWEEP),
+    ("published", 8, False, "latent_chunk_attention"),
+    ("published", 8, True, "latent_chunk_attention"),
+    ("published", 256, True, decode_attention.LATENT_UP_CHUNK),
+    ("published", 512, True, decode_attention.LATENT_UP_CHUNK),
+    ("published", 256, False, decode_attention.LATENT_UP_CHUNK),
+    ("published", 512, False, decode_attention.LATENT_UP_CHUNK),
+    ("selecting-full", 1024, True, decode_attention.LATENT_UP_CHUNK),
+    ("selecting-window", 1024, True, decode_attention.LATENT_UP_CHUNK),
+    ("selecting-window", 8, True, "latent_chunk_attention")])
+def test_the_calls_shape_picks_the_form(monkeypatch, widths, sq, biased,
+                                        kernel):
     """One call site, un-absorbed queries and the layer's up-projection, at
     the published widths (64 heads of 128 + 64 | 128 over a rank of 512 in
-    640 lanes): a tick, a verify's few tokens and any call under a bias run
-    the absorbed kernels, a prompt's chunk the up-projected one.  Traced,
-    never run."""
+    640 lanes) and at the selecting family's two: a tick and a verify's few
+    tokens run the absorbed kernels, a prompt's chunk the up-projected one,
+    under a bias (which reaches the kernel as one more operand, an int8
+    mask) as under none (the parent's six operands).  Traced, never run."""
     monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
-    H, R, W, S = 64, 512, 640, 2048
-    assert decode_attention.latent_up_projects(158, H, W, R, 128, 64, 128) \
-        and not decode_attention.latent_up_projects(157, H, W, R, 128, 64,
-                                                    128)
+    H, R, W, d_nope, S = _WIDTHS[widths]
+    assert decode_attention.latent_up_projects(158, 64, 640, 512, 128, 64,
+                                               128) \
+        and not decode_attention.latent_up_projects(157, 64, 640, 512, 128,
+                                                    64, 128)
     # ... which is what a family tells the batcher of a chunk's passes
     published = latent_moe.LatentMoEConfig(
-        n_head=H, kv_rank=R, d_nope=128, d_rope=64, d_v=128)
+        n_head=64, kv_rank=512, d_nope=128, d_rope=64, d_v=128)
     assert latent_moe_inference.FAMILY.chunk_form(published, sq) == (
         "up_projected" if sq >= 158 else "absorbed")
     shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16)
@@ -231,12 +245,19 @@ def test_the_calls_shape_and_bias_pick_the_form(monkeypatch, sq, biased,
         return decode_attention.latent_cached_attention(
             q, bank, jnp.asarray([300]), 0.07, R, layer=1,
             bias=bias if biased else None,
-            up=decode_attention.LatentUp(w, 1, 128))
+            up=decode_attention.LatentUp(w, 1, d_nope))
     jaxpr = jax.make_jaxpr(call)(
-        shape(1, sq, H, 192), shape(2, 1, S, W), shape(2, H, R, 256),
+        shape(1, sq, H, d_nope + 64), shape(2, 1, S, W),
+        shape(2, H, R, d_nope + 128),
         jax.ShapeDtypeStruct((1, sq, S), jnp.float32))
     assert _kernels(jaxpr.jaxpr) == [kernel]
     assert jaxpr.out_avals[0].shape == (1, sq, H, 128)
+    if kernel == decode_attention.LATENT_UP_CHUNK:
+        from tests.unit.ops.traced_sweeps import _deep
+        launch, = [e for e in _deep(jaxpr.jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        assert [str(v.aval.dtype) for v in launch.invars[6:]] == \
+            ["int8"] * biased
 
 
 def test_a_left_out_term_fails_where_bf16_passes():

@@ -12,6 +12,9 @@ window works every tick), ``index_topk`` 24, chunks of 16, a dense first
 layer and both kinds of layer.  The selection is exact, so with both sides
 in float32 the same tokens are chosen: no tolerance there."""
 
+import contextlib
+import functools
+
 import numpy as np
 import pytest
 
@@ -21,7 +24,7 @@ import jax.numpy as jnp
 from benchmarks.chip import dots3_family
 from benchmarks.chip.reference import dots3_control as control
 from benchmarks.chip.reference import dots3_reference as reference
-from deepspeed_tpu.models import cache_family, sparse_latent_moe
+from deepspeed_tpu.models import cache_family, latent_moe, sparse_latent_moe
 from deepspeed_tpu.moe.held_experts import read_pair_counts
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from tests.unit.chipbench.common import check_configuration
@@ -256,6 +259,169 @@ def test_the_kernels_under_the_interpreter(interpreted, pos):
         q, whole, at, 0.1, 128, jnp.where(band, 0.0, -jnp.inf))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
                                rtol=1e-4)
+
+
+# ------------------------------------- a chunk under a bias, up-projected
+
+#: heads, rank, d_nope, d_rope, d_v of the two kinds of layer at lane-row
+#: widths: a key part that is whole lane rows, and one of 192 that the
+#: head-major copy lays out in 256
+_UP_WIDTHS = {"full": (4, 128, 128, 64, 128), "window": (2, 128, 192, 64,
+                                                         128)}
+_UP_CHUNK, _UP_KEYS = 64, 768           # three key blocks of 256
+
+
+@functools.lru_cache(maxsize=None)
+def _up_programs(kind, ring):
+    """One layer's chunk pass at ``_UP_WIDTHS[kind]`` in both forms, each
+    ONE jitted program for every case of its static shape: ``(up-projected
+    through the kernel, absorbed through the dense reference and W_kvb[v],
+    weights)``.  The up-projected side goes the family's own way: the
+    head-major copy (``latent_moe.head_major``), un-absorbed queries
+    (``latent_moe.unabsorbed``) and, over a ring, ``latent_ring_attention``.
+    ``bias`` None: the call with no bias at all."""
+    H, R, d_nope, d_rope, d_v = _UP_WIDTHS[kind]
+    dims = sparse_latent_moe.Dims(H, 32, R, d_nope, d_rope, d_v, 1e4)
+    cfg = sparse_latent_moe.SparseLatentMoEConfig(dtype=jnp.float32)
+    wkv_b = jax.random.normal(jax.random.PRNGKey(H), (2, R, H, d_nope + d_v),
+                              jnp.float32) * 0.1
+    heads = latent_moe.head_major(wkv_b, cfg, dims)
+    assert heads.shape == (2, H, R, latent_moe.lane_rows(d_nope) + d_v)
+    kw = dict(sm_scale=dims.softmax_scale, rank=R)
+
+    def attend(q, rows, pos, bias, up):
+        if ring:
+            return da.latent_ring_attention(q, rows, bias, pos, 130, 1, up=up,
+                                            **kw)
+        return da.latent_cached_attention(q, rows, pos, layer=1, bias=bias,
+                                          up=up, **kw)
+
+    def up_projected(q_n, q_r, rows, pos, bias):
+        return attend(latent_moe.unabsorbed(q_n, q_r), rows, pos, bias,
+                      da.LatentUp(heads, 1, latent_moe.lane_rows(d_nope)))
+
+    def absorbed(q_n, q_r, rows, pos, bias):
+        q = jnp.concatenate(
+            [jnp.einsum("bshe,rhe->bshr", q_n, wkv_b[1, ..., :d_nope]), q_r,
+             jnp.zeros(q_n.shape[:3] + (dims.lanes - R - d_rope,))], -1)
+        with _dense():
+            weighed = attend(q, rows, pos, bias, None)
+        return jnp.einsum("bshr,rhe->bshe", weighed, wkv_b[1, ..., d_nope:])
+
+    return jax.jit(up_projected), jax.jit(absorbed), dims
+
+
+@contextlib.contextmanager
+def _dense():
+    """The dense formulas in the kernels' place, while a program traces."""
+    was, da.use_pallas = da.use_pallas, lambda: False
+    try:
+        yield
+    finally:
+        da.use_pallas = was
+
+
+@pytest.mark.parametrize("case", [
+    "full-selection", "full-everything", "full-alone", "full-ragged",
+    "full-no-bias", "window-selection", "window-band-unlapped",
+    "window-band-lapped"])
+def test_a_chunk_under_a_bias_in_the_up_projected_form(interpreted,
+                                                        monkeypatch, case):
+    """The up-projected chunk kernel under a bias, the interpreter's run of
+    it against the absorbed form's dense formula: a random selection, a
+    selection that is everything (the bias all zeros: bit for bit the call
+    with NO bias, which is the parent's kernel), one that is the query
+    alone, ragged positions that end inside a key block, and a ring's band
+    before and after its first lap, through the head-major copy that pads a
+    192-wide key part to 256 lanes."""
+    # the form for a chunk this short (the rule's own test:
+    # ``test_latent_moe.py::test_the_calls_shape_picks_the_form``)
+    monkeypatch.setattr(da, "latent_up_projects", lambda *a: True)
+    kind, what = case.split("-", 1)
+    ring = what.startswith("band")
+    up_projected, absorbed, dims = _up_programs(kind, ring)
+    B, C, S = 2, _UP_CHUNK, _UP_KEYS
+    key = iter(jax.random.split(jax.random.PRNGKey(len(case)), 8))
+    normal = lambda *shape: jax.random.normal(next(key), shape, jnp.float32)
+    q_n, q_r = normal(B, C, dims.n_head, dims.d_nope), \
+        normal(B, C, dims.n_head, dims.d_rope)
+    if ring:
+        # a ring as it stood before the chunk, the chunk's rows in the
+        # bias's place (``_up_programs``), a window of 130: 320 cells not yet
+        # lapped (with the chunk's rows three key blocks of 128), 576 lapped
+        # (640 rows: padded to two blocks of 512)
+        lapped = what == "band-lapped"
+        at = jnp.asarray([600, 607] if lapped else [100, 107])
+        rows = normal(2, B, 576 if lapped else 320, dims.lanes)
+        bias = normal(B, C, dims.lanes)
+    else:
+        at = jnp.asarray([300, 263] if what == "ragged" else [256, 448])
+        rows = normal(2, B, S, dims.lanes)
+        q_pos = at[:, None] + jnp.arange(C)[None]
+        own = jnp.arange(S)[None, None] == q_pos[..., None]
+        chosen = {"selection": own | (normal(B, C, S) > 0.5),
+                  "ragged": own | (normal(B, C, S) > 0.5),
+                  "everything": jnp.ones((B, C, S), bool),
+                  "alone": own, "no-bias": None}[what]
+        bias = None if chosen is None else jnp.where(chosen, 0.0, -jnp.inf)
+    got = up_projected(q_n, q_r, rows, at, bias)
+    want = absorbed(q_n, q_r, rows, at, bias)
+    if what in ("selection", "band-unlapped", "band-lapped"):   # the kernel
+        from tests.unit.ops.traced_sweeps import _deep
+        assert [e.params["name"] for e in _deep(jax.make_jaxpr(up_projected)(
+            q_n, q_r, rows, at, bias).jaxpr)
+            if e.primitive.name == "pallas_call"] == [da.LATENT_UP_CHUNK]
+    assert got.shape == (B, C, dims.n_head, dims.d_v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=1e-4)
+    if what == "everything":
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(up_projected(q_n, q_r, rows, at,
+                                                     None)))
+
+
+def test_a_served_chunk_takes_the_form_its_shape_picks(monkeypatch):
+    """The family's own route to the up-projected form (``step``'s
+    head-major copies, ``attention_project``'s un-absorbed queries and
+    ``LatentUp``, the attend hooks, ``attention_output`` without
+    ``W_kvb[v]``) gives the absorbed route's logits: a prompt pass and two
+    further chunks through all three kinds of cached state with the rule
+    saying yes to every chunk, against the same passes with the rule as it
+    is (at the tiny widths: absorbed).  ``chunk_form`` says one name a
+    pass: ``up_projected`` only where both kinds of layer are."""
+    cfg, params = harness.model(SPEC, std=LOUD)
+    fam = cache_family(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (1, 3 * CHUNK), 0,
+                                cfg.vocab_size)
+
+    def passes(params, tokens):
+        logits, cache = fam.prefill(params, tokens[:, :CHUNK], cfg,
+                                    fam.init_cache(cfg, 1, 128))
+        out = [logits]
+        for i in (1, 2):
+            logits, cache = fam.extend(
+                params, tokens[:, i * CHUNK:(i + 1) * CHUNK], cfg, cache)
+            out.append(logits)
+        return jnp.concatenate(out, 1)
+
+    assert fam.chunk_form(cfg, CHUNK) == "absorbed"
+    # (a new function a form: one jitted twice is traced once)
+    want = jax.jit(lambda p, t: passes(p, t))(params, tokens)
+    monkeypatch.setattr(
+        da, "latent_up_projects",
+        lambda Sq, H, *a: Sq > 1 and H == cfg.w_n_head)
+    assert fam.chunk_form(cfg, CHUNK) == "absorbed"     # window layers alone
+    monkeypatch.setattr(da, "latent_up_projects", lambda Sq, *a: Sq > 1)
+    assert fam.chunk_form(cfg, CHUNK) == "up_projected"
+    assert fam.chunk_form(cfg, 1) == "absorbed"
+    asked = []
+    up = latent_moe.up_projection
+    monkeypatch.setattr(latent_moe, "up_projection", lambda *a: (
+        asked.append(up(*a) is not None), up(*a))[1])
+    got = jax.jit(lambda p, t: passes(p, t))(params, tokens)
+    assert asked and all(asked)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL,
+                               rtol=RTOL)
 
 
 # ------------------------------------------------------------- the controls

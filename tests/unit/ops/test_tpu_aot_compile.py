@@ -1256,19 +1256,39 @@ def _moves_of_a_pool(text, pool):
                                                  "dynamic-slice"))]
 
 
+#: the selecting family's admission against its chunk's ``extend`` on the
+#: batch-1 row and the pool (compiler, PR 60): 14,632,959,488 bytes planned
+#: beside 7,092,738,560 + 6,747,586,560, 1.0573 of them; the parent's, every
+#: chunk call absorbed, 14,319,682,560 beside 6,829,290,496 + 6,747,586,560,
+#: 1.0547.  Both hold what the other families' hundredth does not: the first
+#: chunk's ``prefill`` beside the loop's ``extend``, each with a selection's
+#: ``[1024, 16384]`` scores, keys and bias.  The 313 MB between them are the
+#: head-major stacks (3 x 33.5 + 6 x 50.3 MB, made by ``prefill`` and by the
+#: loop: ROADMAP S3 10) less the absorbed queries and results
+_SELECTED_ROOM = 1.06
+
+
 @pytest.mark.parametrize("program", [
     "tick", pytest.param("admission", marks=pytest.mark.slow)])
 def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
     """The tick and the admission of the selected-latent / window-latent
     family at its cell's geometry, for the described chip: the index's two
-    score kernels, the latent sweep and the latent chunk kernel under a bias
-    lower (a ring's chunk pass at 1,152 lanes takes 8 positions a step, not
-    16: 16 overran the kernel's VMEM), the donated pool is updated in place,
-    and nothing copies, transposes or slices as much as a layer of any of
-    the three stacks: the latent bank, the index keys, the rings.  The
-    index's work list is built once a tick, outside the layer scans."""
-    from deepspeed_tpu.models import cache_family
-    from deepspeed_tpu.serving.batcher import admission
+    score kernels, the latent sweep under a bias and, in the admission, the
+    UP-PROJECTED chunk kernel under one lower, the donated pool is updated
+    in place, and nothing copies, transposes or slices as much as a layer of
+    any of the three stacks: the latent bank, the index keys, the rings.
+    The index's work list is built once a tick, outside the layer scans.
+
+    Every latent chunk pass of this family carries a bias (a selection, a
+    ring's band) and, at its cell's chunk of 1,024, up-projects its keys and
+    values all the same (``latent_up_projects`` at each kind's widths, a
+    window layer's 192-wide key part in 256 lanes): one call a layer of each
+    scan's body, twice in the program (the first chunk's ``prefill`` and the
+    loop's ``extend``), each on a WHOLE head-major stack with the bias as an
+    int8 mask its last operand; the absorbed chunk kernel and the absorbed
+    queries ``[chunk, heads, lanes]`` are gone from the program.  The TICK
+    holds no up-projected call: one query a row amortises nothing."""
+    from deepspeed_tpu.models import cache_family, latent_moe
     # ``dots3n-serve-longgen-sat``: the nine layers of its cut at 80 x
     # 16,384 in chunks of 1,024, three kinds of cached state (a 640-lane
     # latent bank and a 128-wide bank of index keys on three layers, a ring
@@ -1276,13 +1296,13 @@ def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
     init, cfg, slots, smax, chunk = _served("selected")
     fam = cache_family(cfg)
     params = _described(jax.eval_shape(init, jax.random.PRNGKey(0)), v5e)
-    pool = _described(jax.eval_shape(
-        lambda: fam.init_cache(cfg, slots, smax)), v5e)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
     if program == "tick":
+        pool = _described(jax.eval_shape(
+            lambda: fam.init_cache(cfg, slots, smax)), v5e)
         tick = jax.jit(
             lambda p, c, tok, lengths, active: fam.decode_step(
                 p, tok, cfg, c, lengths=lengths, active=active),
@@ -1293,24 +1313,47 @@ def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
             tick.trace(*args).jaxpr, slots,
             segments=_segments(fam, cfg, params))
         compiled = tick.lower(*args).compile()
+        text = compiled.as_text()
         kernels = ("index_decode_scores", "latent_decode_attention")
+        assert decode.LATENT_UP_CHUNK not in text
     else:
-        vocab = cfg.padded_vocab
-        per_slot = [arg((slots,) + tail, dtype) for tail, dtype in (
-            ((), jnp.int32), ((vocab,), jnp.float32), ((2,), jnp.uint32),
-            ((), jnp.bool_), ((), jnp.float32), ((), jnp.bool_))]
-        compiled = jax.jit(
-            admission(fam, cfg, smax, None), donate_argnums=(1, 3)).lower(
-                params, pool, *per_slot,
-                arg((smax // chunk, chunk), jnp.int32), arg((7,), jnp.int32),
-                arg((2,), jnp.uint32)).compile()
-        kernels = ("index_chunk_scores", "latent_chunk_attention")
-    text = compiled.as_text()
+        compiled, extend, pool, _ = _compile_admission(v5e, "selected", False)
+        text = compiled.as_text()
+        kernels = ("index_chunk_scores", decode.LATENT_UP_CHUNK)
+        assert "/latent_chunk_attention/pallas_call" not in text
+        full, window = cfg.dims("full"), cfg.dims("window")
+        # a pass: the dense first layer's call, then the unit's four
+        a_pass = [full, full, window, window, window]
+        calls = [shape for k, shape in _custom_calls(text)
+                 if k == decode.LATENT_UP_CHUNK]
+        assert sorted(calls) == sorted(
+            f"bf16[{dm.n_head},{chunk},{dm.d_v}]" for dm in 2 * a_pass), calls
+        for dm, n in ((full, 4), (window, 6)):
+            e = latent_moe.lane_rows(dm.d_nope) + dm.d_v
+            assert decode.latent_up_tiles(
+                chunk, dm.n_head, dm.lanes, dm.kv_rank, e - dm.d_v, dm.d_v,
+                128, biased=True) is not None
+            # the stack goes in whole, layers leading, the mask after it
+            stacks = re.findall(
+                rf"bf16\[(\d+),{dm.n_head},{dm.kv_rank},{e}\]\{{3,2,1,0\}}, "
+                rf"s8\[1,{chunk},\d+\]\{{2,1,0\}}\}}, frontend_attributes",
+                text)
+            assert len(stacks) == n, (dm, stacks)
+            # ... no layer of it is re-laid inside a loop, and no absorbed
+            # query array is built anywhere
+            relaid = [(dims, op) for dims, op in _in_loops(text)
+                      if tuple(d for d in dims if d > 1) in (
+                          (dm.kv_rank, dm.n_head, e), (dm.n_head, dm.kv_rank, e))
+                      and op in ("copy", "transpose")]
+            assert not relaid, relaid
+            assert f"{chunk},{dm.n_head},{dm.lanes}]" not in text
+            assert f"[{chunk * dm.n_head},{dm.lanes}]" not in text
+        assert _planned_bytes(compiled) <= _SELECTED_ROOM * (
+            _planned_bytes(extend) + _pool_bytes(pool)), (
+                _planned_bytes(compiled), _planned_bytes(extend),
+                _pool_bytes(pool))
     for kernel in kernels:
         assert f"/{kernel}/pallas_call" in text, kernel
-    # every latent chunk pass of this family carries a bias (a selection, a
-    # ring's band): none takes the up-projected form
-    assert decode.LATENT_UP_CHUNK not in text
     moved = _moves_of_a_pool(text, pool)
     assert not moved, f"the {program} moves whole layers of a pool: {moved}"
     assert not _pair_rows(text, cfg, params,
