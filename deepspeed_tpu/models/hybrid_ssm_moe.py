@@ -65,11 +65,24 @@ MAMBA, ATTENTION, EXPERTS = "mamba", "attention", "experts"
 MAX_UNIT = 4
 
 
-def layer_units(types: Tuple[str, ...], max_unit: int = MAX_UNIT):
+#: the suffix of a layer's label where its FFN is the dense one, in the
+#: families whose labels say more than the mixer (``kda+dense``)
+DENSE = "+dense"
+
+
+def mixer_of(label: str) -> str:
+    """A label's mixer: what precedes ``+dense``."""
+    return label.split("+")[0]
+
+
+def layer_units(types: Tuple[str, ...], max_unit: int = MAX_UNIT,
+                kind=lambda label: label):
     """``types`` (a stack's kinds in depth order) as runs: ``(unit, firsts,
-    n)`` each, see ``HybridSSMMoEConfig.units``."""
+    n)`` each, see ``HybridSSMMoEConfig.units``.  ``kind``: what a label's
+    layers are counted by in ``firsts`` where a label says more than its
+    mixer (``kda+dense`` and ``kda`` index ONE state stack)."""
     out: List[Tuple[Tuple[str, ...], Tuple[int, ...], int]] = []
-    seen = dict.fromkeys(types, 0)
+    seen = dict.fromkeys(map(kind, types), 0)
     i = 0
     while i < len(types):
         u, n = 1, 1
@@ -81,9 +94,10 @@ def layer_units(types: Tuple[str, ...], max_unit: int = MAX_UNIT):
             if (times > 1 or length == 1) and times * length > u * n:
                 u, n = length, times
         unit = tuple(types[i:i + u])
-        out.append((unit, tuple(seen[k] + unit[:j].count(k)
-                                for j, k in enumerate(unit)), n))
-        for k in unit:
+        kinds = [kind(label) for label in unit]
+        out.append((unit, tuple(seen[k] + kinds[:j].count(k)
+                                for j, k in enumerate(kinds)), n))
+        for k in kinds:
             seen[k] += n
         i += u * n
     return tuple(out)

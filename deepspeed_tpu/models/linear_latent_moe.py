@@ -40,7 +40,7 @@ from jax import lax
 
 from ..ops.pallas import delta_rule, ssm
 from . import latent_moe
-from .hybrid_ssm_moe import layer_units, run_parts
+from .hybrid_ssm_moe import DENSE, layer_units, mixer_of, run_parts
 from .latent_moe import rms_norm
 from .partitioning import EMBED, EXPERT, LAYERS, MLP, VOCAB
 
@@ -48,8 +48,7 @@ PyTree = Any
 
 KDA, LATENT = "kda", "latent"
 #: a layer's label in ``LinearLatentMoEConfig.labels``: its mixer, and
-#: ``+dense`` where its FFN is the dense one
-DENSE = "+dense"
+#: ``+dense`` (``hybrid_ssm_moe.DENSE``) where its FFN is the dense one
 #: the routed experts' two stacks among a layer's parameters
 ROUTED = ("w_gu", "w_down")
 #: the l2 norm's epsilon on ``q`` and ``k``
@@ -133,14 +132,7 @@ class LinearLatentMoEConfig:
         """``hybrid_ssm_moe.layer_units`` of the labels, with ``firsts``
         counted by MIXER (a dense layer's state is layer 0 of the same
         stack as the expert layers'): ``(unit, firsts, n)``."""
-        out, seen = [], {KDA: 0, LATENT: 0}
-        for unit, _, n in layer_units(self.labels):
-            kinds = [label.split("+")[0] for label in unit]
-            out.append((unit, tuple(seen[k] + kinds[:j].count(k)
-                                    for j, k in enumerate(kinds)), n))
-            for k in kinds:
-                seen[k] += n
-        return tuple(out)
+        return layer_units(self.labels, kind=mixer_of)
 
     def count(self, kind: str) -> int:
         return len(self.kda_layers if kind == KDA else self.full_attn_layers)
@@ -379,12 +371,11 @@ def apply(params: PyTree, tokens, config: LinearLatentMoEConfig):
                           config.dtype)
     zero_state = jnp.zeros((1, B, config.kda_head_dim, config.d_kda),
                            jnp.float32)
-    no_bias = jnp.zeros((3 * config.d_kda,), jnp.float32)
 
     def layer(x, p, label):
         if label.startswith(KDA):
             qkv, g, beta, gate = kda_inputs(x, p, config)
-            u_act, _ = ssm.causal_conv(qkv, zero_tail, p["conv_w"], no_bias)
+            u_act, _ = ssm.causal_conv(qkv, zero_tail, p["conv_w"], None)
             q, k, v = kda_scan_inputs(u_act, config)
             o, _ = delta_rule.kda_chunk_scan(zero_state, 0, q, k, v, g, beta,
                                              chunk=config.kda_chunk)

@@ -89,9 +89,7 @@ def _kda_mixer(x, p, j, cache: KVCache, valid, work,
     qkv, g, beta, gate = model.kda_inputs(x, p, config)
     with jax.named_scope("kda_conv"):
         tail = lax.dynamic_index_in_dim(tails, j, 0, keepdims=False)
-        u_act, tail = ssm.causal_conv(
-            qkv, tail, p["conv_w"], jnp.zeros((qkv.shape[-1],), jnp.float32),
-            valid)
+        u_act, tail = ssm.causal_conv(qkv, tail, p["conv_w"], None, valid)
         tails = lax.dynamic_update_slice(tails, tail[None], (j, 0, 0, 0))
         q, k, v = model.kda_scan_inputs(u_act, config)
     real = jnp.sum(valid)
@@ -138,7 +136,7 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
 
     for (unit, firsts, n), run in zip(config.units, params["runs"]):
         parts = run_parts(run)
-        kinds = [label.split("+")[0] for label in unit]
+        kinds = [model.mixer_of(label) for label in unit]
         # the routed experts' matrices are never an ``xs`` of the layer scan
         # (a slice of a stack handed to a Pallas call is copied out first):
         # the body closes over the run's whole stacks

@@ -545,11 +545,12 @@ class SweepPlan(NamedTuple):
     """The single-token sweep of one cached row (``sweep_plan``).
 
     ``kernel``: which of the three sweeps serves the row, or None where
-    none does (``Smax`` does not tile, or grouped heads narrower than a
-    lane row: the chunk kernel or the dense reference then serves the
-    token).  ``block_k``: tokens a step, None only where ``Smax`` does not
-    tile.  ``copy_rows``: where the copy of a row's last block ends, a tile
-    for the dense sweep, None for the two that stream whole blocks.
+    none does (``Smax`` does not tile, or grouped heads the grouped sweep
+    does not take, ``grouped_sweep_serves``: the chunk kernel or the dense
+    reference then serves the token).  ``block_k``: tokens a step, None
+    only where ``Smax`` does not tile.  ``copy_rows``: where the copy of a
+    row's last block ends, a tile for the dense sweep, None for the two
+    that stream whole blocks.
     ``windows``: the sweep's calls in one decode step as ``(window or None,
     layers)`` pairs, the caller's.  ``ring``: the plan of the family's
     SECOND pool, where it has one (``gpt_inference.KVCache.ring``): the
@@ -605,6 +606,18 @@ class SweepPlan(NamedTuple):
             / ((own + rings) * self.Smax)
 
 
+def grouped_sweep_serves(D: int, Hkv: int) -> bool:
+    """Whether the grouped sweep takes ``Hkv`` key-value heads of ``D``
+    elements: a lane-aligned head (``D`` a multiple of 128), or a head of
+    half a lane row (64) where the ROW is whole lane rows (an even number of
+    key-value heads): the kernel spreads a row's queries over the row's
+    lanes under a mask, so what must tile is the row; its pieces are then
+    64 lanes wide, which Mosaic concatenates and stores (compiled for a v5e
+    and run on one at 32 heads on 8: PERF.md 6, PR 61).  Narrower heads are
+    not served by a sweep (untested)."""
+    return D % 128 == 0 or (D == 64 and (Hkv * D) % 128 == 0)
+
+
 def sweep_plan(widths, Smax: int, heads: int, kv_heads: Optional[int] = None,
                itemsize: int = 2, windows=((None, 1),)) -> SweepPlan:
     """Which sweep serves a row, its block and where its last copy ends:
@@ -625,8 +638,9 @@ def sweep_plan(widths, Smax: int, heads: int, kv_heads: Optional[int] = None,
         block_k = decode_block_k(Smax, widths[0])
         if Hkv == heads:
             kernel, copy_rows = DENSE_SWEEP, decode_copy_rows(itemsize)
-        else:       # grouped heads: lane-aligned or not served by a sweep
-            kernel = GROUPED_SWEEP if (widths[0] // Hkv) % 128 == 0 else None
+        else:
+            kernel = GROUPED_SWEEP if grouped_sweep_serves(
+                widths[0] // Hkv, Hkv) else None
             copy_rows = None
     return SweepPlan(kernel if block_k is not None else None, block_k,
                      copy_rows, Smax, tuple(windows))
@@ -1478,8 +1492,9 @@ def cached_attention(q, cache_k, cache_v, pos,
     query head ``h`` reads key-value head ``h // (H / kv_heads)``; the row
     is never repeated out to ``H`` heads.  Full-precision cache, no ALiBi,
     a band (``window``) for a chunk's call only (``Sq > 1``: a single token
-    over grouped heads sweeps whole rows or a ring); the lane-aligned
-    kernels want ``D`` a multiple of 128.
+    over grouped heads sweeps whole rows or a ring); the grouped sweep
+    wants ``D`` a multiple of 128, or 64 in a row of whole lane rows
+    (``grouped_sweep_serves``).
 
     ``valid_from`` (scalar or [B], a chunk's call only): the row's first
     real key; the slots before it are hidden (``ring_attention``'s unrolled

@@ -37,8 +37,10 @@ at or past ``valid`` take ``dt = 0``: their decay is ``exp(0) = 1`` and they
 add nothing, so a padded tail leaves the state exactly where the last real
 token left it.
 
-``causal_conv`` is the depthwise convolution before the scan with the tail
-of pre-activation inputs a slot keeps; plain XLA.
+``causal_conv`` is the depthwise convolution with the tail of pre-activation
+inputs a slot keeps (before the scans here and in ``delta_rule.py``, with
+SiLU; the whole of a gated short convolution's mixing, with no activation
+and no bias: ``models/conv_moe.py``); plain XLA.
 """
 
 from __future__ import annotations
@@ -62,28 +64,33 @@ DECODE_BLOCK = 4096
 SCAN_BLOCK = 1024
 
 
-def causal_conv(u, tail, w, b, valid=None):
-    """Depthwise causal convolution with a carried tail, then SiLU.
+def causal_conv(u, tail, w, b, valid=None, activation=jax.nn.silu):
+    """Depthwise causal convolution with a carried tail, then ``activation``
+    (SiLU; None: the sum as it is).
 
     ``u`` [B, S, C] pre-activation inputs, ``tail`` [B, K-1, C] the last
     ``K-1`` of them before this call (zeros before a sequence), ``w`` [K, C]
-    (``w[K-1]`` weighs the current position), ``b`` [C].  ``valid`` [B]:
-    how many of the ``S`` positions are real (default all).  Returns
-    ``(silu(b + sum_j w_j u_{t-K+1+j}) [B, S, C] float32, the tail after the
-    last real position [B, K-1, C])``: with ``valid`` 0 the tail comes back
-    bit for bit."""
+    (``w[K-1]`` weighs the current position), ``b`` [C] or None for a
+    convolution without a bias.  ``valid`` [B]: how many of the ``S``
+    positions are real (default all).  Returns ``(activation(b + sum_j w_j
+    u_{t-K+1+j}) [B, S, C] float32, the tail after the last real position
+    [B, K-1, C])``: with ``valid`` 0 the tail comes back bit for bit."""
     B, S, C = u.shape
     K = w.shape[0]
     full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)  # [B,S+K-1,C]
     w32 = w.astype(jnp.float32)
-    out = b.astype(jnp.float32) + sum(
-        w32[j] * full[:, j:j + S].astype(jnp.float32) for j in range(K))
+    out = sum(w32[j] * full[:, j:j + S].astype(jnp.float32)
+              for j in range(K))
+    if b is not None:
+        out = b.astype(jnp.float32) + out
     if valid is None:
         new_tail = full[:, S:]
     else:
         new_tail = jax.vmap(lambda f, n: lax.dynamic_slice_in_dim(
             f, n, K - 1, axis=0))(full, jnp.asarray(valid, jnp.int32))
-    return jax.nn.silu(out), new_tail.astype(tail.dtype)
+    if activation is not None:
+        out = activation(out)
+    return out, new_tail.astype(tail.dtype)
 
 
 def live_rows(active, B: int):

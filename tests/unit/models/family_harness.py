@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from benchmarks.chip import (dots3_family, hybrid_ssm_moe_family,
                              kimi_linear_family, latent_moe_family,
-                             longcat_flash_family, mellum_family,
+                             lfm2_family, longcat_flash_family, mellum_family,
                              nemotron_h_family)
 from benchmarks.chip.reference import (dots3_control, dots3_reference,
                                        hybrid_ssm_moe_control,
@@ -39,13 +39,15 @@ from benchmarks.chip.reference import (dots3_control, dots3_reference,
                                        kimi_linear_control,
                                        kimi_linear_reference,
                                        latent_moe_control,
-                                       latent_moe_reference,
+                                       latent_moe_reference, lfm2_control,
+                                       lfm2_reference,
                                        longcat_flash_control,
                                        longcat_flash_reference,
                                        mellum_control, mellum_reference,
                                        nemotron_h_control,
                                        nemotron_h_reference)
-from deepspeed_tpu.models import (hybrid_ssm_moe, latent_moe,
+from deepspeed_tpu.models import (conv_moe, hybrid_ssm_moe,
+                                  hybrid_ssm_moe_inference, latent_moe,
                                   linear_latent_moe, shortcut_latent_moe,
                                   sparse_latent_moe, window_moe)
 from deepspeed_tpu.models.hybrid_ssm_moe import run_parts
@@ -146,8 +148,11 @@ class Spec:
     slot_paths: Tuple = ()
     keys: dict = dataclasses.field(default_factory=dict)
     #: ``cfg -> layers`` that keep a state (``state_steps``) and that route
-    #: (None: the routed count is not predicted)
+    #: (None: the routed count is not predicted); the names the family gives
+    #: that group's three counters
     state_layers: Optional[Callable] = None
+    state_counters: Tuple[str, ...] = \
+        hybrid_ssm_moe_inference.STATE_COUNTERS
     routed_layers: Optional[Callable] = None
     zero_experts: bool = False
     #: ``apply`` against the reference: tokens a row, marks
@@ -318,7 +323,10 @@ def check_counters(spec, served, before, lengths, ticks=TICKS):
     pairs = read_pair_counts(vector)
     assert pairs["held"] == sum(pairs["per_expert"]) > 0 \
         == pairs["pages_over_cap"]
-    assert pairs["routed"] > pairs["held"] >= pairs["visits"] > 0
+    assert pairs["routed"] >= pairs["held"] >= pairs["visits"] > 0
+    # every pair is held here only where every expert is
+    assert (pairs["routed"] == pairs["held"]) == (
+        len(cfg.held) == cfg.n_experts and not spec.zero_experts)
     assert (pairs["zero"] > 0) == spec.zero_experts
     if spec.routed_layers is not None:
         # every row of every call, a tick's idle slots too
@@ -327,10 +335,9 @@ def check_counters(spec, served, before, lengths, ticks=TICKS):
     if spec.state_layers is not None:
         n = spec.state_layers(cfg)
         assert dict(zip(b.state_counters, grown(
-            served, before, "state_steps"))) == {
-            "ssm_rows_stepped": len(lengths) * ticks * n,
-            "scan_tokens_real": real * n,
-            "scan_tokens_padded": (padded - real) * n}
+            served, before, "state_steps"))) == dict(zip(
+                spec.state_counters, (len(lengths) * ticks * n, real * n,
+                                      (padded - real) * n)))
 
 
 # ------------------------------------------------------------- the faults
@@ -687,6 +694,44 @@ SPECS = {s.name: s for s in (
          siblings_on_tiny=True, bf16="apply",
          # a function replaced (what only a cache shows) and the weights
          readings=(hybrid_ssm_moe_control, ("cache_other", "int8"))),
+    Spec(name="lfm2-8b-a1b", family=lfm2_family, reference=lfm2_reference,
+         program=conv_moe, weights=lfm2_control.WEIGHTS,
+         planted=lfm2_control.planted,
+         why="mellum's for the attention layers; the convolution is three "
+             "multiply-adds a channel in the same order on both sides, and "
+             "the way back from the experts sums a token's rows in another "
+             "order than the reference's loop over experts",
+         retouch=_loud_router_bias,
+         # one chunk, a chunk and a token, three chunk edges and a padded
+         # tail: a tail carried between passes and taken inside a padded one
+         slot_paths=_cases(("1+C+C+1+3C+5", (1, _C, _C + 1, 3 * _C + 5))),
+         state_layers=lambda cfg: cfg.count(conv_moe.CONV),
+         state_counters=("conv_rows_stepped", "conv_tokens_real",
+                         "conv_tokens_padded"),
+         routed_layers=lambda cfg: cfg.n_layer - cfg.n_dense,
+         # through apply what is in the mathematics; through the slot path
+         # the two only a carried tail can show
+         faults=tuple((f, "apply", ()) for f in (
+             "zero", "no_gate_c", "no_gate_b", "taps_reversed", "no_qk_norm",
+             "bias_weights", "int8"))
+         + (("chunk_edge", "slot", ()), ("pad_end", "slot", ()),
+            ("bf16_router", "slot", ())),
+         faint=("bf16_router",),
+         decided=("zero", "no_gate_c", "no_gate_b", "taps_reversed"),
+         readings=(hybrid_ssm_moe_control, ("pad_end", "int8")),
+         siblings=(
+             ("conv_bias", True, "conv_bias"),
+             ("rope_scaling", {"factor": 4, "rope_type": "yarn"},
+              "rope_scaling"),
+             ("tie_word_embeddings", False, "untied head"),
+             ("conv_L_cache", 4, "conv_L_cache"),
+             ("norm_topk_prob", False, "norm_topk_prob"),
+             ("use_expert_bias", False, "use_expert_bias"),
+             ("layer_types", ["conv"] * 13 + ["sliding_attention"],
+              "sliding_attention"),
+             ("num_attention_heads", 16, "heads of 64"),
+             ("model_type", "lfm2", "")),
+         bf16="slot"),
 )}
 
 

@@ -19,8 +19,9 @@ import deepspeed_tpu
 from benchmarks.chip import (hybrid_ssm_moe_family, latent_moe_family,
                              mellum_family, nemotron_h_family)
 from benchmarks.chip import dots3_family, kimi_linear_family
-from benchmarks.chip import longcat_flash_family
-from deepspeed_tpu.models import (cache_family, gpt, gpt_inference, gpt_moe,
+from benchmarks.chip import lfm2_family, longcat_flash_family
+from deepspeed_tpu.models import (cache_family, conv_moe, conv_moe_inference,
+                                  gpt, gpt_inference, gpt_moe,
                                   gpt_moe_inference, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference,
                                   latent_moe_inference,
@@ -64,14 +65,16 @@ def _served(name):
         "linear": (kimi_linear_family, "kimi-linear-48b-a3b-ep8",
                    linear_latent_moe_inference.FAMILY),
         "shortcut": (longcat_flash_family, "longcat-flash-chat-ep32",
-                     shortcut_latent_moe_inference.FAMILY)}[name]
+                     shortcut_latent_moe_inference.FAMILY),
+        "conv": (lfm2_family, "lfm2-8b-a1b",
+                 conv_moe_inference.FAMILY)}[name]
     cfg = dataclasses.replace(builder.build(tiny_file(file)),
                               dtype=jnp.float32)
     return cfg, lambda k: builder.init(cfg, k, jnp.float32), family
 
 
 SERVED = ("dense", "moe", "latent", "hybrid", "single_part", "window",
-          "selected", "linear", "shortcut")
+          "selected", "linear", "shortcut", "conv")
 
 
 @pytest.mark.parametrize("name", SERVED)
@@ -100,13 +103,21 @@ def test_cache_family_returns_the_whole_declaration(name):
         "window": {"moe_pairs"},
         "selected": {"moe_pairs", "sparse_select"},
         "linear": {"moe_pairs", "state_steps"},
-        "shortcut": {"moe_pairs"}}[name]
+        "shortcut": {"moe_pairs"},
+        "conv": {"moe_pairs", "state_steps"}}[name]
     assert fam.select_counters == (
         sparse_latent_moe_inference.SELECT_COUNTERS
         if name == "selected" else ())
     assert fam.state_counters == (
         hybrid_ssm_moe_inference.STATE_COUNTERS
-        if name in ("hybrid", "single_part", "linear") else ())
+        if name in ("hybrid", "single_part", "linear") else
+        ("conv_rows_stepped", "conv_tokens_real", "conv_tokens_padded")
+        if name == "conv" else ())
+    # the per-slot state is a tuple of as many arrays as the family says:
+    # a pair for the scans' families, ONE for the convolution's tail
+    state = jax.eval_shape(lambda: fam.init_cache(cfg, 2, 32)).state
+    assert (None if state is None else len(state)) == {
+        "hybrid": 2, "single_part": 2, "linear": 2, "conv": 1}.get(name)
     # the dense family alone serves as a draft
     assert ("draft" in fam.unsupported) == (name != "dense")
 
@@ -217,6 +228,8 @@ def test_what_a_family_serves_is_not_refused(name, feature):
     ("shortcut", "the latent-attention families cache in the compute dtype "
                  "only: the int8 cache's scale banks are per head and a "
                  "latent row has no heads (kv_cache_dtype='int8')"),
+    ("conv", "the short-convolution family caches in the compute dtype only "
+             "(kv_cache_dtype='int8')"),
 ])
 def test_the_int8_cache_is_refused_where_the_cache_is_made(name, said):
     cfg, _, fam = _served(name)
@@ -244,7 +257,7 @@ def _draft_engine(name):
 
 
 @pytest.mark.parametrize("name", ["moe", "latent", "hybrid", "linear",
-                                  "shortcut"])
+                                  "shortcut", "conv"])
 def test_a_draft_must_be_dense_and_both_callers_say_so(dense_engine, name):
     draft = _draft_engine(name)
     with pytest.raises(NotImplementedError) as e:
@@ -303,15 +316,26 @@ def _swept(name):
             d_model=128, n_head=8, n_kv_head=2, head_dim=128, window=512,
             n_experts=4, experts_per_token=2, d_expert=32,
             dtype=jnp.float32), 1024, decode_attention.GROUPED_SWEEP
-    assert name == "grouped-64"     # grouped heads narrower than a lane row
+    if name == "conv":      # heads of 64 in a row of whole lane rows
+        return conv_moe, conv_moe.ConvMoEConfig(
+            vocab_size=256, max_seq_len=1024, layer_types=("conv", "conv",
+                                                           A, "conv", A),
+            d_model=128, n_head=8, n_kv_head=2, head_dim=64, n_experts=4,
+            experts_per_token=2, d_expert=32, d_ff=64,
+            dtype=jnp.float32), 1024, decode_attention.GROUPED_SWEEP
+    if name == "grouped-64":    # the same row under another family's heads
+        return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
+            max_seq_len=1024, layer_types=(M, A, M), n_head=4, n_kv_head=2,
+            head_dim=64, **_HYBRID), 1024, decode_attention.GROUPED_SWEEP
+    assert name == "grouped-32"     # grouped heads of a quarter lane row
     return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
         max_seq_len=1024, layer_types=(M, A, M), n_head=4, n_kv_head=2,
-        head_dim=64, **_HYBRID), 1024, None
+        head_dim=32, **_HYBRID), 1024, None
 
 
 @pytest.mark.parametrize("name", ["dense", "dense-banded", "moe", "latent",
                                   "hybrid", "single_part", "window",
-                                  "grouped-64"])
+                                  "conv", "grouped-64", "grouped-32"])
 def test_the_plan_is_the_work_lists_block_and_the_kernels(monkeypatch, name):
     """One function of the row says which sweep serves it and by which
     block; the family's plan, the tick's work list and the kernel the tick

@@ -240,13 +240,15 @@ def _root_opcodes(hlo_text):
             for n, op, calls in rows]
 
 
-def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
+def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1,
+                                           kernel_free=0):
     """The kernel's work list is a function of the tick's inputs alone: its
     running sum (``decode_sweep``'s ``cumsum`` over the rows' block counts,
     rank 1, one entry a slot; an MoE gate's running sums over ``[tokens,
     experts]`` and a grouped matmul's over its groups and tiles are not it)
     is an equation of the tick and of no layer's body, where the kernel
-    call itself sits."""
+    call itself sits.  ``kernel_free``: the segments whose layers call no
+    kernel at all (a run of convolution mixers over dense FFNs)."""
     def names(jp):
         return [e.primitive.name if e.primitive.name != "cumsum"
                 or e.invars[0].aval.shape != (slots,) else "sweep_cumsum"
@@ -279,9 +281,9 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
     around = [n for e in jaxpr.eqns if e not in scans
               for sub in inner(e) for n in deep(sub)]
     assert "sweep_cumsum" in around, around
-    for scan in scans:
-        body = deep(inner(scan)[0])
-        assert "pallas_call" in body
+    bodies = [deep(inner(scan)[0]) for scan in scans]
+    assert sum("pallas_call" not in body for body in bodies) == kernel_free
+    for body in bodies:
         assert "sweep_cumsum" not in body, \
             "the sweep is rebuilt in every layer"
 
@@ -289,10 +291,11 @@ def _sweep_is_built_outside_the_layer_scan(jaxpr, slots, segments=1):
 _SERVED = pytest.mark.parametrize("family,int8", [
     ("dense", False), ("dense", True), ("moe", False), ("moe", True),
     ("latent", False), ("hybrid", False), ("single_part", False),
-    ("window", False), ("linear", False), ("shortcut", False)],
+    ("window", False), ("linear", False), ("shortcut", False),
+    ("conv", False)],
     ids=["bf16-dense", "int8-dense", "bf16-moe", "int8-moe", "bf16-latent",
          "bf16-hybrid", "bf16-single_part", "bf16-window", "bf16-linear",
-         "bf16-shortcut"])
+         "bf16-shortcut", "bf16-conv"])
 
 def _cell_files(cell):
     """``(configuration file, traffic file)`` of one ``workloads`` entry of
@@ -343,6 +346,11 @@ _GUARDED = {
     "linear": ("kimilin-serve-think-sat", {}),
     "shortcut": ("longcat-serve-docqa-sat", {}),
     "selected": ("dots3n-serve-longgen-sat", {}),
+    # all 14 layers, ``(C+dense) x 2, (A C C C) x 3`` as two scans, every one
+    # of a layer's 32 experts: 9.3 GB of weights beside a 4.8 GB pool of
+    # grouped KV at a head of 64 (this is where a 64-lane concatenation or
+    # store Mosaic refused would show before any chip)
+    "conv": ("lfm2-serve-assist-sat", {}),
 }
 
 
@@ -457,13 +465,58 @@ def _beyond_the_known(moved, family, program):
 
 def _layer_elements(cfg, cache, slots, smax):
     """Elements of one layer of the pool's smallest large stack: a bank's,
-    or the per-slot state's where the family keeps one."""
+    or the per-slot state's or the ring's where the family keeps one.  A
+    stack that is IN ALL under a tenth of one layer of a bank is no stack of
+    the pool's size and sets no threshold (a convolution's tails, two rows a
+    slot a layer: 11 layers are 23 MB where a layer of a bank is 805 MB, and
+    a line drawn at one layer of them, 2 MB, would take every activation of
+    a tick for the pool); every other stack does, and
+    :data:`_LAYER_ELEMENTS` pins what each family is held to."""
     from deepspeed_tpu.models.gpt_inference import cache_row
     layers = [slots * smax * cache_row(cfg)[0]]
     for stacks in (cache.state, cache.ring):
-        if stacks is not None:
+        if stacks is not None and 10 * stacks[0].size >= layers[0]:
             layers.append(stacks[0].size // stacks[0].shape[0])
     return min(layers)
+
+
+#: What "as large as a layer of the pool" is for each row of
+#: :data:`_GUARDED`, in elements, at its cell's geometry: the line over which
+#: a tick or an admission may copy, transpose or slice nothing but what
+#: :data:`_KNOWN_MOVES` names.  Written out so that a change to
+#: :func:`_layer_elements` that loosens a family's guard shows as a change
+#: here: a layer of a bank where that is the pool's only stack, else a layer
+#: of the state (hybrid, single-part, linear: 128 or 256 slots of a
+#: recurrent state) or of the ring (window, selected).
+_LAYER_ELEMENTS = {
+    "dense": 256 * 1024 * 256, "moe": 256 * 1024 * 256,
+    "latent": 128 * 8192 * 640, "shortcut": 64 * 6144 * 640,
+    "hybrid": 128 * 128 * 8192, "single_part": 128 * 128 * 4096,
+    "linear": 256 * 128 * 4096,
+    "window": 48 * 1024 * 512, "selected": 80 * 640 * 1152,
+    # a layer of a bank: 256 slots x 3,072 tokens x 8 heads of 64
+    "conv": 256 * 3072 * 512,
+}
+
+
+@pytest.mark.parametrize("family", sorted(_GUARDED))
+def test_a_layer_of_the_pool_is_what_it_was(family):
+    from deepspeed_tpu.models import cache_family
+    _, cfg, slots, smax, _ = _served(family)
+    cache = jax.eval_shape(
+        lambda: cache_family(cfg).init_cache(cfg, slots, smax))
+    assert _layer_elements(cfg, cache, slots, smax) == \
+        _LAYER_ELEMENTS[family]
+
+
+def _kernel_free_runs(cfg):
+    """Runs of the family's step whose layers call no kernel at all: a
+    convolution mixer (``causal_conv`` is XLA's) over a dense FFN, read off
+    the config's own labels."""
+    from deepspeed_tpu.models.conv_moe import CONV
+    from deepspeed_tpu.models.hybrid_ssm_moe import DENSE
+    return sum(all(label == CONV + DENSE for label in unit)
+               for unit, _, _ in getattr(cfg, "units", ()))
 
 
 def _pair_rows(text, cfg, params, tokens, matrices_that_tall=False):
@@ -473,10 +526,11 @@ def _pair_rows(text, cfg, params, tokens, matrices_that_tall=False):
     expert's, gate beside up): the held experts' path moves the pairs held
     HERE, a page of ``held_experts.pairs_cap`` rows, and what it keeps a
     pair of the whole call is integers (``held_experts_ffn``).  Empty for a
-    family with no held experts."""
+    family with no held experts, and for one that holds every expert: every
+    pair is then held here, and its row is the layer's to move."""
     import re
     k = getattr(cfg, "experts_per_token", None)
-    if not getattr(cfg, "held", None):
+    if not getattr(cfg, "held", None) or len(cfg.held) == cfg.n_experts:
         return []
     widths = set()
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
@@ -534,7 +588,8 @@ def test_decode_tick_leaves_the_pool_in_place(v5e, family, int8):
         donate_argnums=(1,))
     traced = tick.trace(params, cache, rows, rows, live).jaxpr
     _sweep_is_built_outside_the_layer_scan(
-        traced, slots, segments=_segments(fam, cfg, params))
+        traced, slots, segments=_segments(fam, cfg, params),
+        kernel_free=_kernel_free_runs(cfg))
     _sweeps_in_blocks_of(traced, cfg, slots, smax, _SWEEP_BLOCK[family])
     compiled = tick.lower(params, cache, rows, rows, live).compile()
     text = compiled.as_text()
@@ -594,7 +649,7 @@ _LATENT_TICKS = {
 #: (PR 46), the latent sweep's own 512
 _SWEEP_BLOCK = {"dense": 256, "moe": 256, "hybrid": 256,
                 "single_part": 1024, "latent": 512, "window": 512,
-                "linear": 512, "shortcut": 512}
+                "linear": 512, "shortcut": 512, "conv": 512}
 
 
 def _sweeps_in_blocks_of(jaxpr, cfg, slots, smax, block):
@@ -870,11 +925,12 @@ def _one_up_projected_chunk_call_a_sublayer(text, cfg, chunk, smax):
 #: keeps them tokens-on-lanes), and at two layers the compiler also moves
 #: the 2 MB code banks; the hybrid family's bank is its one attention
 #: layer, so the chunk kernel's transposed reads of a layer are as large;
-#: the single-part block's and the window family's two banks are gathered
-#: from the loop's carry for the slot write (:data:`_KNOWN_MOVES`).
+#: the single-part block's, the window family's and the convolution
+#: family's two banks are gathered from the loop's carry for the slot write
+#: (:data:`_KNOWN_MOVES`; the last's are 9.4 MB each).
 _ROW_BANK_COPIES = {("dense", True): 4, ("moe", True): 6,
                     ("hybrid", False): 8, ("single_part", False): 2,
-                    ("window", False): 2}
+                    ("window", False): 2, ("conv", False): 2}
 
 
 def _row_bank_ops(hlo_text, row_cache, opcode):
@@ -1049,6 +1105,8 @@ _CHUNK_PASSES = {
     "agent-sat": ((32, 2, 128, 1024, 16384, None), (64, 1024)),
     "rag-sat": ((32, 8, 128, 512, 5120, None), (256, 1024)),
     "gpt2-medium": ((16, 16, 64, 128, 1024, None), (128, 512)),
+    # 4 query heads of 64 a key-value head: a group's rows half a lane row
+    "assist-sat": ((32, 8, 64, 1024, 3072, None), (256, 1024)),
     # a verify's few positions under grouped heads: 8-row tiles of a packed
     # dtype, a group's under one another
     "verify-8": ((32, 4, 128, 8, 2048, None), (8, 1024)),
