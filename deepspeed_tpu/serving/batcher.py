@@ -82,47 +82,89 @@ class PrefixEntry:
     length: int
 
 
-#: widths of an admission's wide passes, widest first.  A pass over the
-#: weights costs their stream whatever it holds until its rows' products
-#: take longer, on a v5e at 197e12 / 819e9 = 240 rows: a pass of 128 rows
-#: pays for 240 (2.22 ms of GPT-2 medium's device time), the first width
-#: past the ridge runs twice the rows for a quarter more (2.84 ms at 256),
-#: and past it a pass is bound by its products (5.14 ms at 512 for two of
-#: 256's 5.68) while every width is one more body of the model to trace,
-#: lower and load, 0.65 s of a server's start (PERF.md 6, PR 52).  One
-#: width stands; it is a tuple because the admission runs ONE loop over
-#: :func:`pass_widths` (the chunk the last of them) whatever their number,
-#: and the tests patch in widths small enough for their slots.
+#: widths of an admission's passes beside the chunk, in both directions.  A
+#: pass over the weights costs their stream whatever it holds until its
+#: rows' products take longer, on a v5e at 197e12 / 819e9 = 240 rows: a pass
+#: of 128 rows pays for 240 (2.22 ms of GPT-2 medium's device time), the
+#: first width past the ridge runs twice the rows for a quarter more (2.84
+#: ms at 256), and past it a pass is bound by its products (5.14 ms at 512
+#: for two of 256's 5.68; of a stack that streams every expert 16.5 / 20.9 /
+#: 30.0 ms at 256 / 512 / 1,024: PERF.md 6, PR 62) while every width is one
+#: more body of the model to trace, lower and load: 0.65 s of a server's
+#: start for GPT-2 medium (PERF.md 6, PR 52), 2.4-15 s in the cells with a
+#: chunk of 512 or 1,024 (PR 62).  UPWARD one width stands, the first past
+#: the ridge: a chunk under it runs its whole passes there.  DOWNWARD one
+#: width stands too, the chunk's HALF where that is no narrower than the
+#: ridge (a pass under it costs what the ridge costs): a prompt's LAST
+#: pass, the only one that holds padding, runs there if what is left of
+#: the prompt fits.  It saves at most half a chunk of a prompt's rows and
+#: costs a body of the model like any width, so it stands only where a slot
+#: is at most ``NARROW_SLOT_CHUNKS`` chunks long: prompts of a pass or two,
+#: of which half a chunk is a fifth (``lfm2-serve-assist-sat``, 3 chunks a
+#: slot: +3.5% tokens/s for +3% of its start; at 8 to 16 chunks a slot the
+#: same width read +0.3 to +1.6% for +2.5 to +21% of the start).  The
+#: quarter was measured and dropped: +0.8% more there for 2.9 s of 30.
+#: ``WIDE_PASSES`` is a tuple because the admission runs ONE loop over the
+#: wide widths of :func:`pass_widths` whatever their number, and the tests
+#: patch in widths (and a ridge) small enough for their slots.
 WIDE_PASSES = (256,)
+NARROW_FLOOR = 256
+NARROW_SLOT_CHUNKS = 4
 
 
 def pass_widths(chunk: int, max_len: int) -> Tuple[int, ...]:
     """The widths of an admission's passes at ``prefill_chunk`` ``chunk``
     over ``max_len``-token slots, descending: those of ``WIDE_PASSES`` that
     are whole multiples of the chunk, wider than it and no longer than the
-    slot, then the chunk itself (alone at a chunk of 256 and more, and in a
-    slot shorter than 256)."""
+    slot; the chunk itself; then, for a prompt's last pass, the chunk's
+    half where that is whole and no narrower than ``NARROW_FLOOR`` and the
+    slot at most ``NARROW_SLOT_CHUNKS`` chunks long (``(1024, 512)`` at a
+    chunk of 1,024 in slots of 3,072; the chunk alone in slots of 8,192, at
+    256 and in a slot shorter than 256; ``(256, 128)`` at 128)."""
+    half = chunk // 2
+    narrow = (chunk % 2 == 0 and half >= NARROW_FLOOR
+              and max_len <= NARROW_SLOT_CHUNKS * chunk)
     return tuple(w for w in WIDE_PASSES
-                 if w > chunk and w % chunk == 0 and w <= max_len) + (chunk,)
+                 if w > chunk and w % chunk == 0 and w <= max_len) + (
+                     chunk,) + ((half,) if narrow else ())
 
 
-def ladder_passes(n: int, widths: Tuple[int, ...],
-                  first: int = 0) -> Tuple[int, int]:
-    """``(passes, wide)`` of an admission of ``n`` tokens whose ``first``
-    chunks run as chunks (1: a fresh row's first; 0 where it continues a
-    prefix): the chunks the prompt is padded to, taken by each width of
-    ``widths`` in turn in the WHOLE passes that fit what is left, the last
-    (the chunk) the rest; ``wide`` counts the positions of the passes wider
-    than the chunk.  The host's count of the admission program's own trip
-    counts."""
-    chunk = widths[-1]
-    left = max(-(-n // chunk) - first, 0)
-    passes, wide = first, 0
-    for w in widths[:-1]:
-        trips = left // (w // chunk)
-        passes, wide, left = passes + trips, wide + trips * w, \
-            left - trips * (w // chunk)
-    return passes + left, wide
+def last_pass(n, chunk: int, widths: Tuple[int, ...]):
+    """Which of ``widths`` the last pass of ``n`` tokens (> 0) takes, as
+    its place among those narrower than ``chunk``, counted from 1 (0: the
+    chunk, or a wider pass that holds it): the narrowest that holds what
+    stands in the prompt's last chunk.  ``n`` is a host integer or a traced
+    one: the admission program's rule and its host's count are this one
+    function."""
+    tail = n - chunk * ((n - 1) // chunk)
+    return sum((tail <= w) * 1 for w in widths if w < chunk)
+
+
+def ladder_passes(n: int, chunk: int, widths: Tuple[int, ...],
+                  first: int = 0) -> Tuple[int, int, int]:
+    """``(passes, wide, narrow)`` of an admission of ``n`` tokens at
+    ``prefill_chunk`` ``chunk`` whose ``first`` chunks run as chunks (1: a
+    fresh row's first, the ``prefill``, where no width is narrower than the
+    chunk; 0 where it continues a prefix or starts empty): ``narrow`` is the
+    width of the last pass where :func:`last_pass` finds one narrower than
+    the chunk, else 0; the chunks the prompt is padded to, less that one,
+    are taken by each width of ``widths`` from the widest down to the chunk
+    in the WHOLE passes that fit what is left, the chunk the rest; ``wide``
+    counts the positions of the passes wider than the chunk.  A prompt
+    that fits a narrow width is that one pass.  The host's count of the
+    admission program's own trip counts."""
+    which = last_pass(n, chunk, widths)
+    narrow = 0 if not which else [w for w in widths if w < chunk][which - 1]
+    left = -(-n // chunk) - (narrow > 0)
+    passes = min(first, left)
+    left -= passes
+    wide = 0
+    for w in widths:
+        if w > chunk:
+            trips = left // (w // chunk)
+            passes, wide, left = passes + trips, wide + trips * w, \
+                left - trips * (w // chunk)
+    return passes + left + (narrow > 0), wide, narrow
 
 
 def admission(fam, cfg, max_len: int, kv_dtype):
@@ -143,17 +185,34 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         ``start``, or fill a fresh row cache from 0, and the row's key is
         ``key``, or ``fold_in(key, fold)`` as the host's own
         ``jax.random.fold_in`` gives it.  After the first chunk a loop a
-        width of :func:`pass_widths` runs the whole passes of that width
-        that fit the chunks left of the prompt as it is padded (so a wide
-        pass computes the positions its chunks would, and only a prompt's
-        last pass holds padding), the last loop, at ``C``, the rest
-        (:func:`ladder_passes` counts them).  Every loop's trip count is
-        traced, so one compiled program serves every prompt length; every
-        pass is the family's own ``prefill`` / ragged ``extend``, as
+        width of :func:`pass_widths` down to the chunk runs the whole
+        passes of that width that fit the chunks left of the prompt as it
+        is padded (so a wide pass computes the positions its chunks would),
+        the loop at ``C`` the rest; where a width narrower than ``C`` holds
+        what stands in the prompt's last chunk (:func:`last_pass`), those
+        loops stop a chunk short and ONE pass of the narrowest such width
+        (half the chunk: :func:`pass_widths` gives one) runs over the
+        prompt's end, under a ``lax.switch``, so only that pass holds
+        padding and less than half of it is (:func:`ladder_passes` counts
+        them all).  Every trip count and the choice are traced, so one
+        compiled program serves every prompt length; every pass is the
+        family's own ``prefill`` / ragged ``extend``, as
         :meth:`SlotBatcher._chunked_prefill` runs them one launch each at
-        ``C``."""
+        ``C``.  A fresh row's first chunk is the ``prefill`` where the
+        ladder stops at the chunk; where it goes down, every width is one
+        more body of the model in this program (2.4-3.6 s of a warm
+        start each in most cells with a chunk of 512 or 1,024, 15 s in
+        one: PERF.md 6, PR 62), so a fresh row starts empty and its first pass is the
+        ``extend`` at position 0 like the rest: a body a width, and none
+        of ``prefill``."""
         C = tokens.shape[1]
         row, start, n = meta[0], meta[1], meta[2]
+        widths = pass_widths(C, max_len)
+        narrow = tuple(w for w in widths if w < C)
+        # the last pass's width among ``narrow`` (0: none of them), and the
+        # chunks that run at ``C`` and wider
+        which = last_pass(n, C, widths)
+        full = (n + C - 1) // C - (which > 0) * 1
         # the named scopes are the parts of the one program, by which a
         # profiler's device time is split (``telemetry.device_time``):
         # ``admit_row_cache`` the batch-1 row cache's allocation and
@@ -191,23 +250,35 @@ def admission(fam, cfg, max_len: int, kv_dtype):
                 return take(lg, at), cache
             return one
 
-        if prefix is None:
-            chunk0 = tokens[:1]
-            with jax.named_scope("admit_row_cache"):
-                fresh = fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype)
-            with jax.named_scope("admit_chunk"):
-                lg, cache = fam.prefill(params, chunk0, cfg, fresh,
-                                        valid=real(0, C))
-            done, carry = 1, (take(lg, 0), cache)
-        else:
+        if prefix is not None:
             done, carry = 0, (
                 jnp.zeros(last.shape[1:], last.dtype),
                 dataclasses.replace(prefix, length=start))
-        for w in pass_widths(C, max_len):
-            trips = jnp.maximum((n + C - 1) // C - done, 0) // (w // C)
+        else:
+            with jax.named_scope("admit_row_cache"):
+                fresh = fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype)
+            if narrow:
+                # every pass an ``extend``, the first from the empty row
+                done, carry = 0, (jnp.zeros(last.shape[1:], last.dtype),
+                                  fresh)
+            else:
+                with jax.named_scope("admit_chunk"):
+                    lg, cache = fam.prefill(params, tokens[:1], cfg, fresh,
+                                            valid=real(0, C))
+                done, carry = 1, (take(lg, 0), cache)
+        for w in (w for w in widths if w >= C):
+            trips = jnp.maximum(full - done, 0) // (w // C)
             carry = lax.fori_loop(jnp.int32(0), trips, passes(w, done * C),
                                   carry)
             done = done + trips * (w // C)
+        if narrow:
+            # ... and ONE pass over the prompt's end at the narrow width
+            # chosen, if one was: a branch a width (what a body re-lays of
+            # its weights then lives inside its branch; a loop of 0 or 1
+            # trips hoists it out, to live beside the chunk's)
+            carry = lax.switch(which, [lambda c: c] + [
+                (lambda c, w=w: passes(w, full * C)(jnp.int32(0), c))
+                for w in narrow], carry)
         vec, cache = carry
         with jax.named_scope("admit_bind"):
             key = jnp.where(meta[5] != 0, jax.random.fold_in(
@@ -720,7 +791,8 @@ class SlotBatcher:
         n_chunks = -(-S // C)
         with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S,
                               start=start_len, chunk=C, padded=n_chunks * C,
-                              chunks=n_chunks, passes=n_chunks, wide=0):
+                              chunks=n_chunks, passes=n_chunks, wide=0,
+                              narrow=0):
             chunks = self._padded_chunks(tokens, C, n_chunks)
             if start_cache is not None:
                 cache = start_cache
@@ -804,12 +876,18 @@ class SlotBatcher:
                          np.float32(temperature).view(np.int32),
                          fold is not None,
                          np.uint32(fold or 0).view(np.int32)], np.int32)
-        # a fresh row's first chunk is the family's ``prefill``, at ``C``
-        passes, wide = ladder_passes(S, pass_widths(C, self.max_len),
-                                     first=0 if prefix is not None else 1)
-        # what the launch computes, for its host span and its device span
-        work = dict(tokens=S, chunk=C, padded=n_chunks * C, passes=passes,
-                    wide=wide)
+        # a fresh row's first chunk is the family's ``prefill`` where the
+        # ladder stops at the chunk
+        widths = pass_widths(C, self.max_len)
+        passes, wide, narrow = ladder_passes(
+            S, C, widths,
+            first=0 if prefix is not None or widths[-1] < C else 1)
+        # what the launch computes, for its host span and its device span:
+        # ``padded`` the rows its passes run, a narrow last pass's for the
+        # last chunk's
+        work = dict(tokens=S, chunk=C,
+                    padded=n_chunks * C - (C - narrow if narrow else 0),
+                    passes=passes, wide=wide, narrow=narrow)
         if self._fam.chunk_form is not None:
             # which of its forms the program's passes take at this chunk
             work["form"] = self._fam.chunk_form(self._cfg, C)

@@ -128,9 +128,10 @@ class SpanName:
     #: its row cache is a temporary of its one program)
     SERVE_CACHE_ALLOC = "serve.cache_alloc"
     #: chunked prefill of a prompt/prefix through the fixed-width chunks
-    #: (tokens = real, padded = computed, chunks in args): around an
-    #: admission's ONE launch (chunk loop, slot write and bind), or around
-    #: a prefix build's launch a chunk
+    #: (tokens = real, padded = the rows its passes computed, chunks in
+    #: args; narrow = the width of a last pass narrower than chunk, else
+    #: 0): around an admission's ONE launch (chunk loop, slot write and
+    #: bind), or around a prefix build's launch a chunk
     SERVE_PREFILL = "serve.prefill"
     #: one prefill/extend chunk dispatch of a prefix build (index, pos,
     #: program in args)
@@ -170,8 +171,9 @@ class SpanName:
     #: and its predecessor's completion to its own completion, on this
     #: clock.  program and waited (seconds queued behind its predecessors)
     #: in args; of an admission also serve.prefill's tokens, padded, passes,
-    #: wide and chunk, and slot.  Launches of one registry never overlap;
-    #: what ran unwatched between two of them (release) falls to the later
+    #: wide, narrow and chunk, and slot.  Launches of one registry never
+    #: overlap; what ran unwatched between two of them (release) falls to
+    #: the later
     SERVE_DEVICE = "serve.device"
     #: restoring a tiered session's KV for a follow-up turn (gather or
     #: host rehydrate + remainder prefill)

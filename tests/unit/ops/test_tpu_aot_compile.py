@@ -718,7 +718,8 @@ def _pool_sized_moves(hlo_text, layer_k):
 def _compile_admission(v5e, family, int8, layers=None):
     """``serving.batcher.admission`` of a family, and its widest pass's
     ``extend`` on its batch-1 row alone (GPT-2's, at chunks of 128 in slots
-    of 1,024, is the ladder's 256 tokens; every other family's its chunk),
+    of 1,024, is the ladder's 256 tokens; every other family's its chunk,
+    from which its ladder goes down),
     compiled for the described chip at the serving cells' geometry
     (:func:`_served`; ``layers`` deep where given): ``(admit, extend, pool,
     row cache)``, the last two as shapes."""
@@ -813,20 +814,21 @@ def _admission_is_one_program_on_the_pool_in_place(admission_of, family,
     # compiler hoists out of the chunk loop (a weight re-laid once an
     # admission, not once a chunk) stays live through it (37 MB of 6.6 GB
     # for the latent family; 57 MB of 14.0 GB at its cell's depth, under
-    # the tick's own 14.43 GB: PERF.md 6).  A program that holds the ladder
-    # (GPT-2's: a chunk kernel's call a width) is held to the same
-    # hundredth at its cell's own depth, against its WIDEST pass's
-    # ``extend`` there
-    import re
-
+    # the tick's own 14.43 GB: PERF.md 6).  A program that holds a ladder
+    # (a chunk kernel's or a scan's call a width: GPT-2's upward, the
+    # convolution family's downward, whose narrow pass's temporaries are
+    # smaller than the chunk's and never live with them) is held to the
+    # same hundredth against its WIDEST pass's ``extend``, GPT-2's at its
+    # cell's own depth
     from deepspeed_tpu.serving.batcher import pass_widths
     widths = pass_widths(chunk, smax)
-    assert widths == ((256, 128) if family in ("dense", "moe")
-                      else (chunk,))
-    if len(widths) > 1:
-        assert {int(w) for w in re.findall(
-            r"%chunk_attention[.\d]* = bf16\[16,1,(\d+),64\]", text)} == \
-            set(widths)
+    # the cell's own geometry decides: 256 above a chunk of 128; half the
+    # chunk below one of 1,024 in slots of 3,072, the one cell whose slot is
+    # at most four chunks long (PR 62); the chunk alone in every other
+    assert widths == {"dense": (256, 128), "moe": (256, 128),
+                      "conv": (1024, 512)}.get(family, (chunk,))
+    _one_call_a_layer_a_width(text, widths, chunk)
+    if family in ("dense", "moe"):
         compiled, extend, pool, _ = admission_of(family, int8,
                                                  _LADDER_LAYERS)
     held_today = _planned_bytes(extend) + _pool_bytes(pool)
@@ -840,6 +842,34 @@ def _admission_is_one_program_on_the_pool_in_place(admission_of, family,
     room = 1.016 if family == "shortcut" else 1.01
     assert _planned_bytes(compiled) <= room * held_today, (
         _planned_bytes(compiled), _planned_bytes(extend), _pool_bytes(pool))
+
+
+def _one_call_a_layer_a_width(hlo_text, widths, chunk):
+    """Every Mosaic kernel of an admission but the experts' grouped product
+    (whose rows are pairs, not positions) is called as often at every
+    width of the ladder: an ``extend`` body a width, a chunk kernel's or a
+    scan's call a layer of it (GPT-2's ladder stops at the chunk, so its
+    program holds the ``prefill`` too: a kernel that only a ``prefill``
+    runs, its flash forward, at every width no wider than the chunk).  The
+    width is read off the call's first result, the one dimension of it
+    that is a width of the ladder."""
+    import collections
+    import re
+    calls = collections.Counter()
+    for name, dims in re.findall(
+            r"%([a-zA-Z_]+)[.\d]* = \(?\w+\[([\d,]*)\][^\n]*"
+            r"custom_call_target=\"tpu_custom_call\"", hlo_text):
+        at = [int(d) for d in dims.split(",") if d and int(d) in widths]
+        if name != "gmm":
+            assert len(at) == 1, (name, dims)
+            calls[name, at[0]] += 1
+    assert calls, "no chunk kernel or scan in the admission"
+    for name in {name for name, _ in calls}:
+        by_width = {w: calls[name, w] for w in widths if calls[name, w]}
+        assert set(by_width) in (set(widths),
+                                 {w for w in widths if w <= chunk}), (
+            name, by_width)
+        assert len(set(by_width.values())) == 1, (name, by_width)
 
 
 def _in_loops(hlo_text):
@@ -878,12 +908,15 @@ def _in_loops(hlo_text):
 
 
 def _one_up_projected_chunk_call_a_sublayer(text, cfg, chunk, smax):
-    """An admission's latent chunk passes at the cells' chunk (512 and
-    1,024 positions: over the 158 from which a key's up-projection pays,
+    """An admission's latent chunk passes at the cells' widths (a chunk of
+    512 or 1,024 positions, and its half where the ladder goes down: all
+    over the 158 from which a key's up-projection pays,
     ``decode_attention.latent_up_projects``) run the UP-PROJECTED form: ONE
-    custom call a sublayer body, twice in the program (the first chunk's
-    ``prefill`` and the loop's ``extend``), each with the one rank-3 result
-    ``[heads, chunk, d_v]`` by which the benchmark's roofline reader finds
+    custom call a sublayer body, twice a width in the program (the first
+    chunk's ``prefill`` and the loop's ``extend``; under a ladder that
+    goes down a fresh row starts empty and the program holds the
+    ``extend`` alone), each with the one rank-3 result
+    ``[heads, width, d_v]`` by which the benchmark's roofline reader finds
     it and no helper call beside it (a rank-2 result would read as a
     grouped matmul, a second rank-3 one would double the reader's calls);
     the absorbed chunk kernel is gone from the program.  That the program
@@ -894,23 +927,32 @@ def _one_up_projected_chunk_call_a_sublayer(text, cfg, chunk, smax):
     inside the chunk loop nothing as large as ONE layer of it is copied or
     transposed."""
     import re
+
+    from deepspeed_tpu.serving.batcher import pass_widths
+    widths = pass_widths(chunk, smax)
     kernels = [(k, shape) for k, shape in _custom_calls(text)
                if k != "gmm"]
     H, rank, e = cfg.n_head, cfg.kv_rank, cfg.d_nope + cfg.d_v
-    assert kernels == [(decode.LATENT_UP_CHUNK,
-                        f"bf16[{H},{chunk},{cfg.d_v}]")] * 4, kernels
-    assert decode.latent_up_tiles(
-        chunk, H, cfg.cache_row[0], rank, cfg.d_nope, cfg.d_v,
-        decode.latent_block_k(smax)) is not None
+    # a ``prefill`` and an ``extend`` where the ladder stops at the chunk;
+    # under one that goes down a fresh row starts empty: an ``extend`` a
+    # width and no ``prefill``
+    bodies = 1 if widths[-1] < chunk else 2
+    assert sorted(kernels) == sorted(
+        [(decode.LATENT_UP_CHUNK, f"bf16[{H},{w},{cfg.d_v}]")
+         for w in widths] * (2 * bodies)), kernels
+    for w in widths:
+        assert decode.latent_up_tiles(
+            w, H, cfg.cache_row[0], rank, cfg.d_nope, cfg.d_v,
+            decode.latent_block_k(smax)) is not None
     # the calls' last operand is a WHOLE stack, layers leading
     stacks = re.findall(
         rf"%{decode.LATENT_UP_CHUNK}[.\d]* = [^\n]*?bf16\[(\d+),{H},{rank},"
         rf"{e}\]\{{3,2,1,0\}}\}}, frontend_attributes", text)
-    assert len(stacks) == 4, stacks
+    assert len(stacks) == 2 * bodies * len(widths), stacks
     # ... and no layer of it, in the stack's order or the head-major one
-    # (but where that is the queries' own ``[heads, chunk, 256]``), is
+    # (but where that is the queries' own ``[heads, width, 256]``), is
     # copied or transposed inside a loop
-    layers = {(rank, H, e)} | ({(H, rank, e)} - {(H, chunk, e)})
+    layers = {(rank, H, e)} | ({(H, rank, e)} - {(H, w, e) for w in widths})
     moved = [(dims, op) for dims, op in _in_loops(text)
              if tuple(d for d in dims if d > 1) in layers
              and op in ("copy", "transpose")]
@@ -927,10 +969,13 @@ def _one_up_projected_chunk_call_a_sublayer(text, cfg, chunk, smax):
 #: layer, so the chunk kernel's transposed reads of a layer are as large;
 #: the single-part block's, the window family's and the convolution
 #: family's two banks are gathered from the loop's carry for the slot write
-#: (:data:`_KNOWN_MOVES`; the last's are 9.4 MB each).
+#: (:data:`_KNOWN_MOVES`; the last's are 9.4 MB each), and the last
+#: family's are copied once more into the ``lax.switch`` of its narrow last
+#: pass (PR 62: a conditional copies a bank it writes; 46 us of an
+#: admission of 40 ms).
 _ROW_BANK_COPIES = {("dense", True): 4, ("moe", True): 6,
                     ("hybrid", False): 8, ("single_part", False): 2,
-                    ("window", False): 2, ("conv", False): 2}
+                    ("window", False): 2, ("conv", False): 4}
 
 
 def _row_bank_ops(hlo_text, row_cache, opcode):
@@ -1379,33 +1424,40 @@ def test_the_selecting_family_leaves_its_three_pools_in_place(v5e, program):
         text = compiled.as_text()
         kernels = ("index_chunk_scores", decode.LATENT_UP_CHUNK)
         assert "/latent_chunk_attention/pallas_call" not in text
+        from deepspeed_tpu.serving.batcher import pass_widths
         full, window = cfg.dims("full"), cfg.dims("window")
         # a pass: the dense first layer's call, then the unit's four
         a_pass = [full, full, window, window, window]
+        # ... a ``prefill`` and an ``extend`` a width of the ladder, which
+        # in slots of sixteen chunks is the chunk alone
+        widths = pass_widths(chunk, smax)
+        assert widths == (1024,)
         calls = [shape for k, shape in _custom_calls(text)
                  if k == decode.LATENT_UP_CHUNK]
         assert sorted(calls) == sorted(
-            f"bf16[{dm.n_head},{chunk},{dm.d_v}]" for dm in 2 * a_pass), calls
+            f"bf16[{dm.n_head},{w},{dm.d_v}]" for dm in 2 * a_pass
+            for w in widths), calls
         for dm, n in ((full, 4), (window, 6)):
             e = latent_moe.lane_rows(dm.d_nope) + dm.d_v
-            assert decode.latent_up_tiles(
-                chunk, dm.n_head, dm.lanes, dm.kv_rank, e - dm.d_v, dm.d_v,
-                128, biased=True) is not None
-            # the stack goes in whole, layers leading, the mask after it
-            stacks = re.findall(
-                rf"bf16\[(\d+),{dm.n_head},{dm.kv_rank},{e}\]\{{3,2,1,0\}}, "
-                rf"s8\[1,{chunk},\d+\]\{{2,1,0\}}\}}, frontend_attributes",
-                text)
-            assert len(stacks) == n, (dm, stacks)
-            # ... no layer of it is re-laid inside a loop, and no absorbed
-            # query array is built anywhere
+            for w in widths:
+                assert decode.latent_up_tiles(
+                    w, dm.n_head, dm.lanes, dm.kv_rank, e - dm.d_v, dm.d_v,
+                    128, biased=True) is not None
+                # the stack goes in whole, layers leading, the mask after
+                stacks = re.findall(
+                    rf"bf16\[(\d+),{dm.n_head},{dm.kv_rank},{e}\]"
+                    rf"\{{3,2,1,0\}}, s8\[1,{w},\d+\]\{{2,1,0\}}\}}, "
+                    rf"frontend_attributes", text)
+                assert len(stacks) == n, (dm, w, stacks)
+                # ... and no absorbed query array is built anywhere
+                assert f"{w},{dm.n_head},{dm.lanes}]" not in text
+                assert f"[{w * dm.n_head},{dm.lanes}]" not in text
+            # no layer of the stack is re-laid inside a loop
             relaid = [(dims, op) for dims, op in _in_loops(text)
                       if tuple(d for d in dims if d > 1) in (
                           (dm.kv_rank, dm.n_head, e), (dm.n_head, dm.kv_rank, e))
                       and op in ("copy", "transpose")]
             assert not relaid, relaid
-            assert f"{chunk},{dm.n_head},{dm.lanes}]" not in text
-            assert f"[{chunk * dm.n_head},{dm.lanes}]" not in text
         assert _planned_bytes(compiled) <= _SELECTED_ROOM * (
             _planned_bytes(extend) + _pool_bytes(pool)), (
                 _planned_bytes(compiled), _planned_bytes(extend),

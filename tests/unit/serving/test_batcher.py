@@ -673,27 +673,35 @@ LONG = gpt.GPTConfig(vocab_size=256, max_seq_len=1024, n_layer=2, n_head=2,
 _LADDERED = {}
 
 
-def _laddered(family):
+def _laddered(family, widths="wide"):
     """``(fused, plain)`` as :func:`_served` gives them, in float32 (a wide
     pass sums in another order than the narrow ones it stands for): the
     dense model at the cell's geometry, chunks of 128 in slots of 1,024,
     where the ladder is 256, 128; a family with per-slot state and one
     with rings at their tiny sizes, chunks of 8 in slots of 64, for which
-    the caller patches the wide widths small."""
-    if family not in _LADDERED:
+    the caller patches the widths small; and so any family of the unified
+    suite by its configuration's name, or ``"tiny-dense"`` / ``"tiny-moe"``.
+    A pair of batchers a ``widths`` (a key only): a program keeps the
+    widths it was traced with."""
+    if (family, widths) not in _LADDERED:
+        geometry = {"slots": 2, "max_len": SLOT, "prefill_chunk": CHUNK}
         if family == "dense":
             cfg, params = LONG, gpt.init(LONG, jax.random.PRNGKey(0))
             geometry = {"slots": 2, "max_len": 1024, "prefill_chunk": 128}
+        elif family in ("tiny-dense", "tiny-moe"):
+            mod, cfg = (gpt, CFG) if family == "tiny-dense" else (
+                gpt_moe, MOE_CFG)
+            params = mod.init(cfg, jax.random.PRNGKey(0))
         else:
             cfg, params = _tiny({
                 "state": "granite-4.0-h-small-ep4",
-                "ring": "mellum2-12b-a2.5b-ep4"}[family], dtype=jnp.float32)
-            geometry = {"slots": 2, "max_len": SLOT, "prefill_chunk": CHUNK}
+                "ring": "mellum2-12b-a2.5b-ep4"}.get(family, family),
+                dtype=jnp.float32)
         eng = deepspeed_tpu.init_inference(model=(cfg, params),
                                            config={"dtype": "float32"})
         serving = ServingConfig.from_dict(geometry)
-        _LADDERED[family] = _two_batchers(eng, serving)
-    return _LADDERED[family]
+        _LADDERED[family, widths] = _two_batchers(eng, serving)
+    return _LADDERED[family, widths]
 
 
 @jax.tree_util.register_dataclass
@@ -702,6 +710,8 @@ class _Counts:
     """What :func:`_counting_family` keeps where a family keeps a cache."""
     passes: jax.Array
     wide: jax.Array
+    narrow: jax.Array
+    rows: jax.Array
     seen: jax.Array
     length: jax.Array
 
@@ -709,14 +719,15 @@ class _Counts:
 def _counting_family(vocab=4):
     """A stand-in for a model family whose "cache" counts what the admission
     program asks of it: the passes it ran, the tokens of those wider than
-    the chunk (handed over where a family's ``cfg`` goes), how often each
-    position was computed as a real token, and in its "logits" the position
-    each row stands at."""
+    the chunk (handed over where a family's ``cfg`` goes) and of those
+    narrower, the rows of them all, how often each position was computed as
+    a real token, and in its "logits" the position each row stands at."""
     import types
 
     def init_cache(cfg, batch, max_len, kv_dtype=None):
-        return _Counts(jnp.int32(0), jnp.int32(0),
-                       jnp.zeros((max_len,), jnp.int32), jnp.int32(0))
+        return _Counts(jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                       jnp.int32(0), jnp.zeros((max_len,), jnp.int32),
+                       jnp.int32(0))
 
     def run(tokens, cache, pos0, valid, chunk):
         w = tokens.shape[1]
@@ -726,8 +737,9 @@ def _counting_family(vocab=4):
         lg = jnp.broadcast_to(at.astype(jnp.float32)[None, :, None],
                               (1, w, vocab))
         return lg, _Counts(cache.passes + 1,
-                           cache.wide + (w if w > chunk else 0), seen,
-                           pos0 + w)
+                           cache.wide + (w if w > chunk else 0),
+                           cache.narrow + (w if w < chunk else 0),
+                           cache.rows + w, seen, pos0 + w)
 
     return types.SimpleNamespace(
         init_cache=init_cache,
@@ -762,59 +774,117 @@ def _admit_counting(chunk, max_len, start=0):
 @pytest.mark.parametrize("chunk,max_len,start,widths", [
     (128, 1024, 0, (256, 128)), (128, 1024, 77, (256, 128)),
     (64, 1024, 0, (256, 64)), (256, 1024, 0, (256,)),
-    (512, 1024, 0, (512,)), (128, 192, 0, (128,)), (8, 64, 0, (8,)),
-    (8, 64, 5, (8,))])
+    (512, 1024, 0, (512, 256)), (512, 2048, 300, (512, 256)),
+    (1024, 3072, 0, (1024, 512)), (1024, 4096, 77, (1024, 512)),
+    (128, 192, 0, (128,)), (8, 64, 0, (8,)), (8, 64, 5, (8,))])
 def test_the_hosts_pass_count_is_the_programs_trip_counts(chunk, max_len,
                                                           start, widths):
-    """``ladder_passes`` (what ``serve.prefill`` carries as ``passes`` and
-    ``wide``) against the admission program's own loops, counted by a
-    stand-in family, for prompts on both sides of every boundary of the
-    ladder (a wide pass takes the chunks a prompt is padded to, so its
-    last may hold padding): every real token is computed once, at its own
-    position, the frontier logits are the last one's, and a pass is 256
-    tokens wide where that is a whole multiple of the chunk, wider than it
-    and fits the slot."""
+    """``ladder_passes`` (what ``serve.prefill`` carries as ``passes``,
+    ``wide`` and ``narrow``, and ``padded`` follows) against the admission
+    program's own loops, counted by a stand-in family, for prompts on both
+    sides of every boundary of the ladder (a wide pass takes the chunks a
+    prompt is padded to, so its last may hold padding; a narrow one is the
+    last, over what is left of the prompt): every real token is computed
+    once, at its own position, the frontier logits are the last one's, a
+    pass is 256 tokens wide where that is a whole multiple of the chunk,
+    wider than it and fits the slot, and a last pass is half the chunk, if
+    that is no narrower than 256, where it holds the prompt's end."""
     from deepspeed_tpu.serving.batcher import ladder_passes, pass_widths
     assert pass_widths(chunk, max_len) == widths
-    first = 0 if start else 1
+    # a fresh row's first chunk is the ``prefill`` only where the ladder
+    # stops at the chunk: under a narrow width it starts empty
+    first = 0 if start or widths[-1] < chunk else 1
     edges = {1, chunk, max_len - start}
     for w in widths:
         for k in (1, 2, 3):
-            edges.update({k * w, first * chunk + k * w})
+            edges.update({k * w, first * chunk + k * w, k * chunk + w})
     admit = _admit_counting(chunk, max_len, start)
     for n in sorted({e + d for e in edges for d in (-1, 0, 1)}):
         if not 0 < n <= max_len - start:
             continue
         counted, at = admit(n)
-        passes, wide = ladder_passes(n, widths, first)
-        assert (int(counted.passes), int(counted.wide)) == (passes, wide), n
+        passes, wide, narrow = ladder_passes(n, chunk, widths, first)
+        assert (int(counted.passes), int(counted.wide),
+                int(counted.narrow)) == (passes, wide, narrow), n
+        assert int(counted.rows) == -(-n // chunk) * chunk - (
+            chunk - narrow if narrow else 0), n
         seen = np.asarray(counted.seen)
         assert (seen[start:start + n] == 1).all() and seen.sum() == n, n
         assert at == start + n - 1, n
+
+
+@pytest.mark.parametrize("chunk,max_len,widths", [
+    (128, 1024, (256, 128)), (256, 1024, (256,)), (512, 1024, (512, 256)),
+    (1024, 3072, (1024, 512)), (1024, 1024, (1024, 512)),
+    (1024, 4096, (1024, 512)), (1024, 4097, (1024,)),
+    (1024, 8192, (1024,)), (1024, 16384, (1024,)), (512, 2048, (512, 256)),
+    (512, 5120, (512,)), (512, 6144, (512,)), (512, 384, (384,)),
+    (768, 3072, (768, 384)), (640, 4096, (640,)), (96, 4096, (96,)),
+    (510, 1024, (510,)), (513, 1024, (513,)), (2048, 8192, (2048, 1024))])
+def test_the_ladder_goes_down_from_the_chunk_by_its_half(chunk, max_len,
+                                                          widths):
+    """``pass_widths``: after the chunk its half, where that is whole and
+    no narrower than 256 rows (a pass under the ridge costs what the ridge
+    costs) and the slot at most four chunks long (half a chunk is then a
+    share of a prompt worth a body of the model at a server's start: of
+    the serving cells' geometries 1,024 in 3,072 alone), and no quarter; at
+    128 and 256 what it returned before it went down; a chunk the batcher
+    has cut to a slot shorter than it is that slot's."""
+    from deepspeed_tpu.serving.batcher import pass_widths
+    assert pass_widths(min(chunk, max_len), max_len) == widths
+
+
+@pytest.mark.parametrize("n,first,want", [
+    # (passes, wide, narrow) at a chunk of 1,024 over (1024, 512, 256)
+    (255, 1, (1, 0, 256)), (256, 1, (1, 0, 256)), (257, 1, (1, 0, 512)),
+    (512, 1, (1, 0, 512)), (513, 1, (1, 0, 0)), (1024, 1, (1, 0, 0)),
+    (1025, 1, (2, 0, 256)), (1280, 1, (2, 0, 256)), (1281, 1, (2, 0, 512)),
+    (1536, 1, (2, 0, 512)), (1537, 1, (2, 0, 0)), (2048, 1, (2, 0, 0)),
+    (3000, 1, (3, 0, 0)), (3072, 1, (3, 0, 0)),
+    # after a prefix every pass is an ``extend``: the same tail rule
+    (1, 0, (1, 0, 256)), (256, 0, (1, 0, 256)), (257, 0, (1, 0, 512)),
+    (1024, 0, (1, 0, 0)), (1025, 0, (2, 0, 256)), (1537, 0, (2, 0, 0))])
+def test_a_last_pass_is_as_narrow_as_what_is_left_of_the_prompt(n, first,
+                                                                want):
+    """``ladder_passes`` over a ladder of TWO narrow widths (what
+    ``pass_widths`` would give with the quarter it was measured with and
+    lost): the narrowest that holds the prompt's end, fresh or after a
+    prefix."""
+    from deepspeed_tpu.serving.batcher import ladder_passes
+    assert ladder_passes(n, 1024, (1024, 512, 256), first) == want
 
 
 def test_a_704_token_document_is_four_passes_not_six():
     from deepspeed_tpu.serving.batcher import ladder_passes, pass_widths
     widths = pass_widths(128, 1024)
     assert widths == (256, 128)
-    assert ladder_passes(704, widths, 1) == (4, 512)
-    assert ladder_passes(704, (128,), 1) == (6, 0)
+    assert ladder_passes(704, 128, widths, 1) == (4, 512, 0)
+    assert ladder_passes(704, 128, (128,), 1) == (6, 0, 0)
     # a wide pass takes the chunks a prompt is PADDED to: 129 tokens past
     # the first chunk are the two chunks they were, in one pass
-    assert ladder_passes(256, widths, 1) == (2, 0)
-    assert ladder_passes(257, widths, 1) == (2, 256)
-    assert ladder_passes(384, widths, 1) == (2, 256)
-    assert ladder_passes(385, widths, 1) == (3, 256)
+    assert ladder_passes(256, 128, widths, 1) == (2, 0, 0)
+    assert ladder_passes(257, 128, widths, 1) == (2, 256, 0)
+    assert ladder_passes(384, 128, widths, 1) == (2, 256, 0)
+    assert ladder_passes(385, 128, widths, 1) == (3, 256, 0)
     # continuing a prefix, the wide passes come first
-    assert ladder_passes(704, widths) == (3, 768)
+    assert ladder_passes(704, 128, widths) == (3, 768, 0)
     # the widths compose: a ladder of two takes the widest first
-    assert ladder_passes(704, (512, 256, 128), 1) == (3, 512)
-    assert ladder_passes(1024, (512, 256, 128), 1) == (4, 768)
+    assert ladder_passes(704, 128, (512, 256, 128), 1) == (3, 512, 0)
+    assert ladder_passes(1024, 128, (512, 256, 128), 1) == (4, 768, 0)
+    # ... and in both directions: the chunks but the last by the wide
+    # widths, the prompt's end by the narrowest that holds it
+    both = (64, 32, 16, 8, 4)
+    assert ladder_passes(16 + 64 + 3, 16, both, 1) == (3, 64, 4)
+    assert ladder_passes(16 + 64 + 3, 16, both) == (3, 64, 4)
+    assert ladder_passes(16 + 96 + 5, 16, both, 1) == (4, 96, 8)
+    assert ladder_passes(16 + 96 + 9, 16, both, 1) == (4, 96, 0)
+    assert ladder_passes(16 + 96 + 9, 16, both) == (2, 128, 0)
 
 
-def _chunk_loops(chunk, max_len, prefix=False):
-    """The traced-trip-count loops of the admission's jaxpr at the top
-    level of the program (a family's own scans lie below)."""
+def _chunk_loops(chunk, max_len, prefix=False, primitive="while"):
+    """The traced-trip-count loops (or, for ``"cond"``, the switches) of
+    the admission's jaxpr at the top level of the program (a family's own
+    scans lie below)."""
     from deepspeed_tpu.serving.batcher import admission
     fam = gpt_inference.DENSE
     cfg = LONG
@@ -832,20 +902,25 @@ def _chunk_loops(chunk, max_len, prefix=False):
         i32(-(-max_len // chunk), chunk), i32(7),
         jax.ShapeDtypeStruct((2,), jnp.uint32),
         *((row,) if prefix else ()))
-    return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "while"]
+    return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == primitive]
 
 
 @pytest.mark.parametrize("prefix", [False, True], ids=["admit", "prefix"])
-@pytest.mark.parametrize("chunk,max_len,loops", [
-    (128, 1024, 2), (256, 1024, 1), (512, 1024, 1), (1024, 1024, 1),
-    (512, 8192, 1), (1024, 16384, 1), (128, 192, 1), (8, 64, 1)])
-def test_the_ladder_is_absent_where_the_chunk_is_wide_or_the_slot_short(
-        chunk, max_len, loops, prefix):
-    """The admission holds ONE chunk loop at a ``prefill_chunk`` of 256 and
-    more (every serving cell's but GPT-2's, and the ``chunk_widen`` rung's)
-    and in slots shorter than 256 (nearly every test's), as it did before
-    the ladder.  Two at 128 in slots of 1,024."""
-    assert len(_chunk_loops(chunk, max_len, prefix)) == loops
+@pytest.mark.parametrize("chunk,max_len,loops,switches", [
+    (128, 1024, 2, 0), (256, 1024, 1, 0), (512, 1024, 1, 1),
+    (1024, 1024, 1, 1), (1024, 3072, 1, 1), (512, 8192, 1, 0),
+    (1024, 16384, 1, 0), (128, 192, 1, 0), (8, 64, 1, 0)])
+def test_the_ladder_is_absent_where_the_chunk_is_the_ridge_or_the_slot_short(
+        chunk, max_len, loops, switches, prefix):
+    """The admission holds ONE chunk loop and nothing else at a
+    ``prefill_chunk`` of 256, in slots shorter than 256 (nearly every
+    test's) and in slots longer than four chunks (every serving cell's but
+    one), as it did before the ladder.  Two loops at 128 in slots of 1,024
+    (256 above the chunk); at 512 and 1,024 in slots of at most four
+    chunks (``lfm2-serve-assist-sat``'s 1,024 in 3,072) the one loop and
+    ONE ``lax.switch`` after it, the last pass at half the chunk or none."""
+    assert [len(_chunk_loops(chunk, max_len, prefix, primitive))
+            for primitive in ("while", "cond")] == [loops, switches]
 
 
 @pytest.mark.parametrize("n,cut", [
@@ -882,6 +957,51 @@ def test_wide_passes_of_a_state_family_and_of_a_ring_family(monkeypatch,
         for cut in cuts:
             if n - cut > 0 and (cut == 0 or n > 8):
                 _same_slot(fused, plain, n, cut, tol=2e-3)
+
+
+@pytest.mark.parametrize("family", [
+    "tiny-dense", "tiny-moe", "kimi-k2.7-code-ep32",
+    "granite-4.0-h-small-ep4", "nemotron-3-nano-30b-a3b-ep4",
+    "mellum2-12b-a2.5b-ep4", "dots3-note-prev-ep32",
+    "kimi-linear-48b-a3b-ep8", "longcat-flash-chat-ep32", "lfm2-8b-a1b"])
+def test_a_narrow_last_pass_leaves_the_slot_as_the_chunk_leaves_it(
+        monkeypatch, family):
+    """The ladder downward with its ridge patched small and its slots'
+    rule long (widths of 8 and 4 at chunks of 8 in slots of 64), for every
+    family the unified suite
+    serves: a prompt whose end half a chunk holds leaves the slot as
+    launches of 8 leave it: the frontier logits, the row's length, its
+    cache up to the frontier, per-slot state (a convolution's tail, an SSM
+    or delta-rule state, a ring: ``valid`` inside the narrow pass is what
+    carries it) and the six greedy tokens that follow, with and without a
+    prefix; a fresh row starts empty and every pass is the family's
+    ``extend``, the first at position 0, against launches that start with
+    its ``prefill``; one program serves them all."""
+    from deepspeed_tpu.serving import batcher
+    monkeypatch.setattr(batcher, "NARROW_FLOOR", 2)
+    monkeypatch.setattr(batcher, "NARROW_SLOT_CHUNKS", SLOT // CHUNK)
+    fused, plain = _laddered(family, "narrow")
+    assert batcher.pass_widths(fused.chunk, fused.max_len) == (8, 4)
+    cuts = (0,) if fused.unsupported("prefix") else (0, 5)
+    for n in (1, 3, 4, 5, 8, 9, 11, 12, 13, 16, 26, 28, 29, 58, 64):
+        for cut in cuts:
+            if n - cut > 0:
+                _same_slot(fused, plain, n, cut, tol=2e-3)
+
+
+def test_the_ladder_in_both_directions_at_once(monkeypatch):
+    """Widths of 32 and 16 over chunks of 8 and of 4 under them, for a
+    family with per-slot state: the whole wide passes first, then the
+    chunks but the last, then the prompt's end at half a chunk where that
+    holds it."""
+    from deepspeed_tpu.serving import batcher
+    monkeypatch.setattr(batcher, "WIDE_PASSES", (32, 16))
+    monkeypatch.setattr(batcher, "NARROW_FLOOR", 2)
+    monkeypatch.setattr(batcher, "NARROW_SLOT_CHUNKS", SLOT // CHUNK)
+    fused, plain = _laddered("state", "both")
+    assert batcher.pass_widths(fused.chunk, fused.max_len) == (32, 16, 8, 4)
+    for n in (2, 10, 26, 27, 41, 43, 47, 57, 59, 64):
+        _same_slot(fused, plain, n, 0, tol=2e-3)
 
 
 def test_the_fold_inside_the_admission_is_the_hosts_fold():

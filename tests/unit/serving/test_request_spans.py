@@ -62,7 +62,8 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     # admission's one child, where the work happens: ``serve.prefill``
     # around the ONE launch that runs the chunks, writes the slot and binds
     # the row, with the arguments it always had and the passes the program
-    # ran for them (in a slot of 64 none is wider than the chunk).  No
+    # ran for them (in a slot of 64 none is wider than the chunk, and under
+    # the ridge none is narrower).  No
     # batch-1 cache is allocated by the host, no chunk is a launch of its
     # own and the slot write is no step of its own: their spans are not
     # entered
@@ -70,7 +71,7 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     assert _inside(prefill, admit)
     assert prefill.args == {"tokens": CHUNK + 1, "start": 0, "chunk": CHUNK,
                             "padded": 2 * CHUNK, "chunks": 2, "passes": 2,
-                            "wide": 0}
+                            "wide": 0, "narrow": 0}
     assert [s.name for s in spans if _inside(s, admit)] == ["serve.prefill"]
     for name in ("serve.cache_alloc", "serve.prefill_chunk",
                  "serve.slot_write"):
@@ -124,10 +125,11 @@ def test_requests_keep_their_own_ids_and_only_a_prefix_build_runs_chunk_by_chunk
     admits = by("serve.admit")
     build, first, second = by("serve.prefill")
     assert [(p.args["tokens"], p.args["start"], p.args["padded"],
-             p.args["chunks"], p.args["passes"], p.args["wide"])
+             p.args["chunks"], p.args["passes"], p.args["wide"],
+             p.args["narrow"])
             for p in (build, first, second)] == [
-        (2 * CHUNK, 0, 2 * CHUNK, 2, 2, 0), (3, 2 * CHUNK, CHUNK, 1, 1, 0),
-        (5, 2 * CHUNK, CHUNK, 1, 1, 0)]
+        (2 * CHUNK, 0, 2 * CHUNK, 2, 2, 0, 0),
+        (3, 2 * CHUNK, CHUNK, 1, 1, 0, 0), (5, 2 * CHUNK, CHUNK, 1, 1, 0, 0)]
     assert _inside(build, admits[0]) and _inside(first, admits[0])
     assert _inside(second, admits[1])
     # the builder's children: the batch-1 cache it allocates and a launch a
@@ -172,6 +174,49 @@ def test_the_prefill_span_counts_the_ladders_passes(engine, monkeypatch):
         (40, 0, 8, 40, 5, 2, 32), (63, 0, 8, 64, 8, 4, 48),
         (11, 0, 8, 16, 2, 2, 0),        # the prefix, built chunk by chunk
         (49, 11, 8, 56, 7, 3, 48)]      # 32, 16 and one token in a chunk
+    assert gw.snapshot()["recompiles"] == 0
+
+
+def test_the_spans_carry_a_narrow_last_pass_and_the_rows_computed(
+        engine, monkeypatch):
+    """``serve.prefill`` and ``serve.device`` carry ``narrow``, the width of
+    a last pass narrower than ``chunk`` (0: the chunk), and ``padded`` is
+    the rows the passes COMPUTED: with the ridge patched to 2 under chunks
+    of 8 in slots of 64 (widths 8 and 4), a prompt of 3 tokens is one pass of 4 rows, 9
+    tokens a chunk and 4 rows, 13 two chunks, 27 three chunks and 4 rows,
+    and after a pooled prefix the same rule holds from its end; ``chunks``
+    and ``chunk`` keep their meaning (the padded prompt's); the prefix's
+    builder runs its chunks whole."""
+    from deepspeed_tpu.serving import batcher
+    monkeypatch.setattr(batcher, "NARROW_FLOOR", 2)
+    monkeypatch.setattr(batcher, "NARROW_SLOT_CHUNKS", 8)
+    tracer = Tracer(name="serving")
+    gw = engine.serve(config=SERVING, tracer=tracer)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, 256, (n,)).astype(np.int32)
+               for n in (3, 9, 13, 27)]
+    for p in prompts:
+        gw.submit(p, max_new_tokens=1).result(timeout=120)
+    gw.submit(prompts[-1][:22], max_new_tokens=1,
+              prefix_len=CHUNK + 3).result(timeout=120)
+    gw.shutdown()
+    keys = ("tokens", "start", "chunk", "padded", "chunks", "passes", "wide",
+            "narrow")
+    spans = [tuple(s.args[k] for k in keys) for s in tracer.spans()
+             if s.name == "serve.prefill"]
+    assert spans == [
+        (3, 0, 8, 4, 1, 1, 0, 4), (9, 0, 8, 12, 2, 2, 0, 4),
+        (13, 0, 8, 16, 2, 2, 0, 0), (27, 0, 8, 28, 4, 4, 0, 4),
+        (11, 0, 8, 16, 2, 2, 0, 0),     # the prefix, built chunk by chunk
+        (11, 11, 8, 12, 2, 2, 0, 4)]    # a chunk and three tokens in 4 rows
+    device = [s for s in tracer.spans() if s.name == "serve.device"
+              and s.args["program"].startswith("admit")]
+    # the launches' device spans carry the same counts (the prefix's
+    # builder is no watched launch), less the host's ``start`` and ``chunks``
+    both = [i for i, k in enumerate(keys) if k not in ("start", "chunks")]
+    assert [tuple(d.args[keys[i]] for i in both)
+            for d in sorted(device, key=lambda s: s.t0)] == [
+        tuple(s[i] for i in both) for s in spans[:4] + spans[5:]]
     assert gw.snapshot()["recompiles"] == 0
 
 
@@ -258,7 +303,7 @@ def test_every_admission_and_every_tick_leaves_a_device_span(engine, on):
     # an admission's span carries ``serve.prefill``'s counts and its slot
     prefills = sorted((s for s in spans if s.name == "serve.prefill"),
                       key=lambda s: s.t0)
-    keys = ("tokens", "padded", "passes", "wide", "chunk")
+    keys = ("tokens", "padded", "passes", "wide", "narrow", "chunk")
     assert [[a.args[k] for k in keys] for a in admits] == [
         [p.args[k] for k in keys] for p in prefills]
     assert [a.args["padded"] for a in admits] == [8, 16, 24, 8]

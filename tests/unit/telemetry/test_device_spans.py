@@ -106,7 +106,9 @@ def test_watched_launches_are_stamped_in_order_by_one_thread():
 
 def test_the_report_prints_device_time_by_program(tmp_path, capsys):
     """``scripts/run_report.py --trace``: launches, p50 and p95 ms, us a
-    padded token and the share of the run, by program."""
+    padded token (``padded``: the rows an admission's passes computed, a
+    narrow last pass's in the last chunk's place) and the share of the run,
+    by program."""
     import importlib.util
     import json
     import os
@@ -118,8 +120,11 @@ def test_the_report_prints_device_time_by_program(tmp_path, capsys):
     for i in range(20):                 # a tick of 3 ms, back to back
         reg._stamp("tick", t, t + 0.003, {})
         t += 0.003
-        if i % 5 == 0:                  # and four admissions of 10 ms
-            reg._stamp("admit", t, t + 0.010, {"padded": 256, "slot": i})
+        if i % 5 == 0:                  # and four admissions of 10 ms,
+            # the last a chunk and a narrow last pass of 64 rows
+            reg._stamp("admit", t, t + 0.010, {
+                "padded": 256 if i < 15 else 128 + 64, "chunk": 128,
+                "narrow": 0 if i < 15 else 64, "slot": i})
             t += 0.010
     with tracer.span(SpanName.SERVE_TICK):
         pass
@@ -139,8 +144,9 @@ def test_the_report_prints_device_time_by_program(tmp_path, capsys):
     # the export keeps whole microseconds
     assert table["admit"]["n"] == 4
     assert table["admit"]["p50_ms"] == pytest.approx(10.0, abs=2e-3)
+    # ... over the rows the passes computed, not the chunks they stand for
     assert table["admit"]["us_per_padded_token"] == pytest.approx(
-        10000 / 256, abs=1e-2)
+        40000 / (3 * 256 + 128 + 64), abs=1e-2)
     assert table["admit"]["share"] == pytest.approx(0.4, abs=1e-3)
     assert mod.main([str(tmp_path), "--trace", path]) == 0
     out = capsys.readouterr().out
