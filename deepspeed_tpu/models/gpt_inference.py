@@ -284,12 +284,13 @@ def read_slot(cache: KVCache, row, length=None) -> KVCache:
 
 def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
                       window=None, k_scale=None, v_scale=None, layer=None,
-                      active=None, sweep=None):
+                      active=None, sweep=None, row=None):
     """q: [B, S_q, H, D] attending to cache[:, :pos+S_q]; with ``layer``
     the cache operands are the stacked [L, B, S_max, H*D] pool and the
     decode kernel reads that layer in place.  ``active`` / ``sweep``
     (single-token decode only): the live rows and the kernel's work list
-    built from them, see ``cached_attention``.
+    built from them, see ``cached_attention``; ``row`` (a chunk only): the
+    pool's rows the queries read, see there too.
 
     ``pos`` is the number of tokens already in the cache before this call;
     query i sits at absolute position pos+i and sees cache slots ≤ pos+i.
@@ -318,7 +319,7 @@ def _cached_attention(q, cache_k, cache_v, pos, config: gpt.GPTConfig,
     return cached_attention(q, cache_k, cache_v, pos, sm_scale=scale,
                             k_scale=k_scale, v_scale=v_scale,
                             window=window, slopes=slopes, layer=layer,
-                            active=active, sweep=sweep)
+                            active=active, sweep=sweep, row=row)
 
 
 def _block_tail(x, attn, p, config: gpt.GPTConfig):
@@ -354,12 +355,12 @@ def _dense_attend_fresh(q, fresh, cache, config, idx):
 
 
 def _dense_attend_cached(q, cache, pos, config, idx, active=None,
-                         sweep=None):
+                         sweep=None, row=None):
     return _cached_attention(
         q, cache.k, cache.v, pos, config,
         window=gpt.layer_window(config, idx, cache.max_len),
         k_scale=cache.k_scale, v_scale=cache.v_scale, layer=idx,
-        active=active, sweep=sweep)
+        active=active, sweep=sweep, row=row)
 
 
 def _dense_windows(config: gpt.GPTConfig, max_len: int):
@@ -441,7 +442,10 @@ class Family:
     whose queries attend to a selection of their cache); ``chunk_form(config,
     chunk)``: the name of the form its passes of ``chunk`` positions take,
     for a family whose passes have more than one (the latent families'
-    ``"up_projected"`` / ``"absorbed"``; None: one form, nothing to say)."""
+    ``"up_projected"`` / ``"absorbed"``; None: one form, nothing to say);
+    ``pool_rows``: its ``attend_cached`` takes ``row=`` and reads a chunk's
+    keys out of that row of a pool of any number of rows (the dense block's
+    does), so an ``extend`` may work on a slot's own row (:func:`in_place`)."""
     step: Any
     project: Any = _dense_project
     attend_fresh: Any = _dense_attend_fresh
@@ -457,6 +461,7 @@ class Family:
     state_counters: Tuple[str, ...] = ()
     select_counters: Tuple[str, ...] = ()
     chunk_form: Any = None
+    pool_rows: bool = False
 
     # the cache, the passes and the slot ops: the module's functions below
     # with this family in them, defined once for every family
@@ -474,9 +479,9 @@ class Family:
                                              family=self, valid=valid)
 
     def extend(self, params, tokens, config, cache, lengths=None,
-               valid=None):
+               valid=None, row=None):
         return extend(params, tokens, config, cache, lengths=lengths,
-                      family=self, valid=valid)
+                      family=self, valid=valid, row=row)
 
     def decode_step(self, params, token, config, cache, lengths=None,
                     active=None):
@@ -548,7 +553,21 @@ def _sweeps(family: Family, pos, B, config, max_len, active):
 
 
 #: the dense GPT family; also every speculative draft's
-DENSE = Family(step=dense_step)
+DENSE = Family(step=dense_step, pool_rows=True)
+
+
+def in_place(family, cache) -> bool:
+    """Whether one row's ``extend`` may run on a row of the POOL ``cache``
+    itself (``row=``), with no batch-1 cache made of it and none copied
+    back: the family's attention reads a row where it lies
+    (``Family.pool_rows``) and the cache is banks alone, two of them in the
+    compute dtype.  Anything else a cache holds needs the row apart: a
+    recurrence's state must not see a pass's padding, a ring is no prefix
+    of a row, a latent row and scale banks have kernels that read a layer
+    of a batch.  Decided by what the cache holds, never by a model's name."""
+    return bool(getattr(family, "pool_rows", False)) \
+        and isinstance(cache, KVCache) and cache.v is not None \
+        and not cache.int8 and cache.state is None and cache.ring is None
 
 
 def _layer_scan(x, params, cache: KVCache, config, positions, write, attn,
@@ -692,10 +711,12 @@ def _chunk_scatter(bank, layer, val, pos0):
                    pos0[:, None] + jnp.arange(Sc)].set(val)
 
 
-def _chunk_slice(bank, layer, val, pos0):
+def _chunk_slice(bank, layer, val, pos0, row=0):
     """:func:`_chunk_scatter` of ONE row (``B == 1``, ``S_c <= S``), whose
     cells are consecutive: one update slice, the same cells written bit for
-    bit.  An update slice CLAMPS its start where a scatter drops, so a
+    bit, into row ``row`` of the bank (a batch-1 cache's only one; a slot's
+    own, where an admission works on the pool: :func:`in_place`).  An
+    update slice CLAMPS its start where a scatter drops, so a
     chunk that would pass the row's end starts ``over`` cells early (0
     everywhere else), its rows moved up by as many over the cells as they
     were: what lies before the frontier is written back as read, the rows
@@ -711,11 +732,11 @@ def _chunk_slice(bank, layer, val, pos0):
     S, Sc = bank.shape[2], val.shape[1]
     at = jnp.minimum(pos0[0], S - Sc)
     over = jnp.minimum(pos0[0] - at, Sc)
-    old = lax.dynamic_slice(bank, (layer, 0, at, 0), (1,) + val.shape)
+    old = lax.dynamic_slice(bank, (layer, row, at, 0), (1,) + val.shape)
     rows = jnp.where((jnp.arange(Sc) >= over)[:, None],
                      jnp.roll(val, over, axis=1), old[0])
     return with_layout_constraint(
-        lax.dynamic_update_slice(bank, rows[None], (layer, 0, at, 0)),
+        lax.dynamic_update_slice(bank, rows[None], (layer, row, at, 0)),
         Layout(major_to_minor=tuple(range(bank.ndim))))
 
 
@@ -759,7 +780,7 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
 
 def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
            lengths=None, family: Family = DENSE,
-           valid=None) -> Tuple[jnp.ndarray, KVCache]:
+           valid=None, row=None) -> Tuple[jnp.ndarray, KVCache]:
     """Chunked prefill: append ``tokens`` [B, S_c] at positions
     ``cache.length .. cache.length+S_c-1``, attending causally over the
     cached prefix + the chunk.
@@ -784,16 +805,30 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
     slots ``lengths[b] .. lengths[b]+S_c-1`` and attends through its own
     live prefix; ``cache.length`` advances to ``max(lengths) + S_c`` and
     the caller tracks per-row lengths.
+
+    ``row`` (scalar, may be traced; with ``lengths`` [1] and one row of
+    tokens, where :func:`in_place` says the family and the cache allow it):
+    ``cache`` is a POOL of any number of rows and the chunk is written to,
+    and attends over, row ``row`` of it where it lies (an admission's pass
+    on the slot it fills).  Cells past the chunk keep what they held: every
+    reader masks by the row's length.  ``cache.length`` keeps the pool's
+    max-frontier meaning.
     """
     B, Sc = tokens.shape
     ragged = lengths is not None
     pos0 = lengths if ragged else cache.length
+    if row is not None and not (ragged and B == 1 and Sc <= cache.max_len
+                                and in_place(family, cache)):
+        raise ValueError(
+            "extend(row=) is one row's ragged chunk (lengths [1]) on a pool "
+            "of banks alone, for a family whose attention reads a row of it")
     if not isinstance(pos0, jax.core.Tracer) and \
             int(jnp.max(pos0)) + Sc > cache.max_len:
         raise ValueError(
             f"extend of {Sc} tokens at length {int(jnp.max(pos0))} "
             f"overflows the cache (max_len {cache.max_len}); the write "
             "would clamp and corrupt the cached prefix")
+    at_row = {} if row is None else {"row": row}
     if ragged:
         positions = pos0[:, None] + jnp.arange(Sc)          # [B, S_c]
         # one row's chunk is consecutive cells of that row: a slice
@@ -801,7 +836,7 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
             else _chunk_scatter
 
         def write(bank, layer, val):
-            return place(bank, layer, val, pos0)
+            return place(bank, layer, val, pos0, **at_row)
     else:
         positions = pos0 + jnp.arange(Sc)   # [S_c], shared across rows
 
@@ -814,13 +849,16 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
     def attn(q, fresh, cache, idx, **ring):
         if ring:    # the chunk is not in its ring yet: its rows ride along
             ring["fresh"] = fresh
-        return family.attend_cached(q, cache, pos0, config, idx, **ring)
+        return family.attend_cached(q, cache, pos0, config, idx, **ring,
+                                    **at_row)
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
                            family, _real_tokens(valid, B, Sc))
     logits = family.logits(params, x, config)
-    return logits, dataclasses.replace(cache,
-                                       length=jnp.max(pos0) + Sc)
+    length = jnp.max(pos0) + Sc
+    return logits, dataclasses.replace(
+        cache, length=length if row is None else jnp.maximum(
+            cache.length, length))
 
 
 def decode_step(params: PyTree, token: jnp.ndarray, config, cache: KVCache,

@@ -25,12 +25,16 @@ scalar prefetch beside ``pos`` and the sweep, a grid step takes all heads of
 one block of one slot (the dense sweep by its own asynchronous copies out
 of the pool in HBM, the next steps' in flight behind the current one), and
 nothing is sliced, transposed or copied to feed it.  The chunk kernel
-(``extend``: admission, speculative verify) still takes one KEY-VALUE head a
-step from a ``[B*Hkv, S_max, D]`` view of one layer (a 64-wide head is half
-a lane row and cannot be a block of the folded row), with the whole group
+(``extend``: admission, speculative verify) takes one KEY-VALUE head a
+step from a ``[B*Hkv, S_max, D]`` view of one layer, with the whole group
 of query heads that share it: ``G * block_q`` query rows against one key
 block, products in the query's dtype as the sweeps make them
-(``_chunk_kernel``).
+(``_chunk_kernel``).  An admission that works on its slot's own row of the
+pool (``cached_attention(row=)``) reads that row where it lies too
+(``_row_chunk``): the same step over LANE BLOCKS of the folded bank, layer
+and row by scalar prefetch, a head of 128 lanes with its group a block of
+its own and two ungrouped heads of 64 sharing one, each scored with the
+other's query lanes zeroed.
 
 A latent cache (``models/latent_moe.py``) keeps ONE row per token and layer,
 shared by all heads: ``[c | R(k_r)]``, stored ``[L, B, S_max, W]`` with ``W``
@@ -892,6 +896,234 @@ def _chunk(q4, k3, v3, pos, sm_scale, block_q, block_k, Hkv, ks3=None,
                           name="chunk_attention")(*args)
 
 
+# ------------------------------------------- a chunk over a row of the pool
+
+def row_chunk_lanes(Hkv: int, G: int, D: int) -> Optional[Tuple[int, int]]:
+    """``(kw, qw)``: the lanes of the folded bank ``[.., Hkv * D]`` and of the
+    folded queries ``[.., H * D]`` that one LANE BLOCK of the chunk kernel
+    over a row of the pool (``_row_chunk``) takes, or None where that kernel
+    does not serve the heads.  A key-value head of whole lane rows (``D`` a
+    multiple of 128) is a lane block of its own, beside the ``G * D`` lanes
+    of its group's queries; ungrouped heads narrower than a lane row (``G``
+    1, ``D`` 64: two heads in 128 lanes) share a block of 128 lanes of both,
+    and a product contracts all 128 with the other heads' query lanes zeroed
+    (half of the matrix unit's depth stands idle under a head of 64 either
+    way: ``ROADMAP.md``'s two questions for the compiler, PR 43).  Grouped
+    heads narrower than a lane row would want their queries shifted across
+    lanes: they keep the heads-major view (``_chunk``)."""
+    if D % 128 == 0:
+        return D, G * D
+    if G == 1 and 128 % D == 0 and (Hkv * D) % 128 == 0:
+        return 128, 128
+    return None
+
+
+#: bytes of VMEM a step of ``_row_chunk`` is sized to, of the 16 MiB a v5e's
+#: kernel may use (``_UP_VMEM``'s count, for the same reason)
+_ROW_CHUNK_VMEM = 12 << 20
+
+
+def row_chunk_blocks(lanes: int, kw: int, qw: int, G: int, block_q: int,
+                     block_k: int, itemsize: int) -> int:
+    """Lane blocks a step of ``_row_chunk`` takes of a ``lanes``-wide row:
+    the largest divisor of their number whose step stays within
+    ``_ROW_CHUNK_VMEM``, counting a lane block's two buffers a bank, its
+    queries' and its result's two each and its float32 accumulator, beside
+    the float32 scores and probabilities of the one lane block in hand.  A
+    step costs ~0.35 us beside its products, so as many as fit run in one:
+    ``gpt2-medium``'s 16 heads of 64 over 512 keys are all eight lane
+    blocks, one step a key block (measured, PERF.md 6, PR 63: 16.6 / 13.5 /
+    12.2 / 11.6 us a call of 128 rows at 1 / 2 / 4 / 8); a group of 8 heads
+    of 128 over 1,024 keys fills the step with its scores and takes one."""
+    rows = G * block_q
+    scores = 3 * rows * block_k * 4
+    block = 2 * 2 * block_k * kw * itemsize + 2 * 2 * block_q * qw * itemsize \
+        + rows * kw * 4
+    n = lanes // kw
+    return max(p for p in range(1, n + 1) if n % p == 0 and (
+        p == 1 or scores + p * block <= _ROW_CHUNK_VMEM))
+
+
+def _lane_block(q, ks, vs, visible, m, l, acc, *, sm_scale, G, D):
+    """One lane block of one key block, in values: ``q`` ``(block_q, qw)``,
+    ``ks`` / ``vs`` ``(block_k, kw)``, ``visible`` the ``(G * block_q,
+    block_k)`` mask, and the block's running max and sum ``m`` / ``l``
+    (``(rows, 1)`` a head of the block) and accumulator ``acc`` ``(rows,
+    kw)``: ``(m, l, acc)`` after the step.
+
+    A lane block that IS a key-value head (``D == kw``) takes its group's
+    ``G`` query heads as ``G * block_q`` rows under one another, as
+    ``_chunk_kernel`` does.  One that holds several ungrouped heads scores
+    each head with the others' query lanes zeroed and keeps, of its
+    probabilities' product with all ``kw`` value lanes, that head's own."""
+    kw = ks.shape[1]
+    heads = kw // D
+
+    def step(q, m_prev, l_prev):
+        s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(visible, s * sm_scale, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        return (m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                alpha, jnp.dot(p.astype(vs.dtype), vs,
+                               preferred_element_type=jnp.float32))
+
+    if heads == 1:
+        rows = q if G == 1 else jnp.concatenate(
+            [q[:, g * D:(g + 1) * D] for g in range(G)], axis=0)
+        m_new, l_new, alpha, pv = step(rows, m[0], l[0])
+        return [m_new], [l_new], acc * alpha + pv
+    own = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1) // D
+    ms, ls, alpha, pv = [], [], None, None
+    for j in range(heads):
+        m_new, l_new, a, w = step(jnp.where(own == j, q, jnp.zeros_like(q)),
+                                  m[j], l[j])
+        ms.append(m_new)
+        ls.append(l_new)
+        alpha = a if j == 0 else jnp.where(own == j, a, alpha)
+        pv = w if j == 0 else jnp.where(own == j, w, pv)
+    return ms, ls, acc * alpha + pv
+
+
+def _row_chunk_kernel(pos_ref, rows_ref, layer_ref, *rest, sm_scale, block_q,
+                      block_k, G, D, kw, P, windowed):
+    """``_chunk_kernel`` over the folded row where it lies: ``k_ref`` /
+    ``v_ref`` are ``(block_k, P * kw)``, ``P`` lane blocks of one key block of
+    row ``rows_ref[b]`` of layer ``layer_ref[0]`` of the pool, and ``q_ref`` /
+    ``o_ref`` ``(block_q, P * qw)``, the same heads' lanes of the folded
+    queries (``row_chunk_lanes``).  The lane blocks are walked inside the
+    step (``_lane_block``), each with its own running max, sum and
+    accumulator, by a ROLLED loop over lane-aligned slices: unrolled in
+    Python the 16 heads of a ``gpt2-medium`` step ran 12% faster (11.6 us a
+    call of 128 rows for 13.3) and cost a warm server's start 3.8 s of 10.5
+    in tracing and lowering its three instances (PERF.md 6, PR 63)."""
+    window_ref = None
+    if windowed:
+        window_ref, rest = rest[0], rest[1:]
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = rest
+    b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
+    heads = kw // D                 # heads a lane block (1: a group's)
+    qw = G * D if heads == 1 else kw
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    q_lo = pos_ref[b] + qi * block_q        # the tile's first position
+    window = window_ref[0] if windowed else None
+    lo, hi = _chunk_live_range(q_lo, block_q, block_k, window, None)
+
+    def lanes(i, w):
+        return pl.ds(pl.multiple_of(i * w, w), w)
+
+    @pl.when(jnp.logical_and(lo <= ki, ki <= hi))
+    def _update():
+        shape = (G * block_q, block_k)
+        r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        if G > 1:
+            r = jax.lax.rem(r, block_q)
+        dist = q_lo + r - ki * block_k \
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        visible = dist >= 0
+        if windowed:
+            visible = jnp.logical_and(visible, dist < window)
+
+        def block(p, _):
+            cs = lanes(p, kw)
+            m, l, acc_ref[p] = _lane_block(
+                q_ref[:, lanes(p, qw)], k_ref[:, cs], v_ref[:, cs], visible,
+                [m_ref[p * heads + j] for j in range(heads)],
+                [l_ref[p * heads + j] for j in range(heads)],
+                acc_ref[p], sm_scale=sm_scale, G=G, D=D)
+            for j in range(heads):
+                m_ref[p * heads + j] = m[j]
+                l_ref[p * heads + j] = l[j]
+
+        jax.lax.fori_loop(0, P, block, None)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        own = jax.lax.broadcasted_iota(jnp.int32, (block_q, kw), 1) // D
+
+        def block(p, _):
+            total = l_ref[p * heads]
+            for j in range(1, heads):   # each lane under its own head's sum
+                total = jnp.where(own == j, l_ref[p * heads + j], total)
+            o = (acc_ref[p] / total).astype(o_ref.dtype)
+            if G == 1:
+                o_ref[:, lanes(p, kw)] = o
+            else:       # (G * block_q, D): the group's heads under one another
+                for g in range(G):
+                    o_ref[:, lanes(p * G + g, D)] = \
+                        o[g * block_q:(g + 1) * block_q]
+
+        jax.lax.fori_loop(0, P, block, None)
+
+
+def _row_chunk(q, k, v, layer, rows, pos, sm_scale, block_q, block_k, Hkv,
+               window=None):
+    """A chunk's queries over rows of the pool WHERE THEY LIE: ``q`` [B, Sq,
+    H * D], folded as the projection writes them, against cells of row
+    ``rows[b]`` of layer ``layer`` of the banks ``k`` / ``v`` [L, slots,
+    Smax, Hkv * D]; [B, Sq, H * D].  The layer and the rows ride the scalar
+    prefetch beside ``pos`` and the index maps address the folded bank by
+    them, as the single-token sweeps' do: no layer is sliced out (a layer
+    of a pool is all its slots) and no head-major copy is made of either
+    side.  Grid step ``(b, lanes, qi, ki)`` takes ``row_chunk_blocks`` lane
+    blocks of key block ``ki`` (clamped into the tile's live range, so a
+    dead step fetches nothing); the result is rank 3 with a chunk's rows,
+    never one (a single-token sweep's is told by that)."""
+    B, Sq, HD = q.shape
+    Smax, W = k.shape[2], k.shape[3]
+    D = W // Hkv
+    G = HD // W
+    kw, qw = row_chunk_lanes(Hkv, G, D)
+    P = row_chunk_blocks(W, kw, qw, G, block_q, block_k, k.dtype.itemsize)
+    heads = kw // D
+    windowed = window is not None
+    kernel = functools.partial(
+        _row_chunk_kernel, sm_scale=sm_scale, block_q=block_q,
+        block_k=block_k, G=G, D=D, kw=kw, P=P, windowed=windowed)
+
+    def kv_idx(b, lp, qi, ki, pos_ref, rows_ref, layer_ref, *more):
+        lo, hi = _chunk_live_range(
+            pos_ref[b] + qi * block_q, block_q, block_k,
+            more[0][0] if windowed else None, None)
+        return (layer_ref[0], rows_ref[b], jnp.clip(ki, lo, hi), lp)
+
+    q_spec = pl.BlockSpec((None, block_q, P * qw),
+                          lambda b, lp, qi, ki, *_: (b, qi, lp))
+    kv_spec = pl.BlockSpec((None, None, block_k, P * kw), kv_idx)
+    rows_n = G * block_q
+
+    def as_b(x):
+        return jnp.broadcast_to(jnp.asarray(x, jnp.int32).reshape(-1), (B,))
+
+    prefetch = (as_b(pos), as_b(rows),
+                jnp.asarray(layer, jnp.int32).reshape(1)) + (
+        (jnp.asarray(window, jnp.int32).reshape(1),) if windowed else ())
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, W // (P * kw), Sq // block_q, Smax // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((P, rows_n, kw), jnp.float32),
+            pltpu.VMEM((P * heads, rows_n, 1), jnp.float32),
+            pltpu.VMEM((P * heads, rows_n, 1), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(kernel, grid_spec=grid_spec,
+                          out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+                          interpret=interpret_mode(),
+                          name="chunk_attention")(*prefetch, q, k, v)
+
+
 # ----------------------------------------------------------- grouped heads
 
 def _gqa_decode_kernel(rows_ref, blocks_ref, n_ref, pos_ref, layer_ref,
@@ -1484,7 +1716,7 @@ def cached_attention(q, cache_k, cache_v, pos,
                      window=None, slopes=None, layer=None,
                      active=None, sweep=None, latent_rank=None,
                      kv_heads: Optional[int] = None, valid_from=None,
-                     latent_up: Optional[LatentUp] = None):
+                     latent_up: Optional[LatentUp] = None, row=None):
     """q [B,Sq,H,D] over a padded cache [B,Smax,H,D], visibility ≤ pos+i.
 
     ``kv_heads`` (default ``H``): grouped heads.  The cache holds
@@ -1512,6 +1744,15 @@ def cached_attention(q, cache_k, cache_v, pos,
     prefetch beside ``pos``; no layer is sliced out and nothing is
     transposed to feed the kernel.  A per-layer [B,Smax,H,D] cache is the
     same call on a stack of one.
+
+    With ``row`` (scalar or [B], may be traced; a chunk's call on a stacked
+    two-bank pool only) query row ``b`` reads row ``row[b]`` of the pool,
+    which may hold any number of them: an admission's chunks over the slot
+    they are written to.  Where the heads allow (``row_chunk_lanes``) and
+    the call brings no scales, slopes or ``valid_from``, the chunk kernel
+    addresses ``(layer, row)`` of the folded banks through its index maps
+    (``_row_chunk``) and takes the queries folded too; any other such call
+    slices its rows out of the layer and goes the way of a call without.
 
     ``pos``: scalar, or a per-row [B] vector for ragged decode.
     Single-token decode (Sq=1) takes the Pallas streaming kernel, whose
@@ -1570,6 +1811,10 @@ def cached_attention(q, cache_k, cache_v, pos,
         raise NotImplementedError(
             "valid_from bounds a chunk's call; a single token's frontier "
             "is its pos")
+    if row is not None and (Sq == 1 or layer is None):
+        raise NotImplementedError(
+            "row= names a chunk's rows of a stacked pool; a single token "
+            "sweeps every row by its work list")
     banks = (cache_k, cache_v) + ((k_scale, v_scale) if int8_cache else ())
     if layer is None:
         # [B,Smax,H,*] → a pool of one layer, heads folded into the row
@@ -1608,10 +1853,22 @@ def cached_attention(q, cache_k, cache_v, pos,
                         ks=ks, vs=vs, window=window, slopes=slopes)
         return dead_rows_zero(o.reshape(B, 1, H, D))
 
+    tiles = use_pallas() and key_block is not None and block_q is not None
+    if row is not None:
+        if tiles and not int8_cache and slopes is None and valid_from is \
+                None and row_chunk_lanes(Hkv, G, D) is not None:
+            return _row_chunk(q.reshape(B, Sq, H * D), banks[0], banks[1],
+                              layer, row, pos, scale, block_q, key_block,
+                              Hkv, window=window).reshape(q.shape)
+        rows = jnp.broadcast_to(jnp.asarray(row, jnp.int32).reshape(-1), (B,))
+        banks = [jax.vmap(lambda r, x=x: jax.lax.dynamic_slice(
+            x, (layer, r, 0, 0), (1, 1) + x.shape[2:])[0, 0])(rows)[None]
+            for x in banks]
+        layer = 0
     # one layer, heads unfolded: [B,Smax,Hkv,D] (scales [B,Smax,H,1])
     banks = [jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
              .reshape(B, Smax, Hkv, -1) for x in banks]
-    if use_pallas() and key_block is not None and block_q is not None:
+    if tiles:
         def to3(x):
             return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2],
                                                    x.shape[1], -1)

@@ -16,7 +16,11 @@ on a request:
   and a 700-token one into slot 7 share one program (a second name
   continues a pooled prefix; :meth:`SlotBatcher.build_prefix` and the
   fleet's prefill worker, which need a batch-1 cache back, run the same
-  chunks a launch each);
+  chunks a launch each).  Where the pool is banks alone and the family
+  reads a row of it where it lies (``gpt_inference.in_place``: the dense
+  and the GPT-MoE family's bf16 pool) there is no batch-1 cache and no
+  insertion: the chunks are written to, and attend over, the slot's own
+  row of the donated pool;
 - each decode **tick** advances every slot one token through the family's
   ragged ``decode_step`` (per-slot frontiers, per-slot RNG keys, per-slot
   greedy/temperature — all traced operands of one compiled program).
@@ -58,6 +62,7 @@ from ..inference.bucketing import bucket_cache_len, bucket_draft_k
 from ..inference.sampling import filter_logits
 from ..inference.speculative import (spec_accept_batch, spec_accept_keys,
                                      spec_draft_keys)
+from ..models import gpt_inference
 from ..telemetry.spans import SpanName, Tracer
 from ..utils.compile_watch import CompiledProgramRegistry, hot_path
 from .config import ServingConfig
@@ -204,11 +209,24 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         start each in most cells with a chunk of 512 or 1,024, 15 s in
         one: PERF.md 6, PR 62), so a fresh row starts empty and its first pass is the
         ``extend`` at position 0 like the rest: a body a width, and none
-        of ``prefill``."""
+        of ``prefill``.
+
+        IN PLACE (``gpt_inference.in_place``: no ``prefix``, a pool of banks
+        alone, a family that reads a row where it lies) there is no row
+        cache at all: the loops' carry is the donated pool and every pass
+        the ``extend`` on ``(row, start + at)`` of it, the first from
+        position 0 (so none of ``prefill`` here either, and the wide
+        passes start with the prompt), no zero-fill before and no slot
+        write after.  The cells past the frontier keep what the row's last
+        tenant left: every reader masks by the row's length (the tick's
+        sweep, the chunk kernel's ``pos``), as it masks a padded pass's
+        rows."""
         C = tokens.shape[1]
         row, start, n = meta[0], meta[1], meta[2]
         widths = pass_widths(C, max_len)
         narrow = tuple(w for w in widths if w < C)
+        in_place = prefix is None and gpt_inference.in_place(fam, pool)
+        at_row = {"row": row} if in_place else {}
         # the last pass's width among ``narrow`` (0: none of them), and the
         # chunks that run at ``C`` and wider
         which = last_pass(n, C, widths)
@@ -219,8 +237,8 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         # zero-fill, ``admit_chunk`` a pass of any width (the family's own
         # scopes below it: ``admit_chunk/head`` is the head over all its
         # positions), ``admit_head`` the one row taken of it,
-        # ``admit_slot_write`` the row's copy into the pool, ``admit_bind``
-        # the slot's vectors
+        # ``admit_slot_write`` the row's copy into the pool (neither it nor
+        # ``admit_row_cache`` in place), ``admit_bind`` the slot's vectors
 
         def real(at, w):
             # where the prompt ends inside the pass of ``w`` tokens at
@@ -246,7 +264,7 @@ def admission(fam, cfg, max_len: int, kv_dtype):
                         params,
                         lax.dynamic_slice(tokens.reshape(-1), (at,), (w,))[
                             None], cfg, carry[1], lengths=(start + at)[None],
-                        valid=real(at, w))
+                        valid=real(at, w), **at_row)
                 return take(lg, at), cache
             return one
 
@@ -254,6 +272,8 @@ def admission(fam, cfg, max_len: int, kv_dtype):
             done, carry = 0, (
                 jnp.zeros(last.shape[1:], last.dtype),
                 dataclasses.replace(prefix, length=start))
+        elif in_place:
+            done, carry = 0, (jnp.zeros(last.shape[1:], last.dtype), pool)
         else:
             with jax.named_scope("admit_row_cache"):
                 fresh = fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype)
@@ -283,8 +303,11 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         with jax.named_scope("admit_bind"):
             key = jnp.where(meta[5] != 0, jax.random.fold_in(
                 key, lax.bitcast_convert_type(meta[6], jnp.uint32)), key)
-        with jax.named_scope("admit_slot_write"):
-            pool = fam.write_slot(pool, row, cache)
+        if in_place:
+            pool = cache
+        else:
+            with jax.named_scope("admit_slot_write"):
+                pool = fam.write_slot(pool, row, cache)
         with jax.named_scope("admit_bind"):
             return (pool,
                     lengths.at[row].set(start + n), last.at[row].set(vec),
@@ -337,6 +360,9 @@ class SlotBatcher:
         #: bytes of the batch-1 cache every fresh prefill allocates: the
         #: family's row, whatever its banks
         self._row_cache_bytes = cache_bank_bytes(self.cache) // B
+        #: whether an admission with no prefix works on the slot's own row
+        #: of the pool (no row cache, no slot write): the program's own rule
+        self._in_place = gpt_inference.in_place(fam, self.cache)
         #: the plan of the family's single-token sweep over this pool (its
         #: kernel, block, copy boundary and calls a tick): what
         #: ``sweep_blocks`` and ``sweep_by_kind`` count by
@@ -435,7 +461,7 @@ class SlotBatcher:
         (random-init dense GPT over the target's vocabulary — the bench
         fixture path).  The draft must be dense GPT: its whole point is
         being small, and the proposal loop rides ``gpt_inference``."""
-        from ..models import gpt, gpt_inference
+        from ..models import gpt
         from ..models.gpt_moe import GPTMoEConfig
         from ..runtime.config import DeepSpeedConfigError
         cfg = self._cfg
@@ -792,7 +818,7 @@ class SlotBatcher:
         with self.tracer.span(SpanName.SERVE_PREFILL, tokens=S,
                               start=start_len, chunk=C, padded=n_chunks * C,
                               chunks=n_chunks, passes=n_chunks, wide=0,
-                              narrow=0):
+                              narrow=0, in_place=0):
             chunks = self._padded_chunks(tokens, C, n_chunks)
             if start_cache is not None:
                 cache = start_cache
@@ -877,17 +903,20 @@ class SlotBatcher:
                          fold is not None,
                          np.uint32(fold or 0).view(np.int32)], np.int32)
         # a fresh row's first chunk is the family's ``prefill`` where the
-        # ladder stops at the chunk
+        # ladder stops at the chunk and the row is a cache of its own
         widths = pass_widths(C, self.max_len)
+        in_place = prefix is None and self._in_place
         passes, wide, narrow = ladder_passes(
             S, C, widths,
-            first=0 if prefix is not None or widths[-1] < C else 1)
+            first=0 if prefix is not None or widths[-1] < C or in_place
+            else 1)
         # what the launch computes, for its host span and its device span:
         # ``padded`` the rows its passes run, a narrow last pass's for the
-        # last chunk's
+        # last chunk's; ``in_place``: on the slot's own row of the pool
         work = dict(tokens=S, chunk=C,
                     padded=n_chunks * C - (C - narrow if narrow else 0),
-                    passes=passes, wide=wide, narrow=narrow)
+                    passes=passes, wide=wide, narrow=narrow,
+                    in_place=int(in_place))
         if self._fam.chunk_form is not None:
             # which of its forms the program's passes take at this chunk
             work["form"] = self._fam.chunk_form(self._cfg, C)

@@ -15,6 +15,16 @@ keys a block; a slot the keys do not tile takes the next size down).
 ``--parent DIR``: also time ``DIR``'s ``decode_attention.py`` (another
 checkout's kernel) on the same inputs and compare the results.
 
+``--in-place`` (PR 63; the cells of ``CELLS``): a chunk's call as an
+admission's layer makes it, on the STACKED banks: layer 1 of a batch-1 row
+cache ``[2, 1, Smax, Hkv*D]`` (the layer sliced out and re-laid heads-major
+around the kernel: the row-cache path) against ``(layer 1, row 2)`` of a pool
+``[2, 4, Smax, Hkv*D]`` read where it lies (``cached_attention(row=)``), with
+``--lanes 1,2,8``: the lane blocks a step of ``_row_chunk`` takes, and
+``--keys 512,256``: the keys of its blocks (0: the tree's rule).  Beside
+the kernel's own time each line gives the program's whole device time a
+call: what the relay around the kernel costs is their difference.
+
 The LATENT cells (``docqa``, ``reason``, ``think``, ``longgen``: ``LATENT``)
 time the latent chunk kernel's one custom call at a sublayer's shapes over a
 prompt's chunk positions: un-absorbed queries and the layer's ``W_kvb``
@@ -79,6 +89,8 @@ CELLS = {
     "rag": ((1, 512, 32, 8, 128), _calls(512, 5120, (0, 512, 1024))),
     # gpt2-medium, both serving cells: ungrouped heads of 64
     "gpt2m": ((1, 128, 16, 16, 64), _calls(128, 1024, (0, 128))),
+    # ... and its ladder's wide pass over a document's positions
+    "gpt2m-wide": ((1, 256, 16, 16, 64), _calls(256, 1024, (0, 256, 512))),
     # a verify's few positions under grouped heads: 8-row tiles of bf16
     "verify": ((1, 8, 32, 4, 128), _calls(8, 2048, (1024, 1531))),
 }
@@ -196,9 +208,27 @@ def _program(mod, geo, call):
     return fn, (q, k, v, jnp.full((B,), call["pos"], jnp.int32), first)
 
 
+def _stacked_program(geo, call, row):
+    """A layer's chunk call on stacked banks: ``row`` None: layer 1 of a
+    batch-1 row cache; else ``(layer 1, row)`` of a pool of four rows."""
+    B, Sq, H, Hkv, D = geo
+    keys = jax.random.split(jax.random.PRNGKey(call["Smax"] + call["pos"]), 3)
+    q = jax.random.normal(keys[0], (B, Sq, H, D), jnp.bfloat16)
+    # every row of the pool holds the row cache's row: one result to compare
+    k, v = (jnp.tile(jax.random.normal(key, (2, 1, call["Smax"], Hkv * D),
+                                       jnp.bfloat16),
+                     (1, 1 if row is None else 4, 1, 1)) for key in keys[1:])
+    at = {} if row is None else {"row": jnp.int32(row)}
+    fn = jax.jit(lambda q, k, v, pos: da.cached_attention(
+        q, k, v, pos, kv_heads=Hkv, layer=jnp.int32(1), **at))
+    return fn, (q, k, v, jnp.full((B,), call["pos"], jnp.int32))
+
+
 def _kernel_ms(programs):
-    """Median device milliseconds of each program's one Pallas call."""
-    from benchmarks.chip.trace.reduce import read_device_ops
+    """Median device milliseconds of each program's one Pallas call, the
+    call's result, and the mean milliseconds of ALL a program's device ops a
+    call (the relay around the kernel with it)."""
+    from benchmarks.chip.trace.reduce import _OPS_LINE, read_device_ops
     with tempfile.TemporaryDirectory() as logdir:
         jax.profiler.start_trace(logdir)
         for fn, args in programs:
@@ -208,11 +238,49 @@ def _kernel_ms(programs):
         jax.profiler.stop_trace()
         path = glob.glob(os.path.join(
             logdir, "plugins/profile/*/*.xplane.pb"))[-1]
-        ops = sorted((o for o in read_device_ops(path) if o.is_kernel),
-                     key=lambda o: o.start)
+        every = sorted((o for o in read_device_ops(path)
+                        if o.line == _OPS_LINE), key=lambda o: o.start)
+    ops = [o for o in every if o.is_kernel]
     assert len(ops) == REPS * len(programs), len(ops)
-    return [1e3 * float(np.median([o.dur for o in ops[i:i + REPS]]))
-            for i in range(0, len(ops), REPS)], ops[0].shape
+    ms = [1e3 * float(np.median([o.dur for o in ops[i:i + REPS]]))
+          for i in range(0, len(ops), REPS)]
+    # a program's ops: those since the kernel call before its first
+    edges = [0.0] + [ops[i - 1].end for i in range(REPS, len(ops), REPS)] \
+        + [float("inf")]
+    total = [1e3 * sum(o.dur for o in every if lo <= o.start < hi) / REPS
+             for lo, hi in zip(edges, edges[1:])]
+    return ms, ops[0].shape, total
+
+
+def _in_place(cells, lanes, keys):
+    """``--in-place``: see the module's docstring."""
+    rule, rule_k = da.row_chunk_blocks, da.chunk_block_k
+    for cell in cells:
+        geo, calls = CELLS[cell]
+        want = {}
+        for n, k in [(None, None)] + [(n, k) for n in lanes for k in keys]:
+            name = "row_cache" if n is None else f"pool_lanes{n}_keys{k}"
+            da.row_chunk_blocks = rule if not n else (
+                lambda lanes, kw, *a, n=n: min(n, lanes // kw))
+            da.chunk_block_k = rule_k if not k else (lambda Smax, k=k: k)
+            programs = [_stacked_program(geo, call, None if n is None else 2)
+                        for _, call in calls if "window" not in call]
+            worst = 0.0
+            for i, (fn, a) in enumerate(programs):
+                got = np.asarray(fn(*a), np.float32)
+                worst = max(worst, float(np.abs(
+                    got - want.setdefault(i, got)).max()))
+            ms, shape, total = _kernel_ms(programs)
+            print(json.dumps({
+                "cell": cell, "path": name, "result": shape,
+                "max_err": round(worst, 5),
+                "kernel_ms_a_call": round(float(np.mean(ms)), 4),
+                "program_ms_a_call": round(float(np.mean(total)), 4),
+                "calls": [[c["Smax"], c["pos"], round(t, 4), round(w, 4)]
+                          for (_, c), t, w in zip(
+                              [x for x in calls if "window" not in x[1]],
+                              ms, total)]}), flush=True)
+    da.row_chunk_blocks, da.chunk_block_k = rule, rule_k
 
 
 def main():
@@ -222,8 +290,16 @@ def main():
         list(CELLS) + list(LATENT) + list(KINDS)))
     ap.add_argument("--tiles", default="rule")
     ap.add_argument("--parent")
+    ap.add_argument("--in-place", action="store_true")
+    ap.add_argument("--lanes", default="1,2,8")
+    ap.add_argument("--keys", default="0", help="key blocks of the pool's "
+                    "call (0: the tree's rule)")
     args = ap.parse_args()
     require_tpu()
+    if args.in_place:
+        return _in_place(args.cells.split(","),
+                         [int(n) for n in args.lanes.split(",")],
+                         [int(n) for n in args.keys.split(",")])
     rule_k, rule_q = da.chunk_block_k, da.chunk_block_q
     variants = [("parent", _other_tree(args.parent))] if args.parent else []
     variants += [(tile, da) for tile in args.tiles.split(",")]
@@ -263,7 +339,7 @@ def main():
                 worst = max(worst, float(np.abs(
                     got - want.setdefault(i, got)).max()))
             far |= worst >= 0.05
-            ms, shape = _kernel_ms(programs)
+            ms, shape, _ = _kernel_ms(programs)
             weights = [w for w, _ in calls]
             print(json.dumps({
                 "cell": cell, "tile": name, "result": shape,

@@ -59,11 +59,13 @@ _SERVING_TICK_FIELDS = ("overlap_share", "late_row_share",
 
 
 def device_span_table(trace_events) -> dict:
-    """``program -> {n, p50_ms, p95_ms, us_per_padded_token, share}`` of a
-    trace's ``serve.device`` events (``docs/telemetry.md`` "Device spans":
-    each admission's and each tick's launch as the device ran it): launches,
-    the median and the 95th percentile of their device time, of admissions
-    the device time a computed prompt token (``padded``), and the program's
+    """``program -> {n, p50_ms, p95_ms, us_per_padded_token, in_place,
+    share}`` of a trace's ``serve.device`` events (``docs/telemetry.md``
+    "Device spans": each admission's and each tick's launch as the device
+    ran it): launches, the median and the 95th percentile of their device
+    time, of admissions the device time a computed prompt token
+    (``padded``) and the share of them that worked on the slot's own row of
+    the pool (``in_place``; None where no span says), and the program's
     share of the run (the first such span's start to the last one's end).
     Empty where the trace holds none (a tracer that was off, an older
     program)."""
@@ -83,12 +85,15 @@ def device_span_table(trace_events) -> dict:
     for program, evs in sorted(by_program.items()):
         durs = sorted(e["dur"] for e in evs)
         padded = sum((e.get("args") or {}).get("padded", 0) for e in evs)
+        said = [(e.get("args") or {})["in_place"] for e in evs
+                if "in_place" in (e.get("args") or {})]
         table[program] = {
             "n": len(evs),
             "p50_ms": rank(durs, 0.5) / 1e3,
             "p95_ms": rank(durs, 0.95) / 1e3,
             "us_per_padded_token": round(sum(durs) / padded, 3)
             if padded else None,
+            "in_place": round(sum(said) / len(said), 4) if said else None,
             "share": round(sum(durs) / max(run_us, 1), 4)}
     return table
 
@@ -253,12 +258,14 @@ def report(args) -> int:
                 # the serving device spans: what each admission's and each
                 # tick's launch took ON THE DEVICE (serve.device)
                 print("    serving, device time by program (serve.device): "
-                      "launches, p50 ms, p95 ms, us a padded token, share "
-                      "of the run")
+                      "launches, p50 ms, p95 ms, us a padded token, on the "
+                      "slot's own row, share of the run")
                 for program, r in device.items():
+                    in_place = "-" if r["in_place"] is None \
+                        else f"{100 * r['in_place']:.0f}%"
                     print(f"      {program}: {r['n']}, {r['p50_ms']:.3f}, "
                           f"{r['p95_ms']:.3f}, "
-                          f"{r['us_per_padded_token'] or '-'}, "
+                          f"{r['us_per_padded_token'] or '-'}, {in_place}, "
                           f"{100 * r['share']:.2f}%")
         for p in problems:
             print(f"  PROBLEM: {p}", file=sys.stderr)

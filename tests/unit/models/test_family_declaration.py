@@ -91,6 +91,12 @@ def test_cache_family_returns_the_whole_declaration(name):
             # selecting one's under their biases too)
             assert (value is not None) == (name in (
                 "latent", "linear", "shortcut", "selected"))
+        elif field.name == "pool_rows":
+            # the families whose attention reads a row of a pool where it
+            # lies (``attend_cached(row=)``): the dense block's own hook
+            assert value == (name in ("dense", "moe"))
+            assert value == (
+                fam.attend_cached is gpt_inference._dense_attend_cached)
         elif field.name in ("unsupported", "state_counters",
                             "select_counters"):
             assert value is not None

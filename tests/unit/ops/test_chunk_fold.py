@@ -224,3 +224,105 @@ def test_a_tiles_live_range_is_the_masks(call):
                                       window, first)
         assert list(range(int(lo), min(int(hi), Smax // block_k - 1) + 1)) \
             == live
+
+
+# ------------------------------------- a chunk over a row of the pool (PR 63)
+
+@functools.partial(jax.jit, static_argnames=("Hkv", "windowed"))
+def _row_pair(q, k, v, pos, rows, window, Hkv, windowed):
+    """A chunk over rows of layer 1 of a pool of two layers: the call with
+    ``row=`` beside the reference over those rows sliced out."""
+    B, G = q.shape[0], q.shape[2] // Hkv
+    window = window if windowed else None
+    at = jnp.broadcast_to(rows, (B,))
+    own = [x[1][at].reshape(B, x.shape[2], Hkv, -1) for x in (k, v)]
+    return (cached_attention(q, k, v, pos, layer=jnp.int32(1), row=rows,
+                             kv_heads=Hkv, window=window),
+            cached_attention_reference(q, _grouped(own[0], G),
+                                       _grouped(own[1], G), pos,
+                                       window=window))
+
+
+@pytest.mark.parametrize("band", [None, 200, 1100], ids=[
+    "whole_row", "band_under_a_block", "band_over_a_block"])
+@pytest.mark.parametrize("pos", ["scalar", "per_row"])
+@pytest.mark.parametrize("Hkv,G,D,sq", [(16, 1, 64, 256), (16, 1, 64, 128),
+                                        (4, 8, 128, 128)],
+                         ids=["H16_D64", "H16_D64_a_chunk", "Hkv4_D128_G8"])
+def test_a_chunk_over_a_row_of_the_pool_where_it_lies(pallas_interpret, Hkv,
+                                                      G, D, sq, pos, band):
+    """``cached_attention(row=)`` through ``_row_chunk``: the folded banks
+    ``[L, slots, S, Hkv * D]`` addressed ``(layer, row)`` by the index maps
+    and the queries folded too, against the reference over the same rows
+    sliced out: two ungrouped heads of 64 in a lane block of 128
+    (``gpt2-medium``'s 16), and a key-value head of 128 with its group of 8
+    (the grouped families' full layers), at a frontier one key past a
+    block's edge and, per row, inside another block; under no band, a band
+    shorter and one longer than a key block.  The rows are not in order
+    and one is the pool's last: a call that read row ``b`` would fail."""
+    Smax, slots = 2048, 5
+    per_row = pos == "per_row"
+    B = 2 if per_row else 1
+    keys = jax.random.split(jax.random.PRNGKey(Hkv + sq), 3)
+    q = _rows(keys[0], B, sq, Hkv * G, D)
+    k, v = (_rows(key, 2, slots, Smax, Hkv * D) for key in keys[1:])
+    assert da.row_chunk_lanes(Hkv, G, D) == ((128, 128) if D == 64
+                                             else (128, 1024))
+    rows = jnp.asarray([4, 1], jnp.int32) if per_row else jnp.int32(3)
+    at = jnp.asarray([1025, 725], jnp.int32) if per_row else jnp.int32(1025)
+    _close(*_row_pair(q, k, v, at, rows, jnp.int32(band or 0), Hkv,
+                      band is not None))
+
+
+def test_what_the_row_kernel_does_not_serve_reads_its_row_out(
+        pallas_interpret, monkeypatch):
+    """A call with ``row=`` that the row kernel does not serve goes the way
+    of a call without, over its rows sliced out of the layer: an int8
+    cache's codes and scales, ALiBi's slopes, grouped heads of 64 (their
+    queries would want a lane shift); and ``ring_attention`` (its
+    ``valid_from``, a pool of its own unrolling) never names a row.  Each
+    still runs the heads-major kernel (``_chunk``) and agrees with the
+    reference."""
+    calls = []
+    chunk, row_chunk = da._chunk, da._row_chunk
+    monkeypatch.setattr(da, "_chunk", lambda *a, **kw: (
+        calls.append("heads_major"), chunk(*a, **kw))[1])
+    monkeypatch.setattr(da, "_row_chunk", lambda *a, **kw: (
+        calls.append("row"), row_chunk(*a, **kw))[1])
+    Smax, slots, sq = 512, 3, 128
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    pos, row = jnp.asarray([130], jnp.int32), jnp.int32(2)
+
+    def pool(Hkv, D):
+        return (_rows(key, 2, slots, Smax, Hkv * D) for key in keys[1:])
+
+    # grouped heads of 64
+    q = _rows(keys[0], 1, sq, 8, 64)
+    k, v = pool(2, 64)
+    assert da.row_chunk_lanes(2, 4, 64) is None
+    got = cached_attention(q, k, v, pos, layer=1, row=row, kv_heads=2)
+    own = [x[1, 2][None].reshape(1, Smax, 2, 64) for x in (k, v)]
+    _close(got, cached_attention_reference(
+        q, _grouped(own[0], 4), _grouped(own[1], 4), pos))
+    # ALiBi
+    q = _rows(keys[0], 1, sq, 4, 64)
+    k, v = pool(4, 64)
+    slopes = jnp.asarray([0.5, 0.25, 0.125, 0.0625], jnp.float32)
+    got = cached_attention(q, k, v, pos, layer=1, row=row, slopes=slopes)
+    own = [x[1, 2][None].reshape(1, Smax, 4, 64) for x in (k, v)]
+    _close(got, cached_attention_reference(q, *own, pos, slopes=slopes))
+    # the int8 cache
+    codes = [da.quantize_kv(x.reshape(2, slots, Smax, 4, 64)) for x in (k, v)]
+    got = cached_attention(
+        q, *(c.reshape(2, slots, Smax, -1) for c, _ in codes), pos, layer=1,
+        row=row, k_scale=codes[0][1][..., 0], v_scale=codes[1][1][..., 0])
+    own = [da.dequantize_kv(c, s, jnp.float32)[1, 2][None] for c, s in codes]
+    _close(got, cached_attention_reference(q, *own, pos))
+    assert calls == ["heads_major"] * 3
+    # a plain call with a row is the row kernel's; a ring's is not
+    cached_attention(q, k, v, pos, layer=1, row=row)
+    _ring(1, 64, np.asarray([700, 700]), seed=1, window=256, sq=128)
+    assert calls[3:] == ["row", "heads_major"]
+    # and one token names no row: it sweeps every row by its work list
+    with pytest.raises(NotImplementedError):
+        cached_attention(q[:, :1], k, v, pos, layer=1, row=row)

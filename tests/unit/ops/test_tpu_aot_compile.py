@@ -848,9 +848,11 @@ def _one_call_a_layer_a_width(hlo_text, widths, chunk):
     """Every Mosaic kernel of an admission but the experts' grouped product
     (whose rows are pairs, not positions) is called as often at every
     width of the ladder: an ``extend`` body a width, a chunk kernel's or a
-    scan's call a layer of it (GPT-2's ladder stops at the chunk, so its
-    program holds the ``prefill`` too: a kernel that only a ``prefill``
-    runs, its flash forward, at every width no wider than the chunk).  The
+    scan's call a layer of it (where a ladder stops at the chunk and the row
+    is a cache of its own, the program holds the ``prefill`` too: a kernel
+    that only a ``prefill`` runs, its flash forward, at every width no wider
+    than the chunk; GPT-2's bf16 admission works on the slot's own row and
+    holds none: PR 63).  The
     width is read off the call's first result, the one dimension of it
     that is a width of the ladder."""
     import collections
@@ -1006,16 +1008,141 @@ def _an_admissions_chunks_write_its_row_by_update_slices(
         _ROW_BANK_COPIES.get((family, int8), 0)
 
 
+#: ``(family, int8) -> (kernel calls, planned bytes)`` of the admissions
+#: that keep the batch-1 row cache, as the PARENT of PR 63 compiled them at
+#: these geometries (compiler, PR 63: ``git archive aa89bde``, the same
+#: helpers): every Mosaic call by name and result, and what the program
+#: holds at once.  PR 63 gave the dense and GPT-MoE families' bf16 admission
+#: a path of its own and left these the program they had, instruction for
+#: instruction (the normalised text of both trees' programs was compared
+#: once, by hand: ``PERF.md`` 6, PR 63); what a later PR can hold them to
+#: without the parent's tree is this.
+_ROW_CACHE_ADMISSIONS = {
+    ("dense", True): (
+        {("chunk_attention", "bf16[16,1,128,64]"): 1,
+         ("chunk_attention", "bf16[16,1,256,64]"): 1},
+        516793344),
+    ("moe", True): (
+        {("chunk_attention", "bf16[16,1,128,64]"): 2,
+         ("chunk_attention", "bf16[16,1,256,64]"): 2},
+        574775808),
+    ("latent", False): (
+        {("gmm", "bf16[128,4096]"): 2,
+         ("gmm", "bf16[128,7168]"): 2,
+         ("latent_chunk_attention_up", "bf16[64,512,128]"): 4},
+        6557924864),
+    ("hybrid", False): (
+        {("chunk_attention", "bf16[8,4,512,128]"): 2,
+         ("gmm", "bf16[2560,1536]"): 6,
+         ("gmm", "bf16[2560,4096]"): 6},
+        13697161728),
+    ("single_part", False): (
+        {("chunk_attention", "bf16[2,16,1024,128]"): 4,
+         ("gmm", "bf16[3072,1920]"): 8,
+         ("gmm", "bf16[3072,2688]"): 8},
+        13443703808),
+    ("window", False): (
+        {("chunk_attention", "bf16[4,8,1024,128]"): 8,
+         ("gmm", "bf16[4096,1792]"): 8,
+         ("gmm", "bf16[4096,2304]"): 8},
+        15069265408),
+    ("linear", False): (
+        {("gmm", "bf16[2048,2048]"): 8,
+         ("gmm", "bf16[2048,2304]"): 8,
+         ("latent_chunk_attention_up", "bf16[32,1024,128]"): 4},
+        13155078144),
+    ("shortcut", False): (
+        {("gmm", "bf16[256,4096]"): 2,
+         ("gmm", "bf16[256,6144]"): 2,
+         ("latent_chunk_attention_up", "bf16[64,512,128]"): 4},
+        14834354176),
+    ("conv", False): (
+        {("chunk_attention", "bf16[8,4,1024,64]"): 1,
+         ("chunk_attention", "bf16[8,4,512,64]"): 1,
+         ("gmm", "bf16[2048,2048]"): 4,
+         ("gmm", "bf16[2048,3584]"): 4,
+         ("gmm", "bf16[4096,2048]"): 4,
+         ("gmm", "bf16[4096,3584]"): 4},
+        14598098944),
+}
+
+
+def _kernel_calls(hlo_text):
+    """``{(kernel, result): calls}`` of a compiled module's Mosaic calls."""
+    import collections
+    return dict(collections.Counter(_custom_calls(hlo_text)))
+
+
+def _an_admission_takes_the_path_its_cache_allows(admission_of, family,
+                                                  int8):
+    """Which of its two paths a family's admission compiled to
+    (``gpt_inference.in_place``, decided by what the cache holds).
+
+    ON THE SLOT'S OWN ROW (the dense and GPT-MoE families' bf16 pool): no
+    row cache is made and none is written to the slot, so nothing in the
+    whole program is as large as a bank of a batch-1 row: no ``broadcast``
+    (the zero-fill), no ``copy``, ``dynamic-slice`` or ``transpose`` (the
+    layer sliced out and re-laid heads-major around the chunk kernel), no
+    ``scatter``; the chunk kernel is called once a layer of the body a
+    width, on the folded queries, with the result ``bf16[1, width, H*D]``
+    (rank 3 with a chunk's rows: one query row would read as a single-token
+    sweep, ``ROADMAP.md`` S0 g2), its fifth and sixth operands the pool's
+    banks whole; and the program holds no body of ``prefill`` (no flash
+    forward): every pass is the ``extend``.  That the pool is aliased and
+    nothing as large as a layer of it moves is ``one_program_in_place``'s.
+
+    THROUGH THE ROW CACHE (every other row of :data:`_SERVED`): the
+    program the parent compiled, by its kernel calls and its plan
+    (:data:`_ROW_CACHE_ADMISSIONS`), and it still writes its row cache to
+    the slot (the scope ``admit_slot_write``; the zero-fill's constants
+    carry no scope's name)."""
+    from deepspeed_tpu.models import cache_family
+    from deepspeed_tpu.models.gpt_inference import in_place
+    from deepspeed_tpu.serving.batcher import pass_widths
+    init, cfg, slots, smax, chunk = _served(family)
+    compiled, _, pool, row_cache = admission_of(family, int8, None)
+    text = compiled.as_text()
+    assert in_place(cache_family(cfg), pool) == (
+        family in ("dense", "moe") and not int8)
+    if (family, int8) in _ROW_CACHE_ADMISSIONS:
+        calls, planned = _ROW_CACHE_ADMISSIONS[family, int8]
+        assert _kernel_calls(text) == calls
+        assert _planned_bytes(compiled) <= planned
+        assert "/admit_slot_write/" in text
+        return
+    # ... read at the cell's own depth, where a bank of a row (25M elements)
+    # is the size of nothing else in the program
+    compiled, _, pool, row_cache = admission_of(family, int8, _LADDER_LAYERS)
+    text = compiled.as_text()
+    for opcode in ("broadcast", "copy", "dynamic-slice", "transpose",
+                   "scatter"):
+        assert not _row_bank_ops(text, row_cache, opcode), opcode
+    assert "/admit_slot_write/" not in text
+    HD = cfg.n_head * cfg.head_dim
+    layers = 1 if family == "dense" else 2      # of a scan's body
+    assert _kernel_calls(text) == {
+        **{("chunk_attention", f"bf16[1,{w},{HD}]"): layers
+           for w in pass_widths(chunk, smax)},
+        **{k: n for k, n in _kernel_calls(text).items() if k[0] == "gmm"}}
+    banks = re.findall(
+        r"%chunk_attention[.\d]* = [^\n]*operand_layout_constraints=\{"
+        r"(?:s32\[1\]\{0\}, ){3}bf16\[1,\d+,\d+\]\{2,1,0\}, "
+        rf"bf16\[(\d+),{slots},{smax},{HD}\]\{{3,2,1,0\}}, "
+        rf"bf16\[\1,{slots},{smax},{HD}\]\{{3,2,1,0\}}\}}", text)
+    assert len(banks) == layers * len(pass_widths(chunk, smax)), banks
+
+
 _ADMISSION_GUARDS = {
     "one_program_in_place": _admission_is_one_program_on_the_pool_in_place,
     "row_by_update_slices":
-        _an_admissions_chunks_write_its_row_by_update_slices}
+        _an_admissions_chunks_write_its_row_by_update_slices,
+    "the_path_it_takes": _an_admission_takes_the_path_its_cache_allows}
 
 
 @pytest.mark.parametrize("guard", list(_ADMISSION_GUARDS))
 @_SERVED
 def test_an_admission(admission_of, family, int8, guard):
-    """The two guards that read one compile of a family's admission
+    """The three guards that read one compile of a family's admission
     (``admission_of``, once a process), as cases of one function so that
     they run one after the other: apart, the scheduler hands them to two
     workers about every other run and each compiles the program (99 s the
@@ -1032,11 +1159,21 @@ def test_the_update_slices_plan_is_the_scatters(v5e, admission_of,
     ladder's program is served at (:data:`_LADDER_LAYERS`)."""
     from deepspeed_tpu.models import gpt_inference
     sliced = _planned_bytes(admission_of("dense", int8, _LADDER_LAYERS)[0])
-    monkeypatch.setattr(gpt_inference, "_chunk_slice",
-                        gpt_inference._chunk_scatter)
-    scattered, _, _, row_cache = _compile_admission(v5e, "dense", int8,
-                                                    _LADDER_LAYERS)
-    assert _row_bank_ops(scattered.as_text(), row_cache, "scatter")
+    def scatter(bank, layer, val, pos0, row=None):
+        # the parent's scatter; of one row of a pool where the bf16
+        # admission works on the slot's own (PR 63)
+        if row is None:
+            return gpt_inference._chunk_scatter(bank, layer, val, pos0)
+        return bank.at[layer, row, pos0[0] + jnp.arange(val.shape[1])].set(
+            val[0])
+
+    monkeypatch.setattr(gpt_inference, "_chunk_slice", scatter)
+    scattered, _, pool, row_cache = _compile_admission(v5e, "dense", int8,
+                                                       _LADDER_LAYERS)
+    # the bf16 admission works on the slot's own row: its scatter's result
+    # is a bank of the pool (PR 63); the int8 cache's a bank of its row
+    assert _row_bank_ops(scattered.as_text(), row_cache if int8 else pool,
+                         "scatter")
     assert sliced <= _planned_bytes(scattered) + (1 << 20)
 
 

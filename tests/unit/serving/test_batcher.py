@@ -1051,3 +1051,183 @@ def test_the_gateway_counts_one_launch_an_admission_and_a_prefix_build_more():
     assert snap["recompiles"] == 0
     counts = snap["compile_counts"]
     assert counts["admit"] == counts["admit_prefix"] == 1, counts
+
+
+# --------------------------------- an admission on the slot's own row (PR 63)
+
+#: slots of 38 cells in chunks of 8 (the last chunk passes the row's end),
+#: one wide width over the chunk and its half under it
+_ROW_SLOTS, _ROW_LEN = 3, 38
+
+
+def _row_and_pool_admissions(monkeypatch, cfg):
+    """``admit(pool, state, row, tokens) -> (pool, state, logits row)`` of
+    the dense family at ``cfg`` twice over, jitted: IN PLACE on the slot's
+    own row of the pool (the family as it is), and through the batch-1 row
+    cache (the same family with ``pool_rows`` off), the ladder patched so
+    that BOTH start from an empty row and run the same passes (16, 8 and 4
+    tokens wide), so what they leave can be held together bit for bit."""
+    from deepspeed_tpu.serving import batcher
+    monkeypatch.setattr(batcher, "WIDE_PASSES", (16,))
+    monkeypatch.setattr(batcher, "NARROW_FLOOR", 2)
+    monkeypatch.setattr(batcher, "NARROW_SLOT_CHUNKS", 8)
+    assert batcher.pass_widths(CHUNK, _ROW_LEN) == (16, 8, 4)
+    fam = gpt_inference.DENSE
+    apart = dataclasses.replace(fam, pool_rows=False)
+    params = gpt.init(cfg, jax.random.PRNGKey(0))
+
+    def admit_of(family):
+        fn = jax.jit(batcher.admission(family, cfg, _ROW_LEN, None))
+
+        def admit(pool, state, row, tokens):
+            chunks = np.zeros((-(-_ROW_LEN // CHUNK), CHUNK), np.int32)
+            chunks.reshape(-1)[:len(tokens)] = tokens
+            out = fn(params, pool, *state, chunks,
+                     np.array([row, 0, len(tokens), 1, 0, 0, 0], np.int32),
+                     jax.random.PRNGKey(len(tokens)))
+            return out[0], out[1:7], out[7]
+        return admit
+
+    def fresh():
+        return fam.init_cache(cfg, _ROW_SLOTS, _ROW_LEN), (
+            jnp.zeros((_ROW_SLOTS,), jnp.int32),
+            jnp.zeros((_ROW_SLOTS, cfg.padded_vocab), jnp.float32),
+            jnp.zeros((_ROW_SLOTS, 2), jnp.uint32),
+            jnp.ones((_ROW_SLOTS,), bool), jnp.ones((_ROW_SLOTS,)),
+            jnp.zeros((_ROW_SLOTS,), bool))
+
+    return fam, params, admit_of(fam), admit_of(apart), fresh
+
+
+def _same_bits(a, b, what):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=what)
+
+
+@pytest.mark.parametrize("first,then", [
+    (5, None), (8, None), (16, None), (32, None), (19, None), (27, None),
+    (37, None), (38, None), (38, 5), (33, 16), (30, 19)],
+    ids=["one_chunk", "a_whole_chunk", "a_wide_pass", "two_wide_passes",
+         "a_padded_narrow_pass", "a_padded_chunk", "the_clamp",
+         "the_rows_last_cell", "after_a_longer_tenant",
+         "a_wide_pass_after_a_longer", "a_narrow_pass_after_a_longer"])
+def test_an_admission_in_place_is_the_row_caches_bit_for_bit(
+        monkeypatch, first, then):
+    """The admission on the slot's own row of the pool against the
+    row-cache admission of the same tree (float32, the CPU): the slot's
+    cells up to its frontier, the frontier logits, lengths, keys and the
+    other binds, bit for bit, for prompts of one chunk, of whole wide
+    passes, of a padded last pass, of a last chunk that passes the row's
+    end (``_chunk_slice``'s clamp) and up to the row's last cell; and in a
+    row whose last tenant was LONGER: the stale cells past the new frontier
+    (the row cache's are zeros) are read by nobody, so one tick each gives
+    the same logits bit for bit too."""
+    fam, params, in_place, apart, fresh = _row_and_pool_admissions(
+        monkeypatch, CFG)
+    rng = np.random.default_rng(first)
+    row = first % _ROW_SLOTS
+    pools, states = zip(fresh(), fresh())
+    for n in (first,) + ((then,) if then else ()):
+        tokens = rng.integers(0, CFG.vocab_size, (n,)).astype(np.int32)
+        (pool_a, state_a, vec_a), (pool_b, state_b, vec_b) = (
+            admit(pool, state, row, tokens)
+            for admit, pool, state in zip((in_place, apart), pools, states))
+        pools, states = (pool_a, pool_b), (state_a, state_b)
+        _same_bits(vec_a, vec_b, "frontier logits")
+        for name, a, b in zip(("lengths", "last", "keys", "greedy", "temp",
+                               "active"), state_a, state_b):
+            _same_bits(a, b, name)
+        assert int(state_a[0][row]) == n
+        for bank in ("k", "v"):
+            _same_bits(getattr(pool_a, bank)[:, row, :n],
+                       getattr(pool_b, bank)[:, row, :n], bank)
+            # ... and no other row was touched
+            others = [r for r in range(_ROW_SLOTS) if r != row]
+            assert not np.asarray(getattr(pool_a, bank)[:, others]).any()
+    if then:
+        # the last tenant's cells are still there, past the frontier (and
+        # past the last pass's padding, which both paths write)
+        stale = slice(-(-then // CHUNK) * CHUNK, first)
+        assert np.asarray(pools[0].k[:, row, stale]).any()
+        assert not np.asarray(pools[1].k[:, row, stale]).any()
+    if n < _ROW_LEN:
+        nxt = jnp.argmax(states[0][1][:, :CFG.vocab_size], -1).astype(
+            jnp.int32)
+        ticks = [fam.decode_step(params, nxt, CFG, pool, lengths=state[0],
+                                 active=state[5])
+                 for pool, state in zip(pools, states)]
+        _same_bits(ticks[0][0][row], ticks[1][0][row], "a tick's logits")
+        _same_bits(ticks[0][1].k[:, row, :n + 1], ticks[1][1].k[:, row, :n + 1],
+                   "a tick's cell")
+
+
+def _pool_of(case):
+    """``(family, pool)`` that an admission must NOT take in place."""
+    from deepspeed_tpu.models import cache_family
+    if case == "int8":
+        return gpt_inference.DENSE, gpt_inference.DENSE.init_cache(
+            CFG, 2, SLOT, kv_dtype="int8")
+    if case == "another_attention":
+        return dataclasses.replace(gpt_inference.DENSE, pool_rows=False), \
+            gpt_inference.DENSE.init_cache(CFG, 2, SLOT)
+    cfg, _ = _tiny(_CELLS_FAMILIES[case])
+    fam = cache_family(cfg)
+    return fam, jax.eval_shape(lambda: fam.init_cache(cfg, 2, SLOT))
+
+
+#: what a cache holds beside two equal banks -> a configuration of the
+#: benchmark's that serves such a cache (all eight of its other families)
+_CELLS_FAMILIES = {"state": "granite-4.0-h-small-ep4",
+                   "state_single_part": "nemotron-3-nano-30b-a3b-ep4",
+                   "ring": "mellum2-12b-a2.5b-ep4",
+                   "latent": "kimi-k2.7-code-ep32",
+                   "latent_and_ring": "dots3-note-prev-ep32",
+                   "latent_and_state": "kimi-linear-48b-a3b-ep8",
+                   "latent_two_rows": "longcat-flash-chat-ep32",
+                   "conv_tail": "lfm2-8b-a1b"}
+
+
+@pytest.mark.parametrize("case", list(_CELLS_FAMILIES) + [
+    "int8", "another_attention", "prefix"])
+def test_what_a_row_cache_is_still_for(case):
+    """A cache with per-slot state, with rings, of one latent bank or with
+    an int8 cache's scale banks (every family of the benchmark but the
+    dense one: their cells' admissions say ``in_place`` 0), a family whose
+    attention does not read a row of a pool, and ANY admission that
+    continues a pooled prefix keep the batch-1 row cache:
+    ``gpt_inference.in_place`` says so from what the cache holds, the
+    traced admission makes its row cache (``admit_row_cache``) and writes
+    the slot (``admit_slot_write``), and the dense bf16 pool's plain
+    admission has neither scope."""
+    from deepspeed_tpu.serving.batcher import admission
+    fam, pool = (gpt_inference.DENSE, gpt_inference.DENSE.init_cache(
+        CFG, 2, SLOT)) if case == "prefix" else _pool_of(case)
+    assert gpt_inference.in_place(fam, pool) == (case == "prefix")
+    if case in _CELLS_FAMILIES:
+        # it is what the cache holds that says no, whatever the family says
+        assert pool.state is not None or pool.ring is not None \
+            or pool.v is None
+        assert not gpt_inference.in_place(gpt_inference.DENSE, pool)
+        return      # their admissions run in the families' own suites
+    assert pool.int8 == (case == "int8")
+    kv = "int8" if case == "int8" else None
+    params = jax.eval_shape(lambda: gpt.init(CFG, jax.random.PRNGKey(0)))
+    shape = jax.ShapeDtypeStruct
+    vectors = (shape((2,), jnp.int32), shape((2, CFG.padded_vocab),
+                                             jnp.float32),
+               shape((2, 2), jnp.uint32), shape((2,), bool),
+               shape((2,), jnp.float32), shape((2,), bool),
+               shape((SLOT // CHUNK, CHUNK), jnp.int32),
+               shape((7,), jnp.int32), shape((2,), jnp.uint32))
+    prefix = (jax.eval_shape(lambda: fam.init_cache(CFG, 1, SLOT)),) \
+        if case == "prefix" else ()
+
+    def scopes(*more):
+        text = jax.jit(admission(fam, CFG, SLOT, kv)).lower(
+            params, pool, *vectors, *more).as_text(debug_info=True)
+        return {s for s in ("admit_row_cache", "admit_slot_write")
+                if s in text}
+
+    assert scopes(*prefix) == {"admit_slot_write"} | (
+        set() if prefix else {"admit_row_cache"})
+    if case == "prefix":    # the same pool with no prefix: in place
+        assert scopes() == set()

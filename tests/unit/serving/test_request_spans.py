@@ -63,7 +63,8 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     # around the ONE launch that runs the chunks, writes the slot and binds
     # the row, with the arguments it always had and the passes the program
     # ran for them (in a slot of 64 none is wider than the chunk, and under
-    # the ridge none is narrower).  No
+    # the ridge none is narrower), on the slot's own row of the dense pool
+    # (``in_place``).  No
     # batch-1 cache is allocated by the host, no chunk is a launch of its
     # own and the slot write is no step of its own: their spans are not
     # entered
@@ -71,7 +72,7 @@ def test_one_request_leaves_its_spans_keyed_by_its_id(engine):
     assert _inside(prefill, admit)
     assert prefill.args == {"tokens": CHUNK + 1, "start": 0, "chunk": CHUNK,
                             "padded": 2 * CHUNK, "chunks": 2, "passes": 2,
-                            "wide": 0, "narrow": 0}
+                            "wide": 0, "narrow": 0, "in_place": 1}
     assert [s.name for s in spans if _inside(s, admit)] == ["serve.prefill"]
     for name in ("serve.cache_alloc", "serve.prefill_chunk",
                  "serve.slot_write"):
@@ -152,9 +153,11 @@ def test_the_prefill_span_counts_the_ladders_passes(engine, monkeypatch):
     program ran) and ``wide`` (the tokens of its passes wider than
     ``chunk``) beside the arguments it had, which keep their meaning: with
     the wide widths patched to 32 and 16 over chunks of 8, a prompt of 63
-    tokens is a first chunk, 32, 16 and a padded 8 where it was 8 chunks;
-    one that continues a pooled prefix starts with its wide passes; the
-    prefix's builder runs a launch a chunk, none wide."""
+    tokens is two passes of 32 where it was 8 chunks (on the slot's own row
+    of the pool the wide passes start with the prompt: no ``prefill`` takes
+    its first chunk), ``in_place`` 1; one that continues a pooled prefix
+    keeps the row cache (``in_place`` 0) and starts with its wide passes
+    too; the prefix's builder runs a launch a chunk, none wide."""
     from deepspeed_tpu.serving import batcher
     monkeypatch.setattr(batcher, "WIDE_PASSES", (32, 16))
     tracer = Tracer(name="serving")
@@ -169,11 +172,11 @@ def test_the_prefill_span_counts_the_ladders_passes(engine, monkeypatch):
     gw.shutdown()
     spans = [s.args for s in tracer.spans() if s.name == "serve.prefill"]
     assert [(a["tokens"], a["start"], a["chunk"], a["padded"], a["chunks"],
-             a["passes"], a["wide"]) for a in spans] == [
-        (9, 0, 8, 16, 2, 2, 0), (24, 0, 8, 24, 3, 2, 16),
-        (40, 0, 8, 40, 5, 2, 32), (63, 0, 8, 64, 8, 4, 48),
-        (11, 0, 8, 16, 2, 2, 0),        # the prefix, built chunk by chunk
-        (49, 11, 8, 56, 7, 3, 48)]      # 32, 16 and one token in a chunk
+             a["passes"], a["wide"], a["in_place"]) for a in spans] == [
+        (9, 0, 8, 16, 2, 1, 16, 1), (24, 0, 8, 24, 3, 2, 16, 1),
+        (40, 0, 8, 40, 5, 2, 32, 1), (63, 0, 8, 64, 8, 2, 64, 1),
+        (11, 0, 8, 16, 2, 2, 0, 0),     # the prefix, built chunk by chunk
+        (49, 11, 8, 56, 7, 3, 48, 0)]   # 32, 16 and one token in a chunk
     assert gw.snapshot()["recompiles"] == 0
 
 

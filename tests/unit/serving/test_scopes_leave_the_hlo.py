@@ -32,9 +32,14 @@ SCOPES = {
 #: the scopes each program's compiled text names (the CPU's tick has no
 #: kernel, so no work list: ``sweep`` is dead code there; the row cache's
 #: zero-fill is re-made by the compiler as a broadcast of a constant that
-#: carries no name, on the CPU as on the chip: PERF.md 6, PR 38)
+#: carries no name, on the CPU as on the chip: PERF.md 6, PR 38).  The dense
+#: bf16 admission works on the slot's own row of the pool (PR 63): it makes
+#: no row cache and writes no slot; ``admit_apart`` is the same family sent
+#: through the batch-1 row cache, whose scopes those two stay
 PRESENT = {"tick": {"project", "qkv", "attn_out", "norm"},
-           "admit": set(SCOPES) - {"sweep", "admit_row_cache"}}
+           "admit": set(SCOPES) - {"sweep", "admit_row_cache",
+                                   "admit_slot_write"},
+           "admit_apart": set(SCOPES) - {"sweep", "admit_row_cache"}}
 CFG = dataclasses.replace(gpt.GPT2_350M, n_layer=2, d_model=64, n_head=4,
                           vocab_size=256, max_seq_len=64,
                           dtype=jnp.bfloat16)
@@ -65,6 +70,8 @@ def _lowered(program):
     per_slot = [arg((SLOTS,) + tail, dtype) for tail, dtype in (
         ((), jnp.int32), ((vocab,), jnp.float32), ((2,), jnp.uint32),
         ((), jnp.bool_), ((), jnp.float32), ((), jnp.bool_))]
+    if program == "admit_apart":
+        fam = dataclasses.replace(fam, pool_rows=False)
     return jax.jit(admission(fam, CFG, SMAX, None),
                    donate_argnums=(1, 3)).lower(
         params, pool, *per_slot, arg((SMAX // CHUNK, CHUNK), jnp.int32),
@@ -99,10 +106,10 @@ def _compiled(program, without=()):
 
 @pytest.fixture(scope="module")
 def with_scopes():
-    return {p: _compiled(p) for p in ("tick", "admit")}
+    return {p: _compiled(p) for p in PRESENT}
 
 
-@pytest.mark.parametrize("program", ["tick", "admit"])
+@pytest.mark.parametrize("program", list(PRESENT))
 @pytest.mark.parametrize("without", [tuple(SCOPES)] + [(s,) for s in SCOPES],
                          ids=["all"] + list(SCOPES))
 def test_a_scope_changes_nothing_but_metadata(with_scopes, program, without):

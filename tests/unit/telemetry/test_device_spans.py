@@ -107,8 +107,9 @@ def test_watched_launches_are_stamped_in_order_by_one_thread():
 def test_the_report_prints_device_time_by_program(tmp_path, capsys):
     """``scripts/run_report.py --trace``: launches, p50 and p95 ms, us a
     padded token (``padded``: the rows an admission's passes computed, a
-    narrow last pass's in the last chunk's place) and the share of the run,
-    by program."""
+    narrow last pass's in the last chunk's place), the share of a
+    program's admissions that worked on the slot's own row of the pool
+    (``in_place``) and the share of the run, by program."""
     import importlib.util
     import json
     import os
@@ -124,7 +125,8 @@ def test_the_report_prints_device_time_by_program(tmp_path, capsys):
             # the last a chunk and a narrow last pass of 64 rows
             reg._stamp("admit", t, t + 0.010, {
                 "padded": 256 if i < 15 else 128 + 64, "chunk": 128,
-                "narrow": 0 if i < 15 else 64, "slot": i})
+                "narrow": 0 if i < 15 else 64, "slot": i,
+                "in_place": int(i >= 5)})
             t += 0.010
     with tracer.span(SpanName.SERVE_TICK):
         pass
@@ -140,7 +142,7 @@ def test_the_report_prints_device_time_by_program(tmp_path, capsys):
     assert table["tick"] == {
         "n": 20, "p50_ms": pytest.approx(3.0, abs=2e-3),
         "p95_ms": pytest.approx(3.0, abs=2e-3), "us_per_padded_token": None,
-        "share": pytest.approx(0.6, abs=1e-3)}
+        "in_place": None, "share": pytest.approx(0.6, abs=1e-3)}
     # the export keeps whole microseconds
     assert table["admit"]["n"] == 4
     assert table["admit"]["p50_ms"] == pytest.approx(10.0, abs=2e-3)
@@ -148,10 +150,12 @@ def test_the_report_prints_device_time_by_program(tmp_path, capsys):
     assert table["admit"]["us_per_padded_token"] == pytest.approx(
         40000 / (3 * 256 + 128 + 64), abs=1e-2)
     assert table["admit"]["share"] == pytest.approx(0.4, abs=1e-3)
+    assert table["admit"]["in_place"] == 0.75
     assert mod.main([str(tmp_path), "--trace", path]) == 0
     out = capsys.readouterr().out
     assert "device time by program (serve.device)" in out
     assert "      tick: 20, " in out and "      admit: 4, " in out
+    assert ", 75%, " in out and ", -, " in out
     # a trace without such spans prints no table
     assert mod.device_span_table([{"ph": "X", "name": "serve.tick",
                                    "ts": 1, "dur": 1}]) == {}
