@@ -79,6 +79,10 @@ class LinearLatentMoEConfig:
     kda_head_dim: int = 32          # d_k = d_v
     conv_kernel: int = 4
     kda_chunk: int = 64
+    #: the factor on ``beta = sigmoid(W_b h)``: 1 keeps every eigenvalue of
+    #: the state's transition in [0, 1]; 2 (``linear_gqa_moe``) lets them
+    #: reach -1
+    kda_beta_scale: float = 1.0
     # latent attention: ``latent_moe``'s names, read by its functions
     n_head: int = 4
     kv_rank: int = 32
@@ -180,8 +184,8 @@ class LinearLatentMoEConfig:
 def kda_inputs(x, p, config: LinearLatentMoEConfig):
     """From ``x`` [B, S, d]: the convolution's pre-activation input ``q~ |
     k~ | v~`` [B, S, 3 d_kda] in ``config.dtype``; the log-decay ``g`` [B, S,
-    H, d_k] (<= 0), ``beta`` [B, S, H] and the output gate [B, S, d_kda],
-    float32."""
+    H, d_k] (<= 0), ``beta`` [B, S, H] (``kda_beta_scale sigmoid(W_b h)``)
+    and the output gate [B, S, d_kda], float32."""
     cdt = config.dtype
     H, K = config.kda_heads, config.kda_head_dim
     h = rms_norm(x, p["ln1"], config.eps, cdt)
@@ -198,6 +202,8 @@ def kda_inputs(x, p, config: LinearLatentMoEConfig):
         beta = jax.nn.sigmoid(jnp.einsum(
             "bsd,dh->bsh", h, p["w_b"].astype(cdt),
             preferred_element_type=jnp.float32))
+        if config.kda_beta_scale != 1.0:
+            beta = config.kda_beta_scale * beta
         gate = jax.nn.sigmoid(low("w_ga", "w_gb")
                               + p["b_g"].astype(jnp.float32))
     return qkv, g, beta, gate
@@ -244,6 +250,58 @@ lm_logits = latent_moe.lm_logits
 
 # -------------------------------------------------------------------- init
 
+def _normal(key, shape, s, pdt):
+    return (jax.random.normal(key, shape, jnp.float32) * s).astype(pdt)
+
+
+def kda_init(key, config, n: int, std, out_std):
+    """``n`` stacked KDA layers' mixer and both norms (the ranges are
+    :func:`init`'s), for any config with this family's KDA fields."""
+    d, pdt = config.d_model, config.param_dtype
+    normal = lambda key, shape, s: _normal(key, shape, s, pdt)
+    k = jax.random.split(key, 10)
+    H, K, F = config.kda_heads, config.kda_head_dim, config.d_kda
+    r = K       # between the decay's and the gate's two matrices: a head's
+    taps = config.conv_kernel
+    dt = jnp.exp(jax.random.uniform(k[8], (n, F), jnp.float32)
+                 * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return {
+        "ln1": jnp.ones((n, d), pdt), "ln2": jnp.ones((n, d), pdt),
+        "w_qkv": normal(k[0], (n, d, 3 * F), std),
+        "conv_w": (jax.random.uniform(k[1], (n, taps, 3 * F),
+                                      jnp.float32, -1.0, 1.0)
+                   / math.sqrt(taps)).astype(pdt),
+        "w_fa": normal(k[2], (n, d, r), std),
+        "w_fb": normal(k[3], (n, r, F), std),
+        # the inverse of softplus, so that softplus(dt_bias) = dt
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+        "A_log": jnp.log(jax.random.uniform(
+            k[9], (n, H), jnp.float32, 1.0, 16.0)).astype(pdt),
+        "w_b": normal(k[4], (n, d, H), std),
+        "w_ga": normal(k[5], (n, d, r), std),
+        "w_gb": normal(k[6], (n, r, F), std),
+        "b_g": normal(jax.random.fold_in(key, 21), (n, F), std),
+        "norm_o": jnp.ones((n, K), pdt),
+        "w_o": normal(k[7], (n, F, d), out_std)}
+
+
+def expert_init(key, config, n: int, std, out_std, routed_std):
+    """``n`` stacked expert layers' FFN halves: the router with its
+    selection bias, the held experts and the shared one."""
+    d, pdt = config.d_model, config.param_dtype
+    normal = lambda key, shape, s: _normal(key, shape, s, pdt)
+    k = jax.random.split(key, 7)
+    E = len(config.held)
+    f, fs = config.d_expert, config.d_expert * config.n_shared_experts
+    return {"router": normal(k[2], (n, d, config.n_experts), std),
+            # small and not zero, so that the bias is exercised
+            "router_bias": normal(k[3], (n, config.n_experts), 0.01),
+            "w_gu": normal(k[0], (n, E, d, 2 * f), std),
+            "w_down": normal(k[1], (n, E, f, d), routed_std),
+            "ws_gu": normal(k[4], (n, d, 2 * fs), std),
+            "ws_down": normal(k[5], (n, fs, d), out_std)}
+
+
 def init(config: LinearLatentMoEConfig, rng: jax.Array, std: float = 0.02,
          routed_out_std: Optional[float] = None,
          embed_std: Optional[float] = None) -> PyTree:
@@ -256,56 +314,21 @@ def init(config: LinearLatentMoEConfig, rng: jax.Array, std: float = 0.02,
     uniform in +-1/sqrt(taps), its output gate's bias normal ``std``."""
     d, v = config.d_model, config.padded_vocab
     pdt = config.param_dtype
-    E = len(config.held)
     out_std = std / math.sqrt(2 * config.n_layer)
     routed_std = out_std if routed_out_std is None else routed_out_std
-
-    def normal(key, shape, s):
-        return (jax.random.normal(key, shape, jnp.float32) * s).astype(pdt)
+    normal = lambda key, shape, s: _normal(key, shape, s, pdt)
 
     def ffn_init(key, label, n):
-        k = jax.random.split(key, 7)
         if label.endswith(DENSE):
+            k = jax.random.split(key, 7)
             return {"w_gu": normal(k[0], (n, d, 2 * config.d_ff), std),
                     "w_down": normal(k[1], (n, config.d_ff, d), out_std)}
-        f, fs = config.d_expert, config.d_expert * config.n_shared_experts
-        return {"router": normal(k[2], (n, d, config.n_experts), std),
-                # small and not zero, so that the bias is exercised
-                "router_bias": normal(k[3], (n, config.n_experts), 0.01),
-                "w_gu": normal(k[0], (n, E, d, 2 * f), std),
-                "w_down": normal(k[1], (n, E, f, d), routed_std),
-                "ws_gu": normal(k[4], (n, d, 2 * fs), std),
-                "ws_down": normal(k[5], (n, fs, d), out_std)}
-
-    def kda_init(key, n):
-        k = jax.random.split(key, 10)
-        H, K, F = config.kda_heads, config.kda_head_dim, config.d_kda
-        r = K       # between the decay's and the gate's two matrices: a head's
-        taps = config.conv_kernel
-        dt = jnp.exp(jax.random.uniform(k[8], (n, F), jnp.float32)
-                     * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
-        return {
-            "ln1": jnp.ones((n, d), pdt), "ln2": jnp.ones((n, d), pdt),
-            "w_qkv": normal(k[0], (n, d, 3 * F), std),
-            "conv_w": (jax.random.uniform(k[1], (n, taps, 3 * F),
-                                          jnp.float32, -1.0, 1.0)
-                       / math.sqrt(taps)).astype(pdt),
-            "w_fa": normal(k[2], (n, d, r), std),
-            "w_fb": normal(k[3], (n, r, F), std),
-            # the inverse of softplus, so that softplus(dt_bias) = dt
-            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
-            "A_log": jnp.log(jax.random.uniform(
-                k[9], (n, H), jnp.float32, 1.0, 16.0)).astype(pdt),
-            "w_b": normal(k[4], (n, d, H), std),
-            "w_ga": normal(k[5], (n, d, r), std),
-            "w_gb": normal(k[6], (n, r, F), std),
-            "b_g": normal(jax.random.fold_in(key, 21), (n, F), std),
-            "norm_o": jnp.ones((n, K), pdt),
-            "w_o": normal(k[7], (n, F, d), out_std)}
+        return expert_init(key, config, n, std, out_std, routed_std)
 
     def part_init(key, label, n):
         km, kf = jax.random.split(key)
-        mixer = kda_init(km, n) if label.startswith(KDA) else \
+        mixer = kda_init(km, config, n, std, out_std) \
+            if label.startswith(KDA) else \
             latent_moe.attention_init(km, config, n, std, out_std)
         return {**mixer, **ffn_init(kf, label, n)}
 
@@ -324,31 +347,31 @@ def init(config: LinearLatentMoEConfig, rng: jax.Array, std: float = 0.02,
             "lm_head": normal(keys[1], (v, d), std)}
 
 
+#: a KDA layer's mixer and both norms: the head norm and the gate run over a
+#: token's heads together, so the mixer is not sliced by head
+KDA_AXES = {"ln1": (LAYERS, EMBED), "ln2": (LAYERS, EMBED),
+            "w_qkv": (LAYERS, EMBED, None), "conv_w": (LAYERS, None, None),
+            "w_fa": (LAYERS, EMBED, None), "w_fb": (LAYERS, None, None),
+            "dt_bias": (LAYERS, None), "A_log": (LAYERS, None),
+            "w_b": (LAYERS, EMBED, None),
+            "w_ga": (LAYERS, EMBED, None), "w_gb": (LAYERS, None, None),
+            "b_g": (LAYERS, None), "norm_o": (LAYERS, None),
+            "w_o": (LAYERS, None, EMBED)}
+#: an expert layer's FFN half
+EXPERT_AXES = {"router": (LAYERS, EMBED, None), "router_bias": (LAYERS, None),
+               "w_gu": (LAYERS, EXPERT, EMBED, MLP),
+               "w_down": (LAYERS, EXPERT, MLP, EMBED),
+               "ws_gu": (LAYERS, EMBED, MLP), "ws_down": (LAYERS, MLP, EMBED)}
+
+
 def logical_axes(config: LinearLatentMoEConfig) -> PyTree:
     def part_axes(label):
-        if label.startswith(KDA):
-            # the head norm and the gate run over a token's heads together:
-            # the mixer is not sliced by head
-            p = {"ln1": (LAYERS, EMBED), "ln2": (LAYERS, EMBED),
-                 "w_qkv": (LAYERS, EMBED, None),
-                 "conv_w": (LAYERS, None, None),
-                 "w_fa": (LAYERS, EMBED, None), "w_fb": (LAYERS, None, None),
-                 "dt_bias": (LAYERS, None), "A_log": (LAYERS, None),
-                 "w_b": (LAYERS, EMBED, None),
-                 "w_ga": (LAYERS, EMBED, None), "w_gb": (LAYERS, None, None),
-                 "b_g": (LAYERS, None), "norm_o": (LAYERS, None),
-                 "w_o": (LAYERS, None, EMBED)}
-        else:
-            p = latent_moe.attention_axes(config)
+        p = dict(KDA_AXES) if label.startswith(KDA) else \
+            latent_moe.attention_axes(config)
         if label.endswith(DENSE):
             return {**p, "w_gu": (LAYERS, EMBED, MLP),
                     "w_down": (LAYERS, MLP, EMBED)}
-        return {**p, "router": (LAYERS, EMBED, None),
-                "router_bias": (LAYERS, None),
-                "w_gu": (LAYERS, EXPERT, EMBED, MLP),
-                "w_down": (LAYERS, EXPERT, MLP, EMBED),
-                "ws_gu": (LAYERS, EMBED, MLP),
-                "ws_down": (LAYERS, MLP, EMBED)}
+        return {**p, **EXPERT_AXES}
 
     def run_axes(unit):
         return part_axes(unit[0]) if len(unit) == 1 else \

@@ -109,15 +109,22 @@ def _kda_mixer(x, p, j, cache: KVCache, valid, work,
             counters.astype(jnp.int32))
 
 
-def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
+def kda_step(params: PyTree, config, valid, full, ffn,
+             extras=lambda kind, p: None):
+    """``Family.step`` of a family whose layers are KDA layers and full
+    layers of ONE other kind: the layer loop both such families share.
+    ``full(x, p, extra, i, j, attend, cache) -> (x, cache)`` is the full
+    layer's mixer (``j``: its index among the full layers; ``i``: the
+    repetition of its run), ``extra`` what ``extras(kind, p)`` made of its
+    position's stacks outside the scan (None for a KDA position);
+    ``ffn(x, p, config, label, experts=, layer=)`` a layer's second half."""
     segments = []
     # a tick's work list, built once for all its KDA layers
     work = ssm.live_rows(valid > 0, valid.shape[0])
     groups = stats_groups(config)
 
-    def layer(x, label, p, experts, heads, i, j, attend, cache):
-        """Layer ``j`` of its mixer's kind, repetition ``i`` of its run;
-        ``heads``: a latent layer's ``wkv_b`` stack, head-major."""
+    def layer(x, label, p, experts, extra, i, j, attend, cache):
+        """Layer ``j`` of its mixer's kind, repetition ``i`` of its run."""
         stats = cache.stats
         if label.startswith(KDA):
             x, state, counters = _kda_mixer(x, p, j, cache, valid, work,
@@ -125,11 +132,8 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
             cache = dataclasses.replace(cache, state=state)
             stats = stats.at[groups["state_steps"]].add(counters)
         else:
-            with jax.named_scope("latent_attention"):
-                p = latent_moe.with_up(p, heads, i)
-                a, cache = attend(x, p, j, cache)
-                x = latent_moe.latent_output(x, a, p, config)
-        x, counts = model.ffn(x, p, config, label, experts=experts, layer=i)
+            x, cache = full(x, p, extra, i, j, attend, cache)
+        x, counts = ffn(x, p, config, label, experts=experts, layer=i)
         if counts is not None:
             stats = stats.at[groups["moe_pairs"]].add(counts)
         return x, dataclasses.replace(cache, stats=stats)
@@ -142,15 +146,13 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
         # the body closes over the run's whole stacks
         routed = [None if label.endswith(DENSE) else
                   {k: p[k] for k in ROUTED} for label, p in zip(unit, parts)]
-        heads = [None if kind == KDA else
-                 latent_moe.head_major(p["wkv_b"], config)
-                 for kind, p in zip(kinds, parts)]
+        extra = [extras(kind, p) for kind, p in zip(kinds, parts)]
 
         def body(x, ps, i, attend, cache, unit=unit, kinds=kinds,
-                 firsts=firsts, routed=routed, heads=heads):
-            for label, kind, first, p, experts, up in zip(
-                    unit, kinds, firsts, ps, routed, heads):
-                x, cache = layer(x, label, p, experts, up, i,
+                 firsts=firsts, routed=routed, extra=extra):
+            for label, kind, first, p, experts, ex in zip(
+                    unit, kinds, firsts, ps, routed, extra):
+                x, cache = layer(x, label, p, experts, ex, i,
                                  first + i * kinds.count(kind), attend, cache)
             return x, cache
 
@@ -159,6 +161,20 @@ def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
             {k: v for k, v in p.items() if k not in ROUTED}
             for p, experts in zip(parts, routed)), body))
     return segments
+
+
+def _step(params: PyTree, config: LinearLatentMoEConfig, valid):
+    def latent(x, p, heads, i, j, attend, cache):
+        """``heads``: the layer's run's ``wkv_b`` stack, head-major."""
+        with jax.named_scope("latent_attention"):
+            p = latent_moe.with_up(p, heads, i)
+            a, cache = attend(x, p, j, cache)
+            return latent_moe.latent_output(x, a, p, config), cache
+
+    return kda_step(
+        params, config, valid, latent, model.ffn,
+        extras=lambda kind, p: None if kind == KDA else
+        latent_moe.head_major(p["wkv_b"], config))
 
 
 FAMILY = gpt_inference.Family(

@@ -6,7 +6,9 @@ A KDA mixer keeps, per layer and per conversation, one matrix a head, ``S``
 head ``h`` brings a query and a key ``q, k`` ``[d_k]`` (unit length, the
 query over ``sqrt(d_k)``), a value ``v`` ``[d_v]``, a log-decay ``g`` ``[d_k]``
 (<= 0: EVERY KEY CHANNEL decays by its own ``alpha = exp(g)``) and a scalar
-``beta`` in (0, 1):
+``beta`` in (0, 2) (``sigmoid`` in ``linear_latent_moe``, twice that in
+``linear_gqa_moe``: past 1 the transition REFLECTS what the state reads for
+``k``, its eigenvalue along ``k`` ``1 - beta`` in (-1, 0)):
 
     S' = alpha[:, None] * S_{t-1}        what is left of the state
     u  = beta * (v - S'^T k)             the delta: what k should read, less
@@ -71,16 +73,32 @@ before it, ``k_s o exp(G_r - G_s)``, one product a sub-block (float32,
 (the diagonal blocks) keep the direct form, a column ``s`` at a time:
 ``k_s o D[:, s]`` against the sub-block's own rows, a ``SUB_BLOCK``-th of
 what a column over the whole sub-chunk costs the vector unit.
-``(I + A)^-1`` is the product ``(I + N)(I + N^2)(I + N^4)...`` with
-``N = -A`` (``N`` is strictly lower triangular, so ``N^C = 0`` and the
-product ends after ``log2 C`` squarings): products, where forward
-substitution would be ``C`` dependent steps.  Positions at or past ``valid``
+``(I + A)^-1`` is built by halves (``_inverse_unit_lower``): inside a
+diagonal block of 2 it is ``I - A``, and a block of ``2b`` follows from its
+two blocks of ``b`` by two products, ``log2 C - 1`` levels: products, where
+forward substitution would be ``C`` dependent steps, and every intermediate
+a block of the inverse itself.  Positions at or past ``valid``
 take ``g = 0`` and ``beta = 0``: ``D`` is 1 across them, their ``u`` is 0,
 and a padded tail leaves the state exactly where the last real token left
 it.  Everything inside is float32.
 
 The convolutions before the kernels are ``ssm.causal_conv`` (one call over
 ``q | k | v``).
+
+What is served and what was checked.  Heads: any number whose tiles are
+whole lane rows by whole sublane rows (``d_k``, ``d_v`` multiples of 128);
+32 heads (one step block of 4,096 lanes a slot, 4 scan blocks of 8 heads)
+and 64 (two step blocks, 8 scan blocks) run in the benchmark, and both
+compile for the chip in ``tests/unit/ops/test_tpu_aot_compile.py``.
+``beta``: (0, 2).  Against the recurrence token by token in float64
+(``tests/unit/ops/test_delta_rule.py``): ``beta`` in (0, 1) on random keys
+under decays from e^-0.001 to e^-40 a token, and ``beta`` in (1, 2) at 64
+heads on random keys and on keys within 0.05 of one direction under a decay
+of at most e^-0.001 a token, where ``beta |k_t . k_s| D[t, s]`` stays near 2
+over a whole sub-chunk: both read 1e-6 to 2e-5 on states of order 2.  The
+product form ``(I + N)(I + N^2)(I + N^4)...`` (``N = -A``), which this file
+used until PR 64, costs the same ten products a sub-chunk and read 1e33
+there: its powers of ``N`` grow like ``2^n C(t - s, n)`` before they cancel.
 """
 
 from __future__ import annotations
@@ -253,16 +271,27 @@ def kda_decode_step(state, layer, q, k, v, g, beta, active=None, work=None
 # -------------------------------------------------------------- chunk scan
 
 def _inverse_unit_lower(a):
-    """``(I + a)^-1`` for ``a`` [..., C, C] strictly lower triangular: ``(I
-    + N)(I + N^2)(I + N^4) ...`` with ``N = -a``, which ends where ``N^C =
-    0``."""
+    """``(I + a)^-1`` for ``a`` [..., C, C] strictly lower triangular, by
+    HALVES: inside a diagonal block of 2 the inverse is ``I - a`` exactly,
+    and a block of ``2b`` follows from its two blocks of ``b``, ``[[P, 0],
+    [R, Q]]^-1 = [[P^-1, 0], [-Q^-1 R P^-1, Q^-1]]``: two products a level,
+    ``log2 C - 1`` levels.  Every intermediate is a block of the inverse
+    itself, which the delta rule keeps of order 1 for ``beta`` in (0, 2)
+    (``I - beta k k^T`` never stretches); the product form ``(I + N)(I +
+    N^2)(I + N^4)...`` costs the same products and forms powers of ``N = -a``
+    that pass 1e30 where ``beta |k_t . k_s|`` stays near 2 over a sub-chunk
+    (near-parallel keys under a slow decay) before they cancel."""
     C = a.shape[-1]
+    t_of = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    s_of = lax.broadcasted_iota(jnp.int32, (C, C), 1)
     dot = functools.partial(jnp.matmul, precision=_HI)
-    p = -a
-    inv = jnp.eye(C, dtype=a.dtype) + p
-    for _ in range(max(C - 1, 1).bit_length() - 1):
-        p = dot(p, p)
-        inv = inv + dot(inv, p)
+    inv = jnp.eye(C, dtype=a.dtype) \
+        - jnp.where(t_of >> 1 == s_of >> 1, a, 0.0)
+    for level in range(1, max(C - 1, 1).bit_length()):
+        # ``a`` between the two blocks of ``2^level`` of a block twice that
+        between = (t_of >> level + 1 == s_of >> level + 1) \
+            & (t_of >> level != s_of >> level)
+        inv = inv - dot(dot(inv, jnp.where(between, a, 0.0)), inv)
     return inv
 
 

@@ -32,7 +32,7 @@ import deepspeed_tpu
 from benchmarks.chip import (dots3_family, hybrid_ssm_moe_family,
                              kimi_linear_family, latent_moe_family,
                              lfm2_family, longcat_flash_family, mellum_family,
-                             nemotron_h_family)
+                             nemotron_h_family, solar_open2_family)
 from benchmarks.chip.reference import (dots3_control, dots3_reference,
                                        hybrid_ssm_moe_control,
                                        hybrid_ssm_moe_reference,
@@ -45,10 +45,13 @@ from benchmarks.chip.reference import (dots3_control, dots3_reference,
                                        longcat_flash_reference,
                                        mellum_control, mellum_reference,
                                        nemotron_h_control,
-                                       nemotron_h_reference)
+                                       nemotron_h_reference,
+                                       solar_open2_control,
+                                       solar_open2_reference)
 from deepspeed_tpu.models import (conv_moe, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference, latent_moe,
-                                  linear_latent_moe, shortcut_latent_moe,
+                                  linear_gqa_moe, linear_latent_moe,
+                                  shortcut_latent_moe,
                                   sparse_latent_moe, window_moe)
 from deepspeed_tpu.models.hybrid_ssm_moe import run_parts
 from deepspeed_tpu.moe.held_experts import n_pair_counts, read_pair_counts
@@ -451,8 +454,9 @@ def patchable(spec):
     from deepspeed_tpu.ops.pallas import decode_attention, delta_rule, ssm
     served = importlib.import_module(spec.program.__name__ + "_inference")
     return {(m.__name__, n): id(v) for m in (
-        spec.program, served, latent_moe, gpt_inference, held_experts,
-        decode_attention, delta_rule, ssm) for n, v in vars(m).items()
+        spec.program, served, latent_moe, linear_latent_moe, gpt_inference,
+        held_experts, decode_attention, delta_rule, ssm)
+        for n, v in vars(m).items()
         if not n.startswith("__")}
 
 
@@ -731,6 +735,55 @@ SPECS = {s.name: s for s in (
               "sliding_attention"),
              ("num_attention_heads", 16, "heads of 64"),
              ("model_type", "lfm2", "")),
+         bf16="slot"),
+    Spec(name="solar-open2-250b-ep8", family=solar_open2_family,
+         reference=solar_open2_reference, program=linear_gqa_moe,
+         weights=solar_open2_control.WEIGHTS,
+         planted=solar_open2_control.planted,
+         why="kimi-linear's for the KDA layers (the chunked form against the "
+             "recurrence, its triangular system solved by halves) and "
+             "granite's for the grouped layer (a blocked softmax); the gate "
+             "is one multiply a channel on both sides: 1e-6 to 2e-5 on "
+             "logits of about 0.6",
+         tweak=lambda cfg: dataclasses.replace(cfg, kda_chunk=8),
+         retouch=_loud_router_bias,
+         slot_paths=_cases(("1+C+3C+5", (1, _C, 3 * _C + 5))),
+         state_layers=lambda cfg: cfg.count(linear_gqa_moe.KDA),
+         routed_layers=lambda cfg: cfg.n_layer,
+         # through apply what is in the mathematics: beta without its factor
+         # 2, the grouped layer's gate left out, its queries and keys
+         # rotated, the full layer LAST in the period, the shared expert left
+         # out, a head's decay the mean of its channels', the routed product
+         # zeroed, 8-bit matrices; through the slot path what only a cache
+         # can show
+         faults=tuple((f, "apply", ()) for f in (
+             "none", "beta_1", "no_attn_gate", "rotated", "full_last",
+             "no_shared", "mean_decay", "zero", "int8"))
+         + (("state_other", "slot", ()), ("bf16_state", "slot", ())),
+         faint=("bf16_state",),
+         decided=("beta_1", "no_attn_gate", "rotated", "full_last"),
+         readings=(hybrid_ssm_moe_control, ("beta_1", "no_attn_gate")),
+         shares=Shares(keys=dict(n_routed_experts=16),
+                       layer=lambda params: _first(params["runs"][0]),
+                       ffn=linear_gqa_moe.ffn, shares=8, each=2,
+                       reference=lambda file, x, p: x + jax.vmap(
+                           lambda h: solar_open2_reference._experts(
+                               file, h, p, lambda e: p["w_gu"][e],
+                               lambda e: p["w_down"][e]))(
+                           solar_open2_reference._norm(
+                               x, p["ln2"], file["rms_norm_eps"])),
+                       atol=1e-6, rtol=1e-5),
+         siblings=(
+             ("first_k_dense_replace", 1, "first_k_dense_replace"),
+             ("use_rope", True, "use_rope"),
+             ("tie_word_embeddings", True, "tie_word_embeddings"),
+             ("use_gqa_gate", False, "granite-4.0-h-small-ep4's family"),
+             ("kda_use_full_proj", True, "kda_use_full_proj"),
+             ("kda_allow_neg_eigval", False,
+              "kimi-linear-48b-a3b-ep8's family"),
+             ("n_shared_experts", 2, "n_shared_experts"),
+             ("norm_topk_prob", False, "norm_topk_prob"),
+             ("model_type", "kimi_linear", "")),
          bf16="slot"),
 )}
 

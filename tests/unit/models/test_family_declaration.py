@@ -20,11 +20,14 @@ from benchmarks.chip import (hybrid_ssm_moe_family, latent_moe_family,
                              mellum_family, nemotron_h_family)
 from benchmarks.chip import dots3_family, kimi_linear_family
 from benchmarks.chip import lfm2_family, longcat_flash_family
+from benchmarks.chip import solar_open2_family
 from deepspeed_tpu.models import (cache_family, conv_moe, conv_moe_inference,
+                                  linear_gqa_moe,
                                   gpt, gpt_inference, gpt_moe,
                                   gpt_moe_inference, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference,
                                   latent_moe_inference,
+                                  linear_gqa_moe_inference,
                                   linear_latent_moe_inference,
                                   shortcut_latent_moe_inference,
                                   sparse_latent_moe_inference, window_moe,
@@ -67,14 +70,16 @@ def _served(name):
         "shortcut": (longcat_flash_family, "longcat-flash-chat-ep32",
                      shortcut_latent_moe_inference.FAMILY),
         "conv": (lfm2_family, "lfm2-8b-a1b",
-                 conv_moe_inference.FAMILY)}[name]
+                 conv_moe_inference.FAMILY),
+        "linear_gqa": (solar_open2_family, "solar-open2-250b-ep8",
+                       linear_gqa_moe_inference.FAMILY)}[name]
     cfg = dataclasses.replace(builder.build(tiny_file(file)),
                               dtype=jnp.float32)
     return cfg, lambda k: builder.init(cfg, k, jnp.float32), family
 
 
 SERVED = ("dense", "moe", "latent", "hybrid", "single_part", "window",
-          "selected", "linear", "shortcut", "conv")
+          "selected", "linear", "shortcut", "conv", "linear_gqa")
 
 
 @pytest.mark.parametrize("name", SERVED)
@@ -110,20 +115,22 @@ def test_cache_family_returns_the_whole_declaration(name):
         "selected": {"moe_pairs", "sparse_select"},
         "linear": {"moe_pairs", "state_steps"},
         "shortcut": {"moe_pairs"},
-        "conv": {"moe_pairs", "state_steps"}}[name]
+        "conv": {"moe_pairs", "state_steps"},
+        "linear_gqa": {"moe_pairs", "state_steps"}}[name]
     assert fam.select_counters == (
         sparse_latent_moe_inference.SELECT_COUNTERS
         if name == "selected" else ())
     assert fam.state_counters == (
         hybrid_ssm_moe_inference.STATE_COUNTERS
-        if name in ("hybrid", "single_part", "linear") else
+        if name in ("hybrid", "single_part", "linear", "linear_gqa") else
         ("conv_rows_stepped", "conv_tokens_real", "conv_tokens_padded")
         if name == "conv" else ())
     # the per-slot state is a tuple of as many arrays as the family says:
     # a pair for the scans' families, ONE for the convolution's tail
     state = jax.eval_shape(lambda: fam.init_cache(cfg, 2, 32)).state
     assert (None if state is None else len(state)) == {
-        "hybrid": 2, "single_part": 2, "linear": 2, "conv": 1}.get(name)
+        "hybrid": 2, "single_part": 2, "linear": 2, "conv": 1,
+        "linear_gqa": 2}.get(name)
     # the dense family alone serves as a draft
     assert ("draft" in fam.unsupported) == (name != "dense")
 
@@ -236,6 +243,9 @@ def test_what_a_family_serves_is_not_refused(name, feature):
                  "latent row has no heads (kv_cache_dtype='int8')"),
     ("conv", "the short-convolution family caches in the compute dtype only "
              "(kv_cache_dtype='int8')"),
+    ("linear_gqa", "the linear-attention families cache in the compute dtype "
+                   "only: the state is float32 and has no heads' scale banks "
+                   "(kv_cache_dtype='int8')"),
 ])
 def test_the_int8_cache_is_refused_where_the_cache_is_made(name, said):
     cfg, _, fam = _served(name)
@@ -263,7 +273,7 @@ def _draft_engine(name):
 
 
 @pytest.mark.parametrize("name", ["moe", "latent", "hybrid", "linear",
-                                  "shortcut", "conv"])
+                                  "shortcut", "conv", "linear_gqa"])
 def test_a_draft_must_be_dense_and_both_callers_say_so(dense_engine, name):
     draft = _draft_engine(name)
     with pytest.raises(NotImplementedError) as e:
@@ -329,6 +339,13 @@ def _swept(name):
             d_model=128, n_head=8, n_kv_head=2, head_dim=64, n_experts=4,
             experts_per_token=2, d_expert=32, d_ff=64,
             dtype=jnp.float32), 1024, decode_attention.GROUPED_SWEEP
+    if name == "linear_gqa":    # a gated grouped row beside a delta-rule state
+        return linear_gqa_moe, linear_gqa_moe.LinearGQAMoEConfig(
+            vocab_size=256, max_seq_len=1024, n_layer=4, gqa_layers=(0,),
+            d_model=128, kda_heads=2, kda_head_dim=32, kda_chunk=16,
+            n_head=16, n_kv_head=2, head_dim=128, n_experts=4,
+            experts_per_token=2, d_expert=32,
+            dtype=jnp.float32), 1024, decode_attention.GROUPED_SWEEP
     if name == "grouped-64":    # the same row under another family's heads
         return hybrid_ssm_moe, hybrid_ssm_moe.HybridSSMMoEConfig(
             max_seq_len=1024, layer_types=(M, A, M), n_head=4, n_kv_head=2,
@@ -341,7 +358,8 @@ def _swept(name):
 
 @pytest.mark.parametrize("name", ["dense", "dense-banded", "moe", "latent",
                                   "hybrid", "single_part", "window",
-                                  "conv", "grouped-64", "grouped-32"])
+                                  "conv", "linear_gqa", "grouped-64",
+                                  "grouped-32"])
 def test_the_plan_is_the_work_lists_block_and_the_kernels(monkeypatch, name):
     """One function of the row says which sweep serves it and by which
     block; the family's plan, the tick's work list and the kernel the tick

@@ -11,7 +11,7 @@ a ``valid`` inside a sub-block and on its edge, both to 1e-5.
 
 The tolerance: both sides are float32 / float64 of the same sums in another
 order; over 192 tokens on a state of order 1 that reads 1e-6 to 1e-5 here
-(the inverse of ``I + A`` by squarings adds nothing visible), and 2e-4 is
+(the inverse of ``I + A`` by halves adds nothing visible), and 2e-4 is
 held as the state-space kernels' tests hold theirs."""
 
 import numpy as np
@@ -208,3 +208,123 @@ def test_the_inverse_of_a_unit_lower_triangular_matrix():
     np.testing.assert_allclose(inv @ (np.eye(64) + a),
                                np.broadcast_to(np.eye(64), a.shape),
                                atol=1e-4)
+
+
+# ---- 64 heads, beta in (1, 2): the negative-eigenvalue delta rule
+
+def _draw_64(rng, B, S, K, keys):
+    """64 heads with ``beta`` in (1, 2): ``I - beta k k^T`` REFLECTS what the
+    state reads for ``k``.  ``keys`` ``"random"``: independent unit keys and
+    decays up to e^-1.6 a token; ``"parallel"``: every key of a head within
+    0.05 of one direction under a decay of at most e^-0.001 a token, so that
+    ``beta |k_t . k_s| D[t, s]`` stays near 2 across a whole sub-chunk (what
+    an inverse of ``I + A`` by powers of ``A`` cannot survive)."""
+    H = 64
+    q, k, v, g, _ = _draw(rng, B, S, H, K, 1.6)
+    if keys == "parallel":
+        k = rng.normal(size=(B, 1, H, K)) + 0.05 * rng.normal(
+            size=(B, S, H, K))
+        k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+        g = -rng.uniform(1e-4, 1e-3, g.shape)
+    return q, k, v, g, rng.uniform(1.0, 2.0, (B, S, H))
+
+
+@pytest.mark.parametrize("keys", ["random", "parallel"])
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_the_chunk_form_at_64_heads_and_beta_past_one(monkeypatch, path,
+                                                      keys):
+    """The published shape of the family with negative eigenvalues (64
+    heads: 8 column blocks of 8 heads a row in the kernel) against the
+    recurrence token by token in float64, ``beta`` drawn in (1, 2), from a
+    state that is not zero, a ragged ``valid``.  The tolerance is the file's
+    (the same sums in another order): with ``beta`` under 2 the transition
+    never stretches, so neither side amplifies its rounding, and the system
+    ``(I + A) U = rhs`` is solved by halves, whose intermediates are blocks
+    of the inverse itself (order 1): this reads 1e-6 to 2e-5 on states of
+    order 2, on near-parallel keys as on random ones, where the product form
+    ``(I - A)(I + A^2)(I + A^4)...`` read 1e33."""
+    _, K = _head(monkeypatch, path)
+    rng = np.random.default_rng(7)
+    B, S = 1, 128
+    q, k, v, g, beta = _draw_64(rng, B, S, K, keys)
+    stack = rng.normal(size=(2, B, K, 64 * K)) * 0.3
+    valid = np.array([101])
+    o, out = delta_rule.kda_chunk_scan(
+        jnp.asarray(stack, jnp.float32), 1, *_f32(q, k, v, g, beta),
+        valid=jnp.asarray(valid), chunk=64)
+    want_o, want_s = _recurrence(stack[1], q, k, v, g, beta, valid)
+    assert np.isfinite(np.asarray(o)[0, :101]).all()
+    np.testing.assert_allclose(np.asarray(o)[0, :101], want_o[0, :101], **TOL)
+    np.testing.assert_allclose(np.asarray(out)[1], want_s, **TOL)
+    assert (np.asarray(out)[0] == np.float32(stack)[0]).all()
+
+
+@pytest.mark.parametrize("keys", ["random", "parallel"])
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_the_decode_step_at_64_heads_and_beta_past_one(monkeypatch, path,
+                                                       keys):
+    """8 tokens one at a time through the step (64 heads: two column
+    blocks of 32 heads a slot in the kernel) with ``beta`` in (1, 2), a
+    freed slot among three, against the recurrence in float64: the step IS
+    the recurrence, a token's sums in float32, so the file's tolerance
+    holds with room (1e-6 here)."""
+    _, K = _head(monkeypatch, path)
+    rng = np.random.default_rng(8)
+    B, T = 3, 8
+    q, k, v, g, beta = _draw_64(rng, B, T, K, keys)
+    stack = rng.normal(size=(2, B, K, 64 * K)) * 0.3
+    live = np.array([True, False, True])
+    state = jnp.asarray(stack, jnp.float32)
+    outs = []
+    for t in range(T):
+        o, state = delta_rule.kda_decode_step(
+            state, 0, *_f32(q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t]),
+            active=jnp.asarray(live))
+        outs.append(np.asarray(o)[:, 0])
+    want_o, want_s = _recurrence(stack[0], q, k, v, g, beta)
+    got = np.stack(outs, 1)
+    for b in np.flatnonzero(live):
+        np.testing.assert_allclose(got[b], want_o[b], **TOL)
+        np.testing.assert_allclose(np.asarray(state)[0, b], want_s[b], **TOL)
+    assert (got[1] == 0).all()
+    assert (np.asarray(state)[0, 1] == np.float32(stack)[0, 1]).all()
+    assert (np.asarray(state)[1] == np.float32(stack)[1]).all()
+
+
+def test_the_inverse_by_halves_is_the_inverse_at_any_size():
+    """``_inverse_unit_lower`` on the delta rule's own ``A`` at its worst
+    (one direction's keys, ``beta`` in (1, 2), no decay: entries near 2
+    everywhere under the diagonal, an inverse of order 1), at sizes that are
+    and are not powers of two, against float64's inverse: float32's rounding
+    of sums of order 1."""
+    rng = np.random.default_rng(9)
+    for C in (2, 8, 24, 64, 128):
+        beta = rng.uniform(1.0, 2.0, (C, 1))
+        k = rng.normal(size=(1, 16)) + 0.05 * rng.normal(size=(C, 16))
+        k /= np.linalg.norm(k, axis=-1, keepdims=True)
+        a = np.tril(beta * (k @ k.T), -1)
+        inv = np.asarray(delta_rule._inverse_unit_lower(
+            jnp.asarray(a, jnp.float32)))
+        want = np.linalg.inv(np.eye(C) + a)
+        assert np.abs(want).max() < 4
+        np.testing.assert_allclose(inv, want, atol=2e-5)
+
+
+def test_near_parallel_keys_under_one_too():
+    """``beta`` in (0.5, 1), the range of the family without the factor 2, on
+    keys within 0.05 of one direction under a slow decay: the product of
+    squarings read 1e12 to 1e21 against the recurrence here as well (powers of
+    ``A`` near ``C(t - s, n)``), the halves the file's tolerance."""
+    rng = np.random.default_rng(11)
+    B, S, H, K = 1, 128, 3, 16
+    q, _, v, g, _ = _draw(rng, B, S, H, K, 1.6)
+    k = rng.normal(size=(B, 1, H, K)) + 0.05 * rng.normal(size=(B, S, H, K))
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -rng.uniform(1e-4, 1e-3, g.shape)
+    beta = rng.uniform(0.5, 1.0, (B, S, H))
+    stack = rng.normal(size=(1, B, K, H * K)) * 0.3
+    o, out = delta_rule.kda_chunk_scan(
+        jnp.asarray(stack, jnp.float32), 0, *_f32(q, k, v, g, beta), chunk=64)
+    want_o, want_s = _recurrence(stack[0], q, k, v, g, beta)
+    np.testing.assert_allclose(np.asarray(o), want_o, **TOL)
+    np.testing.assert_allclose(np.asarray(out)[0], want_s, **TOL)

@@ -1250,7 +1250,7 @@ def test_the_linear_cells_kernels_at_its_shapes(v5e, kernel):
     sub-blocks as products through the row block's first row, the diagonal
     blocks a column at a time from whole sublane rows of 8 in one loop over
     the block's 8 heads; the inverse of ``I + A``
-    by 5 squarings of 64 x 64).  The scan is ONE custom call that returns
+    by halves, 5 levels of two 64 x 64 products).  The scan is ONE custom call that returns
     the chunk's rows beside the aliased stack: the benchmark's reader
     (``state_kernels.kernel_of``) tells the kernel by that pair, and a
     second call would be left out of the time its roofline divides by."""
@@ -1279,6 +1279,70 @@ def test_the_linear_cells_kernels_at_its_shapes(v5e, kernel):
         assert "output_to_operand_aliasing={{1}: (8, {})}" in text
 
 
+@pytest.mark.parametrize("kernel", ["decode_step", "chunk_scan",
+                                    "gqa_decode", "grouped_matmul"])
+def test_the_longctx_cells_kernels_at_its_shapes(v5e, kernel):
+    """The kernels of ``solar2-serve-longctx-sat`` alone, at the cell's
+    shapes (its chunk kernel is ``_CHUNK_PASSES``' ``longctx-sat``): the
+    delta-rule step over 96 slots of a 3-layer stack at 64 heads (two column
+    blocks of 4,096 lanes a slot, 32 heads each), the chunk scan of one
+    row's 1,024-token chunk at 64 heads (8 column blocks of 8 heads, ONE
+    custom call beside the aliased stack, as the benchmark's reader tells
+    it), the grouped-head decode at 8 query heads a key-value head over
+    rows of 16,384 x 1,024, and the grouped matmul over 40 experts of
+    ``[4096, 2560]`` (640-wide columns under the whole contraction side) and
+    ``[1280, 4096]`` (640 x 1,024 tiles), read where they lie in a 3-layer
+    stack (a chunk's 1,024 x 8 x 40/320 pair rows; a tick's are 96)."""
+    held = _KERNEL_MODULES[-2]
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    L, slots, K, H = 3, 96, 128, 64
+    if kernel == "decode_step":
+        assert delta_rule._tiles(K, K)
+        _compiles_with_kernel(
+            lambda st, q, k, v, g, beta, live: delta_rule.kda_decode_step(
+                st, 2, q, k, v, g, beta, active=live),
+            arg((L, slots, K, H * K)), *[arg((slots, H, K))] * 4,
+            arg((slots, H)), arg((slots,), jnp.bool_))
+    elif kernel == "chunk_scan":
+        text = _compiles_with_kernel(
+            lambda st, q, k, v, g, beta, n: delta_rule.kda_chunk_scan(
+                st, 2, q, k, v, g, beta, valid=n, chunk=64),
+            arg((L, 1, K, H * K)), *[arg((1, 1024, H, K))] * 4,
+            arg((1, 1024, H)), arg((1,), jnp.int32))
+        calls = re.findall(r"%kda_chunk_scan\S* = (\(.*?\)) custom-call\(",
+                           text)
+        assert [re.sub(r"\{[^}]*\}", "", c) for c in calls] == [
+            "(f32[1,1024,8192], f32[3,1,128,8192])"], calls
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+    elif kernel == "gqa_decode":
+        from tests.unit.ops.traced_sweeps import sweep_calls
+        smax, width, heads = 16384, 1024, 64
+        pool = arg((1, slots, smax, width), BF16)
+        block = decode.decode_block_k(smax, width)
+        sweep = lambda q, k, v, pos, live: decode.cached_attention(
+            q, k, v, pos, sm_scale=128 ** -0.5, layer=0, active=live,
+            kv_heads=8)
+        shapes = (arg((slots, 1, heads, 128), BF16), pool, pool,
+                  arg((slots,), jnp.int32), arg((slots,), jnp.bool_))
+        assert sweep_calls(jax.make_jaxpr(sweep)(*shapes).jaxpr, slots, smax,
+                           width) == [("gqa_decode_attention", block, block)]
+        # two banks x the pipeline's two blocks beside the float32
+        # accumulator: under half of the 16 MiB a v5e's kernel may use
+        assert 2 * 2 * block * width * 2 + heads * width * 4 < (16 << 20) // 2
+        _compiles_with_kernel(sweep, *shapes)
+    else:
+        assert held.gmm_tiling(4096, 2560) == (128, 4096, 640)
+        assert held.gmm_tiling(1280, 4096) == (128, 640, 1024)
+        for k, n in ((4096, 2560), (1280, 4096)):
+            _compiles_with_kernel(
+                lambda rows, w, sizes: held._grouped(rows, w, sizes, 2),
+                arg((1024, k), BF16), arg((L, 40, k, n), BF16),
+                arg((40,), jnp.int32))
+
+
 # cell: (heads, key-value heads, D, chunk, keys of the call, window) ->
 # (positions a query tile, keys a block)
 _CHUNK_PASSES = {
@@ -1289,6 +1353,8 @@ _CHUNK_PASSES = {
     "gpt2-medium": ((16, 16, 64, 128, 1024, None), (128, 512)),
     # 4 query heads of 64 a key-value head: a group's rows half a lane row
     "assist-sat": ((32, 8, 64, 1024, 3072, None), (256, 1024)),
+    # 8 query heads of 128 a key-value head over a row of 16,384
+    "longctx-sat": ((64, 8, 128, 1024, 16384, None), (128, 1024)),
     # a verify's few positions under grouped heads: 8-row tiles of a packed
     # dtype, a group's under one another
     "verify-8": ((32, 4, 128, 8, 2048, None), (8, 1024)),
