@@ -427,7 +427,7 @@ class Family:
     counts are built from it here (:meth:`sweep_plan`, :func:`_sweeps`).
     ``embed(params, tokens, config, positions)`` and ``logits(params, x,
     config)``.
-    ``prompt_pass(params, tokens, config, cache, family, valid)``: a
+    ``prompt_pass(params, tokens, config, cache, family, valid, head)``: a
     family's own prompt pass (GPT-MoE bounds its gate's dispatch tensors);
     None: one :func:`prefill`.
 
@@ -474,14 +474,16 @@ class Family:
         return init_cache(config, batch, max_len, kv_dtype,
                           stats=self.stats_groups(config))
 
-    def prefill(self, params, tokens, config, cache, valid=None):
+    def prefill(self, params, tokens, config, cache, valid=None,
+                head=True):
         return (self.prompt_pass or prefill)(params, tokens, config, cache,
-                                             family=self, valid=valid)
+                                             family=self, valid=valid,
+                                             head=head)
 
     def extend(self, params, tokens, config, cache, lengths=None,
-               valid=None, row=None):
+               valid=None, row=None, head=True):
         return extend(params, tokens, config, cache, lengths=lengths,
-                      family=self, valid=valid, row=row)
+                      family=self, valid=valid, row=row, head=head)
 
     def decode_step(self, params, token, config, cache, lengths=None,
                     active=None):
@@ -748,14 +750,21 @@ def _real_tokens(valid, B: int, S: int):
 
 
 def prefill(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
-            family: Family = DENSE,
-            valid=None) -> Tuple[jnp.ndarray, KVCache]:
+            family: Family = DENSE, valid=None,
+            head: bool = True) -> Tuple[jnp.ndarray, KVCache]:
     """Run the prompt through the model, filling cache[0:S].
 
     Returns (logits [B, S, padded_vocab] fp32, cache).  Assumes an empty
     cache (length 0) — chunked prefill composes by calling with growing
     ``cache.length`` via :func:`extend`.  ``family`` (here, in ``extend``
     and in ``decode_step``) is the model family's, see :class:`Family`.
+    ``head`` False (here and in ``extend``; a Python bool, static under
+    ``jit``): the layer stack's output ``x`` [B, S, d] in the compute dtype
+    stands in the logits' place, before the final norm, so
+    ``family.logits(params, x, config)`` of any rows of it are those rows'
+    logits: for a caller that wants one row's (an admission,
+    ``serving.batcher.admission``) or none (a pass that only fills the
+    cache).
     ``valid`` [B] (here and in ``extend``, of every family's): how many of
     a row's ``S`` tokens are real, when the caller pads (default: all).
     Only a family with per-slot state reads it; the banks take the padding
@@ -773,14 +782,13 @@ def prefill(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
                            family, _real_tokens(valid, B, S))
-    logits = family.logits(params, x, config)
-    return logits, dataclasses.replace(cache,
-                                       length=jnp.asarray(S, jnp.int32))
+    out = family.logits(params, x, config) if head else x
+    return out, dataclasses.replace(cache, length=jnp.asarray(S, jnp.int32))
 
 
 def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
-           lengths=None, family: Family = DENSE,
-           valid=None, row=None) -> Tuple[jnp.ndarray, KVCache]:
+           lengths=None, family: Family = DENSE, valid=None, row=None,
+           head: bool = True) -> Tuple[jnp.ndarray, KVCache]:
     """Chunked prefill: append ``tokens`` [B, S_c] at positions
     ``cache.length .. cache.length+S_c-1``, attending causally over the
     cached prefix + the chunk.
@@ -793,7 +801,9 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
     caches (the chunk path reads the cache densely, dequantizing when
     int8).
 
-    Returns (logits [B, S_c, padded_vocab] fp32, cache advanced by S_c).
+    Returns (logits [B, S_c, padded_vocab] fp32, cache advanced by S_c);
+    with ``head`` False, ``x`` [B, S_c, d] in the logits' place, as
+    :func:`prefill` does.
 
     Overflow: appending past ``max_len`` is checked eagerly (host call
     with a concrete ``cache.length``); under an outer jit the length is
@@ -854,9 +864,9 @@ def extend(params: PyTree, tokens: jnp.ndarray, config, cache: KVCache,
 
     x, cache = _layer_scan(x, params, cache, config, positions, write, attn,
                            family, _real_tokens(valid, B, Sc))
-    logits = family.logits(params, x, config)
+    out = family.logits(params, x, config) if head else x
     length = jnp.max(pos0) + Sc
-    return logits, dataclasses.replace(
+    return out, dataclasses.replace(
         cache, length=length if row is None else jnp.maximum(
             cache.length, length))
 

@@ -93,8 +93,11 @@ _PREFILL_CHUNK = 128
 
 def _bounded_prefill(params: PyTree, tokens: jnp.ndarray,
                      config: GPTMoEConfig, cache: KVCache, family,
-                     valid=None) -> Tuple[jnp.ndarray, KVCache]:
-    """Prompt pass filling the cache; returns (logits, cache).
+                     valid=None,
+                     head: bool = True) -> Tuple[jnp.ndarray, KVCache]:
+    """Prompt pass filling the cache; returns (logits, cache), or with
+    ``head`` False the layer stack's output in the logits' place
+    (``gpt_inference.prefill``).
 
     Long prompts (> ``_PREFILL_CHUNK`` gated tokens) run as a chain of
     ``extend`` chunks to keep the dropless dispatch tensors bounded at
@@ -107,15 +110,16 @@ def _bounded_prefill(params: PyTree, tokens: jnp.ndarray,
     B, S = tokens.shape
     if B * S <= _PREFILL_CHUNK:
         return gpt_inference.prefill(params, tokens, config, cache,
-                                     family=family, valid=valid)
+                                     family=family, valid=valid, head=head)
     # chunk bounds depend only on the static shape, so this also
     # unrolls under an outer jit (the engine's whole-generate program)
     chunk = max(_PREFILL_CHUNK // B, 1)
     outs = []
     for s0 in range(0, S, chunk):
-        lg, cache = gpt_inference.extend(params, tokens[:, s0:s0 + chunk],
-                                         config, cache, family=family)
-        outs.append(lg)
+        out, cache = gpt_inference.extend(params, tokens[:, s0:s0 + chunk],
+                                          config, cache, family=family,
+                                          head=head)
+        outs.append(out)
     return jnp.concatenate(outs, axis=1), cache
 
 

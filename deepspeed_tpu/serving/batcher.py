@@ -203,7 +203,12 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         compiled program serves every prompt length; every pass is the
         family's own ``prefill`` / ragged ``extend``, as
         :meth:`SlotBatcher._chunked_prefill` runs them one launch each at
-        ``C``.  A fresh row's first chunk is the ``prefill`` where the
+        ``C``, asked for NO head (``head=False``): a pass returns the layer
+        stack's output, the loops carry the one row ``[d]`` of it that may
+        be the last real token's, and the family's ``logits`` (final norm
+        and head product, float32 out) runs ONCE, after the last pass, on
+        that row, so no program holds a ``[rows, vocabulary]`` array.  A
+        fresh row's first chunk is the ``prefill`` where the
         ladder stops at the chunk; where it goes down, every width is one
         more body of the model in this program (2.4-3.6 s of a warm
         start each in most cells with a chunk of 512 or 1,024, 15 s in
@@ -235,8 +240,10 @@ def admission(fam, cfg, max_len: int, kv_dtype):
         # profiler's device time is split (``telemetry.device_time``):
         # ``admit_row_cache`` the batch-1 row cache's allocation and
         # zero-fill, ``admit_chunk`` a pass of any width (the family's own
-        # scopes below it: ``admit_chunk/head`` is the head over all its
-        # positions), ``admit_head`` the one row taken of it,
+        # scopes below it, and none of them a head: a pass returns the
+        # layer stack's output), ``admit_head`` the whole head, the one row
+        # of a pass's output kept and, after the last pass, the family's
+        # final norm and head product on that one row,
         # ``admit_slot_write`` the row's copy into the pool (neither it nor
         # ``admit_row_cache`` in place), ``admit_bind`` the slot's vectors
 
@@ -248,44 +255,49 @@ def admission(fam, cfg, max_len: int, kv_dtype):
             return jnp.clip(n - at, 0, w)[None]
 
         @jax.named_scope("admit_head")
-        def take(lg, at):
-            # the last real token's logits if the pass at ``at`` holds it
-            # (the last pass does; an earlier one's row is junk that the
-            # next iteration replaces)
-            idx = jnp.clip(n - 1 - at, 0, lg.shape[1] - 1)
-            return lax.dynamic_index_in_dim(lg[0], idx, 0, keepdims=False)
+        def take(x, at):
+            # the last real token's row ``[d]`` of the pass's output if the
+            # pass at ``at`` holds it (the last pass does; an earlier one's
+            # row is junk that the next iteration replaces): the loops carry
+            # that, and the head runs once, after them, on the row kept
+            idx = jnp.clip(n - 1 - at, 0, x.shape[1] - 1)
+            return lax.dynamic_index_in_dim(x[0], idx, 0, keepdims=False)
 
         def passes(w, done):
             # pass ``i`` of ``w`` tokens after the first ``done``
             def one(i, carry):
                 at = done + i * w
                 with jax.named_scope("admit_chunk"):
-                    lg, cache = fam.extend(
+                    x, cache = fam.extend(
                         params,
                         lax.dynamic_slice(tokens.reshape(-1), (at,), (w,))[
                             None], cfg, carry[1], lengths=(start + at)[None],
-                        valid=real(at, w), **at_row)
-                return take(lg, at), cache
+                        valid=real(at, w), head=False, **at_row)
+                return take(x, at), cache
             return one
 
+        # the row kept before any pass ran: a position's hidden state as
+        # the embedding makes it (a scan hands it on in the type it took it)
+        x0 = jax.eval_shape(
+            lambda p: fam.embed(p, tokens[:1, :1], cfg,
+                                positions=jnp.arange(1)), params)
+        no_row = jnp.zeros(x0.shape[2:], x0.dtype)
         if prefix is not None:
-            done, carry = 0, (
-                jnp.zeros(last.shape[1:], last.dtype),
-                dataclasses.replace(prefix, length=start))
+            done, carry = 0, (no_row,
+                              dataclasses.replace(prefix, length=start))
         elif in_place:
-            done, carry = 0, (jnp.zeros(last.shape[1:], last.dtype), pool)
+            done, carry = 0, (no_row, pool)
         else:
             with jax.named_scope("admit_row_cache"):
                 fresh = fam.init_cache(cfg, 1, max_len, kv_dtype=kv_dtype)
             if narrow:
                 # every pass an ``extend``, the first from the empty row
-                done, carry = 0, (jnp.zeros(last.shape[1:], last.dtype),
-                                  fresh)
+                done, carry = 0, (no_row, fresh)
             else:
                 with jax.named_scope("admit_chunk"):
-                    lg, cache = fam.prefill(params, tokens[:1], cfg, fresh,
-                                            valid=real(0, C))
-                done, carry = 1, (take(lg, 0), cache)
+                    x, cache = fam.prefill(params, tokens[:1], cfg, fresh,
+                                           valid=real(0, C), head=False)
+                done, carry = 1, (take(x, 0), cache)
         for w in (w for w in widths if w >= C):
             trips = jnp.maximum(full - done, 0) // (w // C)
             carry = lax.fori_loop(jnp.int32(0), trips, passes(w, done * C),
@@ -299,7 +311,11 @@ def admission(fam, cfg, max_len: int, kv_dtype):
             carry = lax.switch(which, [lambda c: c] + [
                 (lambda c, w=w: passes(w, full * C)(jnp.int32(0), c))
                 for w in narrow], carry)
-        vec, cache = carry
+        kept, cache = carry
+        with jax.named_scope("admit_head"):
+            # the family's final norm and head on the one row, float32 out:
+            # what a pass's own head gave row ``n - 1`` of its logits
+            vec = fam.logits(params, kept[None], cfg)[0]
         with jax.named_scope("admit_bind"):
             key = jnp.where(meta[5] != 0, jax.random.fold_in(
                 key, lax.bitcast_convert_type(meta[6], jnp.uint32)), key)
@@ -555,18 +571,24 @@ class SlotBatcher:
             return lengths.at[row].set(0), active.at[row].set(False)
 
         self._p = self.registry.register_all({
-            "prefill": jax.jit(lambda p, t, c: fam.prefill(p, t, cfg, c)),
+            # a chunk a launch, for a caller that keeps the batch-1 cache
+            # (:meth:`_chunked_prefill`) and no logits: they run no head (a
+            # ``jit``'s output is computed whether or not the host reads it)
+            "prefill": jax.jit(
+                lambda p, t, c: fam.prefill(p, t, cfg, c, head=False)),
             "extend": jax.jit(
-                lambda p, t, c, l: fam.extend(p, t, cfg, c, lengths=l)),
+                lambda p, t, c, l: fam.extend(p, t, cfg, c, lengths=l,
+                                              head=False)),
             # the chunk_widen rung's separate jit objects: same functions,
             # compiled lazily at the wide chunk shape on first degraded
             # prefill (a first compile per NAME is free under the
             # CompileWatch contract; pushing a wide chunk through
             # "prefill" would journal perf.recompile)
             "prefill_wide": jax.jit(
-                lambda p, t, c: fam.prefill(p, t, cfg, c)),
+                lambda p, t, c: fam.prefill(p, t, cfg, c, head=False)),
             "extend_wide": jax.jit(
-                lambda p, t, c, l: fam.extend(p, t, cfg, c, lengths=l)),
+                lambda p, t, c, l: fam.extend(p, t, cfg, c, lengths=l,
+                                              head=False)),
             # an admission is ONE launch (plain, or continuing a prefix;
             # each again at the rung's wide chunk): the same function.  It
             # donates the pool like the tick does, and the frontier logits
@@ -714,10 +736,13 @@ class SlotBatcher:
             return cur.at[row].set(tok), keys.at[row].set(k2[0])
 
         progs: Dict[str, Any] = {}
+        # the draft's chunks fill its cache and nothing reads their
+        # logits (:meth:`_draft_prefill`): no head
         progs["draft_prefill"] = jax.jit(
-            lambda p, t, c: dfam.prefill(p, t, dcfg, c))
+            lambda p, t, c: dfam.prefill(p, t, dcfg, c, head=False))
         progs["draft_extend"] = jax.jit(
-            lambda p, t, c, l: dfam.extend(p, t, dcfg, c, lengths=l))
+            lambda p, t, c, l: dfam.extend(p, t, dcfg, c, lengths=l,
+                                           head=False))
         progs["draft_write_slot"] = jax.jit(
             lambda c, row, src: dfam.write_slot(c, row, src))
         progs["spec_seed"] = jax.jit(spec_seed)

@@ -48,7 +48,7 @@ from benchmarks.chip.reference import (dots3_control, dots3_reference,
                                        nemotron_h_reference,
                                        solar_open2_control,
                                        solar_open2_reference)
-from deepspeed_tpu.models import (conv_moe, hybrid_ssm_moe,
+from deepspeed_tpu.models import (cache_family, conv_moe, hybrid_ssm_moe,
                                   hybrid_ssm_moe_inference, latent_moe,
                                   linear_gqa_moe, linear_latent_moe,
                                   shortcut_latent_moe,
@@ -341,6 +341,48 @@ def check_counters(spec, served, before, lengths, ticks=TICKS):
             served, before, "state_steps"))) == dict(zip(
                 spec.state_counters, (len(lengths) * ticks * n, real * n,
                                       (padded - real) * n)))
+
+
+# ---------------------------------------------- a pass asked for no head
+
+def passes_both_ways(fam, cfg, params, n=21, cut=8, max_len=32):
+    """``{"prefill" | "extend": (logits, the family's logits of the stream,
+    stream, cache, the stream's cache)}``: a ``prefill`` of ``cut`` tokens
+    and the ragged ``extend`` of the ``n - cut`` after them, each as it is
+    by default and asked for no head (``head=False``), in ONE program."""
+    def run(p, t):
+        def both(call, *args, **kw):
+            lg, c = call(p, *args, **kw)
+            x, cx = call(p, *args, head=False, **kw)
+            return lg, fam.logits(p, x, cfg), x, c, cx
+        out = {"prefill": both(fam.prefill, t[:, :cut], cfg,
+                               fam.init_cache(cfg, 1, max_len))}
+        out["extend"] = both(fam.extend, t[:, cut:], cfg, out["prefill"][3],
+                             lengths=jnp.full((1,), cut, jnp.int32))
+        return out
+    return jax.jit(run)(params, tokens(cfg, n)[:1])
+
+
+@functools.lru_cache(maxsize=None)
+def loud_passes_both_ways(name):
+    """:func:`passes_both_ways` of the family's one model at ``LOUD``."""
+    cfg, params = loud(SPECS[name])
+    return passes_both_ways(cache_family(cfg), cfg, params)
+
+
+def check_a_pass_without_its_head(cfg, tokens_in, got):
+    """What ``head=False`` promises of one pass (:func:`passes_both_ways`'s
+    tuple): the stream is ``[B, S, d]`` in the compute dtype, the family's
+    ``logits`` of it are the logits the pass returns by default, and the
+    cache is the same cache."""
+    logits, of_stream, x, cache, cache_x = got
+    assert x.ndim == 3 and x.shape[:2] == tokens_in and x.dtype == cfg.dtype
+    assert logits.dtype == jnp.float32 and logits.shape[:2] == tokens_in \
+        and logits.shape[2] >= cfg.vocab_size
+    np.testing.assert_array_equal(np.asarray(of_stream), np.asarray(logits))
+    for a, b in zip(jax.tree_util.tree_leaves(cache),
+                    jax.tree_util.tree_leaves(cache_x), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ------------------------------------------------------------- the faults

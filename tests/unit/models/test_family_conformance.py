@@ -57,6 +57,19 @@ def test_the_slot_path_equals_the_reference(name, lengths):
     harness.check_counters(spec, served, before, lengths)
 
 
+@pytest.mark.parametrize("which", ["prefill", "extend"])
+@pytest.mark.parametrize("name", harness.families())
+def test_a_pass_asked_for_no_head_returns_what_the_head_takes(name, which):
+    """``prefill`` / ``extend`` with ``head=False`` (what an admission's
+    passes ask: the head runs once, after them, on one row) return the layer
+    stack's output in the logits' place: the family's ``logits`` of it are
+    the logits the pass returns by default, the cache is the same."""
+    cfg, _ = harness.loud(SPECS[name])
+    harness.check_a_pass_without_its_head(
+        cfg, {"prefill": (1, 8), "extend": (1, 13)}[which],
+        harness.loud_passes_both_ways(name)[which])
+
+
 @pytest.mark.parametrize("name,fault,mode", harness.cases(
     lambda spec: spec.faults, id_is_a_value=True))
 def test_a_planted_fault_reads_over_the_tolerance(name, fault, mode):

@@ -52,6 +52,36 @@ def test_cache_family_picks_by_config():
     assert eng._family is gpt_inference.DENSE
 
 
+#: ``(family, n, cut) -> harness.passes_both_ways``: one program for both
+_BOTH_WAYS = {}
+
+
+@pytest.mark.parametrize("which", ["prefill", "extend", "prefill-in-chunks"])
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_a_pass_asked_for_no_head_returns_what_the_head_takes(family, which):
+    """``head=False`` for the two families of ``gpt_inference`` itself (the
+    expert families': ``test_family_conformance.py``): the stream of which
+    the family's ``logits`` are the default's logits, and the same cache;
+    through GPT-MoE's own prompt pass too, whose walk over a prompt longer
+    than its gate's bound concatenates the chunks' streams."""
+    from tests.unit.models import family_harness as harness
+    fam, mod, cfg = {"dense": (gpt_inference.DENSE, gpt, CFG),
+                     "moe": (gpt_moe_inference.FAMILY, gpt_moe, MOE_CFG)}[
+                         family]
+    n, cut = (21, 8)
+    if which == "prefill-in-chunks":
+        cfg = dataclasses.replace(cfg, max_seq_len=192)
+        n, cut, which = 150, 140, "prefill"
+        assert cut > gpt_moe_inference._PREFILL_CHUNK
+    if (family, n, cut) not in _BOTH_WAYS:
+        _BOTH_WAYS[family, n, cut] = harness.passes_both_ways(
+            fam, cfg, mod.init(cfg, jax.random.PRNGKey(0)), n=n, cut=cut,
+            max_len=cfg.max_seq_len)
+    harness.check_a_pass_without_its_head(
+        cfg, (1, cut if which == "prefill" else n - cut),
+        _BOTH_WAYS[family, n, cut][which])
+
+
 @pytest.mark.parametrize("fam, mod, cfg", [
     pytest.param(gpt_inference.DENSE, gpt, CFG, id="dense"),
     pytest.param(gpt_moe_inference.FAMILY, gpt_moe, MOE_CFG, id="moe")])
@@ -490,13 +520,17 @@ def _served(family, kv):
 
 #: chunk programs that take ``valid``, a batcher of a family that needs it
 _TOLD_THE_PADDING = {}
+#: the family's ``logits`` as a program of its own, a batcher
+_HEADS = {}
 
 
 def _launch_by_launch(bat, row, tokens, key, greedy, temperature,
                       prefix=None):
     """An admission as it was before it was one program: a fresh batch-1
-    cache (or the prefix's), a ``prefill`` / ``extend`` launch a chunk, the
-    last real token's logits taken, ``write_slot`` and the six binds."""
+    cache (or the prefix's), a ``prefill`` / ``extend`` launch a chunk (the
+    registered pair, which run no head), the family's ``logits`` of the last
+    real token's row of the last launch's output, ``write_slot`` and the six
+    binds."""
     fam, cfg, params = bat._fam, bat._cfg, bat._engine.params
     start = 0 if prefix is None else prefix.length
     cache = prefix.cache if prefix is not None else fam.init_cache(
@@ -509,9 +543,9 @@ def _launch_by_launch(bat, row, tokens, key, greedy, temperature,
         # which the registered pair (a prefix's builders) cannot say
         prefill, extend = _TOLD_THE_PADDING.setdefault(id(bat), (
             jax.jit(lambda p, t, c, valid: fam.prefill(
-                p, t, cfg, c, valid=valid)),
+                p, t, cfg, c, valid=valid, head=False)),
             jax.jit(lambda p, t, c, l, valid: fam.extend(
-                p, t, cfg, c, lengths=l, valid=valid))))
+                p, t, cfg, c, lengths=l, valid=valid, head=False))))
     for at in range(0, len(new), C):
         chunk = np.zeros((1, C), np.int32)
         real = len(new[at:at + C])
@@ -519,12 +553,14 @@ def _launch_by_launch(bat, row, tokens, key, greedy, temperature,
         valid = (jnp.asarray([real], jnp.int32),) \
             if prefill is not bat._p["prefill"] else ()
         if start + at == 0:
-            lg, cache = prefill(params, jnp.asarray(chunk), cache, *valid)
+            x, cache = prefill(params, jnp.asarray(chunk), cache, *valid)
         else:
-            lg, cache = extend(
+            x, cache = extend(
                 params, jnp.asarray(chunk), cache,
                 jnp.asarray([start + at], jnp.int32), *valid)
-    vec = lg[0, len(new) - 1 - at]
+    head = _HEADS.setdefault(id(bat), jax.jit(
+        lambda p, x: fam.logits(p, x, cfg)))
+    vec = head(params, x[0, len(new) - 1 - at][None])[0]
     if bat._last is None:
         bat._last = jnp.zeros((bat.slots,) + vec.shape, vec.dtype)
     bat.cache = fam.write_slot(bat.cache, jnp.int32(row), cache)
@@ -721,7 +757,8 @@ def _counting_family(vocab=4):
     program asks of it: the passes it ran, the tokens of those wider than
     the chunk (handed over where a family's ``cfg`` goes) and of those
     narrower, the rows of them all, how often each position was computed as
-    a real token, and in its "logits" the position each row stands at."""
+    a real token, and in a pass's output (which its "head" hands on as it
+    is) the position each row stands at."""
     import types
 
     def init_cache(cfg, batch, max_len, kv_dtype=None):
@@ -734,18 +771,21 @@ def _counting_family(vocab=4):
         at = pos0 + jnp.arange(w)
         seen = cache.seen.at[at].add(
             (jnp.arange(w) < valid[0]).astype(jnp.int32), mode="drop")
-        lg = jnp.broadcast_to(at.astype(jnp.float32)[None, :, None],
-                              (1, w, vocab))
-        return lg, _Counts(cache.passes + 1,
+        x = jnp.broadcast_to(at.astype(jnp.float32)[None, :, None],
+                             (1, w, vocab))
+        return x, _Counts(cache.passes + 1,
                            cache.wide + (w if w > chunk else 0),
                            cache.narrow + (w if w < chunk else 0),
                            cache.rows + w, seen, pos0 + w)
 
     return types.SimpleNamespace(
         init_cache=init_cache,
-        prefill=lambda p, t, cfg, c, valid=None: run(t, c, 0, valid, cfg),
-        extend=lambda p, t, cfg, c, lengths=None, valid=None: run(
-            t, c, lengths[0], valid, cfg),
+        embed=lambda p, t, cfg, positions: jnp.zeros(t.shape + (vocab,)),
+        logits=lambda p, x, cfg: x,
+        prefill=lambda p, t, cfg, c, valid=None, head=True: run(
+            t, c, 0, valid, cfg),
+        extend=lambda p, t, cfg, c, lengths=None, valid=None, head=True:
+            run(t, c, lengths[0], valid, cfg),
         write_slot=lambda pool, row, cache: cache)
 
 
@@ -959,11 +999,16 @@ def test_wide_passes_of_a_state_family_and_of_a_ring_family(monkeypatch,
                 _same_slot(fused, plain, n, cut, tol=2e-3)
 
 
-@pytest.mark.parametrize("family", [
+#: every family the batcher serves, by what :func:`_laddered` draws it from
+_EVERY_FAMILY = (
     "tiny-dense", "tiny-moe", "kimi-k2.7-code-ep32",
     "granite-4.0-h-small-ep4", "nemotron-3-nano-30b-a3b-ep4",
     "mellum2-12b-a2.5b-ep4", "dots3-note-prev-ep32",
-    "kimi-linear-48b-a3b-ep8", "longcat-flash-chat-ep32", "lfm2-8b-a1b"])
+    "kimi-linear-48b-a3b-ep8", "longcat-flash-chat-ep32", "lfm2-8b-a1b",
+    "solar-open2-250b-ep8")
+
+
+@pytest.mark.parametrize("family", _EVERY_FAMILY)
 def test_a_narrow_last_pass_leaves_the_slot_as_the_chunk_leaves_it(
         monkeypatch, family):
     """The ladder downward with its ridge patched small and its slots'
@@ -987,6 +1032,98 @@ def test_a_narrow_last_pass_leaves_the_slot_as_the_chunk_leaves_it(
         for cut in cuts:
             if n - cut > 0:
                 _same_slot(fused, plain, n, cut, tol=2e-3)
+
+
+#: ``batcher -> whole-prompt prefill``, jitted once a family
+_WHOLE_PROMPT = {}
+
+
+def _whole_prompts_last_logits(bat, tokens):
+    """Row ``n - 1`` of the logits ONE ``prefill`` of the whole prompt
+    returns (the family's head over every row, as every pass of an admission
+    ran it before the head ran once): the prompt padded to the slot, told
+    where it ends."""
+    fam, cfg = bat._fam, bat._cfg
+    whole = _WHOLE_PROMPT.setdefault(id(bat), jax.jit(
+        lambda p, t, valid: fam.prefill(
+            p, t, cfg, fam.init_cache(cfg, 1, bat.max_len), valid=valid)[0]))
+    padded = np.zeros((1, bat.max_len), np.int32)
+    padded[0, :len(tokens)] = tokens
+    return np.asarray(whole(bat._engine.params, padded, jnp.asarray(
+        [len(tokens)], jnp.int32)))[0, len(tokens) - 1]
+
+
+@pytest.mark.parametrize("prompt", [
+    "under-a-pass", "on-a-passes-edge", "passes-then-a-narrow-one",
+    "a-narrow-pass-alone", "after-a-prefix", "the-whole-slot"])
+@pytest.mark.parametrize("family", _EVERY_FAMILY)
+def test_the_admissions_row_is_the_whole_prompts_last_logits(
+        monkeypatch, family, prompt):
+    """The head runs ONCE an admission, on the one row the passes kept:
+    for every family the frontier logits an admission binds (and returns)
+    are row ``n - 1`` of the logits a whole-prompt ``prefill`` gives with
+    its head over every row, and the first greedy token is that row's
+    argmax; for a prompt inside one pass, one that ends on a pass's edge,
+    one of several passes whose end a narrow pass takes, one that is a
+    narrow pass alone, one that continues a prefix (refused in the family's
+    words where it serves none) and one that fills the slot; from the slot's
+    own row of the pool (the dense and GPT-MoE families) and from an empty
+    row cache (every other), at widths of 8 and 4 in slots of 64."""
+    from deepspeed_tpu.serving import batcher
+    monkeypatch.setattr(batcher, "NARROW_FLOOR", 2)
+    monkeypatch.setattr(batcher, "NARROW_SLOT_CHUNKS", SLOT // CHUNK)
+    fused, _ = _laddered(family, "narrow")
+    assert batcher.pass_widths(fused.chunk, fused.max_len) == (8, 4)
+    assert fused._in_place == (family in ("tiny-dense", "tiny-moe"))
+    n, cut = {"under-a-pass": (6, 0), "on-a-passes-edge": (16, 0),
+              "passes-then-a-narrow-one": (27, 0),
+              "a-narrow-pass-alone": (3, 0), "after-a-prefix": (19, 5),
+              "the-whole-slot": (SLOT, 0)}[prompt]
+    vocab = fused._cfg.vocab_size
+    tokens = np.random.default_rng(n).integers(0, vocab, (n,)).astype(
+        np.int32)
+    if cut and fused.unsupported("prefix"):
+        with pytest.raises(NotImplementedError, match="prefix"):
+            fused.build_prefix(tokens[:cut])
+        return
+    for r in range(fused.slots):
+        fused.release(r)
+    fused.admit(1, tokens, jax.random.PRNGKey(n), True, 1.0,
+                prefix=fused.build_prefix(tokens[:cut]) if cut else None)
+    want = _whole_prompts_last_logits(fused, tokens)
+    np.testing.assert_allclose(np.asarray(fused._last[1]), want,
+                               rtol=2e-4, atol=2e-4)
+    if n < fused.max_len:
+        assert int(fused.tick()[1]) == int(np.argmax(want[:vocab]))
+    assert all(v <= 1 for v in fused.compile_counts().values())
+
+
+@pytest.mark.parametrize("family,dtype,first,second", [
+    ("dense", "float32", [204, 102, 130, 237, 216], [204, 102, 130, 237, 216]),
+    ("dense", "bfloat16", [204, 102, 130, 237, 216],
+     [204, 102, 130, 237, 216]),
+    ("moe", "float32", [102, 51, 65, 118, 108], [102, 51, 77, 118, 108]),
+    ("moe", "bfloat16", [102, 51, 65, 118, 108], [102, 51, 77, 118, 108])],
+    ids=["dense-float32", "dense-bfloat16", "moe-float32", "moe-bfloat16"])
+def test_a_seeded_batchs_greedy_tokens_are_the_parents(family, dtype, first,
+                                                       second):
+    """Five seeded prompts of 3 to 59 tokens in slots of 64, chunks of 8:
+    the first two greedy tokens of every row are what the tree gave while
+    every pass of an admission ran its head over all its rows (the PARENT
+    of PR 65, ``git archive 4599934``; all eleven families' five tokens a
+    row were compared once by hand, in bf16 and in float32: PERF.md 6)."""
+    mod, cfg = (gpt, CFG) if family == "dense" else (gpt_moe, MOE_CFG)
+    cfg = dataclasses.replace(cfg, dtype=jnp.dtype(dtype))
+    eng = deepspeed_tpu.init_inference(
+        model=(cfg, mod.init(cfg, jax.random.PRNGKey(0))),
+        config={"dtype": dtype})
+    bat = SlotBatcher(eng, ServingConfig.from_dict(
+        {"slots": 5, "max_len": SLOT, "prefill_chunk": CHUNK}))
+    rng = np.random.default_rng(11)
+    for row, n in enumerate((3, 8, 13, 27, 59)):
+        bat.admit(row, rng.integers(0, cfg.vocab_size, (n,)).astype(
+            np.int32), jax.random.PRNGKey(row), True, 1.0)
+    assert [[int(t) for t in bat.tick()] for _ in range(2)] == [first, second]
 
 
 def test_the_ladder_in_both_directions_at_once(monkeypatch):
